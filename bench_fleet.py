@@ -157,13 +157,23 @@ def ttft_percentiles(ring, completed_ids):
             "p99_s": round(float(np.percentile(arr, 99)), 4)}
 
 
-def elastic_main(args) -> int:
-    """--elastic: sine-wave load vs the autoscaler + a live rolling
-    weight update; stamps ELASTIC_BENCH.json."""
+def _init_jax(args):
+    """Import jax on the platform ``--cpu`` asks for, with the one
+    persistent compilation cache every chip program shares."""
     import jax
 
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
+    from deepspeed_tpu.utils.backend import enable_compile_cache
+
+    enable_compile_cache()
+    return jax
+
+
+def elastic_main(args) -> int:
+    """--elastic: sine-wave load vs the autoscaler + a live rolling
+    weight update; stamps ELASTIC_BENCH.json."""
+    jax = _init_jax(args)
 
     from deepspeed_tpu.autoscale import FleetAutoscaler
     from deepspeed_tpu.fleet import DEAD, fleet_router
@@ -360,10 +370,7 @@ def elastic_main(args) -> int:
 
 def disagg_main(args) -> int:
     """--disagg: the KV-fabric A/Bs; stamps DISAGG_BENCH.json."""
-    import jax
-
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
+    jax = _init_jax(args)
 
     import numpy as np
 
@@ -587,10 +594,7 @@ def procs_main(args) -> int:
         out-of-process fleet; recovery_s measured from the signal,
         salvage partition recorded, completed tokens still identical
         to the in-process arm."""
-    import jax
-
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
+    jax = _init_jax(args)
     # the children pin this flag (tools/replica_child.py): the
     # in-process arm must draw identical init params
     jax.config.update("jax_threefry_partitionable", True)
@@ -986,10 +990,7 @@ def main():
     if args.procs:
         return procs_main(args)
 
-    import jax
-
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
+    jax = _init_jax(args)
 
     from deepspeed_tpu.models import gpt2
     from deepspeed_tpu.utils.evidence import atomic_write_json

@@ -19,10 +19,6 @@ Public entrypoints mirror the reference:
 
 __version__ = "0.1.0"
 
-# FIRST import: resolves shard_map across JAX versions and publishes the
-# portable wrapper at ``jax.shard_map`` when the pinned JAX lacks the
-# top-level entrypoint (mesh.install()), so every module below — and
-# modern-idiom user code — can use one spelling.
 from deepspeed_tpu import mesh
 from deepspeed_tpu.config import Config
 from deepspeed_tpu.topology import MeshSpec, default_mesh

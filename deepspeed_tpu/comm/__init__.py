@@ -189,9 +189,7 @@ def reduce_scatter(x, axis_name: str, axis: int = 0,
         raise ValueError("reduce_scatter supports SUM/AVG")
     out = jax.lax.psum_scatter(x, axis_name, scatter_dimension=axis, tiled=True)
     if op is ReduceOp.AVG:
-        from deepspeed_tpu.mesh import axis_size
-
-        out = out / axis_size(axis_name)
+        out = out / jax.lax.axis_size(axis_name)
     return out
 
 
@@ -229,7 +227,6 @@ def rank_in(axis_name: str):
 # --------------------------------------------------------------------------
 def mesh_all_reduce(x: jax.Array, mesh: Mesh, op: ReduceOp = ReduceOp.SUM) -> jax.Array:
     """Reduce a per-device-sharded array to a replicated one."""
-    from deepspeed_tpu.mesh import shard_map
 
     axes = mesh.axis_names
 
@@ -242,5 +239,5 @@ def mesh_all_reduce(x: jax.Array, mesh: Mesh, op: ReduceOp = ReduceOp.SUM) -> ja
     # host-level op: this record is WALL-TIMED (dispatch side) with the
     # full array's bytes, unlike the trace-time SPMD records above
     with _comms_logger.record("mesh_all_reduce", _nbytes(x)):
-        return jax.jit(shard_map(f, mesh=mesh, in_specs=spec,
-                                 out_specs=P()))(x)
+        return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=spec,
+                                     out_specs=P()))(x)

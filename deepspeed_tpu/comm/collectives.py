@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.mesh import axis_size, detect_hierarchy_size
+from deepspeed_tpu.mesh import detect_hierarchy_size
 from deepspeed_tpu.ops.quant import (
     BLOCK_ELEMS, INT_BOUNDS, block_pad, dequantize, quantize,
     quantized_all_gather, quantized_reduce_scatter)

@@ -42,6 +42,13 @@ _INSTR = re.compile(
     r"=\s+(\([^)]*\)|[a-z0-9]+\[[^\]]*\]\S*)\s+"
     r"((?:all-reduce|all-gather|reduce-scatter|all-to-all|"
     r"collective-permute)(?:-start)?)(?!-done)\b")
+# The TPU compiler emits a reduce-scatter as a custom fusion whose called
+# computation is named all-reduce-scatter ("%fusion.386 = bf16[...]
+# fusion(%x), kind=kCustom, calls=%all-reduce-scatter.clone"): no
+# reduce-scatter opcode is left in the text (compiled for a v5e, PR 21).
+_FUSED_REDUCE_SCATTER = re.compile(
+    r"=\s+(\([^)]*\)|[a-z0-9]+\[[^\]]*\]\S*)\s+fusion\([^)]*\),"
+    r"\s*kind=kCustom,\s*calls=%all-reduce-scatter\b")
 _SHAPE = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
 
 
@@ -68,8 +75,11 @@ def analyze_collectives(hlo_text: str,
     """
     per_kind: Dict[str, Dict[str, int]] = {
         k: {"count": 0, "bytes": 0} for k in COLLECTIVE_KINDS}
-    for typestr, opcode in _INSTR.findall(hlo_text):
-        kind = opcode.replace("-start", "")
+    found = [(t, op.replace("-start", ""))
+             for t, op in _INSTR.findall(hlo_text)]
+    found += [(t, "reduce-scatter")
+              for t in _FUSED_REDUCE_SCATTER.findall(hlo_text)]
+    for typestr, kind in found:
         per_kind[kind]["count"] += 1
         per_kind[kind]["bytes"] += _shape_bytes(typestr)
     total_bytes = sum(v["bytes"] for v in per_kind.values())

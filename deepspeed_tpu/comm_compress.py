@@ -53,7 +53,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.mesh import axis_size, shard_map
 from deepspeed_tpu.ops.quant import dequantize, quantize, \
     quantized_reduce_scatter
 from deepspeed_tpu.topology import MeshSpec
@@ -153,7 +152,7 @@ def quantized_all_reduce(x: jnp.ndarray, axis_name: str = AXIS,
     all-gather of the reduced shard — every hop carries ~1/4 the bytes of
     the f32 ring all-reduce GSPMD would emit.
     """
-    world = axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     flat = _pad_to(x.reshape(-1).astype(jnp.float32), world * _GROUP)
     shard = flat.shape[0] // world
     groups = shard // _GROUP
@@ -305,7 +304,7 @@ def local_grad_shardmap(grad_fn: Callable, ms: MeshSpec, accum: int,
         return grads, jax.lax.pmean(loss, AXIS)
 
     pspec = lambda tree: jax.tree.map(lambda _: P(), tree)
-    return lambda params, batch: shard_map(
+    return lambda params, batch: jax.shard_map(
         f, mesh=ms.mesh,
         in_specs=(pspec(params), jax.tree.map(lambda _: P(AXIS), batch)),
         out_specs=(pspec(params), P()),

@@ -17,10 +17,9 @@ This module closes the gap with three coupled capabilities:
   the engine around the programs ``_build_programs`` produced) via the
   jitted function's ``_cache_size()`` — cheap, exact per site.  A
   process-wide ``jax.monitoring`` duration listener (installed once by
-  :func:`install_compile_listener`, which ``mesh.install()`` calls)
-  pairs best-effort compile DURATIONS with the wrapper's counts; when
-  ``jax.monitoring`` is absent the wrappers alone still count every
-  compile.  A steady-state recompile is a **contract violation**: the
+  :func:`install_compile_listener`, which the first :class:`DevProf`
+  calls) pairs best-effort compile DURATIONS with the wrapper's
+  counts.  A steady-state recompile is a **contract violation**: the
   incident probe trips a ``steady_state_recompile`` bundle and the
   bench gate pins ``steady_state_recompiles == 0``.
 
@@ -118,21 +117,15 @@ def _on_event_duration(event: str, duration: float, **kw) -> None:
 
 def install_compile_listener() -> bool:
     """Install the process-wide compile-duration listener (idempotent).
-    Returns True when installed (now or earlier), False when the pinned
-    jax has no ``jax.monitoring`` listener API — the call-site wrappers
-    then count compiles without durations (the documented fallback)."""
+    Returns True: the installed JAX has ``jax.monitoring`` (the wrappers
+    pair its durations with their own exact counts)."""
     global _listener_installed
     with _listener_lock:
-        if _listener_installed:
-            return True
-        mon = getattr(jax, "monitoring", None)
-        reg = getattr(mon, "register_event_duration_secs_listener",
-                      None)
-        if reg is None:
-            return False
-        reg(_on_event_duration)
-        _listener_installed = True
-        return True
+        if not _listener_installed:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_event_duration)
+            _listener_installed = True
+    return True
 
 
 def compile_listener_installed() -> bool:

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 
 from deepspeed_tpu import lr_schedules, precision, zero
 from deepspeed_tpu.config import Config
-from deepspeed_tpu.mesh import shard_map
 from deepspeed_tpu.ops.optim import Optimizer, from_config as opt_from_config
 from deepspeed_tpu.topology import MeshSpec, default_mesh
 from deepspeed_tpu.utils.logging import logger
@@ -591,7 +590,7 @@ class TrainingEngine:
         opt_specs = jax.tree.map(
             lambda x: P("data") if getattr(x, "ndim", 0) == 2 else P(),
             state.opt_state)
-        new_pflat, new_opt, loss, gnorm, ok = shard_map(
+        new_pflat, new_opt, loss, gnorm, ok = jax.shard_map(
             f, mesh=ms.mesh,
             in_specs=(P("data"), opt_specs,
                       jax.tree.map(lambda _: P("data"), batch)),
@@ -783,7 +782,7 @@ class TrainingEngine:
             mu=repl(state.opt_state.mu),
             nu=repl(state.opt_state.nu),
             err=err_spec)
-        new_params, new_opt, loss, gnorm, ok = shard_map(
+        new_params, new_opt, loss, gnorm, ok = jax.shard_map(
             f, mesh=ms.mesh,
             in_specs=(repl(state.params), opt_specs,
                       jax.tree.map(lambda _: P("data"), batch)),

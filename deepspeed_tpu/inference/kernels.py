@@ -1131,8 +1131,28 @@ class ServingKernelPolicy(NamedTuple):
         }
 
 
+class ServingKernelRefused(ValueError):
+    """A forced kernel choice the TPU compiler is known to refuse for
+    this engine's cache layout — raised at engine build, with the
+    compiler's reason, instead of a Mosaic error in the middle of
+    serving."""
+
+
+# Why the int8-resident Pallas kernels cannot run on the chip: compiled
+# for a described v5e, paged_decode_attention_v2_quant is refused with
+# this error (tests/test_aot_tpu_compile.py pins it).  The [KV, P, ps, 1]
+# f32 scale pages put a unit dim on the 128-lane axis, so the per-page
+# scale DMA is a 1-wide slice of a 128-wide tile.
+_QUANT_RESIDENT_PALLAS_REFUSAL = (
+    "MosaicError: Slice shape along dimension 3 must be aligned to tiling "
+    "(128), but is 1 — the int8-resident kernel's per-page DMA of the "
+    "[KV, P, ps, 1] f32 scale planes")
+
+
 def resolve_serving_kernels(kernels=None, *, tp: bool = False,
-                            interpret: bool = False) -> ServingKernelPolicy:
+                            interpret: bool = False,
+                            quantized_resident: bool = False
+                            ) -> ServingKernelPolicy:
     """Resolve the serving kernel-dispatch policy ONCE, at engine build.
 
     ``kernels``: a ``KernelsConfig`` / dict / None (all-auto).  Env vars
@@ -1151,6 +1171,14 @@ def resolve_serving_kernels(kernels=None, *, tp: bool = False,
     the demotion is VISIBLE (``fallbacks`` row + the engine's
     ``serving_kernel_fallbacks`` counter), fixing the old silent
     ``tp → False``.
+
+    ``quantized_resident`` (the engine's ``kv_tier.quantized_resident``
+    cache layout) on a real chip (``interpret=False``): the TPU
+    compiler refuses the int8-resident Pallas kernel, so a forced
+    ``pallas_v2`` raises :class:`ServingKernelRefused` here at build,
+    and ``auto`` resolves to ``xla`` with a ``fallbacks`` row — the
+    shape gate inside the forward never gets to pick a kernel that
+    cannot compile.
 
     An already-resolved :class:`ServingKernelPolicy` passes through
     untouched — the model builders resolve once and hand the SAME
@@ -1200,6 +1228,19 @@ def resolve_serving_kernels(kernels=None, *, tp: bool = False,
                           "the mesh; the kernel reads the full page "
                           "table per device"))
         paged = "xla"
+    if quantized_resident and not interpret:
+        if paged == "pallas_v2":
+            raise ServingKernelRefused(
+                "kernels.paged_attention=pallas_v2 cannot serve "
+                "int8-resident pages (kv_tier.quantized_resident) on "
+                f"TPU: {_QUANT_RESIDENT_PALLAS_REFUSAL}. Use "
+                "paged_attention=xla (dequantize + gather), or drop "
+                "quantized_resident")
+        if paged == "auto":
+            fallbacks.append(("paged_attention=auto", "xla",
+                              "quant_resident_unsupported: "
+                              + _QUANT_RESIDENT_PALLAS_REFUSAL))
+            paged = "xla"
     if fused == "auto":
         # the measured policy (KERNEL_BENCH.json fused_sample_vs_xla):
         # sampling is one [B, V] argmax — the jitted XLA twin wins at

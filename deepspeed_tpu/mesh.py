@@ -1,134 +1,28 @@
-"""Version-portable shard_map / mesh layer — the SPMD core every
-manual-collective path routes through.
+"""Mesh helpers — the SPMD core every parallel path builds on.
 
 GSPMD (arXiv:2105.04663) is the compilation model: ONE jitted program,
 named mesh axes, ``NamedSharding``/``PartitionSpec`` annotations, and
-XLA choosing the collectives.  ``shard_map`` is the escape hatch for the
-paths that schedule their own collectives (pipeline ticks, ring/Ulysses
-attention, int8 gradient wires, 1-bit momentum) — and it is also the
-API JAX has moved twice:
-
-=================  ==========================  =========================
-spelling           modern (jax >= 0.5.x)       pinned legacy (0.4.x)
-=================  ==========================  =========================
-entrypoint         ``jax.shard_map``           ``jax.experimental.
-                                               shard_map.shard_map``
-manual axes        ``axis_names={...}``        ``auto=frozenset(rest)``
-replication check  ``check_vma=``              ``check_rep=``
-=================  ==========================  =========================
-
-This module resolves the spelling ONCE and exposes one portable
-:func:`shard_map` (plus :func:`axis_size`, the other renamed API) so
-callers never touch a version-specific attribute again.  The package
-was written against the modern spelling; on the pinned JAX the bare
-``jax.shard_map`` attribute does not exist and 31 seed tests died on
-the AttributeError — :func:`install` also publishes the portable
-wrapper AT ``jax.shard_map`` so modern-idiom code (including tests)
-runs unmodified.
-
-Partial manualization note: the modern ``axis_names={...}`` keyword
-leaves the unnamed axes under GSPMD inside the region.  The pinned
-jaxlib's SPMD partitioner cannot lower that mode on CPU (eager dispatch
-is ``NotImplementedError``; under jit ``axis_index`` lowers to a
-``PartitionId`` op the partitioner rejects and f32 psum CHECK-fails on
-``IsManualSubgroup``), so on legacy JAX the wrapper degrades to FULL
-manualization.  ``shard_map`` semantics are defined on global arrays —
-in_specs/out_specs describe the same global-to-local slicing either
-way — so results are identical; the axes you would have left auto are
-simply replicated inside the region (a memory/perf trade on multi-axis
-meshes, not a numerics one; MIGRATION.md "modern mesh idiom" has the
-full contract).  Set ``DSTPU_PARTIAL_MANUAL=1`` to pass ``auto=``
-through natively on stacks where the lowering works.
+XLA choosing the collectives.  ``jax.shard_map`` (``axis_names=`` for
+the axes the body manages itself, ``check_vma=``) is the escape hatch
+for the paths that schedule their own collectives — pipeline ticks,
+ring/Ulysses attention, int8 gradient wires, 1-bit momentum, the flash
+kernel on a mesh — and callers use it and ``jax.lax.axis_size``
+directly: there is one installed JAX and no spelling to resolve.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Any, Dict, Optional, Sequence
+from typing import Dict, Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = [
-    "shard_map", "axis_size", "resolve_shard_map", "install",
     "make_mesh", "named_sharding", "pspec", "mesh_axis_sizes",
     "host_device_count", "detect_hierarchy_size",
 ]
-
-
-def resolve_shard_map():
-    """Locate the native shard_map: ``(callable, style)`` where style is
-    ``"modern"`` (top-level ``jax.shard_map``, axis_names/check_vma
-    keywords) or ``"legacy"`` (``jax.experimental.shard_map``,
-    auto/check_rep keywords).  A wrapper previously published by
-    :func:`install` is never mistaken for a native modern entrypoint."""
-    native = getattr(jax, "shard_map", None)
-    if native is not None and not getattr(native, "_dstpu_shim", False):
-        return native, "modern"
-    from jax.experimental.shard_map import shard_map as legacy
-
-    return legacy, "modern" if legacy is native else "legacy"
-
-
-_NATIVE, _STYLE = resolve_shard_map()
-
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None, *,
-              axis_names=None, check_vma=None, check_rep=None,
-              auto=None, **kw):
-    """Portable ``shard_map`` accepting BOTH keyword dialects.
-
-    ``axis_names`` (modern): the axes the body manages manually; the
-    rest stay under GSPMD.  ``auto`` (legacy): the complement — axes
-    GSPMD keeps.  Pass either; the resolved native entrypoint gets the
-    spelling it understands.  ``check_vma``/``check_rep`` are the same
-    flag under its two names (default True, like both natives).
-
-    On legacy JAX a partial-manual request degrades to full
-    manualization unless ``DSTPU_PARTIAL_MANUAL=1`` (see the module
-    docstring for why that is semantics-preserving).
-    """
-    if mesh is None:
-        raise TypeError("shard_map requires mesh=")
-    check = True
-    if check_vma is not None:
-        check = bool(check_vma)
-    elif check_rep is not None:
-        check = bool(check_rep)
-    all_axes = frozenset(mesh.axis_names)
-    manual: frozenset = all_axes
-    if axis_names is not None and auto is not None:
-        raise TypeError("pass axis_names= or auto=, not both")
-    if axis_names is not None:
-        manual = frozenset(axis_names) & all_axes
-    elif auto is not None:
-        manual = all_axes - frozenset(auto)
-    if _STYLE == "modern":
-        mkw = dict(kw)
-        if manual != all_axes:
-            mkw["axis_names"] = set(manual)
-        return _NATIVE(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=check, **mkw)
-    legacy_auto = frozenset()
-    if manual != all_axes and os.environ.get("DSTPU_PARTIAL_MANUAL"):
-        legacy_auto = all_axes - manual
-    return _NATIVE(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check, auto=legacy_auto, **kw)
-
-
-shard_map._dstpu_shim = True  # type: ignore[attr-defined]
-
-
-def axis_size(axis_name: str):
-    """Portable ``jax.lax.axis_size`` (absent on the pinned JAX): the
-    size of a named mesh axis, from inside SPMD code.  ``psum(1, axis)``
-    is the classic spelling — it folds to a static int at trace time,
-    so the result is safe in shape positions (``jnp.arange(n)``)."""
-    native = getattr(jax.lax, "axis_size", None)
-    if native is not None:
-        return native(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 # ------------------------------------------------------------- helpers
@@ -216,33 +110,3 @@ def host_device_count(n: int) -> None:
         return
     os.environ["XLA_FLAGS"] = (
         flags + f" --xla_force_host_platform_device_count={int(n)}")
-
-
-# ------------------------------------------------------------- install
-def install() -> bool:
-    """Publish the portable wrapper at ``jax.shard_map`` when the
-    pinned JAX predates the top-level entrypoint, so modern-idiom
-    callers (the package everywhere, the seed tests verbatim) never
-    see the AttributeError.  Never shadows a real native entrypoint.
-    Returns True when this call (or an earlier one) installed it.
-
-    Also installs devprof's process-wide ``jax.monitoring`` compile
-    listener (idempotent, best-effort): mesh import is the one choke
-    point every entrypoint passes through before the first jit, so
-    compile-duration events are captured even for programs built
-    before any engine constructs a :class:`~deepspeed_tpu.devprof
-    .DevProf`."""
-    try:
-        from deepspeed_tpu import devprof
-
-        devprof.install_compile_listener()
-    except Exception:
-        pass    # monitoring is an enhancement, never a mesh failure
-    native = getattr(jax, "shard_map", None)
-    if native is None:
-        jax.shard_map = shard_map
-        return True
-    return bool(getattr(native, "_dstpu_shim", False))
-
-
-install()

@@ -109,10 +109,12 @@ def _block(cfg: GPT2Config, x, lp):
     k = k.reshape(B, T, nh, hd)
     v = v.reshape(B, T, nh, hd)
     from deepspeed_tpu.ops.attention import flash_attention
+    from deepspeed_tpu.topology import current_mesh
 
     from jax.ad_checkpoint import checkpoint_name
 
-    attn = flash_attention(q, k, v, causal=True).reshape(B, T, d)
+    attn = flash_attention(q, k, v, causal=True,
+                           mesh=current_mesh()).reshape(B, T, d)
     attn = checkpoint_name(attn, "attn_out")   # remat.py save/offload tag
     x = x + attn @ lp["proj_w"] + lp["proj_b"]
     h = layer_norm(x, lp["ln2_w"], lp["ln2_b"], cfg.norm_eps)

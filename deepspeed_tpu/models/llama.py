@@ -240,14 +240,14 @@ def _attention(q, k, v, cfg: LlamaConfig, segment_ids=None):
                  vh.transpose(0, 2, 1, 3), segment_ids=segment_ids)
         return out.transpose(0, 2, 1, 3)
     if impl in ("auto", "flash"):
-        try:
-            from deepspeed_tpu.ops.attention import flash_attention
+        from deepspeed_tpu.ops.attention import flash_attention
+        from deepspeed_tpu.topology import current_mesh
 
-            return flash_attention(q, k, v, causal=True,
-                                   segment_ids=segment_ids)
-        except Exception:
-            if impl == "flash":
-                raise
+        # no except around this: a kernel the compiler refuses must
+        # stop the trace, not turn into the reference unseen
+        return flash_attention(q, k, v, causal=True,
+                               segment_ids=segment_ids,
+                               mesh=current_mesh())
     return reference_attention(q, k, v, causal=True, segment_ids=segment_ids)
 
 

@@ -3,8 +3,9 @@ ops/transformer/inference).
 
 ``flash_attention`` is the training entrypoint: a Pallas TPU kernel
 (block-tiled online-softmax, fwd+bwd custom VJP) with a jnp reference
-fallback for CPU/interpret runs.  The kernel lands in
-:mod:`deepspeed_tpu.ops.attention_pallas`; this module owns dispatch.
+for CPU runs and shapes the kernel's tiling does not cover.  The kernel
+lands in :mod:`deepspeed_tpu.ops.attention_pallas`; this module owns
+dispatch.
 """
 
 from __future__ import annotations
@@ -12,6 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 
 def _reference(q, k, v, causal=True, segment_ids=None):
@@ -33,27 +35,62 @@ def _reference(q, k, v, causal=True, segment_ids=None):
     return jnp.einsum("bhts,bshd->bthd", probs, v)
 
 
+def _pallas_shapes_ok(q, k, segment_ids=None) -> bool:
+    """The shapes the Pallas kernel's tiling covers (both lengths a
+    multiple of 128 and at least 256, head_dim 64 or 128; packed
+    layouts are self-attention only)."""
+    T, S = q.shape[1], k.shape[1]
+    return ((segment_ids is None or T == S)
+            and T >= 256 and T % 128 == 0
+            and S >= 256 and S % 128 == 0 and q.shape[-1] in (64, 128))
+
+
 def flash_attention(q, k, v, causal: bool = True, segment_ids=None,
-                    force_reference: bool = False):
+                    force_reference: bool = False, mesh=None):
     """[B,T,H,Dh] x [B,T,KV,Dh]^2 → [B,T,H,Dh].
 
     Dispatches to the Pallas TPU kernel when running on TPU with
     kernel-friendly shapes; otherwise the fused-softmax jnp reference
     (which XLA still fuses well).  ``force_reference``: callers whose
-    operands are model-axis sharded (TP serving) must skip the pallas
-    custom call — GSPMD cannot partition it.
+    operands are model-axis sharded without a mesh to split the call
+    over (TP serving) must skip the pallas custom call — GSPMD cannot
+    partition it.  ``mesh``: the :class:`~deepspeed_tpu.topology
+    .MeshSpec` the caller's step runs under (training models pass the
+    ambient one); the kernel then runs per shard, batch split over the
+    token-replicating axes and heads over ``model``.  Operands those
+    axes do not divide take the reference, which GSPMD partitions
+    itself.
     """
-    on_tpu = jax.default_backend() == "tpu"
-    T, S = q.shape[1], k.shape[1]
-    if on_tpu and not force_reference \
-            and (segment_ids is None or T == S) \
-            and T >= 256 and T % 128 == 0 \
-            and S >= 256 and S % 128 == 0 and q.shape[-1] in (64, 128):
-        try:
-            from deepspeed_tpu.ops.attention_pallas import flash_attention_tpu
+    if jax.default_backend() != "tpu" or force_reference \
+            or not _pallas_shapes_ok(q, k, segment_ids):
+        return _reference(q, k, v, causal=causal, segment_ids=segment_ids)
+    from deepspeed_tpu.ops.attention_pallas import flash_attention_tpu
 
-            return flash_attention_tpu(q, k, v, causal=causal,
-                                       segment_ids=segment_ids)
-        except ImportError:
-            pass
-    return _reference(q, k, v, causal=causal, segment_ids=segment_ids)
+    # one device, or already inside a shard_map (pipeline ticks,
+    # Ulysses): the call is direct, as it always was
+    if mesh is None or mesh.mesh.size == 1 \
+            or jax.sharding.get_abstract_mesh().manual_axes:
+        return flash_attention_tpu(q, k, v, causal=causal,
+                                   segment_ids=segment_ids)
+
+    # "Mosaic kernels cannot be automatically partitioned. Please wrap
+    # the call in a shard_map" — and only a full-manual one lowers (no
+    # axis_names=): nothing may be left for GSPMD to partition
+    from deepspeed_tpu.topology import BATCH_AXES
+
+    batch_axes = tuple(a for a in BATCH_AXES if mesh.size(a) > 1)
+    tp = mesh.size("model")
+    if q.shape[0] % int(np.prod([mesh.size(a) for a in batch_axes])) \
+            or q.shape[2] % tp or k.shape[2] % tp:
+        return _reference(q, k, v, causal=causal, segment_ids=segment_ids)
+    spec = P(batch_axes or None, None, "model" if tp > 1 else None, None)
+    args, in_specs = (q, k, v), (spec, spec, spec)
+    if segment_ids is not None:
+        args += (jnp.asarray(segment_ids, jnp.int32),)
+        in_specs += (P(batch_axes or None, None),)
+
+    def per_shard(q, k, v, seg=None):
+        return flash_attention_tpu(q, k, v, causal=causal, segment_ids=seg)
+
+    return jax.shard_map(per_shard, mesh=mesh.mesh, in_specs=in_specs,
+                         out_specs=spec, check_vma=False)(*args)

@@ -66,8 +66,7 @@ def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(rows >= cols, s, NEG_INF)
         if has_seg:
-            s = jnp.where(sq_ref[0][:, None] == sk_ref[0][None, :],
-                          s, NEG_INF)
+            s = jnp.where(sq_ref[0] == sk_ref[0], s, NEG_INF)
 
         m_prev = m_scr[:]                   # [BQ, 1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -126,8 +125,7 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, num_k_blocks,
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(rows >= cols, s, NEG_INF)
         if has_seg:
-            s = jnp.where(sq_ref[0][:, None] == sk_ref[0][None, :],
-                          s, NEG_INF)
+            s = jnp.where(sq_ref[0] == sk_ref[0], s, NEG_INF)
         p = jnp.exp(s - lse_ref[0])                     # [BQ, BK]
         dov = jax.lax.dot_general(
             do_ref[0].astype(jnp.float32), v_ref[0].astype(jnp.float32),
@@ -178,8 +176,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, num_q_blocks,
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(rows >= cols, s, NEG_INF)
         if has_seg:
-            s = jnp.where(sq_ref[0][:, None] == sk_ref[0][None, :],
-                          s, NEG_INF)
+            s = jnp.where(sq_ref[0] == sk_ref[0], s, NEG_INF)
         p = jnp.exp(s - lse_ref[0])                     # [BQ, BK]
         do = do_ref[0].astype(jnp.float32)
         dv_scr[:] += jax.lax.dot_general(
@@ -217,16 +214,26 @@ def _kv_row(b, heads, kv_heads):
     return (b // heads) * kv_heads + (b % heads) // g
 
 
+def _seg_operands(seg):
+    """[B, T] segment ids → the two layouts the kernels read: q-side
+    ``[B, T, 1]`` (a column, like lse) and k-side ``[B, 1, T]`` (a row),
+    so ``sq == sk`` broadcasts to the [BQ, BK] mask with no in-kernel
+    relayout.  A bare ``[B, T]`` operand cannot be blocked ``(1, BQ)``:
+    Mosaic needs the second-to-last block dim divisible by 8 or equal
+    to the array's, which the unit middle axis gives."""
+    return [seg[:, :, None], seg[:, None, :]]
+
+
 def _seg_specs(heads: int, block_q: int, block_k: int):
-    """BlockSpecs for the [B, T] segment-id operands on the fwd/dq
-    grids, which run over flat q rows (b = batch*H + h).  The dkv grid
-    (flat kv rows, q block riding program_id(2)) builds its specs
-    inline — it needs the kv_heads/nq closure."""
+    """BlockSpecs for :func:`_seg_operands` on the fwd/dq grids, which
+    run over flat q rows (b = batch*H + h).  The dkv grid (flat kv
+    rows, q block riding program_id(2)) builds its specs inline — it
+    needs the kv_heads/nq closure."""
     return [
-        pl.BlockSpec((1, block_q),
-                     lambda b, i, j, H=heads: (b // H, i)),
-        pl.BlockSpec((1, block_k),
-                     lambda b, i, j, H=heads: (b // H, j)),
+        pl.BlockSpec((1, block_q, 1),
+                     lambda b, i, j, H=heads: (b // H, i, 0)),
+        pl.BlockSpec((1, 1, block_k),
+                     lambda b, i, j, H=heads: (b // H, 0, j)),
     ]
 
 
@@ -254,7 +261,7 @@ def _flash_fwd_impl(q, k, v, seg, *, causal: bool, block_q: int,
     operands = [q, k, v]
     if seg is not None:
         in_specs += _seg_specs(heads, block_q, block_k)
-        operands += [seg, seg]
+        operands += _seg_operands(seg)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -298,7 +305,7 @@ def _flash_bwd_impl(q, k, v, seg, out, lse, do, *, causal, block_q,
     dq_operands = [q, k, v]
     if seg is not None:
         dq_in_specs += _seg_specs(heads, block_q, block_k)
-        dq_operands += [seg, seg]
+        dq_operands += _seg_operands(seg)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, num_k_blocks=nk,
@@ -331,12 +338,12 @@ def _flash_bwd_impl(q, k, v, seg, out, lse, do, *, causal, block_q,
     if seg is not None:
         # batch = flat kv row // KV; q block index rides program_id(2)
         dkv_in_specs += [
-            pl.BlockSpec((1, block_q),
-                         lambda b, j, i: (b // kv_heads, i % nq)),
-            pl.BlockSpec((1, block_k),
-                         lambda b, j, i: (b // kv_heads, j)),
+            pl.BlockSpec((1, block_q, 1),
+                         lambda b, j, i: (b // kv_heads, i % nq, 0)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda b, j, i: (b // kv_heads, 0, j)),
         ]
-        dkv_operands += [seg, seg]
+        dkv_operands += _seg_operands(seg)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, num_q_blocks=nq,

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from deepspeed_tpu.mesh import axis_size
 
 INT_BOUNDS = {8: 127.0, 4: 7.0, 2: 1.0, 1: 1.0}
 
@@ -257,7 +256,7 @@ def quantized_reduce_scatter(x: jnp.ndarray, axis_name: str, bits: int = 8,
     exchange stays inside each group and ``group_size`` (the uniform
     group length) replaces the full axis size.
     """
-    world = group_size if group_size is not None else axis_size(axis_name)
+    world = group_size if group_size is not None else jax.lax.axis_size(axis_name)
     shard = x.shape[0] // world
     parts = x.reshape((world, shard) + x.shape[1:])
     flat = parts.reshape(world, -1)

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.mesh import shard_map
 from deepspeed_tpu.topology import MeshSpec
 
 PIPE_AXIS = "pipe"
@@ -144,7 +143,7 @@ def pipelined_scan(block_fn: Callable, stacked_params: Any, x: jnp.ndarray,
         out = jax.lax.psum(real, PIPE_AXIS)
         return out.astype(xs.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         run, mesh=mesh.mesh,
         in_specs=(jax.tree.map(lambda _: P(PIPE_AXIS), stacked_params), P()),
         out_specs=P(), axis_names={PIPE_AXIS}, check_vma=False)

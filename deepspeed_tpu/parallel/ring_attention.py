@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.mesh import axis_size, shard_map
 from deepspeed_tpu.topology import MeshSpec
 
 SEQ_AXIS = "seq"
@@ -53,7 +52,7 @@ def ring_attention(q, k, v, axis_name: str = SEQ_AXIS, causal: bool = True,
     cross-segment pairs mask out ring-wide.  Returns [B, Tq, H, Dh] in
     q.dtype.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, Tq, H, Dh = q.shape
     k, v = _repeat_kv(k, v, H)
@@ -128,7 +127,7 @@ def ring_attention_sharded(q, k, v, mesh: MeshSpec, causal: bool = True,
         return ring_attention(q, k, v, axis_name=axis_name, causal=causal,
                               segment_ids=seg)
 
-    fn = shard_map(wrapped, mesh=mesh.mesh, in_specs=in_specs,
-                   out_specs=spec, axis_names={axis_name},
-                   check_vma=False)
+    fn = jax.shard_map(wrapped, mesh=mesh.mesh, in_specs=in_specs,
+                       out_specs=spec, axis_names={axis_name},
+                       check_vma=False)
     return fn(*args)

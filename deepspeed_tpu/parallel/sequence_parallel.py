@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.mesh import axis_size, shard_map
 from deepspeed_tpu.topology import MeshSpec
 
 SEQ_AXIS = "seq"
@@ -50,7 +49,7 @@ def ulysses_attention(q, k, v, axis_name: str = SEQ_AXIS,
     slice, so the ids are all-gathered (tiny int32) and masking is local.
     """
     attn_fn = attn_fn or _default_attn
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     H, KV = q.shape[2], k.shape[2]
     if H % sp != 0:
         raise ValueError(f"n_heads {H} not divisible by seq parallelism {sp}")
@@ -98,7 +97,7 @@ def ulysses_attention_sharded(q, k, v, mesh: MeshSpec, causal: bool = True,
                                  causal=causal, attn_fn=attn_fn,
                                  segment_ids=seg)
 
-    fn = shard_map(wrapped, mesh=mesh.mesh, in_specs=in_specs,
-                   out_specs=spec, axis_names={axis_name},
-                   check_vma=False)
+    fn = jax.shard_map(wrapped, mesh=mesh.mesh, in_specs=in_specs,
+                       out_specs=spec, axis_names={axis_name},
+                       check_vma=False)
     return fn(*args)

@@ -15,49 +15,40 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
-# Peak bf16 FLOP/s per chip by TPU generation (public spec sheets).
-PEAK_FLOPS = {
-    "v4": 275e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v6e": 918e12,
-    "cpu": 1e12,  # nominal, so MFU math never divides by zero off-TPU
+# Peaks per chip, keyed by the ``device_kind`` string JAX reports:
+# (bf16 FLOP/s, HBM bytes/s).  A device that is not here is an error,
+# not a default — an MFU or MBU against an assumed peak is not a
+# measurement.  Add a row with its source when a new chip is used.
+DEVICE_PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    # HBM.  "TPU v5 lite" is what a v5e reports (chip_smoke.py, PR 21).
+    "TPU v5 lite": (197e12, 819e9),
+    # nominal row so the CPU tests' MFU/MBU math has a denominator;
+    # nothing computed against it is a device metric
+    "cpu": (1e12, 100e9),
 }
+
+
+def _device_peaks():
+    kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak FLOP/s / bandwidth row for device_kind {kind!r}; "
+            f"known: {sorted(DEVICE_PEAKS)} (add it to "
+            "deepspeed_tpu/timers.py DEVICE_PEAKS with its source)"
+        ) from None
 
 
 def device_peak_flops() -> float:
-    """Best-effort peak bf16 FLOP/s of the attached chip."""
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return PEAK_FLOPS["cpu"]
-    for key, val in PEAK_FLOPS.items():
-        if key in kind:
-            return val
-    return PEAK_FLOPS["v5e"] if "tpu" in kind else PEAK_FLOPS["cpu"]
-
-
-# Peak HBM bandwidth per chip by TPU generation, bytes/s (public spec
-# sheets) — the MBU denominator, parallel to PEAK_FLOPS for MFU.
-PEAK_HBM_BW = {
-    "v4": 1.2e12,
-    "v5e": 0.82e12,
-    "v5p": 2.77e12,
-    "v6e": 1.64e12,
-    "cpu": 0.1e12,  # nominal, so MBU math never divides by zero off-TPU
-}
+    """Peak bf16 FLOP/s of the attached chip (raises for an unknown one)."""
+    return _device_peaks()[0]
 
 
 def device_peak_bandwidth() -> float:
-    """Best-effort peak HBM bandwidth (bytes/s) of the attached chip."""
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return PEAK_HBM_BW["cpu"]
-    for key, val in PEAK_HBM_BW.items():
-        if key in kind:
-            return val
-    return PEAK_HBM_BW["v5e"] if "tpu" in kind else PEAK_HBM_BW["cpu"]
+    """Peak HBM bytes/s of the attached chip (raises for an unknown one)."""
+    return _device_peaks()[1]
 
 
 def _sync() -> None:

@@ -2,16 +2,21 @@
 (io/aio.py, io/native.py, ops/cpu_adam.py — one loader, not three
 drifting copies).
 
-Contract: build the shared library from source when it is missing or
-stale, then dlopen it.  Two hardenings every caller needs identically:
+The shared libraries are build outputs, not tracked files: each is
+compiled from its ``csrc/*.cpp`` on first use and rebuilt when the
+source changes.  "Changed" is decided by a hash of the source kept
+beside the library (``<lib>.srchash``), never by file times — a copy or
+a checkout resets those.
 
 - temp path + atomic rename: concurrent builders racing the same ``-o``
-  target can CDLL a half-written .so and latch their slow fallback for
-  the whole process lifetime;
-- rebuild-once on dlopen failure: a committed .so built by another
+  target can CDLL a half-written .so;
+- rebuild-once on dlopen failure: a library carried over from another
   toolchain (e.g. a GLIBCXX version mismatch) raises OSError from CDLL
-  but rebuilds from source in seconds — retry once before demoting the
-  caller to its pure-Python fallback.
+  but rebuilds from source in seconds.
+
+Only a missing compiler returns None (callers then take their
+pure-Python path), and it says so in the log.  A source that does not
+compile, or a fresh build that does not load, raises.
 
 Callers keep their own locks/caches and symbol setup; this is just the
 build + load core.
@@ -20,37 +25,55 @@ build + load core.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
 from typing import Optional, Sequence
+
+from deepspeed_tpu.utils.logging import logger
 
 
 def load_or_build(lib_path: str, src_path: str,
                   extra_flags: Sequence[str] = ()
                   ) -> Optional[ctypes.CDLL]:
     """Return the dlopened library, building/rebuilding as needed;
-    None when no toolchain (or no loadable artifact) is available."""
+    None (logged) when there is no ``g++`` to build it with."""
+    hash_path = lib_path + ".srchash"
+    with open(src_path, "rb") as f:
+        want = hashlib.sha256(f.read() + " ".join(extra_flags).encode()
+                              ).hexdigest()
+
     def build():
         tmp = f"{lib_path}.{os.getpid()}.tmp"
-        subprocess.run(
-            ["g++", "-O3", *extra_flags, "-shared", "-fPIC", "-o", tmp,
-             src_path, "-lpthread"],
-            check=True, capture_output=True)
-        os.replace(tmp, lib_path)
-
-    if not os.path.exists(lib_path) or (
-            os.path.exists(src_path)
-            and os.path.getmtime(src_path) > os.path.getmtime(lib_path)):
         try:
-            build()
-        except (subprocess.CalledProcessError, FileNotFoundError):
+            subprocess.run(
+                ["g++", "-O3", *extra_flags, "-shared", "-fPIC", "-o", tmp,
+                 src_path, "-lpthread"],
+                check=True, capture_output=True, text=True)
+        except subprocess.CalledProcessError as e:
+            raise RuntimeError(
+                f"{src_path} does not compile:\n{e.stderr}") from e
+        os.replace(tmp, lib_path)
+        with open(hash_path, "w") as f:
+            f.write(want)
+
+    def built_from_this_source() -> bool:
+        try:
+            with open(hash_path) as f:
+                return os.path.exists(lib_path) and f.read() == want
+        except FileNotFoundError:
+            return False
+
+    if not built_from_this_source():
+        if shutil.which("g++") is None:
+            logger.warning(
+                "no g++ on PATH: %s not built, its caller takes the "
+                "pure-Python path", os.path.basename(lib_path))
             return None
+        build()
     try:
         return ctypes.CDLL(lib_path)
     except OSError:
-        try:
-            build()
-            return ctypes.CDLL(lib_path)
-        except (subprocess.CalledProcessError, FileNotFoundError,
-                OSError):
-            return None
+        build()
+        return ctypes.CDLL(lib_path)

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.timers import (SynchronizedWallClockTimer, ThroughputTimer,
-                                  device_peak_flops)
+                                  device_peak_bandwidth, device_peak_flops)
 from deepspeed_tpu.monitor import CsvMonitor, MonitorMaster
 from deepspeed_tpu.profiler import (FlopsProfiler, get_model_profile,
                                     params_count, transformer_train_flops,
@@ -43,6 +43,27 @@ def test_throughput_timer_mfu():
     assert s["tokens_per_sec"] == pytest.approx(s["samples_per_sec"] * 128)
     assert s["tflops"] > 0 and s["mfu"] > 0
     assert device_peak_flops() > 0
+
+
+@pytest.mark.parametrize("kind,want", [
+    ("TPU v5 lite", (197, 819)),           # what a v5e reports
+    ("cpu", (1, 100)),
+    ("TPU v9 imaginary", None),            # unknown: an error, no default
+])
+def test_device_peaks_keyed_by_device_kind(monkeypatch, kind, want):
+    """(TFLOP/s bf16, GB/s HBM) per ``device_kind``."""
+    class Dev:
+        device_kind = kind
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev()])
+    if want is None:
+        with pytest.raises(KeyError, match="no peak"):
+            device_peak_flops()
+        with pytest.raises(KeyError, match="no peak"):
+            device_peak_bandwidth()
+    else:
+        assert (device_peak_flops() / 1e12,
+                device_peak_bandwidth() / 1e9) == want
 
 
 def test_csv_monitor(tmp_path):

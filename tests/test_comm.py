@@ -3,7 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu import comm
@@ -191,6 +191,20 @@ class TestCommsDigest:
         assert d["per_kind"]["reduce-scatter"] == {"count": 1, "bytes": 4 * 32}
         assert d["total_bytes"] == (4 * 128 * 256 + 2 * 8 * 64
                                     + 2 * 8 * 512 + 4 * 32)
+
+    def test_tpu_fused_reduce_scatter_is_counted(self):
+        # a line of the ZeRO-3 step compiled for a v5e: the TPU compiler
+        # leaves no reduce-scatter opcode, only this custom fusion
+        from deepspeed_tpu.comm.digest import analyze_collectives
+
+        txt = '''
+%all-reduce-scatter.clone.clone (input.10: bf16[4,1024,2048]) -> bf16[2060,8,128] {
+  %fusion.386 = bf16[2060,8,128]{2,1,0:T(8,128)(2,1)S(1)} fusion(%fusion.385), kind=kCustom, calls=%all-reduce-scatter.clone.clone, metadata={op_name="jit(_train_step)/dot_general"}
+  %fusion.9 = f32[8]{0} fusion(%p), kind=kLoop, calls=%fused_computation.9
+'''
+        d = analyze_collectives(txt)
+        assert d["per_kind"] == {"reduce-scatter": {
+            "count": 1, "bytes": 2 * 2060 * 8 * 128}}
 
     def test_async_start_done_counts_once(self):
         from deepspeed_tpu.comm.digest import analyze_collectives

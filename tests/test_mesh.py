@@ -1,13 +1,6 @@
-"""The version-portable shard-map/mesh layer (deepspeed_tpu/mesh.py).
-
-The package is written against the modern mesh idiom (top-level
-``jax.shard_map``, ``axis_names=``/``check_vma=`` keywords); the pinned
-JAX exposes the legacy spelling.  These tests pin the shim's contract:
-both keyword dialects accepted, results identical to hand-rolled
-collectives, the ``jax.shard_map`` attribute installed for
-modern-idiom callers (the 31 seed comm/parallel/pipeline tests run
-through it unmodified), and the helpers building the Mesh /
-NamedSharding objects GSPMD consumes.
+"""The mesh helpers (deepspeed_tpu/mesh.py): building the Mesh /
+NamedSharding objects GSPMD consumes, and the collective entry points
+that run through ``jax.shard_map``.
 """
 
 import numpy as np
@@ -17,117 +10,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-import deepspeed_tpu  # noqa: F401  (mesh.install() runs at import)
 from deepspeed_tpu import mesh as mesh_mod
 from deepspeed_tpu.topology import MeshSpec
-
-
-class TestResolution:
-    def test_resolve_returns_native_callable(self):
-        fn, style = mesh_mod.resolve_shard_map()
-        assert callable(fn)
-        assert style in ("modern", "legacy")
-        # the resolved native is never our own wrapper
-        assert not getattr(fn, "_dstpu_shim", False)
-
-    def test_jax_shard_map_attribute_exists(self):
-        # the seed tests call jax.shard_map directly; after import of
-        # deepspeed_tpu the attribute exists on every JAX version —
-        # native, or the installed portable wrapper
-        assert hasattr(jax, "shard_map")
-
-    def test_install_idempotent(self):
-        before = jax.shard_map
-        mesh_mod.install()
-        assert jax.shard_map is before
-
-
-class TestShardMap:
-    def test_full_manual_psum_matches_mean(self, devices):
-        ms = MeshSpec.build({"data": 8})
-        x = jnp.asarray(
-            np.random.default_rng(0).normal(size=(8, 5)), jnp.float32)
-        got = mesh_mod.shard_map(
-            lambda v: jax.lax.pmean(v, "data"), mesh=ms.mesh,
-            in_specs=P("data"), out_specs=P("data"))(x)
-        want = jnp.mean(x, axis=0)
-        for d in range(8):
-            np.testing.assert_allclose(got[d], want, rtol=1e-6)
-
-    def test_both_dialect_keywords_accepted(self, devices):
-        # axis_names={manual axes} is the modern partial-manual
-        # spelling; auto={the rest} the legacy one.  On legacy JAX a
-        # partial-manual request degrades to full manualization (same
-        # global-array semantics); either spelling must produce the
-        # ppermute ring's rotated result.
-        ms = MeshSpec.build({"pipe": 2, "data": 2, "model": 2})
-        x = jnp.arange(2.0)
-        ring = lambda v: jax.lax.ppermute(v, "pipe", [(0, 1), (1, 0)])
-        modern = mesh_mod.shard_map(
-            ring, mesh=ms.mesh, in_specs=P("pipe"), out_specs=P("pipe"),
-            axis_names={"pipe"}, check_vma=False)(x)
-        legacy = mesh_mod.shard_map(
-            ring, mesh=ms.mesh, in_specs=P("pipe"), out_specs=P("pipe"),
-            auto=frozenset({"data", "model"}), check_rep=False)(x)
-        np.testing.assert_array_equal(np.asarray(modern), [1.0, 0.0])
-        np.testing.assert_array_equal(np.asarray(legacy), [1.0, 0.0])
-
-    def test_both_dialect_keywords_rejected(self, devices):
-        ms = MeshSpec.build({"data": 8})
-        with pytest.raises(TypeError, match="not both"):
-            mesh_mod.shard_map(lambda v: v, mesh=ms.mesh,
-                               in_specs=P("data"), out_specs=P("data"),
-                               axis_names={"data"},
-                               auto=frozenset())
-
-    def test_mesh_required(self):
-        with pytest.raises(TypeError, match="mesh"):
-            mesh_mod.shard_map(lambda v: v)
-
-    def test_under_jit_and_grad(self, devices):
-        # the engine's compressed steps jit + differentiate through the
-        # wrapper; ppermute's transpose rule must survive it
-        ms = MeshSpec.build({"data": 8})
-        x = jnp.asarray(
-            np.random.default_rng(1).normal(size=(8, 4)), jnp.float32)
-
-        def loss(v):
-            y = mesh_mod.shard_map(
-                lambda s: jax.lax.pmean(jnp.sum(s ** 2), "data"),
-                mesh=ms.mesh, in_specs=P("data"), out_specs=P(),
-                check_vma=False)(v)
-            return y
-
-        g = jax.jit(jax.grad(loss))(x)
-        np.testing.assert_allclose(np.asarray(g), np.asarray(2 * x / 8),
-                                   rtol=1e-6)
-
-    def test_installed_attribute_runs_modern_callsite(self, devices):
-        # the exact seed-test shape: jax.shard_map(..., check_vma=False)
-        ms = MeshSpec.build({"data": 8})
-        x = jnp.asarray(
-            np.random.default_rng(2).normal(size=(8, 3)), jnp.float32)
-        got = jax.shard_map(
-            lambda v: jax.lax.pmean(v, "data"), mesh=ms.mesh,
-            in_specs=(P("data"),), out_specs=P("data"),
-            check_vma=False)(x)
-        np.testing.assert_allclose(got[0], jnp.mean(x, 0), rtol=1e-6)
-
-
-class TestAxisSize:
-    def test_static_inside_shard_map(self, devices):
-        # axis_size folds to a static int at trace time — usable in
-        # shape positions (jnp.arange), which the ring scan relies on
-        ms = MeshSpec.build({"data": 8})
-
-        def f(v):
-            n = mesh_mod.axis_size("data")
-            return v + jnp.arange(n, dtype=v.dtype)[0] + n
-
-        got = mesh_mod.shard_map(
-            f, mesh=ms.mesh, in_specs=P("data"), out_specs=P("data"),
-            check_vma=False)(jnp.zeros((8,)))
-        np.testing.assert_array_equal(np.asarray(got), [8.0] * 8)
 
 
 class TestHelpers:
@@ -162,11 +46,10 @@ class TestHelpers:
         assert mesh_mod.mesh_axis_sizes(ms.mesh)["data"] == 4
 
 
-class TestMigratedCallers:
-    """The 31 seed failures were AttributeErrors on jax.shard_map /
-    jax.lax.axis_size reached through these modules; pin that every
-    previously-dead entrypoint now resolves its collective machinery
-    (cheap smoke — the full numerics live in the seed suites)."""
+class TestShardMapCallers:
+    """Entry points that schedule their own collectives under
+    ``jax.shard_map`` (cheap smoke — the full numerics live in the
+    comm/parallel suites)."""
 
     def test_comm_compress_local_grad_harness(self, devices):
         from deepspeed_tpu import comm_compress

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from deepspeed_tpu.ops.quant import (dequantize, from_fp8, quantize,
                                      quantize_pallas, quantized_all_gather,
@@ -67,7 +67,7 @@ def test_quantized_all_gather():
 
     f = shard_map(lambda v: quantized_all_gather(v[0], "data", num_groups=2),
                   mesh=mesh, in_specs=P("data"), out_specs=P(),
-                  check_rep=False)
+                  check_vma=False)
     out = f(x)
     assert out.shape == (8, 16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x), atol=0.05)
@@ -176,7 +176,7 @@ def test_onebit_allreduce_error_feedback():
     f = shard_map(
         lambda v, e: onebit_allreduce(v[0], e[0], "data", num_groups=4),
         mesh=mesh, in_specs=(P("data"), P(None)),
-        out_specs=(P(None), P("data")), check_rep=False)
+        out_specs=(P(None), P("data")), check_vma=False)
     avg, err = f(x, jnp.broadcast_to(err0, (1, 4, 16)))
     # compressed average has the right sign structure & bounded error
     exact = jnp.mean(x, axis=0)
@@ -204,7 +204,7 @@ def test_onebit_adam_converges_spmd():
     step = jax.jit(shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), P(), P("data"), P("data")), out_specs=(P(), P(), P()),
-        check_rep=False))
+        check_vma=False))
     xs = jnp.asarray(x)
     ys = jnp.asarray(y)
     losses = []

@@ -212,7 +212,7 @@ class TestSinks:
     def test_comm_backend_records_collectives(self, devices):
         """The default comm path now records: tracing a collective logs
         (op, per-shard bytes) into the backend's CommsLogger."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from deepspeed_tpu import comm

@@ -8,9 +8,7 @@
 #
 #   bash tools/run_slow_lane.sh
 #
-# Invoked by tools/onchip_watcher.py while the chip is down (idle time
-# costs nothing) on a DSTPU_SLOW_LANE_CADENCE_S cadence; also fine to
-# run by hand.  SLOW_LANE_DEADLINE_S caps the run (default 2700 s).
+# Run by hand.  SLOW_LANE_DEADLINE_S caps the run (default 2700 s).
 set -u
 REPO="$(cd "$(dirname "$0")/.." && pwd)"
 cd "$REPO"
