@@ -54,41 +54,13 @@ from typing import Any, Dict, List, Optional
 import jax
 
 from deepspeed_tpu.config import DevprofConfig
+from deepspeed_tpu.telemetry import mark as telemetry_mark
 from deepspeed_tpu.timers import device_peak_bandwidth, device_peak_flops
 
 # ------------------------------------------------------ phase vocabulary
-# The canonical phase names every surface agrees on: the sampled
-# device-time counters, the TraceAnnotation labels telemetry.span()
-# emits (so on-demand jax.profiler captures show the same words), and
+# The phase names of the sampled device-time counters and of
 # trace_report's device-time column.
 PHASES = ("prefill", "decode", "spec_verify", "promote", "sample")
-
-# span/metric-name aliases → canonical phase (telemetry.span() maps its
-# TraceAnnotation label through this, so a capture's annotations and
-# the sampled attribution agree; unknown names pass through unchanged)
-PHASE_ALIASES = {
-    "serving_step": "decode",
-    "serving_decode": "decode",
-    "decode_chunk": "decode",
-    "serving_prefill": "prefill",
-    "chunk_prefill": "prefill",
-    "prefill_chunk": "prefill",
-    "spec_verify_sweep": "spec_verify",
-    "verify": "spec_verify",
-    "kv_promote": "promote",
-    "tier_promote": "promote",
-    "boundary_sample": "sample",
-    "sample_rows": "sample",
-}
-
-
-def canonical_phase(name: str) -> str:
-    """Map a span/site name onto the devprof phase vocabulary (identity
-    for already-canonical or unknown names)."""
-    if name in PHASES:
-        return name
-    return PHASE_ALIASES.get(name, name)
-
 
 # default phase each sentinel site's dispatches attribute to
 SITE_PHASES = {
@@ -381,6 +353,10 @@ class DevProf:
         entry = self.ledger.record(site, self.steady, n, dur)
         if self.steady:
             self._c_comp_steady.inc(n)
+            # on the profiler's clock too: a capture shows WHICH step
+            # recompiled, and at which site
+            telemetry_mark(f"{self.registry.namespace}/xla_compile",
+                           site=site, n=n)
         else:
             self._c_comp_warm.inc(n)
         if self.tracer is not None and self.tracer.enabled:

@@ -277,8 +277,13 @@ class TrainingEngine:
         # batch placement happens in _align_batch (device_put per leaf, so
         # scalar batch fields ride along replicated); in_shardings=None
         # respects those committed placements without re-transfer
+        # a stable program name: a capture's "XLA Modules" line shows
+        # jit_dstpu_train_step
+        def dstpu_train_step(state, batch):
+            return self._train_step(state, batch)
+
         self._step_fn = jax.jit(
-            self._train_step,
+            dstpu_train_step,
             in_shardings=(self.state_shardings, None),
             out_shardings=(self.state_shardings, None),
             donate_argnums=(0,))
@@ -343,13 +348,18 @@ class TrainingEngine:
         self.registry = MetricsRegistry(enabled=tel.enabled)
         self._c_train_steps = self.registry.counter(
             "train_steps", "optimizer steps taken")
-        self._h_step = self.registry.histogram(
-            "train_step_seconds",
+        # spans built once (telemetry.Span: histogram + TraceAnnotation;
+        # dstpu/train_step and dstpu/train_align_batch in a capture)
+        self._sp_step = self.registry.span(
+            "train_step",
             "per-step wall time (host dispatch wall unless "
             "telemetry.step_sync — then device-synced via the "
             "ThroughputTimer)",
             buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                      0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0))
+        self._sp_align = self.registry.span(
+            "train_align_batch",
+            "placing the host batch on the mesh before the step")
         self._g_loss = self.registry.gauge("train_loss")
         self._g_lr = self.registry.gauge("train_lr")
         self._g_grad_norm = self.registry.gauge("train_grad_norm")
@@ -607,8 +617,13 @@ class TrainingEngine:
 
     # ------------------------------------------------------------------ step
     def _loss_for(self, params, batch):
-        cparams = precision.cast_for_compute(params, self.config.precision)
-        out = self.loss_fn(cparams, batch)
+        # the model's blocks name themselves inside (embed, attn_qkv,
+        # flash, mlp, ...): what stays under "loss" alone is the cast
+        # of the parameters and the loss proper
+        with jax.named_scope("loss"):
+            cparams = precision.cast_for_compute(params,
+                                                 self.config.precision)
+            out = self.loss_fn(cparams, batch)
         if self.has_aux:
             loss, aux = out
         else:
@@ -690,25 +705,30 @@ class TrainingEngine:
     def _finish_step(self, state: TrainState, grads, loss, _aux):
         """Shared step tail: unscale/overflow-check, clip, update, commit."""
         cfg = self.config
-        grads, ok, new_scaler = precision.unscale_and_check(
-            grads, state.scaler, cfg.precision)
+        with jax.named_scope("grad_clip"):
+            grads, ok, new_scaler = precision.unscale_and_check(
+                grads, state.scaler, cfg.precision)
 
-        if cfg.gradient_clipping > 0:
-            grads, gnorm = clip_by_global_norm(grads, cfg.gradient_clipping)
-        else:
-            gnorm = global_norm(grads)
+            if cfg.gradient_clipping > 0:
+                grads, gnorm = clip_by_global_norm(
+                    grads, cfg.gradient_clipping)
+            else:
+                gnorm = global_norm(grads)
 
-        updates, new_opt = self.optimizer.update(grads, state.opt_state, state.params)
-        new_params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
-                                  state.params, updates)
-        # overflow → skip the update, keep old state (ref: fused_optimizer.step)
-        keep = lambda new, old: jax.tree.map(
-            lambda n, o: jnp.where(ok, n, o), new, old)
-        new_state = TrainState(
-            step=state.step + jnp.where(ok, 1, 0).astype(jnp.int32),
-            params=keep(new_params, state.params),
-            opt_state=keep(new_opt, state.opt_state),
-            scaler=new_scaler)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = self.optimizer.update(
+                grads, state.opt_state, state.params)
+            new_params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
+                                      state.params, updates)
+            # overflow → skip the update, keep old state (ref:
+            # fused_optimizer.step)
+            keep = lambda new, old: jax.tree.map(
+                lambda n, o: jnp.where(ok, n, o), new, old)
+            new_state = TrainState(
+                step=state.step + jnp.where(ok, 1, 0).astype(jnp.int32),
+                params=keep(new_params, state.params),
+                opt_state=keep(new_opt, state.opt_state),
+                scaler=new_scaler)
         metrics = {"loss": loss, "grad_norm": gnorm,
                    "overflow": (~ok).astype(jnp.int32),
                    "lr": self.lr_schedule(state.step + 1),
@@ -959,16 +979,16 @@ class TrainingEngine:
         """
         batch = self._apply_curriculum(batch)
         timed = self.monitor.enabled or self._tel_sync
+        with self._sp_align:
+            batch = self._align_batch(batch)
         if timed:
             self.tput_timer.start()
-        t0 = time.perf_counter()
-        self.state, metrics = self._step_fn(self.state, self._align_batch(batch))
-        if timed:
-            self.tput_timer.stop()
-            self._h_step.observe(time.perf_counter() - t0)
-        elif self.registry.enabled:
-            # host dispatch wall only — no forced sync on the hot path
-            self._h_step.observe(time.perf_counter() - t0)
+        # train_step_seconds: the dispatch's host wall, or, when timed,
+        # through the ThroughputTimer's sync; no forced sync otherwise
+        with self._sp_step:
+            self.state, metrics = self._step_fn(self.state, batch)
+            if timed:
+                self.tput_timer.stop()
         self._post_step(metrics)
         return metrics["loss"]
 

@@ -786,6 +786,7 @@ def paged_chunk_attention_v2(q, k_pages, v_pages, table, start,
         ),
         out_shape=jax.ShapeDtypeStruct((B * KV, cg8, Dh), q.dtype),
         interpret=interpret,
+        name="dstpu_paged_chunk_v2",
     )(table, start, qg, k_pages, v_pages)
     out = out[:, :CG].reshape(B, KV, C, G, Dh).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, C, H, Dh)
@@ -935,6 +936,7 @@ def paged_chunk_attention_v2_quant(q, kq_pages, ks_pages, vq_pages,
         ),
         out_shape=jax.ShapeDtypeStruct((B * KV, cg8, Dh), q.dtype),
         interpret=interpret,
+        name="dstpu_paged_chunk_v2_q8",
     )(table, start, qg, kq_pages, ks_pages, vq_pages, vs_pages)
     out = out[:, :CG].reshape(B, KV, C, G, Dh).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, C, H, Dh)
@@ -1057,6 +1059,7 @@ def paged_chunk_attention(q, k_pages, v_pages, table, start,
         ),
         out_shape=jax.ShapeDtypeStruct((B * KV, CG + pad, Dh), q.dtype),
         interpret=interpret,
+        name="dstpu_paged_chunk_v1",
     )(table, start, qg, k_pages.reshape(KV * P, ps, Dh),
       v_pages.reshape(KV * P, ps, Dh))
     out = out[:, :CG].reshape(B, KV, C, G, Dh).transpose(0, 2, 1, 3, 4)
@@ -1283,66 +1286,80 @@ def paged_attention_step(q, k, v, kp, vp, table, start, page_size: int, *,
     if quant and paged_kernel == "pallas_v1":
         raise ValueError("int8-resident pages have no pallas_v1 kernel "
                          "(use xla or pallas_v2)")
+    # the one place for the three attention scopes (kv_write, kv_attend,
+    # flash): every family's forward_paged passes through here
+    write, attend = jax.named_scope("kv_write"), jax.named_scope("kv_attend")
     if continuation and q.shape[1] > 1:
         if quant:
-            kp, kps, vp, vps = write_chunk_pages_quant(
-                kp, kps, vp, vps, k, v, table, start, page_size)
-            if paged_kernel == "pallas_v2":
-                attn = paged_chunk_attention_v2_quant(
-                    q, kp, kps, vp, vps, table, start,
-                    interpret=interpret)
-            else:
-                attn = paged_chunk_attention_reference(
-                    q, dequantize_pages(kp, kps, q.dtype),
-                    dequantize_pages(vp, vps, q.dtype), table, start)
+            with write:
+                kp, kps, vp, vps = write_chunk_pages_quant(
+                    kp, kps, vp, vps, k, v, table, start, page_size)
+            with attend:
+                if paged_kernel == "pallas_v2":
+                    attn = paged_chunk_attention_v2_quant(
+                        q, kp, kps, vp, vps, table, start,
+                        interpret=interpret)
+                else:
+                    attn = paged_chunk_attention_reference(
+                        q, dequantize_pages(kp, kps, q.dtype),
+                        dequantize_pages(vp, vps, q.dtype), table, start)
         else:
-            kp, vp = write_chunk_pages(kp, vp, k, v, table, start,
-                                       page_size)
-            if paged_kernel == "pallas_v1":
-                attn = paged_chunk_attention(q, kp, vp, table, start,
-                                             interpret=interpret)
-            elif paged_kernel == "pallas_v2":
-                attn = paged_chunk_attention_v2(q, kp, vp, table, start,
-                                                interpret=interpret)
-            else:
-                attn = paged_chunk_attention_reference(q, kp, vp, table,
-                                                       start)
+            with write:
+                kp, vp = write_chunk_pages(kp, vp, k, v, table, start,
+                                           page_size)
+            with attend:
+                if paged_kernel == "pallas_v1":
+                    attn = paged_chunk_attention(q, kp, vp, table, start,
+                                                 interpret=interpret)
+                elif paged_kernel == "pallas_v2":
+                    attn = paged_chunk_attention_v2(
+                        q, kp, vp, table, start, interpret=interpret)
+                else:
+                    attn = paged_chunk_attention_reference(
+                        q, kp, vp, table, start)
     elif prefill:
-        attn = flash_attention(q, k, v, causal=True,
-                               force_reference=flash_force_reference)
-        if quant:
-            kp, kps, vp, vps = write_prompt_pages_quant(
-                kp, kps, vp, vps, k, v, table, page_size)
-        else:
-            kp, vp = write_prompt_pages(kp, vp, k, v, table, page_size)
+        with jax.named_scope("flash"):
+            attn = flash_attention(q, k, v, causal=True,
+                                   force_reference=flash_force_reference)
+        with write:
+            if quant:
+                kp, kps, vp, vps = write_prompt_pages_quant(
+                    kp, kps, vp, vps, k, v, table, page_size)
+            else:
+                kp, vp = write_prompt_pages(kp, vp, k, v, table,
+                                            page_size)
     else:
         if quant:
-            kp, kps, vp, vps = write_token_pages_quant(
-                kp, kps, vp, vps, k[:, 0], v[:, 0], table, start,
-                page_size)
-            if paged_kernel == "pallas_v2":
-                attn = paged_decode_attention_v2_quant(
-                    q[:, 0], kp, kps, vp, vps, table, start + 1,
-                    interpret=interpret)[:, None]
-            else:
-                attn = paged_attention_reference(
-                    q[:, 0], dequantize_pages(kp, kps, q.dtype),
-                    dequantize_pages(vp, vps, q.dtype), table,
-                    start + 1)[:, None]
+            with write:
+                kp, kps, vp, vps = write_token_pages_quant(
+                    kp, kps, vp, vps, k[:, 0], v[:, 0], table, start,
+                    page_size)
+            with attend:
+                if paged_kernel == "pallas_v2":
+                    attn = paged_decode_attention_v2_quant(
+                        q[:, 0], kp, kps, vp, vps, table, start + 1,
+                        interpret=interpret)[:, None]
+                else:
+                    attn = paged_attention_reference(
+                        q[:, 0], dequantize_pages(kp, kps, q.dtype),
+                        dequantize_pages(vp, vps, q.dtype), table,
+                        start + 1)[:, None]
         else:
-            kp, vp = write_token_pages(kp, vp, k[:, 0], v[:, 0], table,
-                                       start, page_size)
-            if paged_kernel == "pallas_v1":
-                attn = paged_decode_attention(
-                    q[:, 0], kp, vp, table, start + 1,
-                    interpret=interpret)[:, None]
-            elif paged_kernel == "pallas_v2":
-                attn = paged_decode_attention_v2(
-                    q[:, 0], kp, vp, table, start + 1,
-                    interpret=interpret)[:, None]
-            else:
-                attn = paged_attention_reference(
-                    q[:, 0], kp, vp, table, start + 1)[:, None]
+            with write:
+                kp, vp = write_token_pages(kp, vp, k[:, 0], v[:, 0],
+                                           table, start, page_size)
+            with attend:
+                if paged_kernel == "pallas_v1":
+                    attn = paged_decode_attention(
+                        q[:, 0], kp, vp, table, start + 1,
+                        interpret=interpret)[:, None]
+                elif paged_kernel == "pallas_v2":
+                    attn = paged_decode_attention_v2(
+                        q[:, 0], kp, vp, table, start + 1,
+                        interpret=interpret)[:, None]
+                else:
+                    attn = paged_attention_reference(
+                        q[:, 0], kp, vp, table, start + 1)[:, None]
     return attn, kp, vp, kps, vps
 
 

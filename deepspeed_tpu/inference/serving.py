@@ -99,7 +99,8 @@ from deepspeed_tpu.request_trace import (BoundTracer, RequestTracer,
                                           event_to_dict)
 from deepspeed_tpu.slo import NULL_SLO_TRACKER, SLOTracker
 from deepspeed_tpu.telemetry import (LATENCY_BUCKETS_S, MetricsRegistry,
-                                     Span, TelemetryExporter)
+                                     TelemetryExporter)
+from deepspeed_tpu.telemetry import mark as telemetry_mark
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -110,10 +111,13 @@ def _sample_rows(logits: jnp.ndarray, keys: jnp.ndarray,
     [B] tokens.  temperature 0 rows take the argmax; others sample
     categorically at their temperature.  One jit, one result array — the
     serving loop fetches it with a single device→host transfer."""
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits.astype(jnp.float32) / jnp.maximum(temps, 1e-6)[:, None]
-    sampled = jax.vmap(jax.random.categorical)(keys, scaled)
-    return jnp.where(temps == 0.0, greedy, sampled.astype(jnp.int32))
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        scaled = logits.astype(jnp.float32) / \
+            jnp.maximum(temps, 1e-6)[:, None]
+        sampled = jax.vmap(jax.random.categorical)(keys, scaled)
+        return jnp.where(temps == 0.0, greedy,
+                         sampled.astype(jnp.int32))
 
 
 def _req_key(req_id: Any) -> str:
@@ -547,10 +551,47 @@ class ServingEngine:
             LATENCY_BUCKETS_S)
         # span pieces hoisted out of step(): one histogram resolve and
         # one label format at build time, zero registry locks per step
-        self._h_step_span = r.histogram(
-            "serving_step_seconds",
+        self._sp_step = r.span(
+            "serving_step",
             "scheduler iteration wall time (admit -> decode sync)")
-        self._span_label = f"{r.namespace}/serving_step"
+        # its children, one per PHASE of the iteration (never per slot
+        # or per token): they tile serving_step, so a capture says
+        # which host work fills a gap on the device's line.  Disabled,
+        # each is the shared no-op span.
+        self._sp_admit = r.span(
+            "serving_admit", "shed + watermark sweep + admissions "
+            "(whole-prompt prefill dispatches included)")
+        self._sp_prefill = r.span(
+            "serving_prefill", "one prefill chunk per pending slot")
+        self._sp_boundary = r.span(
+            "serving_boundary", "batched boundary-token sample + fetch "
+            "(a device sync)")
+        self._sp_grow = r.span(
+            "serving_grow_pages", "page growth / preemption before "
+            "decode, and the step's gauges")
+        self._sp_upload = r.span(
+            "serving_upload", "dirty page-table / seq_lens upload")
+        self._sp_inputs = r.span(
+            "serving_inputs", "decode inputs: tokens, temperatures, "
+            "key splits, device puts (speculative: drafting too)")
+        self._sp_dispatch = r.span(
+            "serving_dispatch", "the decode chunk / verify sweep "
+            "dispatch")
+        self._sp_token_sync = r.span(
+            "serving_token_sync", "the host waiting for the device: "
+            "the one token fetch per chunk")
+        self._sp_append = r.span(
+            "serving_append", "appending the fetched tokens to every "
+            "active slot (finishes, publishes, releases)")
+        self._sp_tick = r.span(
+            "serving_tick", "exporter / SLO / history / incident pass "
+            "after the iteration")
+        self._h_queue_wait = r.histogram(
+            "serving_queue_wait_seconds",
+            "arrival -> admitted to a slot: the time work waited",
+            LATENCY_BUCKETS_S)
+        self._mark_admitted = f"{r.namespace}/request_admitted"
+        self._mark_first_token = f"{r.namespace}/request_first_token"
         # ---- time-series history + incidents (PR 15): both blocks
         # ride the exporter's tick-hook pass, so enabling either needs
         # an exporter even without Prometheus/HTTP sinks (a sink-less
@@ -992,9 +1033,16 @@ class ServingEngine:
         """Install ``self._prefill`` / ``self._chunk_prefill`` /
         ``self._decode_chunk_fn`` — any callables honoring the jitted
         contracts; the base engine compiles whole-model programs."""
-        self._prefill = jax.jit(prefill_fn, donate_argnums=(2,))
-        self._chunk_prefill = (jax.jit(chunk_prefill_fn,
-                                       donate_argnums=(2,))
+        # stable program names: a capture's "XLA Modules" line says
+        # which program ran (jit_dstpu_prefill / _chunk / _decode)
+        def dstpu_prefill(params, tokens, cache):
+            return prefill_fn(params, tokens, cache)
+
+        def dstpu_chunk(params, tokens, cache):
+            return chunk_prefill_fn(params, tokens, cache)
+
+        self._prefill = jax.jit(dstpu_prefill, donate_argnums=(2,))
+        self._chunk_prefill = (jax.jit(dstpu_chunk, donate_argnums=(2,))
                                if chunk_prefill_fn is not None else None)
 
         # K decode steps in ONE on-device scan: each step's sampled token
@@ -1010,17 +1058,19 @@ class ServingEngine:
         # change a served greedy stream.
         sample = self._sample_fn
 
-        def chunk_fn(params, tok, cache, keys, temps):
+        def dstpu_decode(params, tok, cache, keys, temps):
             def one(carry, key_k):
                 t, c = carry
                 logits, c = decode_fn(params, t, c)
-                nxt = sample(logits[:, -1], key_k, temps)
+                with jax.named_scope("sample"):
+                    nxt = sample(logits[:, -1], key_k, temps)
                 return (nxt[:, None], c), nxt
 
             (_, cache), toks = jax.lax.scan(one, (tok, cache), keys)
             return jnp.swapaxes(toks, 0, 1), cache          # [B, K]
 
-        self._decode_chunk_fn = jax.jit(chunk_fn, donate_argnums=(2,))
+        self._decode_chunk_fn = jax.jit(dstpu_decode,
+                                        donate_argnums=(2,))
 
     def _devprof_cost_analyze(self) -> None:
         """Build-time roofline pass (devprof.cost_analysis): lower the
@@ -1816,7 +1866,7 @@ class ServingEngine:
                                       generated=[], rng=rng,
                                       seq_id=seq_id,
                                       prefill_done=cached, promo=promo)
-                self._c_admitted.inc()
+                self._note_admitted(req)
                 return True
 
             toks = np.full((1, end), 0, np.int32)
@@ -1842,7 +1892,7 @@ class ServingEngine:
             slot = _Slot(req=req, seq_len=T, generated=[], rng=rng,
                          seq_id=seq_id)
             self.slots[b] = slot
-            self._c_admitted.inc()
+            self._note_admitted(req)
             # the prompt's full pages are immutable from here on
             # (decode writes only at the frontier) — make them
             # matchable now so concurrent same-prefix requests hit
@@ -1886,6 +1936,18 @@ class ServingEngine:
             self._pending_boundary = [p for p in self._pending_boundary
                                       if p[0] != b]
             raise
+
+    def _note_admitted(self, req: Request) -> None:
+        """Admission succeeded: count it and, with telemetry on, record
+        how long the work waited (once a request: a preempted requeue
+        that already produced a token is not work newly waiting) and
+        mark the edge in a capture under the request's id."""
+        self._c_admitted.inc()
+        if self._tel_on and req.t_submit is not None:
+            self._h_queue_wait.observe(
+                time.perf_counter() - req.t_arrival)
+            telemetry_mark(self._mark_admitted,
+                           request_id=str(req.req_id))
 
     def _valid_tokens(self, s: "_Slot") -> int:
         """Positions of slot ``s`` that hold REAL written KV: mid-
@@ -2536,6 +2598,8 @@ class ServingEngine:
                 if s.req.t_submit is not None:
                     self._h_ttft.observe(now - s.req.t_submit)
                     s.req.t_submit = None  # once per request lifetime
+                    telemetry_mark(self._mark_first_token,
+                                   request_id=str(s.req.req_id))
                 elif s.last_tok_t:
                     self._h_itl.observe(now - s.last_tok_t)
                 s.last_tok_t = now
@@ -2618,74 +2682,86 @@ class ServingEngine:
             # span: wall time into serving_step_seconds + a
             # TraceAnnotation so captured device timelines show the
             # scheduler iteration
-            with Span(self._h_step_span, self._span_label):
+            with self._sp_step:
                 self._step_inner()
-            if self._tel_exporter is not None:
-                # one monotonic read drives the WHOLE timed control
-                # plane: sink exports plus the tick hooks (SLO window
-                # refresh, history sampling, incident evaluation)
-                self._tel_exporter.maybe_export()
-            elif self._tick_inline:
-                # no exporter (telemetry= was a bare registry — the
-                # fleet-replica pattern): drive the same pass inline
-                now = time.monotonic()
-                self.history.maybe_sample(now)
-                self.incident_mgr.maybe_evaluate(now)
-                self.devprof.tick(now)  # rate-limited internally
+            with self._sp_tick:
+                self._tick()
         else:
             self._step_inner()
             if self._tick_inline:
                 self.incident_mgr.maybe_evaluate()
+            self._slo_refresh()
+        return list(self._newly_finished)
+
+    def _tick(self) -> None:
+        """The timed control plane after an iteration (telemetry on)."""
+        if self._tel_exporter is not None:
+            # one monotonic read drives the WHOLE timed control
+            # plane: sink exports plus the tick hooks (SLO window
+            # refresh, history sampling, incident evaluation)
+            self._tel_exporter.maybe_export()
+        elif self._tick_inline:
+            # no exporter (telemetry= was a bare registry — the
+            # fleet-replica pattern): drive the same pass inline
+            now = time.monotonic()
+            self.history.maybe_sample(now)
+            self.incident_mgr.maybe_evaluate(now)
+            self.devprof.tick(now)  # rate-limited internally
+        self._slo_refresh()
+
+    def _slo_refresh(self) -> None:
         if self._slo_on and not self._slo_tick_hooked:
             # time-driven window refresh (rate-limited to ~1/s inside):
             # an idle engine's burn gauges must decay as violations age
             # out, not stay latched at their last finish-time values.
             # (With an exporter this runs as a tick hook instead.)
             self.slo_tracker.maybe_refresh()
-        return list(self._newly_finished)
 
     # dstpu: hot-path
     def _step_inner(self) -> None:
-        if self._shed_deadline and self.queue:
-            # BEFORE admission: a request whose deadline already
-            # expired must shed, not burn a slot on unwanted work
-            self._shed_expired()
-        if self._kvt_wm_pages is not None:
-            # BEFORE admission: proactively demoting past the
-            # watermark frees pages the admissions below can use
-            # without paying a per-eviction device read each
-            self._demote_watermark_sweep()
-        while self._admit_one():
-            pass
+        with self._sp_admit:
+            if self._shed_deadline and self.queue:
+                # BEFORE admission: a request whose deadline already
+                # expired must shed, not burn a slot on unwanted work
+                self._shed_expired()
+            if self._kvt_wm_pages is not None:
+                # BEFORE admission: proactively demoting past the
+                # watermark frees pages the admissions below can use
+                # without paying a per-eviction device read each
+                self._demote_watermark_sweep()
+            while self._admit_one():
+                pass
         # split-fuse: absorb ONE chunk per pending-prefill slot, then
         # run the batched decode for every ready slot in the same
         # iteration.  Failure isolation: an exception in one slot's
         # host-side work (including injected `slot` faults) fails THAT
         # request and releases its resources; the others keep serving.
-        for b, s in list(enumerate(self.slots)):
-            if s is not None and s.prefilling:
-                try:
-                    if self._fault_plan is not None:
-                        faults_mod.inject("slot", key=s.req.req_id)
-                    self._advance_prefill(b, s)
-                except faults_mod.FatalStreamError:
-                    raise    # dead WEIGHT stream: engine-fatal, not
-                except Exception as e:       # a per-request failure
-                    self._fail_slot(b, e)
-        if self._fault_plan is not None:
-            # decode-ready slots get the same per-step injection
-            # opportunity (a request that skipped chunked prefill
-            # would otherwise be untargetable)
-            for b, s in enumerate(self.slots):
-                if s is not None and not s.prefilling:
+        with self._sp_prefill:
+            for b, s in list(enumerate(self.slots)):
+                if s is not None and s.prefilling:
                     try:
-                        faults_mod.inject("slot", key=s.req.req_id)
-                    except InjectedFault as e:
+                        if self._fault_plan is not None:
+                            faults_mod.inject("slot", key=s.req.req_id)
+                        self._advance_prefill(b, s)
+                    except faults_mod.FatalStreamError:
+                        raise    # dead WEIGHT stream: engine-fatal, not
+                    except Exception as e:       # a per-request failure
                         self._fail_slot(b, e)
+            if self._fault_plan is not None:
+                # decode-ready slots get the same per-step injection
+                # opportunity (a request that skipped chunked prefill
+                # would otherwise be untargetable)
+                for b, s in enumerate(self.slots):
+                    if s is not None and not s.prefilling:
+                        try:
+                            faults_mod.inject("slot", key=s.req.req_id)
+                        except InjectedFault as e:
+                            self._fail_slot(b, e)
         # every prompt that finished prefilling this step samples its
         # boundary token in ONE batched fetch, before the decode phase
         # reads generated[-1]
-        self._flush_boundary()
+        with self._sp_boundary:
+            self._flush_boundary()
         K = self.decode_chunk
         # the speculative sweep writes K_draft+1 positions per slot —
         # provision its whole window, like chunked decode does
@@ -2693,72 +2769,84 @@ class ServingEngine:
                  else K)
         ready = lambda: [(b, s) for b, s in enumerate(self.slots)
                          if s is not None and not s.prefilling]
-        active = ready()
-        if active:
-            self._grow_pages(ahead=ahead)
+        with self._sp_grow:
             active = ready()
-        if self._tel_on:
-            self._g_queue.set(len(self.queue))
-            self._g_occupancy.set(len(active) / self.max_batch)
-            usable = self.trash_page       # pool minus the reserved page
-            # live-referenced pages only: the warm prefix pool is
-            # reclaimable on demand, so it does not count as utilized
-            self._g_kv_util.set(
-                (usable - self.allocator.available) / max(usable, 1))
-            if self._pc_on:
-                ev = self.allocator.evicted
-                if ev > self._evicted_seen:
-                    self._c_pc_evicted.inc(ev - self._evicted_seen)
-                    self._evicted_seen = ev
-                self._g_pc_pool.set(len(self.allocator.pool))
-                pt = self._c_pc_prompt_tokens.value
-                if pt:
-                    self._g_pc_frac.set(
-                        self._c_pc_cached_tokens.value / pt)
+            if active:
+                self._grow_pages(ahead=ahead)
+                active = ready()
+            if self._tel_on:
+                self._set_step_gauges(len(active))
         if active and self._spec_on:
             self._spec_step(active)
         elif active:
-            self._upload_dirty()
-            toks = np.zeros((self.max_batch, 1), np.int32)
-            temps = np.zeros((self.max_batch,), np.float32)
-            for b, s in active:
-                toks[b, 0] = s.generated[-1] if s.generated \
-                    else s.req.tokens[-1]
-                temps[b] = s.req.temperature
-            self._rng, r = jax.random.split(self._rng)
-            keys = jax.random.split(r, K * self.max_batch).reshape(
-                K, self.max_batch, -1)
-            out, self.cache = self._decode_chunk_fn(
-                self.params, self._put(toks), self.cache,
-                self._put(keys), self._put(temps))
-            if self._devprof_on and self.devprof.should_sample(
-                    "decode"):
-                # dstpu: host-sync-ok: sampled devprof device-time
-                # attribution — the np.asarray below would sync anyway;
-                # this just brackets it with a clock
-                self.devprof.observe_device("decode", out)
-            # trust the decode's structural seq_lens+K between
-            # composition changes (inactive rows drift, rebuilt on the
-            # next dirty upload)
-            for b, s in active:
-                s.seq_len += K
-            self._c_decode_steps.inc(K)
-            self._c_decode_syncs.inc()
-            self._c_kdisp_paged.inc()
-            self._c_kdisp_sample.inc(K)
-            # dstpu: host-sync-ok: the ONE device→host transfer per
-            # decode chunk (K tokens per sync — the module contract)
-            host_toks = np.asarray(out)
-            if self._trace_on and any(s.req.traced for _, s in active):
-                # one event per BATCH sync (not per token): the decode
-                # timeline at chunk granularity, nothing hotter
-                self.tracer.event("decode_batch", attrs={
-                    "active": len(active), "chunk": K})
-            for b, s in active:
-                for j in range(K):
-                    self._append_token(b, int(host_toks[b, j]))
-                    if self.slots[b] is None:   # finished mid-chunk:
-                        break                   # rest is discard
+            with self._sp_upload:
+                self._upload_dirty()
+            with self._sp_inputs:
+                toks = np.zeros((self.max_batch, 1), np.int32)
+                temps = np.zeros((self.max_batch,), np.float32)
+                for b, s in active:
+                    toks[b, 0] = s.generated[-1] if s.generated \
+                        else s.req.tokens[-1]
+                    temps[b] = s.req.temperature
+                self._rng, r = jax.random.split(self._rng)
+                keys = jax.random.split(r, K * self.max_batch).reshape(
+                    K, self.max_batch, -1)
+                toks_d, keys_d, temps_d = (
+                    self._put(toks), self._put(keys), self._put(temps))
+            with self._sp_dispatch:
+                out, self.cache = self._decode_chunk_fn(
+                    self.params, toks_d, self.cache, keys_d, temps_d)
+                # trust the decode's structural seq_lens+K between
+                # composition changes (inactive rows drift, rebuilt on
+                # the next dirty upload)
+                for b, s in active:
+                    s.seq_len += K
+                self._c_decode_steps.inc(K)
+                self._c_decode_syncs.inc()
+                self._c_kdisp_paged.inc()
+                self._c_kdisp_sample.inc(K)
+            with self._sp_token_sync:
+                if self._devprof_on and self.devprof.should_sample(
+                        "decode"):
+                    # dstpu: host-sync-ok: sampled devprof device-time
+                    # attribution — the np.asarray below would sync
+                    # anyway; this just brackets it with a clock
+                    self.devprof.observe_device("decode", out)
+                # dstpu: host-sync-ok: the ONE device→host transfer per
+                # decode chunk (K tokens per sync — the module contract)
+                host_toks = np.asarray(out)
+            with self._sp_append:
+                if self._trace_on and any(
+                        s.req.traced for _, s in active):
+                    # one event per BATCH sync (not per token): the
+                    # decode timeline at chunk granularity, nothing
+                    # hotter
+                    self.tracer.event("decode_batch", attrs={
+                        "active": len(active), "chunk": K})
+                for b, s in active:
+                    for j in range(K):
+                        self._append_token(b, int(host_toks[b, j]))
+                        if self.slots[b] is None:   # finished mid-chunk:
+                            break                   # rest is discard
+
+    def _set_step_gauges(self, n_active: int) -> None:
+        self._g_queue.set(len(self.queue))
+        self._g_occupancy.set(n_active / self.max_batch)
+        usable = self.trash_page       # pool minus the reserved page
+        # live-referenced pages only: the warm prefix pool is
+        # reclaimable on demand, so it does not count as utilized
+        self._g_kv_util.set(
+            (usable - self.allocator.available) / max(usable, 1))
+        if self._pc_on:
+            ev = self.allocator.evicted
+            if ev > self._evicted_seen:
+                self._c_pc_evicted.inc(ev - self._evicted_seen)
+                self._evicted_seen = ev
+            self._g_pc_pool.set(len(self.allocator.pool))
+            pt = self._c_pc_prompt_tokens.value
+            if pt:
+                self._g_pc_frac.set(
+                    self._c_pc_cached_tokens.value / pt)
 
     def _check_frontier_writable(self, active, ahead: int) -> None:
         """COW guard for the speculative write window: every page the
@@ -2800,50 +2888,67 @@ class ServingEngine:
         overwritten by the next sweep; ``_publish_full_pages`` bounds
         on ``_valid_tokens`` keep rejected garbage out of the prefix
         cache."""
+        with self._sp_inputs:
+            K = self.speculative.draft_tokens
+            Bm = self.max_batch
+            toks = np.zeros((Bm, K + 1), np.int32)
+            drafts = np.zeros((Bm, K), np.int32)
+            dlens = np.zeros((Bm,), np.int32)
+            temps = np.zeros((Bm,), np.float32)
+            drafted = 0
+            for b, s in active:
+                hist = s.req.tokens + s.generated
+                d = list(self.drafter.propose(hist, K))[:K]
+                dlens[b] = len(d)
+                drafts[b, :len(d)] = d
+                toks[b, 0] = hist[-1]
+                toks[b, 1:1 + len(d)] = d
+                temps[b] = s.req.temperature
+                drafted += len(d)
+            self._c_spec_drafted.inc(drafted)
+            traced_any = self._trace_on and any(
+                s.req.traced for _, s in active)
+            if traced_any:
+                self.tracer.event("spec_draft", attrs={
+                    "active": len(active), "drafted": drafted})
+            if self._pc_on:
+                self._check_frontier_writable(active, K + 1)
+        with self._sp_upload:
+            self._upload_dirty()
+        with self._sp_dispatch:
+            self._rng, r = jax.random.split(self._rng)
+            keys = jax.random.split(r, (K + 1) * Bm).reshape(
+                Bm, K + 1, -1)
+            logits, self.cache = self._chunk_prefill(
+                self.params, self._put(toks), self.cache)
+            n_acc_d, stop_d = verify_accept(
+                logits, self._put(drafts), self._put(dlens),
+                self._put(keys), self._put(temps))
+        with self._sp_token_sync:
+            if self._devprof_on and self.devprof.should_sample(
+                    "spec_verify"):
+                # dstpu: host-sync-ok: sampled devprof device-time
+                # attribution — the device_get below syncs anyway; this
+                # just brackets the verify sweep with a clock
+                self.devprof.observe_device("spec_verify", n_acc_d)
+            if traced_any:
+                self.tracer.event("spec_verify", attrs={
+                    "active": len(active), "positions": K + 1})
+            # dstpu: host-sync-ok: the ONE device→host transfer per
+            # verify sweep (accepted lengths + stop tokens for the
+            # whole batch)
+            n_acc, stop = jax.device_get((n_acc_d, stop_d))
+        with self._sp_append:
+            self._spec_accept(active, n_acc, stop, drafts, dlens,
+                              traced_any)
+
+    # dstpu: hot-path
+    def _spec_accept(self, active, n_acc, stop, drafts, dlens,
+                     traced_any) -> None:
+        """The host half of a sweep's end: advance every slot by its
+        accepted span and append the tokens."""
         K = self.speculative.draft_tokens
         Bm = self.max_batch
-        toks = np.zeros((Bm, K + 1), np.int32)
-        drafts = np.zeros((Bm, K), np.int32)
-        dlens = np.zeros((Bm,), np.int32)
-        temps = np.zeros((Bm,), np.float32)
-        drafted = 0
-        for b, s in active:
-            hist = s.req.tokens + s.generated
-            d = list(self.drafter.propose(hist, K))[:K]
-            dlens[b] = len(d)
-            drafts[b, :len(d)] = d
-            toks[b, 0] = hist[-1]
-            toks[b, 1:1 + len(d)] = d
-            temps[b] = s.req.temperature
-            drafted += len(d)
-        self._c_spec_drafted.inc(drafted)
-        traced_any = self._trace_on and any(
-            s.req.traced for _, s in active)
-        if traced_any:
-            self.tracer.event("spec_draft", attrs={
-                "active": len(active), "drafted": drafted})
-        if self._pc_on:
-            self._check_frontier_writable(active, K + 1)
-        self._upload_dirty()
-        self._rng, r = jax.random.split(self._rng)
-        keys = jax.random.split(r, (K + 1) * Bm).reshape(Bm, K + 1, -1)
-        logits, self.cache = self._chunk_prefill(
-            self.params, self._put(toks), self.cache)
-        n_acc_d, stop_d = verify_accept(
-            logits, self._put(drafts), self._put(dlens),
-            self._put(keys), self._put(temps))
-        if self._devprof_on and self.devprof.should_sample(
-                "spec_verify"):
-            # dstpu: host-sync-ok: sampled devprof device-time
-            # attribution — the device_get below syncs anyway; this
-            # just brackets the verify sweep with a clock
-            self.devprof.observe_device("spec_verify", n_acc_d)
-        if traced_any:
-            self.tracer.event("spec_verify", attrs={
-                "active": len(active), "positions": K + 1})
-        # dstpu: host-sync-ok: the ONE device→host transfer per verify
-        # sweep (accepted lengths + stop tokens for the whole batch)
-        n_acc, stop = jax.device_get((n_acc_d, stop_d))
         self._c_decode_syncs.inc()
         self._c_kdisp_paged.inc()   # the verify sweep IS a paged dispatch
         self._c_decode_steps.inc(K + 1)

@@ -100,32 +100,57 @@ def param_specs(cfg: GPT2Config) -> Dict[str, Any]:
 
 
 def _block(cfg: GPT2Config, x, lp):
-    B, T, d = x.shape
-    nh, hd = cfg.n_heads, cfg.head_dim
-    h = layer_norm(x, lp["ln1_w"], lp["ln1_b"], cfg.norm_eps)
-    qkv = h @ lp["qkv_w"] + lp["qkv_b"]
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(B, T, nh, hd)
-    k = k.reshape(B, T, nh, hd)
-    v = v.reshape(B, T, nh, hd)
+    from jax.ad_checkpoint import checkpoint_name
+
     from deepspeed_tpu.ops.attention import flash_attention
     from deepspeed_tpu.topology import current_mesh
 
-    from jax.ad_checkpoint import checkpoint_name
+    B, T, d = x.shape
+    q, k, v = _qkv(cfg, x, lp)
+    with jax.named_scope("flash"):
+        attn = flash_attention(q, k, v, causal=True,
+                               mesh=current_mesh()).reshape(B, T, d)
+        attn = checkpoint_name(attn, "attn_out")  # remat.py save/offload tag
+    return _out_mlp(cfg, x, attn, lp, tag=checkpoint_name)
 
-    attn = flash_attention(q, k, v, causal=True,
-                           mesh=current_mesh()).reshape(B, T, d)
-    attn = checkpoint_name(attn, "attn_out")   # remat.py save/offload tag
-    x = x + attn @ lp["proj_w"] + lp["proj_b"]
-    h = layer_norm(x, lp["ln2_w"], lp["ln2_b"], cfg.norm_eps)
-    h = jax.nn.gelu(h @ lp["fc_w"] + lp["fc_b"], approximate=True)
-    h = checkpoint_name(h, "mlp_out")
-    return x + h @ lp["out_w"] + lp["out_b"]
+
+def _qkv(cfg: GPT2Config, x, lp):
+    """LayerNorm + fused QKV projection → q, k, v [B, T, H, hd]."""
+    B, T, _ = x.shape
+    nh, hd = cfg.n_heads, cfg.head_dim
+    with jax.named_scope("attn_qkv"):
+        h = layer_norm(x, lp["ln1_w"], lp["ln1_b"], cfg.norm_eps)
+        qkv = h @ lp["qkv_w"] + lp["qkv_b"]
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        return (q.reshape(B, T, nh, hd), k.reshape(B, T, nh, hd),
+                v.reshape(B, T, nh, hd))
+
+
+def _out_mlp(cfg: GPT2Config, x, attn, lp, tag=None):
+    """Attention output projection, then the GELU MLP, each with its
+    residual.  ``tag`` is training's ``checkpoint_name`` (remat)."""
+    with jax.named_scope("attn_out"):
+        x = x + attn @ lp["proj_w"] + lp["proj_b"]
+    with jax.named_scope("mlp"):
+        h = layer_norm(x, lp["ln2_w"], lp["ln2_b"], cfg.norm_eps)
+        h = jax.nn.gelu(h @ lp["fc_w"] + lp["fc_b"], approximate=True)
+        if tag is not None:
+            h = tag(h, "mlp_out")
+        return x + h @ lp["out_w"] + lp["out_b"]
+
+
+def _head(params, x, cfg: GPT2Config):
+    with jax.named_scope("final_norm"):
+        x = layer_norm(x, params["lnf_w"], params["lnf_b"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        return jnp.einsum("btd,vd->btv", x, params["wte"],
+                          preferred_element_type=jnp.float32)
 
 
 def forward(params, tokens, cfg: GPT2Config):
     B, T = tokens.shape
-    x = params["wte"][tokens] + params["wpe"][:T][None]
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens] + params["wpe"][:T][None]
 
     block = lambda x, lp: (_block(cfg, x, lp), None)
     if cfg.remat != "none":
@@ -133,9 +158,7 @@ def forward(params, tokens, cfg: GPT2Config):
 
         block = jax.checkpoint(block, policy=remat_policy(cfg.remat))
     x, _ = jax.lax.scan(block, x, params["blocks"])
-    x = layer_norm(x, params["lnf_w"], params["lnf_b"], cfg.norm_eps)
-    return jnp.einsum("btd,vd->btv", x, params["wte"],
-                      preferred_element_type=jnp.float32)
+    return _head(params, x, cfg)
 
 
 def forward_with_cache(params, tokens, cfg: GPT2Config, cache):
@@ -150,27 +173,19 @@ def forward_with_cache(params, tokens, cfg: GPT2Config, cache):
     nh, hd = cfg.n_heads, cfg.head_dim
     start = cache.length
     pos = start + jnp.arange(T, dtype=jnp.int32)
-    x = params["wte"][tokens] + params["wpe"][pos][None]
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens] + params["wpe"][pos][None]
 
     def block(x, layer):
         lp, kc, vc = layer
-        h = layer_norm(x, lp["ln1_w"], lp["ln1_b"], cfg.norm_eps)
-        qkv = h @ lp["qkv_w"] + lp["qkv_b"]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, T, nh, hd)
-        k = k.reshape(B, T, nh, hd)
-        v = v.reshape(B, T, nh, hd)
-        attn, kc, vc = cached_attention(q, kc, vc, k, v, start)
-        x = x + attn.reshape(B, T, nh * hd) @ lp["proj_w"] + lp["proj_b"]
-        h = layer_norm(x, lp["ln2_w"], lp["ln2_b"], cfg.norm_eps)
-        h = jax.nn.gelu(h @ lp["fc_w"] + lp["fc_b"], approximate=True)
-        return x + h @ lp["out_w"] + lp["out_b"], (kc, vc)
+        q, k, v = _qkv(cfg, x, lp)
+        with jax.named_scope("kv_attend"):
+            attn, kc, vc = cached_attention(q, kc, vc, k, v, start)
+        return _out_mlp(cfg, x, attn.reshape(B, T, nh * hd), lp), (kc, vc)
 
     x, (new_k, new_v) = jax.lax.scan(block, x,
                                      (params["blocks"], cache.k, cache.v))
-    x = layer_norm(x, params["lnf_w"], params["lnf_b"], cfg.norm_eps)
-    logits = jnp.einsum("btd,vd->btv", x, params["wte"],
-                        preferred_element_type=jnp.float32)
+    logits = _head(params, x, cfg)
     return logits, cache._replace(k=new_k, v=new_v, length=start + T)
 
 
@@ -225,7 +240,8 @@ def forward_paged(params, tokens, cfg: GPT2Config, cache,
     # Learned positions are HARD-bounded by the table (unlike RoPE);
     # serving/generator builders validate max_seq <= cfg.max_seq_len.
     positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
-    x = params["wte"][tokens] + params["wpe"][positions]
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens] + params["wpe"][positions]
 
     quant = cache.k_scale is not None
     if paged_kernel in (None, "auto"):
@@ -239,21 +255,13 @@ def forward_paged(params, tokens, cfg: GPT2Config, cache,
         else:
             lp, kp, vp = layer
             kps = vps = None
-        h = layer_norm(x, lp["ln1_w"], lp["ln1_b"], cfg.norm_eps)
-        qkv = h @ lp["qkv_w"] + lp["qkv_b"]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, T, nh, hd)
-        k = k.reshape(B, T, nh, hd)
-        v = v.reshape(B, T, nh, hd)
+        q, k, v = _qkv(cfg, x, lp)
         attn, kp, vp, kps, vps = paged_attention_step(
             q, k, v, kp, vp, cache.table, start, ps,
             continuation=continuation, prefill=prefill,
             paged_kernel=paged_kernel, flash_force_reference=tp,
             interpret=interpret, kps=kps, vps=vps)
-        x = x + attn.reshape(B, T, d) @ lp["proj_w"] + lp["proj_b"]
-        h = layer_norm(x, lp["ln2_w"], lp["ln2_b"], cfg.norm_eps)
-        h = jax.nn.gelu(h @ lp["fc_w"] + lp["fc_b"], approximate=True)
-        return (x + h @ lp["out_w"] + lp["out_b"],
+        return (_out_mlp(cfg, x, attn.reshape(B, T, d), lp),
                 (kp, vp, kps, vps) if quant else (kp, vp))
 
     if quant:
@@ -264,9 +272,7 @@ def forward_paged(params, tokens, cfg: GPT2Config, cache,
         x, (new_k, new_v) = jax.lax.scan(
             block, x, (params["blocks"], cache.k, cache.v))
         new_ks = new_vs = None
-    x = layer_norm(x, params["lnf_w"], params["lnf_b"], cfg.norm_eps)
-    logits = jnp.einsum("btd,vd->btv", x, params["wte"],
-                        preferred_element_type=jnp.float32)
+    logits = _head(params, x, cfg)
     cache = cache._replace(k=new_k, v=new_v, seq_lens=start + T)
     if quant:
         cache = cache._replace(k_scale=new_ks, v_scale=new_vs)

@@ -259,27 +259,59 @@ def reference_attention(q, k, v, causal=True, segment_ids=None):
     return _reference(q, k, v, causal=causal, segment_ids=segment_ids)
 
 
-def _block(cfg: LlamaConfig, x, layer_params, cos, sin, segment_ids):
-    B, T, d = x.shape
+def _qkv(cfg, x, lp, cos, sin):
+    """Pre-norm + Q/K/V projections + RoPE → q [B, T, H, hd], k and v
+    [B, T, KV, hd].  Shared by every forward (training, cached, paged,
+    layer-streamed; Mixtral's too) so the paths cannot drift."""
+    B, T, _ = x.shape
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    lp = layer_params
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ lp["wq"]).reshape(B, T, nh, hd)
-    k = (h @ lp["wk"]).reshape(B, T, nkv, hd)
-    v = (h @ lp["wv"]).reshape(B, T, nkv, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    from jax.ad_checkpoint import checkpoint_name
+    with jax.named_scope("attn_qkv"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = (h @ lp["wq"]).reshape(B, T, nh, hd)
+        k = (h @ lp["wk"]).reshape(B, T, nkv, hd)
+        v = (h @ lp["wv"]).reshape(B, T, nkv, hd)
+        return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
-    attn = _attention(q, k, v, cfg, segment_ids).reshape(B, T, nh * hd)
-    attn = checkpoint_name(attn, "attn_out")   # remat.py save/offload tag
-    x = x + attn @ lp["wo"]
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+
+def _out_ffn(cfg, x, attn, lp, ffn=None, tag=None):
+    """Attention output projection + residual, then the FFN half:
+    SwiGLU, or ``ffn(lp, h)`` (a MoE family's expert combine, which
+    names its own scopes inside ``mlp``).  ``attn``: [B, T, H*hd].
+    ``tag`` is training's ``checkpoint_name`` (remat)."""
     from deepspeed_tpu.ops.fused_ops import swiglu
 
-    mlp = checkpoint_name(swiglu(h, lp["w1"], lp["w3"]), "mlp_out")
-    x = x + mlp @ lp["w2"]
-    return x
+    with jax.named_scope("attn_out"):
+        x = x + attn @ lp["wo"]
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        if ffn is not None:
+            return x + ffn(lp, h)
+        mlp = swiglu(h, lp["w1"], lp["w3"])
+        if tag is not None:
+            mlp = tag(mlp, "mlp_out")
+        return x + mlp @ lp["w2"]
+
+
+def _head(params, x, cfg):
+    """Final norm + LM head → logits [B, T, V] f32."""
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        return jnp.einsum("btd,dv->btv", x, lm_head(params, cfg),
+                          preferred_element_type=jnp.float32)
+
+
+def _block(cfg: LlamaConfig, x, layer_params, cos, sin, segment_ids):
+    from jax.ad_checkpoint import checkpoint_name
+
+    B, T, d = x.shape
+    lp = layer_params
+    q, k, v = _qkv(cfg, x, lp, cos, sin)
+    with jax.named_scope("flash"):
+        attn = _attention(q, k, v, cfg, segment_ids).reshape(
+            B, T, cfg.n_heads * cfg.head_dim)
+        attn = checkpoint_name(attn, "attn_out")  # remat.py save/offload tag
+    return _out_ffn(cfg, x, attn, lp, tag=checkpoint_name)
 
 
 def forward_hidden(params, tokens, cfg: LlamaConfig, positions=None,
@@ -288,10 +320,11 @@ def forward_hidden(params, tokens, cfg: LlamaConfig, positions=None,
     pre-LM-head activations; :func:`forward` adds the head projection,
     the chunked loss consumes these directly)."""
     B, T = tokens.shape
-    x = params["embed"][tokens]  # [B, T, d]
-    if positions is None:
-        positions = jnp.arange(T, dtype=jnp.int32)
-    cos, sin = rope_tables(cfg, positions)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]  # [B, T, d]
+        if positions is None:
+            positions = jnp.arange(T, dtype=jnp.int32)
+        cos, sin = rope_tables(cfg, positions)
 
     block = lambda x, lp: (_block(cfg, x, lp, cos, sin, segment_ids), None)
     from deepspeed_tpu.topology import current_mesh
@@ -316,7 +349,8 @@ def forward_hidden(params, tokens, cfg: LlamaConfig, positions=None,
             block = jax.checkpoint(block, policy=remat_policy(cfg.remat))
         x, _ = jax.lax.scan(block, x, params["blocks"])
 
-    return rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("final_norm"):
+        return rms_norm(x, params["final_norm"], cfg.norm_eps)
 
 
 def lm_head(params, cfg: LlamaConfig):
@@ -333,8 +367,9 @@ def forward(params, tokens, cfg: LlamaConfig, positions=None,
     """
     x = forward_hidden(params, tokens, cfg, positions=positions,
                        segment_ids=segment_ids, n_micro=n_micro)
-    return jnp.einsum("btd,dv->btv", x, lm_head(params, cfg),
-                      preferred_element_type=jnp.float32)
+    with jax.named_scope("lm_head"):
+        return jnp.einsum("btd,dv->btv", x, lm_head(params, cfg),
+                          preferred_element_type=jnp.float32)
 
 
 def forward_with_cache(params, tokens, cfg: LlamaConfig, cache):
@@ -349,32 +384,21 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache):
     B, T = tokens.shape
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     start = cache.length
-    x = params["embed"][tokens]
-    positions = start + jnp.arange(T, dtype=jnp.int32)
-    cos, sin = rope_tables(cfg, positions)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+        positions = start + jnp.arange(T, dtype=jnp.int32)
+        cos, sin = rope_tables(cfg, positions)
 
     def block(x, layer):
         lp, kc, vc = layer
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = (h @ lp["wq"]).reshape(B, T, nh, hd)
-        k = (h @ lp["wk"]).reshape(B, T, nkv, hd)
-        v = (h @ lp["wv"]).reshape(B, T, nkv, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        attn, kc, vc = cached_attention(q, kc, vc, k, v, start)
-        x = x + attn.reshape(B, T, nh * hd) @ lp["wo"]
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        from deepspeed_tpu.ops.fused_ops import swiglu
-
-        x = x + swiglu(h, lp["w1"], lp["w3"]) @ lp["w2"]
-        return x, (kc, vc)
+        q, k, v = _qkv(cfg, x, lp, cos, sin)
+        with jax.named_scope("kv_attend"):
+            attn, kc, vc = cached_attention(q, kc, vc, k, v, start)
+        return _out_ffn(cfg, x, attn.reshape(B, T, nh * hd), lp), (kc, vc)
 
     x, (new_k, new_v) = jax.lax.scan(block, x,
                                      (params["blocks"], cache.k, cache.v))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("btd,dv->btv", x, head,
-                        preferred_element_type=jnp.float32)
+    logits = _head(params, x, cfg)
     cache = cache._replace(k=new_k, v=new_v, length=start + T)
     return logits, cache
 
@@ -421,20 +445,19 @@ def forward_paged(params, tokens, cfg: LlamaConfig, cache,
     dequantize_pages` ("xla").
     """
     from deepspeed_tpu.inference.kernels import (paged_attention_step,
+                                                 paged_forward_prelude,
                                                  pallas_paged_gate)
-    from deepspeed_tpu.ops.fused_ops import swiglu
-
-    from deepspeed_tpu.inference.kernels import paged_forward_prelude
 
     B, T = tokens.shape
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     interpret, tp_active, ps, start, prefill = paged_forward_prelude(
         cache, tokens, interpret, tp, continuation)
-    x = params["embed"][tokens]
-    # per-sequence position offsets: ragged frontiers under continuous
-    # batching rotate each row by ITS seq_len, not row 0's
-    positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
-    cos, sin = rope_tables(cfg, positions)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+        # per-sequence position offsets: ragged frontiers under
+        # continuous batching rotate each row by ITS seq_len, not row 0's
+        positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+        cos, sin = rope_tables(cfg, positions)
 
     quant = cache.k_scale is not None      # int8-resident KV (static)
     if paged_kernel in (None, "auto"):
@@ -449,21 +472,13 @@ def forward_paged(params, tokens, cfg: LlamaConfig, cache,
         else:
             lp, kp, vp = layer
             kps = vps = None
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = (h @ lp["wq"]).reshape(B, T, nh, hd)
-        k = (h @ lp["wk"]).reshape(B, T, nkv, hd)
-        v = (h @ lp["wv"]).reshape(B, T, nkv, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        q, k, v = _qkv(cfg, x, lp, cos, sin)
         attn, kp, vp, kps, vps = paged_attention_step(
             q, k, v, kp, vp, cache.table, start, ps,
             continuation=continuation, prefill=prefill,
             paged_kernel=paged_kernel, flash_force_reference=tp_active,
             interpret=interpret, kps=kps, vps=vps)
-        x = x + attn.reshape(B, T, nh * hd) @ lp["wo"]
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + (swiglu(h, lp["w1"], lp["w3"]) @ lp["w2"]
-                 if ffn is None else ffn(lp, h))
+        x = _out_ffn(cfg, x, attn.reshape(B, T, nh * hd), lp, ffn=ffn)
         return x, ((kp, vp, kps, vps) if quant else (kp, vp))
 
     if quant:
@@ -474,10 +489,7 @@ def forward_paged(params, tokens, cfg: LlamaConfig, cache,
         x, (new_k, new_v) = jax.lax.scan(
             block, x, (params["blocks"], cache.k, cache.v))
         new_ks, new_vs = cache.k_scale, cache.v_scale
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("btd,dv->btv", x, head,
-                        preferred_element_type=jnp.float32)
+    logits = _head(params, x, cfg)
     cache = cache._replace(k=new_k, v=new_v, seq_lens=start + T,
                            k_scale=new_ks, v_scale=new_vs)
     return logits, cache
@@ -509,15 +521,16 @@ def paged_layered_fns(cfg: LlamaConfig, tp: bool = False, ffn=None,
     from deepspeed_tpu.inference.kernels import (paged_attention_step,
                                                  pallas_paged_gate)
     from deepspeed_tpu.inference.quantized import dequantize_params
-    from deepspeed_tpu.ops.fused_ops import swiglu
 
     def stem_fn(sp, tokens, start):
         sp = dequantize_params(sp)
-        x = sp["embed"][tokens]
-        T = tokens.shape[1]
-        positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
-        cos, sin = rope_tables(cfg, positions)
-        return x, cos, sin
+        with jax.named_scope("embed"):
+            x = sp["embed"][tokens]
+            T = tokens.shape[1]
+            positions = start[:, None] + \
+                jnp.arange(T, dtype=jnp.int32)[None]
+            cos, sin = rope_tables(cfg, positions)
+            return x, cos, sin
 
     def block_fn(lp, x, cos, sin, kp, vp, table, start, *,
                  continuation: bool, prefill: bool):
@@ -527,12 +540,7 @@ def paged_layered_fns(cfg: LlamaConfig, tp: bool = False, ffn=None,
         ps = kp.shape[2]
         itp = (jax.default_backend() != "tpu") if interpret is None \
             else interpret
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = (h @ lp["wq"]).reshape(B, T, nh, hd)
-        k = (h @ lp["wk"]).reshape(B, T, nkv, hd)
-        v = (h @ lp["wv"]).reshape(B, T, nkv, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        q, k, v = _qkv(cfg, x, lp, cos, sin)
         if paged_kernel in (None, "auto"):
             pk = "pallas_v2" if pallas_paged_gate(
                 B, nkv, hd, ps, table.shape[1], kp.dtype.itemsize,
@@ -543,18 +551,11 @@ def paged_layered_fns(cfg: LlamaConfig, tp: bool = False, ffn=None,
             q, k, v, kp, vp, table, start, ps,
             continuation=continuation, prefill=prefill,
             paged_kernel=pk, flash_force_reference=tp, interpret=itp)
-        x = x + attn.reshape(B, T, nh * hd) @ lp["wo"]
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + (swiglu(h, lp["w1"], lp["w3"]) @ lp["w2"]
-                 if ffn is None else ffn(lp, h))
+        x = _out_ffn(cfg, x, attn.reshape(B, T, nh * hd), lp, ffn=ffn)
         return x, kp, vp
 
     def head_fn(hp, x):
-        hp = dequantize_params(hp)
-        x = rms_norm(x, hp["final_norm"], cfg.norm_eps)
-        head = hp["embed"].T if cfg.tie_embeddings else hp["lm_head"]
-        return jnp.einsum("btd,dv->btv", x, head,
-                          preferred_element_type=jnp.float32)
+        return _head(dequantize_params(hp), x, cfg)
 
     return stem_fn, block_fn, head_fn
 

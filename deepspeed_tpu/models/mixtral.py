@@ -155,18 +155,16 @@ def _attn_block(cfg: MixtralConfig, lcfg, x, lp, cos, sin,
     """The attention half of a Mixtral block (pre-norm attn + residual),
     shared by the training forward, the eval forward, and the layered
     streaming block so the four paths cannot drift."""
-    B, T, _ = x.shape
-    hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    h = _llama.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = _llama.apply_rope((h @ lp["wq"]).reshape(B, T, nh, hd), cos, sin)
-    k = _llama.apply_rope((h @ lp["wk"]).reshape(B, T, nkv, hd), cos, sin)
-    v = (h @ lp["wv"]).reshape(B, T, nkv, hd)
     from jax.ad_checkpoint import checkpoint_name
 
-    attn = _llama._attention(q, k, v, lcfg,
-                             segment_ids).reshape(B, T, nh * hd)
-    attn = checkpoint_name(attn, "attn_out")   # remat.py save/offload tag
-    return x + attn @ lp["wo"]
+    B, T, _ = x.shape
+    q, k, v = _llama._qkv(cfg, x, lp, cos, sin)
+    with jax.named_scope("flash"):
+        attn = _llama._attention(q, k, v, lcfg, segment_ids).reshape(
+            B, T, cfg.n_heads * cfg.head_dim)
+        attn = checkpoint_name(attn, "attn_out")  # remat.py save/offload tag
+    with jax.named_scope("attn_out"):
+        return x + attn @ lp["wo"]
 
 
 def _moe_ffn(cfg: MixtralConfig, x, lp, mesh):
@@ -178,7 +176,9 @@ def _moe_ffn(cfg: MixtralConfig, x, lp, mesh):
 
     layer = MoELayer(cfg=cfg.moe_config(), expert_fn=expert_fn, mesh=mesh)
     eparams = {"w1": lp["w1"], "w3": lp["w3"], "w2": lp["w2"]}
-    return layer(lp["gate"], eparams, x)
+    # the layer names its gate moe_router inside
+    with jax.named_scope("moe_ffn"):
+        return layer(lp["gate"], eparams, x)
 
 
 def forward(params, tokens, cfg: MixtralConfig, positions=None,
@@ -191,18 +191,16 @@ def forward(params, tokens, cfg: MixtralConfig, positions=None,
     lcfg = cfg.llama_view()
     mesh = current_mesh()
     B, T = tokens.shape
-    x = params["embed"][tokens]
-    if positions is None:
-        positions = jnp.arange(T, dtype=jnp.int32)
-    cos, sin = _llama.rope_tables(lcfg, positions)
+    x, cos, sin = _embed(params, tokens, lcfg, positions)
 
     def block(carry, lp):
         from jax.ad_checkpoint import checkpoint_name
 
         x, aux_acc = carry
         x = _attn_block(cfg, lcfg, x, lp, cos, sin, segment_ids)
-        h = _llama.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        y, aux = _moe_ffn(cfg, h, lp, mesh)
+        with jax.named_scope("mlp"):
+            h = _llama.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            y, aux = _moe_ffn(cfg, h, lp, mesh)
         y = checkpoint_name(y, "mlp_out")
         x = x + y
         aux_acc = {
@@ -222,10 +220,16 @@ def forward(params, tokens, cfg: MixtralConfig, positions=None,
                 "moe_z_loss": jnp.float32(0.0),
                 "moe_expert_load": jnp.zeros((cfg.num_experts,), jnp.float32)}
     (x, aux), _ = jax.lax.scan(blk, (x, zero_aux), params["blocks"])
-    x = _llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)
-    return logits, aux
+    return _llama._head(params, x, lcfg), aux
+
+
+def _embed(params, tokens, lcfg, positions=None):
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+        if positions is None:
+            positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+        cos, sin = _llama.rope_tables(lcfg, positions)
+        return x, cos, sin
 
 
 def _moe_ffn_dense(cfg: MixtralConfig, x, lp):
@@ -249,21 +253,24 @@ def _moe_ffn_dense(cfg: MixtralConfig, x, lp):
     B, T, d = x.shape
     E, k = cfg.num_experts, cfg.top_k
     h = x.reshape(-1, d)
-    # router math in f32 like the training gate — bf16 logits could flip
-    # a near-tied top-k choice and diverge from the trained routing
-    logits = h.astype(jnp.float32) @ lp["gate"].astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    _, topi = jax.lax.top_k(logits, k)                              # [N, k]
-    w = jnp.take_along_axis(probs, topi, axis=-1)
-    if k > 1:
-        # same renormalization as the training gate (top2gating)
-        w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
-    ys = jax.vmap(lambda p1, p3, p2: swiglu(h, p1, p3) @ p2)(
-        lp["w1"], lp["w3"], lp["w2"])                               # [E, N, d]
-    wfull = jnp.sum(jax.nn.one_hot(topi, E, dtype=w.dtype)
-                    * w[..., None], axis=1)                         # [N, E]
-    y = jnp.einsum("ne,end->nd", wfull, ys.astype(w.dtype))
-    return y.reshape(B, T, d).astype(x.dtype)
+    with jax.named_scope("moe_router"):
+        # router math in f32 like the training gate — bf16 logits could
+        # flip a near-tied top-k choice and diverge from the trained
+        # routing
+        logits = h.astype(jnp.float32) @ lp["gate"].astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        _, topi = jax.lax.top_k(logits, k)                          # [N, k]
+        w = jnp.take_along_axis(probs, topi, axis=-1)
+        if k > 1:
+            # same renormalization as the training gate (top2gating)
+            w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
+        wfull = jnp.sum(jax.nn.one_hot(topi, E, dtype=w.dtype)
+                        * w[..., None], axis=1)                     # [N, E]
+    with jax.named_scope("moe_ffn"):
+        ys = jax.vmap(lambda p1, p3, p2: swiglu(h, p1, p3) @ p2)(
+            lp["w1"], lp["w3"], lp["w2"])                           # [E, N, d]
+        y = jnp.einsum("ne,end->nd", wfull, ys.astype(w.dtype))
+        return y.reshape(B, T, d).astype(x.dtype)
 
 
 def forward_eval(params, tokens, cfg: MixtralConfig, positions=None):
@@ -272,21 +279,16 @@ def forward_eval(params, tokens, cfg: MixtralConfig, positions=None):
     kernel injection serves — the reference's eval-mode contract, where
     generation quality must not depend on router load balance."""
     lcfg = cfg.llama_view()
-    B, T = tokens.shape
-    x = params["embed"][tokens]
-    if positions is None:
-        positions = jnp.arange(T, dtype=jnp.int32)
-    cos, sin = _llama.rope_tables(lcfg, positions)
+    x, cos, sin = _embed(params, tokens, lcfg, positions)
 
     def block(x, lp):
         x = _attn_block(cfg, lcfg, x, lp, cos, sin)
-        h = _llama.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        return x + _moe_ffn_dense(cfg, h, lp), None
+        with jax.named_scope("mlp"):
+            h = _llama.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            return x + _moe_ffn_dense(cfg, h, lp), None
 
     x, _ = jax.lax.scan(block, x, params["blocks"])
-    x = _llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return jnp.einsum("btd,dv->btv", x, params["lm_head"],
-                      preferred_element_type=jnp.float32)
+    return _llama._head(params, x, lcfg)
 
 
 def forward_with_cache(params, tokens, cfg: MixtralConfig, cache):
@@ -299,29 +301,22 @@ def forward_with_cache(params, tokens, cfg: MixtralConfig, cache):
     B, T = tokens.shape
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     start = cache.length
-    x = params["embed"][tokens]
-    positions = start + jnp.arange(T, dtype=jnp.int32)
-    cos, sin = _llama.rope_tables(lcfg, positions)
+    x, cos, sin = _embed(params, tokens, lcfg,
+                         start + jnp.arange(T, dtype=jnp.int32))
+    ffn = lambda lp, h: _moe_ffn_dense(cfg, h, lp)
 
     def block(x, layer):
         lp, kc, vc = layer
-        h = _llama.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = (h @ lp["wq"]).reshape(B, T, nh, hd)
-        k = (h @ lp["wk"]).reshape(B, T, nkv, hd)
-        v = (h @ lp["wv"]).reshape(B, T, nkv, hd)
-        q = _llama.apply_rope(q, cos, sin)
-        k = _llama.apply_rope(k, cos, sin)
-        attn, kc, vc = cached_attention(q, kc, vc, k, v, start)
-        x = x + attn.reshape(B, T, nh * hd) @ lp["wo"]
-        h = _llama.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _moe_ffn_dense(cfg, h, lp)
+        q, k, v = _llama._qkv(cfg, x, lp, cos, sin)
+        with jax.named_scope("kv_attend"):
+            attn, kc, vc = cached_attention(q, kc, vc, k, v, start)
+        x = _llama._out_ffn(cfg, x, attn.reshape(B, T, nh * hd), lp,
+                            ffn=ffn)
         return x, (kc, vc)
 
     x, (new_k, new_v) = jax.lax.scan(block, x,
                                      (params["blocks"], cache.k, cache.v))
-    x = _llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)
+    logits = _llama._head(params, x, lcfg)
     cache = cache._replace(k=new_k, v=new_v, length=start + T)
     return logits, cache
 

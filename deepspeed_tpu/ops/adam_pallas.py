@@ -130,6 +130,7 @@ def adam_update_flat(g, m, v, p, step, lr, *, b1=0.9, b2=0.999, eps=1e-8,
                    jax.ShapeDtypeStruct((rows_pad, _LANES), jnp.float32),
                    jax.ShapeDtypeStruct((rows_pad, _LANES), jnp.float32)],
         interpret=interpret,
+        name="dstpu_adam",
     )(gf, mf, vf, pf, c1.reshape(1, 1), c2.reshape(1, 1), lr_.reshape(1, 1))
     u = u.reshape(-1)[:n].reshape(shape)
     mo = mo.reshape(-1)[:n].reshape(shape)

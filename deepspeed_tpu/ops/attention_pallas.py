@@ -280,6 +280,7 @@ def _flash_fwd_impl(q, k, v, seg, *, causal: bool, block_q: int,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="dstpu_flash_fwd",
     )(*operands)
     return out, lse
 
@@ -320,6 +321,7 @@ def _flash_bwd_impl(q, k, v, seg, out, lse, do, *, causal, block_q,
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="dstpu_flash_bwd_dq",
     )(*dq_operands, do, lse, delta)
 
     # dk/dv grid runs over KV heads; the inner axis sweeps (group member,
@@ -367,6 +369,7 @@ def _flash_bwd_impl(q, k, v, seg, out, lse, do, *, causal, block_q,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="dstpu_flash_bwd_dkv",
     )(*dkv_operands, do, lse, delta)
     return dq, dk, dv
 

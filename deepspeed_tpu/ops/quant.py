@@ -219,6 +219,7 @@ def quantize_pallas(x: jnp.ndarray, num_groups: int = 1,
         out_shape=[jax.ShapeDtypeStruct((num_groups, gsz), jnp.int8),
                    jax.ShapeDtypeStruct((num_groups, 1), jnp.float32)],
         interpret=interpret,
+        name="dstpu_quant_rows",
     )(g)
     return q.reshape(x.shape), s[:, 0]
 

@@ -97,6 +97,7 @@ def fused_greedy_rows(logits: jnp.ndarray,
         functools.partial(_greedy_kernel, vocab=vp),
         out_shape=jax.ShapeDtypeStruct((b8, _LANES), jnp.int32),
         interpret=interpret,
+        name="dstpu_fused_sample",
     )(x)
     return out[:B, 0]
 

@@ -134,11 +134,12 @@ class MoELayer:
             # when the batch rides both data and expert axes)
             xf = jax.lax.with_sharding_constraint(
                 xf, self.mesh.sharding(P(self.mesh.batch_spec()[0], None)))
-        logits = (xf.astype(jnp.float32) @ gate_w.astype(jnp.float32))
         factor = cfg.capacity_factor if train else cfg.eval_capacity_factor
         cap = capacity(N, cfg.num_experts, cfg.top_k, factor,
                        cfg.min_capacity)
-        gate = top_k_gating(logits, cfg.top_k, cap, rng=rng)
+        with jax.named_scope("moe_router"):
+            logits = (xf.astype(jnp.float32) @ gate_w.astype(jnp.float32))
+            gate = top_k_gating(logits, cfg.top_k, cap, rng=rng)
 
         # dispatch: [N,E,C] x [N,d] -> [E,C,d]; constraining the E dim to the
         # expert axis makes XLA emit the token all-to-all onto ICI.

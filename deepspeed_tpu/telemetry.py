@@ -14,9 +14,20 @@ now flow through:
   implicit ``+Inf``).
 - :meth:`MetricsRegistry.span`: a context manager that records wall
   time into a histogram *and* opens a
-  ``jax.profiler.TraceAnnotation`` (bridging to ``utils/trace.py``), so
-  a host-side phase shows up both as a latency distribution and as a
-  named range in a captured device timeline.
+  ``jax.profiler.TraceAnnotation``, so a host-side phase shows up both
+  as a latency distribution (``<name>_seconds``) and as a range named
+  ``<namespace>/<name>`` in a captured device timeline, on the device's
+  clock.  The span families of the hot paths (one span a phase a step,
+  never one a slot or a token): ``serving_step`` and its children
+  ``serving_admit``, ``serving_prefill``, ``serving_boundary``,
+  ``serving_grow_pages``, ``serving_upload``, ``serving_inputs``,
+  ``serving_dispatch``, ``serving_token_sync``, ``serving_append``,
+  and ``serving_tick`` beside it; ``train_step`` and
+  ``train_align_batch`` in ``engine.train_batch``.
+  ``serving_queue_wait_seconds`` (arrival to admitted) is a plain
+  histogram.  :func:`mark` is the zero-length annotation for an edge
+  that exists once (``dstpu/request_admitted``,
+  ``dstpu/request_first_token``, ``dstpu/xla_compile``).
 - Three sinks: a periodic bridge into the existing
   :class:`~deepspeed_tpu.monitor.MonitorMaster`
   (tensorboard/wandb/csv/comet), a Prometheus text-exposition writer
@@ -210,9 +221,14 @@ _NULL_SPAN = _NullSpan()
 class Span:
     """Wall-time → histogram + ``jax.profiler.TraceAnnotation`` range.
 
-    The annotation makes the host phase visible in captured device
-    timelines next to the XLA ops it overlaps — the bridge between this
-    registry and ``utils/trace.py``'s Tracer captures.
+    The one span type of the program.  The annotation is a TraceMe: a
+    profiler capture (``/profilez``, the benchmark's ``TailTrace``)
+    shows the host phase on the device's clock, next to the XLA
+    operations it overlaps, under the label ``<namespace>/<name>``;
+    the histogram ``<name>_seconds`` carries the same word.  An
+    instance is reusable (not re-entrant): hot paths build theirs once
+    through :meth:`MetricsRegistry.span` and ``with`` it every step, so
+    a step pays no registry lock and no string formatting.
     """
 
     __slots__ = ("_hist", "_label", "_ann", "_t0")
@@ -223,7 +239,7 @@ class Span:
         self._ann = None
 
     def __enter__(self):
-        import jax
+        import jax      # on first use: the disabled path never needs it
 
         self._ann = jax.profiler.TraceAnnotation(self._label)
         self._ann.__enter__()
@@ -234,6 +250,18 @@ class Span:
         self._hist.observe(time.perf_counter() - self._t0)
         self._ann.__exit__(*exc)
         return False
+
+
+def mark(label: str, **kw) -> None:
+    """A zero-length annotation with keyword stats: an edge that exists
+    once (a request admitted, a program compiled), visible in a capture
+    on the same clock as the spans.  Costs nothing measurable when no
+    profiler session is active; callers guard it behind their
+    telemetry bool all the same."""
+    import jax
+
+    with jax.profiler.TraceAnnotation(label, **kw):
+        pass
 
 
 class MetricsRegistry:
@@ -292,19 +320,13 @@ class MetricsRegistry:
     def span(self, name: str, help: str = "",
              buckets: Sequence[float] = LATENCY_BUCKETS_S):
         """Context manager: wall time into ``{name}_seconds`` + a
-        ``TraceAnnotation`` named ``{namespace}/{phase}``, where
-        ``phase`` is ``name`` normalized through the devprof phase
-        vocabulary — so captured device timelines use the same
-        prefill/decode/spec_verify/promote/sample names the
-        ``devprof_device_seconds_*`` counters report under.  The
-        histogram keeps the caller's literal name (metric families are
-        a stable exposition contract)."""
+        ``TraceAnnotation`` named ``{namespace}/{name}``: the caller's
+        word in both places, so a capture and the registry can be read
+        side by side.  Reusable: build it once, ``with`` it per step."""
         if not self.enabled:
             return _NULL_SPAN
-        from deepspeed_tpu.devprof import canonical_phase
-
         h = self.histogram(f"{name}_seconds", help, buckets)
-        return Span(h, f"{self.namespace}/{canonical_phase(name)}")
+        return Span(h, f"{self.namespace}/{name}")
 
     # ----------------------------------------------------------- export
     def snapshot(self) -> Dict[str, Any]:

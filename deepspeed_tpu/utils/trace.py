@@ -1,56 +1,15 @@
-"""Execution tracing (aux subsystem; ref: DeepSpeed's profiling hooks +
-
-``deepspeed.comm`` comms-logger).  TPU-native tracing rides
-``jax.profiler``: captured traces contain per-HLO device timelines
-viewable in TensorBoard/Perfetto — strictly richer than the reference's
-python-level hooks, because the schedule being traced is XLA's real one.
+"""The Python-side collective log (ref: ``deepspeed.comm``'s
+comms-logger).  Spans and device captures live elsewhere:
+``telemetry.Span`` is the program's one span type, ``/profilez``
+(devprof) the operator's capture.
 """
 
 from __future__ import annotations
 
 import contextlib
-import os
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
-
-import jax
-
-
-class Tracer:
-    """start/stop trace capture + named annotation ranges."""
-
-    def __init__(self, log_dir: str = "/tmp/dstpu_trace"):
-        self.log_dir = log_dir
-        self.active = False
-
-    def start(self) -> None:
-        os.makedirs(self.log_dir, exist_ok=True)
-        jax.profiler.start_trace(self.log_dir)
-        self.active = True
-
-    def stop(self) -> None:
-        if self.active:
-            jax.profiler.stop_trace()
-            self.active = False
-
-    @contextlib.contextmanager
-    def trace(self):
-        self.start()
-        try:
-            yield self
-        finally:
-            self.stop()
-
-    @staticmethod
-    def annotate(name: str):
-        """Named range visible in the device timeline."""
-        return jax.profiler.TraceAnnotation(name)
-
-    @staticmethod
-    def step(step_num: int):
-        """Mark one train step (groups HLOs under a step in the viewer)."""
-        return jax.profiler.StepTraceAnnotation("train_step", step_num=step_num)
+from typing import Dict, Tuple
 
 
 class CommsLogger:
@@ -113,34 +72,3 @@ class CommsLogger:
         with self._lock:
             self.records.clear()
             self._totals.clear()
-
-
-_global_tracer: Optional[Tracer] = None
-_DEFAULT_LOG_DIR = "/tmp/dstpu_trace"
-
-
-def get_tracer(log_dir: Optional[str] = None) -> Tracer:
-    """Process-wide profiler tracer.
-
-    ``log_dir=None`` means "whatever the singleton already uses".  The
-    old behavior cached the FIRST caller's dir forever and silently
-    ignored every later ``log_dir`` — a second subsystem asking for its
-    own capture directory got a tracer writing somewhere else.  Now an
-    explicit dir re-points the idle singleton; if a capture is ACTIVE
-    the running profiler owns its directory, so the change is refused
-    with a warning instead of being silently dropped."""
-    global _global_tracer
-    if _global_tracer is None:
-        _global_tracer = Tracer(log_dir or _DEFAULT_LOG_DIR)
-    elif log_dir is not None and log_dir != _global_tracer.log_dir:
-        if _global_tracer.active:
-            from deepspeed_tpu.utils.logging import logger
-
-            logger.warning(
-                "get_tracer: capture already active in %s — ignoring "
-                "log_dir=%r until stop() (stop the capture before "
-                "re-pointing the tracer)",
-                _global_tracer.log_dir, log_dir)
-        else:
-            _global_tracer.log_dir = log_dir
-    return _global_tracer

@@ -30,8 +30,7 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 
 from deepspeed_tpu.config import DevprofConfig  # noqa: E402
 from deepspeed_tpu.devprof import (NULL_DEVPROF, PHASES,  # noqa: E402
-                                   CompileLedger, DevProf,
-                                   canonical_phase)
+                                   CompileLedger, DevProf)
 from deepspeed_tpu.telemetry import MetricsRegistry  # noqa: E402
 
 
@@ -217,21 +216,13 @@ class TestRoofline:
 
 # ----------------------------------------------------- phase vocabulary
 class TestPhaseVocabulary:
-    def test_canonical_phase(self):
-        for p in PHASES:
-            assert canonical_phase(p) == p
-        assert canonical_phase("decode_chunk") == "decode"
-        assert canonical_phase("chunk_prefill") == "prefill"
-        assert canonical_phase("kv_promote") == "promote"
-        assert canonical_phase("unknown_name") == "unknown_name"
-
-    def test_span_normalizes_annotation_not_metric(self):
+    def test_span_keeps_the_callers_word_everywhere(self):
         r = MetricsRegistry(namespace="dstpu")
         span = r.span("decode_chunk", "help")
-        # the metric family keeps the literal name (stable exposition
-        # contract); the TraceAnnotation label is canonical
+        # one word: the histogram family and the TraceAnnotation label
+        # a capture shows are both the caller's literal name
         assert "decode_chunk_seconds" in r.snapshot()["histograms"]
-        assert span._label == "dstpu/decode"
+        assert span._label == "dstpu/decode_chunk"
 
 
 # ------------------------------------------------------- incident probe

@@ -1,0 +1,292 @@
+"""The names the program gives what runs (ISSUE 24): host spans inside
+``step()`` and ``train_batch`` on the profiler's clock, named scopes in
+every lowered program, a ``name=`` on every Mosaic kernel."""
+
+import functools
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import deepspeed_tpu
+from benchmark.harness import scopes, trace
+from deepspeed_tpu.inference.kernels import PagedKVCache
+from deepspeed_tpu.inference.serving import serving_engine
+from deepspeed_tpu.models import gpt2, mixtral
+
+CHILDREN = ("admit", "prefill", "boundary", "grow_pages", "upload",
+            "inputs", "dispatch", "token_sync", "append")
+ENGINE_KW = dict(max_batch=2, page_size=8, num_pages=32, max_seq=64,
+                 prefill_bucket=8)
+PROMPTS = {"a": ([5, 9, 2], 6), "b": ([17, 3, 3, 8, 1], 5),
+           "c": ([40, 2], 7)}
+
+
+def _gpt2():
+    cfg = gpt2.GPT2Config.tiny(dim=64, n_layers=2, n_heads=4,
+                               max_seq_len=64)
+    return cfg, gpt2.init_params(jax.random.PRNGKey(0), cfg), gpt2
+
+
+def _mixtral():
+    cfg = mixtral.MixtralConfig.tiny()
+    return cfg, mixtral.init_params(jax.random.PRNGKey(0), cfg), mixtral
+
+
+MODELS = {"gpt2": _gpt2, "mixtral": _mixtral}
+
+
+def _serve(telemetry):
+    cfg, params, _ = _gpt2()
+    eng = serving_engine(params, cfg, telemetry=telemetry, **ENGINE_KW)
+    for rid, (p, n) in PROMPTS.items():
+        eng.submit(rid, p, max_new_tokens=n)
+    return eng
+
+
+def _trainer():
+    cfg, params, mod = _gpt2()
+    engine, *_ = deepspeed_tpu.initialize(
+        loss_fn=mod.loss_fn(cfg), params=params,
+        config={"train_batch_size": 8,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+                "gradient_clipping": 1.0,
+                "zero_optimization": {"stage": 1}})
+    batch = {"tokens": np.arange(8 * 17, dtype=np.int32).reshape(8, 17)
+             % cfg.vocab_size}
+    return engine, batch
+
+
+# ---------------------------------------------- (a) spans in a capture
+@pytest.fixture(scope="module")
+def capture(tmp_path_factory):
+    """One CPU capture, three stretches marked by ``bench/`` spans: a
+    toy engine with telemetry, the same without, two training steps.
+    Everything is compiled before the profiler starts."""
+    on, off = _serve(True), _serve(False)
+    trainer, batch = _trainer()
+    for eng in (on, off):
+        eng.step()
+    trainer.train_batch(batch)
+    logdir = str(tmp_path_factory.mktemp("capture"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(logdir, profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("bench/on"):
+            steps = 0
+            while on.has_work:
+                on.step()
+                steps += 1
+        with jax.profiler.TraceAnnotation("bench/off"):
+            off.run()
+        with jax.profiler.TraceAnnotation("bench/train"):
+            for _ in range(2):
+                jax.block_until_ready(trainer.train_batch(batch))
+    finally:
+        jax.profiler.stop_trace()
+    scoped = scopes.load(trace.newest_xplane(logdir))
+    return scoped, steps, on
+
+
+def _inside(scoped, outer):
+    box = next(s for s in scoped.spans if s.name == outer)
+    return [s for s in scoped.spans if s.name.startswith("dstpu/")
+            and box.start <= s.start and
+            s.start + s.dur <= box.start + box.dur]
+
+
+def test_serving_spans_nest_in_the_step_and_tile_it(capture):
+    scoped, steps, _ = capture
+    mine = _inside(scoped, "bench/on")
+    per_step = scopes.children(
+        scopes.Scoped({}, {}, mine), "dstpu/serving_step")
+    assert len(per_step) == steps
+    for parent, kids in per_step:
+        names = [k.name for k in kids if "request_" not in k.name]
+        # one span a phase a step: none repeated per slot or per token
+        assert len(names) == len(set(names))
+        assert set(names) <= {f"dstpu/serving_{c}" for c in CHILDREN}
+    seen = {k.name for _, kids in per_step for k in kids}
+    assert {f"dstpu/serving_{c}" for c in CHILDREN} <= seen
+    assert scopes.coverage(scopes.Scoped({}, {}, mine)) >= 0.90
+    # the tick runs beside the step, once a step
+    assert sum(s.name == "dstpu/serving_tick" for s in mine) == steps
+
+
+def test_step_phases_add_up_to_the_step(capture):
+    scoped, steps, _ = capture
+    mine = scopes.Scoped({}, {}, _inside(scoped, "bench/on"))
+    kids = [f"dstpu/serving_{c}" for c in CHILDREN]
+    phases = scopes.step_phases(mine, kids)
+    whole = [p.dur for p, _ in scopes.children(mine)]
+    assert len(phases) == steps
+    assert sum(phases) == pytest.approx(sum(whole), rel=0.10)
+    with_tick = scopes.step_phases(mine, ["dstpu/serving_append"],
+                                   beside=["dstpu/serving_tick"])
+    assert all(a >= b for a, b in zip(
+        with_tick, scopes.step_phases(mine, ["dstpu/serving_append"])))
+
+
+def test_a_request_is_marked_once_at_each_edge_under_its_id(capture):
+    scoped, _, on = capture
+    mine = _inside(scoped, "bench/on")
+    for edge in ("request_admitted", "request_first_token"):
+        ids = sorted(s.stats["request_id"] for s in mine
+                     if s.name == f"dstpu/{edge}")
+        # "a" was admitted by the warm-up step, before the capture
+        assert set(ids) <= set(PROMPTS) and len(ids) == len(set(ids)) >= 1
+    hist = on.registry.snapshot()["histograms"]
+    assert hist["serving_queue_wait_seconds"]["count"] == len(PROMPTS)
+    for c in CHILDREN + ("tick", "step"):
+        assert hist[f"serving_{c}_seconds"]["count"] > 0
+
+
+def test_without_telemetry_no_program_span_at_all(capture):
+    scoped, _, _ = capture
+    assert _inside(scoped, "bench/off") == []
+
+
+def test_train_spans(capture):
+    scoped, _, _ = capture
+    names = [s.name for s in _inside(scoped, "bench/train")]
+    assert names.count("dstpu/train_step") == 2
+    assert names.count("dstpu/train_align_batch") == 2
+
+
+# -------------------------------------- (b) scopes in lowered programs
+@functools.lru_cache(maxsize=None)
+def _paged_programs(name):
+    """The lowered prefill, chunk and decode programs of a toy engine,
+    as text with locations."""
+    cfg, params, _ = MODELS[name]()
+    eng = serving_engine(params, cfg, telemetry=False, **ENGINE_KW)
+    absx = lambda x: (jax.ShapeDtypeStruct(x.shape, x.dtype)
+                      if hasattr(x, "shape") else x)
+    tm = jax.tree_util.tree_map
+    params_a, cache_a = tm(absx, eng.params), tm(absx, eng.cache)
+    view_a = tm(absx, eng.cache._replace(
+        table=jnp.zeros((1, eng.max_pages_per_seq), jnp.int32),
+        seq_lens=jnp.zeros((1,), jnp.int32)))
+    B, K = eng.max_batch, eng.decode_chunk
+    keys = jax.random.split(jax.random.PRNGKey(0), K * B).reshape(K, B, -1)
+    lowered = {
+        "prefill": eng._prefill.lower(
+            params_a, jax.ShapeDtypeStruct((1, 8), jnp.int32), view_a),
+        "chunk": eng._chunk_prefill.lower(
+            params_a, jax.ShapeDtypeStruct((B, 8), jnp.int32), cache_a),
+        "decode": eng._decode_chunk_fn.lower(
+            params_a, jax.ShapeDtypeStruct((B, 1), jnp.int32), cache_a,
+            absx(keys), jax.ShapeDtypeStruct((B,), jnp.float32)),
+    }
+    assert isinstance(eng.cache, PagedKVCache)
+    return {k: v.as_text(debug_info=True) for k, v in lowered.items()}
+
+
+BLOCKS = {"gpt2": {"embed", "attn_qkv", "attn_out", "mlp", "final_norm",
+                   "lm_head", "kv_write"},
+          "mixtral": {"embed", "attn_qkv", "attn_out", "mlp", "moe_router",
+                      "moe_ffn", "final_norm", "lm_head", "kv_write"}}
+PHASE = {"prefill": {"flash"}, "chunk": {"kv_attend"},
+         "decode": {"kv_attend", "sample"}}
+
+
+def _words(text):
+    """Vocabulary words that appear as a component of a location path."""
+    found = set()
+    for path in re.findall(r'loc\("([^"]*)"', text):
+        found.update(w for w in scopes.WORD.findall(path)
+                     if w in scopes.VOCABULARY)
+    return found
+
+
+@pytest.mark.parametrize("program", ["prefill", "chunk", "decode"])
+@pytest.mark.parametrize("name", ["gpt2", "mixtral"])
+def test_paged_programs_carry_every_scope_that_applies(name, program):
+    text = _paged_programs(name)[program]
+    want = BLOCKS[name] | PHASE[program]
+    assert want <= _words(text), want - _words(text)
+    assert f"dstpu_{program}" in text          # the program's own name
+
+
+@pytest.mark.parametrize("name", ["gpt2", "mixtral"])
+def test_train_step_carries_model_and_step_scopes(name):
+    cfg, params, mod = MODELS[name]()
+    engine, *_ = deepspeed_tpu.initialize(
+        loss_fn=mod.loss_fn(cfg), params=params, has_aux=name == "mixtral",
+        config={"train_batch_size": 8, "gradient_clipping": 1.0,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})
+    lowered = engine.lower_step({"tokens": np.zeros((8, 17), np.int32)})
+    text = lowered.as_text(debug_info=True)
+    want = (BLOCKS[name] - {"kv_write"}) | {"flash", "loss", "grad_clip",
+                                            "optimizer"}
+    assert want <= _words(text), want - _words(text)
+    assert "dstpu_train_step" in text
+    if name == "gpt2":
+        # what a trace carries is the compiled program's op_name: the
+        # backward pass shows as the same words under a transpose
+        paths = set(re.findall(r'op_name="([^"]*)"',
+                               lowered.compile().as_text()))
+        marks = {scopes.scope_of(p) for p in paths}
+        assert {("attn_qkv", False), ("attn_qkv", True),
+                ("optimizer", False)} <= marks
+        assert ("optimizer", True) not in marks
+
+
+def _pallas_names(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            info = eqn.params.get("name_and_src_info")
+            out.append(getattr(info, "name", None) or eqn.params.get("name"))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_names(sub, out)
+    return out
+
+
+def test_every_mosaic_kernel_has_a_name_of_its_own():
+    from deepspeed_tpu.inference import kernels as K
+    from deepspeed_tpu.ops import (adam_pallas, attention_pallas, quant,
+                                   sampling_pallas)
+
+    q = jnp.zeros((1, 128, 2, 128), jnp.float32)
+    flash = lambda q: attention_pallas.flash_attention_tpu(
+        q, q, q, interpret=True).sum()
+    pages = jnp.zeros((2, 9, 8, 128), jnp.float32)
+    table = jnp.zeros((2, 4), jnp.int32)
+    start = jnp.zeros((2,), jnp.int32)
+    qc = jnp.zeros((2, 8, 2, 128), jnp.float32)
+    codes = jnp.zeros((2, 9, 8, 128), jnp.int8)
+    scale = jnp.ones((2, 9, 8, 1), jnp.float32)
+    sites = {
+        "dstpu_flash_fwd": lambda: jax.make_jaxpr(flash)(q),
+        "dstpu_flash_bwd_dq": lambda: jax.make_jaxpr(jax.grad(flash))(q),
+        "dstpu_flash_bwd_dkv": lambda: jax.make_jaxpr(jax.grad(flash))(q),
+        "dstpu_paged_chunk_v1": lambda: jax.make_jaxpr(
+            lambda: K.paged_chunk_attention(qc, pages, pages, table, start,
+                                            interpret=True))(),
+        "dstpu_paged_chunk_v2": lambda: jax.make_jaxpr(
+            lambda: K.paged_chunk_attention_v2(
+                qc, pages, pages, table, start, interpret=True))(),
+        "dstpu_paged_chunk_v2_q8": lambda: jax.make_jaxpr(
+            lambda: K.paged_chunk_attention_v2_quant(
+                qc, codes, scale, codes, scale, table, start,
+                interpret=True))(),
+        "dstpu_fused_sample": lambda: jax.make_jaxpr(
+            lambda: sampling_pallas.fused_greedy_rows(
+                jnp.zeros((8, 256), jnp.float32), interpret=True))(),
+    }
+    for want, make in sites.items():
+        names = _pallas_names(make().jaxpr, [])
+        assert want in names, (want, names)
+    # the sources give nine sites nine names, none shared
+    named = []
+    for mod in (K, adam_pallas, attention_pallas, quant, sampling_pallas):
+        with open(mod.__file__) as f:
+            text = f.read()
+        assert text.count("pl.pallas_call(") == text.count('name="dstpu_')
+        named += re.findall(r'name="(dstpu_[a-z0-9_]+)"', text)
+    assert len(named) == len(set(named)) == 9

@@ -354,28 +354,3 @@ time.sleep(60)      # never pets again: the simulated hung collective
         dumps = [p for p in os.listdir(tmp_path)
                  if p.startswith("flight_exception")]
         assert dumps
-
-
-class TestGetTracerDirFix:
-    def test_changed_dir_honored_when_idle(self, monkeypatch):
-        import deepspeed_tpu.utils.trace as ut
-
-        monkeypatch.setattr(ut, "_global_tracer", None)
-        t1 = ut.get_tracer("/tmp/dstpu_trace_a")
-        assert t1.log_dir == "/tmp/dstpu_trace_a"
-        # the old bug: this silently returned a tracer aimed at _a
-        t2 = ut.get_tracer("/tmp/dstpu_trace_b")
-        assert t2 is t1
-        assert t2.log_dir == "/tmp/dstpu_trace_b"
-        # no dir argument: keep whatever the singleton uses
-        assert ut.get_tracer().log_dir == "/tmp/dstpu_trace_b"
-
-    def test_active_capture_refuses_repoint(self, monkeypatch):
-        import deepspeed_tpu.utils.trace as ut
-
-        monkeypatch.setattr(ut, "_global_tracer", None)
-        t1 = ut.get_tracer("/tmp/dstpu_trace_c")
-        t1.active = True                   # simulate a live capture
-        t2 = ut.get_tracer("/tmp/dstpu_trace_d")
-        assert t2.log_dir == "/tmp/dstpu_trace_c"   # warned, unchanged
-        t1.active = False
