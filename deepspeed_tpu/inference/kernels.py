@@ -3,8 +3,9 @@ inference — the decode attention kernels behind init_inference's kernel
 injection, which read a preallocated KV workspace; paging per vLLM-style
 block tables is the modern equivalent contract).
 
-TPU design: KV lives in fixed-size **pages** [KV, num_pages, page_size,
-Dh]; each sequence owns a list of page ids (the page table).  Decode
+TPU design: KV lives in fixed-size **pages**, one pool [L, KV, num_pages,
+page_size, Dh] for all layers that the serving programs update in place;
+each sequence owns a list of page ids (the page table).  Decode
 attention is HBM-bandwidth-bound, so the pallas kernel streams exactly
 the live pages of each sequence: the page table is a **scalar-prefetch**
 operand and the K/V BlockSpec index maps dereference it, so the grid's
@@ -89,11 +90,9 @@ class PagedKVCache(NamedTuple):
         except (jax.errors.TracerArrayConversionError,
                 jax.errors.ConcretizationTypeError):
             pass  # traced: bounded by the caller's decode-loop length
-        k_l, v_l = write_token_pages(self.k[layer], self.v[layer],
-                                     new_k, new_v, self.table, pos,
-                                     self.page_size)
-        return self._replace(k=self.k.at[layer].set(k_l),
-                             v=self.v.at[layer].set(v_l))
+        k, v = write_token_pages(self.k, self.v, layer, new_k, new_v,
+                                 self.table, pos)
+        return self._replace(k=k, v=v)
 
     def bump(self) -> "PagedKVCache":
         return self._replace(seq_lens=self.seq_lens + 1)
@@ -356,84 +355,90 @@ class PageAllocator:
                 self.free.append(p)
 
 
-# ----------------------------------------------- per-layer page writers
-# (scan-friendly: operate on ONE layer's pages [KV, P, ps, Dh] with a
-# static page_size, so models can lax.scan over the layer axis)
-def write_token_pages(pages_k, pages_v, new_k, new_v, table, seq_lens,
-                      page_size: int):
-    """Append one token's K/V ([B, KV, Dh]) at each sequence frontier.
-
-    One vectorized scatter over the batch (no per-b unroll — decode B can
-    be large under continuous batching).  A sequence at capacity writes
-    its *existing* value back (no-op) instead of clamping onto the last
-    live slot, so overflow never corrupts attention (advisor finding r1).
-    """
+# ------------------------------------------------ in-place page writers
+# The pool [L, KV, P, ps, Dh] is a CARRY of the models' layer loop, and a
+# writer scatters only the new rows into it at a (possibly traced)
+# ``layer``.  The token and chunk writers scatter windows of ONE Dh row
+# (index arrays over kv head, page, row) and the whole-prompt writer
+# windows of one page: both suit the pool's stored layout, so the
+# compiled program updates it in place and holds no pool- or layer-sized
+# copy (tests/test_aot_tpu_compile.py pins that for the described v5e).
+# A [KV, Dh] window (one token across heads) does not: the TPU compiler
+# re-lays the whole pool out around such a scatter.  Under TP the pool is
+# sharded on KV and GSPMD partitions the row scatter by that index (it
+# gathers the few new rows, never the pool).
+def _row_targets(pool, table, pos):
+    """Page id and row within the page of every position in ``pos``
+    ([B] or [B, C]) of the rows ``table`` describes.  A position at or
+    past a row's capacity gets the out-of-range page id ``num_pages``,
+    which the scatter drops (free: no gather/blend on the hot path), so
+    overflow never lands on a live slot (advisor finding r1)."""
+    num_pages, page_size = pool.shape[2], pool.shape[3]
     max_pages = table.shape[1]
-    num_pages = pages_k.shape[1]
-    capacity = max_pages * page_size
-    valid = seq_lens < capacity                              # [B]
-    page_slot = jnp.minimum(seq_lens // page_size, max_pages - 1)
-    in_page = seq_lens % page_size
-    page_id = jnp.take_along_axis(table, page_slot[:, None], axis=1)[:, 0]
-    # overflow → point the scatter out of range and drop it (free: no
-    # gather/blend on the hot path, the scatter itself skips the write)
-    page_id = jnp.where(valid, page_id, num_pages)
-
-    def upd(store, new):
-        # store: [KV, P, ps, Dh]; new: [B, KV, Dh] → scatter [KV, B, Dh]
-        vals = new.transpose(1, 0, 2).astype(store.dtype)
-        return store.at[:, page_id, in_page].set(vals, mode="drop")
-
-    return upd(pages_k, new_k), upd(pages_v, new_v)
+    pos2 = pos.reshape(pos.shape[0], -1)
+    page_slot = jnp.minimum(pos2 // page_size, max_pages - 1)
+    page_id = jnp.take_along_axis(table, page_slot, axis=1)
+    page_id = jnp.where(pos2 < max_pages * page_size, page_id, num_pages)
+    return page_id.reshape(-1), (pos2 % page_size).reshape(-1)
 
 
-def write_prompt_pages(pages_k, pages_v, new_k, new_v, table,
-                       page_size: int):
-    """Bulk-write a fresh prompt's K/V ([B, T, KV, Dh]) into pages,
-    starting at position 0 (prefill of an empty cache)."""
-    B, T, KV, Dh = new_k.shape
+def _scatter_rows(pool, layer, page_id, in_page, rows):
+    """``pool[layer, h, page_id[n], in_page[n]] = rows[n, h]`` for every
+    kv head h.  pool: [L, KV, P, ps, D]; page_id/in_page: [N]; rows:
+    [N, KV, D].  One vectorized scatter (no per-row unroll: decode B can
+    be large under continuous batching)."""
+    kv = jnp.arange(pool.shape[1], dtype=jnp.int32)[None, :]
+    return pool.at[layer, kv, page_id[:, None], in_page[:, None]].set(
+        rows.astype(pool.dtype), mode="drop")
+
+
+def _scatter_pages(pool, layer, table, new, fill=0):
+    """Whole pages of a fresh prompt: ``new`` [B, T, KV, D] from position
+    0 into the first ceil(T / ps) pages of each table row, the tail of
+    the last page padded with ``fill``."""
+    B, T, KV, D = new.shape
+    page_size = pool.shape[3]
     np_used = -(-T // page_size)
     pad = np_used * page_size - T
-
-    def upd(store, new):
-        if pad:
-            new = jnp.concatenate(
-                [new, jnp.zeros((B, pad, KV, Dh), new.dtype)], axis=1)
-        # [B, np, ps, KV, Dh] → [KV, B*np, ps, Dh]
-        blocks = new.reshape(B, np_used, page_size, KV, Dh) \
-            .transpose(3, 0, 1, 2, 4).reshape(KV, B * np_used,
-                                              page_size, Dh)
-        ids = table[:, :np_used].reshape(-1)            # [B*np]
-        return store.at[:, ids].set(blocks.astype(store.dtype))
-
-    return upd(pages_k, new_k), upd(pages_v, new_v)
+    if pad:
+        new = jnp.concatenate(
+            [new, jnp.full((B, pad, KV, D), fill, new.dtype)], axis=1)
+    # [B, np, ps, KV, D] → [B*np, KV, ps, D]
+    blocks = new.reshape(B, np_used, page_size, KV, D) \
+        .transpose(0, 1, 3, 2, 4).reshape(B * np_used, KV, page_size, D)
+    ids = table[:, :np_used].reshape(-1)                # [B*np]
+    return pool.at[layer, :, ids].set(blocks.astype(pool.dtype))
 
 
-def write_chunk_pages(pages_k, pages_v, new_k, new_v, table, start,
-                      page_size: int):
+def write_token_pages(pool_k, pool_v, layer, new_k, new_v, table,
+                      seq_lens):
+    """Append one token's K/V ([B, KV, Dh]) at each sequence frontier of
+    ``layer``; a sequence at capacity writes nothing."""
+    page_id, in_page = _row_targets(pool_k, table, seq_lens)
+    return (_scatter_rows(pool_k, layer, page_id, in_page, new_k),
+            _scatter_rows(pool_v, layer, page_id, in_page, new_v))
+
+
+def write_prompt_pages(pool_k, pool_v, layer, new_k, new_v, table):
+    """Bulk-write a fresh prompt's K/V ([B, T, KV, Dh]) into pages,
+    starting at position 0 (prefill of an empty cache)."""
+    return (_scatter_pages(pool_k, layer, table, new_k),
+            _scatter_pages(pool_v, layer, table, new_v))
+
+
+def write_chunk_pages(pool_k, pool_v, layer, new_k, new_v, table, start):
     """Write a mid-sequence chunk's K/V ([B, C, KV, Dh]) at each row's
     frontier ``start`` ([B] i32) — the chunked-prefill generalization of
     :func:`write_prompt_pages` (arbitrary, per-row, non-page-aligned
-    offsets) built from the :func:`write_token_pages` scatter, vectorized
-    over the chunk axis.  Positions past a row's capacity are dropped."""
+    offsets): the :func:`write_token_pages` scatter over B*C rows.
+    Positions past a row's capacity are dropped."""
     B, C, KV, Dh = new_k.shape
-    max_pages = table.shape[1]
-    num_pages = pages_k.shape[1]
-    capacity = max_pages * page_size
     pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]   # [B, C]
-    valid = pos < capacity
-    page_slot = jnp.minimum(pos // page_size, max_pages - 1)
-    in_page = pos % page_size
-    page_id = jnp.take_along_axis(table, page_slot, axis=1)       # [B, C]
-    page_id = jnp.where(valid, page_id, num_pages)  # out-of-range → drop
-
-    def upd(store, new):
-        # store: [KV, P, ps, Dh]; new: [B, C, KV, Dh] → [KV, B*C, Dh]
-        vals = new.transpose(2, 0, 1, 3).reshape(KV, B * C, Dh)
-        return store.at[:, page_id.reshape(-1), in_page.reshape(-1)].set(
-            vals.astype(store.dtype), mode="drop")
-
-    return upd(pages_k, new_k), upd(pages_v, new_v)
+    page_id, in_page = _row_targets(pool_k, table, pos)
+    return (_scatter_rows(pool_k, layer, page_id, in_page,
+                          new_k.reshape(B * C, KV, Dh)),
+            _scatter_rows(pool_v, layer, page_id, in_page,
+                          new_v.reshape(B * C, KV, Dh)))
 
 
 # ------------------------------------------- int8-resident page helpers
@@ -464,116 +469,129 @@ def dequantize_pages(codes, scales, dtype):
     return (codes.astype(jnp.float32) * scales).astype(dtype)
 
 
-def write_token_pages_quant(pages_k, pages_ks, pages_v, pages_vs,
-                            new_k, new_v, table, seq_lens,
-                            page_size: int):
+def write_token_pages_quant(pool_k, pool_ks, pool_v, pool_vs, layer,
+                            new_k, new_v, table, seq_lens):
     """:func:`write_token_pages` for the int8-resident store: quantize
     the appended rows on device and scatter codes + scales with the
     same frontier/overflow-drop math.  Scale stores are
-    ``[KV, P, ps, 1]`` f32."""
-    max_pages = table.shape[1]
-    num_pages = pages_k.shape[1]
-    capacity = max_pages * page_size
-    valid = seq_lens < capacity
-    page_slot = jnp.minimum(seq_lens // page_size, max_pages - 1)
-    in_page = seq_lens % page_size
-    page_id = jnp.take_along_axis(table, page_slot[:, None], axis=1)[:, 0]
-    page_id = jnp.where(valid, page_id, num_pages)
+    ``[L, KV, P, ps, 1]`` f32."""
+    page_id, in_page = _row_targets(pool_k, table, seq_lens)
 
     def upd(store, sstore, new):
         codes, scale = quantize_kv_rows(new)          # [B, KV, Dh/1]
-        return (store.at[:, page_id, in_page].set(
-                    codes.transpose(1, 0, 2), mode="drop"),
-                sstore.at[:, page_id, in_page].set(
-                    scale.transpose(1, 0, 2), mode="drop"))
+        return (_scatter_rows(store, layer, page_id, in_page, codes),
+                _scatter_rows(sstore, layer, page_id, in_page, scale))
 
-    pk, pks = upd(pages_k, pages_ks, new_k)
-    pv, pvs = upd(pages_v, pages_vs, new_v)
+    pk, pks = upd(pool_k, pool_ks, new_k)
+    pv, pvs = upd(pool_v, pool_vs, new_v)
     return pk, pks, pv, pvs
 
 
-def write_prompt_pages_quant(pages_k, pages_ks, pages_v, pages_vs,
-                             new_k, new_v, table, page_size: int):
+def write_prompt_pages_quant(pool_k, pool_ks, pool_v, pool_vs, layer,
+                             new_k, new_v, table):
     """:func:`write_prompt_pages` for the int8-resident store (prefill
     of an empty cache, quantizing per token row)."""
-    B, T, KV, Dh = new_k.shape
-    np_used = -(-T // page_size)
-    pad = np_used * page_size - T
-
     def upd(store, sstore, new):
         codes, scale = quantize_kv_rows(new)     # [B,T,KV,Dh], [B,T,KV,1]
-        if pad:
-            codes = jnp.concatenate(
-                [codes, jnp.zeros((B, pad, KV, Dh), codes.dtype)], axis=1)
-            # zero rows carry scale 1.0 by the codec's convention
-            scale = jnp.concatenate(
-                [scale, jnp.ones((B, pad, KV, 1), scale.dtype)], axis=1)
-        ids = table[:, :np_used].reshape(-1)
+        # zero rows carry scale 1.0 by the codec's convention
+        return (_scatter_pages(store, layer, table, codes),
+                _scatter_pages(sstore, layer, table, scale, fill=1))
 
-        def blocks(x, d):
-            return x.reshape(B, np_used, page_size, KV, d) \
-                .transpose(3, 0, 1, 2, 4).reshape(KV, B * np_used,
-                                                  page_size, d)
-
-        return (store.at[:, ids].set(blocks(codes, Dh)),
-                sstore.at[:, ids].set(blocks(scale, 1)))
-
-    pk, pks = upd(pages_k, pages_ks, new_k)
-    pv, pvs = upd(pages_v, pages_vs, new_v)
+    pk, pks = upd(pool_k, pool_ks, new_k)
+    pv, pvs = upd(pool_v, pool_vs, new_v)
     return pk, pks, pv, pvs
 
 
-def write_chunk_pages_quant(pages_k, pages_ks, pages_v, pages_vs,
-                            new_k, new_v, table, start, page_size: int):
+def write_chunk_pages_quant(pool_k, pool_ks, pool_v, pool_vs, layer,
+                            new_k, new_v, table, start):
     """:func:`write_chunk_pages` for the int8-resident store (split-fuse
     continuation chunks at per-row frontiers)."""
     B, C, KV, Dh = new_k.shape
-    max_pages = table.shape[1]
-    num_pages = pages_k.shape[1]
-    capacity = max_pages * page_size
     pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
-    valid = pos < capacity
-    page_slot = jnp.minimum(pos // page_size, max_pages - 1)
-    in_page = pos % page_size
-    page_id = jnp.take_along_axis(table, page_slot, axis=1)
-    page_id = jnp.where(valid, page_id, num_pages)
+    page_id, in_page = _row_targets(pool_k, table, pos)
 
     def upd(store, sstore, new):
         codes, scale = quantize_kv_rows(new)
-        cvals = codes.transpose(2, 0, 1, 3).reshape(KV, B * C, Dh)
-        svals = scale.transpose(2, 0, 1, 3).reshape(KV, B * C, 1)
-        ids, ip = page_id.reshape(-1), in_page.reshape(-1)
-        return (store.at[:, ids, ip].set(cvals, mode="drop"),
-                sstore.at[:, ids, ip].set(svals, mode="drop"))
+        return (_scatter_rows(store, layer, page_id, in_page,
+                              codes.reshape(B * C, KV, Dh)),
+                _scatter_rows(sstore, layer, page_id, in_page,
+                              scale.reshape(B * C, KV, 1)))
 
-    pk, pks = upd(pages_k, pages_ks, new_k)
-    pv, pvs = upd(pages_v, pages_vs, new_v)
+    pk, pks = upd(pool_k, pool_ks, new_k)
+    pv, pvs = upd(pool_v, pool_vs, new_v)
     return pk, pks, pv, pvs
 
 
 # -------------------------------------------------------- numerics oracle
+# Every reader below takes the whole pool [L, KV, P, ps, Dh] and a
+# (possibly traced) ``layer``, or one layer's pages [KV, P, ps, Dh] — a
+# pool of one layer, read at layer 0.
+def _as_pool(layer, *pages):
+    if pages[0].ndim == 4:
+        return (0, *(p if p is None else p[None] for p in pages))
+    return (layer, *pages)
+
+
+def _layer_operand(layer):
+    """``layer`` as a scalar-prefetch operand of the Mosaic kernels."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _gather_rows(pages, layer, table, scales=None, dtype=None):
+    """The rows ``table`` [B, mp] names in ``layer``, as [B, KV, mp*ps,
+    Dh]: ONE gather with the layer index in it, so no layer-sized slice
+    of the pool is materialised first.  ``scales`` (int8-resident
+    pages): dequantize the gathered rows to ``dtype`` — elementwise, so
+    the bits are those of dequantizing the whole layer first."""
+    layer, pages, scales = _as_pool(layer, pages, scales)
+    B, mp = table.shape
+    # indices (layer, page) per table entry; the kv heads stay a window
+    # dim (under TP the pool is sharded on it) and land second: the
+    # result is [B, KV, mp, ps, Dh], a reshape away from what is wanted
+    idx = jnp.stack([jnp.full_like(table, layer), table], axis=-1)
+    dnums = jax.lax.GatherDimensionNumbers(
+        offset_dims=(1, 3, 4), collapsed_slice_dims=(0, 2),
+        start_index_map=(0, 2))
+
+    def rows(pool):
+        # A table names only pages of the pool, so no mode changes a
+        # value.  "fill" is the measured choice: behind its mask the TPU
+        # compiler fuses the callers' f32 converts into their products,
+        # where after a clamped gather it writes the gathered K and V
+        # out again in f32 (GPT-2 1.3B at 28 rows: 37 ms a decode step
+        # against 67, PERF.md 6, PR 25; the AOT test's bound on
+        # temporaries pins it).  Zeros, not NaN, so that a page id out
+        # of range under a masked position could not reach the output.
+        _, KV, _, ps, D = pool.shape
+        return jax.lax.gather(pool, idx, dnums, (1, KV, 1, ps, D),
+                              mode="fill", fill_value=0).reshape(
+            B, KV, mp * ps, D)
+
+    g = rows(pages)
+    return g if scales is None else dequantize_pages(g, rows(scales), dtype)
+
+
 def paged_chunk_attention_reference(q, k_pages, v_pages, table, start,
-                                    scale: Optional[float] = None):
+                                    scale: Optional[float] = None, *,
+                                    layer=None, k_scale=None, v_scale=None):
     """Chunked-prefill attention: q [B, C, H, Dh] at positions
     ``start + 0..C-1`` attends causally over the gathered pages (which
     must already contain the chunk's own K/V).  Returns [B, C, H, Dh].
 
     This is the split-fuse read path: history + chunk in one masked
     gather, so a long prompt can be absorbed ``C`` tokens per iteration
-    between decode steps."""
+    between decode steps.  ``k_scale``/``v_scale``: the pages are int8
+    codes, dequantized to q's dtype after the gather."""
     B, C, H, Dh = q.shape
-    KV, _, ps, _ = k_pages.shape
+    kg = _gather_rows(k_pages, layer, table, k_scale, q.dtype)
+    vg = _gather_rows(v_pages, layer, table, v_scale, q.dtype)
+    KV, S = kg.shape[1], kg.shape[2]
     G = H // KV
     scale = scale if scale is not None else Dh ** -0.5
-    mp = table.shape[1]
-    kg = k_pages[:, table].transpose(1, 0, 2, 3, 4).reshape(
-        B, KV, mp * ps, Dh)
-    vg = v_pages[:, table].transpose(1, 0, 2, 3, 4).reshape(
-        B, KV, mp * ps, Dh)
     qg = q.reshape(B, C, KV, G, Dh)
     s = jnp.einsum("bckgd,bksd->bckgs", qg.astype(jnp.float32),
                    kg.astype(jnp.float32)) * scale
-    kpos = jnp.arange(mp * ps)[None, None]                  # [1, 1, S]
+    kpos = jnp.arange(S)[None, None]                        # [1, 1, S]
     qpos = (start[:, None] + jnp.arange(C)[None])[:, :, None]  # [B, C, 1]
     s = jnp.where((kpos <= qpos)[:, :, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
@@ -582,22 +600,21 @@ def paged_chunk_attention_reference(q, k_pages, v_pages, table, start,
 
 
 def paged_attention_reference(q, k_pages, v_pages, table, seq_lens,
-                              scale: Optional[float] = None):
-    """q: [B, H, Dh]; k/v_pages: [KV, P, ps, Dh]; table: [B, max_pages];
-    seq_lens: [B]. Returns [B, H, Dh]."""
+                              scale: Optional[float] = None, *,
+                              layer=None, k_scale=None, v_scale=None):
+    """q: [B, H, Dh]; k/v_pages: the pool and ``layer``, or one layer's
+    [KV, P, ps, Dh]; table: [B, max_pages]; seq_lens: [B].  Returns
+    [B, H, Dh]."""
     B, H, Dh = q.shape
-    KV, _, ps, _ = k_pages.shape
+    kg = _gather_rows(k_pages, layer, table, k_scale, q.dtype)
+    vg = _gather_rows(v_pages, layer, table, v_scale, q.dtype)
+    KV, S = kg.shape[1], kg.shape[2]
     G = H // KV
     scale = scale if scale is not None else Dh ** -0.5
-    kg = k_pages[:, table]                     # [KV, B, mp, ps, Dh]
-    vg = v_pages[:, table]
-    mp = table.shape[1]
-    kg = kg.transpose(1, 0, 2, 3, 4).reshape(B, KV, mp * ps, Dh)
-    vg = vg.transpose(1, 0, 2, 3, 4).reshape(B, KV, mp * ps, Dh)
     qg = q.reshape(B, KV, G, Dh)
     s = jnp.einsum("bkgd,bksd->bkgs", qg.astype(jnp.float32),
                    kg.astype(jnp.float32)) * scale
-    valid = jnp.arange(mp * ps)[None] < seq_lens[:, None]   # [B, S]
+    valid = jnp.arange(S)[None] < seq_lens[:, None]         # [B, S]
     s = jnp.where(valid[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgs,bksd->bkgd", p, vg.astype(jnp.float32))
@@ -609,10 +626,11 @@ def paged_attention_reference(q, k_pages, v_pages, table, seq_lens,
 # ------------------------------------------------------------ pallas kernel
 def paged_decode_attention(q, k_pages, v_pages, table, seq_lens,
                            scale: Optional[float] = None,
-                           interpret: bool = False):
+                           interpret: bool = False, layer=None):
     """Pallas paged decode attention; same contract as the reference fn.
 
-    q: [B, H, Dh] (one decode step), k/v_pages: [KV, P, ps, Dh].
+    q: [B, H, Dh] (one decode step), k/v_pages: the pool and ``layer``,
+    or one layer's [KV, P, ps, Dh].
 
     Decode IS the C=1 chunked-prefill case — the query sits at position
     ``seq_lens - 1`` and attends ``kpos <= seq_lens - 1`` — so one kernel
@@ -623,18 +641,19 @@ def paged_decode_attention(q, k_pages, v_pages, table, seq_lens,
     """
     return paged_chunk_attention(
         q[:, None], k_pages, v_pages, table, seq_lens - 1, scale=scale,
-        interpret=interpret)[:, 0]
+        interpret=interpret, layer=layer)[:, 0]
 
 
 # --------------------------------------- multi-page-per-step decode kernel
 def paged_decode_attention_v2(q, k_pages, v_pages, table, seq_lens,
                               scale: Optional[float] = None,
                               pages_per_block: int = 8,
-                              interpret: bool = False):
+                              interpret: bool = False, layer=None):
     """Multi-page-per-step paged decode attention (same contract as
     :func:`paged_attention_reference` / :func:`paged_decode_attention`).
 
-    q: [B, H, Dh] (one decode step), k/v_pages: [KV, P, ps, Dh],
+    q: [B, H, Dh] (one decode step), k/v_pages: the pool and ``layer``
+    or one layer's [KV, P, ps, Dh],
     table: [B, mp] int32, seq_lens: [B] int32.  Pages live in HBM
     (``pl.ANY``) and are DMA-streamed ``pages_per_block`` at a time per
     (batch, kv_head) grid step with double buffering; only live pages
@@ -649,13 +668,14 @@ def paged_decode_attention_v2(q, k_pages, v_pages, table, seq_lens,
     accumulator/DMA fix lands exactly once."""
     return paged_chunk_attention_v2(
         q[:, None], k_pages, v_pages, table, seq_lens - 1, scale=scale,
-        pages_per_block=pages_per_block, interpret=interpret)[:, 0]
+        pages_per_block=pages_per_block, interpret=interpret,
+        layer=layer)[:, 0]
 
 
 # ----------------------------------- multi-page chunked-prefill kernel (v2)
-def _chunk_v2_kernel(table_ref, start_ref, q_ref, k_hbm, v_hbm, o_ref, *,
-                     scale, ps, kv_heads, max_pages, cg8, group, chunk,
-                     ppcb):
+def _chunk_v2_kernel(table_ref, start_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                     o_ref, *, scale, ps, kv_heads, max_pages, cg8, group,
+                     chunk, ppcb):
     """The multi-page v2 kernel (decode shares it:
     :func:`paged_decode_attention_v2` delegates here as the C=1 chunked
     case — there is no separate decode kernel): one grid step
@@ -663,11 +683,14 @@ def _chunk_v2_kernel(table_ref, start_ref, q_ref, k_hbm, v_hbm, o_ref, *,
     double-buffered VMEM scratch, and the page sweep stops at the last
     page holding any position ``<= start + C - 1`` (history + chunk),
     so pages past the frontier are never read.  Rows are the flattened
-    [C*G] chunk queries; row r sits at position start + r // G."""
+    [C*G] chunk queries; row r sits at position start + r // G.  K/V are
+    the whole pool [L, KV, P, ps, Dh] in HBM; ``layer_ref[0]`` names the
+    layer whose pages the DMAs read, so no layer is sliced out first."""
     bk = pl.program_id(0)
     b = bk // kv_heads
     h = bk % kv_heads
     start = start_ref[b]
+    layer = layer_ref[0]
     live = start + chunk                            # positions 0..live-1
     pages_live = (live + ps - 1) // ps
     nch = (pages_live + ppcb - 1) // ppcb
@@ -680,11 +703,11 @@ def _chunk_v2_kernel(table_ref, start_ref, q_ref, k_hbm, v_hbm, o_ref, *,
                 psafe = jnp.minimum(p, max_pages - 1)
                 pid = jnp.where(p < pages_live, table_ref[b, psafe], 0)
                 dmas.append(pltpu.make_async_copy(
-                    k_hbm.at[h, pid], kb.at[slot, pl.ds(j * ps, ps), :],
-                    sem.at[slot, 0]))
+                    k_hbm.at[layer, h, pid],
+                    kb.at[slot, pl.ds(j * ps, ps), :], sem.at[slot, 0]))
                 dmas.append(pltpu.make_async_copy(
-                    v_hbm.at[h, pid], vb.at[slot, pl.ds(j * ps, ps), :],
-                    sem.at[slot, 1]))
+                    v_hbm.at[layer, h, pid],
+                    vb.at[slot, pl.ds(j * ps, ps), :], sem.at[slot, 1]))
             return dmas
 
         @pl.when(nch > 0)
@@ -748,13 +771,14 @@ def _chunk_v2_kernel(table_ref, start_ref, q_ref, k_hbm, v_hbm, o_ref, *,
 def paged_chunk_attention_v2(q, k_pages, v_pages, table, start,
                              scale: Optional[float] = None,
                              pages_per_block: int = 8,
-                             interpret: bool = False):
+                             interpret: bool = False, layer=None):
     """Multi-page chunked-prefill attention — same contract as
     :func:`paged_chunk_attention_reference`, built like
     :func:`paged_decode_attention_v2` (HBM-resident pages, explicit
     double-buffered DMA, live-pages-only sweep)."""
     B, C, H, Dh = q.shape
-    KV, P, ps, _ = k_pages.shape
+    layer, k_pages, v_pages = _as_pool(layer, k_pages, v_pages)
+    _, KV, P, ps, _ = k_pages.shape
     G = H // KV
     mp = table.shape[1]
     scale = scale if scale is not None else Dh ** -0.5
@@ -774,28 +798,27 @@ def paged_chunk_attention_v2(q, k_pages, v_pages, table, start,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,   # table, start
+            num_scalar_prefetch=3,   # table, start, layer
             grid=(B * KV,),
             in_specs=[
-                pl.BlockSpec((1, cg8, Dh), lambda bk, tbl, st: (bk, 0, 0)),
+                pl.BlockSpec((1, cg8, Dh), lambda bk, *_: (bk, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec(
-                (1, cg8, Dh), lambda bk, tbl, st: (bk, 0, 0)),
+            out_specs=pl.BlockSpec((1, cg8, Dh), lambda bk, *_: (bk, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((B * KV, cg8, Dh), q.dtype),
         interpret=interpret,
         name="dstpu_paged_chunk_v2",
-    )(table, start, qg, k_pages, v_pages)
+    )(table, start, _layer_operand(layer), qg, k_pages, v_pages)
     out = out[:, :CG].reshape(B, KV, C, G, Dh).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, C, H, Dh)
 
 
 # ------------------------- int8-dequant-fused multi-page chunked kernel
-def _chunk_v2_quant_kernel(table_ref, start_ref, q_ref, kq_hbm, ks_hbm,
-                           vq_hbm, vs_hbm, o_ref, *, scale, ps, kv_heads,
-                           max_pages, cg8, group, chunk, ppcb):
+def _chunk_v2_quant_kernel(table_ref, start_ref, layer_ref, q_ref, kq_hbm,
+                           ks_hbm, vq_hbm, vs_hbm, o_ref, *, scale, ps,
+                           kv_heads, max_pages, cg8, group, chunk, ppcb):
     """:func:`_chunk_v2_kernel` over int8-resident pages: per page the
     DMA streams the int8 codes AND the per-token-row f32 scales
     (``[ps, 1]`` — the same (N, 1) VMEM layout the v1 kernel's m/l
@@ -807,6 +830,7 @@ def _chunk_v2_quant_kernel(table_ref, start_ref, q_ref, kq_hbm, ks_hbm,
     b = bk // kv_heads
     h = bk % kv_heads
     start = start_ref[b]
+    layer = layer_ref[0]
     live = start + chunk
     pages_live = (live + ps - 1) // ps
     nch = (pages_live + ppcb - 1) // ppcb
@@ -819,16 +843,16 @@ def _chunk_v2_quant_kernel(table_ref, start_ref, q_ref, kq_hbm, ks_hbm,
                 psafe = jnp.minimum(p, max_pages - 1)
                 pid = jnp.where(p < pages_live, table_ref[b, psafe], 0)
                 dmas.append(pltpu.make_async_copy(
-                    kq_hbm.at[h, pid],
+                    kq_hbm.at[layer, h, pid],
                     kqb.at[slot, pl.ds(j * ps, ps), :], sem.at[slot, 0]))
                 dmas.append(pltpu.make_async_copy(
-                    ks_hbm.at[h, pid],
+                    ks_hbm.at[layer, h, pid],
                     ksb.at[slot, pl.ds(j * ps, ps), :], sem.at[slot, 1]))
                 dmas.append(pltpu.make_async_copy(
-                    vq_hbm.at[h, pid],
+                    vq_hbm.at[layer, h, pid],
                     vqb.at[slot, pl.ds(j * ps, ps), :], sem.at[slot, 2]))
                 dmas.append(pltpu.make_async_copy(
-                    vs_hbm.at[h, pid],
+                    vs_hbm.at[layer, h, pid],
                     vsb.at[slot, pl.ds(j * ps, ps), :], sem.at[slot, 3]))
             return dmas
 
@@ -893,16 +917,18 @@ def paged_chunk_attention_v2_quant(q, kq_pages, ks_pages, vq_pages,
                                    vs_pages, table, start,
                                    scale: Optional[float] = None,
                                    pages_per_block: int = 8,
-                                   interpret: bool = False):
+                                   interpret: bool = False, layer=None):
     """Int8-dequant-fused chunked-prefill attention: same contract as
     :func:`paged_chunk_attention_reference` over
     ``dequantize_pages(kq, ks) / (vq, vs)``, but the dequant happens in
     VMEM inside the page sweep — the ~2x-smaller int8 pages are what
     crosses HBM.  ``kq/vq_pages``: int8 ``[KV, P, ps, Dh]``;
     ``ks/vs_pages``: f32 ``[KV, P, ps, 1]`` per-token-row scales (the
-    ``kv_tier.quantize_page`` codec)."""
+    ``kv_tier.quantize_page`` codec); or the four pools and ``layer``."""
     B, C, H, Dh = q.shape
-    KV, P, ps, _ = kq_pages.shape
+    layer, kq_pages, ks_pages, vq_pages, vs_pages = _as_pool(
+        layer, kq_pages, ks_pages, vq_pages, vs_pages)
+    _, KV, P, ps, _ = kq_pages.shape
     G = H // KV
     mp = table.shape[1]
     scale = scale if scale is not None else Dh ** -0.5
@@ -922,22 +948,22 @@ def paged_chunk_attention_v2_quant(q, kq_pages, ks_pages, vq_pages,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,   # table, start
+            num_scalar_prefetch=3,   # table, start, layer
             grid=(B * KV,),
             in_specs=[
-                pl.BlockSpec((1, cg8, Dh), lambda bk, tbl, st: (bk, 0, 0)),
+                pl.BlockSpec((1, cg8, Dh), lambda bk, *_: (bk, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec(
-                (1, cg8, Dh), lambda bk, tbl, st: (bk, 0, 0)),
+            out_specs=pl.BlockSpec((1, cg8, Dh), lambda bk, *_: (bk, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((B * KV, cg8, Dh), q.dtype),
         interpret=interpret,
         name="dstpu_paged_chunk_v2_q8",
-    )(table, start, qg, kq_pages, ks_pages, vq_pages, vs_pages)
+    )(table, start, _layer_operand(layer), qg, kq_pages, ks_pages,
+      vq_pages, vs_pages)
     out = out[:, :CG].reshape(B, KV, C, G, Dh).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, C, H, Dh)
 
@@ -947,17 +973,17 @@ def paged_decode_attention_v2_quant(q, kq_pages, ks_pages, vq_pages,
                                     vs_pages, table, seq_lens,
                                     scale: Optional[float] = None,
                                     pages_per_block: int = 8,
-                                    interpret: bool = False):
+                                    interpret: bool = False, layer=None):
     """Int8-dequant-fused paged decode attention — the C=1 chunked case,
     exactly as :func:`paged_decode_attention_v2` delegates."""
     return paged_chunk_attention_v2_quant(
         q[:, None], kq_pages, ks_pages, vq_pages, vs_pages, table,
         seq_lens - 1, scale=scale, pages_per_block=pages_per_block,
-        interpret=interpret)[:, 0]
+        interpret=interpret, layer=layer)[:, 0]
 
 
 # ------------------------------------------- pallas chunked-prefill kernel
-def _chunk_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
+def _chunk_kernel(table_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, scale, page_size, kv_heads,
                   max_pages, group, chunk):
     """Chunk rows are flattened [C*G, Dh]; row r is query position
@@ -1005,7 +1031,7 @@ def _chunk_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_chunk_attention(q, k_pages, v_pages, table, start,
                           scale: Optional[float] = None,
-                          interpret: bool = False):
+                          interpret: bool = False, layer=None):
     """Pallas chunked-prefill attention — same contract as
     :func:`paged_chunk_attention_reference` but streaming pages through
     the DMA engine instead of materializing the gather.
@@ -1017,7 +1043,8 @@ def paged_chunk_attention(q, k_pages, v_pages, table, start,
     default (the decode kernel's measured policy applies meanwhile).
     """
     B, C, H, Dh = q.shape
-    KV, P, ps, _ = k_pages.shape
+    layer, k_pages, v_pages = _as_pool(layer, k_pages, v_pages)
+    L, KV, P, ps, _ = k_pages.shape
     G = H // KV
     mp = table.shape[1]
     scale = scale if scale is not None else Dh ** -0.5
@@ -1033,24 +1060,23 @@ def paged_chunk_attention(q, k_pages, v_pages, table, start,
         _chunk_kernel, scale=scale, page_size=ps, kv_heads=KV,
         max_pages=mp, group=G, chunk=C)
 
-    def kv_map(bk, p, tbl, lens):
+    def kv_map(bk, p, tbl, lens, lyr):
         b = bk // KV
         pid = jnp.where(p * ps < lens[b] + C, tbl[b, p], 0)
-        return ((bk % KV) * P + pid, 0, 0)
+        return ((lyr[0] * KV + bk % KV) * P + pid, 0, 0)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,   # table, start
+            num_scalar_prefetch=3,   # table, start, layer
             grid=(B * KV, mp),
             in_specs=[
-                pl.BlockSpec((1, CG + pad, Dh),
-                             lambda bk, p, tbl, lens: (bk, 0, 0)),
+                pl.BlockSpec((1, CG + pad, Dh), lambda bk, *_: (bk, 0, 0)),
                 pl.BlockSpec((1, ps, Dh), kv_map),
                 pl.BlockSpec((1, ps, Dh), kv_map),
             ],
             out_specs=pl.BlockSpec(
-                (1, CG + pad, Dh), lambda bk, p, tbl, lens: (bk, 0, 0)),
+                (1, CG + pad, Dh), lambda bk, *_: (bk, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((CG + pad, 1), jnp.float32),
                 pltpu.VMEM((CG + pad, 1), jnp.float32),
@@ -1060,8 +1086,9 @@ def paged_chunk_attention(q, k_pages, v_pages, table, start,
         out_shape=jax.ShapeDtypeStruct((B * KV, CG + pad, Dh), q.dtype),
         interpret=interpret,
         name="dstpu_paged_chunk_v1",
-    )(table, start, qg, k_pages.reshape(KV * P, ps, Dh),
-      v_pages.reshape(KV * P, ps, Dh))
+    )(table, start, _layer_operand(layer), qg,
+      k_pages.reshape(L * KV * P, ps, Dh),
+      v_pages.reshape(L * KV * P, ps, Dh))
     out = out[:, :CG].reshape(B, KV, C, G, Dh).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, C, H, Dh)
 
@@ -1258,16 +1285,22 @@ def resolve_serving_kernels(kernels=None, *, tp: bool = False,
         env_overrides=tuple(env_overrides), fallbacks=tuple(fallbacks))
 
 
-def paged_attention_step(q, k, v, kp, vp, table, start, page_size: int, *,
+def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
                          continuation: bool, prefill: bool,
                          paged_kernel: str,
                          flash_force_reference: bool,
                          interpret: bool = False,
                          kps=None, vps=None):
     """The per-layer paged-attention step every model family shares:
-    page writes + the right attention for the phase.
+    page writes + the right attention for the phase, on the WHOLE pool.
 
-    q: [B, T, H, Dh]; k/v: [B, T, KV, Dh]; kp/vp: one layer's pages.
+    q: [B, T, H, Dh]; k/v: [B, T, KV, Dh]; kp/vp: the pool [L, KV, P,
+    ps, Dh], which the caller's layer loop carries; ``layer``: the index
+    (traced in that loop) of the layer to write and read.  The writers
+    scatter the new rows into the pool and the readers take the layer by
+    its index, so the program updates the pool in place and never holds
+    a copy of it or of one layer (a per-layer store passes ``kp[None]``
+    and layer 0: :func:`~deepspeed_tpu.models.llama.paged_layered_fns`).
     ``paged_kernel`` is the RESOLVED dispatch ("xla" | "pallas_v1" |
     "pallas_v2" — the gate/policy decided before the trace; no env
     reads here).  A forced Pallas kernel with ``interpret=True`` runs
@@ -1275,11 +1308,11 @@ def paged_attention_step(q, k, v, kp, vp, table, start, page_size: int, *,
     CPU identity gates exercise the kernels.  ``kps``/``vps`` non-None
     selects the int8-resident path: kp/vp hold int8 codes, kps/vps the
     per-token-row f32 scales, writes quantize on device, and
-    "pallas_v2" dispatches the dequant-fused kernel ("xla" dequantizes
-    with :func:`dequantize_pages` and runs the references; there is no
-    quantized v1).  Phases: chunked-prefill continuation (split-fuse),
-    whole-prompt prefill (empty cache), or single-token decode.
-    Returns (attn [B, T, H, Dh], kp, vp, kps, vps)."""
+    "pallas_v2" dispatches the dequant-fused kernel ("xla" gathers the
+    codes and dequantizes them with :func:`dequantize_pages`; there is
+    no quantized v1).  Phases: chunked-prefill continuation
+    (split-fuse), whole-prompt prefill (empty cache), or single-token
+    decode.  Returns (attn [B, T, H, Dh], kp, vp, kps, vps)."""
     from deepspeed_tpu.ops.attention import flash_attention
 
     quant = kps is not None
@@ -1293,30 +1326,32 @@ def paged_attention_step(q, k, v, kp, vp, table, start, page_size: int, *,
         if quant:
             with write:
                 kp, kps, vp, vps = write_chunk_pages_quant(
-                    kp, kps, vp, vps, k, v, table, start, page_size)
+                    kp, kps, vp, vps, layer, k, v, table, start)
             with attend:
                 if paged_kernel == "pallas_v2":
                     attn = paged_chunk_attention_v2_quant(
                         q, kp, kps, vp, vps, table, start,
-                        interpret=interpret)
+                        interpret=interpret, layer=layer)
                 else:
                     attn = paged_chunk_attention_reference(
-                        q, dequantize_pages(kp, kps, q.dtype),
-                        dequantize_pages(vp, vps, q.dtype), table, start)
+                        q, kp, vp, table, start, layer=layer,
+                        k_scale=kps, v_scale=vps)
         else:
             with write:
-                kp, vp = write_chunk_pages(kp, vp, k, v, table, start,
-                                           page_size)
+                kp, vp = write_chunk_pages(kp, vp, layer, k, v, table,
+                                           start)
             with attend:
                 if paged_kernel == "pallas_v1":
                     attn = paged_chunk_attention(q, kp, vp, table, start,
-                                                 interpret=interpret)
+                                                 interpret=interpret,
+                                                 layer=layer)
                 elif paged_kernel == "pallas_v2":
                     attn = paged_chunk_attention_v2(
-                        q, kp, vp, table, start, interpret=interpret)
+                        q, kp, vp, table, start, interpret=interpret,
+                        layer=layer)
                 else:
                     attn = paged_chunk_attention_reference(
-                        q, kp, vp, table, start)
+                        q, kp, vp, table, start, layer=layer)
     elif prefill:
         with jax.named_scope("flash"):
             attn = flash_attention(q, k, v, causal=True,
@@ -1324,43 +1359,64 @@ def paged_attention_step(q, k, v, kp, vp, table, start, page_size: int, *,
         with write:
             if quant:
                 kp, kps, vp, vps = write_prompt_pages_quant(
-                    kp, kps, vp, vps, k, v, table, page_size)
+                    kp, kps, vp, vps, layer, k, v, table)
             else:
-                kp, vp = write_prompt_pages(kp, vp, k, v, table,
-                                            page_size)
+                kp, vp = write_prompt_pages(kp, vp, layer, k, v, table)
     else:
         if quant:
             with write:
                 kp, kps, vp, vps = write_token_pages_quant(
-                    kp, kps, vp, vps, k[:, 0], v[:, 0], table, start,
-                    page_size)
+                    kp, kps, vp, vps, layer, k[:, 0], v[:, 0], table,
+                    start)
             with attend:
                 if paged_kernel == "pallas_v2":
                     attn = paged_decode_attention_v2_quant(
                         q[:, 0], kp, kps, vp, vps, table, start + 1,
-                        interpret=interpret)[:, None]
+                        interpret=interpret, layer=layer)[:, None]
                 else:
                     attn = paged_attention_reference(
-                        q[:, 0], dequantize_pages(kp, kps, q.dtype),
-                        dequantize_pages(vp, vps, q.dtype), table,
-                        start + 1)[:, None]
+                        q[:, 0], kp, vp, table, start + 1, layer=layer,
+                        k_scale=kps, v_scale=vps)[:, None]
         else:
             with write:
-                kp, vp = write_token_pages(kp, vp, k[:, 0], v[:, 0],
-                                           table, start, page_size)
+                kp, vp = write_token_pages(kp, vp, layer, k[:, 0],
+                                           v[:, 0], table, start)
             with attend:
                 if paged_kernel == "pallas_v1":
                     attn = paged_decode_attention(
                         q[:, 0], kp, vp, table, start + 1,
-                        interpret=interpret)[:, None]
+                        interpret=interpret, layer=layer)[:, None]
                 elif paged_kernel == "pallas_v2":
                     attn = paged_decode_attention_v2(
                         q[:, 0], kp, vp, table, start + 1,
-                        interpret=interpret)[:, None]
+                        interpret=interpret, layer=layer)[:, None]
                 else:
                     attn = paged_attention_reference(
-                        q[:, 0], kp, vp, table, start + 1)[:, None]
+                        q[:, 0], kp, vp, table, start + 1,
+                        layer=layer)[:, None]
     return attn, kp, vp, kps, vps
+
+
+def paged_layer_loop(block, x, blocks, cache: PagedKVCache):
+    """Run a model's layers over the paged cache with the pool as a CARRY.
+
+    ``block(x, lp, layer, kp, vp, kps, vps) -> (x, kp, vp, kps, vps)``
+    is one layer (``kps``/``vps`` are None unless the cache is
+    int8-resident); ``blocks`` the stacked layer params.  The scan runs
+    over (params, layer index) and carries the activations with the
+    pool, so every layer updates the SAME buffers: as a scanned input and
+    stacked output the pool would be two buffers, and each layer would
+    slice its pages out of one and copy them whole into the other.
+    Returns (x, cache) with the new pool; ``seq_lens`` is the caller's."""
+    def body(carry, layer):
+        x, pools = carry
+        x, *pools = block(x, *layer, *pools)
+        return (x, tuple(pools)), None
+
+    (x, (k, v, ks, vs)), _ = jax.lax.scan(
+        body, (x, (cache.k, cache.v, cache.k_scale, cache.v_scale)),
+        (blocks, jnp.arange(cache.k.shape[0], dtype=jnp.int32)))
+    return x, cache._replace(k=k, v=v, k_scale=ks, v_scale=vs)
 
 
 def paged_forward_prelude(cache, tokens, interpret, tp,

@@ -25,7 +25,10 @@ Static-shape tricks worth noting:
 - inactive slots' table rows point at a reserved TRASH page: the decode
   step structurally writes a token for every row, and aiming dead rows
   at a sacrificial page keeps them from corrupting live sequences.
-- the decode jit donates the cache, so pages update in place in HBM.
+- every serving jit donates the cache, and inside the program the pool
+  is a carry of the layer loop that the page writers scatter rows
+  into (inference/kernels.py), so pages update in place in HBM: no
+  program holds a pool- or layer-sized copy.
 
 Automatic prefix caching (``prefix_cache=`` / the config block): the
 page allocator is a refcounted, content-addressed pool — full pages are

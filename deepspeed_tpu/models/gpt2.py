@@ -229,6 +229,7 @@ def forward_paged(params, tokens, cfg: GPT2Config, cache,
     host discards it (the engine bounds real positions by max_seq)."""
     from deepspeed_tpu.inference.kernels import (paged_attention_step,
                                                  paged_forward_prelude,
+                                                 paged_layer_loop,
                                                  pallas_paged_gate)
 
     B, T = tokens.shape
@@ -243,37 +244,21 @@ def forward_paged(params, tokens, cfg: GPT2Config, cache,
     with jax.named_scope("embed"):
         x = params["wte"][tokens] + params["wpe"][positions]
 
-    quant = cache.k_scale is not None
     if paged_kernel in (None, "auto"):
         paged_kernel = ("pallas_v2" if pallas_paged_gate(
             B, nh, hd, ps, cache.table.shape[1], cache.k.dtype.itemsize,
             interpret, tp) else "xla")
 
-    def block(x, layer):
-        if quant:
-            lp, kp, vp, kps, vps = layer
-        else:
-            lp, kp, vp = layer
-            kps = vps = None
+    def block(x, lp, layer, kp, vp, kps, vps):
         q, k, v = _qkv(cfg, x, lp)
         attn, kp, vp, kps, vps = paged_attention_step(
-            q, k, v, kp, vp, cache.table, start, ps,
+            q, k, v, kp, vp, layer, cache.table, start,
             continuation=continuation, prefill=prefill,
             paged_kernel=paged_kernel, flash_force_reference=tp,
             interpret=interpret, kps=kps, vps=vps)
         return (_out_mlp(cfg, x, attn.reshape(B, T, d), lp),
-                (kp, vp, kps, vps) if quant else (kp, vp))
+                kp, vp, kps, vps)
 
-    if quant:
-        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-            block, x, (params["blocks"], cache.k, cache.v,
-                       cache.k_scale, cache.v_scale))
-    else:
-        x, (new_k, new_v) = jax.lax.scan(
-            block, x, (params["blocks"], cache.k, cache.v))
-        new_ks = new_vs = None
+    x, cache = paged_layer_loop(block, x, params["blocks"], cache)
     logits = _head(params, x, cfg)
-    cache = cache._replace(k=new_k, v=new_v, seq_lens=start + T)
-    if quant:
-        cache = cache._replace(k_scale=new_ks, v_scale=new_vs)
-    return logits, cache
+    return logits, cache._replace(seq_lens=start + T)

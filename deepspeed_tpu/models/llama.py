@@ -446,6 +446,7 @@ def forward_paged(params, tokens, cfg: LlamaConfig, cache,
     """
     from deepspeed_tpu.inference.kernels import (paged_attention_step,
                                                  paged_forward_prelude,
+                                                 paged_layer_loop,
                                                  pallas_paged_gate)
 
     B, T = tokens.shape
@@ -459,40 +460,25 @@ def forward_paged(params, tokens, cfg: LlamaConfig, cache,
         positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
         cos, sin = rope_tables(cfg, positions)
 
-    quant = cache.k_scale is not None      # int8-resident KV (static)
     if paged_kernel in (None, "auto"):
         # no engine policy passed: the shape-measured auto gate decides
         paged_kernel = "pallas_v2" if pallas_paged_gate(
             B, nkv, hd, ps, cache.table.shape[1],
             cache.k.dtype.itemsize, interpret, tp_active) else "xla"
 
-    def block(x, layer):
-        if quant:
-            lp, kp, vp, kps, vps = layer
-        else:
-            lp, kp, vp = layer
-            kps = vps = None
+    def block(x, lp, layer, kp, vp, kps, vps):
         q, k, v = _qkv(cfg, x, lp, cos, sin)
         attn, kp, vp, kps, vps = paged_attention_step(
-            q, k, v, kp, vp, cache.table, start, ps,
+            q, k, v, kp, vp, layer, cache.table, start,
             continuation=continuation, prefill=prefill,
             paged_kernel=paged_kernel, flash_force_reference=tp_active,
             interpret=interpret, kps=kps, vps=vps)
         x = _out_ffn(cfg, x, attn.reshape(B, T, nh * hd), lp, ffn=ffn)
-        return x, ((kp, vp, kps, vps) if quant else (kp, vp))
+        return x, kp, vp, kps, vps
 
-    if quant:
-        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-            block, x, (params["blocks"], cache.k, cache.v,
-                       cache.k_scale, cache.v_scale))
-    else:
-        x, (new_k, new_v) = jax.lax.scan(
-            block, x, (params["blocks"], cache.k, cache.v))
-        new_ks, new_vs = cache.k_scale, cache.v_scale
+    x, cache = paged_layer_loop(block, x, params["blocks"], cache)
     logits = _head(params, x, cfg)
-    cache = cache._replace(k=new_k, v=new_v, seq_lens=start + T,
-                           k_scale=new_ks, v_scale=new_vs)
-    return logits, cache
+    return logits, cache._replace(seq_lens=start + T)
 
 
 def paged_layered_fns(cfg: LlamaConfig, tp: bool = False, ffn=None,
@@ -510,7 +496,8 @@ def paged_layered_fns(cfg: LlamaConfig, tp: bool = False, ffn=None,
                  *, continuation, prefill)      -> (x, kp, vp)
         head_fn(head, x)                        -> logits [B, T, V] f32
 
-    ``kp``/``vp`` are ONE layer's pages [KV, P, ps, Dh].  Every param
+    ``kp``/``vp`` are ONE layer's pages [KV, P, ps, Dh] (to
+    ``paged_attention_step`` a pool of one layer).  Every param
     tree may carry int8 :class:`~deepspeed_tpu.inference.quantized.
     QuantizedTensor` leaves — the dequant is traced into each per-layer
     program, exactly as the whole-model quantized forward fuses it.  The
@@ -547,12 +534,13 @@ def paged_layered_fns(cfg: LlamaConfig, tp: bool = False, ffn=None,
                 itp, tp) else "xla"
         else:
             pk = paged_kernel
+        # one layer's pages are a pool of one layer, written at layer 0
         attn, kp, vp, _, _ = paged_attention_step(
-            q, k, v, kp, vp, table, start, ps,
+            q, k, v, kp[None], vp[None], 0, table, start,
             continuation=continuation, prefill=prefill,
             paged_kernel=pk, flash_force_reference=tp, interpret=itp)
         x = _out_ffn(cfg, x, attn.reshape(B, T, nh * hd), lp, ffn=ffn)
-        return x, kp, vp
+        return x, kp[0], vp[0]
 
     def head_fn(hp, x):
         return _head(dequantize_params(hp), x, cfg)

@@ -9,7 +9,10 @@ GQA layout (32 query / 8 KV heads of 128, T 2048).  Nothing executes;
 results are the interpret-mode tests' job.
 """
 
+import dataclasses
+import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -19,6 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from deepspeed_tpu.inference import kernels as K
+from deepspeed_tpu.models import gpt2, mixtral
 from deepspeed_tpu.ops.attention_pallas import flash_attention_tpu
 from deepspeed_tpu.ops.sampling_pallas import fused_greedy_rows
 
@@ -154,3 +158,131 @@ def test_quant_resident_policy_on_chip(paged, want):
     assert K.resolve_serving_kernels(
         {"paged_attention": "pallas_v2"}, interpret=True,
         quantized_resident=True).paged_attention == "pallas_v2"
+
+
+# ------------------------------------------- the K/V pool stays in place
+# The serving programs at the benchmark's widths, four layers deep:
+# (family, config, pool pages, decode rows, paged kernel).
+POOLS = {
+    "gpt2_1_3b": (gpt2, lambda: dataclasses.replace(
+        gpt2.GPT2Config.gpt2_1_3b(), n_layers=4), 1793, 28, "xla"),
+    "mixtral_chat": (mixtral, lambda: dataclasses.replace(
+        mixtral.MixtralConfig.mixtral_8x7b(), n_layers=4), 4097, 64,
+        "pallas_v2"),
+}
+# phase -> (rows, tokens, continuation); None rows = the decode batch.
+# Prefill and chunk run one row at a time, as the engine dispatches them.
+PHASES = {"decode": (None, 1, False), "prefill": (1, 256, False),
+          "chunk": (1, 128, True)}
+_NOT_OPS = {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
+            "call", "conditional"}
+
+
+def _pool_sized_ops(hlo, pool_shape):
+    """Instructions of the optimized HLO, fusion bodies aside, whose
+    result has the element count of the pool or of one layer of it and
+    is not the in-place scatter (or its fusion) or a Mosaic call."""
+    sizes = {math.prod(pool_shape), math.prod(pool_shape[1:])}
+    bodies, cur = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if m:
+            cur = bodies.setdefault(m.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    called = lambda line: re.search(r"calls=%([\w.\-]+)", line).group(1)
+    fusion_bodies = {called(l) for ls in bodies.values() for l in ls
+                     if " fusion(" in l}
+    found = []
+    for comp, lines in bodies.items():
+        if comp in fusion_bodies:
+            continue
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) "
+                         r"([a-z][\w\-]*)\(", line)
+            if not m or m.group(3) in _NOT_OPS:
+                continue
+            name, result, op = m.groups()
+            counts = {math.prod(int(d) for d in dims.split(",") if d)
+                      for dims in re.findall(r"[a-z]\w*\[([\d,]*)\]", result)}
+            if not counts & sizes:
+                continue
+            if op == "scatter" or "tpu_custom_call" in line or (
+                    op == "fusion" and any(" scatter(" in l
+                                           for l in bodies[called(line)])):
+                continue
+            found.append(f"{name} = {result.split('{')[0]} {op}")
+    return found
+
+
+def test_pool_sized_ops_reads_the_old_shape_of_the_loop():
+    """The reader itself, on the operations the scan-over-the-pool loop
+    compiled to (PERF.md 5, PR 24) and on what may stay."""
+    hlo = """
+%fused_computation.6 (p: bf16[2,4,9,8,16]) -> bf16[2,4,9,8,16] {
+  %p = bf16[2,4,9,8,16]{4,3,2,1,0} parameter(0)
+  ROOT %scatter.1 = bf16[2,4,9,8,16]{4,3,2,1,0} scatter(%p, %i, %u)
+}
+%fused_computation.7 (p: bf16[2,4,9,8,16]) -> bf16[4,9,8,16] {
+  %p.1 = bf16[2,4,9,8,16]{4,3,2,1,0} parameter(0)
+  ROOT %ds = bf16[4,9,8,16]{3,0,2,1} dynamic-slice(%p.1, %l)
+}
+%body (c: (bf16[2,4,9,8,16])) -> (bf16[2,4,9,8,16]) {
+  %g = bf16[2,4,9,8,16]{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%c), index=0
+  %fusion.1 = bf16[2,4,9,8,16]{4,3,2,1,0:T(8,128)(2,1)} fusion(%g), kind=kCustom, calls=%fused_computation.6
+  %copy_bitcast_fusion.4 = bf16[4,9,8,16]{3,0,2,1:T(8,128)(2,1)} fusion(%g), kind=kLoop, calls=%fused_computation.7
+  %att = bf16[64,8,128]{2,1,0} custom-call(%q, %fusion.1), custom_call_target="tpu_custom_call"
+  ROOT %t = (bf16[2,4,9,8,16]{4,3,2,1,0}) tuple(%fusion.1)
+}
+ENTRY %main (k: bf16[2,4,9,8,16]) -> bf16[2,4,9,8,16] {
+  %k = bf16[2,4,9,8,16]{4,3,2,1,0} parameter(0)
+  %w = (bf16[2,4,9,8,16]{4,3,2,1,0}) while(%k), condition=%cond, body=%body
+  ROOT %copy.54 = bf16[2,4,9,8,16]{4,3,2,1,0} copy(%k)
+}
+"""
+    assert _pool_sized_ops(hlo, (2, 4, 9, 8, 16)) == [
+        "copy_bitcast_fusion.4 = bf16[4,9,8,16] fusion",
+        "copy.54 = bf16[2,4,9,8,16] copy"]
+
+
+@pytest.mark.parametrize("phase", PHASES)
+@pytest.mark.parametrize("pool", POOLS)
+def test_serving_program_leaves_the_pool_in_place(chip, pool, phase):
+    """``forward_paged``'s decode, whole-prompt prefill and chunk
+    programs hold no copy of the K/V pool or of one layer of it: the
+    pool is a carry of the layer loop, the writers scatter rows into it
+    and the readers take a layer by its index.  The bound on temporaries
+    also holds the XLA gather to bf16: after a clamped gather the
+    compiler writes the gathered K and V out in f32, 0.44 GiB at GPT-2's
+    28 rows (``kernels._gather_rows``)."""
+    family, make_cfg, pages, batch, paged_kernel = POOLS[pool]
+    rows, T, continuation = PHASES[phase]
+    rows = rows or batch
+    cfg = make_cfg()
+    shape = (cfg.n_layers, cfg.n_kv_heads, pages, PAGE, DH)
+    on_chip = lambda tree: jax.tree.map(       # page_size stays an int
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+        if hasattr(x, "shape") else x, tree)
+    params = jax.eval_shape(lambda: family.init_params(
+        jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16))
+    kv = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    cache = K.PagedKVCache(
+        k=kv, v=kv, table=jax.ShapeDtypeStruct((rows, 1024 // PAGE),
+                                               jnp.int32),
+        seq_lens=jax.ShapeDtypeStruct((rows,), jnp.int32), page_size=PAGE)
+
+    def program(params, tokens, cache):
+        logits, cache = family.forward_paged(
+            params, tokens, cfg, cache, interpret=False, tp=False,
+            continuation=continuation, paged_kernel=paged_kernel)
+        return logits[:, -1], cache
+
+    compiled = jax.jit(program, donate_argnums=(2,)).lower(*on_chip((
+        params, jax.ShapeDtypeStruct((rows, T), jnp.int32),
+        cache))).compile()
+    hlo = compiled.as_text()
+    if paged_kernel == "pallas_v2" and phase != "prefill":
+        assert "tpu_custom_call" in hlo
+    assert _pool_sized_ops(hlo, shape) == []
+    pool_bytes = 2 * math.prod(shape) * 2               # K and V, bf16
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 2
