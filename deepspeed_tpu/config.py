@@ -448,12 +448,15 @@ class KernelsConfig:
     folklore).
 
     ``paged_attention`` picks the paged decode/chunk attention
-    implementation: ``auto`` (the shape-measured crossover gate,
-    ``pallas_paged_gate`` — XLA gather below the crossover, the Pallas
-    v2 DMA kernel above it), ``xla`` (always the gather reference
+    implementation: ``auto`` (``kernels.paged_reader``, from the phase
+    and the layout alone, no size threshold: a decode program on one
+    device over float pages reads live pages only through the Mosaic
+    decode kernel at every batch and table width; chunk programs,
+    tensor parallelism, int8-resident pages and CPU/interpret runs take
+    the XLA gather), ``xla`` (always the gather reference
     composition), ``pallas_v1`` (the one-page-per-grid-step kernel,
-    kept for A/B), or ``pallas_v2`` (force the double-buffered DMA
-    kernel).  ``fused_sampling`` picks the boundary/decode sampler:
+    kept for A/B), or ``pallas_v2`` (force the DMA kernels, decode and
+    chunk).  ``fused_sampling`` picks the boundary/decode sampler:
     ``auto`` (crossover gate on batch x vocab), ``off`` (the jitted XLA
     ``_sample_rows``), ``on`` (force the fused Pallas greedy kernel;
     greedy output is bit-exact either way).

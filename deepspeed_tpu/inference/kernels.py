@@ -644,41 +644,186 @@ def paged_decode_attention(q, k_pages, v_pages, table, seq_lens,
         interpret=interpret, layer=layer)[:, 0]
 
 
-# --------------------------------------- multi-page-per-step decode kernel
+# ------------------------------------------- live-pages-only decode kernel
+# One K (or V) block of the decode kernel in VMEM, per buffer slot: big
+# enough that a block's products amortise the loop's fixed cost, small
+# enough that two slots of K and V plus their f32 copies stay far inside
+# the 16 MiB a v5e kernel may use.
+_DECODE_BLOCK_BYTES = 512 << 10
+
+
+def decode_pages_per_block(n_kv: int, page_size: int, head_dim: int,
+                           itemsize: int, max_pages: int) -> int:
+    """Pages the decode kernel streams per inner iteration: what fits
+    ``_DECODE_BLOCK_BYTES`` with every kv head of a page in one copy."""
+    page_bytes = n_kv * page_size * head_dim * itemsize
+    return max(1, min(max_pages, _DECODE_BLOCK_BYTES // page_bytes))
+
+
+def _decode_kernel(table_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                   o_ref, kb, vb, sem, *, scale, ps, max_pages, ppb):
+    """One grid step a batch row.  The row's live pages stream ``ppb`` at
+    a time through a double-buffered VMEM scratch, each page's K (and V)
+    for ALL kv heads in one strided copy out of the stored pool
+    [L, KV, P, ps, Dh] (``k_hbm.at[layer, :, pid]``); the products are
+    batched over the kv heads.  Nothing past the row's ``seq_len`` is
+    dereferenced: a dead page of the last block is zeroed in VMEM, not
+    copied, and a row with ``seq_len == 0`` starts no copy and returns
+    zeros.  Scores, the online softmax and the accumulator are f32."""
+    b = pl.program_id(0)
+    n = lens_ref[b]
+    layer = layer_ref[0]
+    pages_live = (n + ps - 1) // ps
+    nblk = (pages_live + ppb - 1) // ppb
+
+    def page(c, slot, j):
+        """(live, the two copies) of page ``j`` of block ``c``."""
+        p = c * ppb + j
+        pid = table_ref[b, jnp.minimum(p, max_pages - 1)]
+        return p < pages_live, (
+            pltpu.make_async_copy(k_hbm.at[layer, :, pid],
+                                  kb.at[slot, :, j], sem.at[slot, 0]),
+            pltpu.make_async_copy(v_hbm.at[layer, :, pid],
+                                  vb.at[slot, :, j], sem.at[slot, 1]))
+
+    def start(c, slot):
+        def body(j, _):
+            live, copies = page(c, slot, j)
+
+            @pl.when(live)
+            def _():
+                for d in copies:
+                    d.start()
+
+            # stale VMEM under a masked position: its score is masked
+            # whatever K holds, but 0 * V must stay 0
+            @pl.when(jnp.logical_not(live))
+            def _():
+                vb[slot, :, j] = jnp.zeros(
+                    (vb.shape[1], ps, vb.shape[4]), vb.dtype)
+
+        jax.lax.fori_loop(0, ppb, body, None)
+
+    def wait(c, slot):
+        def body(j, _):
+            live, copies = page(c, slot, j)
+
+            @pl.when(live)
+            def _():
+                for d in copies:
+                    d.wait()
+
+        jax.lax.fori_loop(0, ppb, body, None)
+
+    @pl.when(nblk > 0)
+    def _():
+        start(0, 0)
+
+    q = q_ref[0].astype(jnp.float32)                # [KV, g8, Dh]
+    kv, g8, dh = q.shape
+
+    def block(buf, slot):
+        """One slot's pages as [KV, ppb * ps, Dh] in f32: a page is a
+        whole number of tiles, so the merge moves nothing."""
+        return buf[slot].reshape(kv, ppb * ps, dh).astype(jnp.float32)
+
+    def loop(c, carry):
+        m, l, acc = carry
+        slot = jax.lax.rem(c, 2)
+
+        @pl.when(c + 1 < nblk)
+        def _():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        s = jax.lax.dot_general(
+            q, block(kb, slot),
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale     # [KV, g8, S]
+        kpos = c * (ppb * ps) + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 2)
+        s = jnp.where(kpos < n, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        # block 0 always holds position 0 < n, so m_new is finite; the
+        # select keeps a masked entry at 0 whatever a later mask does
+        pr = jnp.where(kpos < n, jnp.exp(s - m_new), 0.0)
+        l = l * alpha + jnp.sum(pr, axis=2, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
+            pr, block(vb, slot),
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)             # [KV, g8, Dh]
+        return m_new, l, acc
+
+    init = (jnp.full((kv, g8, 1), NEG_INF, jnp.float32),
+            jnp.zeros((kv, g8, 1), jnp.float32),
+            jnp.zeros((kv, g8, dh), jnp.float32))
+    m, l, acc = jax.lax.fori_loop(0, nblk, loop, init)
+    l = jnp.where(l == 0.0, 1.0, l)                 # empty rows → zeros
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
 def paged_decode_attention_v2(q, k_pages, v_pages, table, seq_lens,
                               scale: Optional[float] = None,
-                              pages_per_block: int = 8,
+                              pages_per_block: Optional[int] = None,
                               interpret: bool = False, layer=None):
-    """Multi-page-per-step paged decode attention (same contract as
-    :func:`paged_attention_reference` / :func:`paged_decode_attention`).
+    """Paged decode attention that reads live pages only (same contract
+    as :func:`paged_attention_reference`).
 
     q: [B, H, Dh] (one decode step), k/v_pages: the pool and ``layer``
-    or one layer's [KV, P, ps, Dh],
-    table: [B, mp] int32, seq_lens: [B] int32.  Pages live in HBM
-    (``pl.ANY``) and are DMA-streamed ``pages_per_block`` at a time per
-    (batch, kv_head) grid step with double buffering; only live pages
-    are read, and stale table entries past seq_len are never
-    dereferenced.  This is the fix for the measured v1 failure
-    (KERNEL_BENCH r5: one 16-token page per GRID step = B*KV*mp tiny
-    dispatches, 145 ms where the XLA gather runs 5.8 ms).
+    or one layer's [KV, P, ps, Dh], table: [B, mp] int32, seq_lens: [B]
+    int32.  The pool stays in HBM (``pl.ANY``) in its stored layout; see
+    :func:`_decode_kernel`.  Its cost follows the live tokens, not
+    slots x ``max_seq``: table entries past ``seq_len`` (stale, or not a
+    page of the pool) are never dereferenced.  ``pages_per_block`` is
+    derived (:func:`decode_pages_per_block`); tests pass it to put a
+    block edge where they want one."""
+    B, H, Dh = q.shape
+    layer, k_pages, v_pages = _as_pool(layer, k_pages, v_pages)
+    _, KV, _, ps, _ = k_pages.shape
+    G = H // KV
+    mp = table.shape[1]
+    scale = scale if scale is not None else Dh ** -0.5
+    ppb = min(mp, pages_per_block or decode_pages_per_block(
+        KV, ps, Dh, k_pages.dtype.itemsize, mp))
+    g8 = -(-G // 8) * 8                             # sublane alignment
+    qg = q.reshape(B, KV, G, Dh)
+    if g8 != G:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g8 - G), (0, 0)))
 
-    Decode IS the C=1 chunked case (v1 makes the same delegation): the
-    query sits at position ``seq_lens - 1`` and attends
-    ``kpos <= seq_lens - 1``, so ONE kernel serves both paths and any
-    accumulator/DMA fix lands exactly once."""
-    return paged_chunk_attention_v2(
-        q[:, None], k_pages, v_pages, table, seq_lens - 1, scale=scale,
-        pages_per_block=pages_per_block, interpret=interpret,
-        layer=layer)[:, 0]
+    kernel = functools.partial(_decode_kernel, scale=scale, ps=ps,
+                               max_pages=mp, ppb=ppb)
+    row = lambda b, *_: (b, 0, 0, 0)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,   # table, seq_lens, layer
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, KV, g8, Dh), row),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, KV, g8, Dh), row),
+            scratch_shapes=[
+                pltpu.VMEM((2, KV, ppb, ps, Dh), k_pages.dtype),
+                pltpu.VMEM((2, KV, ppb, ps, Dh), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, KV, g8, Dh), q.dtype),
+        interpret=interpret,
+        name="dstpu_paged_decode",
+    )(table, seq_lens, _layer_operand(layer), qg, k_pages, v_pages)
+    return out[:, :, :G].reshape(B, H, Dh)
 
 
 # ----------------------------------- multi-page chunked-prefill kernel (v2)
 def _chunk_v2_kernel(table_ref, start_ref, layer_ref, q_ref, k_hbm, v_hbm,
                      o_ref, *, scale, ps, kv_heads, max_pages, cg8, group,
                      chunk, ppcb):
-    """The multi-page v2 kernel (decode shares it:
-    :func:`paged_decode_attention_v2` delegates here as the C=1 chunked
-    case — there is no separate decode kernel): one grid step
+    """The multi-page v2 chunk kernel (decode has its own,
+    :func:`_decode_kernel`): one grid step
     per (batch, kv_head); K/V pages stream ppcb at a time through a
     double-buffered VMEM scratch, and the page sweep stops at the last
     page holding any position ``<= start + C - 1`` (history + chunk),
@@ -773,9 +918,8 @@ def paged_chunk_attention_v2(q, k_pages, v_pages, table, start,
                              pages_per_block: int = 8,
                              interpret: bool = False, layer=None):
     """Multi-page chunked-prefill attention — same contract as
-    :func:`paged_chunk_attention_reference`, built like
-    :func:`paged_decode_attention_v2` (HBM-resident pages, explicit
-    double-buffered DMA, live-pages-only sweep)."""
+    :func:`paged_chunk_attention_reference` (HBM-resident pages,
+    explicit double-buffered DMA, live-pages-only sweep)."""
     B, C, H, Dh = q.shape
     layer, k_pages, v_pages = _as_pool(layer, k_pages, v_pages)
     _, KV, P, ps, _ = k_pages.shape
@@ -974,8 +1118,9 @@ def paged_decode_attention_v2_quant(q, kq_pages, ks_pages, vq_pages,
                                     scale: Optional[float] = None,
                                     pages_per_block: int = 8,
                                     interpret: bool = False, layer=None):
-    """Int8-dequant-fused paged decode attention — the C=1 chunked case,
-    exactly as :func:`paged_decode_attention_v2` delegates."""
+    """Int8-dequant-fused paged decode attention: the C=1 case of the
+    quantized chunk kernel (the position ``seq_lens - 1`` attends
+    ``kpos <= seq_lens - 1``)."""
     return paged_chunk_attention_v2_quant(
         q[:, None], kq_pages, ks_pages, vq_pages, vs_pages, table,
         seq_lens - 1, scale=scale, pages_per_block=pages_per_block,
@@ -1094,50 +1239,35 @@ def paged_chunk_attention(q, k_pages, v_pages, table, start,
 
 
 # --------------------------------------------- shared per-layer dispatch
-# Crossover for the auto policy: total live-KV bytes (K+V pages a full-
-# occupancy decode sweep reads) below which the XLA gather composition
-# wins.  Anchored on KERNEL_BENCH.json: the r5 v5e rows show the gather
-# at ~1-6 ms across every small/mid decode shape (nothing for a kernel
-# to claw back below ~256 MiB of live KV), and the paged_v2_vs_xla
-# crossover-sweep rows carry the forced-on v2 arm next to the gather at
-# each shape so the threshold is re-derivable from committed evidence.
-# The v2 kernel fixes the measured v1 failure (one 16-token page per
-# grid step = B*KV*mp tiny dispatches, 25x slower at the largest shape)
-# by streaming ppcb pages per inner iteration through double-buffered
-# DMA — the regime where that pays is big-KV decode, where the sweep is
-# HBM-bandwidth-bound and the gather's materialized transient stops
-# fitting anywhere useful.  Re-stamp the sweep on chip before lowering
-# this.
-_PAGED_V2_MIN_KV_BYTES = 1 << 28
+def paged_reader(policy: Optional[str], *, decode: bool, tp: bool,
+                 interpret: bool, quant: bool) -> Tuple[str, str]:
+    """Which reader a paged program's attention runs, and why: ("xla" |
+    "pallas_v1" | "pallas_v2", reason).  The one answer every family's
+    ``forward_paged``, ``paged_layered_fns`` and the engine's
+    ``/statusz`` share.
 
-
-def pallas_paged_gate(B: int, n_kv: int, head_dim: int, page_size: int,
-                      max_pages: int, kv_itemsize: int,
-                      interpret: bool, tp: bool) -> bool:
-    """The shape-dependent ``auto`` policy for the paged Pallas kernels,
-    shared by every model's paged forward: True when the multi-page v2
-    kernel should replace the XLA gather composition for this shape.
-
-    Pure shape math — no env reads.  Env/config overrides are resolved
-    ONCE at engine build by :func:`resolve_serving_kernels` (which
-    passes an explicit ``paged_kernel`` down, bypassing this gate), so
-    an already-compiled program can never disagree with the visible
-    policy.  The crossover is total live-KV bytes per decode sweep
-    (``_PAGED_V2_MIN_KV_BYTES``, see the comment above): below it the
-    measured XLA gather is already ~ms-fast; above it the double-
-    buffered DMA sweep streams the pages the gather would materialize.
-
-    ``interpret`` (CPU) always takes the reference path — interpret-mode
-    kernels are a correctness harness, not a fast path.  ``tp`` also
-    returns False: the kernel is per-device and the serving engines
-    surface that demotion VISIBLY (``serving_kernel_fallbacks`` counter
-    + a ``/statusz`` reason via :func:`resolve_serving_kernels`) rather
-    than silently as before."""
-    if interpret or tp:
-        return False
-    live_kv_bytes = (2 * B * n_kv * max_pages * page_size * head_dim
-                     * kv_itemsize)
-    return live_kv_bytes >= _PAGED_V2_MIN_KV_BYTES
+    A forced ``policy`` is itself (the engine build has already demoted
+    what cannot run: :func:`resolve_serving_kernels`).  ``auto`` (or
+    None) answers from the phase and the layout alone, no sizes, no env
+    reads: a decode program (``T == 1``) on one device over float pages
+    reads live pages only through the Mosaic kernel
+    (:func:`paged_decode_attention_v2`), whose cost follows the live
+    tokens where the gather's follows slots x ``max_seq``.  The gather
+    stays where the kernel cannot go: under tensor parallelism (the
+    kernel is per-device and the KV heads are sharded), over
+    int8-resident pages (the compiler refuses the quantized kernel) and
+    in ``interpret`` mode (no TPU: interpret-mode kernels are a
+    correctness harness).  Chunk programs (``T > 1`` over history) keep
+    the gather: a chunk reader is its own measurement."""
+    if policy not in (None, "auto"):
+        return policy, "forced"
+    for off, why in ((not decode, "chunk program"),
+                     (tp, "tp: KV heads are sharded over the mesh"),
+                     (quant, "int8-resident pages"),
+                     (interpret, "interpret: no TPU backend")):
+        if off:
+            return "xla", why
+    return "pallas_v2", "decode on one device over float pages"
 
 
 class ServingKernelPolicy(NamedTuple):
@@ -1150,10 +1280,14 @@ class ServingKernelPolicy(NamedTuple):
     env_overrides: Tuple[Tuple[str, str, str], ...] = ()
     # (field, demoted_to, reason) for forced choices the build demoted
     fallbacks: Tuple[Tuple[str, str, str], ...] = ()
+    # (reader, reason): what the decode program's attention runs
+    # (:func:`paged_reader` at this build's layout)
+    decode: Tuple[str, str] = ("xla", "")
 
     def as_dict(self) -> dict:
         return {
             "paged_attention": self.paged_attention,
+            "decode": {"reader": self.decode[0], "reason": self.decode[1]},
             "fused_sampling": self.fused_sampling,
             "env_overrides": [list(o) for o in self.env_overrides],
             "fallbacks": [{"field": f, "demoted_to": d, "reason": r}
@@ -1207,8 +1341,7 @@ def resolve_serving_kernels(kernels=None, *, tp: bool = False,
     compiler refuses the int8-resident Pallas kernel, so a forced
     ``pallas_v2`` raises :class:`ServingKernelRefused` here at build,
     and ``auto`` resolves to ``xla`` with a ``fallbacks`` row — the
-    shape gate inside the forward never gets to pick a kernel that
-    cannot compile.
+    forward never gets to pick a kernel that cannot compile.
 
     An already-resolved :class:`ServingKernelPolicy` passes through
     untouched — the model builders resolve once and hand the SAME
@@ -1282,7 +1415,9 @@ def resolve_serving_kernels(kernels=None, *, tp: bool = False,
         fused = "on" if pallas_sample_gate(interpret=interpret) else "off"
     return ServingKernelPolicy(
         paged_attention=paged, fused_sampling=fused,
-        env_overrides=tuple(env_overrides), fallbacks=tuple(fallbacks))
+        env_overrides=tuple(env_overrides), fallbacks=tuple(fallbacks),
+        decode=paged_reader(paged, decode=True, tp=tp, interpret=interpret,
+                            quant=quantized_resident))
 
 
 def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
@@ -1302,8 +1437,8 @@ def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
     a copy of it or of one layer (a per-layer store passes ``kp[None]``
     and layer 0: :func:`~deepspeed_tpu.models.llama.paged_layered_fns`).
     ``paged_kernel`` is the RESOLVED dispatch ("xla" | "pallas_v1" |
-    "pallas_v2" — the gate/policy decided before the trace; no env
-    reads here).  A forced Pallas kernel with ``interpret=True`` runs
+    "pallas_v2" — :func:`paged_reader` decided before the trace; no
+    env reads here).  A forced Pallas kernel with ``interpret=True`` runs
     in interpret mode — that is an explicit request and exactly how the
     CPU identity gates exercise the kernels.  ``kps``/``vps`` non-None
     selects the int8-resident path: kp/vp hold int8 codes, kps/vps the
@@ -1423,13 +1558,11 @@ def paged_forward_prelude(cache, tokens, interpret, tp,
                           continuation: bool):
     """Shared preamble for every model's ``forward_paged``: resolve the
     interpret/tp defaults (ambient mesh consulted only when tp is None —
-    serving closures pass it explicitly), derive the page size and
-    ragged per-row start offsets, and guard the whole-prompt prefill
-    against a non-empty cache.  Returns (interpret, tp, ps, start,
-    prefill)."""
+    serving closures pass it explicitly), derive the ragged per-row
+    start offsets, and guard the whole-prompt prefill against a
+    non-empty cache.  Returns (interpret, tp, start, prefill)."""
     import jax as _jax
 
-    ps = cache.k.shape[3]
     if interpret is None:
         interpret = _jax.default_backend() != "tpu"
     if tp is None:
@@ -1448,4 +1581,4 @@ def paged_forward_prelude(cache, tokens, interpret, tp,
         except (_jax.errors.TracerArrayConversionError,
                 _jax.errors.ConcretizationTypeError):
             pass  # traced: caller's responsibility
-    return interpret, tp, ps, start, prefill
+    return interpret, tp, start, prefill

@@ -793,8 +793,8 @@ class ServingEngine:
         self._c_kdisp_paged = r.counter(
             f"serving_kernel_dispatch_paged_{pk}",
             "decode sweeps dispatched under the resolved "
-            "paged-attention policy (auto = the per-shape gate inside "
-            "the compiled forward)")
+            "paged-attention policy (auto = kernels.paged_reader: the "
+            "reader /statusz kernels.decode names)")
         self._c_kdisp_sample = r.counter(
             f"serving_kernel_dispatch_sample_{fs}",
             "batched sampling dispatches (decode-chunk syncs + "

@@ -230,11 +230,11 @@ def forward_paged(params, tokens, cfg: GPT2Config, cache,
     from deepspeed_tpu.inference.kernels import (paged_attention_step,
                                                  paged_forward_prelude,
                                                  paged_layer_loop,
-                                                 pallas_paged_gate)
+                                                 paged_reader)
 
     B, T = tokens.shape
     nh, hd, d = cfg.n_heads, cfg.head_dim, cfg.dim
-    interpret, tp, ps, start, prefill = paged_forward_prelude(
+    interpret, tp, start, prefill = paged_forward_prelude(
         cache, tokens, interpret, tp, continuation)
     # per-sequence position offsets: ragged frontiers under continuous
     # batching index each row's learned positions by ITS seq_len.
@@ -244,10 +244,9 @@ def forward_paged(params, tokens, cfg: GPT2Config, cache,
     with jax.named_scope("embed"):
         x = params["wte"][tokens] + params["wpe"][positions]
 
-    if paged_kernel in (None, "auto"):
-        paged_kernel = ("pallas_v2" if pallas_paged_gate(
-            B, nh, hd, ps, cache.table.shape[1], cache.k.dtype.itemsize,
-            interpret, tp) else "xla")
+    paged_kernel, _ = paged_reader(
+        paged_kernel, decode=T == 1, tp=tp, interpret=interpret,
+        quant=cache.k_scale is not None)
 
     def block(x, lp, layer, kp, vp, kps, vps):
         q, k, v = _qkv(cfg, x, lp)

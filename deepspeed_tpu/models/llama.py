@@ -435,10 +435,12 @@ def forward_paged(params, tokens, cfg: LlamaConfig, cache,
     in one sweep (custom ``chunk_prefill_fn`` replacements must honor
     this; see MIGRATION.md).
 
-    ``paged_kernel``: the RESOLVED paged-attention dispatch ("xla" |
-    "pallas_v1" | "pallas_v2") baked in by the serving build
-    (``resolve_serving_kernels``); None/"auto" takes the shape-measured
-    gate (``pallas_paged_gate``).  A cache carrying ``k_scale`` planes
+    ``paged_kernel``: the paged-attention policy the serving build
+    resolved (``resolve_serving_kernels``): a forced "xla" | "pallas_v1"
+    | "pallas_v2", or None/"auto", which ``paged_reader`` answers from
+    the phase and the layout (decode on one device over float pages
+    reads live pages through the Mosaic kernel; else the gather).  A
+    cache carrying ``k_scale`` planes
     is int8-resident (``kv_tier.quantized_resident``): writes quantize
     per token row on device and attention dequantizes in VMEM
     ("pallas_v2") or via :func:`~deepspeed_tpu.inference.kernels.
@@ -447,11 +449,11 @@ def forward_paged(params, tokens, cfg: LlamaConfig, cache,
     from deepspeed_tpu.inference.kernels import (paged_attention_step,
                                                  paged_forward_prelude,
                                                  paged_layer_loop,
-                                                 pallas_paged_gate)
+                                                 paged_reader)
 
     B, T = tokens.shape
-    hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    interpret, tp_active, ps, start, prefill = paged_forward_prelude(
+    hd, nh = cfg.head_dim, cfg.n_heads
+    interpret, tp_active, start, prefill = paged_forward_prelude(
         cache, tokens, interpret, tp, continuation)
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
@@ -460,11 +462,9 @@ def forward_paged(params, tokens, cfg: LlamaConfig, cache,
         positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
         cos, sin = rope_tables(cfg, positions)
 
-    if paged_kernel in (None, "auto"):
-        # no engine policy passed: the shape-measured auto gate decides
-        paged_kernel = "pallas_v2" if pallas_paged_gate(
-            B, nkv, hd, ps, cache.table.shape[1],
-            cache.k.dtype.itemsize, interpret, tp_active) else "xla"
+    paged_kernel, _ = paged_reader(
+        paged_kernel, decode=T == 1, tp=tp_active, interpret=interpret,
+        quant=cache.k_scale is not None)
 
     def block(x, lp, layer, kp, vp, kps, vps):
         q, k, v = _qkv(cfg, x, lp, cos, sin)
@@ -506,7 +506,7 @@ def paged_layered_fns(cfg: LlamaConfig, tp: bool = False, ffn=None,
     ``ffn``: per-block FFN override, the same hook ``forward_paged``
     gives MoE families."""
     from deepspeed_tpu.inference.kernels import (paged_attention_step,
-                                                 pallas_paged_gate)
+                                                 paged_reader)
     from deepspeed_tpu.inference.quantized import dequantize_params
 
     def stem_fn(sp, tokens, start):
@@ -523,17 +523,12 @@ def paged_layered_fns(cfg: LlamaConfig, tp: bool = False, ffn=None,
                  continuation: bool, prefill: bool):
         lp = dequantize_params(lp)
         B, T = x.shape[0], x.shape[1]
-        hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-        ps = kp.shape[2]
+        hd, nh = cfg.head_dim, cfg.n_heads
         itp = (jax.default_backend() != "tpu") if interpret is None \
             else interpret
         q, k, v = _qkv(cfg, x, lp, cos, sin)
-        if paged_kernel in (None, "auto"):
-            pk = "pallas_v2" if pallas_paged_gate(
-                B, nkv, hd, ps, table.shape[1], kp.dtype.itemsize,
-                itp, tp) else "xla"
-        else:
-            pk = paged_kernel
+        pk, _ = paged_reader(paged_kernel, decode=T == 1, tp=tp,
+                             interpret=itp, quant=False)
         # one layer's pages are a pool of one layer, written at layer 0
         attn, kp, vp, _, _ = paged_attention_step(
             q, k, v, kp[None], vp[None], 0, table, start,

@@ -39,8 +39,7 @@ _PALLAS_MIN_PARAMS = 1 << 26
 
 def pallas_adam_gate(n_params: int) -> bool:
     """One measured policy for when the pallas fused Adam beats the XLA
-    elementwise chain — the same data-driven pattern as
-    :func:`~deepspeed_tpu.inference.kernels.pallas_paged_gate`: below
+    elementwise chain: below
     the crossover the kernel is demoted to plain XLA (identical math),
     above it the pallas path holds.  ``DSTPU_FORCE_ADAM_PALLAS=1``
     forces the kernel at every size (read at trace time)."""

@@ -285,37 +285,107 @@ class TestPagedChunkAttention:
 
 
 class TestPagedGatePolicy:
-    """Pin the measured dispatch policy (KERNEL_BENCH.json
-    paged_v2_vs_xla sweep): v2 wins once the live KV footprint clears
-    the DMA-amortization crossover (_PAGED_V2_MIN_KV_BYTES); below it
-    the XLA gather wins.  The gate is pure shape math — env overrides
-    live in resolve_serving_kernels, resolved once at engine build."""
+    """The rule that replaced the byte threshold: which reader a paged
+    program runs follows from the phase, the device layout and the page
+    dtype (``kernels.paged_reader``), never from a size.  A decode
+    program on one device over float pages reads live pages through the
+    Mosaic kernel at every batch and table width; TP, int8-resident
+    pages and interpret mode keep the gather, and say why."""
 
-    def test_crossover_both_sides(self, monkeypatch):
-        from deepspeed_tpu.inference.kernels import (
-            _PAGED_V2_MIN_KV_BYTES, pallas_paged_gate)
+    # the cells' decode programs and the ends: (rows, table entries)
+    @pytest.mark.parametrize("rows,table", [(1, 64), (6, 520), (28, 64),
+                                            (64, 64)])
+    @pytest.mark.parametrize("family", ["gpt2", "llama"])
+    def test_decode_takes_the_kernel_at_every_shape(self, family, rows,
+                                                    table, monkeypatch):
+        """``forward_paged`` traced as the chip would (``interpret=False``)
+        with the default policy: the decode program holds a Pallas call,
+        the chunk program none.  An env switch set after the build
+        changes nothing: the trace reads no environment."""
+        from deepspeed_tpu.inference.kernels import PagedKVCache
+        from deepspeed_tpu.models import gpt2, llama
 
-        # env must NOT leak into the gate (trace-time reads removed)
+        monkeypatch.setenv("DSTPU_PAGED_ATTENTION", "xla")
         monkeypatch.setenv("DSTPU_FORCE_PAGED_PALLAS", "1")
-        # 16x8 heads, 288 pages x 16 x 128 @ bf16 = 302MB live KV ≥ 256MB
-        assert pallas_paged_gate(16, 8, 128, 16, 288, 2,
-                                 interpret=False, tp=False)
-        # 8x4 heads, 128 pages = 32MB — gather wins below the crossover
-        assert not pallas_paged_gate(8, 4, 128, 16, 128, 2,
-                                     interpret=False, tp=False)
-        # the boundary is exactly the committed crossover constant
-        kv_bytes = 2 * 16 * 8 * 288 * 16 * 128 * 2
-        assert kv_bytes >= _PAGED_V2_MIN_KV_BYTES > 2 * 8 * 4 * 128 * 16 * 128 * 2
+        if family == "gpt2":
+            mod, cfg = gpt2, gpt2.GPT2Config(
+                vocab_size=64, max_seq_len=table * 8, n_layers=1,
+                n_heads=2, dim=16)
+        else:
+            mod, cfg = llama, llama.LlamaConfig(
+                vocab_size=64, dim=16, n_layers=1, n_heads=4, n_kv_heads=2,
+                ffn_dim=32, max_seq_len=table * 8)
+        params = jax.eval_shape(lambda: mod.init_params(
+            jax.random.PRNGKey(0), cfg))
+        n_kv = getattr(cfg, "n_kv_heads", cfg.n_heads)
+        kv = jax.ShapeDtypeStruct(
+            (1, n_kv, rows * table + 1, 8, cfg.head_dim), jnp.bfloat16)
+        cache = PagedKVCache(
+            k=kv, v=kv, table=jax.ShapeDtypeStruct((rows, table), jnp.int32),
+            seq_lens=jax.ShapeDtypeStruct((rows,), jnp.int32), page_size=8)
 
-    def test_interpret_and_tp_force_reference(self):
-        from deepspeed_tpu.inference.kernels import pallas_paged_gate
+        def jaxpr(T, continuation):
+            return str(jax.make_jaxpr(
+                lambda p, t, c: mod.forward_paged(
+                    p, t, cfg, c, interpret=False, tp=False,
+                    continuation=continuation))(
+                params, jax.ShapeDtypeStruct((rows, T), jnp.int32), cache))
 
-        # interpret / TP always force the XLA reference paths, even
-        # above the crossover (no TPU grid on CPU; KV heads sharded)
-        assert not pallas_paged_gate(16, 8, 128, 16, 288, 2,
-                                     interpret=True, tp=False)
-        assert not pallas_paged_gate(16, 8, 128, 16, 288, 2,
-                                     interpret=False, tp=True)
+        assert "dstpu_paged_decode" in jaxpr(1, False)
+        assert "pallas_call" not in jaxpr(4, True)
+
+    @pytest.mark.parametrize("layout,reader,why", [
+        (dict(), "pallas_v2", "decode on one device"),
+        (dict(tp=True), "xla", "tp"),
+        (dict(quant=True), "xla", "int8-resident"),
+        (dict(interpret=True), "xla", "interpret"),
+        (dict(decode=False), "xla", "chunk"),
+    ])
+    def test_auto_answers_from_phase_and_layout(self, layout, reader, why):
+        from deepspeed_tpu.inference.kernels import paged_reader
+
+        kw = dict(decode=True, tp=False, interpret=False, quant=False)
+        kw.update(layout)
+        for policy in (None, "auto"):
+            got, reason = paged_reader(policy, **kw)
+            assert got == reader and why in reason
+        # a forced policy is itself, whatever the layout
+        for forced in ("xla", "pallas_v1", "pallas_v2"):
+            assert paged_reader(forced, **kw) == (forced, "forced")
+
+    @pytest.mark.parametrize("build,reader,row", [
+        (dict(), "pallas_v2", None),
+        (dict(tp=True), "xla", None),
+        (dict(tp=True, kernels={"paged_attention": "pallas_v2"}), "xla",
+         "tp_unsupported"),
+        (dict(quantized_resident=True), "xla", "quant_resident_unsupported"),
+        (dict(interpret=True), "xla", None),
+        (dict(interpret=True, kernels={"paged_attention": "pallas_v2"}),
+         "pallas_v2", None),
+    ])
+    def test_the_build_says_which_reader_decode_baked(self, build, reader,
+                                                      row):
+        """``/statusz`` names the decode program's reader with its
+        reason; what the build had to demote keeps its ``fallbacks``
+        row (a forced kernel under TP, ``auto`` over int8-resident
+        pages on a chip)."""
+        from deepspeed_tpu.inference.kernels import resolve_serving_kernels
+
+        kw = dict(tp=False, interpret=False, quantized_resident=False)
+        kw.update(build)
+        d = resolve_serving_kernels(kw.pop("kernels", None), **kw).as_dict()
+        assert d["decode"]["reader"] == reader and d["decode"]["reason"]
+        if row is None:
+            assert d["fallbacks"] == []
+        else:
+            assert [f["demoted_to"] for f in d["fallbacks"]] == ["xla"]
+            assert row in d["fallbacks"][0]["reason"]
+
+    def test_no_byte_threshold_is_left(self):
+        from deepspeed_tpu.inference import kernels
+
+        assert not hasattr(kernels, "_PAGED_V2_MIN_KV_BYTES")
+        assert not hasattr(kernels, "pallas_paged_gate")
 
 
 class TestPagedDecodeV2:

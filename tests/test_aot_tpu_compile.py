@@ -161,14 +161,18 @@ def test_quant_resident_policy_on_chip(paged, want):
 
 
 # ------------------------------------------- the K/V pool stays in place
-# The serving programs at the benchmark's widths, four layers deep:
-# (family, config, pool pages, decode rows, paged kernel).
+# The serving programs at the benchmark's widths, four layers deep, under
+# the default policy (``auto``): (family, config, pool pages, decode rows,
+# table entries, bound on the decode program's temporaries in GiB:
+# PERF.md 4, AOT, PR 25).  The engines are the benchmark cells':
+# chat-0.8knee, chat-sat and docs-sat.
+_GPT2 = lambda: dataclasses.replace(gpt2.GPT2Config.gpt2_1_3b(), n_layers=4)
+_MIXTRAL = lambda: dataclasses.replace(mixtral.MixtralConfig.mixtral_8x7b(),
+                                       n_layers=4)
 POOLS = {
-    "gpt2_1_3b": (gpt2, lambda: dataclasses.replace(
-        gpt2.GPT2Config.gpt2_1_3b(), n_layers=4), 1793, 28, "xla"),
-    "mixtral_chat": (mixtral, lambda: dataclasses.replace(
-        mixtral.MixtralConfig.mixtral_8x7b(), n_layers=4), 4097, 64,
-        "pallas_v2"),
+    "gpt2_1_3b": (gpt2, _GPT2, 1793, 28, 64, 0.12),
+    "mixtral_chat": (mixtral, _MIXTRAL, 4097, 64, 64, 0.015),
+    "mixtral_docs": (mixtral, _MIXTRAL, 3121, 6, 520, 0.015),
 }
 # phase -> (rows, tokens, continuation); None rows = the decode batch.
 # Prefill and chunk run one row at a time, as the engine dispatches them.
@@ -245,17 +249,33 @@ ENTRY %main (k: bf16[2,4,9,8,16]) -> bf16[2,4,9,8,16] {
         "copy.54 = bf16[2,4,9,8,16] copy"]
 
 
+def _shaped_like(hlo, *dims):
+    """Results anywhere in the HLO, fusion bodies included, with the
+    element count of ``dims`` and their last dim (a weight stack can
+    share the count, never the head dim)."""
+    want = math.prod(dims)
+    return sorted({f"{t}[{d}]" for t, d in
+                   re.findall(r"\b([a-z]\w*)\[([\d,]+)\]", hlo)
+                   if math.prod(int(x) for x in d.split(",")) == want
+                   and d.endswith(f",{dims[-1]}")})
+
+
 @pytest.mark.parametrize("phase", PHASES)
 @pytest.mark.parametrize("pool", POOLS)
 def test_serving_program_leaves_the_pool_in_place(chip, pool, phase):
     """``forward_paged``'s decode, whole-prompt prefill and chunk
     programs hold no copy of the K/V pool or of one layer of it: the
     pool is a carry of the layer loop, the writers scatter rows into it
-    and the readers take a layer by its index.  The bound on temporaries
-    also holds the XLA gather to bf16: after a clamped gather the
-    compiler writes the gathered K and V out in f32, 0.44 GiB at GPT-2's
-    28 rows (``kernels._gather_rows``)."""
-    family, make_cfg, pages, batch, paged_kernel = POOLS[pool]
+    and the readers take a layer by its index.
+
+    A decode program reads live pages only: under the default policy it
+    holds the Mosaic decode kernel at every engine (28 x 64 table
+    entries, 64 x 64, 6 x 520) and nothing shaped like the gathered copy
+    of every slot's whole table row ``[B, KV, max_pages * ps, Dh]``.
+    The chunk program keeps the XLA gather; the bound on its temporaries
+    holds that gather to bf16: after a clamped gather the compiler
+    writes the gathered K and V out in f32 (``kernels._gather_rows``)."""
+    family, make_cfg, pages, batch, table, decode_temp_gib = POOLS[pool]
     rows, T, continuation = PHASES[phase]
     rows = rows or batch
     cfg = make_cfg()
@@ -267,22 +287,28 @@ def test_serving_program_leaves_the_pool_in_place(chip, pool, phase):
         jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16))
     kv = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     cache = K.PagedKVCache(
-        k=kv, v=kv, table=jax.ShapeDtypeStruct((rows, 1024 // PAGE),
-                                               jnp.int32),
+        k=kv, v=kv, table=jax.ShapeDtypeStruct((rows, table), jnp.int32),
         seq_lens=jax.ShapeDtypeStruct((rows,), jnp.int32), page_size=PAGE)
 
     def program(params, tokens, cache):
         logits, cache = family.forward_paged(
             params, tokens, cfg, cache, interpret=False, tp=False,
-            continuation=continuation, paged_kernel=paged_kernel)
+            continuation=continuation)
         return logits[:, -1], cache
 
     compiled = jax.jit(program, donate_argnums=(2,)).lower(*on_chip((
         params, jax.ShapeDtypeStruct((rows, T), jnp.int32),
         cache))).compile()
     hlo = compiled.as_text()
-    if paged_kernel == "pallas_v2" and phase != "prefill":
-        assert "tpu_custom_call" in hlo
+    temp = compiled.memory_analysis().temp_size_in_bytes
     assert _pool_sized_ops(hlo, shape) == []
+    if phase == "decode":
+        assert re.search(r"%dstpu_paged_decode[\w.]* = .*tpu_custom_call",
+                         hlo)
+        assert _shaped_like(hlo, rows, cfg.n_kv_heads, table * PAGE,
+                            DH) == []
+        assert temp <= decode_temp_gib * 2 ** 30
+    else:
+        assert "dstpu_paged" not in hlo
     pool_bytes = 2 * math.prod(shape) * 2               # K and V, bf16
-    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 2
+    assert temp < pool_bytes / 2

@@ -271,6 +271,9 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
         "dstpu_paged_chunk_v2": lambda: jax.make_jaxpr(
             lambda: K.paged_chunk_attention_v2(
                 qc, pages, pages, table, start, interpret=True))(),
+        "dstpu_paged_decode": lambda: jax.make_jaxpr(
+            lambda: K.paged_decode_attention_v2(
+                qc[:, 0], pages, pages, table, start, interpret=True))(),
         "dstpu_paged_chunk_v2_q8": lambda: jax.make_jaxpr(
             lambda: K.paged_chunk_attention_v2_quant(
                 qc, codes, scale, codes, scale, table, start,
@@ -282,11 +285,11 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
     for want, make in sites.items():
         names = _pallas_names(make().jaxpr, [])
         assert want in names, (want, names)
-    # the sources give nine sites nine names, none shared
+    # the sources give ten sites ten names, none shared
     named = []
     for mod in (K, adam_pallas, attention_pallas, quant, sampling_pallas):
         with open(mod.__file__) as f:
             text = f.read()
         assert text.count("pl.pallas_call(") == text.count('name="dstpu_')
         named += re.findall(r'name="(dstpu_[a-z0-9_]+)"', text)
-    assert len(named) == len(set(named)) == 9
+    assert len(named) == len(set(named)) == 10

@@ -24,8 +24,8 @@ from deepspeed_tpu.config import Config, KernelsConfig, KVTierConfig
 from deepspeed_tpu.inference.kernels import (
     dequantize_pages, paged_attention_reference,
     paged_chunk_attention_reference, paged_chunk_attention_v2_quant,
-    paged_decode_attention_v2_quant, quantize_kv_rows,
-    resolve_serving_kernels)
+    paged_decode_attention_v2, paged_decode_attention_v2_quant,
+    quantize_kv_rows, resolve_serving_kernels)
 from deepspeed_tpu.inference.kv_tier import KV_TIER_QUANT_RTOL, quantize_page
 from deepspeed_tpu.inference.serving import (_sample_rows,
                                              llama_serving_engine,
@@ -165,6 +165,74 @@ class TestResolveServingKernels:
         assert d["paged_attention"] == "xla"
         assert d["fallbacks"][0]["demoted_to"] == "xla"
         assert "tp_unsupported" in d["fallbacks"][0]["reason"]
+
+
+# ------------------------------------------- the live-pages decode kernel
+class TestDecodeKernelIdentity:
+    """``paged_decode_attention_v2`` (one grid step a row, a page's K/V
+    for every kv head in one copy) in interpret mode against the gather
+    oracle.  Page size 8 and 2 pages a block, so 16 tokens is a block."""
+
+    PS, PPB, MP, DH, LAYERS = 8, 2, 6, 32, 3
+    LENS = {
+        # empty rows beside live ones; inside a page; one token
+        "ragged": [0, 5, 0, 43, 1],
+        # on a page edge, on a block edge, a full table, one past an edge
+        "edges": [8, 16, 48, 17, 32],
+        "all_empty": [0, 0, 0, 0, 0],
+    }
+
+    @pytest.mark.parametrize("lens", LENS)
+    @pytest.mark.parametrize("pool", ["whole_pool_traced_layer",
+                                      "one_layer"])
+    @pytest.mark.parametrize("heads", [(4, 4), (8, 2)],
+                             ids=["mha_4_4", "gqa_8_2"])
+    def test_matches_the_gather(self, heads, pool, lens):
+        H, KV = heads
+        lens = np.asarray(self.LENS[lens], np.int32)
+        B, P = len(lens), len(lens) * self.MP + 1
+        rng = np.random.default_rng(7)
+        shape = (self.LAYERS, KV, P, self.PS, self.DH)
+        k = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        v = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        q = jnp.asarray(rng.normal(size=(B, H, self.DH)), jnp.float32)
+        table = (rng.permutation(P - 1)[:B * self.MP] + 1).reshape(
+            B, self.MP).astype(np.int32)
+        # past a row's live pages the table is stale: ids that name no
+        # page of the pool, which the kernel must never dereference (the
+        # oracle gets them clamped: it masks what it gathers)
+        stale = np.arange(self.MP)[None] >= -(-lens[:, None] // self.PS)
+        oracle_table = jnp.asarray(table)
+        table = jnp.asarray(np.where(stale, P + 1000, table))
+        lens = jnp.asarray(lens)
+
+        if pool == "one_layer":
+            ref = paged_attention_reference(q, k[1], v[1], oracle_table,
+                                            lens)
+            out = paged_decode_attention_v2(
+                q, k[1], v[1], table, lens, pages_per_block=self.PPB,
+                interpret=True)
+        else:
+            ref = paged_attention_reference(q, k, v, oracle_table, lens,
+                                            layer=1)
+            out = jax.jit(lambda layer: paged_decode_attention_v2(
+                q, k, v, table, lens, pages_per_block=self.PPB,
+                interpret=True, layer=layer))(jnp.int32(1))
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5)
+        empty = np.asarray(lens) == 0
+        assert not np.asarray(out)[empty].any()     # zeros, not mean-of-V
+
+    def test_pages_per_block_is_derived(self):
+        """Nobody passes ``pages_per_block``: it follows from the kv
+        heads, the page and the VMEM block (GPT-2 1.3B: 8 pages of 16
+        heads; Mixtral: 16 pages of 8), capped by the table."""
+        from deepspeed_tpu.inference.kernels import decode_pages_per_block
+
+        assert decode_pages_per_block(16, 16, 128, 2, 64) == 8
+        assert decode_pages_per_block(8, 16, 128, 2, 520) == 16
+        assert decode_pages_per_block(2, 8, 32, 4, 6) == 6
+        assert decode_pages_per_block(64, 64, 256, 4, 64) == 1
 
 
 # ----------------------------------------------------------- shape gates

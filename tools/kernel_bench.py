@@ -299,11 +299,11 @@ def chunk_v2_sweep(configs, iters):
 
 
 def paged_v2_vs_xla(configs, iters):
-    """The crossover sweep behind ``pallas_paged_gate``: per decode
-    shape, the live-KV footprint, the gate's auto verdict at that
-    shape, the XLA gather time, and the FORCED-ON v2 arms (dense and
-    int8-dequant-fused) — per-kernel rows, so a chip re-stamp can move
-    ``_PAGED_V2_MIN_KV_BYTES`` with data instead of folklore.
+    """The XLA gather against the decode kernels: per decode shape,
+    the table's K/V footprint, the gather's time, and the v2 arms
+    (dense and int8-dequant-fused), per-kernel rows.  No gate reads
+    this: ``kernels.paged_reader`` answers from the phase and the
+    layout, and the benchmark's serving cells are the measurement.
 
     Off-chip (CPU) the kernels only run in interpret mode, which
     measures the interpreter, not the kernel — so a CPU stamp records
@@ -311,9 +311,8 @@ def paged_v2_vs_xla(configs, iters):
     correctness half of the contract) and leaves the timing columns to
     a TPU run.  Rows carry ``backend`` so the two never mix."""
     from deepspeed_tpu.inference.kernels import (
-        _PAGED_V2_MIN_KV_BYTES, dequantize_pages,
-        paged_attention_reference, paged_decode_attention_v2,
-        paged_decode_attention_v2_quant, pallas_paged_gate,
+        dequantize_pages, paged_attention_reference,
+        paged_decode_attention_v2, paged_decode_attention_v2_quant,
         quantize_kv_rows)
 
     on_tpu = jax.default_backend() == "tpu"
@@ -330,10 +329,6 @@ def paged_v2_vs_xla(configs, iters):
             "shape": {"B": B, "H": H, "KV": KV, "Dh": Dh, "page": ps,
                       "pages": pages, "seq": seq},
             "live_kv_mb": round(live_kv / (1 << 20), 1),
-            "gate_auto_pallas": pallas_paged_gate(
-                B, KV, Dh, ps, mp, kp.dtype.itemsize,
-                interpret=False, tp=False),
-            "crossover_mb": round(_PAGED_V2_MIN_KV_BYTES / (1 << 20)),
         }
         if on_tpu:
             tr = bench(jax.jit(paged_attention_reference),
@@ -532,9 +527,8 @@ def main():
                   (4, 64, 16, 4, 128, 16, 2048, 8192)]
     on_tpu = jax.default_backend() == "tpu"
     if on_tpu:
-        # the shapes that bracket the serving-gate crossovers: one
-        # decode shape below _PAGED_V2_MIN_KV_BYTES, one above; one
-        # (B, V) below _FUSED_SAMPLE_MIN_ROWS_X_VOCAB, one above
+        # a small and a large decode table; one (B, V) below
+        # _FUSED_SAMPLE_MIN_ROWS_X_VOCAB, one above
         gate_paged_cfgs = [(8, 16, 4, 128, 16, 512, 1024),
                            (16, 32, 8, 128, 16, 4608, 4096)]
         gate_sample_shapes = [(8, 32000), (256, 128256)]

@@ -166,8 +166,8 @@ timeout -k 10 600 env JAX_PLATFORMS=cpu python bench_serving.py --cpu \
   --tp 2 --requests 16 --new-tokens 32 --cpu-dim 256 --cpu-layers 2 \
   --json-out "$REPO/TP_BENCH.json" >/dev/null 2>&1 || true
 
-# serving-gate crossover sweeps: the two families behind
-# pallas_paged_gate / pallas_sample_gate.  On a chip they time the
+# serving-kernel sweeps: the paged decode kernels against the gather,
+# and the family behind pallas_sample_gate.  On a chip they time the
 # forced Pallas arms vs XLA at shapes bracketing the crossovers; on
 # this CPU lane they stamp interpret-mode IDENTITY rows (explicit
 # backend/note labels) and MERGE into KERNEL_BENCH.json without
