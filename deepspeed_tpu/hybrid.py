@@ -152,9 +152,9 @@ def llama_hybrid_engine(engine, cfg, *, eos_token_id: Optional[int] = None,
             "engine shares one TP layout between training and generation "
             "— set the mesh model axis instead")
 
-    from deepspeed_tpu.inference.generation import llama_step_alloc
+    from deepspeed_tpu.inference.generation import cached_step_alloc
 
-    step, alloc = llama_step_alloc(cfg, cache_dtype)
+    step, alloc = cached_step_alloc(cfg, cache_dtype)
     return HybridEngine(
         engine, step, step, alloc, eos_token_id=eos_token_id,
         max_out_tokens=hb.get("max_out_tokens"))

@@ -145,6 +145,11 @@ def serving_mesh_from_config(config: Any) -> Optional[MeshSpec]:
     return MeshSpec.build(sizes, devices=devs[:total])
 
 
+_SERVING_BLOCKS_IF_ENABLED = (
+    "zero_inference", "prefix_cache", "kv_tier", "speculative", "slo",
+    "faults", "history", "incidents")
+
+
 def init_serving(params, model_config, *, config: Any = None,
                  mesh: Optional[MeshSpec] = None, **kw):
     """Serving counterpart of :func:`init_inference` (ref: the reference
@@ -180,7 +185,7 @@ def init_serving(params, model_config, *, config: Any = None,
     Remaining ``kw`` (``max_batch``, ``page_size``, ``num_pages``,
     ``decode_chunk``, ``prefill_chunk``, ``weight_dtype``,
     ``prefix_cache``, ``admit_lookahead``, …) pass through to the
-    family builder.
+    builder (:func:`~deepspeed_tpu.inference.serving.serving_engine`).
     """
     from deepspeed_tpu.inference.serving import serving_engine
 
@@ -191,55 +196,19 @@ def init_serving(params, model_config, *, config: Any = None,
         # mesh= kw still wins); see serving_mesh_from_config for the
         # serving reading of the axis sizes
         mesh = serving_mesh_from_config(config)
-    if config is not None and config.zero_inference.enabled:
-        kw.setdefault("zero_inference", config.zero_inference)
-    if config is not None and config.prefix_cache.enabled:
-        # `prefix_cache` block → refcounted content-addressed paged-KV
-        # prefix caching in the engine (an explicit prefix_cache= kw
-        # still wins)
-        kw.setdefault("prefix_cache", config.prefix_cache)
-    if config is not None and config.kv_tier.enabled:
-        # `kv_tier` block → host/NVMe spill + cold-page quantization
-        # for the paged prefix pool (an explicit kv_tier= kw still
-        # wins); requires the prefix_cache block — the engine validates
-        kw.setdefault("kv_tier", config.kv_tier)
-    if config is not None and config.speculative.enabled:
-        # `speculative` block → draft-and-verify multi-token decode
-        # (an explicit speculative= kw still wins; a model drafter
-        # instance rides the separate drafter= kw)
-        kw.setdefault("speculative", config.speculative)
-    if config is not None and config.slo.enabled:
-        # `slo` block → per-tier SLO classification, burn-rate alerts
-        # and goodput accounting on the engine's registry (an explicit
-        # slo= kw still wins)
-        kw.setdefault("slo", config.slo)
-    if config is not None and config.faults.enabled:
-        # `faults` block → deterministic fault injection for the
-        # robustness/chaos machinery (an explicit faults= kw still
-        # wins); a TEST facility — see CONFIG.md before enabling
-        kw.setdefault("faults", config.faults)
-    if config is not None and config.history.enabled:
-        # `history` block → multi-resolution metric-history rings
-        # sampled on the exporter tick (an explicit history= kw still
-        # wins); serves /historyz and the incident bundles' pre-trip
-        # windows
-        kw.setdefault("history", config.history)
-    if config is not None and config.incidents.enabled:
-        # `incidents` block → the incident engine: trigger-event
-        # subscription + EWMA anomaly detectors, deduped atomic
-        # incident bundles (an explicit incidents= kw still wins)
-        kw.setdefault("incidents", config.incidents)
     if config is not None:
-        # `telemetry` config block → the engine's MetricsRegistry (an
-        # explicit telemetry= kw still wins)
-        kw.setdefault("telemetry", config.telemetry)
-        # `tracing` block → the engine's RequestTracer flight recorder
-        # (per-request event timelines + hang postmortems)
-        kw.setdefault("tracing", config.tracing)
-        # `kernels` block → the serving kernel-dispatch policy
-        # (paged_attention / fused_sampling), resolved ONCE at engine
-        # build with env vars as overrides of last resort.  No
-        # .enabled guard: "auto" IS the default policy, so the block
-        # always passes through (an explicit kernels= kw still wins)
-        kw.setdefault("kernels", config.kernels)
+        # a config block → the builder's keyword of the same name (an
+        # explicit keyword still wins).  These pass only when enabled
+        # (kv_tier requires the prefix_cache block — the engine
+        # validates; a model drafter instance rides the separate
+        # drafter= kw; faults is a TEST facility — see CONFIG.md) ...
+        for block in _SERVING_BLOCKS_IF_ENABLED:
+            if getattr(config, block).enabled:
+                kw.setdefault(block, getattr(config, block))
+        # ... and these always: telemetry and tracing carry their own
+        # enabled flag into the engine, and for kernels "auto" IS the
+        # default policy (resolved ONCE at engine build, env vars as
+        # overrides of last resort)
+        for block in ("telemetry", "tracing", "kernels"):
+            kw.setdefault(block, getattr(config, block))
     return serving_engine(params, model_config, mesh=mesh, **kw)

@@ -20,6 +20,11 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.inference.kernels import PagedKVCache
+from deepspeed_tpu.inference.paged_forward import (forward_paged,
+                                                   forward_with_cache)
+from deepspeed_tpu.models.family import decoder_family
+
 
 class KVCache(NamedTuple):
     """Static-shape KV cache; ``length`` = number of valid positions."""
@@ -34,41 +39,6 @@ class KVCache(NamedTuple):
         shape = (n_layers, batch, max_seq, n_kv, head_dim)
         return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
                    length=jnp.zeros((), jnp.int32))
-
-
-def cached_attention(q, k_cache, v_cache, new_k, new_v, start_pos,
-                     scale: Optional[float] = None):
-    """Attention of q against cache[:start_pos+T] (ref: the reference's
-    decode-attention kernel contract: softmax(q @ K^T) @ V with the causal
-    frontier at start_pos + local position).
-
-    q: [B, T, H, Dh]; caches [B, maxT, KV, Dh]; new_k/v: [B, T, KV, Dh].
-    Returns (out [B, T, H, Dh], k_cache, v_cache) with new_k/v written at
-    ``start_pos``.
-    """
-    B, T, H, Dh = q.shape
-    maxT, KV = k_cache.shape[1], k_cache.shape[2]
-    k_cache = jax.lax.dynamic_update_slice(
-        k_cache, new_k.astype(k_cache.dtype), (0, start_pos, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        v_cache, new_v.astype(v_cache.dtype), (0, start_pos, 0, 0))
-    if KV != H:
-        rep = H // KV
-        k = jnp.repeat(k_cache, rep, axis=2)
-        v = jnp.repeat(v_cache, rep, axis=2)
-    else:
-        k, v = k_cache, v_cache
-    scale = scale if scale is not None else Dh ** -0.5
-    scores = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    kpos = jnp.arange(maxT)
-    qpos = start_pos + jnp.arange(T)
-    mask = kpos[None, :] <= qpos[:, None]          # [T, maxT]
-    scores = jnp.where(mask[None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhts,bshd->bthd", probs,
-                     v.astype(jnp.float32)).astype(q.dtype)
-    return out, k_cache, v_cache
 
 
 def sample_logits(logits, rng, temperature: float = 1.0,
@@ -201,11 +171,17 @@ def greedy_draft_fn(step, alloc_cache, window: int, k: int):
     return jax.jit(rollout)
 
 
-def cached_step_alloc(forward_with_cache, cfg, cache_dtype=jnp.bfloat16):
-    """The (step, alloc_cache) pair over any model's
-    ``forward_with_cache(params, tokens, cfg, cache)`` — shared by the
-    generators and the hybrid engine so the cache wiring lives once."""
+def cached_step_alloc(cfg, cache_dtype=jnp.bfloat16):
+    """The (step, alloc_cache) pair over the contiguous-cache forward —
+    shared by :func:`generator`, the drafter and the hybrid engine so the
+    cache wiring lives once.  ``alloc_cache`` refuses a ``max_seq`` the
+    family cannot hold (a learned position table's rows: a traced gather
+    CLAMPS out-of-range indices, so generating past the table would
+    silently reuse the last position's embedding)."""
+    fam = decoder_family(cfg)
+
     def alloc(batch, max_seq):
+        fam.check(cfg, None, max_seq)
         return KVCache.alloc(cfg.n_layers, batch, max_seq, cfg.n_kv_heads,
                              cfg.head_dim, dtype=cache_dtype)
 
@@ -215,62 +191,21 @@ def cached_step_alloc(forward_with_cache, cfg, cache_dtype=jnp.bfloat16):
     return step, alloc
 
 
-def llama_step_alloc(cfg, cache_dtype=jnp.bfloat16):
-    from deepspeed_tpu.models import llama
+def generator(params, cfg, eos_token_id: Optional[int] = None,
+              cache_dtype=jnp.bfloat16) -> Generator:
+    """Cached-attention generation for any decoder family's weights
+    (MoE: capacity-free dense top-k expert combine, ref: DeepSpeed-MoE
+    inference)."""
+    step, alloc = cached_step_alloc(cfg, cache_dtype)
+    return Generator(params, step, step, alloc, eos_token_id=eos_token_id)
 
-    return cached_step_alloc(llama.forward_with_cache, cfg, cache_dtype)
 
-
-def llama_generator(params, cfg, eos_token_id: Optional[int] = None,
+def paged_generator(params, cfg, eos_token_id: Optional[int] = None,
+                    page_size: int = 16, num_pages: Optional[int] = None,
                     cache_dtype=jnp.bfloat16) -> Generator:
-    """Build a :class:`Generator` for models/llama.py weights."""
-    step, alloc = llama_step_alloc(cfg, cache_dtype)
-    return Generator(params, step, step, alloc, eos_token_id=eos_token_id)
-
-
-def gpt2_generator(params, cfg, eos_token_id: Optional[int] = None,
-                   cache_dtype=jnp.bfloat16) -> Generator:
-    """Cached-attention generation for models/gpt2.py weights."""
-    from deepspeed_tpu.models import gpt2
-
-    step, alloc = cached_step_alloc(gpt2.forward_with_cache, cfg,
-                                    cache_dtype)
-
-    def checked_alloc(batch, max_seq):
-        # learned positions: a traced wpe gather CLAMPS out-of-range
-        # indices, so generating past the table would silently reuse the
-        # last position's embedding — fail here instead (RoPE models have
-        # no such table and need no check)
-        if max_seq > cfg.max_seq_len:
-            raise ValueError(
-                f"prompt + max_new_tokens ({max_seq}) exceeds gpt2's "
-                f"learned position table ({cfg.max_seq_len})")
-        return alloc(batch, max_seq)
-
-    return Generator(params, step, step, checked_alloc,
-                     eos_token_id=eos_token_id)
-
-
-def mixtral_generator(params, cfg, eos_token_id: Optional[int] = None,
-                      cache_dtype=jnp.bfloat16) -> Generator:
-    """MoE text generation (ref: DeepSpeed-MoE inference): cached
-    attention + capacity-free dense top-k expert combine."""
-    from deepspeed_tpu.models import mixtral
-
-    step, alloc = cached_step_alloc(mixtral.forward_with_cache, cfg,
-                                    cache_dtype)
-    return Generator(params, step, step, alloc, eos_token_id=eos_token_id)
-
-
-def _paged_generator(forward_paged, params, cfg,
-                     eos_token_id: Optional[int] = None,
-                     page_size: int = 16, num_pages: Optional[int] = None,
-                     cache_dtype=jnp.bfloat16) -> Generator:
-    """Shared paged-KV generator over any ``forward_paged(params, tokens,
-    cfg, cache)`` — cache sizing and wiring live once, model families
-    supply only their forward."""
-    from deepspeed_tpu.inference.kernels import PagedKVCache
-
+    """Paged-KV generation over :func:`forward_paged` — the offline
+    oracle for serving (ref contract: deepspeed/ops/transformer/
+    inference decode kernels + their preallocated KV workspace)."""
     def alloc(batch, max_seq):
         mp = -(-max_seq // page_size)
         n = num_pages if num_pages is not None else batch * mp
@@ -282,28 +217,3 @@ def _paged_generator(forward_paged, params, cfg,
         return forward_paged(params, tokens, cfg, cache)
 
     return Generator(params, step, step, alloc, eos_token_id=eos_token_id)
-
-
-def llama_paged_generator(params, cfg, **kw) -> Generator:
-    """Paged-KV variant: decode streams only live pages via the pallas
-    paged-attention kernel (ref contract: deepspeed/ops/transformer/
-    inference decode kernels + their preallocated KV workspace)."""
-    from deepspeed_tpu.models import llama
-
-    return _paged_generator(llama.forward_paged, params, cfg, **kw)
-
-
-def mixtral_paged_generator(params, cfg, **kw) -> Generator:
-    """Paged-KV MoE generation — the offline oracle for Mixtral serving
-    (ref: DeepSpeed-MoE inference engine's generate path)."""
-    from deepspeed_tpu.models import mixtral
-
-    return _paged_generator(mixtral.forward_paged, params, cfg, **kw)
-
-
-def gpt2_paged_generator(params, cfg, **kw) -> Generator:
-    """Paged-KV GPT-2 generation — the offline oracle for GPT-2 serving
-    (ref: gpt2 kernel-injection container)."""
-    from deepspeed_tpu.models import gpt2
-
-    return _paged_generator(gpt2.forward_paged, params, cfg, **kw)

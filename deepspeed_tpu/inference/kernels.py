@@ -1242,7 +1242,7 @@ def paged_chunk_attention(q, k_pages, v_pages, table, start,
 def paged_reader(policy: Optional[str], *, decode: bool, tp: bool,
                  interpret: bool, quant: bool) -> Tuple[str, str]:
     """Which reader a paged program's attention runs, and why: ("xla" |
-    "pallas_v1" | "pallas_v2", reason).  The one answer every family's
+    "pallas_v1" | "pallas_v2", reason).  The one answer
     ``forward_paged``, ``paged_layered_fns`` and the engine's
     ``/statusz`` share.
 
@@ -1344,7 +1344,7 @@ def resolve_serving_kernels(kernels=None, *, tp: bool = False,
     forward never gets to pick a kernel that cannot compile.
 
     An already-resolved :class:`ServingKernelPolicy` passes through
-    untouched — the model builders resolve once and hand the SAME
+    untouched — ``serving_engine`` resolves once and hands the SAME
     policy to the engine, so the kernels the closures baked and the
     policy ``/statusz`` reports can never drift."""
     from deepspeed_tpu.config import KernelsConfig
@@ -1435,7 +1435,7 @@ def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
     scatter the new rows into the pool and the readers take the layer by
     its index, so the program updates the pool in place and never holds
     a copy of it or of one layer (a per-layer store passes ``kp[None]``
-    and layer 0: :func:`~deepspeed_tpu.models.llama.paged_layered_fns`).
+    and layer 0: :func:`~deepspeed_tpu.inference.paged_forward.paged_layered_fns`).
     ``paged_kernel`` is the RESOLVED dispatch ("xla" | "pallas_v1" |
     "pallas_v2" — :func:`paged_reader` decided before the trace; no
     env reads here).  A forced Pallas kernel with ``interpret=True`` runs
@@ -1455,7 +1455,7 @@ def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
         raise ValueError("int8-resident pages have no pallas_v1 kernel "
                          "(use xla or pallas_v2)")
     # the one place for the three attention scopes (kv_write, kv_attend,
-    # flash): every family's forward_paged passes through here
+    # flash): forward_paged and its layered twin pass through here
     write, attend = jax.named_scope("kv_write"), jax.named_scope("kv_attend")
     if continuation and q.shape[1] > 1:
         if quant:
@@ -1552,33 +1552,3 @@ def paged_layer_loop(block, x, blocks, cache: PagedKVCache):
         body, (x, (cache.k, cache.v, cache.k_scale, cache.v_scale)),
         (blocks, jnp.arange(cache.k.shape[0], dtype=jnp.int32)))
     return x, cache._replace(k=k, v=v, k_scale=ks, v_scale=vs)
-
-
-def paged_forward_prelude(cache, tokens, interpret, tp,
-                          continuation: bool):
-    """Shared preamble for every model's ``forward_paged``: resolve the
-    interpret/tp defaults (ambient mesh consulted only when tp is None —
-    serving closures pass it explicitly), derive the ragged per-row
-    start offsets, and guard the whole-prompt prefill against a
-    non-empty cache.  Returns (interpret, tp, start, prefill)."""
-    import jax as _jax
-
-    if interpret is None:
-        interpret = _jax.default_backend() != "tpu"
-    if tp is None:
-        from deepspeed_tpu.topology import current_mesh as _cm
-
-        _ms = _cm()
-        tp = _ms is not None and _ms.size("model") > 1
-    start = cache.seq_lens
-    prefill = tokens.shape[1] > 1 and not continuation
-    if prefill:
-        try:
-            if int(jnp.max(start)) != 0:
-                raise ValueError(
-                    "forward_paged prefill (T>1) requires an empty "
-                    "cache; pass continuation=True for chunked prefill")
-        except (_jax.errors.TracerArrayConversionError,
-                _jax.errors.ConcretizationTypeError):
-            pass  # traced: caller's responsibility
-    return interpret, tp, start, prefill

@@ -1,0 +1,221 @@
+"""The forwards over a K/V cache, each written once over a decoder
+family's hooks (:mod:`deepspeed_tpu.models.family`).
+
+:func:`forward_paged` is what the serving engine's programs run (ref: the
+reference's inference kernels' workspace contract, modernised to
+vLLM-style page tables; it serves GPT-2, llama and MoE models through one
+inference engine).  :func:`paged_layered_fns` is the same forward one
+layer a program, for weight-streamed (ZeRO-Inference) serving;
+:func:`forward_with_cache` the contiguous-cache forward of the offline
+generators, the drafter and the hybrid engine.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.kernels import (paged_attention_step,
+                                             paged_layer_loop, paged_reader)
+from deepspeed_tpu.inference.quantized import dequantize_params
+from deepspeed_tpu.models.family import decoder_family
+
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    return jax.default_backend() != "tpu" if interpret is None else interpret
+
+
+def _paged_block(fam, cfg, x, lp, ctx, layer, kp, vp, kps, vps, table,
+                 start, *, continuation: bool, prefill: bool,
+                 paged_kernel: str, tp: bool, interpret: bool):
+    """One layer over the pool ``kp``/``vp`` ``[L, KV, P, ps, Dh]``."""
+    B, T = x.shape[:2]
+    q, k, v = fam.qkv(cfg, x, lp, *ctx)
+    attn, kp, vp, kps, vps = paged_attention_step(
+        q, k, v, kp, vp, layer, table, start, continuation=continuation,
+        prefill=prefill, paged_kernel=paged_kernel,
+        flash_force_reference=tp, interpret=interpret, kps=kps, vps=vps)
+    x = fam.out(cfg, x, attn.reshape(B, T, cfg.n_heads * cfg.head_dim), lp)
+    return x, kp, vp, kps, vps
+
+
+def forward_paged(params, tokens, cfg, cache, *,
+                  continuation: bool = False, tp: Optional[bool] = None,
+                  interpret: Optional[bool] = None,
+                  paged_kernel: Optional[str] = None):
+    """Forward over a paged KV cache.  tokens: [B, T] → (logits, cache).
+
+    ``tp``: True = params/cache are sharded over the mesh, so every
+    pallas path (paged kernels AND the prefill flash kernel) must yield
+    to the GSPMD-partitionable XLA formulations.  Serving closures pass
+    this EXPLICITLY at build time — correctness must not hang off the
+    mutable ambient mesh, which is only consulted when ``tp`` is None
+    (direct callers).
+
+    Prefill (T > 1, empty cache): dense causal attention over the prompt,
+    K/V bulk-written into pages.  Decode (T == 1): paged attention over
+    the live pages.  ``continuation=True`` (T > 1, non-empty cache):
+    chunked prefill — the chunk's K/V scatter in at each row's frontier
+    and attention runs over history + chunk (the FastGen split-fuse read
+    path).
+
+    Multi-position decode contract: the continuation path returns
+    logits at EVERY position, not just the last — the serving engine's
+    speculative verify depends on it to score a K+1-token draft window
+    in one sweep (custom ``chunk_prefill_fn`` replacements must honor
+    this; see MIGRATION.md).  Under learned positions, draft positions
+    past the table CLAMP into its last row — harmless, because an
+    acceptance at such a position would exceed the request's token
+    budget and the host discards it (the engine bounds real positions
+    by ``max_seq``, and the family's ``check`` bounds ``max_seq``).
+
+    ``paged_kernel``: the paged-attention policy the serving build
+    resolved (``resolve_serving_kernels``): a forced "xla" | "pallas_v1"
+    | "pallas_v2", or None/"auto", which ``paged_reader`` answers from
+    the phase and the layout (decode on one device over float pages
+    reads live pages through the Mosaic kernel; else the gather).  A
+    cache carrying ``k_scale`` planes is int8-resident
+    (``kv_tier.quantized_resident``): writes quantize per token row on
+    device and attention dequantizes in VMEM ("pallas_v2") or via
+    :func:`~deepspeed_tpu.inference.kernels.dequantize_pages` ("xla").
+    """
+    fam = decoder_family(cfg)
+    T = tokens.shape[1]
+    interpret = _interpret(interpret)
+    if tp is None:
+        from deepspeed_tpu.topology import current_mesh
+
+        ms = current_mesh()
+        tp = ms is not None and ms.size("model") > 1
+    start = cache.seq_lens
+    prefill = T > 1 and not continuation
+    if prefill:
+        try:
+            if int(jnp.max(start)) != 0:
+                raise ValueError(
+                    "forward_paged prefill (T>1) requires an empty "
+                    "cache; pass continuation=True for chunked prefill")
+        except (jax.errors.TracerArrayConversionError,
+                jax.errors.ConcretizationTypeError):
+            pass  # traced: caller's responsibility
+    x, ctx = fam.embed(params, tokens, start, cfg)
+    paged_kernel, _ = paged_reader(
+        paged_kernel, decode=T == 1, tp=tp, interpret=interpret,
+        quant=cache.k_scale is not None)
+
+    def block(x, lp, layer, kp, vp, kps, vps):
+        return _paged_block(
+            fam, cfg, x, lp, ctx, layer, kp, vp, kps, vps, cache.table,
+            start, continuation=continuation, prefill=prefill,
+            paged_kernel=paged_kernel, tp=tp, interpret=interpret)
+
+    x, cache = paged_layer_loop(block, x, params["blocks"], cache)
+    return fam.head(params, x, cfg), cache._replace(seq_lens=start + T)
+
+
+def paged_layered_fns(cfg, *, tp: bool = False,
+                      interpret: Optional[bool] = None,
+                      paged_kernel: Optional[str] = None):
+    """Per-layer factoring of :func:`forward_paged` for weight-streamed
+    (ZeRO-Inference) serving — the serving twin of a family's
+    ``layered_model``: stem (embedding + what the positions give) and
+    head (final norm + LM head) stay HBM-resident, each transformer layer
+    is its OWN jittable program so the streaming engine can upload layer
+    l+1's weights while layer l computes.  Returns
+    ``(stem_fn, block_fn, head_fn)``:
+
+        stem_fn(stem, tokens, start)            -> (x, ctx)
+        block_fn(lp, x, ctx, kp, vp, table, start,
+                 *, continuation, prefill)      -> (x, kp, vp)
+        head_fn(head, x)                        -> logits [B, T, V] f32
+
+    ``kp``/``vp`` are ONE layer's pages [KV, P, ps, Dh]: the block is
+    :func:`forward_paged`'s own, run on a pool of one layer, so streamed
+    serving is token-identical to the resident engine.  Every param tree
+    may carry int8 :class:`~deepspeed_tpu.inference.quantized.
+    QuantizedTensor` leaves — the dequant is traced into each per-layer
+    program, exactly as the whole-model quantized forward fuses it."""
+    fam = decoder_family(cfg)
+
+    def stem_fn(sp, tokens, start):
+        return fam.embed(dequantize_params(sp), tokens, start, cfg)
+
+    def block_fn(lp, x, ctx, kp, vp, table, start, *,
+                 continuation: bool, prefill: bool):
+        lp = dequantize_params(lp)
+        itp = _interpret(interpret)
+        pk, _ = paged_reader(paged_kernel, decode=x.shape[1] == 1, tp=tp,
+                             interpret=itp, quant=False)
+        x, kp, vp, _, _ = _paged_block(
+            fam, cfg, x, lp, ctx, 0, kp[None], vp[None], None, None,
+            table, start, continuation=continuation, prefill=prefill,
+            paged_kernel=pk, tp=tp, interpret=itp)
+        return x, kp[0], vp[0]
+
+    def head_fn(hp, x):
+        return fam.head(dequantize_params(hp), x, cfg)
+
+    return stem_fn, block_fn, head_fn
+
+
+def cached_attention(q, k_cache, v_cache, new_k, new_v, start_pos,
+                     scale: Optional[float] = None):
+    """Attention of q against cache[:start_pos+T] (ref: the reference's
+    decode-attention kernel contract: softmax(q @ K^T) @ V with the causal
+    frontier at start_pos + local position).
+
+    q: [B, T, H, Dh]; caches [B, maxT, KV, Dh]; new_k/v: [B, T, KV, Dh].
+    Returns (out [B, T, H, Dh], k_cache, v_cache) with new_k/v written at
+    ``start_pos``.
+    """
+    B, T, H, Dh = q.shape
+    maxT, KV = k_cache.shape[1], k_cache.shape[2]
+    k_cache = jax.lax.dynamic_update_slice(
+        k_cache, new_k.astype(k_cache.dtype), (0, start_pos, 0, 0))
+    v_cache = jax.lax.dynamic_update_slice(
+        v_cache, new_v.astype(v_cache.dtype), (0, start_pos, 0, 0))
+    if KV != H:
+        rep = H // KV
+        k = jnp.repeat(k_cache, rep, axis=2)
+        v = jnp.repeat(v_cache, rep, axis=2)
+    else:
+        k, v = k_cache, v_cache
+    scale = scale if scale is not None else Dh ** -0.5
+    scores = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
+                        k.astype(jnp.float32)) * scale
+    kpos = jnp.arange(maxT)
+    qpos = start_pos + jnp.arange(T)
+    mask = kpos[None, :] <= qpos[:, None]          # [T, maxT]
+    scores = jnp.where(mask[None, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhts,bshd->bthd", probs,
+                     v.astype(jnp.float32)).astype(q.dtype)
+    return out, k_cache, v_cache
+
+
+def forward_with_cache(params, tokens, cfg, cache):
+    """Incremental forward for generation over a contiguous
+    :class:`~deepspeed_tpu.inference.generation.KVCache`: attends to
+    cache[:len] + tokens, writes new K/V at position ``cache.length``
+    (ref: the reference's inference transformer kernels' KV-cache
+    contract).  tokens: [B, T] → (logits [B, T, V] f32, updated cache)."""
+    fam = decoder_family(cfg)
+    B, T = tokens.shape
+    start = cache.length
+    x, ctx = fam.embed(params, tokens, start, cfg)
+
+    def block(x, layer):
+        lp, kc, vc = layer
+        q, k, v = fam.qkv(cfg, x, lp, *ctx)
+        with jax.named_scope("kv_attend"):
+            attn, kc, vc = cached_attention(q, kc, vc, k, v, start)
+        x = fam.out(cfg, x, attn.reshape(B, T, cfg.n_heads * cfg.head_dim),
+                    lp)
+        return x, (kc, vc)
+
+    x, (new_k, new_v) = jax.lax.scan(block, x,
+                                     (params["blocks"], cache.k, cache.v))
+    return (fam.head(params, x, cfg),
+            cache._replace(k=new_k, v=new_v, length=start + T))

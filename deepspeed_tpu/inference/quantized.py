@@ -82,7 +82,7 @@ def quantize_params(params: Any, *, bits: int = 8, group_size: int = 128,
 
     ``skip_paths``: leaf key names kept exact regardless of ndim — a
     STACKED tree's per-layer vectors ([L, d] norm gains, biases) pass
-    the ndim gate looking like matrices, so model builders must name
+    the ndim gate looking like matrices, so a family's record must name
     them (the reference's weight-only quantization likewise touches only
     the matmul weights)."""
     if bits != 8:

@@ -82,22 +82,25 @@ import jax.numpy as jnp
 from deepspeed_tpu import faults as faults_mod
 from deepspeed_tpu.config import (CommConfig, DevprofConfig, FaultsConfig,
                                   HistoryConfig,
-                                  IncidentsConfig, KVTierConfig,
-                                  PrefixCacheConfig, SLOConfig,
-                                  SpeculativeConfig, TelemetryConfig,
-                                  TracingConfig)
+                                  IncidentsConfig, KernelsConfig,
+                                  KVTierConfig, PrefixCacheConfig,
+                                  SLOConfig, SpeculativeConfig,
+                                  TelemetryConfig, TracingConfig,
+                                  ZeroInferenceConfig)
 from deepspeed_tpu.devprof import NULL_DEVPROF, DevProf
 from deepspeed_tpu.faults import ChecksumError, FaultPlan, InjectedFault
 from deepspeed_tpu.history import NULL_HISTORY, MetricHistory
 from deepspeed_tpu.incidents import NULL_INCIDENTS, IncidentManager
 from deepspeed_tpu.inference.kernels import (PagedKVCache, PageAllocator,
                                              resolve_serving_kernels)
+from deepspeed_tpu.inference.paged_forward import forward_paged
 from deepspeed_tpu.inference.prefix_cache import (extend_page_keys,
                                                   key_hex,
                                                   matchable_pages,
                                                   page_keys)
 from deepspeed_tpu.inference.speculative import (build_drafter,
                                                  verify_accept)
+from deepspeed_tpu.models.family import decoder_families, decoder_family
 from deepspeed_tpu.request_trace import (BoundTracer, RequestTracer,
                                           event_to_dict)
 from deepspeed_tpu.slo import NULL_SLO_TRACKER, SLOTracker
@@ -250,9 +253,9 @@ class ServingEngine:
     """Host scheduler driving jitted prefill/decode over a paged cache.
 
     model_fns: ``(prefill_fn, decode_fn)`` with the
-    :func:`~deepspeed_tpu.models.llama.forward_paged` contract
-    ``(params, tokens, cache) -> (logits, cache)``; built automatically
-    for llama via :func:`llama_serving_engine`.
+    :func:`~deepspeed_tpu.inference.paged_forward.forward_paged`
+    contract ``(params, tokens, cache) -> (logits, cache)``; built for a
+    decoder family's config by :func:`serving_engine`.
     """
 
     def __init__(self, params, prefill_fn, decode_fn, *,
@@ -1618,7 +1621,7 @@ class ServingEngine:
         match, because a shape change would silently retrace inside
         the next request's TTFT).  ``new_params`` must be prepared
         exactly like the originals (same quantization, same TP
-        sharding — use the family builder's preparation).
+        sharding — use :func:`serving_engine`'s preparation).
 
         Only a DRAINED engine may swap: the fleet's rollout drains the
         replica first, so no in-flight request ever mixes layers from
@@ -3499,390 +3502,64 @@ def _record_comm_placement(eng: ServingEngine, stats: Dict[str, Any]):
         stats["max_rel_err"])
 
 
-def _route_zero_inference(zero_inference, family: str, params, cfg,
-                          weight_dtype, quant_group_size, mesh, kw):
-    """Shared builder branch: a live ``zero_inference`` block routes to
-    the weight-streamed engine (inference/zero_inference.py); returns
-    None when the resident path should proceed."""
-    from deepspeed_tpu.config import ZeroInferenceConfig
-
-    zi = ZeroInferenceConfig.coerce(zero_inference)
-    if not zi.enabled:
-        return None
-    from deepspeed_tpu.inference.zero_inference import (
-        zero_inference_serving_engine)
-
-    return zero_inference_serving_engine(
-        params, cfg, zi, family=family, weight_dtype=weight_dtype,
-        quant_group_size=quant_group_size, mesh=mesh, **kw)
+def _live(config_cls):
+    return lambda v: config_cls.coerce(v).enabled
 
 
-def _resolve_kernels_for_builder(kernels, mesh, kv_tier=None):
-    """Resolve the serving-kernel policy for a model builder, with the
-    SAME predicates the engine uses (any model/expert axis > 1 demotes
-    forced pallas — the kernels read the full page table per device;
-    an int8-resident cache on a chip refuses it).  The returned :class:`~deepspeed_tpu.inference.kernels.
-    ServingKernelPolicy` is baked into the forward closures AND passed
-    through as the engine's ``kernels`` kwarg, so there is exactly one
-    resolution per build."""
-    active = mesh is not None and any(
-        mesh.size(ax) > 1 for ax in ("model", "expert"))
-    kvt = KVTierConfig.coerce(kv_tier)
-    return resolve_serving_kernels(
-        kernels, tp=active,
-        interpret=jax.default_backend() != "tpu",
-        quantized_resident=kvt.enabled and kvt.quantized_resident)
+def _pins_kernels(v) -> bool:
+    k = KernelsConfig.coerce(v)
+    return (k.paged_attention, k.fused_sampling) != ("auto", "auto")
 
 
-def llama_serving_engine(params, cfg, weight_dtype: str = "bfloat16",
-                         quant_group_size: int = 128, mesh=None,
-                         zero_inference=None, **kw) -> ServingEngine:
-    """ServingEngine over models/llama.py's paged forward.
-
-    ``weight_dtype="int8"``: weight-only quantized serving (ref:
-    init_inference(dtype=int8)) — int8 codes + group scales in HBM
-    (half the bf16 weight residency), dequant traced into the forward.
-
-    ``mesh``: TP-sharded serving (ref: replace_module.py TP injection) —
-    params shard Megatron-style over the ``model`` axis, the KV cache
-    shards its head axis, and both jits run under GSPMD with the psum
-    after wo/w2 inserted by XLA.  The mesh is published ambient so the
-    forward picks its TP-compatible attention paths.
-
-    ``zero_inference``: a :class:`~deepspeed_tpu.config.
-    ZeroInferenceConfig` (or its dict form) routes to the weight-
-    streamed ZeRO-Inference engine — layer weights live on a host/NVMe
-    tier and stream through a double-buffered HBM working set, so the
-    served model's weight image may exceed HBM.
-    """
-    from deepspeed_tpu.models import llama
-
-    zi_engine = _route_zero_inference(
-        zero_inference, "llama", params, cfg, weight_dtype,
-        quant_group_size, mesh, kw)
-    if zi_engine is not None:
-        return zi_engine
-
-    # tp baked in at BUILD time: the compiled paths must not re-read the
-    # mutable ambient mesh on a later retrace (a cleared/replaced global
-    # would silently re-enable pallas kernels over the sharded cache)
-    tp = mesh is not None and mesh.size("model") > 1
-    # the kernel policy resolves HERE too (config + env, once) and the
-    # same ServingKernelPolicy passes through to the engine, so the
-    # paged_kernel the closures bake and the policy /statusz reports
-    # are one object, not two resolutions that could drift
-    kw["kernels"] = _resolve_kernels_for_builder(
-        kw.get("kernels"), mesh, kw.get("kv_tier"))
-    pk = kw["kernels"].paged_attention
-
-    def step(params, tokens, cache):
-        return llama.forward_paged(params, tokens, cfg, cache, tp=tp,
-                                   paged_kernel=pk)
-
-    def chunk_step(params, tokens, cache):
-        return llama.forward_paged(params, tokens, cfg, cache,
-                                   continuation=True, tp=tp,
-                                   paged_kernel=pk)
-
-    if weight_dtype != "bfloat16":
-        from deepspeed_tpu.inference.quantized import quantize_for_inference
-
-        # raises on anything but "int8" — never silently serve
-        # unquantized; stacked [L, d] norm gains stay exact
-        params, step, chunk_step = quantize_for_inference(
-            params, step, chunk_step, weight_dtype=weight_dtype,
-            group_size=quant_group_size,
-            skip_paths=("attn_norm", "mlp_norm", "final_norm"))
-
-    comm_stats = None
-    if tp:
-        cc = CommConfig.coerce(kw.get("comm"))
-        if cc.quantized_serving:
-            # the training int8 wire reused for replica placement: H2D
-            # ships codes + scales, gated by serving_rtol per leaf
-            params, comm_stats = _quantized_shard_params(
-                params, llama.param_specs(cfg), mesh, cc)
-        else:
-            params = _shard_params_for_serving(
-                params, llama.param_specs(cfg), mesh)
-
-    eng = ServingEngine(
-        params, step, step, n_layers=cfg.n_layers, n_kv=cfg.n_kv_heads,
-        head_dim=cfg.head_dim, chunk_prefill_fn=chunk_step, mesh=mesh,
-        **kw)
-    if comm_stats is not None:
-        _record_comm_placement(eng, comm_stats)
-    return eng
+# What only the paged-KV decode scheduler has: (keyword, is the value a
+# live request for it, what it asks for).  The encoder engines are
+# fixed-shape batch scorers with no pages, decode loop, sampler or request
+# lifecycle; a live block fails loudly there, never silently serves
+# without what the config pinned, and an inert one is dropped.
+_DECODER_ONLY = (
+    ("zero_inference", _live(ZeroInferenceConfig),
+     "zero_inference streams a paged-KV decoder's layer weights"),
+    ("kernels", _pins_kernels,
+     "the kernels block pins paged-KV decode kernels"),
+    ("comm", lambda v: CommConfig.coerce(v).quantized_serving,
+     "comm.quantized_serving quantizes TP replica weight placement"),
+    ("speculative", _live(SpeculativeConfig),
+     "speculative decoding needs the paged-KV decode path"),
+    ("slo", _live(SLOConfig),
+     "the slo block needs the paged-KV decode path"),
+    ("prefix_cache", _live(PrefixCacheConfig),
+     "prefix_cache needs the paged-KV decode path"),
+    ("kv_tier", _live(KVTierConfig),
+     "kv_tier needs the paged-KV decode path"),
+    ("faults", lambda v: isinstance(v, FaultPlan)
+     or FaultsConfig.coerce(v).enabled,
+     "the faults block needs the paged-KV decode path"),
+    ("shed_queue_depth", bool,
+     "load shedding lives in the paged-KV admission path"),
+    ("shed_expired_deadline", bool,
+     "load shedding lives in the paged-KV admission path"),
+)
+# accepted and unused on the encoder path, never an error: the request
+# tracer, history, incidents and devprof ride the decode scheduler's
+# lifecycle (queued/admitted/first-token/finish edges), and a drafter
+# instance is inert without a live speculative block
+_DECODER_ONLY_INERT = ("tracing", "history", "incidents", "devprof",
+                       "drafter")
 
 
-def mixtral_serving_engine(params, cfg, weight_dtype: str = "bfloat16",
-                           quant_group_size: int = 128, mesh=None,
-                           zero_inference=None, **kw) -> ServingEngine:
-    """ServingEngine over models/mixtral.py's paged MoE forward (ref:
-    DeepSpeed-MoE inference serving, deepspeed/inference/engine.py) —
-    iteration-level scheduling, paged KV, split-fuse and decode chunking
-    all apply to the MoE model unchanged.  ``zero_inference`` streams
-    the expert stacks (the dominant MoE weight bytes) from a host/NVMe
-    tier, like the llama builder."""
-    from deepspeed_tpu.models import mixtral
-
-    zi_engine = _route_zero_inference(
-        zero_inference, "mixtral", params, cfg, weight_dtype,
-        quant_group_size, mesh, kw)
-    if zi_engine is not None:
-        return zi_engine
-
-    # sharded MoE serving (ref: DeepSpeed-MoE inference — expert
-    # parallelism, optionally composed with Megatron TP): the stacked
-    # [L, E, ...] expert FFNs shard over the expert axis (XLA inserts
-    # the expert psum at the weighted combine), attention shards
-    # Megatron-style over the model axis, and the KV cache's head axis
-    # follows it.  The model's own param_specs is the single source of
-    # truth for which leaves shard; unused axes are size-1 no-ops.
-    sharded = mesh is not None and any(
-        mesh.size(ax) > 1 for ax in ("model", "expert"))
-    if sharded and cfg.num_experts % mesh.size("expert"):
-        raise ValueError(
-            f"num_experts {cfg.num_experts} not divisible by "
-            f"expert-axis size {mesh.size('expert')}")
-
-    kw["kernels"] = _resolve_kernels_for_builder(
-        kw.get("kernels"), mesh, kw.get("kv_tier"))
-    pk = kw["kernels"].paged_attention
-
-    def step(params, tokens, cache):
-        return mixtral.forward_paged(params, tokens, cfg, cache,
-                                     tp=sharded, paged_kernel=pk)
-
-    def chunk_step(params, tokens, cache):
-        return mixtral.forward_paged(params, tokens, cfg, cache,
-                                     continuation=True, tp=sharded,
-                                     paged_kernel=pk)
-
-    if weight_dtype != "bfloat16":
-        from deepspeed_tpu.inference.quantized import quantize_for_inference
-
-        # the router stays exact (int8 gate logits could flip a
-        # near-tied top-k choice) and so do the stacked norm gains
-        params, step, chunk_step = quantize_for_inference(
-            params, step, chunk_step, weight_dtype=weight_dtype,
-            group_size=quant_group_size,
-            skip_paths=("gate", "attn_norm", "mlp_norm", "final_norm"))
-
-    comm_stats = None
-    if sharded:
-        # expert FFNs shard over the expert axis, attention
-        # Megatron-style over model (ref: DeepSpeed-MoE inference)
-        cc = CommConfig.coerce(kw.get("comm"))
-        if cc.quantized_serving:
-            params, comm_stats = _quantized_shard_params(
-                params, mixtral.param_specs(cfg), mesh, cc)
-        else:
-            params = _shard_params_for_serving(
-                params, mixtral.param_specs(cfg), mesh)
-
-    eng = ServingEngine(
-        params, step, step, n_layers=cfg.n_layers, n_kv=cfg.n_kv_heads,
-        head_dim=cfg.head_dim, chunk_prefill_fn=chunk_step, mesh=mesh,
-        **kw)
-    if comm_stats is not None:
-        _record_comm_placement(eng, comm_stats)
-    return eng
-
-
-def gpt2_serving_engine(params, cfg, weight_dtype: str = "bfloat16",
-                        quant_group_size: int = 128, mesh=None,
-                        **kw) -> ServingEngine:
-    """ServingEngine over models/gpt2.py's paged forward (ref: the
-    reference serves GPT-2 through kernel injection,
-    deepspeed/module_inject/containers/gpt2.py)."""
-    from deepspeed_tpu.models import gpt2
-
-    # TP baked in at build time, like the llama builder: the compiled
-    # paths must not re-read the mutable ambient mesh on a retrace
-    tp = mesh is not None and mesh.size("model") > 1
-    if mesh is not None and mesh.size("expert") > 1:
-        raise ValueError(
-            "GPT-2 has no expert-parallel dimension — shard over the "
-            "model axis instead")
-    max_seq = kw.get("max_seq", 256)
-    if max_seq > cfg.max_seq_len:
-        # learned positions are HARD-bounded by the wpe table (unlike
-        # RoPE); past it jax's clamping gather would silently reuse the
-        # last position embedding
-        raise ValueError(
-            f"max_seq {max_seq} exceeds the learned position table "
-            f"(cfg.max_seq_len={cfg.max_seq_len})")
-
-    kw["kernels"] = _resolve_kernels_for_builder(
-        kw.get("kernels"), mesh, kw.get("kv_tier"))
-    pk = kw["kernels"].paged_attention
-
-    def step(params, tokens, cache):
-        return gpt2.forward_paged(params, tokens, cfg, cache, tp=tp,
-                                  paged_kernel=pk)
-
-    def chunk_step(params, tokens, cache):
-        return gpt2.forward_paged(params, tokens, cfg, cache,
-                                  continuation=True, tp=tp,
-                                  paged_kernel=pk)
-
-    if weight_dtype != "bfloat16":
-        from deepspeed_tpu.inference.quantized import quantize_for_inference
-
-        # only the matmul weights quantize: stacked biases/norm
-        # vectors and the (tiny, accuracy-critical) position table stay
-        # exact
-        params, step, chunk_step = quantize_for_inference(
-            params, step, chunk_step, weight_dtype=weight_dtype,
-            group_size=quant_group_size,
-            skip_paths=("ln1_w", "ln1_b", "ln2_w", "ln2_b", "qkv_b",
-                        "proj_b", "fc_b", "out_b", "lnf_w", "lnf_b",
-                        "wpe"))
-
-    comm_stats = None
-    if tp:
-        # ref: module_inject/containers/gpt2.py — fused qkv shards its
-        # output dim, proj/out row-parallel; biases on sharded outputs
-        # follow the column split
-        cc = CommConfig.coerce(kw.get("comm"))
-        if cc.quantized_serving:
-            params, comm_stats = _quantized_shard_params(
-                params, gpt2.param_specs(cfg), mesh, cc)
-        else:
-            params = _shard_params_for_serving(
-                params, gpt2.param_specs(cfg), mesh)
-
-    eng = ServingEngine(
-        params, step, step, n_layers=cfg.n_layers, n_kv=cfg.n_kv_heads,
-        head_dim=cfg.head_dim, chunk_prefill_fn=chunk_step, mesh=mesh,
-        **kw)
-    if comm_stats is not None:
-        _record_comm_placement(eng, comm_stats)
-    return eng
-
-
-def serving_engine(params, cfg, **kw):
-    """Model registry for serving: dispatch on the config type (ref:
-    init_inference accepting any supported model).  Decoder LMs get the
-    paged continuous-batching engine; encoder families get the
-    lot-batching :class:`~deepspeed_tpu.inference.encoder_serving.
-    EncoderServingEngine` (same submit/run surface, no decode loop)."""
+def _encoder_serving_engine(params, cfg, kw):
     from deepspeed_tpu.models.bert import BertConfig
     from deepspeed_tpu.models.cnn import CNNConfig
-    from deepspeed_tpu.models.gpt2 import GPT2Config
-    from deepspeed_tpu.models.llama import LlamaConfig
-    from deepspeed_tpu.models.mixtral import MixtralConfig
 
-    if isinstance(cfg, MixtralConfig):
-        return mixtral_serving_engine(params, cfg, **kw)
-    if isinstance(cfg, LlamaConfig):
-        return llama_serving_engine(params, cfg, **kw)
-    zi = kw.pop("zero_inference", None)
-    if zi is not None:
-        from deepspeed_tpu.config import ZeroInferenceConfig
-
-        if ZeroInferenceConfig.coerce(zi).enabled:
-            # weight streaming needs the per-layer paged factoring,
-            # which the layered decoder families provide (llama +
-            # mixtral); fail loudly, never silently serve resident
+    decoders = ", ".join(f.name for f in decoder_families())
+    for key, live, what in _DECODER_ONLY:
+        value = kw.pop(key, None)
+        if value is not None and live(value):
             raise NotImplementedError(
-                f"zero_inference streaming is not wired for "
-                f"{type(cfg).__name__} — supported: LlamaConfig, "
-                "MixtralConfig")
-    if isinstance(cfg, GPT2Config):
-        return gpt2_serving_engine(params, cfg, **kw)
-    # per-request tracing lives in the paged-KV decode scheduler's
-    # lifecycle (queued/admitted/first-token/finish edges); the encoder
-    # engines are fixed-shape batch scorers with no such lifecycle —
-    # the block is accepted and unused there, never an error.  The
-    # history/incidents/devprof blocks ride the same lifecycle
-    # (exporter tick hooks + flight-recorder triggers + the compile
-    # sentinel's steady-state boundary at first token) and are likewise
-    # accepted and unused on the encoder path.
-    kw.pop("tracing", None)
-    kw.pop("history", None)
-    kw.pop("incidents", None)
-    kw.pop("devprof", None)
-    kn = kw.pop("kernels", None)
-    if kn is not None:
-        from deepspeed_tpu.config import KernelsConfig
-
-        k = KernelsConfig.coerce(kn)
-        if k.paged_attention != "auto" or k.fused_sampling != "auto":
-            # the kernels block names paged-attention/sampling
-            # dispatches; encoder engines have neither a paged cache
-            # nor a decode sampler — fail loudly, never silently
-            # serve a different kernel than the one pinned
-            raise NotImplementedError(
-                f"the kernels block pins paged-KV decode kernels, "
-                f"which {type(cfg).__name__} does not serve — "
-                "supported: LlamaConfig, MixtralConfig, GPT2Config")
-    cm = kw.pop("comm", None)
-    if cm is not None and CommConfig.coerce(cm).quantized_serving:
-        # quantized placement rides the TP replica upload / ZI layer
-        # stream, neither of which the encoder engines have — fail
-        # loudly, never silently place full-precision weights under a
-        # config that pinned the int8 wire
-        raise NotImplementedError(
-            f"comm.quantized_serving quantizes TP replica weight "
-            f"placement, which {type(cfg).__name__} does not serve — "
-            "supported: LlamaConfig, MixtralConfig, GPT2Config")
-    sp = kw.pop("speculative", None)
-    kw.pop("drafter", None)
-    if sp is not None and SpeculativeConfig.coerce(sp).enabled:
-        # speculation lives in the paged-KV decode loop; the encoder
-        # engines have no decode loop to speculate — fail loudly,
-        # never silently serve unaccelerated
-        raise NotImplementedError(
-            f"speculative decoding needs the paged-KV decode path, "
-            f"which {type(cfg).__name__} does not serve — supported: "
-            "LlamaConfig, MixtralConfig, GPT2Config")
-    so = kw.pop("slo", None)
-    if so is not None and SLOConfig.coerce(so).enabled:
-        # SLO classification hangs off the decode scheduler's lifecycle
-        # (submit/first-token/finish edges); the encoder engines score
-        # fixed-shape lots with no such lifecycle — fail loudly, never
-        # silently drop a latency objective
-        raise NotImplementedError(
-            f"the slo block needs the paged-KV decode path, which "
-            f"{type(cfg).__name__} does not serve — supported: "
-            "LlamaConfig, MixtralConfig, GPT2Config")
-    pc = kw.pop("prefix_cache", None)
-    if pc is not None and PrefixCacheConfig.coerce(pc).enabled:
-        # prefix caching lives in the paged-KV decode scheduler; the
-        # encoder engines are fixed-shape batch scorers with no pages
-        # to share — fail loudly, never silently serve uncached
-        raise NotImplementedError(
-            f"prefix_cache needs the paged-KV decode path, which "
-            f"{type(cfg).__name__} does not serve — supported: "
-            "LlamaConfig, MixtralConfig, GPT2Config")
-    kvt = kw.pop("kv_tier", None)
-    if kvt is not None and KVTierConfig.coerce(kvt).enabled:
-        # the tiered KV cache spills PAGES of the prefix pool; encoder
-        # families have neither — fail loudly, never silently drop the
-        # capacity the block was written for
-        raise NotImplementedError(
-            f"kv_tier needs the paged-KV decode path, which "
-            f"{type(cfg).__name__} does not serve — supported: "
-            "LlamaConfig, MixtralConfig, GPT2Config")
-    fl = kw.pop("faults", None)
-    if fl is not None and (isinstance(fl, FaultPlan)
-                           or FaultsConfig.coerce(fl).enabled):
-        # fault injection exercises the paged scheduler's isolation/
-        # shed/fallback machinery; the encoder engines have none of it
-        # — fail loudly, never silently skip the chaos the block asked
-        # for
-        raise NotImplementedError(
-            f"the faults block needs the paged-KV decode path, which "
-            f"{type(cfg).__name__} does not serve — supported: "
-            "LlamaConfig, MixtralConfig, GPT2Config")
-    if kw.pop("shed_queue_depth", 0) or kw.pop("shed_expired_deadline",
-                                               False):
-        raise NotImplementedError(
-            f"load shedding lives in the paged-KV admission path, "
-            f"which {type(cfg).__name__} does not serve — supported: "
-            "LlamaConfig, MixtralConfig, GPT2Config")
+                f"{what}, which {type(cfg).__name__} does not serve — "
+                f"supported: {decoders}")
+    for key in _DECODER_ONLY_INERT:
+        kw.pop(key, None)
     if isinstance(cfg, BertConfig):
         from deepspeed_tpu.inference.encoder_serving import (
             bert_serving_engine)
@@ -3901,5 +3578,110 @@ def serving_engine(params, cfg, **kw):
         return CNNServingEngine(params, cfg=cfg, **kw)
     raise TypeError(
         f"no serving path for config type {type(cfg).__name__}; "
-        "supported: LlamaConfig, MixtralConfig, GPT2Config, BertConfig, "
-        "CNNConfig")
+        f"supported: {decoders}, BertConfig, CNNConfig")
+
+
+def serving_engine(params, cfg, **kw):
+    """The one serving builder: dispatch on the config type (ref:
+    init_inference accepting any supported model).  A decoder family
+    (:func:`~deepspeed_tpu.models.family.decoder_family`) gets the paged
+    continuous-batching :class:`ServingEngine` over
+    :func:`~deepspeed_tpu.inference.paged_forward.forward_paged`;
+    encoder families get the lot-batching :class:`~deepspeed_tpu.
+    inference.encoder_serving.EncoderServingEngine` (same submit/run
+    surface, no decode loop).
+
+    ``weight_dtype="int8"``: weight-only quantized serving (ref:
+    init_inference(dtype=int8)) — int8 codes + group scales
+    (``quant_group_size``) in HBM, half the bf16 weight residency,
+    dequant traced into the forward; the family's ``quant_skip_paths``
+    stay exact.
+
+    ``mesh``: sharded serving (ref: replace_module.py TP injection;
+    DeepSpeed-MoE inference's expert parallelism) — params shard by the
+    family's own ``param_specs`` over its ``shard_axes``, the KV cache
+    shards its head axis over ``model``, and both jits run under GSPMD
+    with the psums inserted by XLA.
+
+    ``zero_inference``: a :class:`~deepspeed_tpu.config.
+    ZeroInferenceConfig` (or its dict form) routes to the weight-
+    streamed ZeRO-Inference engine — layer weights live on a host/NVMe
+    tier and stream through a double-buffered HBM working set, so the
+    served model's weight image may exceed HBM.
+    """
+    try:
+        fam = decoder_family(cfg)
+    except TypeError:
+        return _encoder_serving_engine(params, cfg, kw)
+    # the decoder build stays in THIS function: a helper's frame between
+    # here and ServingEngine.__init__ cost 2-3 s of set-up on the chip's
+    # host, the warm-up's lowering loop being that deep (PERF.md 6, PR 30)
+    weight_dtype = kw.pop("weight_dtype", "bfloat16")
+    quant_group_size = kw.pop("quant_group_size", 128)
+    mesh = kw.pop("mesh", None)
+    zero_inference = kw.pop("zero_inference", None)
+    fam.check(cfg, mesh, kw.get("max_seq", 256))
+    # sharded-ness is baked in at BUILD time: the compiled paths must not
+    # re-read the mutable ambient mesh on a later retrace (a cleared or
+    # replaced global would silently re-enable pallas kernels over the
+    # sharded cache)
+    sharded = fam.sharded(mesh)
+    # the kernel policy resolves HERE (config + env, once), with the SAME
+    # predicates the engine uses (any model/expert axis > 1 demotes forced
+    # pallas — the kernels read the full page table per device; an
+    # int8-resident cache on a chip refuses it), and the same
+    # ServingKernelPolicy passes through to the engine: the paged_kernel
+    # the closures bake and the policy /statusz reports are one object
+    kvt = KVTierConfig.coerce(kw.get("kv_tier"))
+    kw["kernels"] = resolve_serving_kernels(
+        kw.get("kernels"),
+        tp=mesh is not None and any(
+            mesh.size(ax) > 1 for ax in ("model", "expert")),
+        interpret=jax.default_backend() != "tpu",
+        quantized_resident=kvt.enabled and kvt.quantized_resident)
+    pk = kw["kernels"].paged_attention
+
+    zi = ZeroInferenceConfig.coerce(zero_inference)
+    if zi.enabled:
+        from deepspeed_tpu.inference.zero_inference import (
+            zero_inference_serving_engine)
+
+        return zero_inference_serving_engine(
+            params, cfg, zi, family=fam, weight_dtype=weight_dtype,
+            quant_group_size=quant_group_size, mesh=mesh, **kw)
+
+    def step(params, tokens, cache):
+        return forward_paged(params, tokens, cfg, cache, tp=sharded,
+                             paged_kernel=pk)
+
+    def chunk_step(params, tokens, cache):
+        return forward_paged(params, tokens, cfg, cache, continuation=True,
+                             tp=sharded, paged_kernel=pk)
+
+    if weight_dtype != "bfloat16":
+        from deepspeed_tpu.inference.quantized import quantize_for_inference
+
+        # raises on anything but "int8" — never silently serve unquantized
+        params, step, chunk_step = quantize_for_inference(
+            params, step, chunk_step, weight_dtype=weight_dtype,
+            group_size=quant_group_size, skip_paths=fam.quant_skip_paths)
+
+    comm_stats = None
+    if sharded:
+        cc = CommConfig.coerce(kw.get("comm"))
+        if cc.quantized_serving:
+            # the training int8 wire reused for replica placement: H2D
+            # ships codes + scales, gated by serving_rtol per leaf
+            params, comm_stats = _quantized_shard_params(
+                params, fam.param_specs(cfg), mesh, cc)
+        else:
+            params = _shard_params_for_serving(
+                params, fam.param_specs(cfg), mesh)
+
+    eng = ServingEngine(
+        params, step, step, n_layers=cfg.n_layers, n_kv=cfg.n_kv_heads,
+        head_dim=cfg.head_dim, chunk_prefill_fn=chunk_step, mesh=mesh,
+        **kw)
+    if comm_stats is not None:
+        _record_comm_placement(eng, comm_stats)
+    return eng

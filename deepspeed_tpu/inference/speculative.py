@@ -140,10 +140,9 @@ class NgramDrafter(Drafter):
 
 class ModelDrafter(Drafter):
     """Resident small-model drafter: greedy ``k``-token rollout of a
-    draft model over the tail of the history, reusing the model
-    family's cached forward (the same per-family step the generators
-    run — see :func:`~deepspeed_tpu.inference.generation.
-    greedy_draft_fn`).
+    draft model over the tail of the history, reusing the
+    contiguous-cache forward (the same step the generators run — see
+    :func:`~deepspeed_tpu.inference.generation.greedy_draft_fn`).
 
     The history tail is LEFT-padded to a fixed ``window`` so the
     rollout compiles once; padding (and the shifted absolute positions
@@ -158,23 +157,12 @@ class ModelDrafter(Drafter):
                  window: int = 64):
         from deepspeed_tpu.inference.generation import (cached_step_alloc,
                                                         greedy_draft_fn)
-        from deepspeed_tpu.models.gpt2 import GPT2Config
-        from deepspeed_tpu.models.llama import LlamaConfig
-        from deepspeed_tpu.models.mixtral import MixtralConfig
+        from deepspeed_tpu.models.family import decoder_family
 
-        if isinstance(cfg, MixtralConfig):
-            from deepspeed_tpu.models import mixtral as fam
-        elif isinstance(cfg, LlamaConfig):
-            from deepspeed_tpu.models import llama as fam
-        elif isinstance(cfg, GPT2Config):
-            from deepspeed_tpu.models import gpt2 as fam
-            # learned positions are hard-bounded by the wpe table
-            window = min(window, cfg.max_seq_len - draft_tokens)
-        else:
-            raise TypeError(
-                f"no draft forward for config type "
-                f"{type(cfg).__name__}; supported: LlamaConfig, "
-                "MixtralConfig, GPT2Config")
+        bound = decoder_family(cfg).max_positions(cfg)
+        if bound is not None:
+            # learned positions are hard-bounded by the table
+            window = min(window, bound - draft_tokens)
         self.params = params
         self.k = int(draft_tokens)
         self.window = int(window)
@@ -182,7 +170,7 @@ class ModelDrafter(Drafter):
             raise ValueError(
                 f"draft_tokens and window must be >= 1, got "
                 f"{draft_tokens}/{window}")
-        step, alloc = cached_step_alloc(fam.forward_with_cache, cfg)
+        step, alloc = cached_step_alloc(cfg)
         self._rollout = greedy_draft_fn(step, alloc, self.window, self.k)
 
     def propose(self, tokens: Sequence[int], k: int) -> List[int]:

@@ -15,8 +15,8 @@ ServingEngine`); this module re-factors the MODEL the same way the
 training :class:`~deepspeed_tpu.param_stream.ParamStreamEngine` does —
 per-LAYER jits instead of one whole-model jit:
 
-    stem:   (stem, tokens, start) -> (x, cos, sin)       [resident]
-    block:  (lp, x, cos, sin, kp, vp, table, start)
+    stem:   (stem, tokens, start) -> (x, ctx)            [resident]
+    block:  (lp, x, ctx, kp, vp, table, start)
             -> (x, kp, vp)                               [one layer]
     head:   (head, x) -> logits                          [resident]
 
@@ -75,9 +75,9 @@ import jax.numpy as jnp
 from deepspeed_tpu.config import KVTierConfig, ZeroInferenceConfig
 from deepspeed_tpu.infinity import _NvmeTier, _RamTier
 from deepspeed_tpu.inference.kernels import PagedKVCache
-from deepspeed_tpu.inference.serving import (_WIRE_MIN_ELEMS,
-                                             ServingEngine,
-                                             _resolve_kernels_for_builder)
+from deepspeed_tpu.inference.paged_forward import paged_layered_fns
+from deepspeed_tpu.inference.serving import _WIRE_MIN_ELEMS, ServingEngine
+from deepspeed_tpu.models.family import decoder_families
 from deepspeed_tpu.param_stream import TierLayerReader
 from deepspeed_tpu.utils.logging import logger
 
@@ -469,7 +469,7 @@ class ZeroInferenceServingEngine(ServingEngine):
             f = functools.partial(self._block_fn,
                                   continuation=phase == "chunk",
                                   prefill=phase == "prefill")
-            self._bjits[phase] = jax.jit(f, donate_argnums=(4, 5))
+            self._bjits[phase] = jax.jit(f, donate_argnums=(3, 4))
         return self._bjits[phase]
 
     # ------------------------------------------------------ layer sweep
@@ -502,13 +502,12 @@ class ZeroInferenceServingEngine(ServingEngine):
 
     # ------------------------------------------------ streamed executors
     # dstpu: hot-path
-    def _run_blocks(self, phase, x, cos, sin, k_list, v_list, table,
-                    start):
+    def _run_blocks(self, phase, x, ctx, k_list, v_list, table, start):
         bj = self._block_jit(phase)
         t0 = time.perf_counter() if self._tel_on else 0.0
         for l, lp in self._layer_sweep():
             x, k_list[l], v_list[l] = bj(
-                lp, x, cos, sin, k_list[l], v_list[l], table, start)
+                lp, x, ctx, k_list[l], v_list[l], table, start)
         if self._tel_on and self._streamed_ids:
             dt = time.perf_counter() - t0
             if dt > 0:
@@ -520,9 +519,9 @@ class ZeroInferenceServingEngine(ServingEngine):
     def _forward_view(self, phase, toks, view):
         k_list, v_list = list(view.k), list(view.v)
         start = view.seq_lens
-        x, cos, sin = self._stem_jit(self._stem_dev, toks, start)
-        x = self._run_blocks(phase, x, cos, sin, k_list, v_list,
-                             view.table, start)
+        x, ctx = self._stem_jit(self._stem_dev, toks, start)
+        x = self._run_blocks(phase, x, ctx, k_list, v_list, view.table,
+                             start)
         logits = self._head_jit(self._head_dev, x)
         return logits, view._replace(k=tuple(k_list), v=tuple(v_list))
 
@@ -554,8 +553,8 @@ class ZeroInferenceServingEngine(ServingEngine):
         cols = []
         for j in range(K):
             start = lens + j if j else lens
-            x, cos, sin = self._stem_jit(self._stem_dev, tok, start)
-            x = self._run_blocks("decode", x, cos, sin, k_list, v_list,
+            x, ctx = self._stem_jit(self._stem_dev, tok, start)
+            x = self._run_blocks("decode", x, ctx, k_list, v_list,
                                  cache.table, start)
             logits = self._head_jit(self._head_dev, x)
             # the policy-resolved sampler (base ctor): the fused pallas
@@ -755,58 +754,35 @@ class ZeroInferenceServingEngine(ServingEngine):
         return self.plan["hbm_working_set_bytes"]
 
 
-# --------------------------------------------------------------- builders
-_FAMILY_SKIPS = {
-    # same exact-leaf sets as the resident serving builders — the
-    # quantization grid must match or streamed/resident outputs diverge
-    "llama": ("attn_norm", "mlp_norm", "final_norm"),
-    "mixtral": ("gate", "attn_norm", "mlp_norm", "final_norm"),
-}
-
-
-def zero_inference_serving_engine(params, cfg, zi, *, family: str,
+# ---------------------------------------------------------------- builder
+def zero_inference_serving_engine(params, cfg, zi, *, family, kernels,
                                   weight_dtype: str = "bfloat16",
                                   quant_group_size: int = 128,
                                   mesh=None, **kw
                                   ) -> ZeroInferenceServingEngine:
-    """Build the weight-streamed serving engine for a layered decoder
-    family (ref: deepspeed-inference's init_inference with ZeRO-
-    Inference offload enabled).  ``zi.dtype`` overrides
-    ``weight_dtype``; int8 quantizes on the SAME per-leaf grid as the
-    resident builders, so streamed int8 serving is token-identical to
-    resident int8 serving."""
-    zi = ZeroInferenceConfig.coerce(zi)
-    if family not in _FAMILY_SKIPS:
+    """Build the weight-streamed serving engine (ref: deepspeed-
+    inference's init_inference with ZeRO-Inference offload enabled);
+    :func:`~deepspeed_tpu.inference.serving.serving_engine` routes a live
+    ``zero_inference`` block here with ``family``, the config's
+    :class:`~deepspeed_tpu.models.family.DecoderFamily`, and ``kernels``,
+    the policy it resolved: the per-layer block programs bake its
+    ``paged_attention`` and the engine reports the same policy in
+    /statusz.  ``zi.dtype`` overrides ``weight_dtype``; int8 quantizes on
+    the family's one per-leaf grid (``quant_skip_paths``), so streamed
+    int8 serving is token-identical to resident int8 serving."""
+    if family.streamed_split is None:
         raise NotImplementedError(
-            f"zero-inference streaming supports llama/mixtral, got "
-            f"{family!r}")
-    tp = mesh is not None and mesh.size("model") > 1
-    sharded = mesh is not None and any(
-        mesh.size(ax) > 1 for ax in ("model", "expert"))
-    # one kernel-policy resolution per build, like the resident
-    # builders: the per-layer block programs bake the resolved
-    # paged_kernel and the engine reports the same policy in /statusz
-    kw["kernels"] = _resolve_kernels_for_builder(kw.get("kernels"), mesh)
-    pk = kw["kernels"].paged_attention
-    if family == "mixtral":
-        from deepspeed_tpu.models import mixtral as fam
+            f"zero_inference streaming needs a family's streamed split, "
+            f"which {family.name} does not state — supported: "
+            + ", ".join(f.name for f in decoder_families()
+                        if f.streamed_split is not None))
+    sharded = family.sharded(mesh)
+    fns = paged_layered_fns(cfg, tp=sharded,
+                            paged_kernel=kernels.paged_attention)
 
-        if sharded and cfg.num_experts % mesh.size("expert"):
-            raise ValueError(
-                f"num_experts {cfg.num_experts} not divisible by "
-                f"expert-axis size {mesh.size('expert')}")
-        fns = fam.paged_layered_fns(cfg, tp=sharded, paged_kernel=pk)
-    else:
-        from deepspeed_tpu.models import llama as fam
-
-        fns = fam.paged_layered_fns(cfg, tp=tp, paged_kernel=pk)
-
-    stem = {"embed": params["embed"]}
-    head = {"final_norm": params["final_norm"]}
-    if getattr(cfg, "tie_embeddings", False):
-        head["embed"] = params["embed"]
-    else:
-        head["lm_head"] = params["lm_head"]
+    stem_keys, head_keys = family.streamed_split(cfg)
+    stem = {k: params[k] for k in stem_keys}
+    head = {k: params[k] for k in head_keys}
     blocks = params["blocks"]
 
     wd = zi.dtype or weight_dtype
@@ -817,22 +793,21 @@ def zero_inference_serving_engine(params, cfg, zi, *, family: str,
                 f"got {wd!r}")
         from deepspeed_tpu.inference.quantized import quantize_params
 
-        skips = _FAMILY_SKIPS[family]
         q = lambda t: quantize_params(t, group_size=quant_group_size,
-                                      skip_paths=skips)
+                                      skip_paths=family.quant_skip_paths)
         stem, blocks = q(stem), q(blocks)
-        # tied embeddings: quantize the shared table ONCE and alias the
-        # object — the engine dedupes shared leaves by identity, both
-        # for the planner's byte accounting and the device placement
-        head = q({k: v for k, v in head.items() if k != "embed"})
-        if getattr(cfg, "tie_embeddings", False):
-            head["embed"] = stem["embed"]
+        # a leaf in both (tied embeddings): quantize the shared table ONCE
+        # and alias the object — the engine dedupes shared leaves by
+        # identity, both for the planner's byte accounting and the device
+        # placement
+        head = {**q({k: v for k, v in head.items() if k not in stem}),
+                **{k: stem[k] for k in head if k in stem}}
 
     stem_specs = head_specs = layer_specs = None
     if sharded:
         from jax.sharding import PartitionSpec as P
 
-        specs = fam.param_specs(cfg)
+        specs = family.param_specs(cfg)
 
         def drop_layer_dim(spec):
             if spec is None:
@@ -846,15 +821,12 @@ def zero_inference_serving_engine(params, cfg, zi, *, family: str,
         layer_specs = jax.tree.map(
             drop_layer_dim, specs["blocks"],
             is_leaf=lambda s: s is None or isinstance(s, P))
-        stem_specs = {"embed": specs["embed"]}
-        head_specs = {"final_norm": specs["final_norm"]}
-        if getattr(cfg, "tie_embeddings", False):
-            head_specs["embed"] = specs["embed"]
-        else:
-            head_specs["lm_head"] = specs["lm_head"]
+        stem_specs = {k: specs[k] for k in stem_keys}
+        head_specs = {k: specs[k] for k in head_keys}
 
     return ZeroInferenceServingEngine(
         stem=stem, blocks=blocks, head=head, fns=fns, zi=zi,
         n_layers=cfg.n_layers, n_kv=cfg.n_kv_heads,
         head_dim=cfg.head_dim, mesh=mesh, stem_specs=stem_specs,
-        head_specs=head_specs, layer_specs=layer_specs, **kw)
+        head_specs=head_specs, layer_specs=layer_specs, kernels=kernels,
+        **kw)

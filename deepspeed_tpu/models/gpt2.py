@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.models.family import DecoderFamily, positions_from
 from deepspeed_tpu.ops.fused_ops import layer_norm
 
 
@@ -161,34 +162,6 @@ def forward(params, tokens, cfg: GPT2Config):
     return _head(params, x, cfg)
 
 
-def forward_with_cache(params, tokens, cfg: GPT2Config, cache):
-    """Incremental forward for generation (same KV-cache contract as
-    models/llama.py forward_with_cache; MHA so KV == H).
-
-    tokens: [B, T] → (logits [B, T, V] f32, updated cache).
-    """
-    from deepspeed_tpu.inference.generation import cached_attention
-
-    B, T = tokens.shape
-    nh, hd = cfg.n_heads, cfg.head_dim
-    start = cache.length
-    pos = start + jnp.arange(T, dtype=jnp.int32)
-    with jax.named_scope("embed"):
-        x = params["wte"][tokens] + params["wpe"][pos][None]
-
-    def block(x, layer):
-        lp, kc, vc = layer
-        q, k, v = _qkv(cfg, x, lp)
-        with jax.named_scope("kv_attend"):
-            attn, kc, vc = cached_attention(q, kc, vc, k, v, start)
-        return _out_mlp(cfg, x, attn.reshape(B, T, nh * hd), lp), (kc, vc)
-
-    x, (new_k, new_v) = jax.lax.scan(block, x,
-                                     (params["blocks"], cache.k, cache.v))
-    logits = _head(params, x, cfg)
-    return logits, cache._replace(k=new_k, v=new_v, length=start + T)
-
-
 def loss_fn(cfg: GPT2Config):
     def f(params, batch):
         if "segment_ids" in batch:
@@ -206,58 +179,34 @@ def loss_fn(cfg: GPT2Config):
     return f
 
 
-def forward_paged(params, tokens, cfg: GPT2Config, cache,
-                  interpret=None, continuation: bool = False, tp=None,
-                  paged_kernel=None):
-    """Paged-KV forward for continuous-batching serving (ref: the
-    reference's GPT-2 kernel-injection container,
-    deepspeed/module_inject/containers/gpt2.py — GPT-2 is served through
-    the same inference engine as llama-family models).
-
-    Shares the per-layer paged machinery (page writes, decode/chunk
-    dispatch) with models/llama.py via
-    :func:`~deepspeed_tpu.inference.kernels.paged_attention_step`; the
-    GPT-2 block itself differs (learned positions added at the ragged
-    per-row frontier, LayerNorm+bias, fused QKV, GELU MLP, tied head).
-    tokens: [B, T] → (logits [B, T, V] f32, cache).
-
-    Multi-position decode contract: ``continuation=True`` returns
-    logits at EVERY position (speculative verify scores K+1 draft
-    positions in one call).  Draft positions past the learned table
-    CLAMP into the last wpe row — harmless, because an acceptance at
-    such a position would exceed the request's token budget and the
-    host discards it (the engine bounds real positions by max_seq)."""
-    from deepspeed_tpu.inference.kernels import (paged_attention_step,
-                                                 paged_forward_prelude,
-                                                 paged_layer_loop,
-                                                 paged_reader)
-
-    B, T = tokens.shape
-    nh, hd, d = cfg.n_heads, cfg.head_dim, cfg.dim
-    interpret, tp, start, prefill = paged_forward_prelude(
-        cache, tokens, interpret, tp, continuation)
-    # per-sequence position offsets: ragged frontiers under continuous
-    # batching index each row's learned positions by ITS seq_len.
-    # Learned positions are HARD-bounded by the table (unlike RoPE);
-    # serving/generator builders validate max_seq <= cfg.max_seq_len.
-    positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+def _embed(params, tokens, start, cfg: GPT2Config):
+    """Token + learned position embeddings from ``start`` on."""
+    pos = positions_from(start, tokens.shape[1])
     with jax.named_scope("embed"):
-        x = params["wte"][tokens] + params["wpe"][positions]
+        return params["wte"][tokens] + params["wpe"][pos], ()
 
-    paged_kernel, _ = paged_reader(
-        paged_kernel, decode=T == 1, tp=tp, interpret=interpret,
-        quant=cache.k_scale is not None)
 
-    def block(x, lp, layer, kp, vp, kps, vps):
-        q, k, v = _qkv(cfg, x, lp)
-        attn, kp, vp, kps, vps = paged_attention_step(
-            q, k, v, kp, vp, layer, cache.table, start,
-            continuation=continuation, prefill=prefill,
-            paged_kernel=paged_kernel, flash_force_reference=tp,
-            interpret=interpret, kps=kps, vps=vps)
-        return (_out_mlp(cfg, x, attn.reshape(B, T, d), lp),
-                kp, vp, kps, vps)
+def _check(cfg: GPT2Config, mesh, max_seq: int) -> None:
+    if mesh is not None and mesh.size("expert") > 1:
+        raise ValueError(
+            "GPT-2 has no expert-parallel dimension — shard over the "
+            "model axis instead")
+    if max_seq > cfg.max_seq_len:
+        # learned positions are HARD-bounded by the wpe table (unlike
+        # RoPE); past it jax's clamping gather would silently reuse the
+        # last position embedding
+        raise ValueError(
+            f"max_seq {max_seq} exceeds the learned position table "
+            f"(cfg.max_seq_len={cfg.max_seq_len})")
 
-    x, cache = paged_layer_loop(block, x, params["blocks"], cache)
-    logits = _head(params, x, cfg)
-    return logits, cache._replace(seq_lens=start + T)
+
+# served like the llama family (ref: the reference's GPT-2 kernel-injection
+# container, deepspeed/module_inject/containers/gpt2.py).  Only the matmul
+# weights quantize: stacked biases/norm vectors and the (tiny,
+# accuracy-critical) position table stay exact.  No streamed split.
+FAMILY = DecoderFamily(
+    config_type=GPT2Config, embed=_embed, qkv=_qkv, out=_out_mlp,
+    head=_head, param_specs=param_specs,
+    quant_skip_paths=("ln1_w", "ln1_b", "ln2_w", "ln2_b", "qkv_b",
+                      "proj_b", "fc_b", "out_b", "lnf_w", "lnf_b", "wpe"),
+    check=_check, max_positions=lambda cfg: cfg.max_seq_len)

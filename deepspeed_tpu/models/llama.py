@@ -32,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.models.family import DecoderFamily, positions_from
+
 
 @dataclasses.dataclass
 class LlamaConfig:
@@ -261,8 +263,8 @@ def reference_attention(q, k, v, causal=True, segment_ids=None):
 
 def _qkv(cfg, x, lp, cos, sin):
     """Pre-norm + Q/K/V projections + RoPE → q [B, T, H, hd], k and v
-    [B, T, KV, hd].  Shared by every forward (training, cached, paged,
-    layer-streamed; Mixtral's too) so the paths cannot drift."""
+    [B, T, KV, hd].  Shared by training's block and the family record
+    (Mixtral's too) so the paths cannot drift."""
     B, T, _ = x.shape
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     with jax.named_scope("attn_qkv"):
@@ -370,177 +372,6 @@ def forward(params, tokens, cfg: LlamaConfig, positions=None,
     with jax.named_scope("lm_head"):
         return jnp.einsum("btd,dv->btv", x, lm_head(params, cfg),
                           preferred_element_type=jnp.float32)
-
-
-def forward_with_cache(params, tokens, cfg: LlamaConfig, cache):
-    """Incremental forward for generation: attends to cache[:len]+tokens,
-    writes new K/V at position ``cache.length`` (ref: the reference's
-    inference transformer kernels' KV-cache contract).
-
-    tokens: [B, T] → (logits [B, T, V] f32, updated cache).
-    """
-    from deepspeed_tpu.inference.generation import cached_attention
-
-    B, T = tokens.shape
-    hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    start = cache.length
-    with jax.named_scope("embed"):
-        x = params["embed"][tokens]
-        positions = start + jnp.arange(T, dtype=jnp.int32)
-        cos, sin = rope_tables(cfg, positions)
-
-    def block(x, layer):
-        lp, kc, vc = layer
-        q, k, v = _qkv(cfg, x, lp, cos, sin)
-        with jax.named_scope("kv_attend"):
-            attn, kc, vc = cached_attention(q, kc, vc, k, v, start)
-        return _out_ffn(cfg, x, attn.reshape(B, T, nh * hd), lp), (kc, vc)
-
-    x, (new_k, new_v) = jax.lax.scan(block, x,
-                                     (params["blocks"], cache.k, cache.v))
-    logits = _head(params, x, cfg)
-    cache = cache._replace(k=new_k, v=new_v, length=start + T)
-    return logits, cache
-
-
-def forward_paged(params, tokens, cfg: LlamaConfig, cache,
-                  interpret: Optional[bool] = None,
-                  continuation: bool = False, ffn=None,
-                  tp: Optional[bool] = None,
-                  paged_kernel: Optional[str] = None):
-    """Forward over a paged KV cache (ref: the reference's inference
-    kernels' workspace contract, modernised to vLLM-style page tables).
-
-    ``ffn``: optional ``(lp, h) -> y`` override of the per-block FFN —
-    the paged-attention backbone is model-agnostic, and MoE families
-    (models/mixtral.py) reuse it by swapping in their expert combine.
-
-    ``tp``: True = params/cache are model-axis sharded, so every pallas
-    path (paged kernels AND the prefill flash kernel) must yield to the
-    GSPMD-partitionable XLA formulations.  Serving closures pass this
-    EXPLICITLY at build time — correctness must not hang off the mutable
-    ambient mesh, which is only consulted when ``tp`` is None (direct
-    callers).
-
-    Prefill (T > 1, empty cache): dense causal attention over the prompt,
-    K/V bulk-written into pages.  Decode (T == 1): pallas paged attention
-    streaming only the live pages.  ``continuation=True`` (T > 1,
-    non-empty cache): chunked prefill — the chunk's K/V scatter in at
-    each row's frontier and attention runs over history + chunk (the
-    FastGen split-fuse read path).  tokens: [B, T] → (logits, cache).
-
-    Multi-position decode contract: the continuation path returns
-    logits at EVERY position, not just the last — the serving engine's
-    speculative verify depends on it to score a K+1-token draft window
-    in one sweep (custom ``chunk_prefill_fn`` replacements must honor
-    this; see MIGRATION.md).
-
-    ``paged_kernel``: the paged-attention policy the serving build
-    resolved (``resolve_serving_kernels``): a forced "xla" | "pallas_v1"
-    | "pallas_v2", or None/"auto", which ``paged_reader`` answers from
-    the phase and the layout (decode on one device over float pages
-    reads live pages through the Mosaic kernel; else the gather).  A
-    cache carrying ``k_scale`` planes
-    is int8-resident (``kv_tier.quantized_resident``): writes quantize
-    per token row on device and attention dequantizes in VMEM
-    ("pallas_v2") or via :func:`~deepspeed_tpu.inference.kernels.
-    dequantize_pages` ("xla").
-    """
-    from deepspeed_tpu.inference.kernels import (paged_attention_step,
-                                                 paged_forward_prelude,
-                                                 paged_layer_loop,
-                                                 paged_reader)
-
-    B, T = tokens.shape
-    hd, nh = cfg.head_dim, cfg.n_heads
-    interpret, tp_active, start, prefill = paged_forward_prelude(
-        cache, tokens, interpret, tp, continuation)
-    with jax.named_scope("embed"):
-        x = params["embed"][tokens]
-        # per-sequence position offsets: ragged frontiers under
-        # continuous batching rotate each row by ITS seq_len, not row 0's
-        positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
-        cos, sin = rope_tables(cfg, positions)
-
-    paged_kernel, _ = paged_reader(
-        paged_kernel, decode=T == 1, tp=tp_active, interpret=interpret,
-        quant=cache.k_scale is not None)
-
-    def block(x, lp, layer, kp, vp, kps, vps):
-        q, k, v = _qkv(cfg, x, lp, cos, sin)
-        attn, kp, vp, kps, vps = paged_attention_step(
-            q, k, v, kp, vp, layer, cache.table, start,
-            continuation=continuation, prefill=prefill,
-            paged_kernel=paged_kernel, flash_force_reference=tp_active,
-            interpret=interpret, kps=kps, vps=vps)
-        x = _out_ffn(cfg, x, attn.reshape(B, T, nh * hd), lp, ffn=ffn)
-        return x, kp, vp, kps, vps
-
-    x, cache = paged_layer_loop(block, x, params["blocks"], cache)
-    logits = _head(params, x, cfg)
-    return logits, cache._replace(seq_lens=start + T)
-
-
-def paged_layered_fns(cfg: LlamaConfig, tp: bool = False, ffn=None,
-                      interpret: Optional[bool] = None,
-                      paged_kernel: Optional[str] = None):
-    """Per-layer factoring of :func:`forward_paged` for weight-streamed
-    (ZeRO-Inference) serving — the serving twin of :func:`layered_model`:
-    stem (embedding + rope tables) and head (final norm + LM head) stay
-    HBM-resident, each transformer layer is its OWN jittable program so
-    the streaming engine can upload layer l+1's weights while layer l
-    computes.  Returns ``(stem_fn, block_fn, head_fn)``:
-
-        stem_fn(stem, tokens, start)            -> (x, cos, sin)
-        block_fn(lp, x, cos, sin, kp, vp, table, start,
-                 *, continuation, prefill)      -> (x, kp, vp)
-        head_fn(head, x)                        -> logits [B, T, V] f32
-
-    ``kp``/``vp`` are ONE layer's pages [KV, P, ps, Dh] (to
-    ``paged_attention_step`` a pool of one layer).  Every param
-    tree may carry int8 :class:`~deepspeed_tpu.inference.quantized.
-    QuantizedTensor` leaves — the dequant is traced into each per-layer
-    program, exactly as the whole-model quantized forward fuses it.  The
-    math (kernel choices included) matches :func:`forward_paged` op for
-    op, so streamed serving is token-identical to the resident engine.
-    ``ffn``: per-block FFN override, the same hook ``forward_paged``
-    gives MoE families."""
-    from deepspeed_tpu.inference.kernels import (paged_attention_step,
-                                                 paged_reader)
-    from deepspeed_tpu.inference.quantized import dequantize_params
-
-    def stem_fn(sp, tokens, start):
-        sp = dequantize_params(sp)
-        with jax.named_scope("embed"):
-            x = sp["embed"][tokens]
-            T = tokens.shape[1]
-            positions = start[:, None] + \
-                jnp.arange(T, dtype=jnp.int32)[None]
-            cos, sin = rope_tables(cfg, positions)
-            return x, cos, sin
-
-    def block_fn(lp, x, cos, sin, kp, vp, table, start, *,
-                 continuation: bool, prefill: bool):
-        lp = dequantize_params(lp)
-        B, T = x.shape[0], x.shape[1]
-        hd, nh = cfg.head_dim, cfg.n_heads
-        itp = (jax.default_backend() != "tpu") if interpret is None \
-            else interpret
-        q, k, v = _qkv(cfg, x, lp, cos, sin)
-        pk, _ = paged_reader(paged_kernel, decode=T == 1, tp=tp,
-                             interpret=itp, quant=False)
-        # one layer's pages are a pool of one layer, written at layer 0
-        attn, kp, vp, _, _ = paged_attention_step(
-            q, k, v, kp[None], vp[None], 0, table, start,
-            continuation=continuation, prefill=prefill,
-            paged_kernel=pk, flash_force_reference=tp, interpret=itp)
-        x = _out_ffn(cfg, x, attn.reshape(B, T, nh * hd), lp, ffn=ffn)
-        return x, kp[0], vp[0]
-
-    def head_fn(hp, x):
-        return _head(dequantize_params(hp), x, cfg)
-
-    return stem_fn, block_fn, head_fn
 
 
 def layered_model(cfg: LlamaConfig, params):
@@ -690,3 +521,25 @@ def loss_fn(cfg: LlamaConfig, n_micro: Optional[int] = None):
                                chunk=cfg.loss_chunk or cfg.vocab_size)
 
     return f
+
+
+def _embed(params, tokens, start, cfg):
+    """Token embeddings and the RoPE tables from ``start`` on."""
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+        return x, rope_tables(cfg, positions_from(start, tokens.shape[1]))
+
+
+def _streamed_split(cfg):
+    """Embedding before the streamed blocks; final norm and LM head (the
+    embedding again where it is tied) after them."""
+    tied = getattr(cfg, "tie_embeddings", False)
+    return ("embed",), ("final_norm", "embed" if tied else "lm_head")
+
+
+# stacked [L, d] norm gains stay exact under weight-only quantization
+FAMILY = DecoderFamily(
+    config_type=LlamaConfig, embed=_embed, qkv=_qkv, out=_out_ffn,
+    head=_head, param_specs=param_specs,
+    quant_skip_paths=("attn_norm", "mlp_norm", "final_norm"),
+    streamed_split=_streamed_split)

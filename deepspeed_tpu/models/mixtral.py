@@ -291,36 +291,6 @@ def forward_eval(params, tokens, cfg: MixtralConfig, positions=None):
     return _llama._head(params, x, lcfg)
 
 
-def forward_with_cache(params, tokens, cfg: MixtralConfig, cache):
-    """Incremental MoE forward for generation (DeepSpeed-MoE inference
-    parity): llama-style cached attention + capacity-free dense top-k
-    expert combine.  tokens: [B, T] → (logits [B, T, V] f32, cache)."""
-    from deepspeed_tpu.inference.generation import cached_attention
-
-    lcfg = cfg.llama_view()
-    B, T = tokens.shape
-    hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    start = cache.length
-    x, cos, sin = _embed(params, tokens, lcfg,
-                         start + jnp.arange(T, dtype=jnp.int32))
-    ffn = lambda lp, h: _moe_ffn_dense(cfg, h, lp)
-
-    def block(x, layer):
-        lp, kc, vc = layer
-        q, k, v = _llama._qkv(cfg, x, lp, cos, sin)
-        with jax.named_scope("kv_attend"):
-            attn, kc, vc = cached_attention(q, kc, vc, k, v, start)
-        x = _llama._out_ffn(cfg, x, attn.reshape(B, T, nh * hd), lp,
-                            ffn=ffn)
-        return x, (kc, vc)
-
-    x, (new_k, new_v) = jax.lax.scan(block, x,
-                                     (params["blocks"], cache.k, cache.v))
-    logits = _llama._head(params, x, lcfg)
-    cache = cache._replace(k=new_k, v=new_v, length=start + T)
-    return logits, cache
-
-
 def layered_model(cfg: MixtralConfig, params):
     """Factor a Mixtral tree for the layer-streaming engine — MoE x
     parameter offload (ref: ZeRO-Infinity param swapping composed with
@@ -369,42 +339,6 @@ def layered_model(cfg: MixtralConfig, params):
             "lm_head": head["lm_head"]})
 
 
-def forward_paged(params, tokens, cfg: MixtralConfig, cache,
-                  interpret=None, continuation: bool = False,
-                  tp=None, paged_kernel=None):
-    """Paged-KV MoE forward for continuous-batching serving (ref:
-    DeepSpeed-MoE inference — the reference SERVES MoE models through its
-    inference engine, it does not just eval them; deepspeed/inference/
-    engine.py + deepspeed/moe/sharded_moe.py inference path).
-
-    Reuses models/llama.py's paged-attention backbone (page writes,
-    decode/chunk kernels, ragged frontiers) with the capacity-free dense
-    top-k expert combine swapped in as the FFN — so every ServingEngine
-    feature (split-fuse chunked prefill, K-token decode chunks, paged
-    preemption, speculative draft-and-verify — the continuation path
-    returns logits at every position, the multi-position contract the
-    verify pass needs) works for MoE unchanged.  tokens: [B, T] →
-    (logits [B, T, V] f32, cache)."""
-    return _llama.forward_paged(
-        params, tokens, cfg.llama_view(), cache, interpret=interpret,
-        continuation=continuation, tp=tp, paged_kernel=paged_kernel,
-        ffn=lambda lp, h: _moe_ffn_dense(cfg, h, lp))
-
-
-def paged_layered_fns(cfg: MixtralConfig, tp: bool = False,
-                      interpret=None, paged_kernel=None):
-    """Per-layer factoring of :func:`forward_paged` for weight-streamed
-    (ZeRO-Inference) MoE serving — llama's paged-attention backbone with
-    the capacity-free dense top-k expert combine as the FFN, one program
-    per layer so the expert stacks (the dominant MoE weight bytes)
-    stream through a 2-layer HBM working set.  Router math stays f32
-    inside each block program (the gate is never quantized)."""
-    return _llama.paged_layered_fns(
-        cfg.llama_view(), tp=tp, interpret=interpret,
-        paged_kernel=paged_kernel,
-        ffn=lambda lp, h: _moe_ffn_dense(cfg, h, lp))
-
-
 def loss_fn(cfg: MixtralConfig):
     """Next-token CE + MoE aux losses; returns (loss, aux)."""
 
@@ -434,3 +368,31 @@ def loss_fn(cfg: MixtralConfig):
         return total, {"lm_loss": lm, **aux}
 
     return f
+
+
+def _out_moe(cfg: MixtralConfig, x, attn, lp):
+    """llama's attention output half, then the capacity-free dense top-k
+    expert combine as the FFN (inference must not drop tokens)."""
+    return _llama._out_ffn(cfg, x, attn, lp,
+                           ffn=lambda lp, h: _moe_ffn_dense(cfg, h, lp))
+
+
+def _check(cfg: MixtralConfig, mesh, max_seq: int) -> None:
+    if mesh is not None and cfg.num_experts % mesh.size("expert"):
+        raise ValueError(
+            f"num_experts {cfg.num_experts} not divisible by "
+            f"expert-axis size {mesh.size('expert')}")
+
+
+# llama's attention half with the MoE FFN (ref: DeepSpeed-MoE inference
+# serves MoE models through the same engine).  Sharded serving: the stacked
+# [L, E, ...] expert FFNs shard over the expert axis (XLA inserts the expert
+# psum at the weighted combine), attention Megatron-style over model.  The
+# router stays exact under weight-only quantization (int8 gate logits could
+# flip a near-tied top-k choice) and so do the stacked norm gains.
+FAMILY = dataclasses.replace(
+    _llama.FAMILY, config_type=MixtralConfig, out=_out_moe,
+    head=lambda params, x, cfg: _llama._head(params, x, cfg.llama_view()),
+    param_specs=param_specs,
+    quant_skip_paths=("gate",) + _llama.FAMILY.quant_skip_paths,
+    shard_axes=("model", "expert"), check=_check)

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 import deepspeed_tpu as dstpu
-from deepspeed_tpu.inference.generation import llama_generator
+from deepspeed_tpu.inference.generation import generator
 from deepspeed_tpu.lora import (LoRAConfig, count_trainable, init_lora,
                                 lora_loss_fn, merge_lora)
 from deepspeed_tpu.models import llama
@@ -54,7 +54,7 @@ def main():
             print(f"step {step:3d}: loss {float(loss):.4f}")
 
     merged = merge_lora(base, engine.module_params(), lcfg)
-    gen = llama_generator(
+    gen = generator(
         jax.tree.map(lambda x: x.astype(jnp.bfloat16), merged), cfg)
     out = gen.generate(seq[:, :8], max_new_tokens=17, temperature=0.0)
     agree = float((np.asarray(out)[:, 8:] == np.asarray(seq)[:, 8:]).mean())

@@ -303,6 +303,7 @@ class TestPagedGatePolicy:
         the chunk program none.  An env switch set after the build
         changes nothing: the trace reads no environment."""
         from deepspeed_tpu.inference.kernels import PagedKVCache
+        from deepspeed_tpu.inference.paged_forward import forward_paged
         from deepspeed_tpu.models import gpt2, llama
 
         monkeypatch.setenv("DSTPU_PAGED_ATTENTION", "xla")
@@ -326,7 +327,7 @@ class TestPagedGatePolicy:
 
         def jaxpr(T, continuation):
             return str(jax.make_jaxpr(
-                lambda p, t, c: mod.forward_paged(
+                lambda p, t, c: forward_paged(
                     p, t, cfg, c, interpret=False, tp=False,
                     continuation=continuation))(
                 params, jax.ShapeDtypeStruct((rows, T), jnp.int32), cache))

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from deepspeed_tpu.inference import kernels as K
+from deepspeed_tpu.inference.paged_forward import forward_paged
 from deepspeed_tpu.models import gpt2, mixtral
 from deepspeed_tpu.ops.attention_pallas import flash_attention_tpu
 from deepspeed_tpu.ops.sampling_pallas import fused_greedy_rows
@@ -291,7 +292,7 @@ def test_serving_program_leaves_the_pool_in_place(chip, pool, phase):
         seq_lens=jax.ShapeDtypeStruct((rows,), jnp.int32), page_size=PAGE)
 
     def program(params, tokens, cache):
-        logits, cache = family.forward_paged(
+        logits, cache = forward_paged(
             params, tokens, cfg, cache, interpret=False, tp=False,
             continuation=continuation)
         return logits[:, -1], cache

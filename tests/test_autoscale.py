@@ -303,17 +303,17 @@ def test_scale_factory_failure_and_slow_cold_start(gpt2_model):
 # --------------------------------------------- streamed warm cold-start
 def test_streamed_cold_start_serves_then_flips(llama_model):
     cfg, p0, _ = llama_model
-    from deepspeed_tpu.inference.serving import llama_serving_engine
+    from deepspeed_tpu.inference.serving import serving_engine
 
     router = fleet_router(
         p0, cfg, fleet={"replicas": 1}, prefix_cache=True,
         tracing={"ring_capacity": 16384},
-        engine_builder=lambda params, c, **kw: llama_serving_engine(
+        engine_builder=lambda params, c, **kw: serving_engine(
             params, c, **kw), **LKW)
 
     def factory(rid, streamed=False):
         zi = ({"enabled": True, "tier": "host"} if streamed else None)
-        return llama_serving_engine(
+        return serving_engine(
             p0, cfg, replica_id=rid, prefix_cache=True,
             zero_inference=zi, tracing=router.tracer,
             telemetry=MetricsRegistry(namespace=f"dstpu_{rid}"), **LKW)
@@ -630,10 +630,10 @@ def test_zi_budget_bound_flip_blocked(llama_model):
     # promising a flip that can never land (the autoscaler closes the
     # cold start there rather than spinning forever)
     cfg, p0, _ = llama_model
-    from deepspeed_tpu.inference.serving import llama_serving_engine
+    from deepspeed_tpu.inference.serving import serving_engine
     from deepspeed_tpu.inference.zero_inference import plan_residency
 
-    probe = llama_serving_engine(
+    probe = serving_engine(
         p0, cfg, zero_inference={"enabled": True, "tier": "host"},
         **LKW)
     plan = probe.plan
@@ -648,7 +648,7 @@ def test_zi_budget_bound_flip_blocked(llama_model):
         cache_bytes=plan["cache_bytes"], budget=budget,
         prefetch_depth=plan["prefetch_depth"])["n_resident"] \
         < plan["n_layers"]
-    zi = llama_serving_engine(
+    zi = serving_engine(
         p0, cfg, zero_inference={"enabled": True, "tier": "host",
                                  "hbm_budget_bytes": budget}, **LKW)
     assert not zi.fully_resident
@@ -661,10 +661,10 @@ def test_zi_budget_bound_flip_blocked(llama_model):
 
 def test_zi_swap_weights_token_identical(llama_model):
     cfg, p0, p1 = llama_model
-    from deepspeed_tpu.inference.serving import llama_serving_engine
+    from deepspeed_tpu.inference.serving import serving_engine
 
     oracle1 = oracle_outputs(p1, cfg, [[5, 9, 2]], max_new=6, kw=LKW)
-    zi = llama_serving_engine(
+    zi = serving_engine(
         p0, cfg, zero_inference={"enabled": True, "tier": "host"},
         **LKW)
     zi.submit("a", [5, 9, 2], max_new_tokens=6)

@@ -374,25 +374,25 @@ def llama_model():
 class TestServingQuantizedPlacement:
     def test_tp_identity_off_and_observable_on(self, llama_model,
                                                devices):
-        from deepspeed_tpu.inference.serving import llama_serving_engine
+        from deepspeed_tpu.inference.serving import serving_engine
 
         cfg, params = llama_model
         mesh = MeshSpec.build({"model": 2}, devices=jax.devices()[:2])
-        base = llama_serving_engine(params, cfg, mesh=mesh, **KW)
+        base = serving_engine(params, cfg, mesh=mesh, **KW)
         want = _serve_all(base)
         assert base.statusz().get("comm") is None
 
         # OFF (the default): the comm block rides along but placement
         # is the bit-exact path — greedy tokens identical
-        off = llama_serving_engine(params, cfg, mesh=mesh,
-                                   comm={"quantized_serving": False},
-                                   **KW)
+        off = serving_engine(params, cfg, mesh=mesh,
+                             comm={"quantized_serving": False},
+                             **KW)
         assert _serve_all(off) == want
         assert off.statusz().get("comm") is None
 
         # ON: int8 on the H2D wire, gated by serving_rtol, observable
-        on = llama_serving_engine(params, cfg, mesh=mesh,
-                                  comm={"quantized_serving": True}, **KW)
+        on = serving_engine(params, cfg, mesh=mesh,
+                            comm={"quantized_serving": True}, **KW)
         got = _serve_all(on)
         assert sorted(got) == sorted(want)        # same requests served
         st = on.statusz()["comm"]
@@ -410,14 +410,14 @@ class TestServingQuantizedPlacement:
         assert any(ln.startswith("comm") for ln in lines)
 
     def test_rtol_gate_fails_the_build(self, llama_model, devices):
-        from deepspeed_tpu.inference.serving import llama_serving_engine
+        from deepspeed_tpu.inference.serving import serving_engine
 
         cfg, params = llama_model
         mesh = MeshSpec.build({"model": 2}, devices=jax.devices()[:2])
         with pytest.raises(ValueError, match="serving_rtol"):
-            llama_serving_engine(params, cfg, mesh=mesh,
-                                 comm={"quantized_serving": True,
-                                       "serving_rtol": 1e-9}, **KW)
+            serving_engine(params, cfg, mesh=mesh,
+                           comm={"quantized_serving": True,
+                                 "serving_rtol": 1e-9}, **KW)
 
     def test_encoder_families_reject_quantized_serving(self, devices):
         from deepspeed_tpu.inference.serving import serving_engine
@@ -435,12 +435,12 @@ class TestZeroInferenceWire:
     @pytest.mark.slow
     def test_streamed_layers_ride_the_int8_wire(self, llama_model,
                                                 devices):
-        from deepspeed_tpu.inference.serving import llama_serving_engine
+        from deepspeed_tpu.inference.serving import serving_engine
 
         cfg, params = llama_model
         zi = {"enabled": True, "tier": "host", "hbm_budget_bytes": None}
-        eng = llama_serving_engine(params, cfg, zero_inference=zi,
-                                   comm={"quantized_serving": True}, **KW)
+        eng = serving_engine(params, cfg, zero_inference=zi,
+                             comm={"quantized_serving": True}, **KW)
         got = _serve_all(eng)
         assert sorted(got) == sorted(PROMPTS)
         snap = eng.registry.snapshot()
@@ -452,11 +452,11 @@ class TestZeroInferenceWire:
             >= 3.5 * c["comm_bytes_on_wire_int8"]
 
     def test_zi_rtol_gate_fails_the_build(self, llama_model, devices):
-        from deepspeed_tpu.inference.serving import llama_serving_engine
+        from deepspeed_tpu.inference.serving import serving_engine
 
         cfg, params = llama_model
         zi = {"enabled": True, "tier": "host", "hbm_budget_bytes": None}
         with pytest.raises(ValueError, match="serving_rtol"):
-            llama_serving_engine(params, cfg, zero_inference=zi,
-                                 comm={"quantized_serving": True,
-                                       "serving_rtol": 1e-9}, **KW)
+            serving_engine(params, cfg, zero_inference=zi,
+                           comm={"quantized_serving": True,
+                                 "serving_rtol": 1e-9}, **KW)

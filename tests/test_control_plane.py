@@ -375,14 +375,14 @@ class TestEngineSLO:
 
     def test_preempted_request_keeps_original_arrival(self, devices):
         from deepspeed_tpu.models import llama
-        from deepspeed_tpu.inference.serving import llama_serving_engine
+        from deepspeed_tpu.inference.serving import serving_engine
 
         cfg = llama.LlamaConfig.tiny(dim=32, n_layers=2, n_heads=2,
                                      n_kv_heads=2)
         params = llama.init_params(jax.random.PRNGKey(0), cfg)
         # tiny pool: both sequences cannot hold all their pages at once
         # (same geometry as test_serving's preemption test)
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=2, page_size=4, num_pages=7,
             max_seq=40, prefill_bucket=4,
             slo={"tiers": {"default": {"deadline_s": 300.0}}})
@@ -509,12 +509,12 @@ class TestIntrospection:
 
     def test_zero_inference_statusz_carries_stream_view(self, devices):
         from deepspeed_tpu.models import llama
-        from deepspeed_tpu.inference.serving import llama_serving_engine
+        from deepspeed_tpu.inference.serving import serving_engine
 
         cfg = llama.LlamaConfig.tiny(dim=32, n_layers=2, n_heads=2,
                                      n_kv_heads=2)
         params = llama.init_params(jax.random.PRNGKey(0), cfg)
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, zero_inference={"enabled": True},
             max_batch=2, page_size=8, num_pages=16, max_seq=32,
             prefill_bucket=8)

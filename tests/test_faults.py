@@ -24,7 +24,6 @@ from deepspeed_tpu.faults import (ChecksumError, FatalStreamError,
                                   retry_with_backoff)
 from deepspeed_tpu.inference.kv_tier import KVTierPool
 from deepspeed_tpu.inference.serving import (RequestFailed, RequestShed,
-                                             llama_serving_engine,
                                              serving_engine)
 from deepspeed_tpu.models import gpt2, llama
 
@@ -524,7 +523,7 @@ class TestZIStreamFatal:
     def test_postmortem_on_unrecoverable_stream(self, llama_model,
                                                 devices, tmp_path):
         cfg, params = llama_model
-        zi = llama_serving_engine(
+        zi = serving_engine(
             params, cfg,
             zero_inference={"enabled": True, "tier": "nvme",
                             "nvme_path": str(tmp_path / "zi"),
@@ -548,10 +547,10 @@ class TestZIStreamFatal:
         cfg, params = llama_model
         kw = dict(max_batch=2, page_size=8, num_pages=16, max_seq=32,
                   prefill_bucket=8)
-        ref = llama_serving_engine(params, cfg, **kw)
+        ref = serving_engine(params, cfg, **kw)
         ref.submit("a", [5, 9, 2], max_new_tokens=4)
         want = ref.run()["a"]
-        zi = llama_serving_engine(
+        zi = serving_engine(
             params, cfg,
             zero_inference={"enabled": True, "tier": "nvme",
                             "nvme_path": str(tmp_path / "zi2"),

@@ -11,9 +11,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.inference.generation import (KVCache, llama_generator,
+from deepspeed_tpu.inference.generation import (KVCache, generator,
+                                                paged_generator,
                                                 sample_logits)
-from deepspeed_tpu.models import llama
+from deepspeed_tpu.inference.paged_forward import (forward_paged,
+                                                   forward_with_cache)
+from deepspeed_tpu.models import gpt2, llama, mixtral
 
 
 def _setup(T=12, B=2):
@@ -28,7 +31,7 @@ def test_prefill_matches_forward():
     want = llama.forward(params, toks, cfg)
     cache = KVCache.alloc(cfg.n_layers, 2, 32, cfg.n_kv_heads, cfg.head_dim,
                           dtype=jnp.float32)
-    got, cache = llama.forward_with_cache(params, toks, cfg, cache)
+    got, cache = forward_with_cache(params, toks, cfg, cache)
     assert int(cache.length) == toks.shape[1]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-4, rtol=2e-4)
@@ -41,10 +44,10 @@ def test_incremental_decode_matches_full():
     cache = KVCache.alloc(cfg.n_layers, 2, 16, cfg.n_kv_heads, cfg.head_dim,
                           dtype=jnp.float32)
     # prefill 4, then decode 4 one token at a time
-    logits, cache = llama.forward_with_cache(params, toks[:, :4], cfg, cache)
+    logits, cache = forward_with_cache(params, toks[:, :4], cfg, cache)
     outs = [logits]
     for t in range(4, 8):
-        logits, cache = llama.forward_with_cache(
+        logits, cache = forward_with_cache(
             params, toks[:, t:t + 1], cfg, cache)
         outs.append(logits)
     got = jnp.concatenate(outs, axis=1)
@@ -54,7 +57,7 @@ def test_incremental_decode_matches_full():
 
 def test_generator_greedy_deterministic():
     cfg, params, toks = _setup(T=4)
-    gen = llama_generator(params, cfg, cache_dtype=jnp.float32)
+    gen = generator(params, cfg, cache_dtype=jnp.float32)
     out1 = gen.generate(toks, max_new_tokens=6, temperature=0.0)
     out2 = gen.generate(toks, max_new_tokens=6, temperature=0.0)
     assert out1.shape == (2, 10)
@@ -72,10 +75,10 @@ def test_paged_forward_matches_cached():
                                max_seq=16, dtype=jnp.float32)
     # prefill 6 = one full page + a HALF page (exercises the pad path in
     # write_prompt_pages and decoding into a partially-filled page)
-    logits, cache = llama.forward_paged(params, toks[:, :6], cfg, cache)
+    logits, cache = forward_paged(params, toks[:, :6], cfg, cache)
     outs = [logits]
     for t in range(6, 8):
-        logits, cache = llama.forward_paged(params, toks[:, t:t + 1], cfg,
+        logits, cache = forward_paged(params, toks[:, t:t + 1], cfg,
                                             cache)
         outs.append(logits)
     got = jnp.concatenate(outs, axis=1)
@@ -91,10 +94,10 @@ def test_paged_prefill_requires_empty_cache():
     cache = PagedKVCache.alloc(cfg.n_layers, cfg.n_kv_heads, num_pages=8,
                                page_size=4, head_dim=cfg.head_dim, batch=2,
                                max_seq=16, dtype=jnp.float32)
-    _, cache = llama.forward_paged(params, toks[:, :4], cfg, cache)
+    _, cache = forward_paged(params, toks[:, :4], cfg, cache)
     import pytest
     with pytest.raises(ValueError, match="empty cache"):
-        llama.forward_paged(params, toks[:, 4:8], cfg, cache)
+        forward_paged(params, toks[:, 4:8], cfg, cache)
 
 
 @pytest.mark.slow
@@ -110,8 +113,8 @@ def test_paged_decode_ragged_frontiers():
         c = PagedKVCache.alloc(cfg.n_layers, cfg.n_kv_heads, num_pages=mp,
                                page_size=ps, head_dim=cfg.head_dim, batch=1,
                                max_seq=ps * mp, dtype=jnp.float32)
-        _, c = llama.forward_paged(params, toks[row:row + 1, :L], cfg, c)
-        logits, _ = llama.forward_paged(params, toks[row:row + 1, L:L + 1],
+        _, c = forward_paged(params, toks[row:row + 1, :L], cfg, c)
+        logits, _ = forward_paged(params, toks[row:row + 1, L:L + 1],
                                         cfg, c)
         return c, logits
 
@@ -129,29 +132,47 @@ def test_paged_decode_ragged_frontiers():
         v=merged.v.at[:, :, :mp].set(c0.v).at[:, :, mp:].set(c1.v),
         seq_lens=jnp.asarray([4, 6], jnp.int32))
     nxt = jnp.stack([toks[0, 4], toks[1, 6]])[:, None]
-    lb, _ = llama.forward_paged(params, nxt, cfg, merged)
+    lb, _ = forward_paged(params, nxt, cfg, merged)
     np.testing.assert_allclose(np.asarray(lb[0]), np.asarray(l0[0]),
                                atol=5e-4, rtol=5e-4)
     np.testing.assert_allclose(np.asarray(lb[1]), np.asarray(l1[0]),
                                atol=5e-4, rtol=5e-4)
 
 
-def test_paged_generator_matches_dense():
-    from deepspeed_tpu.inference.generation import llama_paged_generator
+# family -> (module, its tiny config, prompt rows, new tokens, page size,
+# cache dtype): the cases the three per-family copies of this test ran
+PAGED_VS_DENSE = {
+    "gpt2": (gpt2, gpt2.GPT2Config.tiny(dim=64, n_layers=2, n_heads=4,
+                                        max_seq_len=64),
+             [[17, 3, 3, 8, 1]], 5, 8, jnp.bfloat16),
+    "llama": (llama, llama.LlamaConfig.tiny(attn_impl="reference"),
+              np.random.default_rng(1).integers(0, 256, (2, 4)), 6, 4,
+              jnp.float32),
+    "mixtral": (mixtral, mixtral.MixtralConfig.tiny(
+        dim=64, n_layers=2, n_heads=4, n_kv_heads=2, num_experts=4),
+        [[5, 9, 2]], 6, 8, jnp.bfloat16),
+}
 
-    cfg, params, toks = _setup(T=4)
-    dense = llama_generator(params, cfg, cache_dtype=jnp.float32)
-    paged = llama_paged_generator(params, cfg, page_size=4,
-                                  cache_dtype=jnp.float32)
-    o1 = dense.generate(toks, max_new_tokens=6, temperature=0.0)
-    o2 = paged.generate(toks, max_new_tokens=6, temperature=0.0)
+
+@pytest.mark.parametrize("family", PAGED_VS_DENSE)
+def test_paged_matches_dense_cache_greedy(family, devices):
+    """Cross-oracle, a family a case: the paged forward (ragged learned
+    or rotary positions, page writes, the MoE routing) must generate
+    exactly like the contiguous-cache ``forward_with_cache``."""
+    mod, cfg, prompt, n_new, page_size, dtype = PAGED_VS_DENSE[family]
+    params = mod.init_params(jax.random.PRNGKey(0), cfg)
+    toks = jnp.asarray(prompt, jnp.int32)
+    dense = generator(params, cfg, cache_dtype=dtype)
+    paged = paged_generator(params, cfg, page_size=page_size,
+                            cache_dtype=dtype)
+    o1 = dense.generate(toks, max_new_tokens=n_new, temperature=0.0)
+    o2 = paged.generate(toks, max_new_tokens=n_new, temperature=0.0)
     np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
 
 
 def test_generator_eos_stops():
     cfg, params, toks = _setup(T=4)
-    gen = llama_generator(params, cfg, cache_dtype=jnp.float32,
-                          eos_token_id=7)
+    gen = generator(params, cfg, cache_dtype=jnp.float32, eos_token_id=7)
     out = gen.generate(toks, max_new_tokens=8, temperature=0.0)
     assert out.shape[1] <= 12
 
@@ -198,7 +219,6 @@ def test_injection_unknown_arch():
 
 class TestGPT2Generation:
     def test_cached_prefill_matches_forward(self, devices):
-        from deepspeed_tpu.models import gpt2
         from deepspeed_tpu.inference.generation import KVCache
 
         cfg = gpt2.GPT2Config.tiny()
@@ -208,18 +228,15 @@ class TestGPT2Generation:
         ref = gpt2.forward(params, toks, cfg)
         cache = KVCache.alloc(cfg.n_layers, 2, 16, cfg.n_kv_heads,
                               cfg.head_dim, dtype=jnp.float32)
-        got, cache = gpt2.forward_with_cache(params, toks, cfg, cache)
+        got, cache = forward_with_cache(params, toks, cfg, cache)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-3, atol=2e-3)
         assert int(cache.length) == 10
 
     def test_generator_greedy_deterministic(self, devices):
-        from deepspeed_tpu.models import gpt2
-        from deepspeed_tpu.inference.generation import gpt2_generator
-
         cfg = gpt2.GPT2Config.tiny()
         params = gpt2.init_params(jax.random.PRNGKey(1), cfg)
-        gen = gpt2_generator(params, cfg)
+        gen = generator(params, cfg)
         out1 = gen.generate(jnp.asarray([[3, 7, 11]], jnp.int32),
                             max_new_tokens=6)
         out2 = gen.generate(jnp.asarray([[3, 7, 11]], jnp.int32),
@@ -228,12 +245,9 @@ class TestGPT2Generation:
         np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
 
     def test_position_table_overflow_raises(self, devices):
-        from deepspeed_tpu.models import gpt2
-        from deepspeed_tpu.inference.generation import gpt2_generator
-
         cfg = gpt2.GPT2Config.tiny(max_seq_len=16)
         params = gpt2.init_params(jax.random.PRNGKey(0), cfg)
-        gen = gpt2_generator(params, cfg)
+        gen = generator(params, cfg)
         with pytest.raises(ValueError, match="position table"):
             gen.generate(jnp.ones((1, 12), jnp.int32), max_new_tokens=8)
 

@@ -40,7 +40,7 @@ class TestHybridEngine:
     @pytest.mark.slow
     def test_generate_matches_standalone_generator(self, devices, setup):
         cfg, engine, hybrid = setup
-        from deepspeed_tpu.inference.generation import llama_generator
+        from deepspeed_tpu.inference.generation import generator
 
         prompts = _prompts(cfg)
         got = hybrid.generate(prompts, max_new_tokens=8, temperature=0.0)
@@ -48,7 +48,7 @@ class TestHybridEngine:
         # to the compute dtype
         full = jax.tree.map(lambda x: x.astype(jnp.bfloat16),
                             engine.module_params())
-        ref = llama_generator(full, cfg).generate(
+        ref = generator(full, cfg).generate(
             prompts, max_new_tokens=8, temperature=0.0)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 

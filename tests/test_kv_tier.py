@@ -25,8 +25,7 @@ from deepspeed_tpu.inference.kv_tier import (KV_TIER_QUANT_RTOL,
                                              KVTierPool,
                                              dequantize_page,
                                              quantize_page)
-from deepspeed_tpu.inference.serving import (llama_serving_engine,
-                                             serving_engine)
+from deepspeed_tpu.inference.serving import serving_engine
 from deepspeed_tpu.models import gpt2, llama
 
 PAGE_SHAPE = (2, 2, 8, 16)          # (L, KV, ps, Dh)
@@ -545,10 +544,10 @@ class TestTokenIdentical:
         phases = revisit_phases(cfg.vocab_size)
         kw = dict(max_batch=2, page_size=8, num_pages=12, max_seq=64,
                   prefill_bucket=8)
-        off_eng = llama_serving_engine(params, cfg, prefix_cache=True,
-                                       **kw)
+        off_eng = serving_engine(params, cfg, prefix_cache=True,
+                                 **kw)
         off = run_phases(off_eng, phases)
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, prefix_cache=True, kv_tier=True,
             zero_inference={"enabled": True, "tier": "host"}, **kw)
         assert run_phases(eng, phases) == off

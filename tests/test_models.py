@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as dstpu
-from deepspeed_tpu.models import cnn, gpt2, llama
+from deepspeed_tpu.models import bert, cnn, gpt2, llama, mixtral
+from deepspeed_tpu.models.family import decoder_family
 from deepspeed_tpu.topology import MeshSpec
 
 
@@ -137,6 +138,92 @@ class TestCNN:
                     "optimizer": {"type": "adam", "params": {"lr": 1e-3}}})
         losses = [float(engine.train_batch(batch)) for _ in range(10)]
         assert losses[-1] < losses[0]
+
+
+# ------------------------------------------------ the decoder-family seam
+FAMILIES = {
+    "gpt2": (gpt2, gpt2.GPT2Config.tiny(dim=64, n_layers=2, n_heads=4,
+                                        max_seq_len=64)),
+    "llama": (llama, llama.LlamaConfig.tiny(dim=32, n_layers=1, n_heads=2,
+                                            n_kv_heads=2)),
+    "mixtral": (mixtral, mixtral.MixtralConfig.tiny(
+        dim=64, n_layers=2, n_heads=4, n_kv_heads=2, num_experts=4)),
+}
+
+
+@pytest.mark.parametrize("family", [*FAMILIES, "bert"])
+def test_decoder_family_lookup(family):
+    """A config resolves to its own file's record; anything else is a
+    TypeError that names the supported types."""
+    if family in FAMILIES:
+        mod, cfg = FAMILIES[family]
+        assert decoder_family(cfg) is mod.FAMILY
+        assert mod.FAMILY.config_type is type(cfg)
+        return
+    with pytest.raises(TypeError) as e:
+        decoder_family(bert.BertConfig.tiny())
+    for _, cfg in FAMILIES.values():
+        assert type(cfg).__name__ in str(e.value)
+
+
+def test_family_seam_is_one_way():
+    """``models/`` imports nothing from ``inference/``, and nothing
+    outside ``models/family.py`` asks which decoder family a config is."""
+    import ast
+    import pathlib
+
+    root = pathlib.Path(dstpu.__file__).parent
+    upward, chains = [], []
+    for path in sorted(root.rglob("*.py")):
+        rel = path.relative_to(root).as_posix()
+        for node in ast.walk(ast.parse(path.read_text())):
+            names = []
+            if isinstance(node, ast.ImportFrom):
+                names = [node.module or ""]
+            elif isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            if rel.startswith("models/") and any(
+                    n.startswith("deepspeed_tpu.inference") for n in names):
+                upward.append(f"{rel}:{node.lineno}")
+            if (isinstance(node, ast.Call)
+                    and getattr(node.func, "id", "") == "isinstance"
+                    and rel != "models/family.py"
+                    and any(getattr(n, "id", getattr(n, "attr", "")) in
+                            ("GPT2Config", "LlamaConfig", "MixtralConfig")
+                            for n in ast.walk(node.args[1]))):
+                chains.append(f"{rel}:{node.lineno}")
+    assert upward == [] and chains == []
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_registry_serves(family, devices):
+    """Pin the dispatch itself: a config served through the one builder
+    must produce ITS model's tokens (a mis-dispatch would KeyError or
+    emit different tokens)."""
+    from deepspeed_tpu.inference.generation import paged_generator
+    from deepspeed_tpu.inference.serving import serving_engine
+
+    mod, cfg = FAMILIES[family]
+    params = mod.init_params(jax.random.PRNGKey(0), cfg)
+    prompts = {"a": ([5, 9, 2], 6), "b": ([17, 3, 3, 8, 1], 5),
+               "c": ([40, 2], 7)}
+    eng = serving_engine(params, cfg, max_batch=2, page_size=8,
+                         num_pages=32, max_seq=64, prefill_bucket=8)
+    for rid, (p, n) in prompts.items():
+        eng.submit(rid, p, max_new_tokens=n)
+    outs = eng.run()
+    oracle = paged_generator(params, cfg, page_size=8)
+    for rid, (p, n) in prompts.items():
+        want = oracle.generate(jnp.asarray([p], jnp.int32),
+                               max_new_tokens=n)
+        assert outs[rid] == [int(t) for t in np.asarray(want[0])], rid
+
+
+def test_registry_refuses_unknown_config():
+    from deepspeed_tpu.inference.serving import serving_engine
+
+    with pytest.raises(TypeError, match="MixtralConfig"):
+        serving_engine({}, object(), max_batch=1)
 
 
 @pytest.mark.slow

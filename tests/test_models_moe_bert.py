@@ -7,6 +7,8 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
+from deepspeed_tpu.inference.generation import KVCache
+from deepspeed_tpu.inference.paged_forward import forward_with_cache
 from deepspeed_tpu.models import bert, mixtral
 from deepspeed_tpu.topology import MeshSpec
 
@@ -104,11 +106,10 @@ class TestMixtralInference:
         # generous capacity → training forward drops nothing, so the
         # capacity-free inference path must agree
         ref, _ = mixtral.forward(params, toks, cfg)
-        from deepspeed_tpu.inference.generation import KVCache
 
         cache = KVCache.alloc(cfg.n_layers, 2, 16, cfg.n_kv_heads,
                               cfg.head_dim, dtype=jnp.float32)
-        got, cache = mixtral.forward_with_cache(params, toks, cfg, cache)
+        got, cache = forward_with_cache(params, toks, cfg, cache)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-3, atol=2e-3)
         assert int(cache.length) == 10
@@ -116,7 +117,6 @@ class TestMixtralInference:
     def test_incremental_matches_full(self, devices):
         """Token-by-token decode must match one-shot cached prefill."""
         from deepspeed_tpu.models import mixtral
-        from deepspeed_tpu.inference.generation import KVCache
 
         cfg = mixtral.MixtralConfig.tiny()
         params = mixtral.init_params(jax.random.PRNGKey(1), cfg)
@@ -124,12 +124,12 @@ class TestMixtralInference:
             0, cfg.vocab_size, (1, 8)), jnp.int32)
         cache = KVCache.alloc(cfg.n_layers, 1, 8, cfg.n_kv_heads,
                               cfg.head_dim, dtype=jnp.float32)
-        full, _ = mixtral.forward_with_cache(params, toks, cfg, cache)
+        full, _ = forward_with_cache(params, toks, cfg, cache)
         cache = KVCache.alloc(cfg.n_layers, 1, 8, cfg.n_kv_heads,
                               cfg.head_dim, dtype=jnp.float32)
         outs = []
         for i in range(8):
-            lg, cache = mixtral.forward_with_cache(
+            lg, cache = forward_with_cache(
                 params, toks[:, i:i + 1], cfg, cache)
             outs.append(lg)
         inc = jnp.concatenate(outs, axis=1)
@@ -138,11 +138,11 @@ class TestMixtralInference:
 
     def test_generator_end_to_end(self, devices):
         from deepspeed_tpu.models import mixtral
-        from deepspeed_tpu.inference.generation import mixtral_generator
+        from deepspeed_tpu.inference.generation import generator
 
         cfg = mixtral.MixtralConfig.tiny()
         params = mixtral.init_params(jax.random.PRNGKey(2), cfg)
-        gen = mixtral_generator(params, cfg)
+        gen = generator(params, cfg)
         out = gen.generate(jnp.asarray([[3, 7, 11]], jnp.int32),
                            max_new_tokens=6)
         assert out.shape == (1, 9)
@@ -186,12 +186,9 @@ class TestMixtralInference:
         assert bool(jnp.isfinite(logits).all())
         # injected inference is the capacity-FREE eval path: it must agree
         # with the cached path bit-for-bit regardless of router balance
-        from deepspeed_tpu.inference.generation import KVCache
-        from deepspeed_tpu.models import mixtral as mx
-
         cache = KVCache.alloc(cfg.n_layers, 1, 8, cfg.n_kv_heads,
                               cfg.head_dim, dtype=jnp.float32)
-        cached, _ = mx.forward_with_cache(params, toks, cfg, cache)
+        cached, _ = forward_with_cache(params, toks, cfg, cache)
         np.testing.assert_allclose(np.asarray(logits), np.asarray(cached),
                                    rtol=2e-3, atol=2e-3)
 

@@ -4,9 +4,9 @@
 its layer loop, scatters only the new rows into it and reads a layer by
 its index.  The reference here runs the same layers one at a time, each
 on its own ``[KV, P, ps, Dh]`` copy of its layer: ``paged_layered_fns``
-where the family has it and the pool is plain, and otherwise the same
-factoring spelled out from the family's own pieces (GPT-2 has no layered
-factoring, and the layered block carries no scale planes).  Logits of
+where the family streams and the pool is plain, and otherwise the same
+factoring spelled out from the family's own pieces (GPT-2 states no
+streamed split, and the layered block carries no scale planes).  Logits of
 every call and the whole pool at the end, trash page included, must be
 identical.  What the compiled programs hold is
 ``tests/test_aot_tpu_compile.py``'s to say.
@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.kernels import PagedKVCache, paged_attention_step
+from deepspeed_tpu.inference.paged_forward import (forward_paged,
+                                                   paged_layered_fns)
 from deepspeed_tpu.models import gpt2, llama, mixtral
 
 PS, MAX_PAGES, ROWS = 8, 4, 4              # a row holds 32 positions
@@ -39,15 +41,14 @@ def _layered(family, cfg, quant):
     -> (x, pages), head(params, x) -> logits)`` over ONE layer's pages
     ``(kp, vp[, kps, vps])``."""
     if family is not gpt2 and not quant:
-        stem_fn, block_fn, head_fn = family.paged_layered_fns(
+        stem_fn, block_fn, head_fn = paged_layered_fns(
             cfg, interpret=True, paged_kernel="xla")
 
         def block(lp, x, aux, pages, table, start, **phase):
-            x, kp, vp = block_fn(lp, x, *aux, *pages, table, start, **phase)
+            x, kp, vp = block_fn(lp, x, aux, *pages, table, start, **phase)
             return x, (kp, vp)
 
-        return (lambda p, t, s: (lambda x, *aux: (x, aux))(*stem_fn(p, t, s)),
-                block, head_fn)
+        return stem_fn, block, head_fn
 
     if family is gpt2:
         mod, lcfg = gpt2, cfg
@@ -110,7 +111,7 @@ def test_carried_pool_equals_per_layer_pools(name, quant):
     lens = np.zeros((ROWS,), np.int32)                 # row 3 stays empty
 
     fwd = jax.jit(
-        lambda params, tokens, cache, continuation: family.forward_paged(
+        lambda params, tokens, cache, continuation: forward_paged(
             params, tokens, cfg, cache, interpret=True, tp=False,
             continuation=continuation, paged_kernel="xla"),
         static_argnums=3)

@@ -17,8 +17,7 @@ from deepspeed_tpu.config import PrefixCacheConfig
 from deepspeed_tpu.inference.kernels import PageAllocator
 from deepspeed_tpu.inference.prefix_cache import (matchable_pages,
                                                   page_keys)
-from deepspeed_tpu.inference.serving import (llama_serving_engine,
-                                             serving_engine)
+from deepspeed_tpu.inference.serving import serving_engine
 from deepspeed_tpu.models import gpt2, llama
 
 
@@ -331,7 +330,7 @@ class TestCOWFork:
     def test_preemption_releases_references_and_rehits(
             self, llama_model, devices):
         cfg, params = llama_model
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, prefix_cache=True, max_batch=2, page_size=4,
             num_pages=8, max_seq=40, prefill_bucket=4)
         eng.submit("x", [5, 9, 2], max_new_tokens=12)
@@ -342,7 +341,7 @@ class TestCOWFork:
         # the preempted victim's pages were published before release;
         # its recompute admission matches its own cached prefix
         assert cnt["prefix_cache_hits"] >= 1
-        off_eng = llama_serving_engine(
+        off_eng = serving_engine(
             params, cfg, max_batch=2, page_size=4, num_pages=8,
             max_seq=40, prefill_bucket=4)
         off_eng.submit("x", [5, 9, 2], max_new_tokens=12)
@@ -437,7 +436,7 @@ class TestZeroInferenceCompose:
         kw = dict(max_batch=2, page_size=8, num_pages=24, max_seq=48,
                   prefill_bucket=8)
         off, _ = serve(params, cfg, prompts, None, n_new=6, **kw)
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, prefix_cache=True,
             zero_inference={"enabled": True, "tier": "host"}, **kw)
         for i, p in enumerate(prompts):

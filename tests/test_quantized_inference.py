@@ -92,11 +92,11 @@ class TestInt8Inference:
 
     @pytest.mark.slow
     def test_int8_serving_runs_and_matches_int8_offline(self, model, devices):
-        from deepspeed_tpu.inference.serving import llama_serving_engine
+        from deepspeed_tpu.inference.serving import serving_engine
 
         cfg, params = model
         prompt = [5, 9, 2, 33]
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, weight_dtype="int8", max_batch=2, page_size=8,
             num_pages=32, max_seq=64, prefill_bucket=8)
         eng.submit("r", prompt, max_new_tokens=5)
@@ -105,11 +105,12 @@ class TestInt8Inference:
         # oracle: same quantized weights through the offline paged path
         from deepspeed_tpu.inference.generation import Generator, KVCache
         from deepspeed_tpu.inference.kernels import PagedKVCache
+        from deepspeed_tpu.inference.paged_forward import forward_paged
         from deepspeed_tpu.inference.quantized import quantized_apply
 
         qp = quantize_params(params)
         step = quantized_apply(
-            lambda p, t, c: llama.forward_paged(p, t, cfg, c))
+            lambda p, t, c: forward_paged(p, t, cfg, c))
 
         def alloc(batch, max_seq):
             mp = -(-max_seq // 8)
@@ -126,12 +127,12 @@ class TestInt8Inference:
         assert out == [int(t) for t in np.asarray(want[0])]
 
     def test_unknown_weight_dtype_raises(self, model, devices):
-        from deepspeed_tpu.inference.serving import llama_serving_engine
+        from deepspeed_tpu.inference.serving import serving_engine
 
         cfg, params = model
         with pytest.raises(NotImplementedError, match="int8"):
-            llama_serving_engine(params, cfg, weight_dtype="int4",
-                                 max_batch=1, num_pages=8, max_seq=32)
+            serving_engine(params, cfg, weight_dtype="int4",
+                           max_batch=1, num_pages=8, max_seq=32)
 
     def test_prime_rows_fall_back_to_row_groups(self):
         from deepspeed_tpu.inference.quantized import _pick_groups

@@ -12,9 +12,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.generation import llama_paged_generator
-from deepspeed_tpu.inference.serving import ServingEngine, \
-    llama_serving_engine
+from deepspeed_tpu.inference.generation import paged_generator
+from deepspeed_tpu.inference.paged_forward import forward_paged
+from deepspeed_tpu.inference.serving import ServingEngine, serving_engine
 from deepspeed_tpu.models import llama
 
 
@@ -26,7 +26,7 @@ def model():
 
 
 def offline_expected(cfg, params, prompt, n_new):
-    gen = llama_paged_generator(params, cfg, page_size=8)
+    gen = paged_generator(params, cfg, page_size=8)
     out = gen.generate(jnp.asarray([prompt], jnp.int32),
                        max_new_tokens=n_new)
     return [int(t) for t in np.asarray(out[0])]
@@ -43,7 +43,7 @@ class TestServing:
     @pytest.mark.slow
     def test_staggered_arrivals_match_offline_greedy(self, model, devices):
         cfg, params = model
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=3, page_size=8, num_pages=32,
             max_seq=64, prefill_bucket=8)
         # staggered: a at step 0, b after one step, c after another
@@ -63,7 +63,7 @@ class TestServing:
     @pytest.mark.slow
     def test_more_requests_than_slots(self, model, devices):
         cfg, params = model
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=2, page_size=8, num_pages=32,
             max_seq=64, prefill_bucket=8)
         for rid, (prompt, n_new) in PROMPTS.items():
@@ -75,7 +75,7 @@ class TestServing:
 
     def test_page_growth_across_boundaries(self, model, devices):
         cfg, params = model
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=2, page_size=4, num_pages=64,
             max_seq=64, prefill_bucket=4)
         eng.submit("long", [7, 7, 7], max_new_tokens=21)  # crosses 5 pages
@@ -85,7 +85,7 @@ class TestServing:
     def test_preemption_under_page_pressure(self, model, devices):
         cfg, params = model
         # tiny pool: both sequences cannot hold all their pages at once
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=2, page_size=4, num_pages=7,
             max_seq=40, prefill_bucket=4)
         eng.submit("x", [5, 9, 2], max_new_tokens=12)
@@ -103,7 +103,7 @@ class TestServing:
         # as EOS: serving must stop there
         want = offline_expected(cfg, params, [5, 9, 2], 6)
         eos = want[3 + 2]  # 3 prompt tokens, 3rd generated
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=2, page_size=8, num_pages=32,
             max_seq=64, prefill_bucket=8, eos_token_id=eos)
         eng.submit("e", [5, 9, 2], max_new_tokens=6)
@@ -113,7 +113,7 @@ class TestServing:
 
     def test_rejects_oversized_request(self, model, devices):
         cfg, params = model
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=1, page_size=8, num_pages=16, max_seq=32)
         with pytest.raises(ValueError, match="max_seq"):
             eng.submit("big", list(range(30)), max_new_tokens=10)
@@ -123,7 +123,7 @@ class TestServing:
     def test_rejects_request_larger_than_pool(self, model, devices):
         cfg, params = model
         # 4 usable pages of 4 = 16 tokens max lifetime; ask for 20
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=1, page_size=4, num_pages=5, max_seq=32)
         with pytest.raises(ValueError, match="never"):
             eng.submit("big", list(range(10)), max_new_tokens=10)
@@ -132,7 +132,7 @@ class TestServing:
         # prompt near max_seq with prefill_bucket > remaining table space:
         # Tpad must clamp to the row width instead of crashing admission
         cfg, params = model
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=1, page_size=4, num_pages=16,
             max_seq=40, prefill_bucket=32)
         prompt = [3] * 37
@@ -169,7 +169,7 @@ class TestSampleRows:
     def test_mixed_traffic_completes(self, model, devices):
         # sampled + greedy requests through the full loop
         cfg, params = model
-        engine = llama_serving_engine(
+        engine = serving_engine(
             params, cfg, max_batch=4, page_size=8, num_pages=32,
             max_seq=32, prefill_bucket=8)
         rng = np.random.default_rng(3)
@@ -187,7 +187,7 @@ class TestDecodeChunk:
 
     def _run(self, model, chunk, reqs):
         cfg, params = model
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=3, page_size=8, num_pages=32,
             max_seq=64, prefill_bucket=8, decode_chunk=chunk)
         for rid, (prompt, n) in reqs.items():
@@ -219,7 +219,7 @@ class TestDecodeChunk:
 
     def test_chunked_with_more_requests_than_slots(self, model, devices):
         cfg, params = model
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=2, page_size=8, num_pages=24,
             max_seq=48, prefill_bucket=8, decode_chunk=4)
         rng = np.random.default_rng(5)
@@ -253,13 +253,13 @@ def offline_chunked_expected(cfg, params, prompt, n_new, C, page_size=8):
         toks = np.zeros((1, C), np.int32)
         toks[0, :take] = prompt[done:done + take]
         cache = cache._replace(seq_lens=jnp.full((1,), done, jnp.int32))
-        logits, cache = llama.forward_paged(
+        logits, cache = forward_paged(
             params, jnp.asarray(toks), cfg, cache, continuation=True)
         done += take
     out.append(int(jnp.argmax(logits[0, take - 1])))
     cache = cache._replace(seq_lens=jnp.full((1,), T, jnp.int32))
     for _ in range(n_new - 1):
-        logits, cache = llama.forward_paged(
+        logits, cache = forward_paged(
             params, jnp.asarray([[out[-1]]], jnp.int32), cfg, cache)
         out.append(int(jnp.argmax(logits[0, -1])))
     return out
@@ -275,7 +275,7 @@ class TestChunkedPrefill:
         cfg, params = model
         prompt = list(np.random.default_rng(5).integers(
             0, cfg.vocab_size, 37))
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=2, page_size=8, num_pages=32,
             max_seq=64, prefill_chunk=8)
         eng.submit("long", prompt, max_new_tokens=5)
@@ -293,7 +293,7 @@ class TestChunkedPrefill:
         long_prompt = list(np.random.default_rng(6).integers(
             0, cfg.vocab_size, 48))
         short_prompt = [5, 9, 2]
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=2, page_size=8, num_pages=32,
             max_seq=64, prefill_chunk=4)
         eng.submit("long", long_prompt, max_new_tokens=4)
@@ -322,7 +322,7 @@ class TestChunkedPrefill:
     @pytest.mark.slow
     def test_mixed_with_preemption_pool_pressure(self, model, devices):
         cfg, params = model
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=3, page_size=4, num_pages=24,
             max_seq=48, prefill_chunk=8)
         rng = np.random.default_rng(7)

@@ -1,8 +1,10 @@
 """GPT-2 continuous-batching serving (ref: the reference serves GPT-2
 through kernel injection, deepspeed/module_inject/containers/gpt2.py).
 
-Oracles: the dense-cache forward_with_cache generator (cross-oracle for
-the paged forward) and the offline paged generator (for the scheduler).
+Oracle: the offline paged generator (for the scheduler).  That oracle
+against the dense-cache generator, and the registry against the oracle,
+are checked a family a case in tests/test_generation.py and
+tests/test_models.py.
 """
 
 import numpy as np
@@ -11,8 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.generation import (gpt2_generator,
-                                                gpt2_paged_generator)
+from deepspeed_tpu.inference.generation import paged_generator
 from deepspeed_tpu.inference.serving import serving_engine
 from deepspeed_tpu.models import gpt2
 
@@ -33,33 +34,13 @@ PROMPTS = {
 
 
 def offline_expected(cfg, params, prompt, n_new):
-    gen = gpt2_paged_generator(params, cfg, page_size=8)
+    gen = paged_generator(params, cfg, page_size=8)
     out = gen.generate(jnp.asarray([prompt], jnp.int32),
                        max_new_tokens=n_new)
     return [int(t) for t in np.asarray(out[0])]
 
 
 class TestGPT2Serving:
-    def test_paged_matches_dense_cache_greedy(self, model, devices):
-        """The paged forward (ragged learned positions, page writes)
-        must generate exactly like forward_with_cache."""
-        cfg, params = model
-        prompt, n_new = PROMPTS["b"]
-        paged = offline_expected(cfg, params, prompt, n_new)
-        dense = gpt2_generator(params, cfg).generate(
-            jnp.asarray([prompt], jnp.int32), max_new_tokens=n_new)
-        assert paged == [int(t) for t in np.asarray(dense[0])]
-
-    def test_registry_serves_gpt2(self, model, devices):
-        cfg, params = model
-        eng = serving_engine(params, cfg, max_batch=2, page_size=8,
-                             num_pages=32, max_seq=64, prefill_bucket=8)
-        for rid, (p, n) in PROMPTS.items():
-            eng.submit(rid, p, max_new_tokens=n)
-        outs = eng.run()
-        for rid, (p, n) in PROMPTS.items():
-            assert outs[rid] == offline_expected(cfg, params, p, n), rid
-
     @pytest.mark.slow
     def test_split_fuse_matches(self, model, devices):
         cfg, params = model

@@ -27,9 +27,7 @@ from deepspeed_tpu.inference.kernels import (
     paged_decode_attention_v2, paged_decode_attention_v2_quant,
     quantize_kv_rows, resolve_serving_kernels)
 from deepspeed_tpu.inference.kv_tier import KV_TIER_QUANT_RTOL, quantize_page
-from deepspeed_tpu.inference.serving import (_sample_rows,
-                                             llama_serving_engine,
-                                             serving_engine)
+from deepspeed_tpu.inference.serving import _sample_rows, serving_engine
 from deepspeed_tpu.models import gpt2, llama
 from deepspeed_tpu.ops.sampling_pallas import (
     _FUSED_SAMPLE_MIN_ROWS_X_VOCAB, fused_greedy_rows, fused_sample_rows,
@@ -480,10 +478,10 @@ class TestEnginePolicy:
         cfg, params = llama_model
         mesh = MeshSpec.build({"model": 2}, devices=jax.devices()[:2])
         try:
-            base = llama_serving_engine(params, cfg, mesh=mesh, **KW)
+            base = serving_engine(params, cfg, mesh=mesh, **KW)
             want = serve_all(base)
             for forced in ("pallas_v1", "pallas_v2"):
-                eng = llama_serving_engine(
+                eng = serving_engine(
                     params, cfg, mesh=mesh,
                     kernels={"paged_attention": forced}, **KW)
                 assert serve_all(eng) == want
@@ -555,11 +553,11 @@ class TestForcedKernelIdentity:
         prompts = churn_prompts(cfg.vocab_size, groups=2, per=1,
                                 seed=17)[:4]
         kw = dict(KW, zero_inference={"enabled": True, "tier": "host"})
-        base = llama_serving_engine(params, cfg, **kw)
+        base = serving_engine(params, cfg, **kw)
         for i, p in enumerate(prompts):
             base.submit(i, p, max_new_tokens=5)
         want = base.run()
-        eng = llama_serving_engine(
+        eng = serving_engine(
             params, cfg, kernels={"fused_sampling": "on"}, **kw)
         for i, p in enumerate(prompts):
             eng.submit(i, p, max_new_tokens=5)
@@ -570,7 +568,7 @@ class TestForcedKernelIdentity:
         cfg, params = llama_model
         with pytest.raises(NotImplementedError,
                            match="quantized_resident"):
-            llama_serving_engine(
+            serving_engine(
                 params, cfg, prefix_cache=True,
                 kv_tier={"enabled": True, "quantize_cold": True,
                          "quantized_resident": True},

@@ -12,10 +12,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.generation import (mixtral_generator,
-                                                mixtral_paged_generator)
-from deepspeed_tpu.inference.serving import (mixtral_serving_engine,
-                                             serving_engine)
+from deepspeed_tpu.inference.generation import paged_generator
+from deepspeed_tpu.inference.serving import serving_engine
 from deepspeed_tpu.models import mixtral
 
 
@@ -28,7 +26,7 @@ def model():
 
 
 def offline_expected(cfg, params, prompt, n_new):
-    gen = mixtral_paged_generator(params, cfg, page_size=8)
+    gen = paged_generator(params, cfg, page_size=8)
     out = gen.generate(jnp.asarray([prompt], jnp.int32),
                        max_new_tokens=n_new)
     return [int(t) for t in np.asarray(out[0])]
@@ -42,20 +40,10 @@ PROMPTS = {
 
 
 class TestMixtralServing:
-    def test_paged_oracle_matches_dense_cache_greedy(self, model, devices):
-        """Cross-oracle: the paged MoE forward must route and generate
-        exactly like the dense-cache forward_with_cache path."""
-        cfg, params = model
-        prompt, n_new = PROMPTS["a"]
-        paged = offline_expected(cfg, params, prompt, n_new)
-        dense = mixtral_generator(params, cfg).generate(
-            jnp.asarray([prompt], jnp.int32), max_new_tokens=n_new)
-        assert paged == [int(t) for t in np.asarray(dense[0])]
-
     @pytest.mark.slow
     def test_staggered_arrivals_match_offline(self, model, devices):
         cfg, params = model
-        eng = mixtral_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=2, page_size=8, num_pages=32,
             max_seq=64, prefill_bucket=8)
         eng.submit("a", PROMPTS["a"][0], max_new_tokens=PROMPTS["a"][1])
@@ -72,7 +60,7 @@ class TestMixtralServing:
     @pytest.mark.slow
     def test_split_fuse_chunked_prefill_matches(self, model, devices):
         cfg, params = model
-        eng = mixtral_serving_engine(
+        eng = serving_engine(
             params, cfg, max_batch=2, page_size=8, num_pages=32,
             max_seq=64, prefill_chunk=4, decode_chunk=2)
         long_prompt = list(range(2, 23))             # 21 tokens, 6 chunks
@@ -89,7 +77,7 @@ class TestMixtralServing:
         from deepspeed_tpu.inference.quantized import QuantizedTensor
 
         cfg, params = model
-        eng = mixtral_serving_engine(
+        eng = serving_engine(
             params, cfg, weight_dtype="int8", max_batch=2, page_size=8,
             num_pages=32, max_seq=64, prefill_bucket=8)
         gate = eng.params["blocks"]["gate"]
@@ -108,7 +96,7 @@ class TestMixtralServing:
         from deepspeed_tpu.topology import MeshSpec
 
         cfg, params = model
-        base = mixtral_serving_engine(
+        base = serving_engine(
             params, cfg, max_batch=2, page_size=8, num_pages=32,
             max_seq=64, prefill_bucket=8)
         for rid, (p, n) in PROMPTS.items():
@@ -116,7 +104,7 @@ class TestMixtralServing:
         want = base.run()
 
         mesh = MeshSpec.build({"expert": 2}, devices=jax.devices()[:2])
-        eng = mixtral_serving_engine(
+        eng = serving_engine(
             params, cfg, mesh=mesh, max_batch=2, page_size=8,
             num_pages=32, max_seq=64, prefill_bucket=8)
         spec = eng.params["blocks"]["w1"].sharding.spec
@@ -132,7 +120,7 @@ class TestMixtralServing:
         from deepspeed_tpu.topology import MeshSpec
 
         cfg, params = model
-        base = mixtral_serving_engine(
+        base = serving_engine(
             params, cfg, max_batch=2, page_size=8, num_pages=32,
             max_seq=64, prefill_bucket=8)
         for rid, (p, n) in PROMPTS.items():
@@ -140,7 +128,7 @@ class TestMixtralServing:
         want = base.run()
         mesh = MeshSpec.build({"model": 2, "expert": 2},
                               devices=jax.devices()[:4])
-        eng = mixtral_serving_engine(
+        eng = serving_engine(
             params, cfg, mesh=mesh, max_batch=2, page_size=8,
             num_pages=32, max_seq=64, prefill_bucket=8)
         wq_spec = eng.params["blocks"]["wq"].sharding.spec
@@ -163,17 +151,17 @@ class TestMixtralServing:
         cfg, params = model
         kw = dict(max_batch=2, page_size=8, num_pages=32, max_seq=64,
                   prefill_bucket=8)
-        base = mixtral_serving_engine(params, cfg, weight_dtype="int8",
-                                      quant_group_size=16, **kw)
+        base = serving_engine(params, cfg, weight_dtype="int8",
+                              quant_group_size=16, **kw)
         for rid, (p, n) in PROMPTS.items():
             base.submit(rid, p, max_new_tokens=n)
         want = base.run()
 
         mesh = MeshSpec.build({"expert": 2}, devices=jax.devices()[:2])
         try:
-            eng = mixtral_serving_engine(params, cfg, mesh=mesh,
-                                         weight_dtype="int8",
-                                         quant_group_size=16, **kw)
+            eng = serving_engine(params, cfg, mesh=mesh,
+                                 weight_dtype="int8",
+                                 quant_group_size=16, **kw)
             w1 = eng.params["blocks"]["w1"]
             assert isinstance(w1, QuantizedTensor)
             assert "expert" in [s for s in w1.q.sharding.spec if s]
@@ -184,24 +172,3 @@ class TestMixtralServing:
         finally:
             set_current_mesh(None)
         assert got == want
-
-    def test_registry_dispatch(self, model, devices):
-        """Pin the dispatch itself: serving a Mixtral through the generic
-        entrypoint must produce the MoE model's tokens (a mis-dispatch to
-        the llama builder would KeyError or emit different tokens)."""
-        from deepspeed_tpu.models import llama
-
-        cfg, params = model
-        eng = serving_engine(params, cfg, max_batch=2, page_size=8,
-                             num_pages=32, max_seq=64)
-        eng.submit("a", PROMPTS["a"][0], max_new_tokens=4)
-        outs = eng.run()
-        assert outs["a"] == offline_expected(cfg, params,
-                                             PROMPTS["a"][0], 4)
-        lcfg = llama.LlamaConfig.tiny(dim=32, n_layers=1, n_heads=2,
-                                      n_kv_heads=2)
-        lparams = llama.init_params(jax.random.PRNGKey(1), lcfg)
-        serving_engine(lparams, lcfg, max_batch=1, page_size=8,
-                       num_pages=16, max_seq=32)
-        with pytest.raises(TypeError, match="MixtralConfig"):
-            serving_engine(params, object(), max_batch=1)

@@ -12,7 +12,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.serving import llama_serving_engine
+from deepspeed_tpu.inference.serving import serving_engine
 from deepspeed_tpu.models import llama
 from deepspeed_tpu.topology import MeshSpec, set_current_mesh
 
@@ -43,12 +43,12 @@ def serve_all(eng):
 class TestTPServing:
     def test_tp2_matches_single_device(self, model, devices):
         cfg, params = model
-        base = llama_serving_engine(params, cfg, **KW)
+        base = serving_engine(params, cfg, **KW)
         want = serve_all(base)
 
         mesh = MeshSpec.build({"model": 2}, devices=jax.devices()[:2])
         try:
-            eng = llama_serving_engine(params, cfg, mesh=mesh, **KW)
+            eng = serving_engine(params, cfg, mesh=mesh, **KW)
             # the KV cache's head axis is genuinely sharded over model
             spec = eng.cache.k.sharding.spec
             assert "model" in [s for s in spec if s is not None]
@@ -63,14 +63,14 @@ class TestTPServing:
     @pytest.mark.slow
     def test_tp2_split_fuse_and_chunked_decode(self, model, devices):
         cfg, params = model
-        base = llama_serving_engine(params, cfg, **KW)
+        base = serving_engine(params, cfg, **KW)
         want = serve_all(base)
         mesh = MeshSpec.build({"model": 2}, devices=jax.devices()[:2])
         try:
-            eng = llama_serving_engine(params, cfg, mesh=mesh,
-                                       max_batch=2, page_size=8,
-                                       num_pages=32, max_seq=64,
-                                       prefill_chunk=4, decode_chunk=2)
+            eng = serving_engine(params, cfg, mesh=mesh,
+                                 max_batch=2, page_size=8,
+                                 num_pages=32, max_seq=64,
+                                 prefill_chunk=4, decode_chunk=2)
             got = serve_all(eng)
         finally:
             set_current_mesh(None)
@@ -82,15 +82,15 @@ class TestTPServing:
         weights, so served tokens match the unsharded int8 engine
         exactly — same codes, same scales, different placement."""
         cfg, params = model
-        base = llama_serving_engine(params, cfg, weight_dtype="int8",
-                                    quant_group_size=16, **KW)
+        base = serving_engine(params, cfg, weight_dtype="int8",
+                              quant_group_size=16, **KW)
         want = serve_all(base)
 
         mesh = MeshSpec.build({"model": 2}, devices=jax.devices()[:2])
         try:
-            eng = llama_serving_engine(params, cfg, mesh=mesh,
-                                       weight_dtype="int8",
-                                       quant_group_size=16, **KW)
+            eng = serving_engine(params, cfg, mesh=mesh,
+                                 weight_dtype="int8",
+                                 quant_group_size=16, **KW)
             # the int8 codes AND their group scales are genuinely
             # model-axis sharded (column-parallel wq: output dim)
             qt = eng.params["blocks"]["wq"]
@@ -108,6 +108,6 @@ class TestTPServing:
         mesh = MeshSpec.build({"model": 2}, devices=jax.devices()[:2])
         try:
             with pytest.raises(ValueError, match="divisible"):
-                llama_serving_engine(params, cfg, mesh=mesh, **KW)
+                serving_engine(params, cfg, mesh=mesh, **KW)
         finally:
             set_current_mesh(None)

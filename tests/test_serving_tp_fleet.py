@@ -23,7 +23,7 @@ from deepspeed_tpu.config import FleetConfig
 from deepspeed_tpu.fleet import fleet_router, tp_replica_mesh
 from deepspeed_tpu.inference.engine import (init_serving,
                                             serving_mesh_from_config)
-from deepspeed_tpu.inference.serving import llama_serving_engine
+from deepspeed_tpu.inference.serving import serving_engine
 from deepspeed_tpu.models import llama
 from deepspeed_tpu.topology import MeshSpec, set_current_mesh
 
@@ -63,13 +63,13 @@ def serve_all(eng, prompts=PROMPTS):
 class TestTPFlavorIdentity:
     def test_speculative_tp2_matches_single_device(self, model, tp2):
         cfg, params = model
-        base = llama_serving_engine(params, cfg,
-                                    speculative={"draft_tokens": 3},
-                                    **KW)
+        base = serving_engine(params, cfg,
+                              speculative={"draft_tokens": 3},
+                              **KW)
         want = serve_all(base)
-        eng = llama_serving_engine(params, cfg, mesh=tp2,
-                                   speculative={"draft_tokens": 3},
-                                   **KW)
+        eng = serving_engine(params, cfg, mesh=tp2,
+                             speculative={"draft_tokens": 3},
+                             **KW)
         assert serve_all(eng) == want
         # the verify sweep actually speculated under the mesh
         assert int(eng.registry.snapshot()["counters"].get(
@@ -83,10 +83,10 @@ class TestTPFlavorIdentity:
         reqs = {f"u{i}": (pre + rng.integers(1, cfg.vocab_size,
                                              3).tolist(), 5)
                 for i in range(3)}
-        base = llama_serving_engine(params, cfg, **KW)
+        base = serving_engine(params, cfg, **KW)
         want = serve_all(base, reqs)
-        eng = llama_serving_engine(params, cfg, mesh=tp2,
-                                   prefix_cache=True, **KW)
+        eng = serving_engine(params, cfg, mesh=tp2,
+                             prefix_cache=True, **KW)
         assert serve_all(eng, reqs) == want
         cnt = eng.registry.snapshot()["counters"]
         assert cnt.get("prefix_cache_cached_tokens", 0) > 0, \
@@ -95,8 +95,8 @@ class TestTPFlavorIdentity:
     @pytest.mark.slow
     def test_zero_inference_tp2_matches_resident(self, model, tp2):
         cfg, params = model
-        base = llama_serving_engine(params, cfg, mesh=tp2, **KW)
-        zi = llama_serving_engine(
+        base = serving_engine(params, cfg, mesh=tp2, **KW)
+        zi = serving_engine(
             params, cfg, mesh=tp2,
             zero_inference={"enabled": True, "tier": "host"}, **KW)
         assert zi.plan["n_streamed"] == cfg.n_layers
@@ -105,10 +105,10 @@ class TestTPFlavorIdentity:
     @pytest.mark.slow
     def test_chunked_decode_tp2_matches(self, model, tp2):
         cfg, params = model
-        base = llama_serving_engine(params, cfg, **KW)
+        base = serving_engine(params, cfg, **KW)
         want = serve_all(base)
-        eng = llama_serving_engine(params, cfg, mesh=tp2,
-                                   decode_chunk=2, **KW)
+        eng = serving_engine(params, cfg, mesh=tp2,
+                             decode_chunk=2, **KW)
         assert serve_all(eng) == want
 
 
@@ -150,7 +150,7 @@ class TestTPFleet:
         visibly sharded."""
         cfg, params = model
         try:
-            base = llama_serving_engine(params, cfg, **KW)
+            base = serving_engine(params, cfg, **KW)
             for rid, (p, n) in PROMPTS.items():
                 base.submit(rid, p, max_new_tokens=n)
             want = base.run()

@@ -18,9 +18,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.config import Config, SpeculativeConfig
 from deepspeed_tpu.inference.kernels import PageAllocator
-from deepspeed_tpu.inference.serving import (gpt2_serving_engine,
-                                             llama_serving_engine,
-                                             serving_engine)
+from deepspeed_tpu.inference.serving import serving_engine
 from deepspeed_tpu.inference.speculative import (Drafter, ModelDrafter,
                                                  NgramDrafter,
                                                  build_drafter,
@@ -125,7 +123,7 @@ class TestModelDrafter:
         assert d.propose(hist, 0) == []
 
     def test_unknown_family_rejected(self):
-        with pytest.raises(TypeError, match="no draft forward"):
+        with pytest.raises(TypeError, match="not a decoder family"):
             ModelDrafter({}, object(), draft_tokens=2)
 
 
@@ -268,8 +266,8 @@ class TestGreedyIdentity:
 
     def test_plain_gpt2(self, gpt2_model, devices):
         cfg, params = gpt2_model
-        want = serve_all(gpt2_serving_engine(params, cfg, **KW))
-        got = serve_all(gpt2_serving_engine(
+        want = serve_all(serving_engine(params, cfg, **KW))
+        got = serve_all(serving_engine(
             params, cfg, speculative={"draft_tokens": 4}, **KW))
         assert got == want
 
@@ -277,9 +275,9 @@ class TestGreedyIdentity:
         """The spec sweep REPLACES the chunked-decode scan; its output
         must still match a decode_chunk=2 baseline exactly."""
         cfg, params = gpt2_model
-        want = serve_all(gpt2_serving_engine(params, cfg,
-                                             decode_chunk=2, **KW))
-        got = serve_all(gpt2_serving_engine(
+        want = serve_all(serving_engine(params, cfg,
+                                        decode_chunk=2, **KW))
+        got = serve_all(serving_engine(
             params, cfg, decode_chunk=2,
             speculative={"draft_tokens": 3}, **KW))
         assert got == want
@@ -288,18 +286,18 @@ class TestGreedyIdentity:
         cfg, params = gpt2_model
         kw = dict(KW, prefill_chunk=4)
         long = {"long": (list(range(2, 21)), 6), **PROMPTS}
-        want = serve_all(gpt2_serving_engine(params, cfg, **kw),
+        want = serve_all(serving_engine(params, cfg, **kw),
                          prompts=long)
-        got = serve_all(gpt2_serving_engine(
+        got = serve_all(serving_engine(
             params, cfg, speculative={"draft_tokens": 3}, **kw),
             prompts=long)
         assert got == want
 
     def test_int8(self, gpt2_model, devices):
         cfg, params = gpt2_model
-        want = serve_all(gpt2_serving_engine(
+        want = serve_all(serving_engine(
             params, cfg, weight_dtype="int8", quant_group_size=16, **KW))
-        got = serve_all(gpt2_serving_engine(
+        got = serve_all(serving_engine(
             params, cfg, weight_dtype="int8", quant_group_size=16,
             speculative={"draft_tokens": 4}, **KW))
         assert got == want
@@ -312,10 +310,10 @@ class TestGreedyIdentity:
         prefix = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7, 9, 3]
         prompts = {f"u{i}": (prefix + [20 + i, 30 + i], 8)
                    for i in range(4)}
-        want = serve_all(gpt2_serving_engine(params, cfg,
+        want = serve_all(serving_engine(params, cfg,
                                              prefix_cache=True, **KW),
                          prompts=prompts)
-        eng = gpt2_serving_engine(
+        eng = serving_engine(
             params, cfg, prefix_cache=True,
             speculative={"draft_tokens": 4}, **KW)
         got = serve_all(eng, prompts=prompts)
@@ -335,12 +333,12 @@ class TestGreedyIdentity:
         acceptance — from the draft QUALITY a random-init tiny model's
         non-repetitive continuations can't provide."""
         cfg, params = llama_model
-        want = serve_all(llama_serving_engine(params, cfg, **KW))
+        want = serve_all(serving_engine(params, cfg, **KW))
         zi = {"enabled": True, "tier": "host", "hbm_budget_bytes": None}
-        base = llama_serving_engine(params, cfg, zero_inference=zi, **KW)
+        base = serving_engine(params, cfg, zero_inference=zi, **KW)
         out_base = serve_all(base)
         assert out_base == want
-        spec = llama_serving_engine(
+        spec = serving_engine(
             params, cfg, zero_inference=zi,
             speculative={"draft_tokens": 4}, **KW)
         out_spec = serve_all(spec)
@@ -354,7 +352,7 @@ class TestGreedyIdentity:
                         return full[len(t):len(t) + k]
                 return []
 
-        orac = llama_serving_engine(
+        orac = serving_engine(
             params, cfg, zero_inference=zi, drafter=_Oracle(),
             speculative={"draft_tokens": 4}, **KW)
         assert serve_all(orac) == want
@@ -375,10 +373,10 @@ class TestGreedyIdentity:
 
     def test_tp2(self, llama_model, devices):
         cfg, params = llama_model
-        want = serve_all(llama_serving_engine(params, cfg, **KW))
+        want = serve_all(serving_engine(params, cfg, **KW))
         mesh = MeshSpec.build({"model": 2}, devices=jax.devices()[:2])
         try:
-            got = serve_all(llama_serving_engine(
+            got = serve_all(serving_engine(
                 params, cfg, mesh=mesh,
                 speculative={"draft_tokens": 3}, **KW))
         finally:
@@ -389,9 +387,9 @@ class TestGreedyIdentity:
         """A resident small-model drafter (here: the target itself over
         a short padded window — quality irrelevant, exactness not)."""
         cfg, params = gpt2_model
-        want = serve_all(gpt2_serving_engine(params, cfg, **KW))
+        want = serve_all(serving_engine(params, cfg, **KW))
         drafter = ModelDrafter(params, cfg, draft_tokens=3, window=16)
-        got = serve_all(gpt2_serving_engine(
+        got = serve_all(serving_engine(
             params, cfg,
             speculative={"drafter": "model", "draft_tokens": 3},
             drafter=drafter, **KW))
@@ -404,9 +402,9 @@ class TestGreedyIdentity:
         output identical, nothing drafted until history repeats."""
         cfg, params = gpt2_model
         prompts = {"d": ([11, 23, 37, 41], 4)}
-        want = serve_all(gpt2_serving_engine(params, cfg, **KW),
+        want = serve_all(serving_engine(params, cfg, **KW),
                          prompts=prompts)
-        eng = gpt2_serving_engine(
+        eng = serving_engine(
             params, cfg,
             speculative={"draft_tokens": 4, "max_ngram": 4}, **KW)
         got = serve_all(eng, prompts=prompts)
@@ -418,8 +416,8 @@ class TestGreedyIdentity:
         requeued recompute must land the same greedy tokens."""
         cfg, params = gpt2_model
         kw = dict(KW, num_pages=14, max_batch=2)
-        want = serve_all(gpt2_serving_engine(params, cfg, **kw))
-        eng = gpt2_serving_engine(
+        want = serve_all(serving_engine(params, cfg, **kw))
+        eng = serving_engine(
             params, cfg, speculative={"draft_tokens": 4}, **kw)
         got = serve_all(eng)
         assert got == want
@@ -443,7 +441,7 @@ class TestRollbackCOW:
         live slot — the sweep must refuse to write it rather than
         silently poison the content-addressed index."""
         cfg, params = gpt2_model
-        eng = gpt2_serving_engine(
+        eng = serving_engine(
             params, cfg, prefix_cache=True,
             speculative={"draft_tokens": 4}, **KW)
         eng.submit("x", [5, 9, 2, 7, 1, 3, 2, 8, 4], max_new_tokens=8)
@@ -464,7 +462,7 @@ class TestRollbackCOW:
         pages)."""
         cfg, params = gpt2_model
         prefix = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7, 9, 3]
-        eng = gpt2_serving_engine(
+        eng = serving_engine(
             params, cfg, prefix_cache=True,
             speculative={"draft_tokens": 4}, **KW)
         eng.submit("u0", prefix + [21, 31], max_new_tokens=8)
@@ -487,7 +485,7 @@ class TestRollbackCOW:
 class TestTelemetryAndTrace:
     def test_spec_metric_family(self, gpt2_model, devices):
         cfg, params = gpt2_model
-        eng = gpt2_serving_engine(
+        eng = serving_engine(
             params, cfg, speculative={"draft_tokens": 4},
             telemetry=True, **KW)
         serve_all(eng)
@@ -508,7 +506,7 @@ class TestTelemetryAndTrace:
         from deepspeed_tpu.request_trace import request_breakdown
 
         cfg, params = gpt2_model
-        eng = gpt2_serving_engine(
+        eng = serving_engine(
             params, cfg, speculative={"draft_tokens": 4},
             tracing={"sample_rate": 1.0}, **KW)
         serve_all(eng)
@@ -541,10 +539,10 @@ class TestTelemetryAndTrace:
         fetch per step — concurrent admissions share a sync instead of
         paying one device round-trip each."""
         cfg, params = gpt2_model
-        eng = gpt2_serving_engine(params, cfg, telemetry=True,
-                                  max_batch=4, page_size=8,
-                                  num_pages=32, max_seq=64,
-                                  prefill_bucket=8)
+        eng = serving_engine(params, cfg, telemetry=True,
+                             max_batch=4, page_size=8,
+                             num_pages=32, max_seq=64,
+                             prefill_bucket=8)
         for i in range(4):
             eng.submit(i, [5 + i, 9, 2], max_new_tokens=4)
         eng.step()                         # 4 admissions, one flush

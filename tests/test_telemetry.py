@@ -382,17 +382,16 @@ class TestStreamingTelemetry:
     def test_zero_inference_metrics(self, devices):
         """Streamed serving populates upload/sweep counters, the wait
         histogram, and keeps the stats shim keys the benches read."""
-        from deepspeed_tpu.inference.zero_inference import (
-            zero_inference_serving_engine)
+        from deepspeed_tpu.inference.serving import serving_engine
         from deepspeed_tpu.models import llama
 
         cfg = llama.LlamaConfig.tiny(dim=32, n_layers=2, n_heads=2,
                                      n_kv_heads=2)
         params = llama.init_params(jax.random.PRNGKey(0), cfg)
-        zi = zero_inference_serving_engine(
-            params, cfg, {"enabled": True, "tier": "host"},
-            family="llama", max_batch=2, page_size=8, num_pages=16,
-            max_seq=32, prefill_bucket=8)
+        zi = serving_engine(
+            params, cfg, zero_inference={"enabled": True, "tier": "host"},
+            max_batch=2, page_size=8, num_pages=16, max_seq=32,
+            prefill_bucket=8)
         zi.submit("a", [5, 9, 2], max_new_tokens=4)
         zi.run()
         snap = zi.registry.snapshot()
@@ -408,17 +407,16 @@ class TestStreamingTelemetry:
     def test_zero_inference_serves_with_telemetry_disabled(self, devices):
         """The streamed engine must serve with telemetry off (null
         metrics answer .sum/.value on every streaming hot path)."""
-        from deepspeed_tpu.inference.zero_inference import (
-            zero_inference_serving_engine)
+        from deepspeed_tpu.inference.serving import serving_engine
         from deepspeed_tpu.models import llama
 
         cfg = llama.LlamaConfig.tiny(dim=32, n_layers=2, n_heads=2,
                                      n_kv_heads=2)
         params = llama.init_params(jax.random.PRNGKey(0), cfg)
-        zi = zero_inference_serving_engine(
-            params, cfg, {"enabled": True, "tier": "host"},
-            family="llama", max_batch=2, page_size=8, num_pages=16,
-            max_seq=32, prefill_bucket=8, telemetry=False)
+        zi = serving_engine(
+            params, cfg, zero_inference={"enabled": True, "tier": "host"},
+            max_batch=2, page_size=8, num_pages=16, max_seq=32,
+            prefill_bucket=8, telemetry=False)
         zi.submit("a", [5, 9], max_new_tokens=3)
         outs = zi.run()
         assert len(outs["a"]) == 5               # prompt + 3 generated

@@ -16,8 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.config import Config, ZeroInferenceConfig
-from deepspeed_tpu.inference.serving import llama_serving_engine, \
-    serving_engine
+from deepspeed_tpu.inference.serving import serving_engine
 from deepspeed_tpu.inference.zero_inference import (
     ZeroInferenceServingEngine, plan_residency)
 from deepspeed_tpu.models import llama
@@ -78,8 +77,8 @@ class TestZeroInferenceServing:
         cfg, params = model
         bf16 = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
         image = sum(x.nbytes for x in jax.tree.leaves(bf16))
-        resident = llama_serving_engine(bf16, cfg, **KW)
-        zi = llama_serving_engine(
+        resident = serving_engine(bf16, cfg, **KW)
+        zi = serving_engine(
             bf16, cfg,
             zero_inference={"hbm_budget_bytes": image - 1,
                             "tier": "host"}, **KW)
@@ -110,11 +109,11 @@ class TestZeroInferenceServing:
             cfg.head_dim * 2
         # floor (stem+head + cache + 2-layer working set) + exactly 2
         budget = stem_head + cache + 2 * layer_bytes + 2 * layer_bytes
-        zi = llama_serving_engine(
+        zi = serving_engine(
             params, cfg, zero_inference={"hbm_budget_bytes": budget},
             **KW)
         assert zi.plan["n_resident"] == 2 and zi.plan["n_streamed"] == 3
-        resident = llama_serving_engine(params, cfg, **KW)
+        resident = serving_engine(params, cfg, **KW)
         assert _serve(zi) == _serve(resident)
 
     def test_tied_embeddings_charged_once(self, devices):
@@ -124,17 +123,17 @@ class TestZeroInferenceServing:
         cfg = llama.LlamaConfig.tiny(dim=64, n_layers=2, n_heads=4,
                                      n_kv_heads=2, tie_embeddings=True)
         params = llama.init_params(jax.random.PRNGKey(0), cfg)
-        zi = llama_serving_engine(params, cfg, zero_inference={}, **KW)
+        zi = serving_engine(params, cfg, zero_inference={}, **KW)
         assert zi.plan["stem_head_bytes"] == \
             params["embed"].nbytes + params["final_norm"].nbytes
-        resident = llama_serving_engine(params, cfg, **KW)
+        resident = serving_engine(params, cfg, **KW)
         assert _serve(zi) == _serve(resident)
 
     @pytest.mark.slow
     def test_nvme_tier_matches(self, model, devices, tmp_path):
         cfg, params = model
-        resident = llama_serving_engine(params, cfg, **KW)
-        zi = llama_serving_engine(
+        resident = serving_engine(params, cfg, **KW)
+        zi = serving_engine(
             params, cfg,
             zero_inference={"tier": "nvme",
                             "nvme_path": str(tmp_path)}, **KW)
@@ -147,9 +146,9 @@ class TestZeroInferenceServing:
         """int8 composes: tier holds codes+scales on the SAME per-leaf
         quantization grid, so streamed == resident under int8 too."""
         cfg, params = model
-        r8 = llama_serving_engine(params, cfg, weight_dtype="int8", **KW)
-        z8 = llama_serving_engine(params, cfg, weight_dtype="int8",
-                                  zero_inference={}, **KW)
+        r8 = serving_engine(params, cfg, weight_dtype="int8", **KW)
+        z8 = serving_engine(params, cfg, weight_dtype="int8",
+                            zero_inference={}, **KW)
         assert _serve(z8) == _serve(r8)
 
     @pytest.mark.slow
@@ -160,21 +159,21 @@ class TestZeroInferenceServing:
         long_prompt = list(np.random.default_rng(5).integers(
             1, cfg.vocab_size, 21))
         prompts = dict(PROMPTS, long=(long_prompt, 5))
-        resident = llama_serving_engine(params, cfg, **kw)
-        zi = llama_serving_engine(
+        resident = serving_engine(params, cfg, **kw)
+        zi = serving_engine(
             params, cfg, zero_inference={"prefetch_depth": 2}, **kw)
         assert _serve(zi, prompts) == _serve(resident, prompts)
 
     @pytest.mark.slow
     def test_mixtral_streams(self, devices):
-        from deepspeed_tpu.inference.serving import mixtral_serving_engine
+        from deepspeed_tpu.inference.serving import serving_engine
         from deepspeed_tpu.models import mixtral
 
         cfg = mixtral.MixtralConfig.tiny(num_experts=4)
         params = mixtral.init_params(jax.random.PRNGKey(2), cfg)
-        resident = mixtral_serving_engine(params, cfg, **KW)
-        zi = mixtral_serving_engine(params, cfg, zero_inference={},
-                                    **KW)
+        resident = serving_engine(params, cfg, **KW)
+        zi = serving_engine(params, cfg, zero_inference={},
+                            **KW)
         assert _serve(zi) == _serve(resident)
 
     @pytest.mark.slow
@@ -183,9 +182,9 @@ class TestZeroInferenceServing:
 
         cfg, params = model
         ms = MeshSpec.build({"data": 4, "model": 2})
-        resident = llama_serving_engine(params, cfg, mesh=ms, **KW)
-        zi = llama_serving_engine(params, cfg, mesh=ms,
-                                  zero_inference={}, **KW)
+        resident = serving_engine(params, cfg, mesh=ms, **KW)
+        zi = serving_engine(params, cfg, mesh=ms,
+                            zero_inference={}, **KW)
         # uploaded streamed layers land model-axis sharded
         _, lp = next(iter(zi._layer_sweep()))
         assert "model" in str(lp["wq"].sharding.spec), \
