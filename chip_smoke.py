@@ -307,7 +307,8 @@ def serve_phase(size, seed, cache, on_tpu):
     bucket0 = -(-size.prompt_lo // size.prefill_bucket) * size.prefill_bucket
     prefill_flash = "tpu_custom_call" in eng._prefill.lower(
         jax.tree.map(shape, params),
-        jax.ShapeDtypeStruct((1, bucket0), jnp.int32), view).as_text()
+        jax.ShapeDtypeStruct((1, bucket0), jnp.int32), view,
+        jax.ShapeDtypeStruct((1,), jnp.int32)).as_text()
     if on_tpu and not prefill_flash:
         raise AssertionError("prefill did not reach the flash kernel")
 

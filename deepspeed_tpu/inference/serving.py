@@ -48,10 +48,18 @@ for all rows at once (per-row temperature, greedy = argmax), the page
 table and seq_lens upload only when the slot composition changed
 (dirty flags), and between composition changes the device-side
 structural ``seq_lens + 1`` of the decode step is simply trusted.
-Prefill-boundary tokens follow the same discipline: every prompt that
-finishes prefilling within a step queues its last-position logits, and
-ONE batched ``_sample_rows`` fetch appends them all — no per-slot
-device round-trip on the admission path.
+Prefill-boundary tokens follow the same discipline: a prefill program
+returns the logits ROW of its last real position (sliced inside the
+program), one small compiled program — the resolved sampler, the same
+function the decode program calls — turns the row into a ``[1]`` token
+array on the device, every prompt that finishes prefilling within a
+step queues that array, and ONE ``device_get`` of the queue appends
+them all.  Sampling keys are derived on the device from integers the
+host already has (the admission ordinal, the dispatch ordinal) folded
+into a base key uploaded once at build.  So between the start of
+``step()`` and the token fetch the host dispatches compiled programs
+and uploads NumPy arrays, and runs no eager ``jnp`` / ``jax.random``
+operation.
 
 Speculative decoding (``speculative=`` / the config block): each decode
 iteration drafts up to K cheap tokens per slot (prompt-lookup n-gram by
@@ -124,6 +132,88 @@ def _sample_rows(logits: jnp.ndarray, keys: jnp.ndarray,
         sampled = jax.vmap(jax.random.categorical)(keys, scaled)
         return jnp.where(temps == 0.0, greedy,
                          sampled.astype(jnp.int32))
+
+
+def _dispatch_keys(key: jnp.ndarray, ordinal: jnp.ndarray,
+                   *shape: int) -> jnp.ndarray:
+    """``[*shape, 2]`` sampling keys of one decode or verify dispatch,
+    derived inside its program from the engine's ``key`` and the
+    dispatch's ordinal."""
+    with jax.named_scope("sample"):
+        return jax.random.split(jax.random.fold_in(key, ordinal),
+                                int(np.prod(shape))).reshape(*shape, -1)
+
+
+def _last_row(logits: jnp.ndarray, last: jnp.ndarray) -> jnp.ndarray:
+    """What a prefill's first token is sampled from and no more:
+    ``[1, T, V]`` logits -> the ``[1, V]`` row at ``last`` (int32 [1]),
+    the last REAL position of the call.  The padded tail's logits are
+    never read, and no ``[1, T, V]`` leaves a program."""
+    return jax.lax.dynamic_index_in_dim(logits[0], last[0], axis=0)
+
+
+def boundary_program(sample):
+    """The boundary token of one admission: its row, the admissions'
+    base key, its ordinal, its temperature [1] -> token [1].  ONE
+    program whatever the prompt's length: inlined into every prefill
+    program the sampler cost each of them 0.2 s of lowering on every
+    engine build (PERF.md 6, PR 31), and a build warms one a bucket."""
+    def dstpu_boundary(row, key, ordinal, temp):
+        with jax.named_scope("sample"):
+            return sample(row, jax.random.fold_in(key, ordinal)[None],
+                          temp)
+
+    return dstpu_boundary
+
+
+def serving_programs(prefill_fn, decode_fn, chunk_prefill_fn, sample,
+                     decode_chunk: int, max_batch: int):
+    """The serving programs over a model's forwards ``(params, tokens,
+    cache) -> (logits, cache)``, not yet jitted: ``(dstpu_prefill,
+    dstpu_chunk, dstpu_boundary, dstpu_sweep, dstpu_decode)``.  The
+    names are stable: a capture's "XLA Modules" line says which program
+    ran (``jit_dstpu_prefill`` …).
+
+    ``sample`` is the policy-resolved sampler (the fused pallas argmax
+    when ``kernels.fused_sampling`` resolved "on", the jitted XLA twin
+    otherwise) — both emit bit-identical greedy tokens and share the
+    categorical math, so flipping the policy can never change a served
+    greedy stream."""
+    # A prefill returns its last row (_last_row): the model's contract
+    # stays, the slice is the engine's.
+    def dstpu_prefill(params, tokens, cache, last):
+        logits, cache = prefill_fn(params, tokens, cache)
+        return _last_row(logits, last), cache
+
+    def dstpu_chunk(params, tokens, cache, last):
+        logits, cache = chunk_prefill_fn(params, tokens, cache)
+        return _last_row(logits, last), cache
+
+    # the speculative verify sweep: logits at every position
+    def dstpu_sweep(params, tokens, cache):
+        return chunk_prefill_fn(params, tokens, cache)
+
+    # K decode steps in ONE on-device scan: each step's sampled token
+    # feeds the next, so the host syncs once per K tokens (what a
+    # sync costs on the chip is not measured yet — ROADMAP S2).
+    # Tokens a request emits after its own
+    # EOS within a chunk are discarded by the host (waste < K).
+    # K=1 runs the same path as a length-1 scan.
+    def dstpu_decode(params, tok, cache, key, ordinal, temps):
+        keys = _dispatch_keys(key, ordinal, decode_chunk, max_batch)
+
+        def one(carry, key_k):
+            t, c = carry
+            logits, c = decode_fn(params, t, c)
+            with jax.named_scope("sample"):
+                nxt = sample(logits[:, -1], key_k, temps)
+            return (nxt[:, None], c), nxt
+
+        (_, cache), toks = jax.lax.scan(one, (tok, cache), keys)
+        return jnp.swapaxes(toks, 0, 1), cache          # [B, K]
+
+    return dstpu_prefill, dstpu_chunk, boundary_program(sample), \
+        dstpu_sweep, dstpu_decode
 
 
 def _req_key(req_id: Any) -> str:
@@ -238,8 +328,9 @@ class _Slot:
     req: Request
     seq_len: int                       # tokens resident in the KV cache
     generated: List[int]
-    rng: jax.Array
-    seq_id: int = -1                   # PageAllocator owner key
+    # PageAllocator owner key, and the admission ordinal the boundary
+    # draw's key is folded from
+    seq_id: int = -1
     prefill_done: int = -1             # chunked prefill progress; -1 = done
     last_tok_t: float = 0.0            # inter-token latency clock
     promo: Optional[_Promotion] = None  # in-flight tier-page promotion
@@ -436,13 +527,19 @@ class ServingEngine:
         self._table_dirty = True
         self._lens_dirty = True
         self.slots: List[Optional[_Slot]] = [None] * max_batch
-        # prefill-boundary sampling queue: (slot, logits row, key, temp)
-        # collected per admission / final prefill chunk, flushed as ONE
-        # batched _sample_rows fetch per step (no per-slot round-trip)
-        self._pending_boundary: List[Tuple[int, Any, Any, float]] = []
+        # prefill-boundary queue: (slot, the [1] token sampled on the
+        # device from its prefill's last row), collected per admission
+        # / final prefill chunk and fetched in ONE device_get per step
+        self._pending_boundary: List[Tuple[int, Any]] = []
         self.queue: "collections.deque[Request]" = collections.deque()
         self._seq_counter = 0
-        self._rng = jax.random.PRNGKey(seed)
+        # sampling keys are derived on the device: the base key goes up
+        # once, here, and every program that samples folds into it an
+        # integer the host already has, from one counter space — odd
+        # for an admission (2 * seq_id + 1: its boundary draw), even
+        # for a decode or verify dispatch (2 * its ordinal)
+        self._key = self._put(jax.random.PRNGKey(seed))
+        self._n_dispatch = 0
         self.finished: Dict[Any, List[int]] = {}
         self._newly_finished: List[Any] = []
 
@@ -515,9 +612,15 @@ class ServingEngine:
         self._evicted_seen = 0
         self._c_boundary_syncs = r.counter(
             "serving_boundary_syncs",
-            "batched prefill-boundary sampling syncs (one per step "
-            "with >= 1 prefill completion — replaces one host "
-            "round-trip per admitted slot)")
+            "prefill-boundary token fetches (one per step with >= 1 "
+            "prefill completion, however many admissions share it)")
+        self._c_boundary_tokens = r.counter(
+            "serving_boundary_tokens",
+            "boundary tokens sampled on the device from a prefill "
+            "program's row (÷ serving_boundary_syncs: admissions a "
+            "fetch; "
+            "÷ serving_admitted_requests: 1.0 less what failed or was "
+            "preempted before its flush)")
         # speculative-decoding metric family (all zero when off)
         self._c_spec_drafted = r.counter(
             "spec_drafted_tokens",
@@ -685,6 +788,10 @@ class ServingEngine:
                 "chunk_prefill", self._chunk_prefill)
             self._decode_chunk_fn = self.devprof.wrap(
                 "decode_chunk", self._decode_chunk_fn)
+            self._verify_chunk = self.devprof.wrap(
+                "spec_verify", self._verify_chunk)
+            self._boundary = self.devprof.wrap("boundary",
+                                               self._boundary)
             if dcfg.cost_analysis:
                 self._devprof_cost_analyze()
             self._devprof_warmup()
@@ -1039,42 +1146,19 @@ class ServingEngine:
         """Install ``self._prefill`` / ``self._chunk_prefill`` /
         ``self._decode_chunk_fn`` — any callables honoring the jitted
         contracts; the base engine compiles whole-model programs."""
-        # stable program names: a capture's "XLA Modules" line says
-        # which program ran (jit_dstpu_prefill / _chunk / _decode)
-        def dstpu_prefill(params, tokens, cache):
-            return prefill_fn(params, tokens, cache)
-
-        def dstpu_chunk(params, tokens, cache):
-            return chunk_prefill_fn(params, tokens, cache)
-
+        (dstpu_prefill, dstpu_chunk, dstpu_boundary, dstpu_sweep,
+         dstpu_decode) = serving_programs(
+            prefill_fn, decode_fn, chunk_prefill_fn, self._sample_fn,
+            self.decode_chunk, self.max_batch)
         self._prefill = jax.jit(dstpu_prefill, donate_argnums=(2,))
         self._chunk_prefill = (jax.jit(dstpu_chunk, donate_argnums=(2,))
                                if chunk_prefill_fn is not None else None)
-
-        # K decode steps in ONE on-device scan: each step's sampled token
-        # feeds the next, so the host syncs once per K tokens (what a
-        # sync costs on the chip is not measured yet — ROADMAP S2).
-        # Tokens a request emits after its own
-        # EOS within a chunk are discarded by the host (waste < K).
-        # K=1 runs the same path as a length-1 scan.
-        # The sampler is the policy-resolved one (fused pallas argmax
-        # when kernels.fused_sampling resolved "on", the jitted XLA
-        # twin otherwise) — both emit bit-identical greedy tokens and
-        # share the categorical math, so flipping the policy can never
-        # change a served greedy stream.
-        sample = self._sample_fn
-
-        def dstpu_decode(params, tok, cache, keys, temps):
-            def one(carry, key_k):
-                t, c = carry
-                logits, c = decode_fn(params, t, c)
-                with jax.named_scope("sample"):
-                    nxt = sample(logits[:, -1], key_k, temps)
-                return (nxt[:, None], c), nxt
-
-            (_, cache), toks = jax.lax.scan(one, (tok, cache), keys)
-            return jnp.swapaxes(toks, 0, 1), cache          # [B, K]
-
+        self._boundary = jax.jit(dstpu_boundary)
+        # the speculative verify sweep scores K+1 positions and needs
+        # them all: the one caller that reads the continuation
+        # forward's logits, so the one engine that builds this program
+        self._verify_chunk = (jax.jit(dstpu_sweep, donate_argnums=(2,))
+                              if self._spec_on else None)
         self._decode_chunk_fn = jax.jit(dstpu_decode,
                                         donate_argnums=(2,))
 
@@ -1097,14 +1181,11 @@ class ServingEngine:
         try:
             params_a = tm(absx, self.params)
             cache_a = tm(absx, self.cache)
-            K = self.decode_chunk
-            keys = jax.random.split(
-                jax.random.PRNGKey(0), K * self.max_batch).reshape(
-                    K, self.max_batch, -1)
+            key_a = absx(self._key)
             dp.cost_analyze(
                 "decode_chunk", self._decode_chunk_fn, params_a,
                 jax.ShapeDtypeStruct((self.max_batch, 1), jnp.int32),
-                cache_a, absx(keys),
+                cache_a, key_a, jax.ShapeDtypeStruct((), jnp.int32),
                 jax.ShapeDtypeStruct((self.max_batch,), jnp.float32))
             # whole-prompt prefill at the base bucket (the view a
             # bucket-padded admission hands the program)
@@ -1114,14 +1195,14 @@ class ServingEngine:
             dp.cost_analyze(
                 "prefill", self._prefill, params_a,
                 jax.ShapeDtypeStruct((1, self.prefill_bucket),
-                                     jnp.int32), view_a)
-            if self._spec_on and self._chunk_prefill is not None:
-                # under speculation the continuation forward IS the
-                # steady-state decode program — cost it at the verify
-                # sweep's shape
+                                     jnp.int32), view_a,
+                jax.ShapeDtypeStruct((1,), jnp.int32))
+            if self._spec_on:
+                # under speculation the verify sweep IS the
+                # steady-state decode program — cost it at its shape
                 Kd = self.speculative.draft_tokens
                 dp.cost_analyze(
-                    "chunk_prefill", self._chunk_prefill, params_a,
+                    "spec_verify", self._verify_chunk, params_a,
                     jax.ShapeDtypeStruct((self.max_batch, Kd + 1),
                                          jnp.int32), cache_a)
         except Exception:
@@ -1144,9 +1225,14 @@ class ServingEngine:
         warmup compiles.  Side benefit: the first real request pays
         zero compilation (production TPU serving does exactly this —
         precompile the bucket set at startup)."""
-        zi = jnp.zeros
+        # operands go up as the steady state's do, from NumPy: an eager
+        # jnp.zeros is a tiny program a shape, compiled on every build
+        # (too small for the persistent cache to keep)
+        zi = np.zeros
         n0 = time.perf_counter()
         row = self.max_pages_per_seq * self.page_size
+        last = self._put(zi((1,), np.int32))
+        logits_row = None
         if self.prefill_bucket:
             # cold full prefill pads the prompt to prefill_bucket
             # MULTIPLES clamped at the table row — enumerate them all
@@ -1157,11 +1243,11 @@ class ServingEngine:
                 view = PagedKVCache(
                     k=self.cache.k, v=self.cache.v,
                     table=self._put(self._table_host[0:1]),
-                    seq_lens=self._put(zi((1,), jnp.int32)),
+                    seq_lens=self._put(zi((1,), np.int32)),
                     page_size=self.page_size)
-                _, view = self._prefill(
-                    self.params, self._put(zi((1, end), jnp.int32)),
-                    view)
+                logits_row, view = self._prefill(
+                    self.params, self._put(zi((1, end), np.int32)),
+                    view, last)
                 self.cache = self.cache._replace(k=view.k, v=view.v)
         if self._chunk_prefill is not None:
             # the continuation forward's page-table width is bucketed
@@ -1177,11 +1263,11 @@ class ServingEngine:
                 view = PagedKVCache(
                     k=self.cache.k, v=self.cache.v,
                     table=self._put(self._table_host[0:1, :w]),
-                    seq_lens=self._put(zi((1,), jnp.int32)),
+                    seq_lens=self._put(zi((1,), np.int32)),
                     page_size=self.page_size)
-                _, view = self._chunk_prefill(
-                    self.params, self._put(zi((1, C), jnp.int32)),
-                    view)
+                logits_row, view = self._chunk_prefill(
+                    self.params, self._put(zi((1, C), np.int32)),
+                    view, last)
                 self.cache = self.cache._replace(k=view.k, v=view.v)
         # whole-cache dispatches (spec verify, decode) see the
         # page_size leaf as the weak-i32 scalar a previous jit RETURN
@@ -1191,22 +1277,23 @@ class ServingEngine:
         # would still compile (and read as a steady "recompile")
         self.cache = self.cache._replace(
             page_size=jnp.asarray(self.page_size))
-        if self._spec_on and self._chunk_prefill is not None:
+        if self._spec_on:
             # the verify sweep's whole-cache continuation shape
             Kd = self.speculative.draft_tokens
-            _, self.cache = self._chunk_prefill(
+            _, self.cache = self._verify_chunk(
                 self.params,
-                self._put(zi((self.max_batch, Kd + 1), jnp.int32)),
+                self._put(zi((self.max_batch, Kd + 1), np.int32)),
                 self.cache)
-        K = self.decode_chunk
-        keys = jax.random.split(
-            jax.random.PRNGKey(0), K * self.max_batch).reshape(
-                K, self.max_batch, -1)
+        ordinal = self._put(zi((), np.int32))
+        if logits_row is not None:
+            # the boundary sampler, over a row a prefill above returned
+            self._boundary(logits_row, self._key, ordinal,
+                           self._put(zi((1,), np.float32)))
         out, self.cache = self._decode_chunk_fn(
             self.params,
-            self._put(zi((self.max_batch, 1), jnp.int32)),
-            self.cache, self._put(keys),
-            self._put(zi((self.max_batch,), jnp.float32)))
+            self._put(zi((self.max_batch, 1), np.int32)),
+            self.cache, self._key, ordinal,
+            self._put(zi((self.max_batch,), np.float32)))
         del out
         logger.info("devprof warmup: %d programs precompiled in %.1fs",
                     self.devprof.ledger.warmup,
@@ -1365,10 +1452,7 @@ class ServingEngine:
         self._table_host[b, :] = self.trash_page
         self._table_dirty = self._lens_dirty = True
         self.slots[b] = None
-        # a queued boundary sample for this slot would append a token
-        # to a dead request (or index a vacated slot) at the flush
-        self._pending_boundary = [p for p in self._pending_boundary
-                                  if p[0] != b]
+        self._drop_boundary(b)
         self._record_failure(req, "slot_exception", exc, b=b,
                              generated=len(s.generated))
 
@@ -1858,7 +1942,6 @@ class ServingEngine:
                     "tier_pages": len(tier_keys),
                     "queue_skips": queue_skips})
 
-            self._rng, rng = jax.random.split(self._rng)
             if tier_keys:
                 promo = self._begin_promotion(b, tier_keys, page_map)
             if self.prefill_chunk or cached:
@@ -1869,8 +1952,7 @@ class ServingEngine:
                 # under prefill_chunk=0 absorbs prefill_bucket tokens
                 # per iteration.)
                 self.slots[b] = _Slot(req=req, seq_len=cached,
-                                      generated=[], rng=rng,
-                                      seq_id=seq_id,
+                                      generated=[], seq_id=seq_id,
                                       prefill_done=cached, promo=promo)
                 self._note_admitted(req)
                 return True
@@ -1884,19 +1966,19 @@ class ServingEngine:
             view = PagedKVCache(
                 k=self.cache.k, v=self.cache.v,
                 table=self._put(self._table_host[b:b + 1]),
-                seq_lens=self._put(jnp.zeros((1,), jnp.int32)),
+                seq_lens=self._put(np.zeros((1,), np.int32)),
                 page_size=self.page_size)
-            logits, view = self._prefill(self.params, self._put(toks),
-                                         view)
+            row, view = self._prefill(
+                self.params, self._put(toks), view,
+                self._put(np.full((1,), T - 1, np.int32)))
             if self._devprof_on and self.devprof.should_sample(
                     "prefill"):
                 # dstpu: host-sync-ok: sampled devprof device-time
                 # attribution (one sync per 1/sample_rate prefills)
-                self.devprof.observe_device("prefill", logits)
+                self.devprof.observe_device("prefill", row)
             self.cache = self.cache._replace(k=view.k, v=view.v)
 
-            slot = _Slot(req=req, seq_len=T, generated=[], rng=rng,
-                         seq_id=seq_id)
+            slot = _Slot(req=req, seq_len=T, generated=[], seq_id=seq_id)
             self.slots[b] = slot
             self._note_admitted(req)
             # the prompt's full pages are immutable from here on
@@ -1904,9 +1986,9 @@ class ServingEngine:
             # matchable now so concurrent same-prefix requests hit
             self._publish_full_pages(b, slot, upto=T)
             # first generated token comes from the REAL last prompt
-            # position; sampling is deferred into the step's one
-            # batched boundary flush
-            self._queue_boundary(b, logits[0, T - 1], slot)
+            # position's row; it is sampled on the device and fetched
+            # in the step's one boundary flush
+            self._queue_boundary(b, row, slot)
             return True
         except BaseException:
             # an exception between page allocation and slot publish
@@ -1939,8 +2021,7 @@ class ServingEngine:
             self._table_host[b, :] = self.trash_page
             self._table_dirty = self._lens_dirty = True
             self.slots[b] = None
-            self._pending_boundary = [p for p in self._pending_boundary
-                                      if p[0] != b]
+            self._drop_boundary(b)
             raise
 
     def _note_admitted(self, req: Request) -> None:
@@ -2474,14 +2555,15 @@ class ServingEngine:
         view = PagedKVCache(
             k=self.cache.k, v=self.cache.v,
             table=self._put(self._table_host[b:b + 1, :np_bkt]),
-            seq_lens=self._put(jnp.full((1,), done, jnp.int32)),
+            seq_lens=self._put(np.full((1,), done, np.int32)),
             page_size=self.page_size)
-        logits, view = self._chunk_prefill(self.params, self._put(toks),
-                                           view)
+        row, view = self._chunk_prefill(
+            self.params, self._put(toks), view,
+            self._put(np.full((1,), take - 1, np.int32)))
         if self._devprof_on and self.devprof.should_sample("prefill"):
             # dstpu: host-sync-ok: sampled devprof device-time
             # attribution (one sync per 1/sample_rate prefill chunks)
-            self.devprof.observe_device("prefill", logits)
+            self.devprof.observe_device("prefill", row)
         self.cache = self.cache._replace(k=view.k, v=view.v)
         s.prefill_done = done + take
         s.seq_len = s.prefill_done
@@ -2497,7 +2579,7 @@ class ServingEngine:
             # prompt pages are full and immutable now — make them
             # matchable before the first token can finish the request
             self._publish_full_pages(b, s, upto=T)
-            self._queue_boundary(b, logits[0, take - 1], s)
+            self._queue_boundary(b, row, s)
 
     def _preempt_youngest(self) -> None:
         """vLLM-style recompute preemption: release the youngest slot's
@@ -2524,6 +2606,7 @@ class ServingEngine:
         self._table_host[b, :] = self.trash_page
         self._table_dirty = self._lens_dirty = True
         self.slots[b] = None
+        self._drop_boundary(b)
         req = s.req
         if req.traced:
             self.tracer.event("preempt", req.req_id, b, attrs={
@@ -2544,47 +2627,48 @@ class ServingEngine:
         if req.traced:
             self.tracer.event("requeue", req.req_id)
 
-    def _queue_boundary(self, b: int, logits_row, slot: _Slot) -> None:
-        """Defer sampling a prefill-boundary token: hold the slot's
-        last-position logits ROW on device and flush every pending row
-        through one batched :func:`_sample_rows` per step — the old
-        path ran ``sample_logits`` + ``int()`` per slot, one device
-        round-trip per admission."""
-        slot.rng, key = jax.random.split(slot.rng)
-        self._pending_boundary.append(
-            (b, logits_row, key, slot.req.temperature))
+    def _drop_boundary(self, b: int) -> None:
+        """Slot ``b`` was vacated before the flush: its queued boundary
+        token would be appended to a dead request (or index the vacated
+        slot)."""
+        self._pending_boundary = [p for p in self._pending_boundary
+                                  if p[0] != b]
+
+    def _queue_boundary(self, b: int, row, slot: _Slot) -> None:
+        """Sample slot ``b``'s first token from the last-position logits
+        ``row`` its prefill returned — on the device, under the key its
+        admission ordinal (``seq_id``) folds to — and hold the ``[1]``
+        token array for the step's one boundary fetch."""
+        tok = self._boundary(
+            row, self._key,
+            self._put(np.full((), (2 * slot.seq_id + 1) & 0x7FFFFFFF,
+                              np.int32)),
+            self._put(np.full((1,), slot.req.temperature, np.float32)))
+        self._pending_boundary.append((b, tok))
+        self._c_kdisp_sample.inc()
 
     # dstpu: hot-path
     def _flush_boundary(self) -> None:
         if not self._pending_boundary:
             return
         pend, self._pending_boundary = self._pending_boundary, []
-        # pad to max_batch: the pending count varies per step (1 slot
-        # finishing prefill … all of them under a cache-hit burst) and
-        # _sample_rows would compile once per distinct size — pay one
-        # fixed shape instead, row count is bounded by max_batch anyway
-        pad = self.max_batch - len(pend)
-        rows = [p[1] for p in pend] + [pend[0][1]] * pad
-        keys = [p[2] for p in pend] + [pend[0][2]] * pad
-        temps = np.zeros((self.max_batch,), np.float32)
-        temps[:len(pend)] = [p[3] for p in pend]
         want_dev = (self._devprof_on
                     and self.devprof.should_sample("sample"))
         t0_dev = time.perf_counter() if want_dev else 0.0
-        # dstpu: host-sync-ok: boundary sample fetch, one batched
-        # transfer per step for every prefill completion (replaced
-        # PR 7's per-slot device round-trip)
-        toks = np.asarray(self._sample_fn(
-            jnp.stack(rows), jnp.stack(keys), self._put(temps)))
+        # dstpu: host-sync-ok: boundary token fetch, one transfer per
+        # step for every prefill completion (each token was sampled on
+        # the device when its prefill was dispatched; nothing is
+        # dispatched here)
+        toks = jax.device_get([tok for _, tok in pend])
         if want_dev:
-            # the np.asarray above already synced — self-timed, no
+            # the device_get above already synced — self-timed, no
             # extra block_until_ready needed
             self.devprof.record_device(
                 "sample", time.perf_counter() - t0_dev)
         self._c_boundary_syncs.inc()
-        self._c_kdisp_sample.inc()
-        for (b, _, _, _), tok in zip(pend, toks):
-            self._append_token(b, int(tok))
+        self._c_boundary_tokens.inc(len(pend))
+        for (b, _), tok in zip(pend, toks):
+            self._append_token(b, int(tok[0]))
 
     # dstpu: hot-path
     def _append_token(self, b: int, tok: int) -> None:
@@ -2794,14 +2878,13 @@ class ServingEngine:
                     toks[b, 0] = s.generated[-1] if s.generated \
                         else s.req.tokens[-1]
                     temps[b] = s.req.temperature
-                self._rng, r = jax.random.split(self._rng)
-                keys = jax.random.split(r, K * self.max_batch).reshape(
-                    K, self.max_batch, -1)
-                toks_d, keys_d, temps_d = (
-                    self._put(toks), self._put(keys), self._put(temps))
+                toks_d, ordinal_d, temps_d = (
+                    self._put(toks), self._next_dispatch(),
+                    self._put(temps))
             with self._sp_dispatch:
                 out, self.cache = self._decode_chunk_fn(
-                    self.params, toks_d, self.cache, keys_d, temps_d)
+                    self.params, toks_d, self.cache, self._key,
+                    ordinal_d, temps_d)
                 # trust the decode's structural seq_lens+K between
                 # composition changes (inactive rows drift, rebuilt on
                 # the next dirty upload)
@@ -2834,6 +2917,14 @@ class ServingEngine:
                         self._append_token(b, int(host_toks[b, j]))
                         if self.slots[b] is None:   # finished mid-chunk:
                             break                   # rest is discard
+
+    def _next_dispatch(self):
+        """What this decode or verify dispatch's program folds into the
+        base key for its draws, uploaded: twice the dispatch's ordinal
+        (the odd numbers are the admissions')."""
+        n = self._n_dispatch
+        self._n_dispatch = (n + 2) & 0x7FFFFFFF
+        return self._put(np.full((), n, np.int32))
 
     def _set_step_gauges(self, n_active: int) -> None:
         self._g_queue.set(len(self.queue))
@@ -2922,14 +3013,12 @@ class ServingEngine:
         with self._sp_upload:
             self._upload_dirty()
         with self._sp_dispatch:
-            self._rng, r = jax.random.split(self._rng)
-            keys = jax.random.split(r, (K + 1) * Bm).reshape(
-                Bm, K + 1, -1)
-            logits, self.cache = self._chunk_prefill(
+            logits, self.cache = self._verify_chunk(
                 self.params, self._put(toks), self.cache)
             n_acc_d, stop_d = verify_accept(
                 logits, self._put(drafts), self._put(dlens),
-                self._put(keys), self._put(temps))
+                self._key, self._next_dispatch(),
+                self._put(temps))
         with self._sp_token_sync:
             if self._devprof_on and self.devprof.should_sample(
                     "spec_verify"):

@@ -201,13 +201,15 @@ def build_drafter(cfg: SpeculativeConfig) -> Drafter:
 # device time to the "spec_verify" phase at the call site (serving's
 # _spec_step samples the dispatch result) rather than sentinel-wrapping
 # here, so one engine's sampling never charges another's sweep.
-def dstpu_verify(logits, drafts, draft_lens, keys, temps):
+def dstpu_verify(logits, drafts, draft_lens, key, ordinal, temps):
     """Batched acceptance for one verify sweep — ONE host transfer.
 
     logits: [B, K+1, V] target logits at the K+1 scored positions
     (position 0 = the re-fed last token, positions 1..K = the drafts);
     drafts: [B, K] i32 proposed tokens; draft_lens: [B] i32 how many
-    are real per row; keys: [B, K+1, 2] PRNG keys; temps: [B] f32.
+    are real per row; key: the engine's base PRNG key and ordinal: i32
+    scalar, the sweep's dispatch ordinal — folded together and split
+    here, on the device, into one key a (row, position); temps: [B] f32.
 
     Returns ``(n_acc [B] i32, stop_tok [B, K+1] i32)``: ``n_acc`` is
     the longest accepted draft prefix, and ``stop_tok[:, j]`` is the
@@ -230,7 +232,7 @@ def dstpu_verify(logits, drafts, draft_lens, keys, temps):
     scaled = lg / jnp.maximum(temps, 1e-6)[:, None, None]
     probs = jax.nn.softmax(scaled, axis=-1)                  # [B, K+1, V]
 
-    flat = keys.reshape(B * K1, 2)
+    flat = jax.random.split(jax.random.fold_in(key, ordinal), B * K1)
     ku = jax.vmap(lambda kk: jax.random.fold_in(kk, 0))(flat)
     ks = jax.vmap(lambda kk: jax.random.fold_in(kk, 1))(flat)
     u = jax.vmap(jax.random.uniform)(ku).reshape(B, K1)[:, :K]

@@ -76,7 +76,9 @@ from deepspeed_tpu.config import KVTierConfig, ZeroInferenceConfig
 from deepspeed_tpu.infinity import _NvmeTier, _RamTier
 from deepspeed_tpu.inference.kernels import PagedKVCache
 from deepspeed_tpu.inference.paged_forward import paged_layered_fns
-from deepspeed_tpu.inference.serving import _WIRE_MIN_ELEMS, ServingEngine
+from deepspeed_tpu.inference.serving import (_WIRE_MIN_ELEMS, ServingEngine,
+                                             _dispatch_keys, _last_row,
+                                             boundary_program)
 from deepspeed_tpu.models.family import decoder_families
 from deepspeed_tpu.param_stream import TierLayerReader
 from deepspeed_tpu.utils.logging import logger
@@ -435,8 +437,24 @@ class ZeroInferenceServingEngine(ServingEngine):
         self._stem_jit = jax.jit(self._stem_fn)
         self._head_jit = jax.jit(self._head_fn)
         self._bjits: Dict[Any, Any] = {}
+        # the base engine's program contract over host-driven sweeps:
+        # a prefill returns its last row, a decode chunk derives its
+        # keys from the dispatch ordinal — each the same small function
+        # the whole-model programs inline, jitted on its own behind the
+        # streamed head
+        sample = self._sample_fn
+        K, B = self.decode_chunk, self.max_batch
+
+        def dstpu_sample(logits, key, ordinal, j, temps):
+            keys = _dispatch_keys(key, ordinal, K, B)
+            return sample(logits[:, -1], keys[j], temps)
+
+        self._row_jit = jax.jit(_last_row)
+        self._sample_jit = jax.jit(dstpu_sample)
+        self._boundary = jax.jit(boundary_program(sample))
         self._prefill = self._streamed_prefill
         self._chunk_prefill = self._streamed_chunk_prefill
+        self._verify_chunk = self._streamed_verify_chunk
         self._decode_chunk_fn = self._streamed_decode_chunk
 
     def _devprof_cost_analyze(self) -> None:
@@ -525,22 +543,28 @@ class ZeroInferenceServingEngine(ServingEngine):
         logits = self._head_jit(self._head_dev, x)
         return logits, view._replace(k=tuple(k_list), v=tuple(v_list))
 
-    def _streamed_prefill(self, _params, toks, view):
+    def _streamed_prefill(self, _params, toks, view, last):
         # a bucket-1 single-token "prefill" takes the decode path, like
         # forward_paged's prelude (prefill = T > 1) — same kernels, same
         # tokens as the resident engine
         phase = "prefill" if toks.shape[1] > 1 else "decode"
-        return self._forward_view(phase, toks, view)
+        logits, view = self._forward_view(phase, toks, view)
+        return self._row_jit(logits, last), view
 
-    def _streamed_chunk_prefill(self, _params, toks, view):
-        # doubles as the speculative VERIFY executor: the scheduler
-        # hands it [B, K+1] draft windows over the full cache, so one
+    def _streamed_chunk_prefill(self, _params, toks, view, last):
+        logits, view = self._forward_view("chunk", toks, view)
+        return self._row_jit(logits, last), view
+
+    def _streamed_verify_chunk(self, _params, toks, cache):
+        # the speculative VERIFY executor: the scheduler hands it
+        # [B, K+1] draft windows over the full cache, so one
         # layer-stack sweep (= one full weight stream for the streamed
         # suffix) scores every position of every active slot
-        return self._forward_view("chunk", toks, view)
+        return self._forward_view("chunk", toks, cache)
 
     # dstpu: hot-path
-    def _streamed_decode_chunk(self, _params, toks, cache, keys, temps):
+    def _streamed_decode_chunk(self, _params, toks, cache, key, ordinal,
+                               temps):
         """K decode steps, host-driven: each step sweeps the layer
         stack (streamed weights double-buffered ahead), samples on
         device, and feeds the token to the next step — tokens never
@@ -560,7 +584,7 @@ class ZeroInferenceServingEngine(ServingEngine):
             # the policy-resolved sampler (base ctor): the fused pallas
             # argmax when kernels.fused_sampling resolved "on", the
             # jitted XLA twin otherwise — bit-identical greedy tokens
-            nxt = self._sample_fn(logits[:, -1], keys[j], temps)
+            nxt = self._sample_jit(logits, key, ordinal, j, temps)
             cols.append(nxt)
             tok = nxt[:, None]
         cache = cache._replace(k=tuple(k_list), v=tuple(v_list),

@@ -362,7 +362,6 @@ class TestEngineContract:
 
     def test_forced_recompile_counted_once_and_trips_incident(
             self, gpt2_tiny, tmp_path):
-        import jax
         import jax.numpy as jnp
 
         params, cfg, prompts = gpt2_tiny
@@ -377,16 +376,13 @@ class TestEngineContract:
             eng.run()
             assert eng.devprof.steady
             assert eng.devprof.ledger.steady == 0
-            # the shape poke: an off-contract decode dispatch (K+1
-            # keys) the warmup set never compiled — this is exactly
-            # the drift the sentinel exists to catch
-            K = eng.decode_chunk
-            keys = jax.random.split(jax.random.PRNGKey(7),
-                                    (K + 1) * eng.max_batch)
-            keys = keys.reshape(K + 1, eng.max_batch, -1)
+            # the type poke: an off-contract decode dispatch (a
+            # uint32 ordinal, not int32) the warmup set never
+            # compiled — this is exactly the drift the sentinel exists
+            # to catch
             out, eng.cache = eng._decode_chunk_fn(
                 eng.params, jnp.zeros((eng.max_batch, 1), jnp.int32),
-                eng.cache, keys,
+                eng.cache, eng._key, jnp.zeros((), jnp.uint32),
                 jnp.zeros((eng.max_batch,), jnp.float32))
             del out
             assert eng.devprof.ledger.steady == 1   # exactly once

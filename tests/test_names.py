@@ -172,16 +172,23 @@ def _paged_programs(name):
     view_a = tm(absx, eng.cache._replace(
         table=jnp.zeros((1, eng.max_pages_per_seq), jnp.int32),
         seq_lens=jnp.zeros((1,), jnp.int32)))
-    B, K = eng.max_batch, eng.decode_chunk
-    keys = jax.random.split(jax.random.PRNGKey(0), K * B).reshape(K, B, -1)
+    B = eng.max_batch
+    last = jax.ShapeDtypeStruct((1,), jnp.int32)    # the last real position
     lowered = {
         "prefill": eng._prefill.lower(
-            params_a, jax.ShapeDtypeStruct((1, 8), jnp.int32), view_a),
+            params_a, jax.ShapeDtypeStruct((1, 8), jnp.int32), view_a,
+            last),
         "chunk": eng._chunk_prefill.lower(
-            params_a, jax.ShapeDtypeStruct((B, 8), jnp.int32), cache_a),
+            params_a, jax.ShapeDtypeStruct((1, 8), jnp.int32), view_a,
+            last),
+        "boundary": eng._boundary.lower(
+            jax.ShapeDtypeStruct((1, cfg.vocab_size), jnp.float32),
+            absx(eng._key), jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.float32)),
         "decode": eng._decode_chunk_fn.lower(
             params_a, jax.ShapeDtypeStruct((B, 1), jnp.int32), cache_a,
-            absx(keys), jax.ShapeDtypeStruct((B,), jnp.float32)),
+            absx(eng._key), jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.float32)),
     }
     assert isinstance(eng.cache, PagedKVCache)
     return {k: v.as_text(debug_info=True) for k, v in lowered.items()}
@@ -204,11 +211,14 @@ def _words(text):
     return found
 
 
-@pytest.mark.parametrize("program", ["prefill", "chunk", "decode"])
+@pytest.mark.parametrize("program", ["prefill", "chunk", "decode",
+                                     "boundary"])
 @pytest.mark.parametrize("name", ["gpt2", "mixtral"])
 def test_paged_programs_carry_every_scope_that_applies(name, program):
     text = _paged_programs(name)[program]
-    want = BLOCKS[name] | PHASE[program]
+    # the boundary sampler runs no model: its one scope is ``sample``
+    want = ({"sample"} if program == "boundary"
+            else BLOCKS[name] | PHASE[program])
     assert want <= _words(text), want - _words(text)
     assert f"dstpu_{program}" in text          # the program's own name
 

@@ -171,9 +171,10 @@ class TestSpeculativeConfig:
 
 
 # ----------------------------------------------------------- verify math
-def _keys(n, k1, seed=0):
-    return jax.random.split(jax.random.PRNGKey(seed),
-                            n * k1).reshape(n, k1, 2)
+def _keys(seed=0):
+    """The key operands of ``verify_accept``: a base key and a dispatch
+    ordinal, split on the device into one key a (row, position)."""
+    return jax.random.PRNGKey(seed), jnp.int32(0)
 
 
 class TestVerifyAccept:
@@ -187,7 +188,7 @@ class TestVerifyAccept:
         drafts = np.array([[1, 2, 3]], np.int32)
         n_acc, stop = verify_accept(
             jnp.asarray(lg), jnp.asarray(drafts),
-            jnp.asarray([3], jnp.int32), _keys(1, K + 1),
+            jnp.asarray([3], jnp.int32), *_keys(),
             jnp.zeros((1,), jnp.float32))
         assert int(n_acc[0]) == 3
         assert int(stop[0, 3]) == 4
@@ -201,7 +202,7 @@ class TestVerifyAccept:
         drafts = np.array([[1, 9, 3]], np.int32)
         n_acc, stop = verify_accept(
             jnp.asarray(lg), jnp.asarray(drafts),
-            jnp.asarray([3], jnp.int32), _keys(1, K + 1),
+            jnp.asarray([3], jnp.int32), *_keys(),
             jnp.zeros((1,), jnp.float32))
         assert int(n_acc[0]) == 1
         assert int(stop[0, 1]) == 2
@@ -212,7 +213,7 @@ class TestVerifyAccept:
         lg[:, 0, 4] = 5.0
         n_acc, stop = verify_accept(
             jnp.asarray(lg), jnp.zeros((2, K), jnp.int32),
-            jnp.zeros((2,), jnp.int32), _keys(2, K + 1),
+            jnp.zeros((2,), jnp.int32), *_keys(),
             jnp.zeros((2,), jnp.float32))
         assert np.all(np.asarray(n_acc) == 0)
         assert np.all(np.asarray(stop)[:, 0] == 4)
@@ -231,7 +232,7 @@ class TestVerifyAccept:
         drafts = np.full((N, 1), d, np.int32)
         n_acc, stop = verify_accept(
             jnp.asarray(lg), jnp.asarray(drafts),
-            jnp.ones((N,), jnp.int32), _keys(N, 2, seed=7),
+            jnp.ones((N,), jnp.int32), *_keys(seed=7),
             jnp.full((N,), temp, jnp.float32))
         n_acc, stop = np.asarray(n_acc), np.asarray(stop)
         emitted = np.where(n_acc == 1, d, stop[:, 0])
@@ -252,7 +253,7 @@ class TestVerifyAccept:
         lg = np.broadcast_to(logits, (N, 2, V)).copy()
         n_acc, stop = verify_accept(
             jnp.asarray(lg), np.zeros((N, 1), np.int32),
-            jnp.zeros((N,), jnp.int32), _keys(N, 2, seed=3),
+            jnp.zeros((N,), jnp.int32), *_keys(seed=3),
             jnp.ones((N,), jnp.float32))
         freq = np.bincount(np.asarray(stop)[:, 0], minlength=V) / N
         assert np.all(np.abs(freq - np.asarray(p)) < 0.05), freq
