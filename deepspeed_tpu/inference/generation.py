@@ -179,6 +179,7 @@ def cached_step_alloc(cfg, cache_dtype=jnp.bfloat16):
     CLAMPS out-of-range indices, so generating past the table would
     silently reuse the last position's embedding)."""
     fam = decoder_family(cfg)
+    fam.refuse(contiguous_cache=True)
 
     def alloc(batch, max_seq):
         fam.check(cfg, None, max_seq)

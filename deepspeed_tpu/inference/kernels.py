@@ -35,10 +35,19 @@ NEG_INF = -1e30
 
 # ------------------------------------------------------------- page store
 class PagedKVCache(NamedTuple):
-    """Paged KV store for one layer stack.
+    """Paged KV store for one layer stack.  Two kinds of payload:
 
-    k/v: [L, KV, num_pages, page_size, Dh]; table: [B, max_pages] int32
-    page ids; seq_lens: [B] int32 valid token counts.
+    * per-head K and V: ``k``/``v`` [L, KV, num_pages, page_size, Dh]
+      (or int8 codes with ``k_scale``/``v_scale``);
+    * one latent row a token: ``k`` [L, 1, num_pages, page_size, C + Dr]
+      holds ``[c | k_rope]``, which attention reads as keys and whose
+      first C numbers it reads as values, so ``v`` is None (a family
+      whose ``cache_row`` says ``values_in_keys``).
+
+    table: [B, max_pages] int32 page ids; seq_lens: [B] int32 valid
+    token counts.  ``expert_rows`` ([Eh] int32, or None): rows routed to
+    each held expert that the programs have added up since the last
+    decode program handed the sum out (a family with ``expert_rows``).
     """
 
     k: jnp.ndarray
@@ -51,6 +60,7 @@ class PagedKVCache(NamedTuple):
     # [L, KV, num_pages, page_size, 1]; None on the plain path.
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
+    expert_rows: Optional[jnp.ndarray] = None
 
     @classmethod
     def alloc(cls, n_layers: int, n_kv: int, num_pages: int, page_size: int,
@@ -1238,6 +1248,276 @@ def paged_chunk_attention(q, k_pages, v_pages, table, start,
     return out.reshape(B, C, H, Dh)
 
 
+# ------------------------------------------------- latent (one-row) pages
+# A latent family's pool is [L, 1, P, ps, Wp]: a token's row is W = C + Dr
+# numbers, the normed compressed KV ``c`` and the rotated key part every
+# head shares, stored in Wp = W rounded up to whole 128-lane tiles
+# (``models.family.CacheRow.pool_width``; the tail is zeros).  The TPU lays a 576-wide
+# row out in 640 lanes whatever its declared width; declared as 576 the
+# compiler keeps a second, re-laid copy of the pool in a chunk program and
+# Mosaic refuses the page copy ("Slice shape along dimension 4 must be
+# aligned to tiling (128), but is 576"; AOT for a described v5e, PR 33).
+# The writers are the per-head ones (one "kv head"); the readers attend in
+# the absorbed form, q~ = [q_nope W_UK^T | q_rope] against the rows, with
+# the rows' first C numbers as values.
+def _pad_rows(rows, width: int):
+    """``rows`` [..., W] -> [..., width], zeros behind."""
+    pad = width - rows.shape[-1]
+    return rows if not pad else jnp.pad(
+        rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
+
+
+def latent_decode_reference(q_abs, pages, table, seq_lens, scale: float,
+                            value_width: int, *, layer=None):
+    """q_abs: [B, H, W]; pages: the latent pool and ``layer`` (or one
+    layer's [1, P, ps, W]) -> [B, H, value_width].  The XLA formulation:
+    gathers every row ``table`` names."""
+    rows = _gather_rows(pages, layer, table)[:, 0, :, :q_abs.shape[-1]] \
+        .astype(jnp.float32)
+    s = jnp.einsum("bhw,bsw->bhs", q_abs.astype(jnp.float32), rows) * scale
+    valid = jnp.arange(rows.shape[1])[None] < seq_lens[:, None]
+    s = jnp.where(valid[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhs,bsc->bhc", p, rows[..., :value_width])
+    out = jnp.where(seq_lens[:, None, None] > 0, out, 0.0)
+    return out.astype(q_abs.dtype)
+
+
+# tokens a block of the latent decode kernel holds in VMEM, a buffer slot
+_MLA_BLOCK_TOKENS = 1024
+
+
+def _mla_decode_kernel(table_ref, lens_ref, layer_ref, qc_ref, qr_ref,
+                       pool_hbm, o_ref, buf, sem, *, scale, ps, max_pages,
+                       ppb, width_c, width):
+    """One grid step a batch row, as :func:`_decode_kernel`: the row's
+    live pages stream ``ppb`` at a time through a double-buffered VMEM
+    scratch, each page ONE copy of [ps, W] that serves as keys and as
+    values.  The row's H heads are the M dimension of both products
+    (q~ @ rows^T, p @ c), so the MXU does the work; operands stay in the
+    pool's dtype, scores, softmax and the accumulator are f32."""
+    b = pl.program_id(0)
+    n = lens_ref[b]
+    layer = layer_ref[0]
+    pages_live = (n + ps - 1) // ps
+    nblk = (pages_live + ppb - 1) // ppb
+
+    def page(c, slot, j):
+        p = c * ppb + j
+        pid = table_ref[b, jnp.minimum(p, max_pages - 1)]
+        return p < pages_live, pltpu.make_async_copy(
+            pool_hbm.at[layer, 0, pid], buf.at[slot, j], sem.at[slot])
+
+    def each_page(c, slot, full, live_do, dead_do=None):
+        """A block's ``ppb`` pages: all of them straight-line where the
+        block is ``full`` of live pages (every block but a row's last:
+        starting and awaiting a copy is scalar work, and 64 guarded loop
+        trips a block cost more than the block's products), guarded
+        page by page where it is not."""
+        @pl.when(full)
+        def _():
+            for j in range(ppb):
+                live_do(page(c, slot, j)[1])
+
+        @pl.when(jnp.logical_not(full))
+        def _():
+            def body(j, _):
+                live, copy = page(c, slot, j)
+
+                @pl.when(live)
+                def _():
+                    live_do(copy)
+
+                if dead_do is not None:
+                    @pl.when(jnp.logical_not(live))
+                    def _():
+                        dead_do(j)
+
+            jax.lax.fori_loop(0, ppb, body, None)
+
+    def start(c, slot):
+        def zero(j):
+            # the rows are values too: 0 * stale VMEM must stay 0
+            buf[slot, j] = jnp.zeros(buf.shape[2:], buf.dtype)
+
+        each_page(c, slot, (c + 1) * ppb <= pages_live,
+                  lambda copy: copy.start(), zero)
+
+    def wait(c, slot):
+        each_page(c, slot, (c + 1) * ppb <= pages_live,
+                  lambda copy: copy.wait())
+
+    @pl.when(nblk > 0)
+    def _():
+        start(0, 0)
+
+    qc, qr = qc_ref[0], qr_ref[0]                   # [H, C], [H, Dr]
+    heads = qc.shape[0]
+    dims = (((1,), (1,)), ((), ()))
+
+    def loop(c, carry):
+        m, l, acc = carry
+        slot = jax.lax.rem(c, 2)
+
+        @pl.when(c + 1 < nblk)
+        def _():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        rows = buf[slot].reshape(ppb * ps, buf.shape[3])
+        lat, rope = rows[:, :width_c], rows[:, width_c:width]
+        s = (jax.lax.dot_general(qc, lat, dims,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr, rope, dims,
+                                   preferred_element_type=jnp.float32)
+             ) * scale                                      # [H, S]
+        kpos = c * (ppb * ps) + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        s = jnp.where(kpos < n, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        pr = jnp.where(kpos < n, jnp.exp(s - m_new), 0.0)
+        l = l * alpha + jnp.sum(pr, axis=1, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
+            pr.astype(lat.dtype), lat, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)             # [H, C]
+        return m_new, l, acc
+
+    init = (jnp.full((heads, 1), NEG_INF, jnp.float32),
+            jnp.zeros((heads, 1), jnp.float32),
+            jnp.zeros((heads, width_c), jnp.float32))
+    m, l, acc = jax.lax.fori_loop(0, nblk, loop, init)
+    l = jnp.where(l == 0.0, 1.0, l)                 # empty rows -> zeros
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q_abs, pages, table, seq_lens, scale: float,
+                            value_width: int, *,
+                            pages_per_block: Optional[int] = None,
+                            interpret: bool = False, layer=None):
+    """Latent decode attention that reads live pages only, each once
+    (same contract as :func:`latent_decode_reference`): the Mosaic
+    kernel ``dstpu_mla_decode``.  q_abs: [B, H, W] absorbed queries;
+    pages: the latent pool [L, 1, P, ps, Wp] and ``layer`` (or one
+    layer's pages); the pool stays in HBM in its stored layout."""
+    B, H, W = q_abs.shape
+    layer, pages = _as_pool(layer, pages)
+    ps, Wp = pages.shape[3], pages.shape[4]
+    mp = table.shape[1]
+    ppb = min(mp, pages_per_block or max(1, _MLA_BLOCK_TOKENS // ps))
+    h8 = -(-H // 8) * 8                             # sublane alignment
+    if h8 != H:
+        q_abs = jnp.pad(q_abs, ((0, 0), (0, h8 - H), (0, 0)))
+    row = lambda b, *_: (b, 0, 0)
+    out = pl.pallas_call(
+        functools.partial(_mla_decode_kernel, scale=scale, ps=ps,
+                          max_pages=mp, ppb=ppb, width_c=value_width,
+                          width=W),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,   # table, seq_lens, layer
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, h8, value_width), row),
+                pl.BlockSpec((1, h8, W - value_width), row),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, h8, value_width), row),
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb, ps, Wp), pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, h8, value_width), q_abs.dtype),
+        interpret=interpret,
+        name="dstpu_mla_decode",
+    )(table, seq_lens, _layer_operand(layer), q_abs[..., :value_width],
+      q_abs[..., value_width:], pages)
+    return out[:, :H]
+
+
+def latent_reader(decode: Tuple[str, str]) -> Tuple[str, str]:
+    """``ServingKernelPolicy.decode`` of a latent family's build: the
+    same answer (:func:`paged_reader`), with the Mosaic reader under its
+    own name."""
+    reader, why = decode
+    if reader in ("pallas_v1", "pallas_v2"):
+        return "dstpu_mla_decode", (
+            f"{why}; latent rows: absorbed queries against [c | k_rope], "
+            "each live page read once as keys and values")
+    return reader, f"{why}; latent rows gathered, absorbed form"
+
+
+def latent_attention_step(q, row, w_uk, w_uv, scale, pool, layer, table,
+                          start, *, continuation: bool, prefill: bool,
+                          paged_kernel: str, flash_force_reference: bool,
+                          interpret: bool = False):
+    """:func:`paged_attention_step` of a latent family, on its one pool.
+
+    q: [B, T, H, Dn + Dr] (rope part rotated); row: [B, T, 1, C + Dr] =
+    ``[c | k_rope]``, what is cached; w_uk [C, H, Dn] and w_uv [C, H,
+    Dv]: the two halves of W_kvb.  Decode (T == 1) absorbs W_UK into the
+    query and attends over the rows of the live pages (``paged_kernel``
+    "xla": the gather), then takes the result through W_UV.  T > 1
+    attends in the per-head form, blocked: a whole prompt expands its
+    own rows; a chunk writes its rows, gathers the rows its table names
+    (history and itself) and expands those.  Returns
+    (attn [B, T, H, Dv], pool)."""
+    from deepspeed_tpu.ops.attention import latent_flash_attention
+
+    B, T, H, _ = q.shape
+    C, Dn = w_uk.shape[0], w_uk.shape[2]
+    W = row.shape[-1]
+    write, attend = jax.named_scope("kv_write"), jax.named_scope("kv_attend")
+    row = _pad_rows(row, pool.shape[-1])
+    if T == 1:
+        with jax.named_scope("attn_qkv"), jax.named_scope("mla_q"):
+            q_abs = jnp.concatenate(
+                [jnp.einsum("bhd,chd->bhc", q[:, 0, :, :Dn], w_uk),
+                 q[:, 0, :, Dn:]], -1)
+        with write:
+            page_id, in_page = _row_targets(pool, table, start)
+            pool = _scatter_rows(pool, layer, page_id, in_page, row[:, 0])
+        with attend:
+            if paged_kernel in ("pallas_v1", "pallas_v2"):
+                o = latent_decode_attention(
+                    q_abs, pool, table, start + 1, scale, C,
+                    interpret=interpret, layer=layer)
+            else:
+                o = latent_decode_reference(q_abs, pool, table, start + 1,
+                                            scale, C, layer=layer)
+        with jax.named_scope("attn_out"):
+            return jnp.einsum("bhc,chd->bhd", o, w_uv)[:, None], pool
+
+    expand = lambda rows: (
+        jnp.einsum("bsc,chd->bshd", rows[..., :C], w_uk), rows[..., C:W],
+        jnp.einsum("bsc,chd->bshd", rows[..., :C], w_uv))
+    if prefill:
+        with jax.named_scope("attn_qkv"), jax.named_scope("mla_kv"):
+            kn, kr, v = expand(row[:, :, 0])
+        with jax.named_scope("flash"):
+            attn = latent_flash_attention(
+                q[..., :Dn], q[..., Dn:], kn, kr, v,
+                jnp.zeros((B,), jnp.int32), scale,
+                force_reference=flash_force_reference)
+        with write:
+            pool = _scatter_pages(pool, layer, table, row)
+        return attn, pool
+    if not continuation:
+        raise ValueError("T > 1 over a cache is a continuation")
+    with write:
+        pos = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+        page_id, in_page = _row_targets(pool, table, pos)
+        pool = _scatter_rows(pool, layer, page_id, in_page,
+                             row.reshape(B * T, 1, -1))
+    with jax.named_scope("kv_attend"), jax.named_scope("mla_expand"):
+        kn, kr, v = expand(_gather_rows(pool, layer, table)[:, 0])
+    with attend:
+        attn = latent_flash_attention(
+            q[..., :Dn], q[..., Dn:], kn, kr, v, start, scale,
+            force_reference=flash_force_reference)
+    return attn, pool
+
+
 # --------------------------------------------- shared per-layer dispatch
 def paged_reader(policy: Optional[str], *, decode: bool, tp: bool,
                  interpret: bool, quant: bool) -> Tuple[str, str]:
@@ -1532,23 +1812,32 @@ def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
     return attn, kp, vp, kps, vps
 
 
-def paged_layer_loop(block, x, blocks, cache: PagedKVCache):
+def paged_layer_loop(block, x, blocks, cache: PagedKVCache,
+                     first: int = 0, count: Optional[int] = None):
     """Run a model's layers over the paged cache with the pool as a CARRY.
 
-    ``block(x, lp, layer, kp, vp, kps, vps) -> (x, kp, vp, kps, vps)``
-    is one layer (``kps``/``vps`` are None unless the cache is
-    int8-resident); ``blocks`` the stacked layer params.  The scan runs
-    over (params, layer index) and carries the activations with the
-    pool, so every layer updates the SAME buffers: as a scanned input and
-    stacked output the pool would be two buffers, and each layer would
-    slice its pages out of one and copy them whole into the other.
-    Returns (x, cache) with the new pool; ``seq_lens`` is the caller's."""
+    ``block(x, lp, layer, kp, vp, kps, vps, rows) -> (x, kp, vp, kps,
+    vps, rows)`` is one layer (``vp`` is None over latent pages,
+    ``kps``/``vps`` unless the cache is int8-resident, ``rows`` unless
+    the family counts its experts' rows); ``blocks`` the stacked layer
+    params of the pool's layers ``first .. first + count`` (all of them
+    by default; a family with a leading stack runs the loop twice).  The
+    scan runs over (params, layer index) and carries the activations
+    with the pool, so every layer updates the SAME buffers: as a scanned
+    input and stacked output the pool would be two buffers, and each
+    layer would slice its pages out of one and copy them whole into the
+    other.  Returns (x, cache) with the new pool; ``seq_lens`` is the
+    caller's."""
     def body(carry, layer):
         x, pools = carry
         x, *pools = block(x, *layer, *pools)
         return (x, tuple(pools)), None
 
-    (x, (k, v, ks, vs)), _ = jax.lax.scan(
-        body, (x, (cache.k, cache.v, cache.k_scale, cache.v_scale)),
-        (blocks, jnp.arange(cache.k.shape[0], dtype=jnp.int32)))
-    return x, cache._replace(k=k, v=v, k_scale=ks, v_scale=vs)
+    count = cache.k.shape[0] if count is None else count
+    layers = jnp.arange(count, dtype=jnp.int32)
+    (x, (k, v, ks, vs, rows)), _ = jax.lax.scan(
+        body, (x, (cache.k, cache.v, cache.k_scale, cache.v_scale,
+                   cache.expert_rows)),
+        (blocks, layers + first if first else layers))
+    return x, cache._replace(k=k, v=v, k_scale=ks, v_scale=vs,
+                             expert_rows=rows)

@@ -17,7 +17,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.kernels import (paged_attention_step,
+from deepspeed_tpu.inference.kernels import (latent_attention_step,
+                                             paged_attention_step,
                                              paged_layer_loop, paged_reader)
 from deepspeed_tpu.inference.quantized import dequantize_params
 from deepspeed_tpu.models.family import decoder_family
@@ -27,18 +28,28 @@ def _interpret(interpret: Optional[bool]) -> bool:
     return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
-def _paged_block(fam, cfg, x, lp, ctx, layer, kp, vp, kps, vps, table,
-                 start, *, continuation: bool, prefill: bool,
+def _paged_block(fam, out, cfg, x, lp, ctx, layer, kp, vp, kps, vps, rows,
+                 table, start, *, continuation: bool, prefill: bool,
                  paged_kernel: str, tp: bool, interpret: bool):
-    """One layer over the pool ``kp``/``vp`` ``[L, KV, P, ps, Dh]``."""
+    """One layer over the pool ``kp``/``vp`` ``[L, KV, P, ps, Dh]`` (a
+    latent family: ``kp`` ``[L, 1, P, ps, C + Dr]`` alone).  ``out`` is
+    the layer kind's second half: the family's, or its leading stack's."""
     B, T = x.shape[:2]
     q, k, v = fam.qkv(cfg, x, lp, *ctx)
-    attn, kp, vp, kps, vps = paged_attention_step(
-        q, k, v, kp, vp, layer, table, start, continuation=continuation,
-        prefill=prefill, paged_kernel=paged_kernel,
-        flash_force_reference=tp, interpret=interpret, kps=kps, vps=vps)
-    x = fam.out(cfg, x, attn.reshape(B, T, cfg.n_heads * cfg.head_dim), lp)
-    return x, kp, vp, kps, vps
+    phase = dict(continuation=continuation, prefill=prefill,
+                 paged_kernel=paged_kernel, flash_force_reference=tp,
+                 interpret=interpret)
+    if fam.latent is not None:
+        attn, kp = latent_attention_step(
+            q, k, *fam.latent(cfg, lp), kp, layer, table, start, **phase)
+    else:
+        attn, kp, vp, kps, vps = paged_attention_step(
+            q, k, v, kp, vp, layer, table, start, kps=kps, vps=vps, **phase)
+    x = out(cfg, x, attn.reshape(B, T, -1), lp)
+    if out is fam.out and fam.expert_rows(cfg)[0]:
+        x, routed = x
+        rows = None if rows is None else rows + routed
+    return x, kp, vp, kps, vps, rows
 
 
 def forward_paged(params, tokens, cfg, cache, *,
@@ -105,13 +116,34 @@ def forward_paged(params, tokens, cfg, cache, *,
         paged_kernel, decode=T == 1, tp=tp, interpret=interpret,
         quant=cache.k_scale is not None)
 
-    def block(x, lp, layer, kp, vp, kps, vps):
-        return _paged_block(
-            fam, cfg, x, lp, ctx, layer, kp, vp, kps, vps, cache.table,
-            start, continuation=continuation, prefill=prefill,
-            paged_kernel=paged_kernel, tp=tp, interpret=interpret)
+    def block(out, whole=None, first=0):
+        def run(x, lp, layer, kp, vp, kps, vps, rows):
+            if whole:
+                lp = dict(lp, **whole, layer=layer - first)
+            return _paged_block(
+                fam, out, cfg, x, lp, ctx, layer, kp, vp, kps, vps, rows,
+                cache.table, start, continuation=continuation,
+                prefill=prefill, paged_kernel=paged_kernel, tp=tp,
+                interpret=interpret)
 
-    x, cache = paged_layer_loop(block, x, params["blocks"], cache)
+        return run
+
+    if fam.lead is None:
+        x, cache = paged_layer_loop(block(fam.out), x, params["blocks"],
+                                    cache)
+    else:
+        # a leading stack of another layer kind, then the family's own,
+        # one loop each over the same pool
+        key, lead_out = fam.lead
+        n_lead = jax.tree.leaves(params[key])[0].shape[0]
+        x, cache = paged_layer_loop(block(lead_out), x, params[key], cache,
+                                    count=n_lead)
+        blocks = params["blocks"]
+        whole = {k: blocks[k] for k in fam.whole_stacks}
+        x, cache = paged_layer_loop(
+            block(fam.out, whole, n_lead), x,
+            {k: v for k, v in blocks.items() if k not in whole}, cache,
+            first=n_lead, count=cache.k.shape[0] - n_lead)
     return fam.head(params, x, cfg), cache._replace(seq_lens=start + T)
 
 
@@ -148,10 +180,10 @@ def paged_layered_fns(cfg, *, tp: bool = False,
         itp = _interpret(interpret)
         pk, _ = paged_reader(paged_kernel, decode=x.shape[1] == 1, tp=tp,
                              interpret=itp, quant=False)
-        x, kp, vp, _, _ = _paged_block(
-            fam, cfg, x, lp, ctx, 0, kp[None], vp[None], None, None,
-            table, start, continuation=continuation, prefill=prefill,
-            paged_kernel=pk, tp=tp, interpret=itp)
+        x, kp, vp, _, _, _ = _paged_block(
+            fam, fam.out, cfg, x, lp, ctx, 0, kp[None], vp[None], None,
+            None, None, table, start, continuation=continuation,
+            prefill=prefill, paged_kernel=pk, tp=tp, interpret=itp)
         return x, kp[0], vp[0]
 
     def head_fn(hp, x):
@@ -202,6 +234,7 @@ def forward_with_cache(params, tokens, cfg, cache):
     (ref: the reference's inference transformer kernels' KV-cache
     contract).  tokens: [B, T] → (logits [B, T, V] f32, updated cache)."""
     fam = decoder_family(cfg)
+    fam.refuse(contiguous_cache=True)
     B, T = tokens.shape
     start = cache.length
     x, ctx = fam.embed(params, tokens, start, cfg)

@@ -100,6 +100,7 @@ from deepspeed_tpu.faults import ChecksumError, FaultPlan, InjectedFault
 from deepspeed_tpu.history import NULL_HISTORY, MetricHistory
 from deepspeed_tpu.incidents import NULL_INCIDENTS, IncidentManager
 from deepspeed_tpu.inference.kernels import (PagedKVCache, PageAllocator,
+                                             latent_reader,
                                              resolve_serving_kernels)
 from deepspeed_tpu.inference.paged_forward import forward_paged
 from deepspeed_tpu.inference.prefix_cache import (extend_page_keys,
@@ -167,7 +168,8 @@ def boundary_program(sample):
 
 
 def serving_programs(prefill_fn, decode_fn, chunk_prefill_fn, sample,
-                     decode_chunk: int, max_batch: int):
+                     decode_chunk: int, max_batch: int,
+                     expert_rows: bool = False):
     """The serving programs over a model's forwards ``(params, tokens,
     cache) -> (logits, cache)``, not yet jitted: ``(dstpu_prefill,
     dstpu_chunk, dstpu_boundary, dstpu_sweep, dstpu_decode)``.  The
@@ -178,7 +180,9 @@ def serving_programs(prefill_fn, decode_fn, chunk_prefill_fn, sample,
     when ``kernels.fused_sampling`` resolved "on", the jitted XLA twin
     otherwise) — both emit bit-identical greedy tokens and share the
     categorical math, so flipping the policy can never change a served
-    greedy stream."""
+    greedy stream.  ``expert_rows``: the cache carries a family's count
+    of rows routed to its held experts, and the decode program returns
+    it flat behind its tokens (``[B * K + Eh]`` int32)."""
     # A prefill returns its last row (_last_row): the model's contract
     # stays, the slice is the engine's.
     def dstpu_prefill(params, tokens, cache, last):
@@ -210,7 +214,15 @@ def serving_programs(prefill_fn, decode_fn, chunk_prefill_fn, sample,
             return (nxt[:, None], c), nxt
 
         (_, cache), toks = jax.lax.scan(one, (tok, cache), keys)
-        return jnp.swapaxes(toks, 0, 1), cache          # [B, K]
+        toks = jnp.swapaxes(toks, 0, 1)                 # [B, K]
+        if expert_rows:
+            # the rows routed to each held expert since the last decode
+            # program (prefills' and chunks' too) ride in the one fetch
+            # a step makes, behind the tokens; the sum starts again
+            rows = cache.expert_rows
+            return (jnp.concatenate([toks.reshape(-1), rows]),
+                    cache._replace(expert_rows=jnp.zeros_like(rows)))
+        return toks, cache
 
     return dstpu_prefill, dstpu_chunk, boundary_program(sample), \
         dstpu_sweep, dstpu_decode
@@ -364,7 +376,16 @@ class ServingEngine:
                  shed_expired_deadline: bool = False,
                  replica_id: Optional[str] = None,
                  history=None, incidents=None, kernels=None,
-                 devprof=None, comm=None):
+                 devprof=None, comm=None, values_in_keys: bool = False,
+                 expert_rows: int = 0, routed_per_row: int = 0):
+        # what a token's cache row is: per-head K and V pools, or one
+        # pool whose rows are keys and values both (a latent family's
+        # ``cache_row``); and how many held experts' routed rows the
+        # programs count (0: none), each row routed ``routed_per_row``
+        # times in all (top-k x expert layers)
+        self._values_in_keys = bool(values_in_keys)
+        self._n_expert_rows = int(expert_rows)
+        self._routed_per_row = int(routed_per_row)
         # Sharded serving (ref: deepspeed/module_inject/replace_module.py
         # TP injection + deepspeed/moe/sharded_moe.py expert-parallel
         # inference): with a mesh, params arrive pre-sharded from the
@@ -569,6 +590,17 @@ class ServingEngine:
             "serving_decode_syncs", "device->host token syncs")
         self._c_prefill_chunks = r.counter(
             "serving_prefill_chunks", "split-fuse prompt chunks absorbed")
+        # rows the programs routed to each held expert (the registry has
+        # no labels: the expert's index is the name's suffix), and every
+        # (row, expert) pair they routed anywhere, padding rows included
+        self._c_expert_rows = [
+            r.counter(f"serving_expert_rows_{e}",
+                      f"rows routed to held expert {e}, all expert layers")
+            for e in range(self._n_expert_rows)]
+        self._c_routed_rows = r.counter(
+            "serving_routed_rows",
+            "(row, expert) pairs routed: rows x top-k x expert layers")
+        self._rows_pending = 0
         self._g_queue = r.gauge(
             "serving_queue_depth", "requests waiting for a slot")
         self._g_occupancy = r.gauge(
@@ -1135,11 +1167,21 @@ class ServingEngine:
             k=put_kv(jnp.zeros(
                 (n_layers, n_kv, num_pages, page_size, head_dim),
                 cache_dtype)),
-            v=put_kv(jnp.zeros(
+            v=None if self._values_in_keys else put_kv(jnp.zeros(
                 (n_layers, n_kv, num_pages, page_size, head_dim),
                 cache_dtype)),
             table=table, seq_lens=seq_lens,
-            page_size=page_size)
+            page_size=page_size,
+            expert_rows=self._put(np.zeros(
+                (self._n_expert_rows,), np.int32))
+            if self._n_expert_rows else None)
+
+    def _adopt(self, view: PagedKVCache) -> PagedKVCache:
+        """The engine's cache with the buffers a prefill or chunk
+        program returned in ``view`` (its table and lengths were the
+        call's own)."""
+        return self.cache._replace(k=view.k, v=view.v,
+                                   expert_rows=view.expert_rows)
 
     def _build_programs(self, prefill_fn, decode_fn,
                         chunk_prefill_fn) -> None:
@@ -1149,7 +1191,8 @@ class ServingEngine:
         (dstpu_prefill, dstpu_chunk, dstpu_boundary, dstpu_sweep,
          dstpu_decode) = serving_programs(
             prefill_fn, decode_fn, chunk_prefill_fn, self._sample_fn,
-            self.decode_chunk, self.max_batch)
+            self.decode_chunk, self.max_batch,
+            expert_rows=bool(self._n_expert_rows))
         self._prefill = jax.jit(dstpu_prefill, donate_argnums=(2,))
         self._chunk_prefill = (jax.jit(dstpu_chunk, donate_argnums=(2,))
                                if chunk_prefill_fn is not None else None)
@@ -1242,19 +1285,24 @@ class ServingEngine:
             for end in ends:
                 view = PagedKVCache(
                     k=self.cache.k, v=self.cache.v,
+                    expert_rows=self.cache.expert_rows,
                     table=self._put(self._table_host[0:1]),
                     seq_lens=self._put(zi((1,), np.int32)),
                     page_size=self.page_size)
                 logits_row, view = self._prefill(
                     self.params, self._put(zi((1, end), np.int32)),
                     view, last)
-                self.cache = self.cache._replace(k=view.k, v=view.v)
+                self.cache = self._adopt(view)
         if self._chunk_prefill is not None:
             # the continuation forward's page-table width is bucketed
             # to powers of two clamped at the full row — enumerate the
-            # same closed set _advance_prefill draws from
+            # same closed set _advance_prefill draws from: its table
+            # spans the chunk itself at least, so no width under the
+            # power of two that holds C tokens is ever dispatched
             C = self.prefill_chunk or self.prefill_bucket
             widths, w = [], 1
+            while w < -(-C // self.page_size):
+                w *= 2
             while w < self.max_pages_per_seq:
                 widths.append(w)
                 w *= 2
@@ -1262,13 +1310,14 @@ class ServingEngine:
             for w in widths:
                 view = PagedKVCache(
                     k=self.cache.k, v=self.cache.v,
+                    expert_rows=self.cache.expert_rows,
                     table=self._put(self._table_host[0:1, :w]),
                     seq_lens=self._put(zi((1,), np.int32)),
                     page_size=self.page_size)
                 logits_row, view = self._chunk_prefill(
                     self.params, self._put(zi((1, C), np.int32)),
                     view, last)
-                self.cache = self.cache._replace(k=view.k, v=view.v)
+                self.cache = self._adopt(view)
         # whole-cache dispatches (spec verify, decode) see the
         # page_size leaf as the weak-i32 scalar a previous jit RETURN
         # left in the cache, not the python int the constructor put
@@ -1965,18 +2014,20 @@ class ServingEngine:
             # the decode path
             view = PagedKVCache(
                 k=self.cache.k, v=self.cache.v,
+                expert_rows=self.cache.expert_rows,
                 table=self._put(self._table_host[b:b + 1]),
                 seq_lens=self._put(np.zeros((1,), np.int32)),
                 page_size=self.page_size)
             row, view = self._prefill(
                 self.params, self._put(toks), view,
                 self._put(np.full((1,), T - 1, np.int32)))
+            self._rows_pending += end
             if self._devprof_on and self.devprof.should_sample(
                     "prefill"):
                 # dstpu: host-sync-ok: sampled devprof device-time
                 # attribution (one sync per 1/sample_rate prefills)
                 self.devprof.observe_device("prefill", row)
-            self.cache = self.cache._replace(k=view.k, v=view.v)
+            self.cache = self._adopt(view)
 
             slot = _Slot(req=req, seq_len=T, generated=[], seq_id=seq_id)
             self.slots[b] = slot
@@ -2554,17 +2605,19 @@ class ServingEngine:
         np_bkt = min(np_bkt, self.max_pages_per_seq)
         view = PagedKVCache(
             k=self.cache.k, v=self.cache.v,
+            expert_rows=self.cache.expert_rows,
             table=self._put(self._table_host[b:b + 1, :np_bkt]),
             seq_lens=self._put(np.full((1,), done, np.int32)),
             page_size=self.page_size)
         row, view = self._chunk_prefill(
             self.params, self._put(toks), view,
             self._put(np.full((1,), take - 1, np.int32)))
+        self._rows_pending += C
         if self._devprof_on and self.devprof.should_sample("prefill"):
             # dstpu: host-sync-ok: sampled devprof device-time
             # attribution (one sync per 1/sample_rate prefill chunks)
             self.devprof.observe_device("prefill", row)
-        self.cache = self.cache._replace(k=view.k, v=view.v)
+        self.cache = self._adopt(view)
         s.prefill_done = done + take
         s.seq_len = s.prefill_done
         self._c_prefill_chunks.inc()
@@ -2904,6 +2957,8 @@ class ServingEngine:
                 # dstpu: host-sync-ok: the ONE device→host transfer per
                 # decode chunk (K tokens per sync — the module contract)
                 host_toks = np.asarray(out)
+                if self._n_expert_rows:
+                    host_toks = self._take_expert_rows(host_toks, K)
             with self._sp_append:
                 if self._trace_on and any(
                         s.req.traced for _, s in active):
@@ -2917,6 +2972,21 @@ class ServingEngine:
                         self._append_token(b, int(host_toks[b, j]))
                         if self.slots[b] is None:   # finished mid-chunk:
                             break                   # rest is discard
+
+    def _take_expert_rows(self, flat: np.ndarray, K: int) -> np.ndarray:
+        """Split what a decode program of a family that counts its
+        experts' rows returned (``[B * K + Eh]``): the counters advance
+        by the held experts' rows and by every pair the programs routed
+        since the last decode (this one's ``B * K`` rows, padding and
+        idle slots included, and the prefills' in between); the tokens
+        come back ``[B, K]``."""
+        n = self.max_batch * K
+        for c, rows in zip(self._c_expert_rows, flat[n:]):
+            c.inc(int(rows))
+        self._c_routed_rows.inc(
+            (self._rows_pending + n) * self._routed_per_row)
+        self._rows_pending = 0
+        return flat[:n].reshape(self.max_batch, K)
 
     def _next_dispatch(self):
         """What this decode or verify dispatch's program folds into the
@@ -3710,6 +3780,16 @@ def serving_engine(params, cfg, **kw):
     mesh = kw.pop("mesh", None)
     zero_inference = kw.pop("zero_inference", None)
     fam.check(cfg, mesh, kw.get("max_seq", 256))
+    kvt = KVTierConfig.coerce(kw.get("kv_tier"))
+    zi = ZeroInferenceConfig.coerce(zero_inference)
+    if fam.refuses:
+        fam.refuse(
+            zero_inference=zi.enabled, kv_tier=kvt.enabled,
+            quantized_resident=kvt.quantized_resident,
+            prefix_cache=PrefixCacheConfig.coerce(
+                kw.get("prefix_cache")).enabled,
+            speculative=SpeculativeConfig.coerce(
+                kw.get("speculative")).enabled)
     # sharded-ness is baked in at BUILD time: the compiled paths must not
     # re-read the mutable ambient mesh on a later retrace (a cleared or
     # replaced global would silently re-enable pallas kernels over the
@@ -3721,7 +3801,6 @@ def serving_engine(params, cfg, **kw):
     # int8-resident cache on a chip refuses it), and the same
     # ServingKernelPolicy passes through to the engine: the paged_kernel
     # the closures bake and the policy /statusz reports are one object
-    kvt = KVTierConfig.coerce(kw.get("kv_tier"))
     kw["kernels"] = resolve_serving_kernels(
         kw.get("kernels"),
         tp=mesh is not None and any(
@@ -3729,8 +3808,10 @@ def serving_engine(params, cfg, **kw):
         interpret=jax.default_backend() != "tpu",
         quantized_resident=kvt.enabled and kvt.quantized_resident)
     pk = kw["kernels"].paged_attention
+    if fam.latent is not None:
+        kw["kernels"] = kw["kernels"]._replace(
+            decode=latent_reader(kw["kernels"].decode))
 
-    zi = ZeroInferenceConfig.coerce(zero_inference)
     if zi.enabled:
         from deepspeed_tpu.inference.zero_inference import (
             zero_inference_serving_engine)
@@ -3767,9 +3848,15 @@ def serving_engine(params, cfg, **kw):
             params = _shard_params_for_serving(
                 params, fam.param_specs(cfg), mesh)
 
+    row = fam.cache_row(cfg)
+    held, per_row = fam.expert_rows(cfg)
+    if held:
+        kw.update(expert_rows=held, routed_per_row=per_row)
+    if row.values_in_keys:
+        kw["values_in_keys"] = True
     eng = ServingEngine(
-        params, step, step, n_layers=cfg.n_layers, n_kv=cfg.n_kv_heads,
-        head_dim=cfg.head_dim, chunk_prefill_fn=chunk_step, mesh=mesh,
+        params, step, step, n_layers=cfg.n_layers, n_kv=row.n_kv,
+        head_dim=row.pool_width, chunk_prefill_fn=chunk_step, mesh=mesh,
         **kw)
     if comm_stats is not None:
         _record_comm_placement(eng, comm_stats)

@@ -1,6 +1,7 @@
 """What a decoder family is, stated once.
 
-A family's file (``gpt2.py``, ``llama.py``, ``mixtral.py``) ends with its
+A family's file (``gpt2.py``, ``llama.py``, ``mixtral.py``,
+``pangu_ultra_moe.py``) ends with its
 ``FAMILY = DecoderFamily(...)``: the pieces of one transformer layer and
 the facts a serving build needs.  Everything that serves, streams, drafts
 or generates (the ``inference`` package) reads the record through
@@ -13,13 +14,41 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
 
 def _no_check(cfg, mesh, max_seq) -> None:
     return None
+
+
+class CacheRow(NamedTuple):
+    """What one token leaves in the page pool, a layer: ``n_kv`` rows of
+    ``key_width`` numbers that attention reads as keys and, where
+    ``values_in_keys``, whose first ``value_width`` numbers it reads as
+    values too (one pool, no V pool: a latent row ``[c | k_rope]``);
+    otherwise a second pool holds ``value_width`` wide value rows."""
+
+    n_kv: int
+    key_width: int
+    value_width: int
+    values_in_keys: bool = False
+
+    @property
+    def pool_width(self) -> int:
+        """Numbers a row takes in the pool: a shared row is stored in
+        whole 128-lane tiles, zeros behind (the TPU lays 576 numbers out
+        in 640 lanes whatever is declared, and declared as 576 the
+        compiler re-lays the pool in a copy and Mosaic refuses the page
+        slice: ``inference/kernels.py``, the latent pages)."""
+        if not self.values_in_keys:
+            return self.key_width
+        return -(-self.key_width // 128) * 128
+
+
+def _per_head_rows(cfg) -> CacheRow:
+    return CacheRow(cfg.n_kv_heads, cfg.head_dim, cfg.head_dim)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,6 +85,30 @@ class DecoderFamily:
     # under weight streaming, the stacked ``blocks`` streaming between
     # them; None: the family has no streamed split
     streamed_split: Optional[Callable[[Any], Tuple[tuple, tuple]]] = None
+    # cfg -> what a token's cache row is; the default is per-head K and V
+    cache_row: Callable[[Any], CacheRow] = _per_head_rows
+    # a leading stack of another layer kind: (key of its stacked params,
+    # its ``out`` hook).  The paged loop runs it before ``blocks``, its
+    # layers indexing the same pool from layer 0
+    lead: Optional[Tuple[str, Callable[..., Any]]] = None
+    # latent attention: ``(cfg, lp) -> (w_uk [C, H, Dn], w_uv [C, H, Dv],
+    # softmax scale)``.  ``qkv`` then returns ``(q [B, T, H, Dn + Dr],
+    # row [B, T, 1, C + Dr], None)`` and the paged forward attends in the
+    # absorbed form at T == 1 and in the per-head form at T > 1
+    # (:func:`~deepspeed_tpu.inference.kernels.latent_attention_step`)
+    latent: Optional[Callable[..., Tuple[Any, Any, float]]] = None
+    # cfg -> (how many held experts' routed rows a program counts, how
+    # many (row, expert) pairs one row is routed to in all: top-k x the
+    # expert layers); where the first is not 0, ``out`` returns
+    # ``(x, rows [n] int32)``
+    expert_rows: Callable[[Any], Tuple[int, int]] = lambda cfg: (0, 0)
+    # leaves of ``blocks`` the paged loop does not slice a layer out of:
+    # ``out`` gets them whole, [L, ...], with ``lp["layer"]`` the layer's
+    # index in them (a kernel that takes the stack and an index reads a
+    # layer in place; a slice handed to it would be a copy)
+    whole_stacks: Tuple[str, ...] = ()
+    # (mechanism, why) the family cannot serve with yet: see ``refuse``
+    refuses: Tuple[Tuple[str, str], ...] = ()
 
     @property
     def name(self) -> str:
@@ -64,6 +117,15 @@ class DecoderFamily:
     def sharded(self, mesh) -> bool:
         return mesh is not None and any(
             mesh.size(ax) > 1 for ax in self.shard_axes)
+
+    def refuse(self, **asked) -> None:
+        """Raise, naming the mechanism, for the first one ``asked``
+        (``mechanism=truthy``) that the family ``refuses``: a build goes
+        no further, and never falls silently to a path that is wrong."""
+        for mechanism, why in self.refuses:
+            if asked.get(mechanism):
+                raise NotImplementedError(
+                    f"{self.name} cannot serve with {mechanism}: {why}")
 
 
 def positions_from(start, T: int):
@@ -77,7 +139,7 @@ def positions_from(start, T: int):
 
 
 # the registry: one module name a family
-_FAMILY_MODULES = ("gpt2", "llama", "mixtral")
+_FAMILY_MODULES = ("gpt2", "llama", "mixtral", "pangu_ultra_moe")
 
 
 def decoder_families() -> Tuple[DecoderFamily, ...]:

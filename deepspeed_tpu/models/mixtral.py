@@ -243,10 +243,11 @@ def _moe_ffn_dense(cfg: MixtralConfig, x, lp):
     for EXACT no-drop routing that is already optimal among dense
     formulations: a capacity dispatch only guarantees zero drops at
     factor >= E/k, where its expert FLOPs equal the dense path's and its
-    [N, E, N·k/E·factor] dispatch tensor adds O(N²·k) on top.  (A ragged
-    sort-based dispatch — Megablocks-style — is the only cheaper exact
-    option; candidate for a pallas kernel later.)  At decode (N = a few
-    tokens) the overhead is noise either way.
+    [N, E, N·k/E·factor] dispatch tensor adds O(N²·k) on top.  The only
+    cheaper exact option is a sorted, grouped dispatch, and it exists:
+    :func:`deepspeed_tpu.parallel.moe.held_experts_ffn` (drop-free, a
+    grouped product over the experts held; ``models/pangu_ultra_moe.py``
+    serves through it).  This path does not call it yet: ROADMAP S1.
     """
     from deepspeed_tpu.ops.fused_ops import swiglu
 

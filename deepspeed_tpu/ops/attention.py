@@ -37,8 +37,11 @@ def _reference(q, k, v, causal=True, segment_ids=None):
 
 def _pallas_shapes_ok(q, k, segment_ids=None) -> bool:
     """The shapes the Pallas kernel's tiling covers (both lengths a
-    multiple of 128 and at least 256, head_dim 64 or 128; packed
-    layouts are self-attention only)."""
+    multiple of 128 and at least 256, q, k and v of one head_dim, 64 or
+    128; packed layouts are self-attention only).  Latent attention's
+    widths (q/k of 128 + 64 with the 64 shared by all heads, v of 128,
+    queries at an offset into the keys) are
+    :func:`latent_flash_attention`'s, a kernel of their own."""
     T, S = q.shape[1], k.shape[1]
     return ((segment_ids is None or T == S)
             and T >= 256 and T % 128 == 0
@@ -94,3 +97,38 @@ def flash_attention(q, k, v, causal: bool = True, segment_ids=None,
 
     return jax.shard_map(per_shard, mesh=mesh.mesh, in_specs=in_specs,
                          out_specs=spec, check_vma=False)(*args)
+
+
+def _latent_reference(qn, qr, kn, kr, v, start, scale):
+    s = (jnp.einsum("bthd,bshd->bhts", qn, kn,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bthd,bsd->bhts", qr, kr,
+                      preferred_element_type=jnp.float32)) * scale
+    T, S = qn.shape[1], kn.shape[1]
+    seen = jnp.arange(S)[None, None] <= (
+        start[:, None] + jnp.arange(T)[None])[:, :, None]       # [B, T, S]
+    s = jnp.where(seen[:, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhts,bshd->bthd", p, v)
+
+
+def latent_flash_attention(qn, qr, kn, kr, v, start, scale: float,
+                           force_reference: bool = False):
+    """Latent attention in the per-head form, over expanded keys and
+    values: ``qn`` [B, T, H, Dn] and ``qr`` [B, T, H, Dr] (rotated)
+    against ``kn`` [B, S, H, Dn], ``kr`` [B, S, Dr] (one rotated key
+    part for all heads) and ``v`` [B, S, H, Dv] -> [B, T, H, Dv].
+    Query t of row b stands at position ``start[b] + t`` and sees the
+    keys at or before it.
+
+    On a TPU with both lengths a multiple of 128 the blocked Pallas
+    kernel runs (``dstpu_latent_flash_fwd``: never an [H, T, S] score
+    array, and ``kr`` is read once a block, not once a head); elsewhere
+    the jnp reference, which materialises the scores."""
+    T, S = qn.shape[1], kn.shape[1]
+    if jax.default_backend() != "tpu" or force_reference \
+            or T % 128 or S % 128:
+        return _latent_reference(qn, qr, kn, kr, v, start, scale)
+    from deepspeed_tpu.ops.attention_pallas import latent_flash_attention_tpu
+
+    return latent_flash_attention_tpu(qn, qr, kn, kr, v, start, scale)

@@ -441,3 +441,98 @@ def flash_attention_tpu(q, k, v, causal: bool = True, segment_ids=None,
     vf = v.transpose(0, 2, 1, 3).reshape(B * KV, S, D)
     out = _flash_bhtd(qf, kf, vf, segment_ids, causal, interpret, H, KV)
     return out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+
+
+# ------------------------------------------------- latent (MLA) forward
+def _latent_fwd_kernel(start_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
+                       o_ref, m_scr, l_scr, acc_scr, *, scale, heads,
+                       block_q, block_k, num_k_blocks):
+    """:func:`_fwd_kernel` with the score in two parts (per-head
+    ``qn . kn`` plus ``qr . kr`` against the one rotated key part all
+    heads share), a value width of its own, and the queries standing at
+    ``start`` in the keys' positions.  Forward only."""
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    first = start_ref[pl.program_id(0) // heads] + qi * block_q
+
+    @pl.when(ki == 0)
+    def _():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(ki * block_k <= first + block_q - 1)
+    def _():
+        dims = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(qn_ref[0], kn_ref[0], dims,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr_ref[0], kr_ref[0], dims,
+                                   preferred_element_type=jnp.float32)
+             ) * scale                                       # [BQ, BK]
+        rows = first + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        cols = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        s = jnp.where(rows >= cols, s, NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # key 0 is at or before every query: block 0 leaves m finite,
+        # and a masked score's exponential is 0 from then on
+        p = jnp.exp(s - m_new)
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[:] = m_new
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(ki == num_k_blocks - 1)
+    def _():
+        o_ref[0] = (acc_scr[:] / l_scr[:]).astype(o_ref.dtype)
+
+
+def latent_flash_attention_tpu(qn, qr, kn, kr, v, start, scale: float,
+                               interpret: bool = False):
+    """See :func:`deepspeed_tpu.ops.attention.latent_flash_attention`;
+    T and S multiples of 128."""
+    B, T, H, Dn = qn.shape
+    S, Dr, Dv = kn.shape[1], qr.shape[-1], v.shape[-1]
+    block_q, block_k = _pick_blocks(T, S)
+    nk = S // block_k
+    heads_first = lambda a: a.transpose(0, 2, 1, 3).reshape(
+        B * H, a.shape[1], a.shape[3])
+
+    def key_block(b, i, j, start_ref):
+        # past the last block a query block sees, stay on it: no copy
+        last = (start_ref[b // H] + (i + 1) * block_q - 1) // block_k
+        return jnp.minimum(j, last)
+
+    q_spec = lambda D: pl.BlockSpec((1, block_q, D),
+                                    lambda b, i, j, st: (b, i, 0))
+    k_spec = lambda D: pl.BlockSpec(
+        (1, block_k, D), lambda b, i, j, st: (b, key_block(b, i, j, st), 0))
+    out = pl.pallas_call(
+        functools.partial(_latent_fwd_kernel, scale=scale, heads=H,
+                          block_q=block_q, block_k=block_k,
+                          num_k_blocks=nk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * H, T // block_q, nk),
+            in_specs=[
+                q_spec(Dn), q_spec(Dr), k_spec(Dn),
+                pl.BlockSpec((1, block_k, Dr), lambda b, i, j, st: (
+                    b // H, key_block(b, i, j, st), 0)),
+                k_spec(Dv),
+            ],
+            out_specs=q_spec(Dv),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B * H, T, Dv), qn.dtype),
+        interpret=interpret,
+        name="dstpu_latent_flash_fwd",
+    )(start.astype(jnp.int32), heads_first(qn), heads_first(qr),
+      heads_first(kn), kr, heads_first(v))
+    return out.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)

@@ -168,3 +168,114 @@ def expert_param_specs(specs: Any) -> Any:
 
     return jax.tree.map(one, specs,
                         is_leaf=lambda x: x is None or isinstance(x, P))
+
+
+# ------------------------------------------- a share of the experts, served
+def sigmoid_topk_route(h, gate, top_k: int, scale: float = 1.0,
+                       normalize: bool = True):
+    """Router of the sigmoid-scored families: ``h`` [N, d] against
+    ``gate`` [d, E] over ALL E experts, in f32 whatever the inputs are
+    (a bf16 score flips near-tied choices) -> (weights [N, k] f32,
+    experts [N, k] int32).  The k largest scores, divided by their sum
+    (``normalize``) and multiplied by ``scale``."""
+    with jax.named_scope("moe_router"):
+        s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32),
+                                   gate.astype(jnp.float32),
+                                   precision=jax.lax.Precision.HIGHEST))
+        top, idx = jax.lax.top_k(s, top_k)
+        if normalize:
+            top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+        return top * scale, idx.astype(jnp.int32)
+
+
+# Rows (tokens) up to which every held expert evaluates every row: at 128
+# rows of 7,680 the dense products stream the 16 experts once in 2.3 ms
+# where the grouped ones take 3.0 (Mosaic) and 5.1 (XLA's ragged_dot): a
+# group of 4 rows still pays for a whole row tile.  At 1,024 rows it is
+# 8.9 ms against 4.1 and 7.6 (v5e, PERF.md 6, PR 33).
+_DENSE_HELD_ROWS = 256
+# (rows, contraction, columns) tile of the Mosaic grouped product
+_GMM_TILING = (256, 1920, 1024)
+
+
+def _grouped_product(x, w, sizes, layer=None):
+    """``x`` [M, K] in groups of ``sizes`` consecutive rows, group g
+    against ``w[g]`` [K, N] -> [M, N]; rows past the groups are left
+    unspecified.  With ``layer``, ``w`` is the whole stack [L, G, K, N]
+    and the groups are layer ``layer``'s.  On a TPU the Mosaic grouped
+    kernel (JAX's megablox ``gmm``, whose row tile is ours to choose)
+    where its tiles divide the operands: it is handed the stack as L * G
+    groups of which all but the layer's are empty (it visits none of
+    them), because a layer sliced out of a scanned stack is a copy of
+    its 1.5 GB before a Mosaic call (18 ms of a 78 ms chunk program,
+    v5e, PR 33).  Elsewhere XLA's ``ragged_dot`` on the layer's slice."""
+    tm, tk, tn = _GMM_TILING
+    (M, K), N = x.shape, w.shape[-1]
+    if jax.default_backend() == "tpu" and not (M % tm or K % 128 or N % 128):
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+        fit = lambda n, t: next(c for c in range(min(t, n), 0, -128)
+                                if n % c == 0)
+        if layer is not None:
+            L, G = w.shape[:2]
+            w = w.reshape(L * G, K, N)
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((L * G,), sizes.dtype), sizes, (layer * G,))
+        return gmm(x, w, sizes, preferred_element_type=x.dtype,
+                   tiling=(tm, fit(K, tk), fit(N, tn)))
+    return jax.lax.ragged_dot(x, w if layer is None else w[layer], sizes)
+
+
+def held_experts_ffn(h, weights, experts, w1, w3, w2, first: int = 0,
+                     layer=None):
+    """The part of a routed FFN that the experts held here contribute:
+    drop-free at any imbalance, static shapes.  (The sorted dispatch a
+    dense all-experts combine is the alternative to; ROADMAP S1.)
+
+    ``h`` [N, d]; ``weights``/``experts`` [N, k] from the router over all
+    the experts there are; ``w1``/``w3`` [Eh, d, f] and ``w2`` [Eh, f, d]
+    the SwiGLU experts ``first .. first + Eh`` -> (y [N, d] in ``h``'s
+    dtype, rows [Eh] int32 routed to each held expert).  With ``layer``
+    (a layer loop's traced index) the weights are the whole stacks
+    [L, Eh, ...] and that layer's experts are meant.
+
+    Many rows: every (token, expert) pair is a row; pairs whose expert is
+    held sort first, by expert, and the rest (what other ranks compute)
+    fall past the last group, where the grouped product visits no tile.
+    The row buffer is the worst case N * k: all of a token's experts may
+    be held.  Few rows (a decode step): each held expert evaluates every
+    row and the router's weight, zero where it did not choose the
+    expert, combines them; that reads each expert once, as the grouped
+    product would, without its row tiles.
+    """
+    N, k = experts.shape
+    Eh = w1.shape[-3]
+    local = experts.reshape(-1) - first
+    held = (local >= 0) & (local < Eh)
+    group = jnp.where(held, local, Eh)               # not held: sorts last
+    sizes = jnp.zeros((Eh + 1,), jnp.int32).at[group].add(1)[:Eh]
+    with jax.named_scope("moe_routed"):
+        if N <= _DENSE_HELD_ROWS:
+            if layer is not None:
+                w1, w3, w2 = w1[layer], w3[layer], w2[layer]
+            gain = jnp.zeros((N, Eh + 1), jnp.float32).at[
+                jnp.arange(N * k) // k, group].add(weights.reshape(-1))
+            ys = jax.vmap(lambda a, b, c: (jax.nn.silu(h @ a) * (h @ b)) @ c)(
+                w1, w3, w2)                                     # [Eh, N, d]
+            out = jnp.einsum("ne,end->nd", gain[:, :Eh],
+                             ys.astype(jnp.float32))
+            return out.astype(h.dtype), sizes
+        order = jnp.argsort(group)                   # stable
+        x = h[order // k]
+        a = _grouped_product(x, w1, sizes, layer)
+        b = _grouped_product(x, w3, sizes, layer)
+        y = _grouped_product(jax.nn.silu(a) * b, w2, sizes, layer)
+        # back to pair order by a gather (``order`` is a permutation),
+        # then a token's k pairs are summed with the router's weights:
+        # no scatter of wide rows.  A pair that is not held sat past the
+        # groups, where the product left whatever was there.
+        back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+            jnp.arange(N * k, dtype=jnp.int32))
+        y = jnp.where(held[:, None], y[back].astype(jnp.float32), 0.0) \
+            * weights.reshape(-1, 1)
+        return jnp.sum(y.reshape(N, k, -1), axis=1).astype(h.dtype), sizes

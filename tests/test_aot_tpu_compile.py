@@ -332,3 +332,101 @@ def test_serving_program_leaves_the_pool_in_place(chip, pool, phase):
         assert "dstpu_paged" not in hlo
     pool_bytes = 2 * math.prod(shape) * 2               # K and V, bf16
     assert temp < pool_bytes / 2
+
+
+# ---------------------------------------------- the latent family's cell
+# openpangu-ultra-moe-718b-ep16-d5.serve.think-sat as the benchmark builds
+# it: 1 dense + 4 expert layers at the published widths, 16 of 256 experts
+# held, an eighth of the vocabulary; 128 slots over 40,961 pages of 16.
+_PANGU = dict(vocab_size=19200, n_layers=5, n_dense_layers=1,
+              experts_held=(0, 16))
+_PANGU_PAGES, _PANGU_SLOTS, _PANGU_TABLE = 40961, 128, 12288 // PAGE
+# program -> (rows, tokens, table entries, bound on its temporaries in
+# GiB: AOT, PR 33, reads 0.072, 0.856 and 0.268)
+PANGU_PROGRAMS = {"decode": (_PANGU_SLOTS, 1, _PANGU_TABLE, 0.1),
+                  "chunk_full_table": (1, 1024, _PANGU_TABLE, 0.95),
+                  "chunk_first": (1, 1024, 64, 0.35)}
+
+
+def test_mla_decode_kernel(chip):
+    """128 heads over pages of 16 rows of 576 numbers stored in 640
+    lanes; declared 576 wide Mosaic refuses the page copy."""
+    B, H, mp = 8, 128, TABLE_TOKENS // PAGE
+    bf = jnp.bfloat16
+    call = lambda width: _compile(
+        lambda q, pool, table, lens: K.latent_decode_attention(
+            q, pool, table, lens, 192 ** -0.5, 512, layer=1),
+        chip, ((B, H, 576), bf), ((2, 1, B * mp + 1, PAGE, width), bf),
+        ((B, mp), jnp.int32), ((B,), jnp.int32))
+    assert "dstpu_mla_decode" in call(640).as_text()
+    with pytest.raises(Exception, match="aligned to tiling"):
+        call(576)
+
+
+def test_latent_flash_kernel(chip):
+    """A chunk of 1,024 queries at an offset into 4,096 expanded keys:
+    q/k of 128 + 64 with the 64 shared by the 128 heads, v of 128."""
+    from deepspeed_tpu.ops.attention_pallas import latent_flash_attention_tpu
+
+    bf, T, S, H = jnp.bfloat16, 1024, 4096, 128
+    compiled = _compile(
+        lambda *a: latent_flash_attention_tpu(*a, 192 ** -0.5), chip,
+        ((1, T, H, 128), bf), ((1, T, H, 64), bf), ((1, S, H, 128), bf),
+        ((1, S, 64), bf), ((1, S, H, 128), bf), ((1,), jnp.int32))
+    assert "dstpu_latent_flash_fwd" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", PANGU_PROGRAMS)
+def test_latent_cell_programs_fit_and_leave_the_pool_in_place(
+        chip, monkeypatch, program):
+    """The decode and chunk programs of the latent family's cell, at the
+    cell's sizes: they compile for the described v5e (the 9.16 GiB of
+    weights and the 3.91 GiB pool beside their temporaries, inside
+    15.75 GiB), hold no copy of the pool or of a layer's 1.5 GB of
+    experts, and run the kernels by name."""
+    from deepspeed_tpu.models import pangu_ultra_moe as pangu
+
+    # the family asks the backend which attention and grouped product to
+    # run; the described chip is not the default backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, T, table, temp_gib = PANGU_PROGRAMS[program]
+    cfg = pangu.PanguUltraMoEConfig(**_PANGU)
+    shape = (cfg.n_layers, 1, _PANGU_PAGES, PAGE, cfg.head_dim)
+    assert cfg.head_dim == 640
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+        if hasattr(x, "shape") else x, tree)
+    params = jax.eval_shape(lambda: pangu.init_params(
+        jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16))
+    cache = K.PagedKVCache(
+        k=jax.ShapeDtypeStruct(shape, jnp.bfloat16), v=None,
+        table=jax.ShapeDtypeStruct((rows, table), jnp.int32),
+        seq_lens=jax.ShapeDtypeStruct((rows,), jnp.int32), page_size=PAGE,
+        expert_rows=jax.ShapeDtypeStruct((16,), jnp.int32))
+    forward = lambda continuation: lambda params, tokens, cache: \
+        forward_paged(params, tokens, cfg, cache, interpret=False,
+                      tp=False, continuation=continuation)
+    _, chunk, _, _, decode = serving_programs(
+        forward(False), forward(False), forward(True), _sample_rows,
+        decode_chunk=1, max_batch=rows, expert_rows=True)
+    run, operands = (
+        (decode, (jax.ShapeDtypeStruct((2,), jnp.uint32),
+                  jax.ShapeDtypeStruct((), jnp.int32),
+                  jax.ShapeDtypeStruct((rows,), jnp.float32)))
+        if program == "decode"
+        else (chunk, (jax.ShapeDtypeStruct((1,), jnp.int32),)))
+    compiled = jax.jit(run, donate_argnums=(2,)).lower(*on_chip((
+        params, jax.ShapeDtypeStruct((rows, T), jnp.int32), cache,
+        *operands))).compile()
+    hlo, memory = compiled.as_text(), compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= temp_gib * 2 ** 30
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+    assert _pool_sized_ops(hlo, shape) == []
+    # a layer's experts are read in place, not sliced out of the stack
+    assert "dynamic-slice_bitcast_fusion" not in hlo
+    kernel = "dstpu_mla_decode" if program == "decode" \
+        else "dstpu_latent_flash_fwd"
+    assert re.search(rf"%{kernel}[\w.]* = .*tpu_custom_call", hlo)
+    if program != "decode":
+        assert re.search(r"%gmm[\w.]* = .*tpu_custom_call", hlo)

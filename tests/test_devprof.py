@@ -498,3 +498,37 @@ class TestEngineContract:
                 assert cnt["devprof_compiles_warmup"] > 0
         finally:
             router.shutdown()
+
+
+@pytest.mark.parametrize("chunk,page,chunk_programs", [
+    (16, 4, 3),      # tables of 4, 8 and 16 pages: a chunk spans 4
+    (8, 8, 4),       # 1, 2, 4, 8: a chunk is one page
+    (64, 4, 1),      # the chunk spans the whole row
+])
+def test_warmup_compiles_the_table_widths_a_chunk_can_be_given(
+        gpt2_tiny, chunk, page, chunk_programs):
+    """The build-time warm-up compiles the chunk program at the table
+    widths ``_advance_prefill`` draws from and no narrower: a chunk's
+    table spans the chunk itself at least, so a width under the power of
+    two that holds ``prefill_chunk`` tokens is never dispatched.  Prompts
+    of every length then compile nothing."""
+    import numpy as np
+
+    params, cfg, _ = gpt2_tiny
+    eng = _tiny_engine(params, cfg, page_size=page, num_pages=160 // page,
+                       prefill_bucket=0, prefill_chunk=chunk,
+                       telemetry=True, devprof={"sample_rate": 0.0})
+    try:
+        sites = [e["site"] for e in eng.devprof.ledger.snapshot()["entries"]]
+        assert sites.count("chunk_prefill") == chunk_programs
+        warm = eng.devprof.ledger.warmup
+        rng = np.random.default_rng(5)
+        for i, n in enumerate((1, chunk - 1, chunk, chunk + 1, 33, 50)):
+            n = min(n, 60)
+            eng.submit(i, rng.integers(1, cfg.vocab_size, n).tolist(),
+                       max_new_tokens=3)
+        eng.run()
+        assert eng.devprof.ledger.steady == 0
+        assert eng.devprof.ledger.warmup == warm
+    finally:
+        eng.shutdown()

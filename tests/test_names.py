@@ -271,7 +271,16 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
     qc = jnp.zeros((2, 8, 2, 128), jnp.float32)
     codes = jnp.zeros((2, 9, 8, 128), jnp.int8)
     scale = jnp.ones((2, 9, 8, 1), jnp.float32)
+    latent = jnp.zeros((1, 1, 9, 8, 128), jnp.float32)
     sites = {
+        "dstpu_mla_decode": lambda: jax.make_jaxpr(
+            lambda: K.latent_decode_attention(
+                jnp.zeros((2, 8, 40), jnp.float32), latent, table, start,
+                0.2, 32, layer=0, interpret=True))(),
+        "dstpu_latent_flash_fwd": lambda: jax.make_jaxpr(
+            lambda: attention_pallas.latent_flash_attention_tpu(
+                q[..., :16], q[..., :8], q[..., :16], q[:, :, 0, :8],
+                q[..., :16], start[:1], 0.2, interpret=True))(),
         "dstpu_flash_fwd": lambda: jax.make_jaxpr(flash)(q),
         "dstpu_flash_bwd_dq": lambda: jax.make_jaxpr(jax.grad(flash))(q),
         "dstpu_flash_bwd_dkv": lambda: jax.make_jaxpr(jax.grad(flash))(q),
@@ -295,11 +304,11 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
     for want, make in sites.items():
         names = _pallas_names(make().jaxpr, [])
         assert want in names, (want, names)
-    # the sources give ten sites ten names, none shared
+    # the sources give twelve sites twelve names, none shared
     named = []
     for mod in (K, adam_pallas, attention_pallas, quant, sampling_pallas):
         with open(mod.__file__) as f:
             text = f.read()
         assert text.count("pl.pallas_call(") == text.count('name="dstpu_')
         named += re.findall(r'name="(dstpu_[a-z0-9_]+)"', text)
-    assert len(named) == len(set(named)) == 10
+    assert len(named) == len(set(named)) == 12
