@@ -55,7 +55,8 @@ def _paged_block(fam, out, cfg, x, lp, ctx, layer, kp, vp, kps, vps, rows,
 def forward_paged(params, tokens, cfg, cache, *,
                   continuation: bool = False, tp: Optional[bool] = None,
                   interpret: Optional[bool] = None,
-                  paged_kernel: Optional[str] = None):
+                  paged_kernel: Optional[str] = None,
+                  resident: bool = True):
     """Forward over a paged KV cache.  tokens: [B, T] → (logits, cache).
 
     ``tp``: True = params/cache are sharded over the mesh, so every
@@ -63,7 +64,12 @@ def forward_paged(params, tokens, cfg, cache, *,
     to the GSPMD-partitionable XLA formulations.  Serving closures pass
     this EXPLICITLY at build time — correctness must not hang off the
     mutable ambient mesh, which is only consulted when ``tp`` is None
-    (direct callers).
+    (direct callers).  ``resident``: False = ``params`` are values made
+    inside the program (dequantised int8 leaves), not arrays it was
+    handed.  A family's ``whole_stacks`` go to its ``out`` unsliced only
+    where they are resident on one device: a kernel reads a layer of
+    such a stack in place, and could neither be partitioned over a mesh
+    nor be handed a stack that is dequantised whole.
 
     Prefill (T > 1, empty cache): dense causal attention over the prompt,
     K/V bulk-written into pages.  Decode (T == 1): paged attention over
@@ -98,8 +104,7 @@ def forward_paged(params, tokens, cfg, cache, *,
     if tp is None:
         from deepspeed_tpu.topology import current_mesh
 
-        ms = current_mesh()
-        tp = ms is not None and ms.size("model") > 1
+        tp = fam.sharded(current_mesh())
     start = cache.seq_lens
     prefill = T > 1 and not continuation
     if prefill:
@@ -128,22 +133,21 @@ def forward_paged(params, tokens, cfg, cache, *,
 
         return run
 
-    if fam.lead is None:
-        x, cache = paged_layer_loop(block(fam.out), x, params["blocks"],
-                                    cache)
-    else:
+    n_lead = 0
+    if fam.lead is not None:
         # a leading stack of another layer kind, then the family's own,
         # one loop each over the same pool
         key, lead_out = fam.lead
         n_lead = jax.tree.leaves(params[key])[0].shape[0]
         x, cache = paged_layer_loop(block(lead_out), x, params[key], cache,
                                     count=n_lead)
-        blocks = params["blocks"]
-        whole = {k: blocks[k] for k in fam.whole_stacks}
-        x, cache = paged_layer_loop(
-            block(fam.out, whole, n_lead), x,
-            {k: v for k, v in blocks.items() if k not in whole}, cache,
-            first=n_lead, count=cache.k.shape[0] - n_lead)
+    blocks = params["blocks"]
+    whole = ({k: blocks[k] for k in fam.whole_stacks}
+             if resident and not tp else {})
+    x, cache = paged_layer_loop(
+        block(fam.out, whole, n_lead), x,
+        {k: v for k, v in blocks.items() if k not in whole}, cache,
+        first=n_lead, count=cache.k.shape[0] - n_lead)
     return fam.head(params, x, cfg), cache._replace(seq_lens=start + T)
 
 
@@ -246,6 +250,8 @@ def forward_with_cache(params, tokens, cfg, cache):
             attn, kc, vc = cached_attention(q, kc, vc, k, v, start)
         x = fam.out(cfg, x, attn.reshape(B, T, cfg.n_heads * cfg.head_dim),
                     lp)
+        if fam.expert_rows(cfg)[0]:
+            x, _ = x
         return x, (kc, vc)
 
     x, (new_k, new_v) = jax.lax.scan(block, x,

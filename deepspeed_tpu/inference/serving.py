@@ -1149,6 +1149,8 @@ class ServingEngine:
             (self.max_batch, self.max_pages_per_seq),
             self.trash_page, jnp.int32))
         seq_lens = self._put(jnp.zeros((self.max_batch,), jnp.int32))
+        expert_rows = (self._put(np.zeros((self._n_expert_rows,), np.int32))
+                       if self._n_expert_rows else None)
         if self._quant_resident:
             # int8-resident pages: codes replace the dense planes
             # (~2x the pages per HBM byte at bf16, 4x at f32) and a
@@ -1162,7 +1164,8 @@ class ServingEngine:
                 v=put_kv(jnp.zeros(shape, jnp.int8)),
                 table=table, seq_lens=seq_lens, page_size=page_size,
                 k_scale=put_kv(jnp.ones(sshape, jnp.float32)),
-                v_scale=put_kv(jnp.ones(sshape, jnp.float32)))
+                v_scale=put_kv(jnp.ones(sshape, jnp.float32)),
+                expert_rows=expert_rows)
         return PagedKVCache(
             k=put_kv(jnp.zeros(
                 (n_layers, n_kv, num_pages, page_size, head_dim),
@@ -1172,9 +1175,7 @@ class ServingEngine:
                 cache_dtype)),
             table=table, seq_lens=seq_lens,
             page_size=page_size,
-            expert_rows=self._put(np.zeros(
-                (self._n_expert_rows,), np.int32))
-            if self._n_expert_rows else None)
+            expert_rows=expert_rows)
 
     def _adopt(self, view: PagedKVCache) -> PagedKVCache:
         """The engine's cache with the buffers a prefill or chunk
@@ -3782,14 +3783,14 @@ def serving_engine(params, cfg, **kw):
     fam.check(cfg, mesh, kw.get("max_seq", 256))
     kvt = KVTierConfig.coerce(kw.get("kv_tier"))
     zi = ZeroInferenceConfig.coerce(zero_inference)
+    speculating = SpeculativeConfig.coerce(kw.get("speculative")).enabled
     if fam.refuses:
         fam.refuse(
             zero_inference=zi.enabled, kv_tier=kvt.enabled,
             quantized_resident=kvt.quantized_resident,
             prefix_cache=PrefixCacheConfig.coerce(
                 kw.get("prefix_cache")).enabled,
-            speculative=SpeculativeConfig.coerce(
-                kw.get("speculative")).enabled)
+            speculative=speculating)
     # sharded-ness is baked in at BUILD time: the compiled paths must not
     # re-read the mutable ambient mesh on a later retrace (a cleared or
     # replaced global would silently re-enable pallas kernels over the
@@ -3820,13 +3821,17 @@ def serving_engine(params, cfg, **kw):
             params, cfg, zi, family=fam, weight_dtype=weight_dtype,
             quant_group_size=quant_group_size, mesh=mesh, **kw)
 
+    # int8 leaves are dequantised inside each program: no stack of them
+    # is an array a kernel could read a layer of in place
+    resident = weight_dtype == "bfloat16"
+
     def step(params, tokens, cache):
         return forward_paged(params, tokens, cfg, cache, tp=sharded,
-                             paged_kernel=pk)
+                             paged_kernel=pk, resident=resident)
 
     def chunk_step(params, tokens, cache):
         return forward_paged(params, tokens, cfg, cache, continuation=True,
-                             tp=sharded, paged_kernel=pk)
+                             tp=sharded, paged_kernel=pk, resident=resident)
 
     if weight_dtype != "bfloat16":
         from deepspeed_tpu.inference.quantized import quantize_for_inference
@@ -3850,7 +3855,9 @@ def serving_engine(params, cfg, **kw):
 
     row = fam.cache_row(cfg)
     held, per_row = fam.expert_rows(cfg)
-    if held:
+    # the counts ride in the decode program's fetch; a speculating
+    # engine's steady program is the verify sweep, which has none
+    if held and not speculating:
         kw.update(expert_rows=held, routed_per_row=per_row)
     if row.values_in_keys:
         kw["values_in_keys"] = True

@@ -275,19 +275,16 @@ def _qkv(cfg, x, lp, cos, sin):
         return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
-def _out_ffn(cfg, x, attn, lp, ffn=None, tag=None):
-    """Attention output projection + residual, then the FFN half:
-    SwiGLU, or ``ffn(lp, h)`` (a MoE family's expert combine, which
-    names its own scopes inside ``mlp``).  ``attn``: [B, T, H*hd].
-    ``tag`` is training's ``checkpoint_name`` (remat)."""
+def _out_ffn(cfg, x, attn, lp, tag=None):
+    """Attention output projection + residual, then the SwiGLU FFN
+    half.  ``attn``: [B, T, H*hd].  ``tag`` is training's
+    ``checkpoint_name`` (remat)."""
     from deepspeed_tpu.ops.fused_ops import swiglu
 
     with jax.named_scope("attn_out"):
         x = x + attn @ lp["wo"]
     with jax.named_scope("mlp"):
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        if ffn is not None:
-            return x + ffn(lp, h)
         mlp = swiglu(h, lp["w1"], lp["w3"])
         if tag is not None:
             mlp = tag(mlp, "mlp_out")

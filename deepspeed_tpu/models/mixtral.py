@@ -23,7 +23,7 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.config import MoEConfig
 from deepspeed_tpu.models import llama as _llama
-from deepspeed_tpu.parallel.moe import MoELayer
+from deepspeed_tpu.parallel.moe import MoELayer, held_experts_ffn
 
 
 @dataclasses.dataclass
@@ -232,53 +232,52 @@ def _embed(params, tokens, lcfg, positions=None):
         return x, cos, sin
 
 
-def _moe_ffn_dense(cfg: MixtralConfig, x, lp):
-    """Capacity-free exact top-k MoE for the inference path (ref:
+def expert_layer(cfg: MixtralConfig, h, lp):
+    """The inference path's FFN: capacity-free exact top-k (ref:
     DeepSpeed-MoE inference, deepspeed/moe/sharded_moe.py at eval).
+    h [B, T, d] (normed) -> (y [B, T, d], rows [E] int32 routed to each
+    expert).
 
     Training uses the capacity-limited dispatch (token drops are part of
     the reference's ``drop_tokens=True`` semantics under load); inference
-    must not drop.  Every expert evaluates all tokens and outputs combine
-    by the renormalized top-k gate probs — E/k× the top-k FFN FLOPs, but
-    for EXACT no-drop routing that is already optimal among dense
-    formulations: a capacity dispatch only guarantees zero drops at
-    factor >= E/k, where its expert FLOPs equal the dense path's and its
-    [N, E, N·k/E·factor] dispatch tensor adds O(N²·k) on top.  The only
-    cheaper exact option is a sorted, grouped dispatch, and it exists:
-    :func:`deepspeed_tpu.parallel.moe.held_experts_ffn` (drop-free, a
-    grouped product over the experts held; ``models/pangu_ultra_moe.py``
-    serves through it).  This path does not call it yet: ROADMAP S1.
+    must not drop.  The router's choice goes to
+    :func:`~deepspeed_tpu.parallel.moe.held_experts_ffn` with all the
+    experts held: sorted and grouped, so that a row costs its ``top_k``
+    experts, where the paged forward hands the stacks over whole
+    (``lp["layer"]``: plain arrays on one device); every expert on every
+    row, combined by the renormalised gate probabilities, at a decode
+    step's few rows and wherever the weights arrive a layer at a time
+    (sharded over a mesh, dequantised, streamed, scanned).
     """
-    from deepspeed_tpu.ops.fused_ops import swiglu
-
-    B, T, d = x.shape
-    E, k = cfg.num_experts, cfg.top_k
-    h = x.reshape(-1, d)
+    B, T, d = h.shape
+    k = cfg.top_k
+    hf = h.reshape(-1, d)
     with jax.named_scope("moe_router"):
         # router math in f32 like the training gate — bf16 logits could
         # flip a near-tied top-k choice and diverge from the trained
         # routing
-        logits = h.astype(jnp.float32) @ lp["gate"].astype(jnp.float32)
+        logits = hf.astype(jnp.float32) @ lp["gate"].astype(jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1)
         _, topi = jax.lax.top_k(logits, k)                          # [N, k]
         w = jnp.take_along_axis(probs, topi, axis=-1)
         if k > 1:
             # same renormalization as the training gate (top2gating)
             w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
-        wfull = jnp.sum(jax.nn.one_hot(topi, E, dtype=w.dtype)
-                        * w[..., None], axis=1)                     # [N, E]
     with jax.named_scope("moe_ffn"):
-        ys = jax.vmap(lambda p1, p3, p2: swiglu(h, p1, p3) @ p2)(
-            lp["w1"], lp["w3"], lp["w2"])                           # [E, N, d]
-        y = jnp.einsum("ne,end->nd", wfull, ys.astype(w.dtype))
-        return y.reshape(B, T, d).astype(x.dtype)
+        layer = lp.get("layer")
+        y, rows = held_experts_ffn(hf, w, topi, lp["w1"], lp["w3"],
+                                   lp["w2"], layer=layer,
+                                   grouped=layer is not None)
+    return y.reshape(B, T, d), rows
 
 
 def forward_eval(params, tokens, cfg: MixtralConfig, positions=None):
     """Cache-free inference forward: the training attention path with the
-    capacity-free dense MoE combine (no token drops).  This is what
-    kernel injection serves — the reference's eval-mode contract, where
-    generation quality must not depend on router load balance."""
+    capacity-free exact top-k FFN (no token drops; the scanned layers'
+    experts arrive as slices, so every expert evaluates every row).
+    This is what kernel injection serves — the reference's eval-mode
+    contract, where generation quality must not depend on router load
+    balance."""
     lcfg = cfg.llama_view()
     x, cos, sin = _embed(params, tokens, lcfg, positions)
 
@@ -286,7 +285,7 @@ def forward_eval(params, tokens, cfg: MixtralConfig, positions=None):
         x = _attn_block(cfg, lcfg, x, lp, cos, sin)
         with jax.named_scope("mlp"):
             h = _llama.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            return x + _moe_ffn_dense(cfg, h, lp), None
+            return x + expert_layer(cfg, h, lp)[0], None
 
     x, _ = jax.lax.scan(block, x, params["blocks"])
     return _llama._head(params, x, lcfg)
@@ -372,10 +371,15 @@ def loss_fn(cfg: MixtralConfig):
 
 
 def _out_moe(cfg: MixtralConfig, x, attn, lp):
-    """llama's attention output half, then the capacity-free dense top-k
-    expert combine as the FFN (inference must not drop tokens)."""
-    return _llama._out_ffn(cfg, x, attn, lp,
-                           ffn=lambda lp, h: _moe_ffn_dense(cfg, h, lp))
+    """llama's attention output half, then the capacity-free top-k
+    experts as the FFN (inference must not drop tokens) -> (x, rows
+    routed to each expert)."""
+    with jax.named_scope("attn_out"):
+        x = x + attn @ lp["wo"]
+    with jax.named_scope("mlp"):
+        h = _llama.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        y, rows = expert_layer(cfg, h, lp)
+        return x + y, rows
 
 
 def _check(cfg: MixtralConfig, mesh, max_seq: int) -> None:
@@ -390,10 +394,14 @@ def _check(cfg: MixtralConfig, mesh, max_seq: int) -> None:
 # [L, E, ...] expert FFNs shard over the expert axis (XLA inserts the expert
 # psum at the weighted combine), attention Megatron-style over model.  The
 # router stays exact under weight-only quantization (int8 gate logits could
-# flip a near-tied top-k choice) and so do the stacked norm gains.
+# flip a near-tied top-k choice) and so do the stacked norm gains.  On one
+# device over plain arrays the paged loop hands the experts' stacks over
+# whole, and the grouped product reads a layer of them in place.
 FAMILY = dataclasses.replace(
     _llama.FAMILY, config_type=MixtralConfig, out=_out_moe,
     head=lambda params, x, cfg: _llama._head(params, x, cfg.llama_view()),
     param_specs=param_specs,
     quant_skip_paths=("gate",) + _llama.FAMILY.quant_skip_paths,
-    shard_axes=("model", "expert"), check=_check)
+    shard_axes=("model", "expert"), check=_check,
+    expert_rows=lambda cfg: (cfg.num_experts, cfg.top_k * cfg.n_layers),
+    whole_stacks=("w1", "w3", "w2"))

@@ -188,30 +188,47 @@ def sigmoid_topk_route(h, gate, top_k: int, scale: float = 1.0,
         return top * scale, idx.astype(jnp.int32)
 
 
-# Rows (tokens) up to which every held expert evaluates every row: at 128
-# rows of 7,680 the dense products stream the 16 experts once in 2.3 ms
-# where the grouped ones take 3.0 (Mosaic) and 5.1 (XLA's ragged_dot): a
-# group of 4 rows still pays for a whole row tile.  At 1,024 rows it is
-# 8.9 ms against 4.1 and 7.6 (v5e, PERF.md 6, PR 33).
-_DENSE_HELD_ROWS = 256
-# (rows, contraction, columns) tile of the Mosaic grouped product
+# (rows, contraction, columns) tile of the Mosaic grouped product; the
+# last two are fitted to each operand (``fit`` below)
 _GMM_TILING = (256, 1920, 1024)
+
+
+def _every_row_pays(N: int, k: int, Eh: int) -> bool:
+    """Whether ``N`` rows routed ``k`` ways are cheaper with every one of
+    the ``Eh`` held experts on every row than sorted into groups: a rule
+    of the shapes alone.  Every expert on every row is ``N * Eh`` row
+    products; the grouped product is at most ``N * k`` (every pair held)
+    plus up to a row tile of padding an expert.  Both pay the same
+    6 d f a row product, so the widths cancel, and under a row tile of
+    rows both are one pass over the held weights, which the plain
+    products make without a sort and two gathers of wide rows.
+
+    On a v5e (ms for the three products; PERF.md 6, PR 34), 8 of 8
+    experts of 4096 x 14336, k = 2: 256 rows 4.3 plain against 4.9
+    grouped, 384 rows 6.0 against 5.5, 1,024 rows 16.0 against 8.3 (the
+    rule crosses at 341); 16 of 256 experts of 7680 x 2048, k = 8: 256
+    rows 2.4 against 2.6, 512 rows 4.5 against 2.9 (it crosses at 512,
+    late for a share: few of its N * k pairs are held, and nothing here
+    sees how many experts there are in all).
+    """
+    return N * (Eh - k) < Eh * _GMM_TILING[0]
 
 
 def _grouped_product(x, w, sizes, layer=None):
     """``x`` [M, K] in groups of ``sizes`` consecutive rows, group g
     against ``w[g]`` [K, N] -> [M, N]; rows past the groups are left
-    unspecified.  With ``layer``, ``w`` is the whole stack [L, G, K, N]
-    and the groups are layer ``layer``'s.  On a TPU the Mosaic grouped
-    kernel (JAX's megablox ``gmm``, whose row tile is ours to choose)
-    where its tiles divide the operands: it is handed the stack as L * G
-    groups of which all but the layer's are empty (it visits none of
-    them), because a layer sliced out of a scanned stack is a copy of
-    its 1.5 GB before a Mosaic call (18 ms of a 78 ms chunk program,
-    v5e, PR 33).  Elsewhere XLA's ``ragged_dot`` on the layer's slice."""
+    unspecified; M is whole row tiles.  With ``layer``, ``w`` is the
+    whole stack [L, G, K, N] and the groups are layer ``layer``'s.  On a
+    TPU the Mosaic grouped kernel (JAX's megablox ``gmm``, whose row tile
+    is ours to choose) where its tiles divide the widths: it is handed
+    the stack as L * G groups of which all but the layer's are empty (it
+    visits none of them), because a layer sliced out of a scanned stack
+    is a copy of its 1.5 GB before a Mosaic call (18 ms of a 78 ms chunk
+    program, v5e, PR 33).  Elsewhere XLA's ``ragged_dot`` on the layer's
+    slice."""
     tm, tk, tn = _GMM_TILING
-    (M, K), N = x.shape, w.shape[-1]
-    if jax.default_backend() == "tpu" and not (M % tm or K % 128 or N % 128):
+    K, N = x.shape[1], w.shape[-1]
+    if jax.default_backend() == "tpu" and not (K % 128 or N % 128):
         from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
         fit = lambda n, t: next(c for c in range(min(t, n), 0, -128)
@@ -227,10 +244,9 @@ def _grouped_product(x, w, sizes, layer=None):
 
 
 def held_experts_ffn(h, weights, experts, w1, w3, w2, first: int = 0,
-                     layer=None):
+                     layer=None, grouped: bool = True):
     """The part of a routed FFN that the experts held here contribute:
-    drop-free at any imbalance, static shapes.  (The sorted dispatch a
-    dense all-experts combine is the alternative to; ROADMAP S1.)
+    drop-free at any imbalance, static shapes.
 
     ``h`` [N, d]; ``weights``/``experts`` [N, k] from the router over all
     the experts there are; ``w1``/``w3`` [Eh, d, f] and ``w2`` [Eh, f, d]
@@ -243,10 +259,16 @@ def held_experts_ffn(h, weights, experts, w1, w3, w2, first: int = 0,
     held sort first, by expert, and the rest (what other ranks compute)
     fall past the last group, where the grouped product visits no tile.
     The row buffer is the worst case N * k: all of a token's experts may
-    be held.  Few rows (a decode step): each held expert evaluates every
-    row and the router's weight, zero where it did not choose the
-    expert, combines them; that reads each expert once, as the grouped
-    product would, without its row tiles.
+    be held.  Few rows (a decode step; :func:`_every_row_pays`): each
+    held expert evaluates every row and the router's weight, zero where
+    it did not choose the expert, combines them; that reads each expert
+    once, as the grouped product would, without its row tiles.
+
+    ``grouped=False`` is the caller's word that the weights are not
+    plain arrays held whole on one device (sharded over a mesh, which a
+    Mosaic call cannot be partitioned over; dequantised on the way in;
+    a layer's slice of a stack, which a Mosaic call would copy): every
+    held expert then evaluates every row at any row count.
     """
     N, k = experts.shape
     Eh = w1.shape[-3]
@@ -255,7 +277,7 @@ def held_experts_ffn(h, weights, experts, w1, w3, w2, first: int = 0,
     group = jnp.where(held, local, Eh)               # not held: sorts last
     sizes = jnp.zeros((Eh + 1,), jnp.int32).at[group].add(1)[:Eh]
     with jax.named_scope("moe_routed"):
-        if N <= _DENSE_HELD_ROWS:
+        if not grouped or _every_row_pays(N, k, Eh):
             if layer is not None:
                 w1, w3, w2 = w1[layer], w3[layer], w2[layer]
             gain = jnp.zeros((N, Eh + 1), jnp.float32).at[
@@ -266,7 +288,13 @@ def held_experts_ffn(h, weights, experts, w1, w3, w2, first: int = 0,
                              ys.astype(jnp.float32))
             return out.astype(h.dtype), sizes
         order = jnp.argsort(group)                   # stable
-        x = h[order // k]
+        # whole row tiles, a power of two of them: the rows added stand
+        # past every group (a gathered row each, no product), and
+        # programs of neighbouring row counts share one trace of the
+        # grouped product (0.17 s each on the chip's host, PERF.md 6)
+        tm = _GMM_TILING[0]
+        pad = tm * (1 << (-(-N * k // tm) - 1).bit_length()) - N * k
+        x = h[(jnp.pad(order, (0, pad)) if pad else order) // k]
         a = _grouped_product(x, w1, sizes, layer)
         b = _grouped_product(x, w3, sizes, layer)
         y = _grouped_product(jax.nn.silu(a) * b, w2, sizes, layer)

@@ -334,6 +334,57 @@ def test_serving_program_leaves_the_pool_in_place(chip, pool, phase):
     assert temp < pool_bytes / 2
 
 
+# ------------------------------------- docs-sat's chunk of 1,024 tokens
+# table entries -> bound on the temporaries in GiB.  Over the chunk's own
+# 64 pages the every-expert-every-row program held 0.228 GiB and this one
+# holds 0.126; over the full table both hold the f32 scores of 1,024
+# queries against 8,320 gathered keys, 1.040 and 1.049 GiB (AOT, PR 34).
+@pytest.mark.parametrize("table,temp_gib", [(64, 0.14), (520, 1.06)],
+                         ids=["first_chunk", "full_table"])
+def test_mixtral_chunk_program_groups_the_rows_by_expert(
+        chip, monkeypatch, table, temp_gib):
+    """``mixtral-8x7b-d4.serve.docs-sat``'s chunk program as the engine
+    builds it (the experts' rows counted): each layer's FFN is three
+    Mosaic grouped products over the 2,048 (row, expert) pairs the
+    router chose, read out of the whole stack in place; nothing shaped
+    like every expert's answer for every row ``[8, 1024, 14336]`` is
+    left and no layer's 2.8 GB of experts is copied out of the stack."""
+    # the grouped product asks the backend which kernel to run; the
+    # described chip is not the default backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, make_cfg, pages, _, _, _ = POOLS["mixtral_docs"]
+    cfg, T = make_cfg(), 1024
+    shape = (cfg.n_layers, cfg.n_kv_heads, pages, PAGE, DH)
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+        if hasattr(x, "shape") else x, tree)
+    params = jax.eval_shape(lambda: mixtral.init_params(
+        jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16))
+    kv = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    cache = K.PagedKVCache(
+        k=kv, v=kv, table=jax.ShapeDtypeStruct((1, table), jnp.int32),
+        seq_lens=jax.ShapeDtypeStruct((1,), jnp.int32), page_size=PAGE,
+        expert_rows=jax.ShapeDtypeStruct((cfg.num_experts,), jnp.int32))
+    forward = lambda continuation: lambda params, tokens, cache: \
+        forward_paged(params, tokens, cfg, cache, interpret=False,
+                      tp=False, continuation=continuation)
+    _, chunk, _, _, _ = serving_programs(
+        forward(False), forward(False), forward(True), _sample_rows,
+        decode_chunk=1, max_batch=1, expert_rows=True)
+    compiled = jax.jit(chunk, donate_argnums=(2,)).lower(*on_chip((
+        params, jax.ShapeDtypeStruct((1, T), jnp.int32), cache,
+        jax.ShapeDtypeStruct((1,), jnp.int32)))).compile()
+    hlo, memory = compiled.as_text(), compiled.memory_analysis()
+    assert len(re.findall(r"%gmm[\w.]* = .*tpu_custom_call", hlo)) == 3
+    assert _shaped_like(hlo, cfg.num_experts, T, cfg.ffn_dim) == []
+    assert _shaped_like(hlo, cfg.num_experts, cfg.dim, cfg.ffn_dim) == []
+    assert "dynamic-slice_bitcast_fusion" not in hlo
+    assert _pool_sized_ops(hlo, shape) == []
+    assert memory.temp_size_in_bytes <= temp_gib * 2 ** 30
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+
+
 # ---------------------------------------------- the latent family's cell
 # openpangu-ultra-moe-718b-ep16-d5.serve.think-sat as the benchmark builds
 # it: 1 dense + 4 expert layers at the published widths, 16 of 256 experts

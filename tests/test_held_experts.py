@@ -1,0 +1,227 @@
+"""Mixtral's serving FFN runs the experts a token chose (ISSUE 34): the
+router's choice goes through ``parallel/moe.py::held_experts_ffn`` with
+all the experts held, sorted and grouped above the row count where that
+pays and every expert on every row below it; the paged forward hands the
+experts' stacks over whole where they are plain arrays on one device."""
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.kernels import PagedKVCache
+from deepspeed_tpu.inference.paged_forward import forward_paged
+from deepspeed_tpu.inference.serving import serving_engine
+from deepspeed_tpu.models import mixtral
+from deepspeed_tpu.parallel import moe
+
+CFG = mixtral.MixtralConfig.tiny()            # 4 experts, top-2, 2 layers
+PATHS = {"every_expert_every_row": True, "grouped": False}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return mixtral.init_params(jax.random.PRNGKey(0), CFG)
+
+
+def _pin(monkeypatch, path):
+    monkeypatch.setattr(moe, "_every_row_pays",
+                        lambda N, k, Eh: PATHS[path])
+
+
+def _per_token_loop(h, w, experts, w1, w3, w2):
+    """A token at a time, an expert at a time, in float64."""
+    h, w, w1, w3, w2 = (np.asarray(a, np.float64) for a in (h, w, w1, w3, w2))
+    out = np.zeros_like(h)
+    for n, row in enumerate(h):
+        for j, e in enumerate(np.asarray(experts)[n]):
+            a = row @ w1[e]
+            out[n] += w[n, j] * ((a / (1 + np.exp(-a)) * (row @ w3[e]))
+                                 @ w2[e])
+    return out
+
+
+def _routing(case, N, rng):
+    E, k = CFG.num_experts, CFG.top_k
+    if case == "all_rows_on_one_expert":
+        experts = np.tile([2, 0], (N, 1))
+        w = np.tile([1.0, 0.0], (N, 1))
+    elif case == "an_expert_gets_no_row":
+        experts = np.stack([rng.permutation([0, 1, 3])[:k]
+                            for _ in range(N)])
+        w = rng.dirichlet(np.ones(k), N)
+    else:
+        experts = np.stack([rng.permutation(E)[:k] for _ in range(N)])
+        w = rng.dirichlet(np.ones(k), N)
+    return jnp.asarray(w, jnp.float32), jnp.asarray(experts, jnp.int32)
+
+
+@pytest.mark.parametrize("path", PATHS)
+@pytest.mark.parametrize("case", ["as_routed", "all_rows_on_one_expert",
+                                  "an_expert_gets_no_row"])
+def test_the_ffn_is_the_per_token_top_k_loop(monkeypatch, params, path,
+                                             case):
+    """Both sides of the rows rule against a plain loop, at Mixtral's
+    tiny widths: drop-free at any imbalance, and the rows counted are
+    the pairs each expert was routed."""
+    _pin(monkeypatch, path)
+    rng = np.random.default_rng(3)
+    lp = {k: params["blocks"][k][1] for k in ("w1", "w3", "w2")}
+    h = jnp.asarray(rng.normal(size=(40, CFG.dim)), jnp.float32)
+    w, experts = _routing(case, 40, rng)
+    y, rows = moe.held_experts_ffn(h, w, experts, lp["w1"], lp["w3"],
+                                   lp["w2"])
+    np.testing.assert_allclose(
+        y, _per_token_loop(h, w, experts, lp["w1"], lp["w3"], lp["w2"]),
+        atol=2e-5, rtol=2e-5)
+    assert rows.tolist() == np.bincount(
+        np.asarray(experts).ravel(), minlength=CFG.num_experts).tolist()
+    if case == "all_rows_on_one_expert":
+        assert rows.tolist() == [40, 0, 40, 0]
+    if case == "an_expert_gets_no_row":
+        assert rows[2] == 0
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_a_layer_of_the_whole_stack_is_the_sliced_call(monkeypatch, params,
+                                                       path):
+    _pin(monkeypatch, path)
+    rng = np.random.default_rng(4)
+    blocks = params["blocks"]
+    h = jnp.asarray(rng.normal(size=(24, CFG.dim)), jnp.float32)
+    w, experts = _routing("as_routed", 24, rng)
+    sliced = moe.held_experts_ffn(h, w, experts, blocks["w1"][1],
+                                  blocks["w3"][1], blocks["w2"][1])
+    whole = moe.held_experts_ffn(h, w, experts, blocks["w1"], blocks["w3"],
+                                 blocks["w2"], layer=jnp.int32(1))
+    np.testing.assert_allclose(whole[0], sliced[0], atol=1e-6)
+    assert whole[1].tolist() == sliced[1].tolist()
+
+
+# N rows routed k ways over Eh held experts -> every expert on every row?
+@pytest.mark.parametrize("N,k,Eh,every_row", [
+    (128, 8, 16, True), (1024, 8, 16, False),       # pangu's share, PR 33
+    (64, 2, 8, True), (1024, 2, 8, False),          # Mixtral: decode, chunk
+    (256, 8, 16, True), (512, 8, 16, False),        # as timed on the chip
+    (256, 2, 8, True), (384, 2, 8, False),
+    (4096, 2, 2, True)],                            # every expert chosen
+    ids=["pangu_decode", "pangu_chunk", "mixtral_decode", "mixtral_chunk",
+         "pangu_256", "pangu_512", "mixtral_256", "mixtral_384",
+         "all_experts_chosen"])
+def test_the_rows_rule_reads_shapes(N, k, Eh, every_row):
+    assert moe._every_row_pays(N, k, Eh) is every_row
+
+
+def _products(fn, *args):
+    return str(jax.make_jaxpr(fn)(*args)).count("ragged_dot")
+
+
+def test_grouped_false_keeps_every_expert_on_every_row(params):
+    """A caller whose weights are sharded, dequantised or a layer's slice
+    says so: no grouped product at a row count where the rule would
+    choose one."""
+    lp = {k: params["blocks"][k][0] for k in ("w1", "w3", "w2")}
+    N = 4 * moe._GMM_TILING[0]
+    assert not moe._every_row_pays(N, CFG.top_k, CFG.num_experts)
+    h = jnp.zeros((N, CFG.dim), jnp.float32)
+    w, experts = _routing("as_routed", N, np.random.default_rng(5))
+    run = lambda grouped: _products(
+        lambda h: moe.held_experts_ffn(h, w, experts, lp["w1"], lp["w3"],
+                                       lp["w2"], grouped=grouped), h)
+    assert run(True) and not run(False)
+
+
+def test_rows_that_fill_no_whole_tile_are_padded_past_the_groups(
+        monkeypatch, params):
+    """40 pairs against a row tile of 16: the buffer grows to 64 rows (a
+    power of two of whole tiles) and the rows added stand behind every
+    group."""
+    _pin(monkeypatch, "grouped")
+    monkeypatch.setattr(moe, "_GMM_TILING", (16, 128, 128))
+    rng = np.random.default_rng(6)
+    lp = {k: params["blocks"][k][0] for k in ("w1", "w3", "w2")}
+    h = jnp.asarray(rng.normal(size=(20, CFG.dim)), jnp.float32)
+    w, experts = _routing("as_routed", 20, rng)
+    jaxpr = str(jax.make_jaxpr(lambda h: moe.held_experts_ffn(
+        h, w, experts, lp["w1"], lp["w3"], lp["w2"]))(h))
+    assert f"f32[64,{CFG.dim}]" in jaxpr
+    y, _ = moe.held_experts_ffn(h, w, experts, lp["w1"], lp["w3"], lp["w2"])
+    np.testing.assert_allclose(
+        y, _per_token_loop(h, w, experts, lp["w1"], lp["w3"], lp["w2"]),
+        atol=2e-5, rtol=2e-5)
+
+
+# ------------------------------------------- the paged forward's part
+def _cache(rows=2, pages=9, ps=8):
+    shape = (CFG.n_layers, CFG.n_kv_heads, pages, ps, CFG.head_dim)
+    table = np.arange(rows * 4).reshape(rows, 4) % (pages - 1)
+    return PagedKVCache(
+        k=jnp.zeros(shape, jnp.float32), v=jnp.zeros(shape, jnp.float32),
+        table=jnp.asarray(table, jnp.int32),
+        seq_lens=jnp.zeros((rows,), jnp.int32), page_size=ps,
+        expert_rows=jnp.zeros((CFG.num_experts,), jnp.int32))
+
+
+@pytest.mark.parametrize("kw,whole", [
+    (dict(tp=False), True), (dict(tp=True), False),
+    (dict(tp=False, resident=False), False)],
+    ids=["one_device_plain_arrays", "sharded", "dequantised_inside"])
+def test_forward_paged_honours_whole_stacks_without_a_lead(
+        monkeypatch, params, kw, whole):
+    """Mixtral states ``whole_stacks`` and has no leading stack: its
+    ``out`` gets the experts [L, E, ...] and the layer's index where they
+    are resident on one device, and a layer's slice (no index: every
+    expert on every row) elsewhere; the logits are the same."""
+    seen = []
+
+    def spy(cfg, x, attn, lp):
+        seen.append((lp["w1"].ndim, "layer" in lp))
+        return mixtral._out_moe(cfg, x, attn, lp)
+
+    assert mixtral.FAMILY.lead is None
+    assert mixtral.FAMILY.whole_stacks == ("w1", "w3", "w2")
+    monkeypatch.setattr(mixtral, "FAMILY",
+                        dataclasses.replace(mixtral.FAMILY, out=spy))
+    tokens = jnp.asarray(np.random.default_rng(7).integers(
+        0, CFG.vocab_size, (2, 16)), jnp.int32)
+    logits, cache = forward_paged(params, tokens, CFG, _cache(),
+                                  interpret=True, paged_kernel="xla", **kw)
+    assert seen == [(4, True) if whole else (3, False)]
+    np.testing.assert_allclose(
+        logits, mixtral.forward_eval(params, tokens, CFG), atol=2e-4,
+        rtol=2e-4)
+    # 2 x 16 rows, top-2, two layers
+    assert int(cache.expert_rows.sum()) == 2 * 16 * 2 * CFG.n_layers
+
+
+def test_the_engines_counters_add_up(params):
+    """All the experts are held: the rows counted for the experts are
+    every pair the programs routed.  The counts ride in the decode
+    program's own fetch: ``test_step_dispatch.py`` holds a Mixtral
+    engine's plain step to one program."""
+    eng = serving_engine(params, CFG, max_batch=3, page_size=8,
+                         num_pages=40, max_seq=64, prefill_bucket=8,
+                         telemetry=True)
+    for i, n in enumerate((5, 21, 12)):
+        eng.submit(i, list(range(3, 3 + n)), max_new_tokens=5)
+    eng.run()
+    counters = eng.registry.snapshot()["counters"]
+    held = [counters[f"serving_expert_rows_{e}"]
+            for e in range(CFG.num_experts)]
+    assert sum(held) == counters["serving_routed_rows"] > 0
+    assert counters["serving_routed_rows"] % (CFG.top_k * CFG.n_layers) == 0
+    assert eng.check_leaks() == []
+
+
+def test_a_speculating_engine_counts_nothing(params):
+    """Its steady program is the verify sweep, which has no fetch for
+    the counts to ride in."""
+    eng = serving_engine(params, CFG, max_batch=2, page_size=8,
+                         num_pages=40, max_seq=64, prefill_bucket=8,
+                         telemetry=True, speculative={"draft_tokens": 2})
+    assert eng.cache.expert_rows is None
+    eng.submit(0, [5, 9, 2, 7], max_new_tokens=4)
+    assert len(eng.run()[0]) == 8

@@ -61,10 +61,10 @@ def _layered(family, cfg, quant):
     else:
         mod = llama
         lcfg = cfg.llama_view() if family is mixtral else cfg
-        ffn = ((lambda lp, h: mixtral._moe_ffn_dense(cfg, h, lp))
-               if family is mixtral else None)
         qkv = lambda lp, x, aux: llama._qkv(lcfg, x, lp, *aux)
-        out = lambda lp, x, attn: llama._out_ffn(lcfg, x, attn, lp, ffn=ffn)
+        out = ((lambda lp, x, attn: mixtral._out_moe(cfg, x, attn, lp)[0])
+               if family is mixtral
+               else (lambda lp, x, attn: llama._out_ffn(lcfg, x, attn, lp)))
 
         def stem(p, tokens, start):
             pos = start[:, None] + jnp.arange(tokens.shape[1])[None]

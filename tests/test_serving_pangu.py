@@ -216,7 +216,7 @@ def test_latent_flash_kernel_is_the_reference(start):
 
 
 # ------------------------- (iii)-(v) the expert layer that holds a share
-PATHS = {"every_expert_every_row": 10 ** 9, "grouped": 0}
+PATHS = {"every_expert_every_row": True, "grouped": False}
 
 
 def _expert_case(rng, N, d=32, f=16, E=32):
@@ -233,7 +233,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(monkeypatch, path):
     compute (router over all 32, each rank its own two experts) plus the
     shared expert counted once are the uncut reference's expert
     layer."""
-    monkeypatch.setattr(moe, "_DENSE_HELD_ROWS", PATHS[path])
+    monkeypatch.setattr(moe, "_every_row_pays", lambda *a: PATHS[path])
     h, gate, lp = _expert_case(np.random.default_rng(6), N=24)
     w, idx, _ = reference.route(h, gate, 8, 2.5, True)
     uncut = reference.held_part(h, lp, 0, w, idx, 0) \
@@ -255,7 +255,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(monkeypatch, path):
 def test_drop_free_under_imbalance(monkeypatch, path, case):
     """Every token routed to ONE held expert (and seven absent ones):
     none is dropped; no token routed to any held expert: zeros."""
-    monkeypatch.setattr(moe, "_DENSE_HELD_ROWS", PATHS[path])
+    monkeypatch.setattr(moe, "_every_row_pays", lambda *a: PATHS[path])
     h, _, lp = _expert_case(np.random.default_rng(7), N=40)
     held = slice(4, 8)
     absent = np.arange(16, 23)
@@ -278,7 +278,7 @@ def test_drop_free_under_imbalance(monkeypatch, path, case):
 def test_the_layer_index_reads_the_whole_stack(monkeypatch):
     """With ``layer``, the weights are the stacks [L, Eh, ...]: what a
     paged layer loop hands the expert layer (``whole_stacks``)."""
-    monkeypatch.setattr(moe, "_DENSE_HELD_ROWS", 0)
+    monkeypatch.setattr(moe, "_every_row_pays", lambda *a: False)
     rng = np.random.default_rng(9)
     h, gate, lp = _expert_case(rng, N=12, E=4)
     stack = lambda a: jnp.concatenate([a * 0 + 7.0, a])     # layer 1 is it
@@ -346,7 +346,9 @@ def test_the_contiguous_cache_generators_are_refused(params):
 def test_the_other_families_state_nothing_new():
     """gpt2, llama and mixtral leave the seam's new fields at their
     defaults: per-head K and V rows, no leading stack, no latent form,
-    no counted experts, nothing refused."""
+    nothing refused; the dense two count no experts and hand no stack
+    over whole, mixtral counts all of its experts' rows and hands over
+    their stacks (ISSUE 34)."""
     from deepspeed_tpu.models.family import decoder_families
 
     for fam in decoder_families():
@@ -357,8 +359,12 @@ def test_the_other_families_state_nothing_new():
         assert fam.cache_row(cfg) == CacheRow(cfg.n_kv_heads, cfg.head_dim,
                                               cfg.head_dim, False)
         assert fam.cache_row(cfg).pool_width == cfg.head_dim
-        assert (fam.lead, fam.latent, fam.refuses, fam.whole_stacks,
-                fam.expert_rows(cfg)) == (None, None, (), (), (0, 0))
+        assert (fam.lead, fam.latent, fam.refuses) == (None, None, ())
+        sparse = hasattr(cfg, "num_experts")
+        assert fam.whole_stacks == (("w1", "w3", "w2") if sparse else ())
+        assert fam.expert_rows(cfg) == (
+            (cfg.num_experts, cfg.top_k * cfg.n_layers) if sparse
+            else (0, 0))
 
 
 # ------------------------------------------------ names in the programs
