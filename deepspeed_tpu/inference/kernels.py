@@ -31,6 +31,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# what a recurrent layer's per-slot state is kept in (``PagedKVCache.
+# state``): every token's update is rounded to it
+STATE_DTYPE = jnp.float32
 
 
 # ------------------------------------------------------------- page store
@@ -44,10 +47,25 @@ class PagedKVCache(NamedTuple):
       first C numbers it reads as values, so ``v`` is None (a family
       whose ``cache_row`` says ``values_in_keys``).
 
+    The pool's leading dimension counts the layers that ATTEND over
+    pages, which is not the model's depth where a family has recurrent
+    layers (``DecoderFamily.recurrent``): those keep, a SLOT and not a
+    page, ``conv`` [L_rec, B, rows, channels] and ``state`` [L_rec, B,
+    heads, Dk, Dv] float32 beside the pool, B the engine's slots.
+
     table: [B, max_pages] int32 page ids; seq_lens: [B] int32 valid
     token counts.  ``expert_rows`` ([Eh] int32, or None): rows routed to
     each held expert that the programs have added up since the last
     decode program handed the sum out (a family with ``expert_rows``).
+
+    ``real`` ([B] int32, or None: all) is how many of the T tokens a
+    forward is handed are real, a row: a serving program sets it where
+    the cache carries ``state`` (a prefill or chunk its last real
+    position + 1; a decode step 1 for a live row and 0 for a slot that
+    is idle or between two chunks of its prompt, which length 0 marks),
+    and the forward hands the cache back without it.  ``slot`` ([1]
+    int32): which slot's state the one row of a prefill's or chunk's
+    private view stands for; None where the rows are the slots.
     """
 
     k: jnp.ndarray
@@ -61,6 +79,10 @@ class PagedKVCache(NamedTuple):
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
     expert_rows: Optional[jnp.ndarray] = None
+    conv: Optional[jnp.ndarray] = None
+    state: Optional[jnp.ndarray] = None
+    real: Optional[jnp.ndarray] = None
+    slot: Optional[jnp.ndarray] = None
 
     @classmethod
     def alloc(cls, n_layers: int, n_kv: int, num_pages: int, page_size: int,
@@ -1841,3 +1863,46 @@ def paged_layer_loop(block, x, blocks, cache: PagedKVCache,
         (blocks, layers + first if first else layers))
     return x, cache._replace(k=k, v=v, k_scale=ks, v_scale=vs,
                              expert_rows=rows)
+
+
+def state_rows(conv, state, layer, slot):
+    """Recurrent layer ``layer``'s (conv, state) of the rows a forward
+    runs: every slot's (``slot`` None), or the one slot's that a
+    one-row view stands for."""
+    if slot is None:
+        return tuple(jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
+                     for a in (conv, state))
+    at = lambda a: (layer, slot[0]) + (0,) * (a.ndim - 2)
+    return tuple(jax.lax.dynamic_slice(a, at(a), (1, 1) + a.shape[2:])[0]
+                 for a in (conv, state))
+
+
+def write_state_rows(conv, state, layer, slot, new):
+    """The rows' new (conv, state) into the carried buffers, in place."""
+    at = lambda a: (layer, 0 if slot is None else slot[0]) \
+        + (0,) * (a.ndim - 2)
+    return tuple(jax.lax.dynamic_update_slice(a, n[None].astype(a.dtype),
+                                              at(a))
+                 for a, n in zip((conv, state), new))
+
+
+def paged_period_loop(period, x, stacks, cache: PagedKVCache, periods: int):
+    """:func:`paged_layer_loop` for a family whose layers come in
+    periods of two kinds: ``period(x, lps, p, kp, vp, rows, conv, state)
+    -> (x, kp, vp, rows, conv, state)`` runs period ``p``'s layers,
+    ``stacks`` the stacked params the loop slices a period out of
+    (``[periods, ...]`` leaves; a kind whose layers run in a loop of
+    their own inside ``period`` takes its layers out of its own stack).
+    The pool and the per-slot state ride in the carry, so every layer
+    updates the same buffers."""
+    def body(carry, xs):
+        x, held = carry
+        x, *held = period(x, *xs, *held)
+        return (x, tuple(held)), None
+
+    (x, (k, v, rows, conv, state)), _ = jax.lax.scan(
+        body, (x, (cache.k, cache.v, cache.expert_rows, cache.conv,
+                   cache.state)),
+        (stacks, jnp.arange(periods, dtype=jnp.int32)))
+    return x, cache._replace(k=k, v=v, expert_rows=rows, conv=conv,
+                             state=state)

@@ -12,6 +12,7 @@ generators, the drafter and the hybrid engine.
 
 from __future__ import annotations
 
+import itertools
 from typing import Optional
 
 import jax
@@ -19,7 +20,9 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.inference.kernels import (latent_attention_step,
                                              paged_attention_step,
-                                             paged_layer_loop, paged_reader)
+                                             paged_layer_loop,
+                                             paged_period_loop, paged_reader,
+                                             state_rows, write_state_rows)
 from deepspeed_tpu.inference.quantized import dequantize_params
 from deepspeed_tpu.models.family import decoder_family
 
@@ -52,12 +55,106 @@ def _paged_block(fam, out, cfg, x, lp, ctx, layer, kp, vp, kps, vps, rows,
     return x, kp, vp, kps, vps, rows
 
 
+def _forward_periods(fam, params, x, cfg, cache, block, *, whole: bool):
+    """The layers of a family with recurrent layers
+    (``DecoderFamily.recurrent``), whole periods at a time: an attention
+    layer is ``block`` (:func:`_paged_block` over the pool, whose
+    leading dimension counts the attention layers alone); a recurrent
+    layer reads its rows' state beside the pool, mixes, and writes it
+    back.  ``cache.real`` says how many tokens of each row may move a
+    state; a row that starts at position 0 starts from zero state,
+    whatever its slot held."""
+    rec = fam.recurrent
+    kinds = rec.period(cfg)
+    n_rec = sum(kinds)
+    n_att = len(kinds) - n_rec
+    B, T = x.shape[:2]
+    start, slot = cache.seq_lens, cache.slot
+    real = (jnp.full((B,), T, jnp.int32) if cache.real is None
+            else cache.real)
+    periods = cache.k.shape[0] // n_att
+
+    def split(stack):
+        held = {k: stack[k] for k in fam.whole_stacks} if whole else {}
+        return held, {k: v for k, v in stack.items() if k not in held}
+
+    # the recurrent layers' stack stays whole: their loop takes a layer
+    # out of it by its index (sliced a period at a time by the outer
+    # loop, a period's weights were copied once more: 150 MB of one
+    # projection a period, v5e, PR 35)
+    rec_whole, rec_stack = split(params[rec.key])
+    att_whole, att_stack = split(params["blocks"])
+    att_stack = {k: v.reshape((periods, n_att) + v.shape[1:])
+                 for k, v in att_stack.items()}
+
+    def recurrent_layer(carry, layer):
+        x, rows, conv, state = carry
+        lp = {k: jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
+              for k, v in rec_stack.items()}
+        if rec_whole:
+            lp = dict(lp, **rec_whole, layer=layer)
+        held = state_rows(conv, state, layer, slot)
+        if T > 1:
+            first = start == 0
+            held = tuple(jnp.where(
+                first.reshape((B,) + (1,) * (a.ndim - 1)), 0, a)
+                for a in held)
+        y, held = rec.mix(cfg, x, lp, held, real)
+        with jax.named_scope("kv_write"), jax.named_scope("gdn_write"):
+            conv, state = write_state_rows(conv, state, layer, slot, held)
+        x, routed = rec.out(cfg, x, y, lp)
+        return (x, None if rows is None else rows + routed, conv,
+                state), None
+
+    # consecutive layers of one kind: [(recurrent?, how many), ...]
+    runs = [(kind, len(list(same))) for kind, same in
+            itertools.groupby(kinds)]
+
+    def period(x, att, p, kp, vp, rows, conv, state):
+        i_rec = i_att = 0
+        for recurrent, n in runs:
+            if recurrent:
+                # a loop of their own: each iteration reads its layer's
+                # state and updates the carried buffer once.  Unrolled,
+                # layer i + 1 read the buffer layer i had just updated
+                # inside one loop body, and under the memory pressure of
+                # a full chip the compiler rematerialised layer i's
+                # in-place update from the buffer it had already
+                # overwritten: the state moved twice a step (v5e, PR 35)
+                (x, rows, conv, state), _ = jax.lax.scan(
+                    recurrent_layer, (x, rows, conv, state),
+                    p * n_rec + i_rec + jnp.arange(n, dtype=jnp.int32))
+                i_rec += n
+                continue
+            for _ in range(n):
+                layer = p * n_att + i_att
+                lp = {k: v[i_att] for k, v in att.items()}
+                if att_whole:
+                    lp = dict(lp, **att_whole, layer=layer)
+                x, kp, vp, _, _, rows = block(x, lp, layer, kp, vp, None,
+                                              None, rows)
+                i_att += 1
+        return x, kp, vp, rows, conv, state
+
+    x, cache = paged_period_loop(period, x, att_stack, cache, periods)
+    return x, cache._replace(
+        seq_lens=start + jnp.where(real > 0, T, 0), real=None)
+
+
 def forward_paged(params, tokens, cfg, cache, *,
                   continuation: bool = False, tp: Optional[bool] = None,
                   interpret: Optional[bool] = None,
                   paged_kernel: Optional[str] = None,
                   resident: bool = True):
     """Forward over a paged KV cache.  tokens: [B, T] → (logits, cache).
+
+    The pool's leading dimension is the layers that attend over pages:
+    the model's depth for most families, the attention layers alone for
+    one with recurrent layers (``DecoderFamily.recurrent``), whose
+    per-slot state the cache carries beside the pool with ``real``, the
+    rows' real token counts (:class:`~deepspeed_tpu.inference.kernels.
+    PagedKVCache`).  Such a cache comes back with ``real`` consumed and
+    the lengths of rows that had no real token left as they were.
 
     ``tp``: True = params/cache are sharded over the mesh, so every
     pallas path (paged kernels AND the prefill flash kernel) must yield
@@ -133,6 +230,11 @@ def forward_paged(params, tokens, cfg, cache, *,
 
         return run
 
+    if fam.recurrent is not None:
+        x, cache = _forward_periods(
+            fam, params, x, cfg, cache, block(fam.out),
+            whole=resident and not tp)
+        return fam.head(params, x, cfg), cache
     n_lead = 0
     if fam.lead is not None:
         # a leading stack of another layer kind, then the family's own,

@@ -99,8 +99,8 @@ from deepspeed_tpu.devprof import NULL_DEVPROF, DevProf
 from deepspeed_tpu.faults import ChecksumError, FaultPlan, InjectedFault
 from deepspeed_tpu.history import NULL_HISTORY, MetricHistory
 from deepspeed_tpu.incidents import NULL_INCIDENTS, IncidentManager
-from deepspeed_tpu.inference.kernels import (PagedKVCache, PageAllocator,
-                                             latent_reader,
+from deepspeed_tpu.inference.kernels import (STATE_DTYPE, PagedKVCache,
+                                             PageAllocator, latent_reader,
                                              resolve_serving_kernels)
 from deepspeed_tpu.inference.paged_forward import forward_paged
 from deepspeed_tpu.inference.prefix_cache import (extend_page_keys,
@@ -169,7 +169,7 @@ def boundary_program(sample):
 
 def serving_programs(prefill_fn, decode_fn, chunk_prefill_fn, sample,
                      decode_chunk: int, max_batch: int,
-                     expert_rows: bool = False):
+                     expert_rows: bool = False, state: bool = False):
     """The serving programs over a model's forwards ``(params, tokens,
     cache) -> (logits, cache)``, not yet jitted: ``(dstpu_prefill,
     dstpu_chunk, dstpu_boundary, dstpu_sweep, dstpu_decode)``.  The
@@ -182,15 +182,24 @@ def serving_programs(prefill_fn, decode_fn, chunk_prefill_fn, sample,
     categorical math, so flipping the policy can never change a served
     greedy stream.  ``expert_rows``: the cache carries a family's count
     of rows routed to its held experts, and the decode program returns
-    it flat behind its tokens (``[B * K + Eh]`` int32)."""
+    it flat behind its tokens (``[B * K + Eh]`` int32).  ``state``: the
+    cache carries a per-slot recurrent state, which only real tokens may
+    move, so each program tells the forward which of its rows are real
+    (``cache.real``): a prefill or chunk the tokens up to ``last``, a
+    decode step the rows whose length is not 0 (an idle slot's, and a
+    slot's between two chunks of its prompt, is)."""
+    def real_upto(cache, last):
+        return cache._replace(real=last + 1) if state else cache
+
     # A prefill returns its last row (_last_row): the model's contract
     # stays, the slice is the engine's.
     def dstpu_prefill(params, tokens, cache, last):
-        logits, cache = prefill_fn(params, tokens, cache)
+        logits, cache = prefill_fn(params, tokens, real_upto(cache, last))
         return _last_row(logits, last), cache
 
     def dstpu_chunk(params, tokens, cache, last):
-        logits, cache = chunk_prefill_fn(params, tokens, cache)
+        logits, cache = chunk_prefill_fn(params, tokens,
+                                         real_upto(cache, last))
         return _last_row(logits, last), cache
 
     # the speculative verify sweep: logits at every position
@@ -208,6 +217,8 @@ def serving_programs(prefill_fn, decode_fn, chunk_prefill_fn, sample,
 
         def one(carry, key_k):
             t, c = carry
+            if state:
+                c = c._replace(real=(c.seq_lens > 0).astype(jnp.int32))
             logits, c = decode_fn(params, t, c)
             with jax.named_scope("sample"):
                 nxt = sample(logits[:, -1], key_k, temps)
@@ -377,7 +388,8 @@ class ServingEngine:
                  replica_id: Optional[str] = None,
                  history=None, incidents=None, kernels=None,
                  devprof=None, comm=None, values_in_keys: bool = False,
-                 expert_rows: int = 0, routed_per_row: int = 0):
+                 expert_rows: int = 0, routed_per_row: int = 0,
+                 state_row=None):
         # what a token's cache row is: per-head K and V pools, or one
         # pool whose rows are keys and values both (a latent family's
         # ``cache_row``); and how many held experts' routed rows the
@@ -386,6 +398,9 @@ class ServingEngine:
         self._values_in_keys = bool(values_in_keys)
         self._n_expert_rows = int(expert_rows)
         self._routed_per_row = int(routed_per_row)
+        # what a slot keeps beside its pages, a recurrent layer (a
+        # family's ``Recurrent.state_row``; None: nothing)
+        self._state_row = state_row
         # Sharded serving (ref: deepspeed/module_inject/replace_module.py
         # TP injection + deepspeed/moe/sharded_moe.py expert-parallel
         # inference): with a mesh, params arrive pre-sharded from the
@@ -601,6 +616,19 @@ class ServingEngine:
             "serving_routed_rows",
             "(row, expert) pairs routed: rows x top-k x expert layers")
         self._rows_pending = 0
+        self._c_state_fresh = r.counter(
+            "serving_state_fresh_starts",
+            "first chunks that started a slot's recurrent state from zero")
+        self._c_state_masked = r.counter(
+            "serving_state_rows_masked",
+            "rows of decode steps whose recurrent state was held still "
+            "(idle slots, and slots between two chunks of a prompt)")
+        self._g_state_bytes = r.gauge(
+            "serving_state_cache_bytes",
+            "bytes of the per-slot recurrent state beside the page pool")
+        self._g_state_live = r.gauge(
+            "serving_state_live_slots",
+            "slots whose recurrent state belongs to a request")
         self._g_queue = r.gauge(
             "serving_queue_depth", "requests waiting for a slot")
         self._g_occupancy = r.gauge(
@@ -1151,6 +1179,15 @@ class ServingEngine:
         seq_lens = self._put(jnp.zeros((self.max_batch,), jnp.int32))
         expert_rows = (self._put(np.zeros((self._n_expert_rows,), np.int32))
                        if self._n_expert_rows else None)
+        conv = state = None
+        if self._state_row is not None:
+            # indexed by slot, not by page: a slot's state weighs the
+            # same whatever its sequence's length
+            sr = self._state_row
+            conv = self._put(np.zeros(
+                (sr.layers, self.max_batch) + sr.conv, cache_dtype))
+            state = self._put(np.zeros(
+                (sr.layers, self.max_batch) + sr.state, STATE_DTYPE))
         if self._quant_resident:
             # int8-resident pages: codes replace the dense planes
             # (~2x the pages per HBM byte at bf16, 4x at f32) and a
@@ -1175,14 +1212,32 @@ class ServingEngine:
                 cache_dtype)),
             table=table, seq_lens=seq_lens,
             page_size=page_size,
-            expert_rows=expert_rows)
+            expert_rows=expert_rows, conv=conv, state=state)
+
+    def _row_view(self, table_row, seq_len: int, b: int) -> PagedKVCache:
+        """The private one-row view a prefill or chunk program works
+        on: the engine's buffers under the row's own table (from the
+        HOST copy: a device slice can alias the live table buffer, which
+        the program's donation would delete under the decode path) and
+        length, and, where slots keep a recurrent state, the slot
+        (``b``) whose state the row is."""
+        return PagedKVCache(
+            k=self.cache.k, v=self.cache.v,
+            expert_rows=self.cache.expert_rows,
+            conv=self.cache.conv, state=self.cache.state,
+            slot=(None if self._state_row is None
+                  else self._put(np.full((1,), b, np.int32))),
+            table=self._put(table_row),
+            seq_lens=self._put(np.full((1,), seq_len, np.int32)),
+            page_size=self.page_size)
 
     def _adopt(self, view: PagedKVCache) -> PagedKVCache:
         """The engine's cache with the buffers a prefill or chunk
         program returned in ``view`` (its table and lengths were the
         call's own)."""
         return self.cache._replace(k=view.k, v=view.v,
-                                   expert_rows=view.expert_rows)
+                                   expert_rows=view.expert_rows,
+                                   conv=view.conv, state=view.state)
 
     def _build_programs(self, prefill_fn, decode_fn,
                         chunk_prefill_fn) -> None:
@@ -1193,7 +1248,8 @@ class ServingEngine:
          dstpu_decode) = serving_programs(
             prefill_fn, decode_fn, chunk_prefill_fn, self._sample_fn,
             self.decode_chunk, self.max_batch,
-            expert_rows=bool(self._n_expert_rows))
+            expert_rows=bool(self._n_expert_rows),
+            state=self._state_row is not None)
         self._prefill = jax.jit(dstpu_prefill, donate_argnums=(2,))
         self._chunk_prefill = (jax.jit(dstpu_chunk, donate_argnums=(2,))
                                if chunk_prefill_fn is not None else None)
@@ -1284,12 +1340,7 @@ class ServingEngine:
             ends = sorted({min(i * bkt, row)
                            for i in range(1, -(-row // bkt) + 1)})
             for end in ends:
-                view = PagedKVCache(
-                    k=self.cache.k, v=self.cache.v,
-                    expert_rows=self.cache.expert_rows,
-                    table=self._put(self._table_host[0:1]),
-                    seq_lens=self._put(zi((1,), np.int32)),
-                    page_size=self.page_size)
+                view = self._row_view(self._table_host[0:1], 0, 0)
                 logits_row, view = self._prefill(
                     self.params, self._put(zi((1, end), np.int32)),
                     view, last)
@@ -1309,12 +1360,7 @@ class ServingEngine:
                 w *= 2
             widths.append(self.max_pages_per_seq)
             for w in widths:
-                view = PagedKVCache(
-                    k=self.cache.k, v=self.cache.v,
-                    expert_rows=self.cache.expert_rows,
-                    table=self._put(self._table_host[0:1, :w]),
-                    seq_lens=self._put(zi((1,), np.int32)),
-                    page_size=self.page_size)
+                view = self._row_view(self._table_host[0:1, :w], 0, 0)
                 logits_row, view = self._chunk_prefill(
                     self.params, self._put(zi((1, C), np.int32)),
                     view, last)
@@ -2009,16 +2055,8 @@ class ServingEngine:
 
             toks = np.full((1, end), 0, np.int32)
             toks[0, :T] = req.tokens
-            # table row from the HOST copy: a [b:b+1] device slice can
-            # alias the live table buffer (full-range slice), which
-            # prefill's cache donation would then delete out from under
-            # the decode path
-            view = PagedKVCache(
-                k=self.cache.k, v=self.cache.v,
-                expert_rows=self.cache.expert_rows,
-                table=self._put(self._table_host[b:b + 1]),
-                seq_lens=self._put(np.zeros((1,), np.int32)),
-                page_size=self.page_size)
+            view = self._row_view(self._table_host[b:b + 1], 0, b)
+            self._c_state_fresh.inc(self._state_row is not None)
             row, view = self._prefill(
                 self.params, self._put(toks), view,
                 self._put(np.full((1,), T - 1, np.int32)))
@@ -2604,12 +2642,10 @@ class ServingEngine:
         while np_bkt < np_live:
             np_bkt *= 2
         np_bkt = min(np_bkt, self.max_pages_per_seq)
-        view = PagedKVCache(
-            k=self.cache.k, v=self.cache.v,
-            expert_rows=self.cache.expert_rows,
-            table=self._put(self._table_host[b:b + 1, :np_bkt]),
-            seq_lens=self._put(np.full((1,), done, np.int32)),
-            page_size=self.page_size)
+        view = self._row_view(self._table_host[b:b + 1, :np_bkt], done, b)
+        # a chunk that starts at position 0 starts the slot's recurrent
+        # state from zero, whatever the slot held
+        self._c_state_fresh.inc(self._state_row is not None and done == 0)
         row, view = self._chunk_prefill(
             self.params, self._put(toks), view,
             self._put(np.full((1,), take - 1, np.int32)))
@@ -2948,6 +2984,9 @@ class ServingEngine:
                 self._c_decode_syncs.inc()
                 self._c_kdisp_paged.inc()
                 self._c_kdisp_sample.inc(K)
+                if self._state_row is not None:
+                    self._c_state_masked.inc(
+                        K * (self.max_batch - len(active)))
             with self._sp_token_sync:
                 if self._devprof_on and self.devprof.should_sample(
                         "decode"):
@@ -3005,6 +3044,11 @@ class ServingEngine:
         # reclaimable on demand, so it does not count as utilized
         self._g_kv_util.set(
             (usable - self.allocator.available) / max(usable, 1))
+        if self._state_row is not None:
+            self._g_state_bytes.set(self.cache.conv.nbytes
+                                    + self.cache.state.nbytes)
+            self._g_state_live.set(
+                sum(1 for s in self.slots if s is not None))
         if self._pc_on:
             ev = self.allocator.evicted
             if ev > self._evicted_seen:
@@ -3311,6 +3355,19 @@ class ServingEngine:
                     1.0 - valid_tokens / mapped_capacity, 4)
                 if mapped_capacity else 0.0,
             },
+            # the per-slot recurrent state beside the pages (a family
+            # with recurrent layers): what it weighs, and how many
+            # slots' states are in use
+            "cache.state": {
+                "bytes": int(self.cache.conv.nbytes
+                             + self.cache.state.nbytes),
+                "bytes_per_slot": int(
+                    (self.cache.conv.nbytes + self.cache.state.nbytes)
+                    // self.max_batch),
+                "live_slots": sum(1 for s in self.slots if s is not None),
+                "fresh_starts": int(self._c_state_fresh.value),
+                "rows_masked": int(self._c_state_masked.value),
+            } if self._state_row is not None else None,
             "prefix_cache": {
                 "enabled": self._pc_on,
                 "warm_pool_pages": len(al.pool),
@@ -3854,6 +3911,12 @@ def serving_engine(params, cfg, **kw):
                 params, fam.param_specs(cfg), mesh)
 
     row = fam.cache_row(cfg)
+    n_layers = cfg.n_layers
+    if fam.recurrent is not None:
+        # the pool has the layers that attend over pages; the others
+        # keep a state a slot beside it
+        kw["state_row"] = fam.recurrent.state_row(cfg)
+        n_layers -= kw["state_row"].layers
     held, per_row = fam.expert_rows(cfg)
     # the counts ride in the decode program's fetch; a speculating
     # engine's steady program is the verify sweep, which has none
@@ -3862,7 +3925,7 @@ def serving_engine(params, cfg, **kw):
     if row.values_in_keys:
         kw["values_in_keys"] = True
     eng = ServingEngine(
-        params, step, step, n_layers=cfg.n_layers, n_kv=row.n_kv,
+        params, step, step, n_layers=n_layers, n_kv=row.n_kv,
         head_dim=row.pool_width, chunk_prefill_fn=chunk_step, mesh=mesh,
         **kw)
     if comm_stats is not None:

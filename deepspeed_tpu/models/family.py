@@ -1,7 +1,7 @@
 """What a decoder family is, stated once.
 
 A family's file (``gpt2.py``, ``llama.py``, ``mixtral.py``,
-``pangu_ultra_moe.py``) ends with its
+``pangu_ultra_moe.py``, ``qwen3_next.py``) ends with its
 ``FAMILY = DecoderFamily(...)``: the pieces of one transformer layer and
 the facts a serving build needs.  Everything that serves, streams, drafts
 or generates (the ``inference`` package) reads the record through
@@ -45,6 +45,46 @@ class CacheRow(NamedTuple):
         if not self.values_in_keys:
             return self.key_width
         return -(-self.key_width // 128) * 128
+
+
+class StateRow(NamedTuple):
+    """What one SLOT keeps, a recurrent layer, whatever the length of its
+    sequence: ``conv`` (the last inputs of a short causal convolution,
+    rows x channels, in the cache's dtype) and ``state`` (the recurrence's
+    matrix a head, float32), for each of ``layers`` recurrent layers.  It
+    is indexed by slot, not by page: ``PagedKVCache.conv`` is
+    ``[layers, B, *conv]`` and ``PagedKVCache.state`` ``[layers, B,
+    *state]``."""
+
+    layers: int
+    conv: Tuple[int, ...]
+    state: Tuple[int, ...]
+
+
+@dataclasses.dataclass(frozen=True)
+class Recurrent:
+    """The recurrent layers of a family whose layers come in periods of
+    more than one kind (an author's fields, as ``DecoderFamily``'s).
+
+    ``period(cfg)``: one bool a layer of a period, True where the layer
+    mixes tokens through a recurrence over a per-slot state and False
+    where it attends over the page pool (``qkv`` / ``out``); the model is
+    whole periods.  ``key``: the params' stack of the recurrent layers
+    ``[periods * recurrent layers a period, ...]``; ``blocks`` holds the
+    attention layers alone.  ``mix(cfg, x, lp, state, valid) -> (y,
+    state)``: ``x`` [B, T, d] the residual stream, ``state`` the rows'
+    ``(conv [B, *conv], state [B, *state])``, ``valid`` [B] int32 how
+    many of each row's T tokens are real (the rest is padding, or the
+    whole row a slot that is idle or between two chunks of its prompt):
+    the state moves on real tokens only.  ``out(cfg, x, y, lp)``: the
+    residual and the FFN half, as ``DecoderFamily.out``.  ``state_row``:
+    what a slot keeps."""
+
+    key: str
+    period: Callable[[Any], Tuple[bool, ...]]
+    mix: Callable[..., Tuple[Any, Any]]
+    out: Callable[..., Any]
+    state_row: Callable[[Any], StateRow]
 
 
 def _per_head_rows(cfg) -> CacheRow:
@@ -107,6 +147,10 @@ class DecoderFamily:
     # index in them (a kernel that takes the stack and an index reads a
     # layer in place; a slice handed to it would be a copy)
     whole_stacks: Tuple[str, ...] = ()
+    # layers in periods of two kinds, some recurrent over a per-slot
+    # state beside the page pool: see ``Recurrent``.  The pool then has
+    # the attention layers only
+    recurrent: Optional[Recurrent] = None
     # (mechanism, why) the family cannot serve with yet: see ``refuse``
     refuses: Tuple[Tuple[str, str], ...] = ()
 
@@ -139,7 +183,8 @@ def positions_from(start, T: int):
 
 
 # the registry: one module name a family
-_FAMILY_MODULES = ("gpt2", "llama", "mixtral", "pangu_ultra_moe")
+_FAMILY_MODULES = ("gpt2", "llama", "mixtral", "pangu_ultra_moe",
+                   "qwen3_next")
 
 
 def decoder_families() -> Tuple[DecoderFamily, ...]:

@@ -188,6 +188,21 @@ def sigmoid_topk_route(h, gate, top_k: int, scale: float = 1.0,
         return top * scale, idx.astype(jnp.int32)
 
 
+def softmax_topk_route(h, gate, top_k: int, normalize: bool = True):
+    """Router of the softmax-scored families: ``p = softmax(h W)`` over
+    ALL E experts in f32 (as :func:`sigmoid_topk_route`, and for its
+    reason) -> (weights [N, k] f32, experts [N, k] int32): the k largest
+    probabilities, divided by their sum where ``normalize``."""
+    with jax.named_scope("moe_router"):
+        p = jax.nn.softmax(jnp.dot(h.astype(jnp.float32),
+                                   gate.astype(jnp.float32),
+                                   precision=jax.lax.Precision.HIGHEST))
+        top, idx = jax.lax.top_k(p, top_k)
+        if normalize:
+            top = top / jnp.sum(top, axis=-1, keepdims=True)
+        return top, idx.astype(jnp.int32)
+
+
 # (rows, contraction, columns) tile of the Mosaic grouped product; the
 # last two are fitted to each operand (``fit`` below)
 _GMM_TILING = (256, 1920, 1024)

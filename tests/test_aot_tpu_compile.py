@@ -481,3 +481,130 @@ def test_latent_cell_programs_fit_and_leave_the_pool_in_place(
     assert re.search(rf"%{kernel}[\w.]* = .*tpu_custom_call", hlo)
     if program != "decode":
         assert re.search(r"%gmm[\w.]* = .*tpu_custom_call", hlo)
+
+
+# ------------------------------------ the recurrent family's cell (PR 35)
+# qwen3-next-80b-a3b-ep8-d12.serve.docqa-sat as the benchmark builds it:
+# three periods of three Gated DeltaNet layers and one gated attention
+# layer at the published widths, 64 of 512 experts held, an eighth of the
+# vocabulary; 96 slots, each with a state beside its pages, over 65,537
+# pages of 16 in a pool of the THREE attention layers.
+_QWEN = dict(vocab_size=18992, n_layers=12, experts_held=(0, 64))
+_QWEN_PAGES, _QWEN_SLOTS, _QWEN_TABLE = 65537, 96, 17408 // PAGE
+# program -> (rows, tokens, table entries, bound on its temporaries in
+# GiB: AOT, PR 35, reads 0.070, 1.247 and 0.337; 0.26-0.29, 1.26 and
+# 0.55-0.61 while the outer loop sliced a period of the linear layers'
+# weights out of their stack)
+QWEN_PROGRAMS = {"decode": (_QWEN_SLOTS, 1, _QWEN_TABLE, 0.1),
+                 "chunk_full_table": (1, 1024, _QWEN_TABLE, 1.3),
+                 "chunk_first": (1, 1024, 64, 0.4)}
+
+
+def _top_level_results(hlo, dims):
+    """(name, opcode, called computation's lines) of the instructions,
+    fusion bodies aside, one of whose results has exactly ``dims``."""
+    bodies, cur = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if m:
+            cur = bodies.setdefault(m.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    called = lambda line: re.search(r"calls=%([\w.\-]+)", line)
+    fused = {called(l).group(1) for ls in bodies.values() for l in ls
+             if " fusion(" in l}
+    want = "[" + ",".join(map(str, dims)) + "]"
+    found = []
+    for comp, lines in bodies.items():
+        if comp in fused:
+            continue
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) "
+                         r"([a-z][\w\-]*)\(", line)
+            if m and m.group(3) not in _NOT_OPS and want in m.group(2):
+                body = bodies.get(called(line).group(1), []) \
+                    if called(line) else []
+                found.append((m.group(1), m.group(3), body))
+    return found
+
+
+@pytest.mark.parametrize("program", QWEN_PROGRAMS)
+def test_recurrent_cell_programs_fit_and_keep_pool_and_state_in_place(
+        chip, monkeypatch, program):
+    """The decode and chunk programs of the recurrent family's cell, at
+    the cell's sizes: they compile for the described v5e (5.46 GiB of
+    weights, a 6.0 GiB pool and 1.73 GiB of per-slot state beside their
+    temporaries, inside 15.75 GiB); they hold no copy of the pool, whose
+    leading dimension is the three attention layers, nor of the state or
+    of one layer of it: a decode step reads a layer's 96 states in the
+    fusions that reduce them and writes them in the fusion that updates
+    the carried buffer in place; a layer's experts are read in place;
+    the kernels run by name."""
+    from deepspeed_tpu.models import qwen3_next as qn
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, T, table, temp_gib = QWEN_PROGRAMS[program]
+    cfg = qn.Qwen3NextConfig(**_QWEN)
+    sr = qn.FAMILY.recurrent.state_row(cfg)
+    shape = (cfg.n_full_layers, cfg.n_kv_heads, _QWEN_PAGES, PAGE,
+             cfg.head_dim)
+    state_shape = (sr.layers, _QWEN_SLOTS) + sr.state
+    assert shape[0] == 3 and state_shape == (9, 96, 32, 128, 128)
+    S = jax.ShapeDtypeStruct
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: S(x.shape, x.dtype, sharding=chip)
+        if hasattr(x, "shape") else x, tree)
+    params = jax.eval_shape(lambda: qn.init_params(
+        jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16))
+    assert sum(math.prod(a.shape) for a in jax.tree.leaves(params)) \
+        == 2_929_374_400
+    cache = K.PagedKVCache(
+        k=S(shape, jnp.bfloat16), v=S(shape, jnp.bfloat16),
+        table=S((rows, table), jnp.int32), seq_lens=S((rows,), jnp.int32),
+        page_size=PAGE, expert_rows=S((64,), jnp.int32),
+        conv=S((sr.layers, _QWEN_SLOTS) + sr.conv, jnp.bfloat16),
+        state=S(state_shape, K.STATE_DTYPE),
+        slot=None if program == "decode" else S((1,), jnp.int32))
+    forward = lambda continuation: lambda params, tokens, cache: \
+        forward_paged(params, tokens, cfg, cache, interpret=False,
+                      tp=False, continuation=continuation)
+    _, chunk, _, _, decode = serving_programs(
+        forward(False), forward(False), forward(True), _sample_rows,
+        decode_chunk=1, max_batch=rows, expert_rows=True, state=True)
+    run, operands = (
+        (decode, (S((2,), jnp.uint32), S((), jnp.int32),
+                  S((rows,), jnp.float32)))
+        if program == "decode" else (chunk, (S((1,), jnp.int32),)))
+    compiled = jax.jit(run, donate_argnums=(2,)).lower(*on_chip((
+        params, S((rows, T), jnp.int32), cache, *operands))).compile()
+    hlo, memory = compiled.as_text(), compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= temp_gib * 2 ** 30
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+    assert 13.1 * 2 ** 30 < memory.argument_size_in_bytes < 13.3 * 2 ** 30
+    assert _pool_sized_ops(hlo, shape) == []
+    # the whole state is only ever the carried buffer, updated in place;
+    # one layer of it is never a value of its own
+    for name, op, body in _top_level_results(hlo, state_shape):
+        assert op == "dynamic-update-slice" or (op == "fusion" and any(
+            "ROOT" in l and " dynamic-update-slice(" in l for l in body)), \
+            (name, op)
+    assert _top_level_results(hlo, state_shape[1:]) == []
+    # and it is updated once a layer, never rematerialised: with the
+    # three linear layers of a period unrolled in one loop body the
+    # compiler recomputed a layer's in-place update from the buffer it
+    # had already overwritten, under this cell's memory pressure only,
+    # and the state moved twice a step (v5e, PR 35)
+    assert "remat" not in " ".join(
+        name for name, _, _ in _top_level_results(hlo, state_shape))
+    if program != "decode":
+        # a chunk's grouped product reads a layer's 64 experts in the
+        # stack; a decode step (96 rows: every held expert on every row)
+        # slices them out, as Mixtral's and the latent family's do
+        for experts in ((64, 2048, 512), (64, 512, 2048)):
+            assert _top_level_results(hlo, experts) == []
+    if program == "decode":
+        assert re.search(r"%dstpu_paged_decode[\w.]* = .*tpu_custom_call",
+                         hlo)
+    else:
+        assert re.search(r"%gmm[\w.]* = .*tpu_custom_call", hlo)

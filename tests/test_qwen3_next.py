@@ -1,0 +1,389 @@
+"""The qwen3_next family on the serving path, against the benchmark's
+plain float32 reference (``benchmark/reference/qwen3_next.py``, which
+imports nothing from ``deepspeed_tpu``): Gated DeltaNet layers over a
+per-slot state beside the page pool, one gated attention layer a
+period, the softmax-routed share of the experts, the seam's refusals.
+Toy widths, seeded weights, CPU."""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.families import qwen3_next as bench_family  # noqa: E402
+from benchmark.reference import qwen3_next as reference  # noqa: E402
+from deepspeed_tpu.inference import kernels as K  # noqa: E402
+from deepspeed_tpu.inference.generation import generator  # noqa: E402
+from deepspeed_tpu.inference.paged_forward import forward_paged  # noqa: E402
+from deepspeed_tpu.inference.serving import (serving_engine,  # noqa: E402
+                                             serving_programs)
+from deepspeed_tpu.models import qwen3_next as qn  # noqa: E402
+from deepspeed_tpu.models.family import decoder_family  # noqa: E402
+from deepspeed_tpu.parallel import moe  # noqa: E402
+from deepspeed_tpu.topology import MeshSpec  # noqa: E402
+
+CFG = qn.Qwen3NextConfig.tiny(n_layers=8)       # two periods
+PAGE = 8
+
+
+@pytest.fixture(scope="module")
+def params():
+    return qn.init_params(jax.random.PRNGKey(0), CFG)
+
+
+_REFERENCE = jax.jit(lambda params, tokens: reference.forward(
+    params, tokens, **bench_family._ref_kw(CFG)))
+
+
+def _reference_logits(params, tokens):
+    """The reference's logits of ``tokens``; run at one padded length
+    (causal: what follows a position does not reach it), so that it
+    compiles once."""
+    padded = np.zeros(64, np.int32)
+    padded[:len(tokens)] = tokens
+    return np.asarray(_REFERENCE(params, jnp.asarray(padded)))[:len(tokens)]
+
+
+def _engine(params, cfg=CFG, **kw):
+    base = dict(max_batch=3, page_size=PAGE, num_pages=64, max_seq=128,
+                cache_dtype=jnp.float32, telemetry=True, prefill_bucket=0,
+                prefill_chunk=16)
+    base.update(kw)
+    return serving_engine(params, cfg, **base)
+
+
+def _argmax_served(params, out, prompts):
+    for i, p in prompts.items():
+        want = _reference_logits(params, out[i]).argmax(-1)
+        assert out[i][len(p):] == want[len(p) - 1:-1].tolist(), i
+
+
+def _cache(cfg, slots, rows, max_seq, slot=None):
+    """A pool of the attention layers alone, the per-slot state beside
+    it, and the counter; ``rows`` rows of table."""
+    sr = decoder_family(cfg).recurrent.state_row(cfg)
+    mp = -(-max_seq // PAGE)
+    pages = slots * mp + 1
+    shape = (cfg.n_full_layers, cfg.n_kv_heads, pages, PAGE, cfg.head_dim)
+    table = np.arange(slots * mp).reshape(slots, mp)[:rows]
+    return K.PagedKVCache(
+        k=jnp.zeros(shape), v=jnp.zeros(shape),
+        table=jnp.asarray(table, jnp.int32),
+        seq_lens=jnp.zeros((rows,), jnp.int32), page_size=PAGE,
+        expert_rows=jnp.zeros((cfg.experts_held[1],), jnp.int32),
+        conv=jnp.zeros((sr.layers, slots) + sr.conv),
+        state=jnp.full((sr.layers, slots) + sr.state, 7.0),  # stale
+        slot=slot)
+
+
+# -------------------------------- (i) the paged forward vs the reference
+def test_chunks_then_masked_decode_steps_match_the_reference_logits(params):
+    """Prompts of 5, 21 and 37 tokens go through chunks of 16 that do
+    not divide them (the carry, and a padded last chunk whose padding
+    must move nothing), each into its own slot over a state that held
+    rubbish (a first chunk starts from zero); between one slot's chunks
+    the others' decode steps run over all three rows with the
+    unfinished row masked.  Every real position's logits match the
+    reference's full forward."""
+    rng = np.random.default_rng(1)
+    lens, new, C = (5, 21, 37), 5, 16
+    seqs = [rng.integers(0, CFG.vocab_size, n + new) for n in lens]
+    want = [_reference_logits(params, s) for s in seqs]
+    got = [np.zeros_like(w) for w in want]
+    cache = _cache(CFG, 3, 3, 64)
+    tables, trash = cache.table, cache.k.shape[2] - 1
+    fwd = lambda toks, c, **kw: forward_paged(
+        params, jnp.asarray(toks), CFG, c, tp=False, interpret=True, **kw)
+    done, at = [0, 0, 0], list(lens)            # prefilled; decoded up to
+
+    def chunk(b):
+        take = min(C, lens[b] - done[b])
+        toks = np.zeros((1, C), np.int32)
+        toks[0, :take] = seqs[b][done[b]:done[b] + take]
+        view = cache._replace(
+            table=cache.table[b:b + 1], slot=jnp.full((1,), b, jnp.int32),
+            seq_lens=jnp.full((1,), done[b], jnp.int32),
+            real=jnp.full((1,), take, jnp.int32))
+        logits, view = fwd(toks, view, continuation=True)
+        assert view.real is None
+        got[b][done[b]:done[b] + take] = np.asarray(logits[0, :take])
+        done[b] += take
+        return cache._replace(k=view.k, v=view.v, conv=view.conv,
+                              state=view.state,
+                              expert_rows=view.expert_rows)
+
+    def decode():
+        ready = [b for b in range(3) if done[b] == lens[b]
+                 and at[b] < len(seqs[b])]
+        lens_now = np.array([at[b] if b in ready else 0 for b in range(3)])
+        toks = [[seqs[b][at[b]] if b in ready else 0] for b in range(3)]
+        # as the engine uploads them: a row that is not ready has length
+        # 0 and the trash page for a table (the attention layers write a
+        # token for every row)
+        table = np.where((lens_now > 0)[:, None], np.asarray(tables),
+                         trash)
+        c = cache._replace(seq_lens=jnp.asarray(lens_now, jnp.int32),
+                           table=jnp.asarray(table, jnp.int32),
+                           real=jnp.asarray(lens_now > 0, jnp.int32))
+        logits, c = fwd(toks, c)
+        c = c._replace(table=tables)
+        np.testing.assert_array_equal(      # a masked row does not advance
+            np.asarray(c.seq_lens), lens_now + (lens_now > 0))
+        for b in ready:
+            got[b][at[b]] = np.asarray(logits[b, 0])
+            at[b] += 1
+        return c
+
+    cache = chunk(0)                            # slot 0 ready
+    for _ in range(3):                          # slot 2's three chunks,
+        cache = chunk(2)                        # slot 0 decoding between
+        cache = decode()
+    cache = chunk(1)
+    cache = chunk(1)
+    while any(at[b] < len(seqs[b]) for b in range(3)):
+        cache = decode()
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=3e-4, rtol=3e-4)
+    assert int(cache.expert_rows.sum()) > 0
+
+
+def test_padding_and_masked_rows_leave_the_state_bit_for_bit(params):
+    """A decode step over a masked row, and a chunk's rows past its
+    last real token, leave (conv, S) exactly as they were."""
+    cache = _cache(CFG, 2, 2, 32)._replace(
+        seq_lens=jnp.asarray([9, 0], jnp.int32),
+        real=jnp.asarray([1, 0], jnp.int32))
+    _, after = forward_paged(params, jnp.zeros((2, 1), jnp.int32), CFG,
+                             cache, tp=False, interpret=True)
+    for was, now in ((cache.conv, after.conv), (cache.state, after.state)):
+        np.testing.assert_array_equal(np.asarray(was[:, 1]),
+                                      np.asarray(now[:, 1]))
+        assert not np.array_equal(np.asarray(was[:, 0]),
+                                  np.asarray(now[:, 0]))
+    toks = np.random.default_rng(3).integers(0, CFG.vocab_size, (1, 16))
+    view = lambda real: _cache(CFG, 1, 1, 32, slot=jnp.zeros(
+        (1,), jnp.int32))._replace(real=jnp.full((1,), real, jnp.int32))
+    run = lambda t, real: forward_paged(
+        params, jnp.asarray(t), CFG, view(real), continuation=True,
+        tp=False, interpret=True)[1]
+    padded, other = toks.copy(), toks.copy()
+    other[0, 11:] = 5                           # other padding, same state
+    a, b = run(padded, 11), run(other, 11)
+    np.testing.assert_array_equal(np.asarray(a.state), np.asarray(b.state))
+    np.testing.assert_array_equal(np.asarray(a.conv), np.asarray(b.conv))
+
+
+# ------------------------------------------- (ii) through serving_engine
+@pytest.mark.parametrize("engine_kw", [
+    dict(prefill_chunk=16), dict(prefill_chunk=0, prefill_bucket=16)],
+    ids=["chunks", "whole_prompt"])
+def test_the_engine_serves_the_reference_argmax(params, engine_kw):
+    """Scheduler, allocator, state cache, boundary sampling and the
+    decode program's packed fetch: four requests through three slots
+    (the fourth reuses a slot whose state a longer request left), greedy
+    tokens the reference's argmax given the served prefix."""
+    eng = _engine(params, **engine_kw)
+    sr = decoder_family(CFG).recurrent.state_row(CFG)
+    assert eng.cache.k.shape[0] == CFG.n_full_layers == 2
+    assert eng.cache.state.shape == (6, 3) + sr.state
+    assert eng.cache.state.dtype == jnp.float32
+    rng = np.random.default_rng(0)
+    prompts = {i: rng.integers(0, CFG.vocab_size, n).tolist()
+               for i, n in enumerate((37, 21, 5, 9))}
+    for i, p in prompts.items():
+        eng.submit(i, p, max_new_tokens=7)
+    out = eng.run()
+    _argmax_served(params, out, prompts)
+    assert eng.check_leaks() == []
+    counters = eng.registry.snapshot()["counters"]
+    assert counters["serving_state_fresh_starts"] == 4
+    assert counters["serving_state_rows_masked"] > 0
+    routed = counters["serving_routed_rows"]
+    held = sum(counters[f"serving_expert_rows_{e}"]
+               for e in range(CFG.experts_held[1]))
+    assert routed % (CFG.top_k * CFG.n_expert_layers) == 0
+    assert 0 < held < routed
+    status = eng.statusz()["cache.state"]
+    assert status["bytes"] == eng.cache.conv.nbytes + eng.cache.state.nbytes
+    assert status["bytes_per_slot"] * 3 == status["bytes"]
+    assert status["live_slots"] == 0 and status["fresh_starts"] == 4
+
+
+def test_a_preempted_request_resumes_from_a_fresh_state(params):
+    """Too few pages for both: the younger request is preempted while
+    it decodes, its slot's state dropped; it is prefilled again (prompt
+    and what it had generated) from zero state and ends where an
+    undisturbed run ends."""
+    rng = np.random.default_rng(2)
+    prompts = {i: rng.integers(0, CFG.vocab_size, n).tolist()
+               for i, n in enumerate((30, 26))}
+    eng = _engine(params, max_batch=2, num_pages=10, max_seq=64)
+    for i, p in prompts.items():
+        eng.submit(i, p, max_new_tokens=14)
+    out = eng.run()
+    counters = eng.registry.snapshot()["counters"]
+    assert counters["serving_preempted_requests"] >= 1
+    assert counters["serving_state_fresh_starts"] >= 3
+    _argmax_served(params, out, prompts)
+    assert eng.check_leaks() == []
+
+
+def test_a_slot_reused_by_a_shorter_request(params):
+    """One slot, a long request then a short one: the second starts
+    from zero state and zero convolution rows whatever the first left."""
+    rng = np.random.default_rng(4)
+    prompts = {0: rng.integers(0, CFG.vocab_size, 41).tolist(),
+               1: rng.integers(0, CFG.vocab_size, 3).tolist()}
+    eng = _engine(params, max_batch=1)
+    for i, p in prompts.items():
+        eng.submit(i, p, max_new_tokens=5)
+    _argmax_served(params, eng.run(), prompts)
+
+
+# ------------------------------------------------ (iii) the rule itself
+def _rule_inputs(T, H=3, Dk=8, Dv=6, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    l2 = lambda t: t / jnp.sqrt((t * t).sum(-1, keepdims=True) + 1e-6)
+    q = l2(jax.random.normal(ks[0], (T, H, Dk))) * Dk ** -0.5
+    k = l2(jax.random.normal(ks[1], (T, H, Dk)))
+    v = jax.random.normal(ks[2], (T, H, Dv))
+    g = -jnp.exp(jax.random.normal(ks[3], (T, H)) - 2.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (T, H)))
+    S = jax.random.normal(ks[5], (H, Dk, Dv))
+    return q, k, v, g, beta, S
+
+
+@pytest.mark.parametrize("T,block", [(64, 16), (37, 8), (37, 5), (20, 64),
+                                     (9, 1)])
+def test_the_chunked_rule_is_the_recurrence(T, block):
+    """Blocks that divide T, blocks that do not, a block longer than T
+    and a block of one token: the outputs and the state the blocks
+    leave are the token-by-token recurrence's (the reference's)."""
+    q, k, v, g, beta, S = _rule_inputs(T)
+    want_o, want_S, _ = reference.recurrence(q, k, v, g, beta, S, T)
+    o, S1 = qn.gdn_chunk_rule(q[None], k[None], v[None], g[None],
+                              beta[None], S[None], block)
+    np.testing.assert_allclose(np.asarray(o[0]), np.asarray(want_o),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(S1[0]), np.asarray(want_S),
+                               atol=2e-5, rtol=2e-5)
+    # and the step, a token at a time, is the same recurrence
+    S2 = S[None]
+    for t in range(T):
+        o_t, S2 = qn.gdn_step(q[None, t], k[None, t], v[None, t],
+                              g[None, t], beta[None, t], S2)
+        np.testing.assert_allclose(np.asarray(o_t[0]),
+                                   np.asarray(want_o[t]), atol=2e-5,
+                                   rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(S2[0]), np.asarray(want_S),
+                               atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------- (iv) the share
+def test_eight_ranks_shares_add_up_to_the_uncut_layer(params):
+    """The routed parts of all 8 ranks, with what every rank computes
+    alike (the gated shared expert) counted once, are the uncut layer:
+    the program's expert layer on each rank's two experts against the
+    reference's on all sixteen."""
+    whole = qn.Qwen3NextConfig.tiny(experts_held=(0, 16))
+    full = qn.init_params(jax.random.PRNGKey(1), whole)
+    lp = jax.tree.map(lambda a: a[0], full["gdn_blocks"])
+    h = jax.random.normal(jax.random.PRNGKey(2), (1, 24, whole.dim))
+    kw = bench_family._ref_kw(whole)
+    w, idx, _ = reference.route(h[0], lp["gate"], kw["top_k"],
+                                kw["normalize"])
+    shared = jax.nn.sigmoid(h[0] @ lp["shared_gate"]) * reference._swiglu(
+        h[0], lp["sw1"], lp["sw3"], lp["sw2"])
+    stack = {n: full["gdn_blocks"][n] for n in reference.EXPERT_WEIGHTS}
+    want = reference.held_part(h[0], stack, 0, w, idx, 0) + shared
+    routed, rows = 0.0, 0
+    for rank in range(8):
+        cfg = qn.Qwen3NextConfig.tiny(experts_held=(2 * rank, 2))
+        mine = dict(lp, **{n: lp[n][2 * rank:2 * rank + 2]
+                           for n in reference.EXPERT_WEIGHTS})
+        y, n = qn.expert_layer(cfg, h, mine)
+        routed = routed + (y[0] - shared)
+        rows += int(n.sum())
+    np.testing.assert_allclose(np.asarray(routed + shared),
+                               np.asarray(want), atol=2e-5, rtol=2e-5)
+    assert rows == 24 * whole.top_k             # every pair, once
+
+
+def test_softmax_router_is_float32_and_renormalised():
+    h = jax.random.normal(jax.random.PRNGKey(0), (32, 64), jnp.bfloat16)
+    gate = jax.random.normal(jax.random.PRNGKey(1), (64, 16), jnp.bfloat16)
+    w, idx = moe.softmax_topk_route(h, gate, 4)
+    want_w, want_idx, _ = reference.route(h.astype(jnp.float32), gate, 4,
+                                          True)
+    assert w.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_idx))
+    np.testing.assert_allclose(np.asarray(w), np.asarray(want_w), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, rtol=1e-6)
+    raw, _ = moe.softmax_topk_route(h, gate, 4, normalize=False)
+    assert float(raw.sum(-1).max()) < 1.0
+
+
+# --------------------------------------------------- (v) what is refused
+@pytest.mark.parametrize("mechanism,kw", [
+    ("prefix_cache", dict(prefix_cache=True)),
+    ("kv_tier", dict(kv_tier={"host_pool_bytes": 1 << 20})),
+    ("speculative", dict(speculative={"enabled": True, "draft_tokens": 2})),
+    ("zero_inference", dict(zero_inference={"enabled": True})),
+])
+def test_the_family_refuses_by_name(params, mechanism, kw):
+    with pytest.raises(NotImplementedError, match=mechanism):
+        _engine(params, **kw)
+
+
+def test_quantized_resident_contiguous_cache_and_a_mesh_are_refused(params):
+    fam = decoder_family(CFG)
+    assert {m for m, _ in fam.refuses} == {
+        "prefix_cache", "kv_tier", "quantized_resident", "speculative",
+        "zero_inference", "contiguous_cache"}
+    with pytest.raises(NotImplementedError, match="quantized_resident"):
+        fam.refuse(quantized_resident=True)
+    with pytest.raises(NotImplementedError, match="contiguous_cache"):
+        generator(params, CFG)
+    mesh = MeshSpec.build({"model": 2}, devices=jax.devices()[:2])
+    with pytest.raises(NotImplementedError, match="model or expert axis"):
+        _engine(params, mesh=mesh)
+    with pytest.raises(ValueError, match="max_seq_len"):
+        _engine(params, max_seq=CFG.max_seq_len + PAGE)
+
+
+def test_the_programs_tell_the_forward_which_rows_are_real():
+    """``serving_programs(state=True)``: a chunk hands the forward its
+    last real position + 1, a decode step the rows whose length is not
+    0; without ``state`` the cache goes through as it came."""
+    seen = []
+
+    def forward(params, tokens, cache):
+        seen.append(cache.real)
+        B, T = tokens.shape
+        return jnp.zeros((B, T, 4)), cache._replace(real=None)
+
+    cache = K.PagedKVCache(k=jnp.zeros((1,)), v=None,
+                           table=jnp.zeros((2, 1), jnp.int32),
+                           seq_lens=jnp.asarray([0, 5], jnp.int32),
+                           page_size=8)
+    sample = lambda logits, keys, temps: jnp.zeros(
+        (logits.shape[0],), jnp.int32)
+    for state, want in ((True, ([7], [0, 1])), (False, (None, None))):
+        del seen[:]
+        _, chunk, _, _, decode = serving_programs(
+            forward, forward, forward, sample, 1, 2, state=state)
+        chunk(None, jnp.zeros((1, 8), jnp.int32),
+              cache._replace(seq_lens=jnp.zeros((1,), jnp.int32)),
+              jnp.asarray([6]))
+        with jax.disable_jit():                 # the scan as a Python loop
+            decode(None, jnp.zeros((2, 1), jnp.int32), cache,
+                   jax.random.PRNGKey(0), jnp.zeros((), jnp.int32),
+                   jnp.zeros((2,)))
+        got = [None if r is None else np.asarray(r).tolist() for r in seen]
+        assert tuple(got) == want
