@@ -49,6 +49,17 @@ _INSTR = re.compile(
 _FUSED_REDUCE_SCATTER = re.compile(
     r"=\s+(\([^)]*\)|[a-z0-9]+\[[^\]]*\]\S*)\s+fusion\([^)]*\),"
     r"\s*kind=kCustom,\s*calls=%all-reduce-scatter\b")
+# That called computation spells the reduction as a plain all-reduce of
+# the whole operand ("%all-reduce-scatter.1.clone (input: bf16[8192,2048])
+# -> bf16[2080,2048] { ... all-reduce(%pad) ... }"): the fusion above is
+# the collective, and its body is not a second one (PR 36).
+_FUSED_BODY = re.compile(
+    r"^%all-reduce-scatter\b[^\n]*\{\n.*?^\}", re.M | re.S)
+# An async collective the TPU compiler fuses is written out in each of the
+# computations that make up the fusion, under one channel: count a channel
+# once (the step compiled for four v5e chips names each layer's all-gather
+# three times, PR 36).
+_CHANNEL = re.compile(r"channel_id=(\d+)")
 _SHAPE = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
 
 
@@ -75,8 +86,18 @@ def analyze_collectives(hlo_text: str,
     """
     per_kind: Dict[str, Dict[str, int]] = {
         k: {"count": 0, "bytes": 0} for k in COLLECTIVE_KINDS}
-    found = [(t, op.replace("-start", ""))
-             for t, op in _INSTR.findall(hlo_text)]
+    found, channels = [], set()
+    for line in _FUSED_BODY.sub("", hlo_text).splitlines():
+        m = _INSTR.search(line)
+        if m is None:
+            continue
+        kind = m.group(2).replace("-start", "")
+        ch = _CHANNEL.search(line)
+        if ch is not None:
+            if (kind, ch.group(1)) in channels:
+                continue
+            channels.add((kind, ch.group(1)))
+        found.append((m.group(1), kind))
     found += [(t, "reduce-scatter")
               for t in _FUSED_REDUCE_SCATTER.findall(hlo_text)]
     for typestr, kind in found:

@@ -128,7 +128,7 @@ class TrainingEngine:
         # publish for model-side sharded ops (ring/ulysses attention, MoE)
         from deepspeed_tpu import topology as _topo
 
-        _topo.set_current_mesh(self.mesh)
+        _topo.set_current_mesh(self.mesh, zero_stage=config.zero.stage)
         config.resolve_batch_sizes(self.mesh.dp_world)
         self.loss_fn = loss_fn
         self.has_aux = has_aux
@@ -634,11 +634,12 @@ class TrainingEngine:
         # (re)publish the ambient mesh at TRACE time: another engine may
         # have been constructed since __init__, and model code (ring/
         # ulysses attention, MoE, pipeline) reads current_mesh() while
-        # tracing this step.
+        # tracing this step.  The ZeRO stage goes with it: zero.py's
+        # statements inside a model's forward ask for both.
         from deepspeed_tpu import topology as _topo
 
-        _topo.set_current_mesh(self.mesh)
         cfg = self.config
+        _topo.set_current_mesh(self.mesh, zero_stage=cfg.zero.stage)
         # Pipeline mode: the loss fn consumes the WHOLE batch (microbatching
         # happens inside the pipelined scan, ref: runtime/pipe/engine.py
         # train_batch) — no outer accumulation loop.
@@ -821,7 +822,8 @@ class TrainingEngine:
     def _eval_step(self, state: TrainState, batch):
         from deepspeed_tpu import topology as _topo
 
-        _topo.set_current_mesh(self.mesh)
+        _topo.set_current_mesh(self.mesh,
+                               zero_stage=self.config.zero.stage)
         params = state.params
         if self.grad_comm_mode == "qwz":
             # flat [world, chunk] master → model leaves (GSPMD inserts the

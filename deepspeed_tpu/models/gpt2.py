@@ -149,17 +149,28 @@ def _head(params, x, cfg: GPT2Config):
 
 
 def forward(params, tokens, cfg: GPT2Config):
-    B, T = tokens.shape
-    with jax.named_scope("embed"):
-        x = params["wte"][tokens] + params["wpe"][:T][None]
+    from deepspeed_tpu import zero
 
-    block = lambda x, lp: (_block(cfg, x, lp), None)
+    B, T = tokens.shape
+    # under ZeRO-3 a layer's weights are gathered where they are used and
+    # the activations stay on the batch axes (zero.py; both are the
+    # identity anywhere else).  The serving hooks above are not touched.
+    specs = param_specs(cfg)
+    top = {k: params[k] for k in ("wte", "wpe", "lnf_w", "lnf_b")}
+    top = zero.gather_at_use(top, {k: specs[k] for k in top})
+    with jax.named_scope("embed"):
+        x = zero.pin_to_batch(top["wte"][tokens] + top["wpe"][:T][None])
+
+    def block(x, lp):
+        lp = zero.gather_at_use(lp, specs["blocks"], stacked=True)
+        return zero.pin_to_batch(_block(cfg, x, lp)), None
+
     if cfg.remat != "none":
         from deepspeed_tpu.remat import policy as remat_policy
 
         block = jax.checkpoint(block, policy=remat_policy(cfg.remat))
     x, _ = jax.lax.scan(block, x, params["blocks"])
-    return _head(params, x, cfg)
+    return _head(top, x, cfg)
 
 
 def loss_fn(cfg: GPT2Config):

@@ -318,9 +318,15 @@ def forward_hidden(params, tokens, cfg: LlamaConfig, positions=None,
     """tokens: [B, T] int32 → final-norm hidden states [B, T, d] (the
     pre-LM-head activations; :func:`forward` adds the head projection,
     the chunked loss consumes these directly)."""
+    from deepspeed_tpu import zero
+
     B, T = tokens.shape
+    # under ZeRO-3 weights are gathered where they are used and the
+    # activations stay on the batch axes (zero.py; the identity elsewhere)
+    specs = param_specs(cfg)
     with jax.named_scope("embed"):
-        x = params["embed"][tokens]  # [B, T, d]
+        embed = zero.gather_at_use(params["embed"], specs["embed"])
+        x = zero.pin_to_batch(embed[tokens])  # [B, T, d]
         if positions is None:
             positions = jnp.arange(T, dtype=jnp.int32)
         cos, sin = rope_tables(cfg, positions)
@@ -342,6 +348,11 @@ def forward_hidden(params, tokens, cfg: LlamaConfig, positions=None,
         x = pipelined_scan(block, params["blocks"], x, n_micro, ms,
                            remat=cfg.remat)
     else:
+        def block(x, lp):
+            lp = zero.gather_at_use(lp, specs["blocks"], stacked=True)
+            return zero.pin_to_batch(
+                _block(cfg, x, lp, cos, sin, segment_ids)), None
+
         if cfg.remat != "none":
             from deepspeed_tpu.remat import policy as remat_policy
 
@@ -349,11 +360,23 @@ def forward_hidden(params, tokens, cfg: LlamaConfig, positions=None,
         x, _ = jax.lax.scan(block, x, params["blocks"])
 
     with jax.named_scope("final_norm"):
-        return rms_norm(x, params["final_norm"], cfg.norm_eps)
+        norm = zero.gather_at_use(params["final_norm"], specs["final_norm"])
+        return rms_norm(x, norm, cfg.norm_eps)
 
 
 def lm_head(params, cfg: LlamaConfig):
     return params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+
+
+def _lm_head_at_use(params, cfg: LlamaConfig):
+    """:func:`lm_head` for a training forward: its leaf gathered where
+    ZeRO-3 keeps it sharded (``zero.gather_at_use``).  The serving head
+    calls :func:`lm_head` itself."""
+    from deepspeed_tpu import zero
+
+    key = "embed" if cfg.tie_embeddings else "lm_head"
+    leaf = zero.gather_at_use(params[key], param_specs(cfg)[key])
+    return lm_head({key: leaf}, cfg)
 
 
 def forward(params, tokens, cfg: LlamaConfig, positions=None,
@@ -367,7 +390,7 @@ def forward(params, tokens, cfg: LlamaConfig, positions=None,
     x = forward_hidden(params, tokens, cfg, positions=positions,
                        segment_ids=segment_ids, n_micro=n_micro)
     with jax.named_scope("lm_head"):
-        return jnp.einsum("btd,dv->btv", x, lm_head(params, cfg),
+        return jnp.einsum("btd,dv->btv", x, _lm_head_at_use(params, cfg),
                           preferred_element_type=jnp.float32)
 
 
@@ -514,7 +537,8 @@ def loss_fn(cfg: LlamaConfig, n_micro: Optional[int] = None):
                            segment_ids=seg, n_micro=n_micro)
         # loss_chunk=0 → dense path inside chunked_lm_loss (chunk >= V);
         # >0 → fused head+CE, the [B,T,V] f32 logits never hit HBM
-        return chunked_lm_loss(x, lm_head(params, cfg), targets, mask=mask,
+        return chunked_lm_loss(x, _lm_head_at_use(params, cfg), targets,
+                               mask=mask,
                                chunk=cfg.loss_chunk or cfg.vocab_size)
 
     return f

@@ -186,23 +186,31 @@ def forward(params, tokens, cfg: MixtralConfig, positions=None,
     """tokens: [B, T] → (logits [B, T, V] f32, aux_losses dict).
     segment_ids: optional [B, T] int32 packed-document isolation (same
     contract as llama.forward)."""
+    from deepspeed_tpu import zero
     from deepspeed_tpu.topology import current_mesh
 
     lcfg = cfg.llama_view()
     mesh = current_mesh()
     B, T = tokens.shape
-    x, cos, sin = _embed(params, tokens, lcfg, positions)
+    # under ZeRO-3 weights are gathered where they are used and the
+    # activations stay on the batch axes (zero.py; the identity elsewhere)
+    specs = param_specs(cfg)
+    top = {k: params[k] for k in ("embed", "final_norm", "lm_head")}
+    top = zero.gather_at_use(top, {k: specs[k] for k in top})
+    x, cos, sin = _embed(top, tokens, lcfg, positions)
+    x = zero.pin_to_batch(x)
 
     def block(carry, lp):
         from jax.ad_checkpoint import checkpoint_name
 
         x, aux_acc = carry
+        lp = zero.gather_at_use(lp, specs["blocks"], stacked=True)
         x = _attn_block(cfg, lcfg, x, lp, cos, sin, segment_ids)
         with jax.named_scope("mlp"):
             h = _llama.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
             y, aux = _moe_ffn(cfg, h, lp, mesh)
         y = checkpoint_name(y, "mlp_out")
-        x = x + y
+        x = zero.pin_to_batch(x + y)
         aux_acc = {
             "moe_aux_loss": aux_acc["moe_aux_loss"] + aux["moe_aux_loss"],
             "moe_z_loss": aux_acc["moe_z_loss"] + aux["moe_z_loss"],
@@ -220,7 +228,7 @@ def forward(params, tokens, cfg: MixtralConfig, positions=None,
                 "moe_z_loss": jnp.float32(0.0),
                 "moe_expert_load": jnp.zeros((cfg.num_experts,), jnp.float32)}
     (x, aux), _ = jax.lax.scan(blk, (x, zero_aux), params["blocks"])
-    return _llama._head(params, x, lcfg), aux
+    return _llama._head(top, x, lcfg), aux
 
 
 def _embed(params, tokens, lcfg, positions=None):

@@ -66,15 +66,24 @@ class MeshSpec:
 # threading the mesh through every call (the analogue of the reference's
 # global process groups in deepspeed/utils/groups.py).
 _CURRENT_MESH: Optional["MeshSpec"] = None
+# The ZeRO stage of the engine that published the mesh: what zero.py's
+# statements about a model's forward (gather_at_use, pin_to_batch) ask.
+# Whoever publishes a mesh without one (serving, tests) publishes stage 0.
+_CURRENT_ZERO_STAGE: int = 0
 
 
-def set_current_mesh(ms: Optional["MeshSpec"]) -> None:
-    global _CURRENT_MESH
+def set_current_mesh(ms: Optional["MeshSpec"], zero_stage: int = 0) -> None:
+    global _CURRENT_MESH, _CURRENT_ZERO_STAGE
     _CURRENT_MESH = ms
+    _CURRENT_ZERO_STAGE = int(zero_stage)
 
 
 def current_mesh() -> Optional["MeshSpec"]:
     return _CURRENT_MESH
+
+
+def current_zero_stage() -> int:
+    return _CURRENT_ZERO_STAGE
 
 
 def default_mesh(n_devices: Optional[int] = None) -> MeshSpec:

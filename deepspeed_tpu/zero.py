@@ -8,7 +8,8 @@ buffers, round-robin 1-D chunks across the DP group, hook backward to
 reduce-scatter gradients, and all-gather params around each use (stage 3),
 with bucketing/overlap machinery to hide latency.
 
-On TPU none of that machinery is needed — ZeRO *is* a sharding decision:
+On TPU most of that machinery is not needed — where state *lives* is a
+sharding decision:
 
 ========  ======================  ==================  =====================
 stage     optimizer state         gradients           parameters
@@ -16,12 +17,28 @@ stage     optimizer state         gradients           parameters
 0         replicated              replicated (psum)   replicated
 1         sharded over data       replicated (psum)   replicated
 2         sharded over data       sharded (r-scatter) replicated
-3         sharded over data       sharded             sharded (AG at use)
+3         sharded over data       sharded             sharded, gathered at
+                                                      use by the forward
 ========  ======================  ==================  =====================
 
-We express each column as a per-leaf ``NamedSharding`` and let XLA insert
-the exact all-gather / reduce-scatter schedule the reference hand-codes —
-overlapped with compute by the XLA latency-hiding scheduler, riding ICI.
+Each column is a per-leaf ``NamedSharding`` (``param_shardings``,
+``optstate_shardings``, ``grad_constraint``).  Stages 0-2 leave the
+schedule to XLA.  Stage 3 does not: where a parameter is *used* is stated
+too.  A model's forward calls ``gather_at_use`` on the leaves it is about
+to multiply by (one layer's slice inside the scan body, the embedding
+beside it) and ``pin_to_batch`` on the activation it carries, so a layer
+costs one bf16 all-gather forward, one in the recomputed forward, and one
+reduce-scatter of its gradients, and no activation leaves its chip (the
+one exception the compiler still takes: the embedding lookup's gradient,
+see ``pin_to_batch``).  Until PR 36 the shardings stood alone, and on four
+v5e chips GSPMD met a batch split over ``data`` and weights split over
+``data`` on their hidden dimension with partly the tensor-parallel
+program: 146 all-to-alls of activations a step, 36.9% of the step in
+collectives and every one of them exposed (ledger, PR 35; 13.0% and
++64.6% tokens/s after, PERF.md 6).  A layer's gathers still wait inside
+that layer (the compiler fuses the large ones into the product beside
+them) and its reduce-scatters are exposed; fetching layer *l+1* under
+layer *l*'s products is ROADMAP S2's next step.
 
 Model-parallel (TP) shardings compose: callers pass ``param_specs`` — a
 pytree of ``PartitionSpec`` matching the params pytree (or a callable
@@ -31,12 +48,15 @@ unsharded dimension.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional, Union
 
 import jax
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.topology import MeshSpec, ZERO_AXES, shard_leaf_spec
+from deepspeed_tpu.topology import (BATCH_AXES, MeshSpec, ZERO_AXES,
+                                    current_mesh, current_zero_stage,
+                                    shard_leaf_spec)
 
 SpecTree = Union[None, Callable, Any]
 
@@ -138,6 +158,85 @@ def grad_constraint(grads: Any, ms: MeshSpec, stage: int,
     return jax.tree.map(
         lambda g, base: jax.lax.with_sharding_constraint(
             g, ms.sharding(_zero_spec(g, base, ms))), grads, specs)
+
+
+def _mesh_at_use() -> Optional[MeshSpec]:
+    """The ambient mesh, where stage 3's two statements about a forward
+    pass apply at this point of the trace; else None, and they return
+    their argument.  They apply under an engine at stage 3 whose ZeRO axes
+    are larger than 1, outside every ``shard_map`` that holds one of those
+    axes (or a batch axis) manual: inside one (``onebit``, ``qwz``,
+    ``qgz``) the data axis is the body's own, its parameters arrive
+    gathered, and a constraint that names the axis is an error."""
+    ms = current_mesh()
+    if ms is None or current_zero_stage() < 3 or _zero_axis_size(ms) == 1:
+        return None
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    if manual & (set(ZERO_AXES) | set(BATCH_AXES)):
+        return None
+    return ms
+
+
+def gather_at_use(tree: Any, base_specs: SpecTree = None,
+                  stacked: bool = False) -> Any:
+    """Stage 3's all-gather at use (ref: deepspeed/runtime/zero/
+    partitioned_param_coordinator.py ``fetch_sub_module``): the leaves a
+    forward pass is about to multiply by, whole on every chip of the ZeRO
+    axes.
+
+    ``tree`` is what the model holds at that point, already cast for
+    compute, so bf16 travels: the embedding and final norm, or
+    (``stacked``) one layer's slice of the stacked blocks inside the scan
+    body.  ``base_specs`` are the model-parallel specs of the leaves as
+    they are stored (the model's own ``param_specs``; for a slice, of the
+    stacked leaf it was cut from): they stay, only the ZeRO axes go.
+
+    Forward this is one all-gather a leaf.  Backward the leaf's gradient
+    is left unconstrained, so that the stored layout ``grad_constraint``
+    asks for reaches the product that makes it and the chips' partial
+    sums leave as a reduce-scatter: the transpose of a plain constraint
+    asks for the gradient whole on every chip first (an all-reduce of
+    each leaf, as the compiled step showed), hence the ``custom_vjp``.
+    Placed inside a ``jax.checkpoint`` body it is recomputed with it: a
+    layer is gathered once forward, once backward, and never kept.
+    """
+    ms = _mesh_at_use()
+    if ms is None:
+        return tree
+    return jax.tree.map(
+        lambda x, base: _gather(
+            x, ms.sharding(P(*base[1:]) if stacked else base)),
+        tree, resolve_specs(tree, base_specs))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _gather(x, gathered):
+    return jax.lax.with_sharding_constraint(x, gathered)
+
+
+_gather.defvjp(lambda x, gathered: (_gather(x, gathered), None),
+               lambda gathered, _, g: (g,))
+
+
+def pin_to_batch(x):
+    """An activation stays where its batch rows are: the leading dimension
+    on the batch axes, the others left to the compiler (a ``seq`` or
+    ``model`` axis may hold them).  Without this GSPMD meets a batch split
+    over ``data`` and weights split over ``data`` and may re-split the
+    activation by its hidden dimension instead of gathering the weights
+    (146 all-to-alls a step on four chips, ledger, PR 35).
+
+    One activation may still move, outside the layer loop: the gradient
+    of the embedding lookup.  The table's gradient is stored by columns,
+    and the compiler hands each chip every row's slice of columns (one
+    all-to-all of an activation's bytes, 16 MB at GPT-2 1.3B's widths)
+    to add its own columns up, instead of reducing a table-sized partial
+    sum (206 MB): the cheaper program, left to it."""
+    ms = _mesh_at_use()
+    if ms is None:
+        return x
+    spec = P(ms.batch_spec()[0], *[P.UNCONSTRAINED] * (x.ndim - 1))
+    return jax.lax.with_sharding_constraint(x, ms.sharding(spec))
 
 
 def estimate_memory(num_params: int, dp_world: int, stage: int,

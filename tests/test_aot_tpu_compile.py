@@ -36,10 +36,10 @@ DH, PAGE, TABLE_TOKENS = 128, 16, 4096
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """Sharding on one described v5e device; the persistent compilation
-    cache stays off, because an entry written for a described chip
-    cannot be read back without one and the next compile warns."""
+def topo():
+    """A described v5e 2x2; the persistent compilation cache stays off,
+    because an entry written for a described chip cannot be read back
+    without one and the next compile warns."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -52,9 +52,15 @@ def chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """Sharding on one described v5e device."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, chip, *shapes):
@@ -608,3 +614,65 @@ def test_recurrent_cell_programs_fit_and_keep_pool_and_state_in_place(
                          hlo)
     else:
         assert re.search(r"%gmm[\w.]* = .*tpu_custom_call", hlo)
+
+
+# ------------------------------------------- ZeRO-3 over the four chips
+def test_zero3_step_gathers_a_layer_and_scatters_its_gradient(
+        topo, monkeypatch):
+    """``gpt2-1.3b.train.zero3-x4``'s loss and gradient at the published
+    widths (two layers), compiled for the 2x2: a layer's weights arrive
+    by bf16 all-gathers and nothing activation-shaped moves inside the
+    layer loop; the four matrices' gradients leave as reduce-scatter
+    fusions (the TPU compiler's spelling); the one all-to-all left is the
+    embedding lookup's gradient, outside the loop, which the compiler
+    prefers to reducing a table-sized partial sum.  The CPU mesh of
+    tests/test_zero_engine.py cannot show the reduce-scatter: its compiler
+    writes all-reduce + dynamic-slice."""
+    from deepspeed_tpu import topology, zero
+    from deepspeed_tpu.comm.digest import analyze_collectives
+    from deepspeed_tpu.topology import MeshSpec
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ms = MeshSpec.build({"data": 4}, devices=topo.devices)
+    monkeypatch.setattr(topology, "_CURRENT_MESH", ms)
+    monkeypatch.setattr(topology, "_CURRENT_ZERO_STAGE", 3)
+    cfg = dataclasses.replace(
+        gpt2.GPT2Config.gpt2_1_3b(remat="save_dots"), n_layers=2)
+    B, T, d = 16, 1024, cfg.dim
+    shapes = jax.eval_shape(
+        lambda: gpt2.init_params(jax.random.PRNGKey(0), cfg))
+    layout = zero.param_shardings(shapes, ms, 3)
+    loss = gpt2.loss_fn(cfg)
+
+    def grads(params, tokens):
+        cast = lambda p: jax.tree.map(lambda x: x.astype(jnp.bfloat16), p)
+        g = jax.grad(lambda p: loss(cast(p), {"tokens": tokens}))(params)
+        return zero.grad_constraint(g, ms, 3)
+
+    hlo = jax.jit(grads, out_shardings=layout).lower(
+        jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sh), shapes, layout),
+        jax.ShapeDtypeStruct((B, T + 1), jnp.int32, sharding=ms.sharding(
+            ms.batch_spec()))).compile().as_text()
+
+    lines = [l for l in hlo.splitlines() if re.search(
+        r" (all-gather|all-to-all|collective-permute)(-start)?\(", l)]
+    moved = [l for l in lines if re.search(rf"\[[\d,]*\b{T}\b[\d,]*\]", l)
+             and f"[{T},{d}]" not in l]               # [T, d]: wpe
+    assert all(re.search(r"transpose\(jvp\([^\"]*\bembed\b", l)
+               for l in moved), moved
+    assert len([l for l in moved if "all-to-all" in l]) <= 1
+    gathers = [l for l in lines if " all-gather" in l and l not in moved]
+    assert gathers and all(re.search(r"= \(?bf16\[", l) for l in gathers)
+    scattered = re.findall(
+        r"= bf16\[([\d,]+)\]\S* fusion\([^)]*\), kind=kCustom, "
+        r"calls=%all-reduce-scatter", hlo)
+    elements = sorted(int(np.prod([int(x) for x in s.split(",")]))
+                      for s in scattered)
+    # qkv, proj, fc, out: a quarter each (rows padded to a tile's multiple)
+    want = sorted(n // 4 for n in (3 * d * d, d * d, 4 * d * d, 4 * d * d))
+    big = [e for e in elements if e >= want[0]]
+    assert len(big) >= 4 and all(
+        w <= e <= 1.05 * w for e, w in zip(big[:4], want)), elements
+    digest = analyze_collectives(hlo)["per_kind"]
+    assert digest.get("all-reduce", {"bytes": 0})["bytes"] < 2 * d * d

@@ -206,6 +206,40 @@ class TestCommsDigest:
         assert d["per_kind"] == {"reduce-scatter": {
             "count": 1, "bytes": 2 * 2060 * 8 * 128}}
 
+    def test_tpu_fused_reduce_scatter_body_and_channels_count_once(self):
+        # the ZeRO-3 step compiled for four v5e chips (PR 36): the fused
+        # reduce-scatter's own computation spells the reduction as an
+        # all-reduce of the whole operand, and an async all-gather the
+        # compiler fuses is written out in each computation of the
+        # fusion under one channel
+        from deepspeed_tpu.comm.digest import analyze_collectives
+
+        txt = '''
+%all-reduce-scatter.1.clone.clone (input.12: bf16[8192,2048]) -> bf16[2080,2048] {
+  %pad.13 = bf16[8320,2048]{1,0} pad(%input.12, %constant.1598), padding=0_128x0_0
+  %all-reduce.100 = bf16[8320,2048]{1,0} all-reduce(%pad.13), channel_id=120, replica_groups={{0,1,2,3}}, to_apply=%add.3.clone
+  ROOT %dynamic-slice.1 = bf16[2080,2048]{1,0} dynamic-slice(%all-reduce.100, %a, %b)
+}
+
+%fused_computation.1 (param_0.1553: bf16[1,2048,2048]) -> bf16[1,2048,8192] {
+  %all-gather.139 = bf16[1,2048,8192]{2,1,0} all-gather(%param_0.1553), channel_id=9, replica_groups=[1,4]<=[4], dimensions={2}
+}
+
+%fused_computation.2 (param_0.1557: bf16[1,2048,2048]) -> bf16[1,2048,8192] {
+  %all-gather.141 = bf16[1,2048,8192]{2,1,0} all-gather(%param_0.1557), channel_id=9, replica_groups=[1,4]<=[4], dimensions={2}
+}
+
+ENTRY %main () -> bf16[2080,2048] {
+  %all-gather.7 = bf16[1,2048]{1,0} all-gather(%p), channel_id=10, dimensions={1}
+  %fusion.403 = bf16[2080,2048]{1,0} fusion(%gte.1900), kind=kCustom, calls=%all-reduce-scatter.1.clone.clone
+}
+'''
+        d = analyze_collectives(txt)
+        assert d["per_kind"] == {
+            "all-gather": {"count": 2,
+                           "bytes": 2 * 2048 * 8192 + 2 * 2048},
+            "reduce-scatter": {"count": 1, "bytes": 2 * 2080 * 2048}}
+
     def test_async_start_done_counts_once(self):
         from deepspeed_tpu.comm.digest import analyze_collectives
 

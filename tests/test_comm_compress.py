@@ -310,3 +310,38 @@ class TestGates:
         l0 = float(engine.train_batch(batch))
         l1 = float(engine.train_batch(batch))
         assert l1 < l0
+
+
+@pytest.mark.parametrize("mode", ["qgz", "onebit", "qwz"])
+def test_zero3_model_helpers_are_inert_in_the_shard_map_paths(
+        mode, devices, monkeypatch):
+    """A model whose forward states ZeRO-3's gather and batch pin
+    (zero.gather_at_use, zero.pin_to_batch) trains under the compressed
+    paths as before: inside their ``shard_map`` the data axis is manual,
+    and the helpers return their argument (under ``qwz`` the stage is 3
+    and only that keeps a constraint on ``data`` out of the body)."""
+    from deepspeed_tpu import zero
+    from deepspeed_tpu.models import gpt2
+
+    extra, opt = {
+        "qgz": ({"stage": 2, "zero_quantized_gradients": True}, "adamw"),
+        "onebit": ({"stage": 0}, "OnebitAdam"),
+        "qwz": ({"stage": 3, "zero_quantized_weights": True}, "adamw"),
+    }[mode]
+    seen, real = [], zero._mesh_at_use
+    monkeypatch.setattr(zero, "_mesh_at_use",
+                        lambda: seen.append(real()) or seen[-1])
+    cfg = gpt2.GPT2Config.tiny(remat="save_dots")
+    engine, _, _, _ = dstpu.initialize(
+        loss_fn=gpt2.loss_fn(cfg),
+        params=gpt2.init_params(jax.random.PRNGKey(0), cfg),
+        config={"train_micro_batch_size_per_gpu": 1, "mesh": {"data": 8},
+                "zero_optimization": extra,
+                "optimizer": {"type": opt, "params": {"lr": 1e-3}}})
+    assert engine.grad_comm_mode == mode
+    tokens = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (8, 25), dtype=np.int32)
+    losses = [float(engine.train_batch({"tokens": tokens}))
+              for _ in range(3)]
+    assert seen and all(m is None for m in seen)
+    assert np.isfinite(losses).all() and losses[-1] < losses[0]
