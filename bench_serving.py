@@ -206,10 +206,8 @@ def measure_config(name, args, params, mod, cfg, phase, prompts,
         config["kernels"] = kernels
     # device-truth observability rides every row: the compile sentinel
     # proves the steady-state run never recompiled (bench_gate pins
-    # detail.devprof.steady_state_compiles at 0) and MFU/MBU land next
-    # to tokens/s.  A modest sample rate keeps the sampled
-    # block_until_ready syncs out of the throughput signal
-    config["devprof"] = {"sample_rate": 0.05}
+    # detail.devprof.steady_state_compiles at 0)
+    config["devprof"] = True
     # SLO classification rides every row (--slo-ttft-ms 0 disables):
     # the same engine that reports tokens/s reports how many of those
     # tokens came from requests that met their latency objective —

@@ -285,13 +285,12 @@ def serve_phase(size, seed, cache, on_tpu):
     mark, t0 = cache.mark(), time.perf_counter()
     # devprof's build-time warm-up compiles every program the engine can
     # dispatch, so what compiles later is counted as a steady-state
-    # compile; its sampled syncs and cost pass are not wanted here
+    # compile
     eng = serving_engine(
         params, cfg, max_batch=size.max_batch, page_size=size.page_size,
         num_pages=size.max_batch * pages_per_seq + 1,
         max_seq=cfg.max_seq_len, prefill_bucket=size.prefill_bucket,
-        telemetry=True,
-        devprof={"sample_rate": 0.0, "cost_analysis": False})
+        telemetry=True, devprof=True)
     cold_s = time.perf_counter() - t0
     cache_use = cache.since(mark)
 

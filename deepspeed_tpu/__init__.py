@@ -17,6 +17,10 @@ Public entrypoints mirror the reference:
   (ref: deepspeed/inference/engine.py).
 """
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()
+
 __version__ = "0.1.0"
 
 from deepspeed_tpu import mesh
@@ -59,3 +63,6 @@ def add_config_arguments(parser):
         help="Accepted for launcher compatibility; ranks come from JAX.",
     )
     return parser
+
+# what this file's imports cost (the gauge ``package_import_seconds``)
+IMPORT_SECONDS = _time.perf_counter() - _T_IMPORT

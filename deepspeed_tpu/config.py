@@ -1374,39 +1374,27 @@ class DevprofConfig:
     fifth observability pillar next to ``telemetry``/``tracing``/
     ``history``/``incidents`` — see :mod:`deepspeed_tpu.devprof`).
 
-    Three coupled capabilities: a **compile sentinel** (every XLA
-    compile attributed to a call-site ledger, split warmup vs
-    steady-state — a steady-state recompile is a contract violation
-    and trips an incident), **per-phase device-time attribution**
-    (sampled ``block_until_ready`` deltas on a ``sample_rate``
-    cadence feeding ``devprof_device_seconds{phase}`` counters plus a
-    host-vs-device gap gauge), and **roofline accounting** (one-time
-    ``cost_analysis`` of the compiled sweep programs at engine build
-    combined with sampled device time into live MFU/MBU gauges).
-    ``sample_rate`` thins PER DISPATCH deterministically (0.05 times
-    one dispatch in 20 per phase; 0 disables the sync entirely);
+    A **compile sentinel**: every XLA compile at the serving engine's
+    jit sites is attributed to a call site with the build ledger's
+    trace, lowering, cache-load and compile seconds for it, split
+    warmup vs steady-state — a steady-state recompile is a contract
+    violation and trips an incident — and the engine's build-time
+    warm-up dispatches every program once, so that the split is honest.
     ``capture_max_s`` caps on-demand ``/profilez?capture_s=`` device
-    traces (written under ``tracing.dump_dir``); ``cost_analysis``
-    gates the build-time roofline pass (the only part that touches
-    XLA's cost model).
+    traces (written under ``tracing.dump_dir``).  Keys this block no
+    longer has (``sample_rate``, ``cost_analysis``: the sampled
+    device-time and roofline halves went with PR 37) are dropped, not
+    refused: a caller's dictionary that names them still builds.
     """
 
     enabled: bool = False
-    sample_rate: float = 0.05            # per-dispatch; 0 = no syncs
     capture_max_s: float = 10.0          # /profilez duration cap
-    cost_analysis: bool = True           # roofline pass at build
 
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> "DevprofConfig":
         known = {f.name for f in dataclasses.fields(cls)}
         c = cls(**{k: v for k, v in d.items() if k in known})
-        c.sample_rate = float(c.sample_rate)
         c.capture_max_s = float(c.capture_max_s)
-        c.cost_analysis = bool(c.cost_analysis)
-        if not 0.0 <= c.sample_rate <= 1.0:
-            raise ValueError(
-                f"devprof.sample_rate must be in [0, 1], got "
-                f"{c.sample_rate}")
         if c.capture_max_s <= 0:
             raise ValueError(
                 f"devprof.capture_max_s must be positive, got "

@@ -1,46 +1,41 @@
-"""Device-truth observability: compile sentinel, per-phase device time,
-and roofline accounting (no reference analogue; the fifth observability
-pillar next to telemetry/tracing/history/incidents).
+"""Device-truth observability: the build ledger and the compile sentinel
+(no reference analogue; the fifth observability pillar next to
+telemetry/tracing/history/incidents).
 
-Every other timing surface in the repo is host wall-time
-(``perf_counter`` in telemetry/request_trace), but the perf contract
-lives on the device: the serving engine's prewarm/bucket-pad discipline
-exists solely to keep XLA compiles out of TTFT, and ZeRO-Infinity's
-(arXiv:2104.07857) efficiency claims are bandwidth/roofline claims.
-This module closes the gap with three coupled capabilities:
+The serving engine's prewarm/bucket-pad discipline exists solely to
+keep XLA compiles out of TTFT, and a build is mostly the making ready
+of programs.  This module says what each program cost, and holds the
+build to its contract:
 
-- **Compile sentinel**: every XLA compile is attributed to a call-site
-  ledger with timestamps, counted warmup vs **steady-state** (post
-  first-token of the first request), and emitted as ``xla_compile``
-  flight-recorder events on their own Chrome track.  Attribution comes
-  from counting wrappers at the project's jit call sites (installed by
-  the engine around the programs ``_build_programs`` produced) via the
-  jitted function's ``_cache_size()`` — cheap, exact per site.  A
-  process-wide ``jax.monitoring`` duration listener (installed once by
-  :func:`install_compile_listener`, which the first :class:`DevProf`
-  calls) pairs best-effort compile DURATIONS with the wrapper's
-  counts.  A steady-state recompile is a **contract violation**: the
-  incident probe trips a ``steady_state_recompile`` bundle and the
+- **Build ledger**: one process-wide ``jax.monitoring`` listener
+  (:func:`install_compile_listener`, idempotent; installed when this
+  module is imported) takes JAX's own duration events by ``fun_name``
+  and appends to the bounded :data:`BUILD_LEDGER` one entry a program
+  made ready: ``{program, t_end, trace_s, lower_s, cache_load_s,
+  compile_s, cache_hit, steady}``, and ``lower_faults``
+  (:func:`_faults`).  A program is what ends in one
+  ``backend_compile_duration``; before it, on the same thread, come its
+  ``jaxpr_trace_duration``, its ``jaxpr_to_mlir_module_duration`` and,
+  from the persistent cache, ``cache_hits`` or nothing.  The callbacks
+  run *after* each phase: no frame of theirs is under the lowering
+  loop.  Programs the project did not name (``dstpu_*``) go to one
+  aggregate.  ``t_end`` is ``time.perf_counter()``.  The ledger hangs
+  on no registry; :class:`BuildCounters` mirrors one engine's build
+  into its registry where that is enabled.
+
+- **Compile sentinel**: counting wrappers at the project's jit call
+  sites detect a compile by the jitted function's ``_cache_size()`` —
+  cheap, exact per site — and claim the ledger's entry of that name
+  made on their own thread, so a site's seconds are its own.  Counted
+  warmup vs **steady-state** (post first-token of the first request),
+  and emitted as ``xla_compile`` flight-recorder events on their own
+  Chrome track.  A steady-state recompile is a **contract violation**:
+  the incident probe trips a ``steady_state_recompile`` bundle and the
   bench gate pins ``steady_state_recompiles == 0``.
-
-- **Per-phase device-time attribution**: sampled timed dispatches
-  (rate-limited ``block_until_ready`` deltas on the
-  ``devprof.sample_rate`` cadence) feed
-  ``devprof_device_seconds_{prefill|decode|spec_verify|promote|sample}``
-  counters plus a host-vs-device gap gauge (how far the async dispatch
-  queue runs ahead of the host).
-
-- **Roofline accounting**: the engine cost-analyzes its compiled sweep
-  programs once at build (:mod:`deepspeed_tpu.profiler`'s
-  ``cost_analysis`` path), the sentinel wrappers accumulate the
-  per-dispatch flops/bytes estimates, and :meth:`DevProf.tick` turns
-  the counter deltas into live MFU/MBU gauges against
-  :func:`~deepspeed_tpu.timers.device_peak_flops` /
-  :func:`~deepspeed_tpu.timers.device_peak_bandwidth`.
 
 On-demand device traces: ``/profilez?capture_s=`` runs a bounded
 ``jax.profiler`` capture under ``tracing.dump_dir``; the capture
-reference and the compile ledger ride incident bundles.
+reference and the engine's compiles ride incident bundles.
 """
 
 from __future__ import annotations
@@ -49,134 +44,371 @@ import collections
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
+try:                            # Linux: a thread's own page faults
+    from resource import RUSAGE_THREAD, getrusage
+except ImportError:             # pragma: no cover
+    getrusage = None
+
 from deepspeed_tpu.config import DevprofConfig
 from deepspeed_tpu.telemetry import mark as telemetry_mark
-from deepspeed_tpu.timers import device_peak_bandwidth, device_peak_flops
-
-# ------------------------------------------------------ phase vocabulary
-# The phase names of the sampled device-time counters and of
-# trace_report's device-time column.
-PHASES = ("prefill", "decode", "spec_verify", "promote", "sample")
-
-# default phase each sentinel site's dispatches attribute to
-SITE_PHASES = {
-    "prefill": "prefill",
-    "chunk_prefill": "prefill",
-    "decode_chunk": "decode",
-    "spec_verify": "spec_verify",
-}
 
 # ------------------------------------------------- monitoring listener
 # jax.monitoring has no per-listener unregister (only a global clear),
-# so the process installs EXACTLY ONE duration listener, guarded here;
-# every DevProf instance reads the shared recent-durations ring.
-_COMPILE_EVENT_SUFFIX = "backend_compile_duration"
+# so the process installs its listeners EXACTLY ONCE, guarded here.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+NAMED_PREFIX = "dstpu_"         # the programs the project named
+_MAX_TRACES = 1 << 16           # traces kept a thread between programs
+_INNER_KEPT = 10                # inner traces kept an entry, by seconds
 _listener_lock = threading.Lock()
 _listener_installed = False
-# (monotonic_t, duration_s) of recent backend compiles — best-effort
-# pairing material for the wrappers' exact per-site counts
-_recent_durations: "collections.deque" = collections.deque(maxlen=64)
+
+
+def _faults() -> int:
+    """Minor page faults of the calling thread so far.  A call that
+    crosses the end of a 16 KiB chunk of CPython's frame stack maps a
+    chunk in, and its return maps it out again: where the frames under
+    a lowering end decides how often its recursion pays that (40 or
+    1,900 faults a program, and seconds with them: PERF.md 6, PR 37)."""
+    return getrusage(RUSAGE_THREAD).ru_minflt if getrusage else 0
+
+
+class _Thread(threading.local):
+    """What one thread has seen since its last program: the phases of a
+    program arrive in order on the thread that makes it ready."""
+
+    def __init__(self):
+        # (name, seconds, t_end, the thread's page faults at t_end)
+        self.traces: List[Tuple[str, float, float, int]] = []
+        # (program, lower_s, trace_s, inner, lowering, lower_faults)
+        self.lowered = None
+        self.hit = False
+        self.mirror: Optional["BuildCounters"] = None
+        # named entries made ready here: a sentinel's or a span's to claim
+        self.made: "collections.deque" = collections.deque(maxlen=32)
+        self.named = 0
+        # programs, cache misses, trace, lower, cache-load, compile
+        self.sums = [0, 0, 0.0, 0.0, 0.0, 0.0]
+
+
+_tl = _Thread()
+
+
+def _program(fun_name) -> str:
+    """``jit(dstpu_prefill)`` -> ``dstpu_prefill`` (a trace's bare name)."""
+    name = str(fun_name)
+    if name.endswith(")") and "(" in name:
+        return name[name.index("(") + 1:-1]
+    return name
+
+
+def _largest(seconds: Dict[str, float]) -> Dict[str, float]:
+    top = sorted(seconds.items(), key=lambda kv: -kv[1])[:_INNER_KEPT]
+    return {k: round(v, 6) for k, v in top}
+
+
+def _lowered(program: str, lower_s: float):
+    """A lowering ended: find its trace among the thread's.  The
+    outermost trace ends last, so it is the last one of the program's
+    name; traces that ended inside its span are its inner functions
+    (their seconds are inside its own), and traces after it ran while
+    lowering (a threefry expansion, a kernel's body): inside ``lower_s``."""
+    tl = _tl
+    traces, tl.traces = tl.traces, []
+    at = len(traces) - 1
+    while at >= 0 and traces[at][0] != program:
+        at -= 1
+    if at < 0:
+        return program, lower_s, 0.0, {}, {}, 0
+    _, trace_s, t_end, faults = traces[at]
+    began = t_end - trace_s - 1e-4
+    inner: Dict[str, float] = {}
+    for name, s, t, _ in reversed(traces[:at]):
+        if t < began:
+            break
+        inner[name] = inner.get(name, 0.0) + s
+    lowering: Dict[str, float] = {}
+    for name, s, _, _ in traces[at + 1:]:
+        lowering[name] = lowering.get(name, 0.0) + s
+    return (program, lower_s, trace_s, _largest(inner),
+            _largest(lowering), _faults() - faults)
 
 
 def _on_event_duration(event: str, duration: float, **kw) -> None:
-    if str(event).endswith(_COMPILE_EVENT_SUFFIX):
-        _recent_durations.append((time.monotonic(), float(duration)))
+    if event == _TRACE_EVENT:           # thousands a program: first
+        traces = _tl.traces
+        if len(traces) >= _MAX_TRACES:  # traced, never lowered
+            del traces[:]
+        name = kw.get("fun_name")
+        # a named program's lowering counts its page faults from here
+        traces.append((name, duration, time.perf_counter(),
+                       _faults() if str(name).startswith(NAMED_PREFIX)
+                       else 0))
+    elif event == _LOWER_EVENT:
+        _tl.lowered = _lowered(_program(kw.get("fun_name")),
+                               float(duration))
+    elif event == _COMPILE_EVENT:
+        BUILD_LEDGER.made_ready(_program(kw.get("fun_name")),
+                                float(duration))
+
+
+def _on_event(event: str, **kw) -> None:
+    # too small for the cache, a program reports neither hit nor miss
+    if event == _CACHE_HIT_EVENT:
+        _tl.hit = True
 
 
 def install_compile_listener() -> bool:
-    """Install the process-wide compile-duration listener (idempotent).
-    Returns True: the installed JAX has ``jax.monitoring`` (the wrappers
-    pair its durations with their own exact counts)."""
+    """Install the process-wide build listeners (idempotent)."""
     global _listener_installed
     with _listener_lock:
         if not _listener_installed:
             jax.monitoring.register_event_duration_secs_listener(
                 _on_event_duration)
+            jax.monitoring.register_event_listener(_on_event)
             _listener_installed = True
     return True
 
 
-def compile_listener_installed() -> bool:
-    return _listener_installed
+# --------------------------------------------------------- build ledger
+class BuildLedger:
+    """Bounded record of every program the process made ready: which,
+    when (``perf_counter``), its trace, lowering, cache-load and compile
+    seconds, from the cache or not, warmup or steady-state.  Programs
+    named ``dstpu_*`` keep an entry each; the rest (eager fills, a
+    caller's own jits) are one aggregate.  Sums never drop; entries are
+    bounded.  Thread-safe; snapshot() is what /statusz carries."""
 
-
-def _take_recent_duration(max_age_s: float = 60.0) -> Optional[float]:
-    """Pop the newest compile duration observed within ``max_age_s`` —
-    best-effort pairing (a concurrent engine's compile can steal it;
-    counts stay exact either way, only the duration column is
-    heuristic)."""
-    now = time.monotonic()
-    try:
-        while _recent_durations:
-            t, d = _recent_durations.pop()
-            if now - t <= max_age_s:
-                return d
-    except IndexError:
-        pass
-    return None
-
-
-# ------------------------------------------------------- compile ledger
-class CompileLedger:
-    """Append-only (bounded) record of every attributed XLA compile:
-    which call site, when, warmup or steady-state, and the best-effort
-    backend duration.  Thread-safe; snapshot() is what incident
-    bundles and /statusz carry."""
-
-    def __init__(self, capacity: int = 256):
+    def __init__(self, capacity: int = 256, other_capacity: int = 1024):
         self._lock = threading.Lock()
         self._entries: "collections.deque" = collections.deque(
             maxlen=int(capacity))
-        self.warmup = 0
-        self.steady = 0
+        # (t_end, program, seconds) of the programs without an entry
+        self._other: "collections.deque" = collections.deque(
+            maxlen=int(other_capacity))
+        self.other_programs = 0
+        self.other_seconds = 0.0
+        # programs, cache misses, trace, lower, cache-load, compile
+        self.sums = [0, 0, 0.0, 0.0, 0.0, 0.0]
 
-    def record(self, site: str, steady: bool, n: int = 1,
-               duration_s: Optional[float] = None) -> Dict[str, Any]:
-        entry = {
-            "site": str(site),
-            "t": time.strftime("%Y-%m-%dT%H:%M:%S"),
-            "t_monotonic": round(time.monotonic(), 3),
-            "phase": "steady" if steady else "warmup",
-            "n": int(n),
-            "duration_s": (round(float(duration_s), 6)
-                           if duration_s is not None else None),
-        }
-        with self._lock:
-            self._entries.append(entry)
-            if steady:
-                self.steady += n
-            else:
-                self.warmup += n
-        return entry
-
-    def snapshot(self) -> Dict[str, Any]:
-        with self._lock:
-            return {
-                "warmup_compiles": self.warmup,
-                "steady_state_compiles": self.steady,
-                "entries": list(self._entries),
+    def made_ready(self, program: str, seconds: float) -> None:
+        """The listener's: ``program`` ended its backend compile on this
+        thread, ``seconds`` of it (on a cache hit the retrieval and
+        load, on a miss the compiler's)."""
+        tl = _tl
+        low, tl.lowered = tl.lowered, None
+        hit, tl.hit = tl.hit, False
+        if low is None or low[0] != program:
+            low = (program, 0.0, 0.0, {}, {}, 0)
+        _, lower_s, trace_s, inner, lowering, lower_faults = low
+        load_s, compile_s = (seconds, 0.0) if hit else (0.0, seconds)
+        parts = (1, 0 if hit else 1, trace_s, lower_s, load_s, compile_s)
+        t_end = time.perf_counter()
+        entry = None
+        if program.startswith(NAMED_PREFIX):
+            entry = {
+                "program": program,
+                "t_end": t_end,
+                "trace_s": round(trace_s, 6),
+                "lower_s": round(lower_s, 6),
+                "cache_load_s": round(load_s, 6),
+                "compile_s": round(compile_s, 6),
+                "cache_hit": hit,
+                "steady": False,
+                "inner_trace_s": inner,
+                "lowering_trace_s": lowering,
+                # the thread's minor page faults while it lowered
+                "lower_faults": lower_faults,
+                # a sentinel's and a build span's, when one claims it
+                "site": None, "span": None, "run_s": None,
             }
+            tl.made.append(entry)
+            tl.named += 1
+        total = trace_s + lower_s + seconds
+        with self._lock:
+            self.sums = [a + b for a, b in zip(self.sums, parts)]
+            if entry is not None:
+                self._entries.append(entry)
+            else:
+                self._other.append((t_end, program, total))
+                self.other_programs += 1
+                self.other_seconds += total
+        tl.sums = [a + b for a, b in zip(tl.sums, parts)]
+        if tl.mirror is not None:
+            tl.mirror.add(parts)
+
+    def claim(self, program: Optional[str], n: int, site: str,
+              steady: bool) -> List[Dict[str, Any]]:
+        """The newest ``n`` unclaimed entries of ``program`` made ready
+        on THIS thread, now the ``site``'s: exact, because a sentinel
+        asks on the thread its dispatch compiled on, as it returns."""
+        out: List[Dict[str, Any]] = []
+        for e in reversed(_tl.made):
+            if len(out) >= n:
+                break
+            if e["program"] == program and e["site"] is None:
+                e["site"], e["steady"] = site, bool(steady)
+                out.append(e)
+        return out[::-1]
+
+    def mark(self) -> Tuple:
+        """Now, and this thread's sums so far: what :meth:`since` takes."""
+        return (time.perf_counter(), *_tl.sums)
+
+    def since(self, mark: Tuple) -> str:
+        """What this thread made ready since ``mark``, for a log line."""
+        n, miss, tr, lo, ld, co = (
+            b - a for a, b in zip(mark[1:], _tl.sums))
+        return ("%d programs (%d compiled) in %.1fs: trace %.2f, lower "
+                "%.2f, cache load %.2f, compile %.2f"
+                % (n, miss, time.perf_counter() - mark[0], tr, lo, ld, co))
+
+    def snapshot(self, rows: bool = False,
+                 last: Optional[int] = None) -> Dict[str, Any]:
+        """JSON-safe.  ``rows``: the unnamed programs one by one too
+        (``[t_end, program, seconds]``), for a reader that splits them
+        at an instant.  ``last``: no more than that many entries."""
+        with self._lock:
+            n, miss, tr, lo, ld, co = self.sums
+            names: Dict[str, List[float]] = {}
+            for _, program, seconds in self._other:     # the rows kept
+                row = names.setdefault(program, [0.0, 0])
+                row[0] += seconds
+                row[1] += 1
+            top = sorted(names.items(), key=lambda kv: -kv[1][0])[:5]
+            other = {
+                "programs": self.other_programs,
+                "seconds": round(self.other_seconds, 6),
+                "top": [[k, round(v[0], 6), v[1]] for k, v in top],
+            }
+            if rows:
+                other["rows"] = [list(r) for r in self._other]
+            return {
+                "programs": n, "cache_misses": miss,
+                "trace_s": round(tr, 6), "lower_s": round(lo, 6),
+                "cache_load_s": round(ld, 6), "compile_s": round(co, 6),
+                "entries": [dict(e) for e in
+                            list(self._entries)[-(last or 0):]],
+                "other": other,
+            }
+
+
+BUILD_LEDGER = BuildLedger()
+
+
+# the ledger's sums, in their order, as one engine's registry has them
+_BUILD_COUNTERS = (
+    ("build_programs", "programs made ready while this engine was built "
+     "(from the compile cache or by the compiler)"),
+    ("build_cache_misses", "of build_programs, those the compiler built: "
+     "the persistent cache had none, or keeps none so small"),
+    ("build_trace_seconds", "tracing the build's programs to jaxprs"),
+    ("build_lower_seconds", "lowering the build's jaxprs to StableHLO"),
+    ("build_cache_load_seconds", "fetching and loading the build's "
+     "programs from the persistent compile cache"),
+    ("build_compile_seconds", "the compiler's seconds on the build's "
+     "cache misses"),
+)
+
+
+class BuildCounters:
+    """One engine's build in its registry: the programs made ready on
+    the building thread between :meth:`attach` and :meth:`built`,
+    advanced by the listener's own callback; ``build_seconds`` is the
+    wall time attached.  With the registry disabled nothing is
+    attached: the process-wide ledger has the build all the same."""
+
+    def __init__(self, registry):
+        from deepspeed_tpu import IMPORT_SECONDS
+
+        r = registry
+        self._on = r.enabled
+        self._c = [r.counter(name, text) for name, text in _BUILD_COUNTERS]
+        self._g_build = r.gauge(
+            "build_seconds",
+            "wall time of the engine's constructor (training: and of "
+            "its first step)")
+        r.gauge("package_import_seconds",
+                "first to last line of deepspeed_tpu/__init__.py"
+                ).set(IMPORT_SECONDS)
+        self._seconds = 0.0
+        self.attach()
+
+    def attach(self) -> None:
+        self._t0 = time.perf_counter()
+        if self._on:
+            _tl.mirror = self
+
+    def add(self, parts) -> None:
+        for c, v in zip(self._c, parts):
+            c.inc(v)
+
+    def built(self) -> None:
+        self._seconds += time.perf_counter() - self._t0
+        self._g_build.set(self._seconds)
+        if _tl.mirror is self:
+            _tl.mirror = None
+
+
+class ProgramSpan:
+    """``with span(site, end=128):`` around ONE dispatch that makes a
+    program ready: the telemetry span it was built on (annotated with
+    the site and the shape word), and on exit the entry that dispatch
+    made gets the word and ``run_s``, the span's wall time less every
+    part the ledger timed: the program's first run plus dispatch.  A
+    context manager, so no frame of it is under the dispatch."""
+
+    __slots__ = ("_span", "_site", "_word", "_t0", "_named", "_timed")
+
+    def __init__(self, span):
+        self._span = span
+        self._site, self._word = "", {}
+
+    def __call__(self, site: str, **word):
+        self._site, self._word = site, word
+        return self
+
+    def __enter__(self):
+        tl = _tl
+        self._named, self._timed = tl.named, sum(tl.sums[2:])
+        self._span(site=self._site, **self._word).__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        wall = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        tl = _tl
+        if tl.named > self._named:
+            e = tl.made[-1]
+            e["span"] = " ".join(
+                [self._site] + [f"{k}={v}" for k, v in self._word.items()])
+            e["run_s"] = round(
+                max(wall - (sum(tl.sums[2:]) - self._timed), 0.0), 6)
+        return False
 
 
 # ----------------------------------------------------- sentinel wrapper
 class _SentinelFn:
     """Counting wrapper around one compiled program: detects compiles
     via the jitted function's ``_cache_size()`` delta (exact, per call
-    site) and accumulates the site's cost-analysis flops/bytes per
-    dispatch.  Transparent for non-jit callables (the ZeRO-Inference
-    streamed executors): no cache to watch, dispatch accounting only.
-    ``lower`` passes through for the build-time cost analysis."""
+    site).  Transparent for non-jit callables (the ZeRO-Inference
+    streamed executors): no cache to watch.  ``lower`` passes through
+    (``jfn.lower(...)`` is the AOT door)."""
 
-    __slots__ = ("jfn", "site", "_dp", "_last_n")
+    __slots__ = ("jfn", "site", "_dp", "_last_n", "_program")
 
     def __init__(self, jfn, site: str, dp: "DevProf"):
         self.jfn = jfn
         self.site = str(site)
         self._dp = dp
+        self._program = getattr(jfn, "__name__", None)
         self._last_n = self._cache_size()
 
     def _cache_size(self) -> Optional[int]:
@@ -196,9 +428,9 @@ class _SentinelFn:
             # bump is visible the moment the dispatch returns
             n = self._cache_size()
             if n is not None and n != self._last_n:
-                self._dp.on_compile(self.site, max(n - self._last_n, 1))
+                self._dp.on_compile(self.site, max(n - self._last_n, 1),
+                                    self._program)
                 self._last_n = n
-        self._dp.on_dispatch(self.site)
         return out
 
     def lower(self, *a, **kw):
@@ -207,26 +439,26 @@ class _SentinelFn:
 
 # --------------------------------------------------------------- devprof
 class DevProf:
-    """One engine's device-truth profiler (single-writer: every mutator
+    """One engine's compile sentinel (single-writer: every mutator
     runs on the engine thread except :meth:`profilez`, which the HTTP
     thread serializes through ``_capture_lock``)."""
 
     def __init__(self, cfg: DevprofConfig, *, registry, tracer=None,
-                 dump_dir: str = "/tmp/dstpu_flight",
-                 clock=time.perf_counter):
+                 dump_dir: str = "/tmp/dstpu_flight"):
         self.cfg = cfg
         self.enabled = bool(cfg.enabled)
         self.registry = registry
         self.tracer = tracer
         self.dump_dir = str(dump_dir)
-        self._clock = clock
-        self.ledger = CompileLedger()
+        self.ledger = BUILD_LEDGER
+        self.compiles_warmup = 0
+        self.compiles_steady = 0
+        # what this engine's sites compiled: {site, n, steady, t,
+        # duration_s, entries}; incident bundles carry it
+        self._compiles: "collections.deque" = collections.deque(maxlen=64)
         self.steady = False
-        self._steady_t: Optional[float] = None
         self._capture_lock = threading.Lock()
         self.captures: List[Dict[str, Any]] = []
-        # monitoring is the duration source; absence is fine (wrappers
-        # alone count) — record which mode we're in for /statusz
         self.monitoring = install_compile_listener()
         r = registry
         self._c_comp_warm = r.counter(
@@ -238,68 +470,7 @@ class DevProf:
             "XLA compiles attributed AFTER steady state began — each "
             "one is a shape-discipline contract violation and trips a "
             "steady_state_recompile incident")
-        self._c_dev = {
-            "prefill": r.counter(
-                "devprof_device_seconds_prefill",
-                "sampled device-completion seconds of prefill "
-                "dispatches (block_until_ready deltas on the "
-                "devprof.sample_rate cadence)"),
-            "decode": r.counter(
-                "devprof_device_seconds_decode",
-                "sampled device-completion seconds of decode-chunk "
-                "dispatches"),
-            "spec_verify": r.counter(
-                "devprof_device_seconds_spec_verify",
-                "sampled device-completion seconds of speculative "
-                "verify sweeps"),
-            "promote": r.counter(
-                "devprof_device_seconds_promote",
-                "sampled device-completion seconds of KV-tier promote "
-                "scatters"),
-            "sample": r.counter(
-                "devprof_device_seconds_sample",
-                "sampled device-completion seconds of batched "
-                "boundary-sampling fetches"),
-        }
-        self._c_sampled = r.counter(
-            "devprof_sampled_dispatches",
-            "dispatches that paid the sampled block_until_ready sync "
-            "(the devprof.sample_rate numerator)")
-        self._g_gap = r.gauge(
-            "devprof_host_device_gap_seconds",
-            "EWMA of device-completion wait observed AFTER the host "
-            "dispatch returned — how far the async dispatch queue "
-            "runs ahead of the host clock (why host timings lie)")
-        self._g_mfu = r.gauge(
-            "devprof_mfu",
-            "model flops utilization: cost-analysis flops dispatched "
-            "per wall second / device peak flops")
-        self._g_mbu = r.gauge(
-            "devprof_mbu",
-            "memory bandwidth utilization: cost-analysis bytes "
-            "accessed per wall second / device peak HBM bandwidth")
-        self._c_flops = r.counter(
-            "devprof_flops_total",
-            "cost-analysis flops dispatched (per-site XLA estimate x "
-            "dispatch count — the MFU numerator)")
-        self._c_bytes = r.counter(
-            "devprof_bytes_total",
-            "cost-analysis bytes accessed (per-site XLA estimate x "
-            "dispatch count — the MBU numerator)")
-        # deterministic per-phase stride: every round(1/rate)-th
-        # dispatch pays the sync — no RNG on the hot path
-        self._stride = (int(round(1.0 / cfg.sample_rate))
-                        if cfg.sample_rate > 0 else 0)
-        self._phase_n = {p: 0 for p in PHASES}
-        self._costs: Dict[str, Dict[str, float]] = {}
-        self._gap_ewma: Optional[float] = None
-        # roofline tick state (counter deltas over wall intervals)
-        self._tick_t: Optional[float] = None
-        self._tick_flops = 0.0
-        self._tick_bytes = 0.0
         self._probe_seen = 0            # incident-probe cursor
-        self.peak_flops = device_peak_flops()
-        self.peak_bw = device_peak_bandwidth()
 
     # --------------------------------------------------------- wiring
     def wrap(self, site: str, jfn):
@@ -308,132 +479,43 @@ class DevProf:
             return None
         return _SentinelFn(jfn, site, self)
 
-    def register_cost(self, site: str, flops: float,
-                      bytes_accessed: float) -> None:
-        self._costs[str(site)] = {"flops": float(flops),
-                                  "bytes_accessed": float(bytes_accessed)}
-
-    def cost_analyze(self, site: str, jfn, *args, **kw) -> bool:
-        """Build-time roofline pass: lower+compile ``jfn`` at the
-        given (abstract) args and record the compiler's flops/bytes
-        estimate for ``site``.  Best-effort — a backend without
-        ``cost_analysis`` (or a non-jit executor with no ``lower``)
-        just leaves the site uncosted."""
-        if not self.cfg.cost_analysis:
-            return False
-        lower = getattr(jfn, "lower", None)
-        if lower is None:
-            return False
-        try:
-            from deepspeed_tpu.profiler import xla_cost_analysis_lowered
-
-            cost = xla_cost_analysis_lowered(lower(*args, **kw))
-        except Exception:
-            return False
-        if not cost:
-            return False
-        self.register_cost(site, cost.get("flops", 0.0),
-                           cost.get("bytes_accessed", 0.0))
-        return True
-
     # ------------------------------------------------------- sentinel
     def mark_steady(self) -> None:
         """Flip warmup → steady state (the engine calls this at the
         first token of the first request).  From here every attributed
         compile is a contract violation."""
-        if not self.steady:
-            self.steady = True
-            self._steady_t = time.monotonic()
+        self.steady = True
 
-    def on_compile(self, site: str, n: int = 1) -> None:
+    def on_compile(self, site: str, n: int = 1,
+                   program: Optional[str] = None) -> None:
         """A sentinel wrapper detected ``n`` fresh compiles at
-        ``site``: ledger + counters + an ``xla_compile`` event on its
-        own Chrome track (steady-state ones are flagged)."""
-        dur = _take_recent_duration() if self.monitoring else None
-        entry = self.ledger.record(site, self.steady, n, dur)
+        ``site``: the ledger's entries of ``program`` become the
+        site's, counters + an ``xla_compile`` event on its own Chrome
+        track (steady-state ones are flagged)."""
+        entries = self.ledger.claim(program, n, site, self.steady)
+        dur = (round(sum(e["trace_s"] + e["lower_s"] + e["cache_load_s"]
+                         + e["compile_s"] for e in entries), 6)
+               if entries else None)
+        self._compiles.append({
+            "site": str(site), "n": int(n),
+            "t": time.strftime("%Y-%m-%dT%H:%M:%S"),
+            "phase": "steady" if self.steady else "warmup",
+            "duration_s": dur, "entries": entries})
         if self.steady:
+            self.compiles_steady += n
             self._c_comp_steady.inc(n)
             # on the profiler's clock too: a capture shows WHICH step
             # recompiled, and at which site
             telemetry_mark(f"{self.registry.namespace}/xla_compile",
                            site=site, n=n)
         else:
+            self.compiles_warmup += n
             self._c_comp_warm.inc(n)
         if self.tracer is not None and self.tracer.enabled:
             self.tracer.event("xla_compile", attrs={
                 "site": site, "n": n,
                 "steady": self.steady,
-                "duration_s": entry["duration_s"]})
-
-    # dstpu: hot-path
-    def on_dispatch(self, site: str) -> None:
-        """Per-dispatch roofline accounting: add the site's one-time
-        cost-analysis estimate to the flops/bytes counters (two float
-        adds; uncosted sites cost one dict miss)."""
-        c = self._costs.get(site)
-        if c is not None:
-            self._c_flops.inc(c["flops"])
-            self._c_bytes.inc(c["bytes_accessed"])
-
-    # ------------------------------------------------------- sampling
-    # dstpu: hot-path
-    def should_sample(self, phase: str) -> bool:
-        """Deterministic stride gate: True on every
-        ``round(1/sample_rate)``-th dispatch of ``phase``."""
-        if self._stride == 0:
-            return False
-        n = self._phase_n[phase] + 1
-        self._phase_n[phase] = n
-        return n % self._stride == 0
-
-    # dstpu: hot-path
-    def observe_device(self, phase: str, value) -> float:
-        """Time a sampled dispatch's device completion: the wait from
-        host-dispatch-return to ready IS the host-vs-device gap the
-        gauge tracks."""
-        t0 = self._clock()
-        # dstpu: host-sync-ok: sampled devprof attribution — one
-        # block_until_ready per round(1/sample_rate) dispatches of
-        # this phase, the module's documented measurement sync
-        jax.block_until_ready(value)
-        dt = self._clock() - t0
-        self.record_device(phase, dt, gap=dt)
-        return dt
-
-    # dstpu: hot-path
-    def record_device(self, phase: str, dev_s: float,
-                      gap: Optional[float] = None) -> None:
-        """Record an already-measured device-time sample (sites whose
-        existing host sync brackets the device work — the boundary
-        sample fetch — time themselves and report here)."""
-        self._c_dev[phase].inc(dev_s)
-        self._c_sampled.inc()
-        if gap is not None:
-            e = self._gap_ewma
-            self._gap_ewma = gap if e is None else 0.8 * e + 0.2 * gap
-            self._g_gap.set(self._gap_ewma)
-        if self.tracer is not None and self.tracer.enabled:
-            self.tracer.event("devprof_sample", attrs={
-                "devprof_phase": phase, "dev_s": round(dev_s, 6)})
-
-    # ------------------------------------------------------- roofline
-    def tick(self, now: Optional[float] = None) -> None:
-        """Exporter tick hook: turn flops/bytes counter deltas over
-        the wall interval into live MFU/MBU gauges.  Rate-limited
-        internally (~2/s) so the exporter-less inline path can call it
-        every step without shrinking dt toward noise."""
-        now = time.monotonic() if now is None else now
-        if self._tick_t is not None and now - self._tick_t < 0.5:
-            return
-        f, b = self._c_flops.value, self._c_bytes.value
-        if self._tick_t is not None:
-            dt = now - self._tick_t
-            if dt > 0:
-                self._g_mfu.set((f - self._tick_flops) / dt /
-                                self.peak_flops)
-                self._g_mbu.set((b - self._tick_bytes) / dt /
-                                self.peak_bw)
-        self._tick_t, self._tick_flops, self._tick_bytes = now, f, b
+                "duration_s": dur})
 
     # -------------------------------------------------------- capture
     def capture(self, duration_s: float) -> Dict[str, Any]:
@@ -495,58 +577,52 @@ class DevProf:
 
     # ----------------------------------------------------------- read
     def statusz_block(self) -> Dict[str, Any]:
-        led = self.ledger.snapshot()
-        dev = {p: round(float(self._c_dev[p].value), 6) for p in PHASES}
         return {
             "enabled": True,
             "steady": self.steady,
             "monitoring": self.monitoring,
-            "sample_rate": self.cfg.sample_rate,
-            "compiles_warmup": led["warmup_compiles"],
-            "compiles_steady": led["steady_state_compiles"],
-            "device_seconds": dev,
-            "host_device_gap_s": (round(self._gap_ewma, 6)
-                                  if self._gap_ewma is not None
-                                  else None),
-            "mfu": round(float(self._g_mfu.value), 6),
-            "mbu": round(float(self._g_mbu.value), 6),
-            "flops_total": float(self._c_flops.value),
-            "bytes_total": float(self._c_bytes.value),
-            "peak_flops": self.peak_flops,
-            "peak_hbm_bw": self.peak_bw,
-            "cost_sites": {k: dict(v) for k, v in self._costs.items()},
+            "compiles_warmup": self.compiles_warmup,
+            "compiles_steady": self.compiles_steady,
             "captures": list(self.captures)[-4:],
         }
 
+    def compile_ledger(self) -> Dict[str, Any]:
+        """This engine's compiles, site by site, each with the build
+        ledger's entries it claimed."""
+        return {
+            "warmup_compiles": self.compiles_warmup,
+            "steady_state_compiles": self.compiles_steady,
+            "entries": [dict(c, entries=[dict(e) for e in c["entries"]])
+                        for c in self._compiles],
+        }
+
     def bundle_info(self) -> Dict[str, Any]:
-        """What incident bundles attach: the full compile ledger plus
+        """What incident bundles attach: the engine's compiles plus
         recent capture references."""
         return {
-            "compile_ledger": self.ledger.snapshot(),
+            "compile_ledger": self.compile_ledger(),
             "captures": list(self.captures)[-4:],
         }
 
     def incident_probe(self):
         """IncidentManager probe: trip once per NEW steady-state
         compile batch (cursor-based — warmup compiles never trip)."""
-        n = self.ledger.steady
+        n = self.compiles_steady
         if n > self._probe_seen:
             fresh = n - self._probe_seen
             self._probe_seen = n
-            led = self.ledger.snapshot()
             return "steady_state_recompile", {
                 "phase": "steady_state_recompile",
                 "new_compiles": fresh,
                 "steady_state_compiles": n,
-                "recent": led["entries"][-4:],
+                "recent": self.compile_ledger()["entries"][-4:],
             }
         return None
 
 
 class _NullDevProf:
     """Shared no-op stand-in when the block is off: wrap() is the
-    identity, every gate is False, every read surface is the disabled
-    block."""
+    identity and every read surface is the disabled block."""
 
     enabled = False
     steady = False
@@ -556,35 +632,8 @@ class _NullDevProf:
     def wrap(self, site, jfn):
         return jfn
 
-    def register_cost(self, site, flops, bytes_accessed):
-        pass
-
-    def cost_analyze(self, site, jfn, *args, **kw):
-        return False
-
     def mark_steady(self):
         pass
-
-    def on_compile(self, site, n=1):
-        pass
-
-    def on_dispatch(self, site):
-        pass
-
-    def should_sample(self, phase):
-        return False
-
-    def observe_device(self, phase, value):
-        return 0.0
-
-    def record_device(self, phase, dev_s, gap=None):
-        pass
-
-    def tick(self, now=None):
-        pass
-
-    def capture(self, duration_s):
-        return {"error": "devprof disabled"}
 
     def profilez(self, capture_s=None):
         return {"enabled": False}
@@ -600,3 +649,6 @@ class _NullDevProf:
 
 
 NULL_DEVPROF = _NullDevProf()
+
+# whatever the process jits from here on is in the ledger
+install_compile_listener()

@@ -29,7 +29,9 @@ import jax.numpy as jnp
 
 from deepspeed_tpu import lr_schedules, precision, zero
 from deepspeed_tpu.config import Config
+from deepspeed_tpu.devprof import BuildCounters, ProgramSpan
 from deepspeed_tpu.ops.optim import Optimizer, from_config as opt_from_config
+from deepspeed_tpu.telemetry import MetricsRegistry
 from deepspeed_tpu.topology import MeshSpec, default_mesh
 from deepspeed_tpu.utils.logging import logger
 
@@ -122,6 +124,18 @@ class TrainingEngine:
                  lr_scheduler=None,
                  param_specs: "zero.SpecTree" = None,
                  has_aux: bool = False):
+        # the registry first, because the build is measured: the
+        # counters take every program made ready from here to the end
+        # of _finish_init and again around the first step
+        # (deepspeed_tpu.devprof); the spans are entered in place
+        self.registry = MetricsRegistry(enabled=config.telemetry.enabled)
+        self._build: Optional[BuildCounters] = BuildCounters(self.registry)
+        self._sp_build_state = self.registry.span(
+            "build_state", "the state program: parameters and optimizer "
+            "state placed on the mesh")
+        self._sp_build_step = ProgramSpan(self.registry.span(
+            "build_step", "the first dstpu_train_step dispatch"))(
+                "train_step")
         self.config = config
         self.mesh = mesh or MeshSpec.build(
             config.mesh.axis_sizes(jax.device_count()))
@@ -250,20 +264,23 @@ class TrainingEngine:
             opt_state=self.opt_shardings,
             scaler=precision.ScalerState(repl, repl))
 
-        def make_state(p):
+        def dstpu_make_state(p):
             return TrainState(
                 step=jnp.zeros([], jnp.int32),
                 params=p,
                 opt_state=self.optimizer.init(p),
                 scaler=precision.scaler_init(config.precision))
 
-        if self._cast_thunk is not None:
-            cast_thunk, self._cast_thunk = self._cast_thunk, None
-            self.state = jax.jit(lambda: make_state(cast_thunk()),
-                                 out_shardings=self.state_shardings)()
-        else:
-            self.state = jax.jit(
-                make_state, out_shardings=self.state_shardings)(params)
+        with self._sp_build_state:
+            if self._cast_thunk is not None:
+                cast_thunk, self._cast_thunk = self._cast_thunk, None
+                self.state = jax.jit(
+                    lambda: dstpu_make_state(cast_thunk()),
+                    out_shardings=self.state_shardings)()
+            else:
+                self.state = jax.jit(
+                    dstpu_make_state,
+                    out_shardings=self.state_shardings)(params)
         self._finish_init()
 
     def _finish_init(self) -> None:
@@ -341,11 +358,9 @@ class TrainingEngine:
         # path sync-free: gauges that require a device sync (loss, grad
         # norm, MFU) refresh only on the steps_per_print cadence when a
         # sink will read them, or on demand via telemetry_snapshot().
-        from deepspeed_tpu.telemetry import (MetricsRegistry,
-                                             TelemetryExporter)
+        from deepspeed_tpu.telemetry import TelemetryExporter
 
         tel = config.telemetry
-        self.registry = MetricsRegistry(enabled=tel.enabled)
         self._c_train_steps = self.registry.counter(
             "train_steps", "optimizer steps taken")
         # spans built once (telemetry.Span: histogram + TraceAnnotation;
@@ -377,7 +392,7 @@ class TrainingEngine:
         # wire_bytes_per_device).  comm_collective_seconds is observed
         # only at HOST-DRIVEN collective sites (serving placement, ZI
         # layer upload, the bench) — in-jit collective time is
-        # attributed by the devprof phase ledger, not guessed here.
+        # read from a device trace, not guessed here.
         self._comm_hier = None
         self._comm_wire = None
         self._comm_overlap = 0.0
@@ -458,6 +473,8 @@ class TrainingEngine:
             config.train_micro_batch_size_per_gpu,
             config.gradient_accumulation_steps, config.train_batch_size,
             config.precision.dtype, self.grad_comm_mode or "exact")
+        # the first train_batch attaches the counters again
+        self._build.built()
 
     # ------------------------------------------------------- qwZ flat state
     def _setup_qwz_state(self, params, mdt) -> None:
@@ -493,24 +510,28 @@ class TrainingEngine:
             step=repl, params=sh, opt_state=self.opt_shardings,
             scaler=precision.ScalerState(repl, repl))
 
-        def make_state(p):
+        def dstpu_make_state(p):
             flat = self._qwz_flatten(p, mdt).reshape(flat_shape)
             return TrainState(
                 step=jnp.zeros([], jnp.int32), params=flat,
                 opt_state=self.optimizer.init(flat),
                 scaler=precision.scaler_init(self.config.precision))
 
-        if self._cast_thunk is not None:
-            # zero.Init thunk: flattening is traced, so the thunk runs
-            # inside the jit and lands directly in the [world, chunk] rows.
-            # Drop the reference afterwards — the closure may hold large
-            # host-side arrays that must become collectable.
-            cast_thunk, self._cast_thunk = self._cast_thunk, None
-            self.state = jax.jit(lambda: make_state(cast_thunk()),
-                                 out_shardings=self.state_shardings)()
-        else:
-            self.state = jax.jit(
-                make_state, out_shardings=self.state_shardings)(params)
+        with self._sp_build_state:
+            if self._cast_thunk is not None:
+                # zero.Init thunk: flattening is traced, so the thunk
+                # runs inside the jit and lands directly in the [world,
+                # chunk] rows.  Drop the reference afterwards — the
+                # closure may hold large host-side arrays that must
+                # become collectable.
+                cast_thunk, self._cast_thunk = self._cast_thunk, None
+                self.state = jax.jit(
+                    lambda: dstpu_make_state(cast_thunk()),
+                    out_shardings=self.state_shardings)()
+            else:
+                self.state = jax.jit(
+                    dstpu_make_state,
+                    out_shardings=self.state_shardings)(params)
 
     def _qwz_flatten(self, tree, dtype):
         """Ravel a params-shaped pytree into the padded flat buffer."""
@@ -986,11 +1007,20 @@ class TrainingEngine:
         if timed:
             self.tput_timer.start()
         # train_step_seconds: the dispatch's host wall, or, when timed,
-        # through the ThroughputTimer's sync; no forced sync otherwise
-        with self._sp_step:
+        # through the ThroughputTimer's sync; no forced sync otherwise.
+        # The first dispatch makes the step program ready: the build's
+        # (dstpu/build_step), not a step of the histogram (and no local
+        # for it: this frame's size is part of set-up, PERF.md 6, PR 37)
+        if self._build is not None:
+            self._build.attach()
+        with (self._sp_step if self._build is None
+              else self._sp_build_step):
             self.state, metrics = self._step_fn(self.state, batch)
             if timed:
                 self.tput_timer.stop()
+        if self._build is not None:
+            self._build.built()
+            self._build = None
         self._post_step(metrics)
         return metrics["loss"]
 

@@ -1783,10 +1783,9 @@ def fleet_router(params, cfg, *, fleet=None, telemetry=None,
     KVFabric`) attaches the cross-replica KV exchange to every
     replica — each then needs the ``kv_tier`` block in
     ``engine_kw``.  A ``devprof`` block in ``engine_kw`` rides the
-    same passthrough: every replica gets its own compile sentinel,
-    device-time counters and MFU/MBU gauges under its
-    ``dstpu_r{i}`` metric namespace — one scrape shows which replica
-    is recompiling or underutilized."""
+    same passthrough: every replica gets its own compile sentinel
+    under its ``dstpu_r{i}`` metric namespace — one scrape shows
+    which replica is recompiling."""
     fc = FleetConfig.coerce(fleet)
     tracer = RequestTracer.from_config(TracingConfig.coerce(tracing))
     if isinstance(faults, FaultPlan):

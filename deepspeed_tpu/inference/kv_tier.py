@@ -639,8 +639,8 @@ class KVTierPool:
     # single-consumer — the engine serializes promotions that need it,
     # host-resident promotions ride host_view() instead.  The DEVICE
     # half of a promotion — the scatter of these payloads into HBM
-    # pages — is what devprof's "promote" phase samples; the host read
-    # side stays visible through the kv_tier promote-stall histogram)
+    # pages — shows in a device trace; the host read side stays
+    # visible through the kv_tier promote-stall histogram)
     def entry_meta(self, key: bytes):
         """(names, shapes, dtypes) of ``key``'s spilled buffers — the
         read plan a TierPageReader submits."""

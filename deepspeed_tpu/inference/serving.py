@@ -95,7 +95,8 @@ from deepspeed_tpu.config import (CommConfig, DevprofConfig, FaultsConfig,
                                   SLOConfig, SpeculativeConfig,
                                   TelemetryConfig, TracingConfig,
                                   ZeroInferenceConfig)
-from deepspeed_tpu.devprof import NULL_DEVPROF, DevProf
+from deepspeed_tpu.devprof import (BUILD_LEDGER, NULL_DEVPROF, BuildCounters,
+                                   DevProf, ProgramSpan)
 from deepspeed_tpu.faults import ChecksumError, FaultPlan, InjectedFault
 from deepspeed_tpu.history import NULL_HISTORY, MetricHistory
 from deepspeed_tpu.incidents import NULL_INCIDENTS, IncidentManager
@@ -390,6 +391,28 @@ class ServingEngine:
                  devprof=None, comm=None, values_in_keys: bool = False,
                  expert_rows: int = 0, routed_per_row: int = 0,
                  state_row=None):
+        # ---- telemetry: one registry for every hot-path metric.
+        # `telemetry` accepts None/bool/dict/TelemetryConfig — or an
+        # existing MetricsRegistry to share one across engines.  First,
+        # because the build is measured: the counters take every program
+        # this constructor makes ready (deepspeed_tpu.devprof)
+        if isinstance(telemetry, MetricsRegistry):
+            self.registry = telemetry
+            tcfg = None                    # caller owns the sinks
+        else:
+            tcfg = TelemetryConfig.coerce(telemetry)
+            self.registry = MetricsRegistry(enabled=tcfg.enabled)
+        self._build = BuildCounters(self.registry)
+        # the build's phases on the profiler's clock, entered in place
+        # (no frame above a dispatch that lowers: see serving_engine)
+        self._sp_build_alloc = self.registry.span(
+            "build_alloc", "the page pool, tables and state: _alloc_cache")
+        self._sp_build_programs = self.registry.span(
+            "build_programs", "the jits and their sentinel wraps")
+        self._sp_build_warmup = self.registry.span(
+            "build_warmup", "the warm-up: every program dispatched once")
+        self._sp_build_program = ProgramSpan(self.registry.span(
+            "build_program", "one warm-up dispatch: a program made ready"))
         # what a token's cache row is: per-head K and V pools, or one
         # pool whose rows are keys and values both (a latent family's
         # ``cache_row``); and how many held experts' routed rows the
@@ -553,9 +576,11 @@ class ServingEngine:
                 "kernels.paged_attention=pallas_v1 cannot serve "
                 "int8-resident pages (kv_tier.quantized_resident) — "
                 "there is no quantized v1 kernel; use xla or pallas_v2")
-        self.cache = self._alloc_cache(n_layers, n_kv, num_pages,
-                                       page_size, head_dim, cache_dtype)
-        self._build_programs(prefill_fn, decode_fn, chunk_prefill_fn)
+        with self._sp_build_alloc:
+            self.cache = self._alloc_cache(n_layers, n_kv, num_pages,
+                                           page_size, head_dim, cache_dtype)
+        with self._sp_build_programs:
+            self._build_programs(prefill_fn, decode_fn, chunk_prefill_fn)
         self._table_host = np.full((max_batch, self.max_pages_per_seq),
                                    self.trash_page, np.int32)
         # dirty flags: device table/seq_lens re-upload only when the slot
@@ -579,16 +604,6 @@ class ServingEngine:
         self.finished: Dict[Any, List[int]] = {}
         self._newly_finished: List[Any] = []
 
-        # ---- telemetry: one registry for every hot-path metric (the
-        # old ad-hoc `stats` dict survives as a read-only shim below).
-        # `telemetry` accepts None/bool/dict/TelemetryConfig — or an
-        # existing MetricsRegistry to share one across engines.
-        if isinstance(telemetry, MetricsRegistry):
-            self.registry = telemetry
-            tcfg = None                    # caller owns the sinks
-        else:
-            tcfg = TelemetryConfig.coerce(telemetry)
-            self.registry = MetricsRegistry(enabled=tcfg.enabled)
         # _tel_on guards every perf_counter read in the decode loop: the
         # disabled path must cost nothing beyond this bool (no clock, no
         # lock, no TraceAnnotation)
@@ -789,13 +804,11 @@ class ServingEngine:
         dcfg = DevprofConfig.coerce(devprof)
         if dcfg.enabled and not self._tel_on:
             # validated BEFORE the exporter below, like incidents: the
-            # sentinel counters, device-time attribution and MFU/MBU
-            # gauges all live in the registry
+            # sentinel's counters live in the registry
             raise ValueError(
                 "devprof needs the telemetry block — the compile "
-                "sentinel, device-time and roofline surfaces are "
-                "registry metrics; enable telemetry (or drop the "
-                "devprof block)")
+                "sentinel's surfaces are registry metrics; enable "
+                "telemetry (or drop the devprof block)")
         self.history_cfg = hcfg
         self.incidents_cfg = icfg
         self.devprof_cfg = dcfg
@@ -832,10 +845,8 @@ class ServingEngine:
         # ---- device-truth observability (see deepspeed_tpu.devprof):
         # sentinel wrappers around the compiled sweep programs count
         # and attribute every XLA compile (warmup vs steady-state),
-        # sampled block_until_ready deltas attribute device time per
-        # phase, and a one-time cost analysis of the programs feeds
-        # live MFU/MBU gauges.  On-demand /profilez captures land
-        # under the tracer's dump_dir.
+        # each with the build ledger's seconds for it.  On-demand
+        # /profilez captures land under the tracer's dump_dir.
         self.devprof = (
             DevProf(dcfg, registry=self.registry, tracer=self.tracer,
                     dump_dir=getattr(self.tracer, "dump_dir",
@@ -843,18 +854,18 @@ class ServingEngine:
             if dcfg.enabled else NULL_DEVPROF)
         self._devprof_on = self.devprof.enabled
         if self._devprof_on:
-            self._prefill = self.devprof.wrap("prefill", self._prefill)
-            self._chunk_prefill = self.devprof.wrap(
-                "chunk_prefill", self._chunk_prefill)
-            self._decode_chunk_fn = self.devprof.wrap(
-                "decode_chunk", self._decode_chunk_fn)
-            self._verify_chunk = self.devprof.wrap(
-                "spec_verify", self._verify_chunk)
-            self._boundary = self.devprof.wrap("boundary",
-                                               self._boundary)
-            if dcfg.cost_analysis:
-                self._devprof_cost_analyze()
-            self._devprof_warmup()
+            with self._sp_build_programs:
+                self._prefill = self.devprof.wrap("prefill", self._prefill)
+                self._chunk_prefill = self.devprof.wrap(
+                    "chunk_prefill", self._chunk_prefill)
+                self._decode_chunk_fn = self.devprof.wrap(
+                    "decode_chunk", self._decode_chunk_fn)
+                self._verify_chunk = self.devprof.wrap(
+                    "spec_verify", self._verify_chunk)
+                self._boundary = self.devprof.wrap("boundary",
+                                                   self._boundary)
+            with self._sp_build_warmup:
+                self._devprof_warmup()
 
         # rolling-update identity: which weight image this engine is
         # serving (swap_params bumps it; the fleet's per-version SLO
@@ -1107,8 +1118,7 @@ class ServingEngine:
         self._slo_tick_hooked = False
         self._tick_inline = (self._tel_exporter is None and
                              (self.history.enabled
-                              or self.incident_mgr.enabled
-                              or self._devprof_on))
+                              or self.incident_mgr.enabled))
         if self._tel_exporter is not None:
             ex = self._tel_exporter
             if self._slo_on:
@@ -1127,11 +1137,6 @@ class ServingEngine:
                     self.incident_mgr.maybe_evaluate,
                     interval_s=icfg.eval_interval_s,
                     name="incident_evaluate")
-            if self._devprof_on:
-                # roofline gauges: flops/bytes counter deltas → MFU/MBU
-                ex.register_tick_hook(
-                    self.devprof.tick, interval_s=1.0,
-                    name="devprof_roofline")
 
         # ---- introspection: /statusz (live engine snapshot),
         # /healthz (liveness/readiness, watchdog-fed), /requestz?id=
@@ -1159,6 +1164,8 @@ class ServingEngine:
                 self._tel_exporter.register_provider(
                     "tracez", tracez_provider(
                         self.tracer.recorder, replica=self.replica_id))
+        # build_seconds: this line's clock less the first line's
+        self._build.built()
 
     # (the `stats` deprecation shim from PR 2/PR 6 was removed on its
     # announced schedule — read `engine.registry.snapshot()` instead)
@@ -1262,54 +1269,6 @@ class ServingEngine:
         self._decode_chunk_fn = jax.jit(dstpu_decode,
                                         donate_argnums=(2,))
 
-    def _devprof_cost_analyze(self) -> None:
-        """Build-time roofline pass (devprof.cost_analysis): lower the
-        compiled sweep programs once at their steady shapes and record
-        the compiler's flops/bytes estimates as per-dispatch costs.
-        Abstract (ShapeDtypeStruct) args — no device work, and the AOT
-        lower/compile path never touches the jit dispatch caches the
-        sentinel watches.  Best-effort per program: a backend without
-        ``cost_analysis`` just leaves that site uncosted."""
-        dp = self.devprof
-
-        def absx(x):
-            return (jax.ShapeDtypeStruct(x.shape, x.dtype)
-                    if hasattr(x, "shape") and hasattr(x, "dtype")
-                    else x)
-
-        tm = jax.tree_util.tree_map
-        try:
-            params_a = tm(absx, self.params)
-            cache_a = tm(absx, self.cache)
-            key_a = absx(self._key)
-            dp.cost_analyze(
-                "decode_chunk", self._decode_chunk_fn, params_a,
-                jax.ShapeDtypeStruct((self.max_batch, 1), jnp.int32),
-                cache_a, key_a, jax.ShapeDtypeStruct((), jnp.int32),
-                jax.ShapeDtypeStruct((self.max_batch,), jnp.float32))
-            # whole-prompt prefill at the base bucket (the view a
-            # bucket-padded admission hands the program)
-            view_a = tm(absx, self.cache._replace(
-                table=jnp.zeros((1, self.max_pages_per_seq), jnp.int32),
-                seq_lens=jnp.zeros((1,), jnp.int32)))
-            dp.cost_analyze(
-                "prefill", self._prefill, params_a,
-                jax.ShapeDtypeStruct((1, self.prefill_bucket),
-                                     jnp.int32), view_a,
-                jax.ShapeDtypeStruct((1,), jnp.int32))
-            if self._spec_on:
-                # under speculation the verify sweep IS the
-                # steady-state decode program — cost it at its shape
-                Kd = self.speculative.draft_tokens
-                dp.cost_analyze(
-                    "spec_verify", self._verify_chunk, params_a,
-                    jax.ShapeDtypeStruct((self.max_batch, Kd + 1),
-                                         jnp.int32), cache_a)
-        except Exception:
-            # roofline accounting is observability, never a build
-            # failure — uncosted sites simply contribute 0 to MFU/MBU
-            logger.exception("devprof: build-time cost analysis")
-
     def _devprof_warmup(self) -> None:
         """Devprof build-time precompile: dispatch every sweep program
         once per steady shape so the jit caches are fully populated
@@ -1329,7 +1288,11 @@ class ServingEngine:
         # jnp.zeros is a tiny program a shape, compiled on every build
         # (too small for the persistent cache to keep)
         zi = np.zeros
-        n0 = time.perf_counter()
+        n0 = BUILD_LEDGER.mark()
+        # one dstpu/build_program span a dispatch (site, shape word):
+        # the ledger's entry for that program gets the word and run_s.
+        # This frame is under every lowering below, and its size moves
+        # set-up by seconds (PERF.md 6, PR 37): no new local here
         row = self.max_pages_per_seq * self.page_size
         last = self._put(zi((1,), np.int32))
         logits_row = None
@@ -1341,9 +1304,10 @@ class ServingEngine:
                            for i in range(1, -(-row // bkt) + 1)})
             for end in ends:
                 view = self._row_view(self._table_host[0:1], 0, 0)
-                logits_row, view = self._prefill(
-                    self.params, self._put(zi((1, end), np.int32)),
-                    view, last)
+                with self._sp_build_program("prefill", end=end):
+                    logits_row, view = self._prefill(
+                        self.params, self._put(zi((1, end), np.int32)),
+                        view, last)
                 self.cache = self._adopt(view)
         if self._chunk_prefill is not None:
             # the continuation forward's page-table width is bucketed
@@ -1361,9 +1325,10 @@ class ServingEngine:
             widths.append(self.max_pages_per_seq)
             for w in widths:
                 view = self._row_view(self._table_host[0:1, :w], 0, 0)
-                logits_row, view = self._chunk_prefill(
-                    self.params, self._put(zi((1, C), np.int32)),
-                    view, last)
+                with self._sp_build_program("chunk_prefill", w=w):
+                    logits_row, view = self._chunk_prefill(
+                        self.params, self._put(zi((1, C), np.int32)),
+                        view, last)
                 self.cache = self._adopt(view)
         # whole-cache dispatches (spec verify, decode) see the
         # page_size leaf as the weak-i32 scalar a previous jit RETURN
@@ -1376,24 +1341,24 @@ class ServingEngine:
         if self._spec_on:
             # the verify sweep's whole-cache continuation shape
             Kd = self.speculative.draft_tokens
-            _, self.cache = self._verify_chunk(
-                self.params,
-                self._put(zi((self.max_batch, Kd + 1), np.int32)),
-                self.cache)
+            with self._sp_build_program("spec_verify", k=Kd + 1):
+                _, self.cache = self._verify_chunk(
+                    self.params,
+                    self._put(zi((self.max_batch, Kd + 1), np.int32)),
+                    self.cache)
         ordinal = self._put(zi((), np.int32))
         if logits_row is not None:
             # the boundary sampler, over a row a prefill above returned
-            self._boundary(logits_row, self._key, ordinal,
-                           self._put(zi((1,), np.float32)))
-        out, self.cache = self._decode_chunk_fn(
-            self.params,
-            self._put(zi((self.max_batch, 1), np.int32)),
-            self.cache, self._key, ordinal,
-            self._put(zi((self.max_batch,), np.float32)))
-        del out
-        logger.info("devprof warmup: %d programs precompiled in %.1fs",
-                    self.devprof.ledger.warmup,
-                    time.perf_counter() - n0)
+            with self._sp_build_program("boundary"):
+                self._boundary(logits_row, self._key, ordinal,
+                               self._put(zi((1,), np.float32)))
+        with self._sp_build_program("decode_chunk", b=self.max_batch):
+            _, self.cache = self._decode_chunk_fn(
+                self.params,
+                self._put(zi((self.max_batch, 1), np.int32)),
+                self.cache, self._key, ordinal,
+                self._put(zi((self.max_batch,), np.float32)))
+        logger.info("devprof warmup: %s", BUILD_LEDGER.since(n0))
 
     # ------------------------------------------------------------- requests
     def submit(self, req_id, tokens, max_new_tokens: int = 32,
@@ -2061,11 +2026,6 @@ class ServingEngine:
                 self.params, self._put(toks), view,
                 self._put(np.full((1,), T - 1, np.int32)))
             self._rows_pending += end
-            if self._devprof_on and self.devprof.should_sample(
-                    "prefill"):
-                # dstpu: host-sync-ok: sampled devprof device-time
-                # attribution (one sync per 1/sample_rate prefills)
-                self.devprof.observe_device("prefill", row)
             self.cache = self._adopt(view)
 
             slot = _Slot(req=req, seq_len=T, generated=[], seq_id=seq_id)
@@ -2484,10 +2444,6 @@ class ServingEngine:
                 self._put(jnp.asarray(k_host)), mode="drop"),
             v=self.cache.v.at[:, :, idx].set(
                 self._put(jnp.asarray(v_host)), mode="drop"))
-        if self._devprof_on and self.devprof.should_sample("promote"):
-            # dstpu: host-sync-ok: sampled devprof device-time
-            # attribution (one sync per 1/sample_rate promote scatters)
-            self.devprof.observe_device("promote", self.cache.k)
 
     def _upload_promoted_q(self, pages: List[int], kq, ks,
                            vq, vs) -> None:
@@ -2507,10 +2463,6 @@ class ServingEngine:
                 self._put(jnp.asarray(vq)), mode="drop"),
             v_scale=c.v_scale.at[:, :, idx].set(
                 self._put(jnp.asarray(vs)), mode="drop"))
-        if self._devprof_on and self.devprof.should_sample("promote"):
-            # dstpu: host-sync-ok: sampled devprof device-time
-            # attribution (one sync per 1/sample_rate promote scatters)
-            self.devprof.observe_device("promote", self.cache.k)
 
     def _demote_for_evict(self, page: int, key: bytes) -> bool:
         """``PageAllocator.demote_hook``: capture an evicted warm
@@ -2650,10 +2602,6 @@ class ServingEngine:
             self.params, self._put(toks), view,
             self._put(np.full((1,), take - 1, np.int32)))
         self._rows_pending += C
-        if self._devprof_on and self.devprof.should_sample("prefill"):
-            # dstpu: host-sync-ok: sampled devprof device-time
-            # attribution (one sync per 1/sample_rate prefill chunks)
-            self.devprof.observe_device("prefill", row)
         self.cache = self._adopt(view)
         s.prefill_done = done + take
         s.seq_len = s.prefill_done
@@ -2742,19 +2690,11 @@ class ServingEngine:
         if not self._pending_boundary:
             return
         pend, self._pending_boundary = self._pending_boundary, []
-        want_dev = (self._devprof_on
-                    and self.devprof.should_sample("sample"))
-        t0_dev = time.perf_counter() if want_dev else 0.0
         # dstpu: host-sync-ok: boundary token fetch, one transfer per
         # step for every prefill completion (each token was sampled on
         # the device when its prefill was dispatched; nothing is
         # dispatched here)
         toks = jax.device_get([tok for _, tok in pend])
-        if want_dev:
-            # the device_get above already synced — self-timed, no
-            # extra block_until_ready needed
-            self.devprof.record_device(
-                "sample", time.perf_counter() - t0_dev)
         self._c_boundary_syncs.inc()
         self._c_boundary_tokens.inc(len(pend))
         for (b, _), tok in zip(pend, toks):
@@ -2886,7 +2826,6 @@ class ServingEngine:
             now = time.monotonic()
             self.history.maybe_sample(now)
             self.incident_mgr.maybe_evaluate(now)
-            self.devprof.tick(now)  # rate-limited internally
         self._slo_refresh()
 
     def _slo_refresh(self) -> None:
@@ -2988,12 +2927,6 @@ class ServingEngine:
                     self._c_state_masked.inc(
                         K * (self.max_batch - len(active)))
             with self._sp_token_sync:
-                if self._devprof_on and self.devprof.should_sample(
-                        "decode"):
-                    # dstpu: host-sync-ok: sampled devprof device-time
-                    # attribution — the np.asarray below would sync
-                    # anyway; this just brackets it with a clock
-                    self.devprof.observe_device("decode", out)
                 # dstpu: host-sync-ok: the ONE device→host transfer per
                 # decode chunk (K tokens per sync — the module contract)
                 host_toks = np.asarray(out)
@@ -3135,12 +3068,6 @@ class ServingEngine:
                 self._key, self._next_dispatch(),
                 self._put(temps))
         with self._sp_token_sync:
-            if self._devprof_on and self.devprof.should_sample(
-                    "spec_verify"):
-                # dstpu: host-sync-ok: sampled devprof device-time
-                # attribution — the device_get below syncs anyway; this
-                # just brackets the verify sweep with a clock
-                self.devprof.observe_device("spec_verify", n_acc_d)
             if traced_any:
                 self.tracer.event("spec_verify", attrs={
                     "active": len(active), "positions": K + 1})
@@ -3408,6 +3335,9 @@ class ServingEngine:
             },
             "incidents": self.incident_mgr.snapshot(),
             "devprof": self.devprof.statusz_block(),
+            # what making the process's programs ready cost (the
+            # process-wide build ledger, its newest entries)
+            "build": BUILD_LEDGER.snapshot(last=64),
             # the BOUND port (meaningful when http_port=0 asked for an
             # ephemeral bind): how a parent process that spawned this
             # replica learns where to scrape it
@@ -3832,7 +3762,8 @@ def serving_engine(params, cfg, **kw):
         return _encoder_serving_engine(params, cfg, kw)
     # the decoder build stays in THIS function: a helper's frame between
     # here and ServingEngine.__init__ cost 2-3 s of set-up on the chip's
-    # host, the warm-up's lowering loop being that deep (PERF.md 6, PR 30)
+    # host (where the frames under the warm-up's lowering end on the
+    # interpreter's frame stack decides it: PERF.md 6, PRs 30 and 37)
     weight_dtype = kw.pop("weight_dtype", "bfloat16")
     quant_group_size = kw.pop("quant_group_size", 128)
     mesh = kw.pop("mesh", None)

@@ -197,10 +197,8 @@ def build_drafter(cfg: SpeculativeConfig) -> Drafter:
 
 
 # ------------------------------------------------------ device accept
-# NOTE: module-level jit shared across engines — devprof attributes its
-# device time to the "spec_verify" phase at the call site (serving's
-# _spec_step samples the dispatch result) rather than sentinel-wrapping
-# here, so one engine's sampling never charges another's sweep.
+# NOTE: module-level jit shared across engines, so no engine's compile
+# sentinel wraps it: the build ledger has its entry by name.
 def dstpu_verify(logits, drafts, draft_lens, key, ordinal, temps):
     """Batched acceptance for one verify sweep — ONE host transfer.
 

@@ -457,17 +457,6 @@ class ZeroInferenceServingEngine(ServingEngine):
         self._verify_chunk = self._streamed_verify_chunk
         self._decode_chunk_fn = self._streamed_decode_chunk
 
-    def _devprof_cost_analyze(self) -> None:
-        """The streamed executors are host-driven per-layer sweeps, not
-        whole-model jits — there is no single lowered program whose
-        ``cost_analysis()`` describes a dispatch, so the roofline
-        numerators stay unregistered (MFU/MBU read 0).  Devprof's
-        compile sentinel and device-time attribution still work: the
-        sentinel wrappers count dispatches on the streamed callables
-        (``_cache_size`` absent → dispatch accounting only, per-block
-        compiles are caught by the process-wide monitoring listener)."""
-        return
-
     def _devprof_warmup(self) -> None:
         """No build-time precompile either: a streamed-executor
         "dispatch" is a full host-driven layer sweep through the NVMe

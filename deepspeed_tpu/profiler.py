@@ -61,10 +61,8 @@ def params_count(params: Any) -> int:
 def xla_cost_analysis_lowered(lowered) -> Dict[str, float]:
     """Compiler-reported flops / bytes for an already-lowered program
     (``jit(fn).lower(...)`` — concrete args or ShapeDtypeStructs both
-    work).  The entry point :mod:`deepspeed_tpu.devprof` reuses for its
-    roofline denominators: the engine lowers its OWN jitted sweep
-    programs once at build instead of re-jitting through
-    :func:`xla_cost_analysis`."""
+    work): for a caller that has lowered its OWN jitted program and
+    would not re-jit it through :func:`xla_cost_analysis`."""
     ca = lowered.compile().cost_analysis()
     if isinstance(ca, (list, tuple)):  # older jax returns a per-computation list
         ca = ca[0] if ca else {}

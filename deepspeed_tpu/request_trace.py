@@ -66,13 +66,11 @@ _TRACKS = (
     ("tier_", "tier_reader"),
     ("spec_", "speculative"),
     ("kv_", "kv_tier"),
-    # devprof device truth: xla_compile / profile_capture /
-    # devprof_sample get their own track so steady-state recompiles
-    # stand out against the request waterfall instead of drowning in
-    # the catch-all events lane
+    # devprof device truth: xla_compile / profile_capture get their
+    # own track so steady-state recompiles stand out against the
+    # request waterfall instead of drowning in the catch-all events lane
     ("xla_", "xla_compile"),
     ("profile_", "xla_compile"),
-    ("devprof_", "xla_compile"),
 )
 # NOTE: spec_accept is per-request (rides the request's async span as an
 # instant, with drafted/accepted attrs); the batch-level speculation

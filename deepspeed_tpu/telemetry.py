@@ -208,6 +208,9 @@ NULL_METRIC = _NullMetric()
 class _NullSpan:
     __slots__ = ()
 
+    def __call__(self, **kw):
+        return self
+
     def __enter__(self):
         return self
 
@@ -228,20 +231,26 @@ class Span:
     the histogram ``<name>_seconds`` carries the same word.  An
     instance is reusable (not re-entrant): hot paths build theirs once
     through :meth:`MetricsRegistry.span` and ``with`` it every step, so
-    a step pays no registry lock and no string formatting.
+    a step pays no registry lock and no string formatting.  Called
+    with keywords (``with span(site="prefill"):``) it annotates them.
     """
 
-    __slots__ = ("_hist", "_label", "_ann", "_t0")
+    __slots__ = ("_hist", "_label", "_ann", "_t0", "_kw")
 
     def __init__(self, hist: Histogram, label: str):
         self._hist = hist
         self._label = label
         self._ann = None
+        self._kw = {}
+
+    def __call__(self, **kw):
+        self._kw = kw
+        return self
 
     def __enter__(self):
         import jax      # on first use: the disabled path never needs it
 
-        self._ann = jax.profiler.TraceAnnotation(self._label)
+        self._ann = jax.profiler.TraceAnnotation(self._label, **self._kw)
         self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self

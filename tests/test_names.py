@@ -47,11 +47,12 @@ def _serve(telemetry):
     return eng
 
 
-def _trainer():
+def _trainer(telemetry=True):
     cfg, params, mod = _gpt2()
     engine, *_ = deepspeed_tpu.initialize(
         loss_fn=mod.loss_fn(cfg), params=params,
         config={"train_batch_size": 8,
+                "telemetry": {"enabled": telemetry},
                 "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
                 "gradient_clipping": 1.0,
                 "zero_optimization": {"stage": 1}})
@@ -87,6 +88,14 @@ def capture(tmp_path_factory):
         with jax.profiler.TraceAnnotation("bench/train"):
             for _ in range(2):
                 jax.block_until_ready(trainer.train_batch(batch))
+        # a build inside the capture: its phases, and one span a
+        # warm-up dispatch (ISSUE 37)
+        with jax.profiler.TraceAnnotation("bench/build"):
+            cfg, params, _ = _gpt2()
+            serving_engine(params, cfg, telemetry=True, devprof=True,
+                           **ENGINE_KW).shutdown()
+            fresh, batch = _trainer(telemetry=True)
+            jax.block_until_ready(fresh.train_batch(batch))
     finally:
         jax.profiler.stop_trace()
     scoped = scopes.load(trace.newest_xplane(logdir))
@@ -156,6 +165,60 @@ def test_train_spans(capture):
     names = [s.name for s in _inside(scoped, "bench/train")]
     assert names.count("dstpu/train_step") == 2
     assert names.count("dstpu/train_align_batch") == 2
+
+
+BUILD_SPANS = {"dstpu/build_alloc": 1, "dstpu/build_programs": 2,
+               "dstpu/build_warmup": 1, "dstpu/build_program": 14,
+               "dstpu/build_state": 1, "dstpu/build_step": 1}
+BUILD_COUNTERS = ("build_programs", "build_cache_misses",
+                  "build_trace_seconds", "build_lower_seconds",
+                  "build_cache_load_seconds", "build_compile_seconds")
+BUILD_GAUGES = ("build_seconds", "package_import_seconds")
+
+
+@pytest.mark.parametrize("name", sorted(BUILD_SPANS))
+def test_build_spans(capture, name):
+    """The build's phases on the profiler's clock: the serving engine's
+    allocation, jits and warm-up with one ``build_program`` a dispatch
+    inside it (the site and the shape word as stats), the training
+    engine's state placement and first step."""
+    scoped, _, _ = capture
+    mine = [s for s in _inside(scoped, "bench/build") if s.name == name]
+    assert len(mine) == BUILD_SPANS[name]
+    if name == "dstpu/build_program":
+        (warm,) = [s for s in _inside(scoped, "bench/build")
+                   if s.name == "dstpu/build_warmup"]
+        assert all(warm.start <= s.start and
+                   s.start + s.dur <= warm.start + warm.dur for s in mine)
+        words = [(s.stats["site"],
+                  {k: int(v) for k, v in s.stats.items() if k != "site"})
+                 for s in mine]
+        assert words[:8] == [("prefill", {"end": 8 * i})
+                             for i in range(1, 9)]
+        assert words[8:12] == [("chunk_prefill", {"w": w})
+                               for w in (1, 2, 4, 8)]
+        assert words[12:] == [("boundary", {}), ("decode_chunk", {"b": 2})]
+    if name == "dstpu/build_step":
+        assert mine[0].stats["site"] == "train_step"
+
+
+@pytest.mark.parametrize("build", ["serving", "training"])
+def test_build_counters_and_gauges_are_in_the_registry(build):
+    if build == "serving":
+        cfg, params, _ = _gpt2()
+        eng = serving_engine(params, cfg, telemetry=True, **ENGINE_KW)
+        snap = eng.registry.snapshot()
+        eng.shutdown()
+    else:
+        snap = _trainer()[0].registry.snapshot()
+    assert set(BUILD_COUNTERS) <= set(snap["counters"])
+    assert set(BUILD_GAUGES) <= set(snap["gauges"])
+    assert snap["gauges"]["build_seconds"] > 0
+    # nothing the sampled half gave is left
+    names = [n for kind in ("counters", "gauges", "histograms")
+             for n in snap[kind]]
+    assert not [n for n in names if n.startswith("devprof_")
+                and "compiles" not in n]
 
 
 # -------------------------------------- (b) scopes in lowered programs

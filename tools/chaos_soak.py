@@ -1248,7 +1248,7 @@ def main():
               # faults, shedding and tier churn must also never
               # recompile after its first token — the stamp's
               # steady_state_recompiles is gated at exactly 0
-              devprof={"sample_rate": 0.05})
+              devprof=True)
 
     # ---- fault-free oracle: every distinct prompt's greedy completion.
     # The oracle ALSO runs history+incidents (same cadences as the
@@ -1450,10 +1450,6 @@ def main():
         "devprof": {
             "compiles_warmup": int(
                 devprof_snap.get("compiles_warmup", 0)),
-            "mfu": devprof_snap.get("mfu", 0.0),
-            "mbu": devprof_snap.get("mbu", 0.0),
-            "host_device_gap_s": devprof_snap.get("host_device_gap_s"),
-            "device_seconds": devprof_snap.get("device_seconds", {}),
         },
         "injected": plan_snap,
         "degraded_at_end": healthz["degraded"],

@@ -75,8 +75,6 @@ _ENGINE_SPARKS = (
     ("kv util", "serving_kv_page_utilization"),
     ("ttft p95", "serving_ttft_seconds:p95"),
     ("decode/s", "serving_decode_steps:rate"),
-    ("mfu", "devprof_mfu"),
-    ("mbu", "devprof_mbu"),
 )
 _FLEET_SPARKS = (
     ("queue", "fleet_queue_depth"),
@@ -338,17 +336,16 @@ def render(status: dict, health: dict | None = None,
                  f"<{cm.get('serving_rtol', 0.0):g}")
     dp = status.get("devprof", {})
     if dp.get("enabled"):
-        ds = dp.get("device_seconds", {})
         steady = dp.get("compiles_steady", 0)
-        L.append(f"dev   mfu {100 * dp.get('mfu', 0.0):.1f}%"
-                 f"  mbu {100 * dp.get('mbu', 0.0):.1f}%"
-                 f"  gap {1e3 * dp.get('host_device_gap_s', 0.0):.2f}ms"
-                 f"  compiles {dp.get('compiles_warmup', 0)}w"
+        bd = status.get("build", {})
+        L.append(f"dev   compiles {dp.get('compiles_warmup', 0)}w"
                  f"/{steady}s{'  RECOMPILING' if steady else ''}"
-                 f"  dev_s " +
-                 " ".join(f"{p[:3]}={ds.get(p, 0.0):.2f}"
-                          for p in ("prefill", "decode", "spec_verify",
-                                    "promote", "sample")))
+                 f"  build {bd.get('programs', 0)} programs"
+                 f" ({bd.get('cache_misses', 0)} compiled):"
+                 f" trace {bd.get('trace_s', 0.0):.2f}s"
+                 f" lower {bd.get('lower_s', 0.0):.2f}s"
+                 f" load {bd.get('cache_load_s', 0.0):.2f}s"
+                 f" compile {bd.get('compile_s', 0.0):.2f}s")
     L.extend(render_history(historyz, _ENGINE_SPARKS))
 
     slo = status.get("slo", {})

@@ -285,17 +285,15 @@ def main():
         "on the same build (telemetry+tracing+slo on in both arms); "
         "the enabled path adds one tick-hook compare per step")
 
-    # devprof-overhead A/B (ISSUE 17 acceptance): compile sentinel +
-    # sampled device-time attribution + roofline counters on vs off,
-    # telemetry/tracing on in BOTH arms — the enabled delta is the
-    # price of the sentinel's cache-size check, two counter adds per
-    # dispatch, and one block_until_ready per 1/sample_rate dispatches.
+    # devprof-overhead A/B (ISSUE 17 acceptance): compile sentinel on
+    # vs off, telemetry/tracing on in BOTH arms — the enabled delta is
+    # the price of the sentinel's cache-size check per dispatch.
     _, devprof_overhead = _ab("devprof")
     devprof_overhead["backend"] = jax.default_backend()
     devprof_overhead["note"] = (
-        "best-of-3 ms/decode-step, devprof enabled (compile sentinel + "
-        "5% sampled block_until_ready attribution + per-dispatch "
-        "flops/bytes accounting) vs disabled on the same build "
+        "best-of-3 ms/decode-step, devprof enabled (the compile "
+        "sentinel's cache-size check per dispatch) vs disabled on the "
+        "same build "
         "(telemetry+tracing on in both arms); disabled path = shared "
         "NULL_DEVPROF, wrap() is the identity")
 

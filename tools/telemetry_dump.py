@@ -18,8 +18,8 @@ Runs a tiny gpt2 ServingEngine on whatever backend is available (pass
   5. stamps DEVPROF_SAMPLE.json (ISSUE 17): the devprof block from
      /statusz plus the /profilez round-trip and a short on-demand
      jax.profiler capture, all over the same real HTTP server — the
-     standing record of the compile ledger (steady_state_compiles
-     must read 0), per-phase device seconds and MFU/MBU.
+     standing record of the compile sentinel (steady_state_compiles
+     must read 0) and of the build ledger (/statusz ``build``).
 
     python tools/telemetry_dump.py --cpu
 """
@@ -85,10 +85,9 @@ def main():
                        "batch": {"deadline_s": 300.0, "target": 0.9}},
              "default_tier": "interactive"},
         telemetry={"http_port": 0, "interval_s": 0.0},
-        # full-rate sampling: this is a tiny sample loop, so every
-        # dispatch contributing device time gives the stamp dense
-        # per-phase attribution (production default is 0.05)
-        devprof={"sample_rate": 1.0})
+        # the compile sentinel and its build-time warm-up: the stamp
+        # carries the zero-recompile contract and the build ledger
+        devprof=True)
 
     rng = np.random.default_rng(0)
     prefix = rng.integers(1, cfg.vocab_size, prompt_len - 4).tolist()
@@ -176,6 +175,9 @@ def main():
         "steady": dp.get("steady"),
         "steady_state_compiles": dp.get("compiles_steady"),
         "devprof": dp,
+        # what making the loop's programs ready cost, program by
+        # program (/statusz "build": the process-wide build ledger)
+        "build": statusz.get("build", {}),
         "profilez": profilez,
         "capture": capture,
     }, args.devprof_out)

@@ -123,29 +123,22 @@ def breakdown_from_chrome(trace: dict) -> dict:
     return {"requests": per, "summary": summary}
 
 
-def device_time_summary(rows) -> dict:
-    """Aggregate devprof's sampled device-time instants + compile
-    ledger events into the report's device section.  ``rows`` is an
-    iterable of ``(name, attrs)`` pairs — both export formats reduce
-    to it.  Sampled means sampled: the totals cover one dispatch per
-    1/devprof.sample_rate, a lower bound on device time, not a sum
-    over every dispatch (that's the devprof_device_seconds counters'
-    job)."""
-    phases = {}
-    compiles = {"warmup": 0, "steady": 0}
+def compile_summary(rows) -> dict:
+    """Aggregate the compile sentinel's ``xla_compile`` events into the
+    report's device section: how many programs each half made ready
+    and the build ledger's seconds for them.  ``rows`` is an iterable
+    of ``(name, attrs)`` pairs — both export formats reduce to it."""
+    compiles = {"warmup": 0, "steady": 0, "seconds": 0.0}
     for name, attrs in rows:
-        attrs = attrs or {}
-        if name == "devprof_sample":
-            p = attrs.get("devprof_phase", "?")
-            rec = phases.setdefault(p, {"dev_s": 0.0, "samples": 0})
-            rec["dev_s"] += float(attrs.get("dev_s", 0.0))
-            rec["samples"] += 1
-        elif name == "xla_compile":
+        if name == "xla_compile":
+            attrs = attrs or {}
             compiles["steady" if attrs.get("steady") else
                      "warmup"] += int(attrs.get("n", 1))
-    if not phases and not (compiles["warmup"] or compiles["steady"]):
+            compiles["seconds"] += float(attrs.get("duration_s") or 0.0)
+    if not (compiles["warmup"] or compiles["steady"]):
         return {}
-    return {"phases": phases, "compiles": compiles}
+    compiles["seconds"] = round(compiles["seconds"], 6)
+    return {"compiles": compiles}
 
 
 def load_breakdown(path: str) -> dict:
@@ -154,12 +147,12 @@ def load_breakdown(path: str) -> dict:
     if path.endswith(".jsonl"):
         evs = read_jsonl(path)
         bd = request_breakdown(evs)
-        dev = device_time_summary((e[3], e[4]) for e in evs)
+        dev = compile_summary((e[3], e[4]) for e in evs)
     else:
         with open(path) as f:
             trace = json.load(f)
         bd = breakdown_from_chrome(trace)
-        dev = device_time_summary(
+        dev = compile_summary(
             (ev.get("name"), ev.get("args"))
             for ev in trace.get("traceEvents", []))
     if dev:
@@ -316,15 +309,9 @@ def print_report(bd: dict, limit: int = 20) -> None:
               f"{kt['promote_wait_s']:.4f}s inside TTFT")
     dv = summary.get("device")
     if dv:
-        # device truth next to the host columns above: the host clock
-        # includes dispatch/python; these are block_until_ready deltas
-        print("  device_s (sampled)  "
-              + "  ".join(f"{p}={rec['dev_s']:.4f}s"
-                          f"/{rec['samples']}x"
-                          for p, rec in sorted(dv["phases"].items())))
         c = dv["compiles"]
         print(f"  xla compiles: {c['warmup']} warmup, "
-              f"{c['steady']} steady"
+              f"{c['steady']} steady, {c.get('seconds', 0.0):.2f}s"
               + ("  <-- STEADY-STATE RECOMPILE (shape drift)"
                  if c["steady"] else ""))
     if summary.get("truncated_requests"):
@@ -373,15 +360,15 @@ def selftest(args) -> int:
     # speculation on: the stamped sample demonstrates draft/verify/
     # rollback attribution (spec_accept instants inside request spans,
     # sweep events on the speculative track, summary.speculation)
-    # devprof on at sample_rate=1: the stamped sample demonstrates the
-    # device-time column + compile ledger next to the host breakdown
+    # devprof on: the stamped sample carries the compile sentinel's
+    # ledger next to the host breakdown
     eng = serving_engine(
         params, cfg, max_batch=4, page_size=8,
         num_pages=4 * (-(-max_seq // 8)) + 16, max_seq=max_seq,
         prefill_bucket=8, decode_chunk=4, prefix_cache=True,
         speculative={"draft_tokens": 4},
         tracing={"sample_rate": 1.0},
-        devprof={"sample_rate": 1.0})
+        devprof=True)
 
     rng = np.random.default_rng(0)
     prefix = rng.integers(1, cfg.vocab_size, prompt_len - 4).tolist()
@@ -407,7 +394,7 @@ def selftest(args) -> int:
 
     events = eng.tracer.recorder.events()
     bd = request_breakdown(events)
-    dev = device_time_summary((e[3], e[4]) for e in events)
+    dev = compile_summary((e[3], e[4]) for e in events)
     if dev:
         bd["summary"]["device"] = dev
     print_report(bd)
