@@ -56,7 +56,10 @@ class PagedKVCache(NamedTuple):
     table: [B, max_pages] int32 page ids; seq_lens: [B] int32 valid
     token counts.  ``expert_rows`` ([Eh] int32, or None): rows routed to
     each held expert that the programs have added up since the last
-    decode program handed the sum out (a family with ``expert_rows``).
+    decode program handed the sum out (a family with ``expert_rows``);
+    [Eh + 1] where the last counts the further passes of the held
+    experts' pair buffer (an engine of a family that holds a share of
+    the experts its ``router`` scores).
 
     ``real`` ([B] int32, or None: all) is how many of the T tokens a
     forward is handed are real, a row: a serving program sets it where

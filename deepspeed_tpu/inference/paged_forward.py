@@ -25,6 +25,7 @@ from deepspeed_tpu.inference.kernels import (latent_attention_step,
                                              state_rows, write_state_rows)
 from deepspeed_tpu.inference.quantized import dequantize_params
 from deepspeed_tpu.models.family import decoder_family
+from deepspeed_tpu.parallel.moe import extra_pair_passes
 
 
 def _interpret(interpret: Optional[bool]) -> bool:
@@ -51,8 +52,23 @@ def _paged_block(fam, out, cfg, x, lp, ctx, layer, kp, vp, kps, vps, rows,
     x = out(cfg, x, attn.reshape(B, T, -1), lp)
     if out is fam.out and fam.expert_rows(cfg)[0]:
         x, routed = x
-        rows = None if rows is None else rows + routed
+        rows = _count_routed(fam, cfg, rows, routed, B * T)
     return x, kp, vp, kps, vps, rows
+
+
+def _count_routed(fam, cfg, rows, routed, N: int):
+    """A cache's running count ``rows`` with an expert layer's
+    ``routed`` [n] (the rows of its ``N`` that went to each held expert)
+    added.  A count one longer than ``routed`` has the further passes of
+    the held experts' pair buffer behind the experts (an engine of a
+    family that holds a share of what its ``router`` scores)."""
+    if rows is None:
+        return None
+    if rows.shape == routed.shape:
+        return rows + routed
+    scored, top_k = fam.router(cfg)
+    return rows + jnp.append(
+        routed, extra_pair_passes(routed, N, top_k, scored))
 
 
 def _forward_periods(fam, params, x, cfg, cache, block, *, whole: bool):
@@ -103,7 +119,7 @@ def _forward_periods(fam, params, x, cfg, cache, block, *, whole: bool):
         with jax.named_scope("kv_write"), jax.named_scope("gdn_write"):
             conv, state = write_state_rows(conv, state, layer, slot, held)
         x, routed = rec.out(cfg, x, y, lp)
-        return (x, None if rows is None else rows + routed, conv,
+        return (x, _count_routed(fam, cfg, rows, routed, B * T), conv,
                 state), None
 
     # consecutive layers of one kind: [(recurrent?, how many), ...]

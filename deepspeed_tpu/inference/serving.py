@@ -182,13 +182,14 @@ def serving_programs(prefill_fn, decode_fn, chunk_prefill_fn, sample,
     otherwise) — both emit bit-identical greedy tokens and share the
     categorical math, so flipping the policy can never change a served
     greedy stream.  ``expert_rows``: the cache carries a family's count
-    of rows routed to its held experts, and the decode program returns
-    it flat behind its tokens (``[B * K + Eh]`` int32).  ``state``: the
-    cache carries a per-slot recurrent state, which only real tokens may
-    move, so each program tells the forward which of its rows are real
-    (``cache.real``): a prefill or chunk the tokens up to ``last``, a
-    decode step the rows whose length is not 0 (an idle slot's, and a
-    slot's between two chunks of its prompt, is)."""
+    of rows routed to its held experts (and of its pair buffer's
+    further passes, where it has one), and the decode program returns it
+    flat behind its tokens (``[B * K + Eh]`` int32, or one more).
+    ``state``: the cache carries a per-slot recurrent state, which only
+    real tokens may move, so each program tells the forward which of its
+    rows are real (``cache.real``): a prefill or chunk the tokens up to
+    ``last``, a decode step the rows whose length is not 0 (an idle
+    slot's, and a slot's between two chunks of its prompt, is)."""
     def real_upto(cache, last):
         return cache._replace(real=last + 1) if state else cache
 
@@ -389,7 +390,7 @@ class ServingEngine:
                  replica_id: Optional[str] = None,
                  history=None, incidents=None, kernels=None,
                  devprof=None, comm=None, values_in_keys: bool = False,
-                 expert_rows: int = 0, routed_per_row: int = 0,
+                 expert_rows=(0, False), routed_per_row: int = 0,
                  state_row=None):
         # ---- telemetry: one registry for every hot-path metric.
         # `telemetry` accepts None/bool/dict/TelemetryConfig — or an
@@ -416,10 +417,12 @@ class ServingEngine:
         # what a token's cache row is: per-head K and V pools, or one
         # pool whose rows are keys and values both (a latent family's
         # ``cache_row``); and how many held experts' routed rows the
-        # programs count (0: none), each row routed ``routed_per_row``
-        # times in all (top-k x expert layers)
+        # programs count (0: none) and whether they count, behind them,
+        # the further passes of the held experts' pair buffer (a family
+        # that holds a share of the experts its router scores), each row
+        # routed ``routed_per_row`` times in all (top-k x expert layers)
         self._values_in_keys = bool(values_in_keys)
-        self._n_expert_rows = int(expert_rows)
+        self._n_expert_rows, self._pair_passes = expert_rows
         self._routed_per_row = int(routed_per_row)
         # what a slot keeps beside its pages, a recurrent layer (a
         # family's ``Recurrent.state_row``; None: nothing)
@@ -630,6 +633,11 @@ class ServingEngine:
         self._c_routed_rows = r.counter(
             "serving_routed_rows",
             "(row, expert) pairs routed: rows x top-k x expert layers")
+        self._c_pair_passes = r.counter(
+            "serving_expert_pair_extra_passes",
+            "passes beyond the first over the held experts' pair buffer: "
+            "an expert layer of a program was routed more held pairs "
+            "than the buffer's bound (none is dropped)")
         self._rows_pending = 0
         self._c_state_fresh = r.counter(
             "serving_state_fresh_starts",
@@ -1184,8 +1192,9 @@ class ServingEngine:
             (self.max_batch, self.max_pages_per_seq),
             self.trash_page, jnp.int32))
         seq_lens = self._put(jnp.zeros((self.max_batch,), jnp.int32))
-        expert_rows = (self._put(np.zeros((self._n_expert_rows,), np.int32))
-                       if self._n_expert_rows else None)
+        expert_rows = (self._put(np.zeros(
+            (self._n_expert_rows + self._pair_passes,), np.int32))
+            if self._n_expert_rows else None)
         conv = state = None
         if self._state_row is not None:
             # indexed by slot, not by page: a slot's state weighs the
@@ -2948,14 +2957,17 @@ class ServingEngine:
 
     def _take_expert_rows(self, flat: np.ndarray, K: int) -> np.ndarray:
         """Split what a decode program of a family that counts its
-        experts' rows returned (``[B * K + Eh]``): the counters advance
-        by the held experts' rows and by every pair the programs routed
-        since the last decode (this one's ``B * K`` rows, padding and
-        idle slots included, and the prefills' in between); the tokens
-        come back ``[B, K]``."""
+        experts' rows returned (``[B * K + Eh]``, and one more where
+        the pair buffer's further passes are counted): the counters
+        advance by the held experts' rows and by every pair the programs
+        routed since the last decode (this one's ``B * K`` rows, padding
+        and idle slots included, and the prefills' in between); the
+        tokens come back ``[B, K]``."""
         n = self.max_batch * K
         for c, rows in zip(self._c_expert_rows, flat[n:]):
             c.inc(int(rows))
+        if self._pair_passes:
+            self._c_pair_passes.inc(int(flat[-1]))
         self._c_routed_rows.inc(
             (self._rows_pending + n) * self._routed_per_row)
         self._rows_pending = 0
@@ -3852,7 +3864,8 @@ def serving_engine(params, cfg, **kw):
     # the counts ride in the decode program's fetch; a speculating
     # engine's steady program is the verify sweep, which has none
     if held and not speculating:
-        kw.update(expert_rows=held, routed_per_row=per_row)
+        kw.update(expert_rows=(held, fam.router(cfg)[0] > held),
+                  routed_per_row=per_row)
     if row.values_in_keys:
         kw["values_in_keys"] = True
     eng = ServingEngine(

@@ -142,6 +142,13 @@ class DecoderFamily:
     # expert layers); where the first is not 0, ``out`` returns
     # ``(x, rows [n] int32)``
     expert_rows: Callable[[Any], Tuple[int, int]] = lambda cfg: (0, 0)
+    # cfg -> (how many experts the router scores in all, how many of
+    # them a row is routed to).  Where that is more experts than
+    # ``expert_rows`` says are held (a rank's share of an expert-parallel
+    # deployment), the programs count one thing more behind the held
+    # experts' rows: the further passes the held experts' pair buffer
+    # needed (:func:`~deepspeed_tpu.parallel.moe.extra_pair_passes`)
+    router: Callable[[Any], Tuple[int, int]] = lambda cfg: (0, 0)
     # leaves of ``blocks`` the paged loop does not slice a layer out of:
     # ``out`` gets them whole, [L, ...], with ``lp["layer"]`` the layer's
     # index in them (a kernel that takes the stack and an index reads a

@@ -275,7 +275,8 @@ def expert_layer(cfg: MixtralConfig, h, lp):
         layer = lp.get("layer")
         y, rows = held_experts_ffn(hf, w, topi, lp["w1"], lp["w3"],
                                    lp["w2"], layer=layer,
-                                   grouped=layer is not None)
+                                   grouped=layer is not None,
+                                   n_experts=lp["gate"].shape[-1])
     return y.reshape(B, T, d), rows
 
 
@@ -412,4 +413,5 @@ FAMILY = dataclasses.replace(
     quant_skip_paths=("gate",) + _llama.FAMILY.quant_skip_paths,
     shard_axes=("model", "expert"), check=_check,
     expert_rows=lambda cfg: (cfg.num_experts, cfg.top_k * cfg.n_layers),
+    router=lambda cfg: (cfg.num_experts, cfg.top_k),
     whole_stacks=("w1", "w3", "w2"))

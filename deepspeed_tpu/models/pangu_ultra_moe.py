@@ -258,7 +258,8 @@ def expert_layer(cfg, h, lp):
     with jax.named_scope("moe_ffn"):
         y, rows = held_experts_ffn(hf, w, experts, lp["w1"], lp["w3"],
                                    lp["w2"], first=cfg.experts_held[0],
-                                   layer=lp.get("layer"))
+                                   layer=lp.get("layer"),
+                                   n_experts=lp["gate"].shape[-1])
         with jax.named_scope("moe_shared"):
             y = y + swiglu(hf, lp["sw1"], lp["sw3"]) @ lp["sw2"]
     return y.reshape(B, T, d), rows
@@ -340,6 +341,7 @@ FAMILY = DecoderFamily(
     lead=("dense_blocks", _out_dense), latent=_latent,
     expert_rows=lambda cfg: (cfg.experts_held[1],
                              cfg.top_k * cfg.n_expert_layers),
+    router=lambda cfg: (cfg.n_routed_experts, cfg.top_k),
     whole_stacks=("w1", "w3", "w2"),
     refuses=(
         ("quantized_resident", "int8-resident " + _LATENT_PAGES),

@@ -380,7 +380,8 @@ def expert_layer(cfg, h, lp):
     with jax.named_scope("moe_ffn"):
         y, rows = held_experts_ffn(hf, w, experts, lp["w1"], lp["w3"],
                                    lp["w2"], first=cfg.experts_held[0],
-                                   layer=lp.get("layer"))
+                                   layer=lp.get("layer"),
+                                   n_experts=lp["gate"].shape[-1])
         with jax.named_scope("moe_shared"):
             open_ = jax.nn.sigmoid(jnp.einsum(
                 "nd,do->no", hf, lp["shared_gate"],
@@ -470,6 +471,7 @@ FAMILY = DecoderFamily(
     shard_axes=("model", "expert"), check=_check,
     expert_rows=lambda cfg: (cfg.experts_held[1],
                              cfg.top_k * cfg.n_expert_layers),
+    router=lambda cfg: (cfg.n_routed_experts, cfg.top_k),
     whole_stacks=("w1", "w3", "w2"),
     recurrent=Recurrent(key="gdn_blocks", period=_period, mix=gdn_mix,
                         out=_gdn_out, state_row=_state_row),

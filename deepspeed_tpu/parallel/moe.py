@@ -206,6 +206,15 @@ def softmax_topk_route(h, gate, top_k: int, normalize: bool = True):
 # (rows, contraction, columns) tile of the Mosaic grouped product; the
 # last two are fitted to each operand (``fit`` below)
 _GMM_TILING = (256, 1920, 1024)
+# The pair buffer of a rank that holds a share of the experts, as a
+# multiple of the pairs it is routed if the router is even.  The routed
+# part alone on a v5e, ms a layer at 1,024 rows (PERF.md 6, PR 40): 64 of
+# 512 experts of 2048 x 512, k = 10: 1.11 at 1.5 (2,048 rows), 1.16 at 2
+# (4,096), 1.26 at 4 (8,192), 1.68 with a row for every pair (16,384);
+# 16 of 256 experts of 7680 x 2048, k = 8: 3.22, 3.22 (1,024 rows both),
+# 3.34, 4.04.  2 and not 1.5: a pass more costs a whole pass, 4% buys
+# twice the room over an even router, and a trained router is not even
+_PAIR_BOUND = 2
 
 
 def _every_row_pays(N: int, k: int, Eh: int) -> bool:
@@ -223,8 +232,10 @@ def _every_row_pays(N: int, k: int, Eh: int) -> bool:
     grouped, 384 rows 6.0 against 5.5, 1,024 rows 16.0 against 8.3 (the
     rule crosses at 341); 16 of 256 experts of 7680 x 2048, k = 8: 256
     rows 2.4 against 2.6, 512 rows 4.5 against 2.9 (it crosses at 512,
-    late for a share: few of its N * k pairs are held, and nothing here
-    sees how many experts there are in all).
+    late for a share: few of its N * k pairs are held.  The grouped
+    branch's pair buffer is sized from how many experts there are in
+    all, :func:`_pair_buffer_rows`; this rule still is not, and no
+    cell's programs have a row count between the two crossings).
     """
     return N * (Eh - k) < Eh * _GMM_TILING[0]
 
@@ -258,13 +269,44 @@ def _grouped_product(x, w, sizes, layer=None):
     return jax.lax.ragged_dot(x, w if layer is None else w[layer], sizes)
 
 
+def _pair_buffer_rows(N: int, k: int, Eh: int, E: int) -> int:
+    """Rows of the grouped branch's pair buffer, a rule of the shapes:
+    whole row tiles, a power of two of them (programs of neighbouring
+    row counts share one trace of the grouped product: 0.17 s each on
+    the chip's host, PERF.md 6, PR 34), enough for ``_PAIR_BOUND`` times
+    the ``N * k * Eh / E`` pairs that are held if the router is even,
+    and never more than the ``N * k`` pairs there are.  All the experts
+    held (``Eh == E``): every pair, as before the bound."""
+    tm = _GMM_TILING[0]
+    tiles = lambda rows: 1 << (-(-rows // tm) - 1).bit_length()
+    return tm * min(tiles(N * k),
+                    tiles(math.ceil(_PAIR_BOUND * N * k * Eh / E)))
+
+
+def extra_pair_passes(sizes, N: int, k: int, E: int):
+    """How many passes beyond the first :func:`held_experts_ffn` makes
+    over its pair buffer for ``N`` rows routed ``k`` ways over ``E``
+    experts, of which ``sizes`` [Eh] went to each held expert -> int32
+    scalar: a pass takes :func:`_pair_buffer_rows` held pairs.  None
+    where every held expert takes every row, nor where the buffer has a
+    row for every pair.  What the engines count as
+    ``serving_expert_pair_extra_passes``."""
+    Eh = sizes.shape[0]
+    C = _pair_buffer_rows(N, k, Eh, E)
+    if _every_row_pays(N, k, Eh) or C >= N * k:
+        return jnp.zeros((), jnp.int32)
+    return jnp.maximum(-(-jnp.sum(sizes) // C) - 1, 0)
+
+
 def held_experts_ffn(h, weights, experts, w1, w3, w2, first: int = 0,
-                     layer=None, grouped: bool = True):
+                     layer=None, grouped: bool = True,
+                     n_experts: Optional[int] = None):
     """The part of a routed FFN that the experts held here contribute:
     drop-free at any imbalance, static shapes.
 
     ``h`` [N, d]; ``weights``/``experts`` [N, k] from the router over all
-    the experts there are; ``w1``/``w3`` [Eh, d, f] and ``w2`` [Eh, f, d]
+    the ``n_experts`` there are (the width of its gate; not said: no
+    more than are held); ``w1``/``w3`` [Eh, d, f] and ``w2`` [Eh, f, d]
     the SwiGLU experts ``first .. first + Eh`` -> (y [N, d] in ``h``'s
     dtype, rows [Eh] int32 routed to each held expert).  With ``layer``
     (a layer loop's traced index) the weights are the whole stacks
@@ -273,11 +315,16 @@ def held_experts_ffn(h, weights, experts, w1, w3, w2, first: int = 0,
     Many rows: every (token, expert) pair is a row; pairs whose expert is
     held sort first, by expert, and the rest (what other ranks compute)
     fall past the last group, where the grouped product visits no tile.
-    The row buffer is the worst case N * k: all of a token's experts may
-    be held.  Few rows (a decode step; :func:`_every_row_pays`): each
-    held expert evaluates every row and the router's weight, zero where
-    it did not choose the expert, combines them; that reads each expert
-    once, as the grouped product would, without its row tiles.
+    The row buffer holds a bound on the pairs held HERE
+    (:func:`_pair_buffer_rows`: twice the even share of a rank that
+    holds ``Eh`` of ``n_experts``), not every pair the router made; when
+    more are held than it takes, further passes over the sorted order
+    take the rest (:func:`extra_pair_passes` counts them), each adding
+    into the same f32 sum.  Few rows (a decode step;
+    :func:`_every_row_pays`): each held expert evaluates every row and
+    the router's weight, zero where it did not choose the expert,
+    combines them; that reads each expert once, as the grouped product
+    would, without its row tiles.
 
     ``grouped=False`` is the caller's word that the weights are not
     plain arrays held whole on one device (sharded over a mesh, which a
@@ -303,22 +350,63 @@ def held_experts_ffn(h, weights, experts, w1, w3, w2, first: int = 0,
                              ys.astype(jnp.float32))
             return out.astype(h.dtype), sizes
         order = jnp.argsort(group)                   # stable
-        # whole row tiles, a power of two of them: the rows added stand
-        # past every group (a gathered row each, no product), and
-        # programs of neighbouring row counts share one trace of the
-        # grouped product (0.17 s each on the chip's host, PERF.md 6)
-        tm = _GMM_TILING[0]
-        pad = tm * (1 << (-(-N * k // tm) - 1).bit_length()) - N * k
-        x = h[(jnp.pad(order, (0, pad)) if pad else order) // k]
-        a = _grouped_product(x, w1, sizes, layer)
-        b = _grouped_product(x, w3, sizes, layer)
-        y = _grouped_product(jax.nn.silu(a) * b, w2, sizes, layer)
-        # back to pair order by a gather (``order`` is a permutation),
-        # then a token's k pairs are summed with the router's weights:
-        # no scatter of wide rows.  A pair that is not held sat past the
-        # groups, where the product left whatever was there.
-        back = jnp.zeros((N * k,), jnp.int32).at[order].set(
-            jnp.arange(N * k, dtype=jnp.int32))
-        y = jnp.where(held[:, None], y[back].astype(jnp.float32), 0.0) \
-            * weights.reshape(-1, 1)
-        return jnp.sum(y.reshape(N, k, -1), axis=1).astype(h.dtype), sizes
+
+        def products(x, sizes):
+            a = _grouped_product(x, w1, sizes, layer)
+            b = _grouped_product(x, w3, sizes, layer)
+            return _grouped_product(jax.nn.silu(a) * b, w2, sizes, layer)
+
+        def stands():
+            """Where each pair stands in the sorted order (``order`` is
+            a permutation): what takes a product's rows back to their
+            tokens by a gather, no scatter of wide rows."""
+            return jnp.zeros((N * k,), jnp.int32).at[order].set(
+                jnp.arange(N * k, dtype=jnp.int32))
+
+        C = _pair_buffer_rows(N, k, Eh, n_experts or Eh)
+        if C >= N * k:
+            # every pair has a row: the rows added stand past every
+            # group (a gathered row each, no product)
+            pad = C - N * k
+            y = products(
+                h[(jnp.pad(order, (0, pad)) if pad else order) // k], sizes)
+            # a token's k pairs are summed with the router's weights.  A
+            # pair that is not held sat past the groups, where the
+            # product left whatever was there.
+            back = stands()
+            y = jnp.where(held[:, None], y[back].astype(jnp.float32), 0.0) \
+                * weights.reshape(-1, 1)
+            return jnp.sum(y.reshape(N, k, -1), axis=1).astype(h.dtype), \
+                sizes
+        # pass p takes the sorted pairs p * C .. (p + 1) * C: the held
+        # ones stand first, so one pass is all of them unless more than
+        # C are held.  One loop, so that a program traces the grouped
+        # product once
+        ends = jnp.cumsum(sizes)
+        held, back = held.reshape(N, k), stands().reshape(N, k)
+        order = jnp.pad(order, (0, -N * k % C))
+
+        def one_pass(p, out):
+            lo = p * C
+            # the part of each expert's run of pairs inside this pass
+            part = jnp.clip(ends, lo, lo + C) \
+                - jnp.clip(ends - sizes, lo, lo + C)
+            y = products(
+                h[jax.lax.dynamic_slice(order, (lo,), (C,)) // k], part)
+            # slot by slot, a token's pair out of this pass's rows: k
+            # gathers of N rows in one fusion, no [N, k, d] value (a
+            # second-minor dimension of k is a relayout: 0.16 s of a
+            # 3.8 s trace at k = 10, v5e, PR 35)
+            at = back - lo
+            here = held & (at >= 0) & (at < C)
+            at = jnp.clip(at, 0, C - 1)
+            for j in range(k):
+                out = out + jnp.where(
+                    here[:, j, None], y[at[:, j]].astype(jnp.float32), 0.0) \
+                    * weights[:, j, None]
+            return out
+
+        out = jax.lax.fori_loop(
+            0, 1 + extra_pair_passes(sizes, N, k, n_experts), one_pass,
+            jnp.zeros((N, h.shape[1]), jnp.float32))
+        return out.astype(h.dtype), sizes

@@ -399,10 +399,11 @@ _PANGU = dict(vocab_size=19200, n_layers=5, n_dense_layers=1,
               experts_held=(0, 16))
 _PANGU_PAGES, _PANGU_SLOTS, _PANGU_TABLE = 40961, 128, 12288 // PAGE
 # program -> (rows, tokens, table entries, bound on its temporaries in
-# GiB: AOT, PR 33, reads 0.072, 0.856 and 0.268)
+# GiB: AOT, PR 40, reads 0.072, 0.856 and 0.155; the last 0.268 while
+# the pair buffer was 8,192 rows tall)
 PANGU_PROGRAMS = {"decode": (_PANGU_SLOTS, 1, _PANGU_TABLE, 0.1),
                   "chunk_full_table": (1, 1024, _PANGU_TABLE, 0.95),
-                  "chunk_first": (1, 1024, 64, 0.35)}
+                  "chunk_first": (1, 1024, 64, 0.2)}
 
 
 def test_mla_decode_kernel(chip):
@@ -440,7 +441,9 @@ def test_latent_cell_programs_fit_and_leave_the_pool_in_place(
     cell's sizes: they compile for the described v5e (the 9.16 GiB of
     weights and the 3.91 GiB pool beside their temporaries, inside
     15.75 GiB), hold no copy of the pool or of a layer's 1.5 GB of
-    experts, and run the kernels by name."""
+    experts, and run the kernels by name; a chunk's pair buffer is the
+    1,024 rows that bound the pairs held here, not the 8,192 there
+    are."""
     from deepspeed_tpu.models import pangu_ultra_moe as pangu
 
     # the family asks the backend which attention and grouped product to
@@ -459,7 +462,7 @@ def test_latent_cell_programs_fit_and_leave_the_pool_in_place(
         k=jax.ShapeDtypeStruct(shape, jnp.bfloat16), v=None,
         table=jax.ShapeDtypeStruct((rows, table), jnp.int32),
         seq_lens=jax.ShapeDtypeStruct((rows,), jnp.int32), page_size=PAGE,
-        expert_rows=jax.ShapeDtypeStruct((16,), jnp.int32))
+        expert_rows=jax.ShapeDtypeStruct((16 + 1,), jnp.int32))
     forward = lambda continuation: lambda params, tokens, cache: \
         forward_paged(params, tokens, cfg, cache, interpret=False,
                       tp=False, continuation=continuation)
@@ -487,6 +490,10 @@ def test_latent_cell_programs_fit_and_leave_the_pool_in_place(
     assert re.search(rf"%{kernel}[\w.]* = .*tpu_custom_call", hlo)
     if program != "decode":
         assert re.search(r"%gmm[\w.]* = .*tpu_custom_call", hlo)
+        assert "bf16[8192,7680]" not in hlo and "f32[1024,8,7680]" not in hlo
+        # the pass loop hands the Mosaic call the stack it was handed
+        for stack in ((64, 7680, 2048), (64, 2048, 7680)):
+            assert _top_level_results(hlo, stack) == []
 
 
 # ------------------------------------ the recurrent family's cell (PR 35)
@@ -498,12 +505,13 @@ def test_latent_cell_programs_fit_and_leave_the_pool_in_place(
 _QWEN = dict(vocab_size=18992, n_layers=12, experts_held=(0, 64))
 _QWEN_PAGES, _QWEN_SLOTS, _QWEN_TABLE = 65537, 96, 17408 // PAGE
 # program -> (rows, tokens, table entries, bound on its temporaries in
-# GiB: AOT, PR 35, reads 0.070, 1.247 and 0.337; 0.26-0.29, 1.26 and
-# 0.55-0.61 while the outer loop sliced a period of the linear layers'
-# weights out of their stack)
+# GiB: AOT, PR 40, reads 0.070, 1.266 and 0.340 (PR 35: 0.070, 1.247 and
+# 0.337: the attention layers' gathered K/V and scores are the peak, not
+# the pair buffer); 0.26-0.29, 1.26 and 0.55-0.61 while the outer loop
+# sliced a period of the linear layers' weights out of their stack)
 QWEN_PROGRAMS = {"decode": (_QWEN_SLOTS, 1, _QWEN_TABLE, 0.1),
                  "chunk_full_table": (1, 1024, _QWEN_TABLE, 1.3),
-                 "chunk_first": (1, 1024, 64, 0.4)}
+                 "chunk_first": (1, 1024, 64, 0.36)}
 
 
 def _top_level_results(hlo, dims):
@@ -567,7 +575,7 @@ def test_recurrent_cell_programs_fit_and_keep_pool_and_state_in_place(
     cache = K.PagedKVCache(
         k=S(shape, jnp.bfloat16), v=S(shape, jnp.bfloat16),
         table=S((rows, table), jnp.int32), seq_lens=S((rows,), jnp.int32),
-        page_size=PAGE, expert_rows=S((64,), jnp.int32),
+        page_size=PAGE, expert_rows=S((64 + 1,), jnp.int32),
         conv=S((sr.layers, _QWEN_SLOTS) + sr.conv, jnp.bfloat16),
         state=S(state_shape, K.STATE_DTYPE),
         slot=None if program == "decode" else S((1,), jnp.int32))
@@ -609,6 +617,18 @@ def test_recurrent_cell_programs_fit_and_keep_pool_and_state_in_place(
         # slices them out, as Mixtral's and the latent family's do
         for experts in ((64, 2048, 512), (64, 512, 2048)):
             assert _top_level_results(hlo, experts) == []
+        # nor does the pass loop copy the stack it hands the Mosaic call
+        # (the attention layers' [3, 64, ...], the linear layers' [9, ...])
+        for stack in ((192, 2048, 512), (192, 512, 2048),
+                      (576, 2048, 512), (576, 512, 2048)):
+            assert _top_level_results(hlo, stack) == []
+        # the pair buffer is 4,096 rows, a bound on the ~1,280 of the
+        # chunk's 10,240 pairs that are held here; no pair that another
+        # rank computes is gathered, re-laid out or summed
+        assert "bf16[4096,2048]" in hlo
+        for gone in ("bf16[16384,2048]", "bf16[10240,2048]",
+                     "f32[1024,10,2048]", "f32[10240,2048]"):
+            assert gone not in hlo, gone
     if program == "decode":
         assert re.search(r"%dstpu_paged_decode[\w.]* = .*tpu_custom_call",
                          hlo)

@@ -225,3 +225,127 @@ def test_a_speculating_engine_counts_nothing(params):
     assert eng.cache.expert_rows is None
     eng.submit(0, [5, 9, 2, 7], max_new_tokens=4)
     assert len(eng.run()[0]) == 8
+
+
+# -------------------------------- a share's pair buffer (ISSUE 40)
+# (N, k, Eh, E): the two shares' shapes in small.  Against a row tile of
+# 16 the buffer is 256 rows of Qwen3-Next's 640 pairs (an eighth held if
+# the router is even: 80) and 64 rows of openPangu's 512 (a sixteenth: 32)
+SHARES = {"k10_an_eighth": (64, 10, 16, 128),
+          "k8_a_sixteenth": (64, 8, 8, 128)}
+ROUTINGS = ("as_drawn", "every_pair_held", "one_pair_more_than_the_buffer")
+TILE, D, F = 16, 32, 16
+
+
+def _share_case(monkeypatch, share, routing):
+    """-> (h, w, experts, (w1, w3, w2), first, C): a rank that holds
+    experts ``first .. first + Eh`` of E, on the grouped branch."""
+    N, k, Eh, E = SHARES[share]
+    _pin(monkeypatch, "grouped")
+    monkeypatch.setattr(moe, "_GMM_TILING", (TILE, 128, 128))
+    C = moe._pair_buffer_rows(N, k, Eh, E)
+    rng = np.random.default_rng(len(share) + len(routing))
+    first = Eh                                  # the second rank's share
+    held = first + np.arange(Eh)
+    absent = np.setdiff1d(np.arange(E), held)
+    if routing == "as_drawn":
+        experts = np.stack([rng.permutation(E)[:k] for _ in range(N)])
+    elif routing == "every_pair_held":
+        experts = np.stack([rng.choice(held, k, replace=Eh < k)
+                            for _ in range(N)])
+    else:                       # the first C + 1 pairs, and no other
+        experts = rng.choice(absent, (N, k))
+        flat = experts.reshape(-1)
+        flat[:C + 1] = held[np.arange(C + 1) % Eh]
+    g = lambda *s: jnp.asarray(rng.normal(size=s) * s[-2] ** -0.5,
+                               jnp.float32)
+    return (jnp.asarray(rng.normal(size=(N, D)), jnp.float32),
+            jnp.asarray(rng.dirichlet(np.ones(k), N), jnp.float32),
+            jnp.asarray(experts, jnp.int32),
+            (g(Eh, D, F), g(Eh, D, F), g(Eh, F, D)), first, C)
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+@pytest.mark.parametrize("share", SHARES)
+def test_a_shares_bounded_buffer_is_the_per_token_loop(monkeypatch, share,
+                                                       routing):
+    """The pair buffer holds a bound on the pairs held here, and what it
+    cannot take goes through further passes: no pair is dropped when
+    every pair is held (the last pass is full) nor when one more than
+    the buffer is (a second pass of one pair), and the passes counted
+    are the passes made."""
+    N, k, Eh, E = SHARES[share]
+    h, w, experts, ws, first, C = _share_case(monkeypatch, share, routing)
+    assert C < N * k
+    y, rows = moe.held_experts_ffn(h, w, experts, *ws, first=first,
+                                   n_experts=E)
+    local = np.asarray(experts) - first
+    mine = (local >= 0) & (local < Eh)
+    np.testing.assert_allclose(
+        y, _per_token_loop(h, np.where(mine, w, 0.0),
+                           np.where(mine, local, 0), *ws),
+        atol=2e-5, rtol=2e-5)
+    assert rows.tolist() == np.bincount(local[mine], minlength=Eh).tolist()
+    extra = int(moe.extra_pair_passes(rows, N, k, E))
+    assert extra == {"as_drawn": 0, "every_pair_held": -(-N * k // C) - 1,
+                     "one_pair_more_than_the_buffer": 1}[routing]
+    if routing == "as_drawn":
+        assert 0 < mine.sum() <= C
+    elif routing == "one_pair_more_than_the_buffer":
+        assert mine.sum() == C + 1
+
+
+@pytest.mark.parametrize("share", SHARES)
+def test_a_shares_buffer_is_its_bound_and_not_every_pair(monkeypatch, share):
+    """The traced call holds a [C, d] row buffer, gathers no [N * k, d]
+    rows and never forms [N, k, d]."""
+    N, k, Eh, E = SHARES[share]
+    h, w, experts, ws, first, C = _share_case(monkeypatch, share, "as_drawn")
+    jaxpr = str(jax.make_jaxpr(lambda h: moe.held_experts_ffn(
+        h, w, experts, *ws, first=first, n_experts=E))(h))
+    assert f"f32[{C},{D}]" in jaxpr and f"f32[{C},{F}]" in jaxpr
+    for gone in (f"[{N * k},{D}]", f"[{N * k},{F}]", f"[{N},{k},{D}]",
+                 f"[{TILE * 64},{D}]"):
+        assert gone not in jaxpr, gone
+    assert jaxpr.count("= ragged_dot") == 3        # one trace of the pass
+
+
+def _before_the_bound(h, weights, experts, w1, w3, w2, first=0):
+    """The grouped branch as it was before ISSUE 40, every pair a row."""
+    N, k = experts.shape
+    Eh = w1.shape[-3]
+    local = experts.reshape(-1) - first
+    held = (local >= 0) & (local < Eh)
+    group = jnp.where(held, local, Eh)
+    sizes = jnp.zeros((Eh + 1,), jnp.int32).at[group].add(1)[:Eh]
+    with jax.named_scope("moe_routed"):
+        order = jnp.argsort(group)
+        tm = moe._GMM_TILING[0]
+        pad = tm * (1 << (-(-N * k // tm) - 1).bit_length()) - N * k
+        x = h[(jnp.pad(order, (0, pad)) if pad else order) // k]
+        a = moe._grouped_product(x, w1, sizes, None)
+        b = moe._grouped_product(x, w3, sizes, None)
+        y = moe._grouped_product(jax.nn.silu(a) * b, w2, sizes, None)
+        back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+            jnp.arange(N * k, dtype=jnp.int32))
+        y = jnp.where(held[:, None], y[back].astype(jnp.float32), 0.0) \
+            * weights.reshape(-1, 1)
+        return jnp.sum(y.reshape(N, k, -1), axis=1).astype(h.dtype), sizes
+
+
+@pytest.mark.parametrize("said", [None, CFG.num_experts],
+                         ids=["experts_not_said", "all_experts_held"])
+def test_all_the_experts_held_trace_as_before_the_bound(monkeypatch, params,
+                                                        said):
+    """Mixtral holds every expert its router scores: the bound is every
+    pair, and the traced call is the one it was, equation for equation."""
+    _pin(monkeypatch, "grouped")
+    rng = np.random.default_rng(8)
+    lp = {k: params["blocks"][k][0] for k in ("w1", "w3", "w2")}
+    h = jnp.asarray(rng.normal(size=(300, CFG.dim)), jnp.float32)
+    w, experts = _routing("as_routed", 300, rng)
+    now = jax.make_jaxpr(lambda h: moe.held_experts_ffn(
+        h, w, experts, lp["w1"], lp["w3"], lp["w2"], n_experts=said))(h)
+    then = jax.make_jaxpr(lambda h: _before_the_bound(
+        h, w, experts, lp["w1"], lp["w3"], lp["w2"]))(h)
+    assert str(now) == str(then)

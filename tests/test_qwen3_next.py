@@ -209,10 +209,43 @@ def test_the_engine_serves_the_reference_argmax(params, engine_kw):
                for e in range(CFG.experts_held[1]))
     assert routed % (CFG.top_k * CFG.n_expert_layers) == 0
     assert 0 < held < routed
+    assert counters["serving_expert_pair_extra_passes"] == 0
     status = eng.statusz()["cache.state"]
     assert status["bytes"] == eng.cache.conv.nbytes + eng.cache.state.nbytes
     assert status["bytes_per_slot"] * 3 == status["bytes"]
     assert status["live_slots"] == 0 and status["fresh_starts"] == 4
+
+
+def test_a_chunk_routed_past_the_pair_buffer_makes_further_passes(
+        monkeypatch):
+    """8 held of 64 experts: the pair buffer of a 16-row chunk takes 16
+    of its 64 pairs.  With every absent expert's gate column zero (a
+    logit of 0, under any held expert's that is positive) most pairs are
+    held: the chunk programs make further passes, the engine counts
+    them, and the tokens are the reference's argmax."""
+    import dataclasses
+
+    cfg = dataclasses.replace(CFG, n_routed_experts=64)
+    assert bench_family._ref_kw(cfg) == bench_family._ref_kw(CFG)
+    monkeypatch.setattr(moe, "_every_row_pays", lambda N, k, Eh: N < 16)
+    monkeypatch.setattr(moe, "_GMM_TILING", (4, 128, 128))
+    params = qn.init_params(jax.random.PRNGKey(1), cfg)
+    for stack in ("blocks", "gdn_blocks"):
+        params[stack]["gate"] = params[stack]["gate"].at[
+            ..., cfg.experts_held[1]:].set(0)
+    eng = _engine(params, cfg, max_batch=2)
+    assert eng.cache.expert_rows.shape == (cfg.experts_held[1] + 1,)
+    rng = np.random.default_rng(1)
+    prompts = {i: rng.integers(0, cfg.vocab_size, n).tolist()
+               for i, n in enumerate((33, 16))}
+    for i, p in prompts.items():
+        eng.submit(i, p, max_new_tokens=4)
+    _argmax_served(params, eng.run(), prompts)
+    counters = eng.registry.snapshot()["counters"]
+    assert counters["serving_expert_pair_extra_passes"] > 0
+    held = sum(counters[f"serving_expert_rows_{e}"]
+               for e in range(cfg.experts_held[1]))
+    assert held > counters["serving_routed_rows"] // 2
 
 
 def test_a_preempted_request_resumes_from_a_fresh_state(params):

@@ -133,8 +133,44 @@ def test_the_engine_serves_the_reference_argmax(params, engine_kw):
     routed = counters["serving_routed_rows"]
     assert routed % (CFG.top_k * CFG.n_expert_layers) == 0
     assert 0 < sum(held) < routed
+    assert counters["serving_expert_pair_extra_passes"] == 0
     status = eng.statusz()["kernels"]["decode"]
     assert "latent rows" in status["reason"]
+
+
+def test_a_chunk_routed_past_the_pair_buffer_makes_further_passes(
+        monkeypatch):
+    """4 held of 64 experts: the pair buffer of a 16-row chunk takes 8
+    of its 64 pairs.  With every absent expert's gate column zero (a
+    score of one half, under any held expert's that is positive) half
+    the pairs are held: the chunk programs make further passes, the
+    engine counts them, and the tokens are the reference's argmax."""
+    import dataclasses
+
+    cfg = dataclasses.replace(CFG, n_routed_experts=64)
+    monkeypatch.setattr(moe, "_every_row_pays", lambda N, k, Eh: N < 16)
+    monkeypatch.setattr(moe, "_GMM_TILING", (4, 128, 128))
+    params = pg.init_params(jax.random.PRNGKey(1), cfg)
+    params["blocks"]["gate"] = params["blocks"]["gate"].at[
+        ..., cfg.experts_held[1]:].set(0)
+    eng = serving_engine(params, cfg, max_batch=2, page_size=PAGE,
+                         num_pages=40, max_seq=96, cache_dtype=jnp.float32,
+                         telemetry=True, prefill_bucket=0, prefill_chunk=16)
+    assert eng.cache.expert_rows.shape == (cfg.experts_held[1] + 1,)
+    rng = np.random.default_rng(1)
+    prompts = {i: rng.integers(0, cfg.vocab_size, n).tolist()
+               for i, n in enumerate((33, 16))}
+    for i, p in prompts.items():
+        eng.submit(i, p, max_new_tokens=4)
+    out = eng.run()
+    for i, p in prompts.items():
+        want = _reference_logits(params, out[i], cfg).argmax(-1)
+        assert out[i][len(p):] == want[len(p) - 1:-1].tolist()
+    counters = eng.registry.snapshot()["counters"]
+    assert counters["serving_expert_pair_extra_passes"] > 0
+    held = sum(counters[f"serving_expert_rows_{e}"]
+               for e in range(cfg.experts_held[1]))
+    assert held > counters["serving_routed_rows"] // 4
 
 
 def test_statusz_names_the_latent_reader():
