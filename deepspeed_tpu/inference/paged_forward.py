@@ -72,14 +72,14 @@ def _count_routed(fam, cfg, rows, routed, N: int):
 
 
 def _forward_periods(fam, params, x, cfg, cache, block, *, whole: bool):
-    """The layers of a family with recurrent layers
-    (``DecoderFamily.recurrent``), whole periods at a time: an attention
-    layer is ``block`` (:func:`_paged_block` over the pool, whose
-    leading dimension counts the attention layers alone); a recurrent
-    layer reads its rows' state beside the pool, mixes, and writes it
-    back.  ``cache.real`` says how many tokens of each row may move a
-    state; a row that starts at position 0 starts from zero state,
-    whatever its slot held."""
+    """The layers of a family with recurrent layers, whole periods at a
+    time, a period's kinds in the order ``Recurrent.period`` states them
+    (attention may end a period or stand inside it): an attention layer is
+    ``block`` (:func:`_paged_block` over the pool, whose leading dimension
+    counts the attention layers alone); a recurrent layer reads its rows'
+    state beside the pool, mixes, and writes it back.  ``cache.real``: how
+    many tokens of each row may move a state; a row that starts at position
+    0 starts from zero state, whatever its slot held."""
     rec = fam.recurrent
     kinds = rec.period(cfg)
     n_rec = sum(kinds)
@@ -116,15 +116,15 @@ def _forward_periods(fam, params, x, cfg, cache, block, *, whole: bool):
                 first.reshape((B,) + (1,) * (a.ndim - 1)), 0, a)
                 for a in held)
         y, held = rec.mix(cfg, x, lp, held, real)
-        with jax.named_scope("kv_write"), jax.named_scope("gdn_write"):
+        with jax.named_scope("kv_write"), jax.named_scope(rec.write_scope):
             conv, state = write_state_rows(conv, state, layer, slot, held)
-        x, routed = rec.out(cfg, x, y, lp)
-        return (x, _count_routed(fam, cfg, rows, routed, B * T), conv,
-                state), None
+        x = rec.out(cfg, x, y, lp)
+        if fam.expert_rows(cfg)[0]:     # else a dense FFN: nothing to count
+            x, rows = x[0], _count_routed(fam, cfg, rows, x[1], B * T)
+        return (x, rows, conv, state), None
 
     # consecutive layers of one kind: [(recurrent?, how many), ...]
-    runs = [(kind, len(list(same))) for kind, same in
-            itertools.groupby(kinds)]
+    runs = [(kind, len(list(g))) for kind, g in itertools.groupby(kinds)]
 
     def period(x, att, p, kp, vp, rows, conv, state):
         i_rec = i_att = 0

@@ -1,7 +1,8 @@
 """What a decoder family is, stated once.
 
 A family's file (``gpt2.py``, ``llama.py``, ``mixtral.py``,
-``pangu_ultra_moe.py``, ``qwen3_next.py``) ends with its
+``pangu_ultra_moe.py``, ``qwen3_next.py``, ``granite_hybrid.py``) ends
+with its
 ``FAMILY = DecoderFamily(...)``: the pieces of one transformer layer and
 the facts a serving build needs.  Everything that serves, streams, drafts
 or generates (the ``inference`` package) reads the record through
@@ -68,8 +69,10 @@ class Recurrent:
 
     ``period(cfg)``: one bool a layer of a period, True where the layer
     mixes tokens through a recurrence over a per-slot state and False
-    where it attends over the page pool (``qkv`` / ``out``); the model is
-    whole periods.  ``key``: the params' stack of the recurrent layers
+    where it attends over the page pool (``qkv`` / ``out``), in whatever
+    order the model has them (an attention layer may end a period or
+    stand inside it); the model is whole periods.  ``key``: the params'
+    stack of the recurrent layers
     ``[periods * recurrent layers a period, ...]``; ``blocks`` holds the
     attention layers alone.  ``mix(cfg, x, lp, state, valid) -> (y,
     state)``: ``x`` [B, T, d] the residual stream, ``state`` the rows'
@@ -77,14 +80,19 @@ class Recurrent:
     many of each row's T tokens are real (the rest is padding, or the
     whole row a slot that is idle or between two chunks of its prompt):
     the state moves on real tokens only.  ``out(cfg, x, y, lp)``: the
-    residual and the FFN half, as ``DecoderFamily.out``.  ``state_row``:
-    what a slot keeps."""
+    residual and the FFN half, as ``DecoderFamily.out`` (``(x, rows)``
+    where the family counts its experts' rows, ``x`` where it has none).
+    ``state_row``: what a slot keeps.  ``write_scope``: the
+    ``jax.named_scope`` word, inside ``kv_write``, of the write-back of
+    a layer's state into the carried buffers (a capture's readers sum a
+    family's words by their prefix)."""
 
     key: str
     period: Callable[[Any], Tuple[bool, ...]]
     mix: Callable[..., Tuple[Any, Any]]
     out: Callable[..., Any]
     state_row: Callable[[Any], StateRow]
+    write_scope: str
 
 
 def _per_head_rows(cfg) -> CacheRow:
@@ -191,7 +199,7 @@ def positions_from(start, T: int):
 
 # the registry: one module name a family
 _FAMILY_MODULES = ("gpt2", "llama", "mixtral", "pangu_ultra_moe",
-                   "qwen3_next")
+                   "qwen3_next", "granite_hybrid")
 
 
 def decoder_families() -> Tuple[DecoderFamily, ...]:

@@ -474,7 +474,8 @@ FAMILY = DecoderFamily(
     router=lambda cfg: (cfg.n_routed_experts, cfg.top_k),
     whole_stacks=("w1", "w3", "w2"),
     recurrent=Recurrent(key="gdn_blocks", period=_period, mix=gdn_mix,
-                        out=_gdn_out, state_row=_state_row),
+                        out=_gdn_out, state_row=_state_row,
+                        write_scope="gdn_write"),
     refuses=(
         ("prefix_cache", _STATE + "a shared prefix's pages say nothing of "
          "the state at its end, and no snapshot of it is kept"),
