@@ -16,6 +16,13 @@ the sequence length are masked (their DMA reads page 0 — cheap and safe).
 
 The jnp reference path (`paged_attention_reference`) materialises the
 gather and is the numerics oracle for tests/CPU.
+
+The Mosaic kernels here, by the names a capture shows: `dstpu_paged_decode`
+(live pages only, one decode step), `dstpu_paged_chunk_v1` / `_v2` /
+`_v2_q8` (a chunk over history), `dstpu_mla_decode` (latent rows) and
+`dstpu_state_step`: one token of a recurrent layer's rule on the per-slot
+state carried beside the pool, a tile at a time, in place
+(:func:`state_step`).
 """
 
 from __future__ import annotations
@@ -1588,11 +1595,15 @@ class ServingKernelPolicy(NamedTuple):
     # (reader, reason): what the decode program's attention runs
     # (:func:`paged_reader` at this build's layout)
     decode: Tuple[str, str] = ("xla", "")
+    # pallas | xla: how the decode program steps a recurrent layer's
+    # per-slot state (:func:`state_stepper`; never configured)
+    state_step: str = "xla"
 
     def as_dict(self) -> dict:
         return {
             "paged_attention": self.paged_attention,
             "decode": {"reader": self.decode[0], "reason": self.decode[1]},
+            "state_step": self.state_step,
             "fused_sampling": self.fused_sampling,
             "env_overrides": [list(o) for o in self.env_overrides],
             "fallbacks": [{"field": f, "demoted_to": d, "reason": r}
@@ -1620,8 +1631,8 @@ _QUANT_RESIDENT_PALLAS_REFUSAL = (
 
 def resolve_serving_kernels(kernels=None, *, tp: bool = False,
                             interpret: bool = False,
-                            quantized_resident: bool = False
-                            ) -> ServingKernelPolicy:
+                            quantized_resident: bool = False,
+                            recurrent: bool = False) -> ServingKernelPolicy:
     """Resolve the serving kernel-dispatch policy ONCE, at engine build.
 
     ``kernels``: a ``KernelsConfig`` / dict / None (all-auto).  Env vars
@@ -1647,6 +1658,12 @@ def resolve_serving_kernels(kernels=None, *, tp: bool = False,
     ``pallas_v2`` raises :class:`ServingKernelRefused` here at build,
     and ``auto`` resolves to ``xla`` with a ``fallbacks`` row — the
     forward never gets to pick a kernel that cannot compile.
+
+    ``recurrent``: the family has recurrent layers, whose per-slot state
+    a decode program steps in place through ``dstpu_state_step`` on one
+    device (:func:`state_stepper`).  That is resolved here from those
+    two facts and never configured; where ``tp`` demotes it the
+    ``state_step`` row reads ``xla`` beside a ``fallbacks`` row.
 
     An already-resolved :class:`ServingKernelPolicy` passes through
     untouched — ``serving_engine`` resolves once and hands the SAME
@@ -1718,11 +1735,15 @@ def resolve_serving_kernels(kernels=None, *, tp: bool = False,
         from deepspeed_tpu.ops.sampling_pallas import pallas_sample_gate
 
         fused = "on" if pallas_sample_gate(interpret=interpret) else "off"
+    stepper, why = state_stepper(decode=recurrent, tp=tp)
+    if recurrent and stepper != "pallas":
+        fallbacks.append(("state_step=pallas", stepper, why))
     return ServingKernelPolicy(
         paged_attention=paged, fused_sampling=fused,
         env_overrides=tuple(env_overrides), fallbacks=tuple(fallbacks),
         decode=paged_reader(paged, decode=True, tp=tp, interpret=interpret,
-                            quant=quantized_resident))
+                            quant=quantized_resident),
+        state_step=stepper)
 
 
 def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
@@ -1868,25 +1889,208 @@ def paged_layer_loop(block, x, blocks, cache: PagedKVCache,
                              expert_rows=rows)
 
 
-def state_rows(conv, state, layer, slot):
-    """Recurrent layer ``layer``'s (conv, state) of the rows a forward
-    runs: every slot's (``slot`` None), or the one slot's that a
-    one-row view stands for."""
+def state_rows(carried, layer, slot):
+    """Recurrent layer ``layer``'s rows of each of the ``carried``
+    buffers [layers, slots, ...] (the convolution's and the state's, or
+    the former alone where the state is stepped where it lies,
+    :func:`state_step`) that a forward runs: every slot's (``slot``
+    None), or the one slot's that a one-row view stands for."""
     if slot is None:
         return tuple(jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
-                     for a in (conv, state))
+                     for a in carried)
     at = lambda a: (layer, slot[0]) + (0,) * (a.ndim - 2)
     return tuple(jax.lax.dynamic_slice(a, at(a), (1, 1) + a.shape[2:])[0]
-                 for a in (conv, state))
+                 for a in carried)
 
 
-def write_state_rows(conv, state, layer, slot, new):
-    """The rows' new (conv, state) into the carried buffers, in place."""
+def write_state_rows(carried, layer, slot, new):
+    """The rows' ``new`` values into the ``carried`` buffers, in place."""
     at = lambda a: (layer, 0 if slot is None else slot[0]) \
         + (0,) * (a.ndim - 2)
     return tuple(jax.lax.dynamic_update_slice(a, n[None].astype(a.dtype),
                                               at(a))
-                 for a, n in zip((conv, state), new))
+                 for a, n in zip(carried, new))
+
+
+def state_stepper(*, decode: bool, tp: bool) -> Tuple[str, str]:
+    """How a recurrent layer's state takes a token, and why: ("pallas" |
+    "xla", reason), the one answer ``forward_paged`` and the engine's
+    ``/statusz`` share.  A decode step over every slot (``decode``: one
+    token a row, the rows the slots) on one device steps the carried
+    state where it lies (:func:`state_step`, in interpret mode off the
+    TPU).  A prompt chunk's one-slot view runs the family's chunked rule
+    on the slot's rows; under a mesh (``tp``) the kernel, which is one
+    device's, cannot be partitioned."""
+    for off, why in ((not decode, "no decode step over every slot's state"),
+                     (tp, "tp: the kernel is one device's")):
+        if off:
+            return "xla", why
+    return "pallas", "decode over every slot on one device"
+
+
+# --------------------------------------------- the per-slot state's step
+# A tile of the carried state, and how many of them the kernel keeps in
+# the fast memory: while one is stepped, the next ``_STATE_TILES_AHEAD``
+# are on their way in and the last ones on their way out (8 MiB of the
+# 16 MiB a v5e kernel may use without asking).
+_STATE_TILE_BYTES = 2 << 20
+_STATE_TILES, _STATE_TILES_AHEAD = 4, 2
+
+
+def _state_tile(slots: int, heads: int, head_bytes: int,
+                tile_bytes: int) -> Tuple[int, int]:
+    """(slots, heads) of a tile of at most ``tile_bytes`` that divides
+    the layer: whole slots where a slot's heads fit, else some of one
+    slot's heads."""
+    most = lambda n, fit: max(d for d in range(1, n + 1)
+                              if n % d == 0 and d <= max(1, fit))
+    fit = tile_bytes // head_bytes
+    if fit >= heads:
+        return most(slots, fit // heads), heads
+    return 1, most(heads, fit)
+
+
+def _state_step_kernel(layer_ref, *refs, rule, kinds, o_kind, tile, ahead):
+    """Layer ``layer_ref[0]`` of the state, which stays where it is
+    (``s_hbm`` and ``out_hbm`` are one buffer), a tile [slots, heads, R,
+    C] at a time through ``buf``: tile t is stepped in its buffer while
+    tiles up to t + ``ahead`` are read and the ones before it written
+    back.  ``rule`` runs on one head's [R, C] at a time, its vectors
+    beside it as [1, C] (``row``), [R, 1] (``col``) or a scalar (``one``,
+    in the scalar memory).  A vector that runs along R arrives as a row
+    of its array, R on the lanes, and is turned on the spot: the
+    diagonal of its [R, R] broadcast, summed over the lanes (one number
+    and zeros: exact); a result along R goes back the same way, summed
+    over the sublanes."""
+    n = kinds.count("one")
+    s_hbm, *tiles, o_ref, out_hbm, buf, r_sem, w_sem = refs[n:]
+    ones, tiles = iter(refs[:n]), iter(tiles)
+    v_refs = [next(ones if k == "one" else tiles) for k in kinds]
+    _, B, H, R, C = s_hbm.shape
+    (bb, hb), layer, held = tile, layer_ref[0], buf.shape[0]
+    n_tiles = (B // bb) * (H // hb)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (R, R), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (R, R), 1))
+
+    def first(t):
+        """Tile t's first slot and head."""
+        return t // (H // hb) * bb, t % (H // hb) * hb
+
+    def copy(t, out: bool):
+        b0, h0 = first(t)
+        hbm = (out_hbm if out else s_hbm).at[layer, pl.ds(b0, bb),
+                                             pl.ds(h0, hb)]
+        at, sem = buf.at[t % held], (w_sem if out else r_sem).at[t % held]
+        return pltpu.make_async_copy(*((at, hbm) if out else (hbm, at)), sem)
+
+    def vector(ref, kind, b, h):
+        if kind == "one":
+            return ref[b * H + h]
+        v = ref[pl.ds(b, 1), :] if ref.ndim == 2 else ref[b, pl.ds(h, 1), :]
+        return (jnp.sum(jnp.where(eye, v, 0.0), axis=1, keepdims=True)
+                if kind == "col" else v)
+
+    def step(t, _):
+        copy(t, False).wait()
+
+        @pl.when(t + ahead < n_tiles)
+        def _():
+            @pl.when(t + ahead >= held)     # the buffer's last tile is out
+            def _():
+                copy(t + ahead - held, True).wait()
+
+            copy(t + ahead, False).start()
+
+        (b0, h0), cur = first(t), buf.at[t % held]
+
+        def head(i, _):
+            b, h = i // hb, i % hb
+            # float32 whatever the state is kept in; rounded on its way out
+            o, S = rule(cur[b, h].astype(jnp.float32),
+                        *(vector(ref, kind, b0 + b, h0 + h)
+                          for ref, kind in zip(v_refs, kinds)))
+            if o_kind == "col":
+                o = jnp.sum(jnp.where(eye, o, 0.0), axis=0, keepdims=True)
+            o_ref[b0 + b, pl.ds(h0 + h, 1), :] = o
+            cur[b, h] = S.astype(cur.dtype)
+
+        jax.lax.fori_loop(0, bb * hb, head, None)
+        copy(t, True).start()
+
+    for t in range(min(ahead, n_tiles)):
+        copy(t, False).start()
+    jax.lax.fori_loop(0, n_tiles, step, None)
+    for t in range(max(0, n_tiles - held), n_tiles):
+        copy(t, True).wait()
+
+
+def state_step(rule, state, layer, vectors, *, interpret: bool = False,
+               tile_bytes: Optional[int] = None):
+    """One token of a recurrence on layer ``layer`` of the carried state
+    ``state`` [layers, slots, H, R, C] (``STATE_DTYPE``), in place: the
+    Mosaic kernel ``dstpu_state_step`` reads a tile of it, applies
+    ``rule`` and writes the tile back, so a step moves the layer's state
+    out of the memory once and into it once, and no layer of it is ever a
+    value of the program's.
+
+    ``rule(S, *vectors) -> (o, S)`` is the family's own statement of the
+    step, written over the last two dimensions: ``S`` [..., R, C] and
+    each vector [..., 1, C], [..., R, 1] or [..., 1, 1] (what it
+    multiplies or adds to S by broadcasting; the kernel hands it the
+    last as a scalar), ``o`` [..., 1, C] or [..., R, 1].  ``vectors``:
+    every slot's, [slots, H or 1, R or 1, C or 1] float32 (1 heads:
+    shared by the heads; one number a head is always [slots, H, 1, 1]);
+    they and ``o`` are whole in the kernel's memory.  Returns (o [slots,
+    H, 1, C] or [slots, H, R, 1], the buffer).  The tile is read from
+    the shapes (:func:`_state_tile`); ``tile_bytes`` is a measurement's
+    and a test's."""
+    L, B, H, R, C = state.shape
+    kind_of = lambda shape: {(1, C): "row", (R, 1): "col",
+                             (1, 1): "one"}[tuple(shape)]
+    kinds = tuple(kind_of(v.shape[2:]) for v in vectors)
+    o_shape = jax.eval_shape(
+        rule, jax.ShapeDtypeStruct((R, C), jnp.float32),
+        *(jax.ShapeDtypeStruct(() if k == "one" else v.shape[2:], v.dtype)
+          for v, k in zip(vectors, kinds)))[0].shape
+    o_kind = kind_of(o_shape)
+    tile = _state_tile(B, H, R * C * state.dtype.itemsize,
+                       tile_bytes or _STATE_TILE_BYTES)
+    # a vector's unit dimension is dropped in the memory, where a [.., R,
+    # 1] array is stored a 128-lane tile a number (one shared by the heads
+    # is [slots, width]: as [slots, 1, width] its layout, a tile a row,
+    # went back through the convolution to the carried buffer of its
+    # rows, which the program then re-laid on its way in and out, v5e)
+    ones = [v.reshape(-1) for v, k in zip(vectors, kinds) if k == "one"]
+    rows = [v.reshape((B,) + ((H,) if v.shape[1] > 1 else ()) + (-1,))
+            for v, k in zip(vectors, kinds) if k != "one"]
+    o = jax.ShapeDtypeStruct((B, H, R if o_kind == "col" else C),
+                             jnp.float32)
+    held = min(_STATE_TILES, (B // tile[0]) * (H // tile[1]))
+    buf = jax.ShapeDtypeStruct((held,) + tile + (R, C), state.dtype)
+    in_vmem = sum(a.size * a.dtype.itemsize for a in rows + [o, buf])
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    o, state = pl.pallas_call(
+        functools.partial(_state_step_kernel, rule=rule, kinds=kinds,
+                          o_kind=o_kind, tile=tile,
+                          ahead=max(1, min(_STATE_TILES_AHEAD, held - 1))),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1 + len(ones),   # layer, the scalars
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + [whole] * len(rows),
+            out_specs=[whole, pl.BlockSpec(memory_space=pl.ANY)],
+            scratch_shapes=[pltpu.VMEM(buf.shape, buf.dtype),
+                            pltpu.SemaphoreType.DMA((held,)),
+                            pltpu.SemaphoreType.DMA((held,))],
+        ),
+        out_shape=[o, jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        # the state comes back in its own buffer
+        input_output_aliases={1 + len(ones): 1},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(16 << 20, in_vmem + (4 << 20))),
+        interpret=interpret,
+        name="dstpu_state_step",
+    )(_layer_operand(layer), *ones, state, *rows)
+    return o.reshape((B, H) + o_shape), state
 
 
 def paged_period_loop(period, x, stacks, cache: PagedKVCache, periods: int):

@@ -12,6 +12,7 @@ generators, the drafter and the hybrid engine.
 
 from __future__ import annotations
 
+import functools
 import itertools
 from typing import Optional
 
@@ -22,9 +23,10 @@ from deepspeed_tpu.inference.kernels import (latent_attention_step,
                                              paged_attention_step,
                                              paged_layer_loop,
                                              paged_period_loop, paged_reader,
-                                             state_rows, write_state_rows)
+                                             state_rows, state_step,
+                                             state_stepper, write_state_rows)
 from deepspeed_tpu.inference.quantized import dequantize_params
-from deepspeed_tpu.models.family import decoder_family
+from deepspeed_tpu.models.family import CarriedState, decoder_family
 from deepspeed_tpu.parallel.moe import extra_pair_passes
 
 
@@ -71,15 +73,20 @@ def _count_routed(fam, cfg, rows, routed, N: int):
         routed, extra_pair_passes(routed, N, top_k, scored))
 
 
-def _forward_periods(fam, params, x, cfg, cache, block, *, whole: bool):
+def _forward_periods(fam, params, x, cfg, cache, block, *, whole: bool,
+                     tp: bool, interpret: bool):
     """The layers of a family with recurrent layers, whole periods at a
     time, a period's kinds in the order ``Recurrent.period`` states them
     (attention may end a period or stand inside it): an attention layer is
     ``block`` (:func:`_paged_block` over the pool, whose leading dimension
     counts the attention layers alone); a recurrent layer reads its rows'
-    state beside the pool, mixes, and writes it back.  ``cache.real``: how
-    many tokens of each row may move a state; a row that starts at position
-    0 starts from zero state, whatever its slot held."""
+    state beside the pool, mixes, and writes it back, but in a decode step
+    over every slot on one device (:func:`~deepspeed_tpu.inference.
+    kernels.state_stepper`), where ``mix`` steps its layer of the carried
+    state where it lies (``family.CarriedState``) and only the
+    convolution's rows go out and back.  ``cache.real``: how many tokens
+    of each row may move a state; a row that starts at position 0 starts
+    from zero state, whatever its slot held."""
     rec = fam.recurrent
     kinds = rec.period(cfg)
     n_rec = sum(kinds)
@@ -89,6 +96,9 @@ def _forward_periods(fam, params, x, cfg, cache, block, *, whole: bool):
     real = (jnp.full((B,), T, jnp.int32) if cache.real is None
             else cache.real)
     periods = cache.k.shape[0] // n_att
+    in_place = state_stepper(decode=T == 1 and slot is None,
+                             tp=tp)[0] == "pallas"
+    step = functools.partial(state_step, interpret=interpret)
 
     def split(stack):
         held = {k: stack[k] for k in fam.whole_stacks} if whole else {}
@@ -109,15 +119,19 @@ def _forward_periods(fam, params, x, cfg, cache, block, *, whole: bool):
               for k, v in rec_stack.items()}
         if rec_whole:
             lp = dict(lp, **rec_whole, layer=layer)
-        held = state_rows(conv, state, layer, slot)
+        carried = (conv,) if in_place else (conv, state)
+        held = state_rows(carried, layer, slot)
         if T > 1:
             first = start == 0
             held = tuple(jnp.where(
                 first.reshape((B,) + (1,) * (a.ndim - 1)), 0, a)
                 for a in held)
+        if in_place:
+            held += (CarriedState(state, layer, step),)
         y, held = rec.mix(cfg, x, lp, held, real)
         with jax.named_scope("kv_write"), jax.named_scope(rec.write_scope):
-            conv, state = write_state_rows(conv, state, layer, slot, held)
+            carried = write_state_rows(carried, layer, slot, held)
+        conv, state = (carried[0], held[1].buffer) if in_place else carried
         x = rec.out(cfg, x, y, lp)
         if fam.expert_rows(cfg)[0]:     # else a dense FFN: nothing to count
             x, rows = x[0], _count_routed(fam, cfg, rows, x[1], B * T)
@@ -249,7 +263,7 @@ def forward_paged(params, tokens, cfg, cache, *,
     if fam.recurrent is not None:
         x, cache = _forward_periods(
             fam, params, x, cfg, cache, block(fam.out),
-            whole=resident and not tp)
+            whole=resident and not tp, tp=tp, interpret=interpret)
         return fam.head(params, x, cfg), cache
     n_lead = 0
     if fam.lead is not None:

@@ -3807,7 +3807,8 @@ def serving_engine(params, cfg, **kw):
         tp=mesh is not None and any(
             mesh.size(ax) > 1 for ax in ("model", "expert")),
         interpret=jax.default_backend() != "tpu",
-        quantized_resident=kvt.enabled and kvt.quantized_resident)
+        quantized_resident=kvt.enabled and kvt.quantized_resident,
+        recurrent=fam.recurrent is not None)
     pk = kw["kernels"].paged_attention
     if fam.latent is not None:
         kw["kernels"] = kw["kernels"]._replace(

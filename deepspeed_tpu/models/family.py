@@ -84,8 +84,16 @@ class Recurrent:
     where the family counts its experts' rows, ``x`` where it has none).
     ``state_row``: what a slot keeps.  ``write_scope``: the
     ``jax.named_scope`` word, inside ``kv_write``, of the write-back of
-    a layer's state into the carried buffers (a capture's readers sum a
-    family's words by their prefix)."""
+    a layer's rows into the carried buffers (a capture's readers sum a
+    family's words by their prefix).
+
+    Where the program is a decode step over every slot on one device,
+    ``mix`` is handed as ``state[1]`` not the rows' state but a
+    :class:`CarriedState`: the whole buffer of every recurrent layer and
+    which layer this is.  A family steps either through
+    :func:`step_state` with its one-token rule, and hands back what that
+    returned; it never reads a ``CarriedState`` as an array (a prompt
+    chunk's ``mix`` is never handed one)."""
 
     key: str
     period: Callable[[Any], Tuple[bool, ...]]
@@ -93,6 +101,34 @@ class Recurrent:
     out: Callable[..., Any]
     state_row: Callable[[Any], StateRow]
     write_scope: str
+
+
+class CarriedState(NamedTuple):
+    """A recurrent layer's state where it lives: ``buffer`` [layers,
+    slots, *state] (every recurrent layer's, the serving programs' carry),
+    ``layer`` (a traced index) and ``step(rule, buffer, layer, vectors)
+    -> (o, buffer)``, which applies a one-token rule to that layer of
+    the buffer in place (:func:`deepspeed_tpu.inference.kernels.
+    state_step`)."""
+
+    buffer: Any
+    layer: Any
+    step: Callable[..., Tuple[Any, Any]]
+
+
+def step_state(rule, S, *vectors):
+    """One token of a recurrence: ``rule(S, *vectors) -> (o, S)``, the
+    family's statement of it over the last two dimensions of ``S`` [...,
+    R, C], each vector [..., 1, C], [..., R, 1] or [..., 1, 1] (a scalar
+    where the rule is applied to one head's [R, C]) and ``o`` [..., 1,
+    C] or [..., R, 1].  ``S`` is the rows' state [B, H, R, C], and the
+    rule is applied to it as it stands, or a :class:`CarriedState`,
+    whose layer is stepped where it lies; the second result is of the
+    kind ``S`` was."""
+    if isinstance(S, CarriedState):
+        o, buffer = S.step(rule, S.buffer, S.layer, vectors)
+        return o, S._replace(buffer=buffer)
+    return rule(S.astype(jnp.float32), *vectors)
 
 
 def _per_head_rows(cfg) -> CacheRow:

@@ -49,7 +49,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models.family import (CacheRow, DecoderFamily, Recurrent,
-                                         StateRow)
+                                         StateRow, step_state)
 
 _PUBLISHED_PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 
@@ -261,20 +261,29 @@ def _residual(cfg, x, y):
 _mm = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
 
 
+def ssm_rule(S, dtx, decay, Bm, Cm):
+    """One token of the recurrence over the last two dimensions: S [...,
+    P, N], dtx [..., P, 1], decay [..., 1, 1], Bm, Cm [..., 1, N], f32 ->
+    (o [..., P, 1] without the ``D x`` skip, S).  Two passes over S, the
+    reduction and the update, not products: ``o = S_new C = e^(dt A) (S
+    C) + (B . C) dt x``."""
+    SC = jnp.sum(S * Cm, axis=-1, keepdims=True)
+    BC = jnp.sum(Bm * Cm, axis=-1, keepdims=True)
+    return decay * SC + BC * dtx, decay * S + dtx * Bm
+
+
 def ssm_step(x, dt, A, Bm, Cm, S):
     """One token of the recurrence, every row and head at once: x [B, H,
-    P], dt [B, H], A [H], Bm, Cm [B, N], S [B, H, P, N], all f32 -> (o
-    [B, H, P] without the ``D x`` skip, S).  Written as two passes over S
-    that XLA fuses (the reduction reads it, the update reads and writes
-    it), not as products: ``o = S_new C = e^(dt A) (S C) + (B . C) dt
-    x``.  A row with ``dt = 0`` leaves its state as it was, bit for
-    bit."""
-    decay = jnp.exp(dt * A)[..., None]                       # [B, H, 1]
-    dtx = dt[..., None] * x                                  # [B, H, P]
-    SC = jnp.sum(S * Cm[:, None, None, :], axis=-1)          # [B, H, P]
-    BC = jnp.sum(Bm * Cm, -1)[:, None, None]
-    S = decay[..., None] * S + dtx[..., None] * Bm[:, None, None, :]
-    return decay * SC + BC * dtx, S
+    P], dt [B, H], A [H], Bm, Cm [B, N], all f32, S [B, H, P, N] or the
+    carried buffer it is a layer of (``family.step_state``) -> (o [B, H,
+    P] without the ``D x`` skip, S as it came): :func:`ssm_rule` on
+    ``dt x`` and ``e^(dt A)``.  A row with ``dt = 0`` leaves its state as
+    it was, bit for bit."""
+    decay = jnp.exp(dt * A)[..., None, None]                 # [B, H, 1, 1]
+    dtx = (dt[..., None] * x)[..., None]                     # [B, H, P, 1]
+    o, S = step_state(ssm_rule, S, dtx, decay, Bm[:, None, None, :],
+                      Cm[:, None, None, :])
+    return o[..., 0], S
 
 
 def ssm_chunk_scan(x, dt, A, Bm, Cm, S, block: int):
@@ -333,7 +342,7 @@ def ssm_mix(cfg, x, lp, state, valid):
     H, Pd, N, taps = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
                       cfg.conv_kernel)
     inner, f32 = cfg.ssm_inner, jnp.float32
-    conv, S = state[0], state[1].astype(f32)    # f32 whatever it is kept in
+    conv, S = state
     # the benchmark's vocabulary has attention's words; ours nest in them
     with jax.named_scope("attn_qkv"), jax.named_scope("ssm_proj"):
         a = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
@@ -360,7 +369,9 @@ def ssm_mix(cfg, x, lp, state, valid):
             o = o[:, None]
     else:
         with jax.named_scope("kv_attend"), jax.named_scope("ssm_scan"):
-            o, S = ssm_chunk_scan(xs, dt, A, Bm, Cm, S, cfg.ssm_block)
+            # f32 whatever the state is kept in
+            o, S = ssm_chunk_scan(xs, dt, A, Bm, Cm, S.astype(f32),
+                                  cfg.ssm_block)
     with jax.named_scope("attn_out"), jax.named_scope("ssm_gate_norm"):
         o = o + lp["D"].astype(f32)[:, None] * xs
         o = _gated_norm(o.reshape(B, T, inner), z, lp["ssm_norm"],

@@ -53,7 +53,7 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models import llama as _llama
 from deepspeed_tpu.models.family import (DecoderFamily, Recurrent, StateRow,
-                                         positions_from)
+                                         positions_from, step_state)
 from deepspeed_tpu.parallel.moe import held_experts_ffn, softmax_topk_route
 
 
@@ -248,19 +248,28 @@ def _l2norm(x):
 _mm = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
 
 
+def gdn_rule(S, q, k, v, decay, beta):
+    """One token of the recurrence over the last two dimensions: S [...,
+    Dk, Dv], q, k [..., Dk, 1], v [..., 1, Dv], decay, beta [..., 1, 1],
+    f32 -> (o [..., 1, Dv], S).  Two passes over S, the two reductions
+    and the update, not products: ``o = S_new^T q = e^g S^T q + (k . q)
+    u``."""
+    Sk = jnp.sum(S * k, axis=-2, keepdims=True) * decay      # S'^T k
+    Sq = jnp.sum(S * q, axis=-2, keepdims=True) * decay
+    u = beta * (v - Sk)
+    return Sq + jnp.sum(k * q, -2, keepdims=True) * u, decay * S + k * u
+
+
 def gdn_step(q, k, v, g, beta, S):
     """One token of the recurrence, every row and head at once: q, k
-    [B, H, Dk], v [B, H, Dv], g, beta [B, H], S [B, H, Dk, Dv], all f32
-    -> (o [B, H, Dv], S).  Written as two passes over S that XLA fuses
-    (the two reductions read it, the update reads and writes it), not as
-    products: ``o = S_new^T q = e^g S^T q + (k . q) u``.  A row with
+    [B, H, Dk], v [B, H, Dv], g, beta [B, H], all f32, S [B, H, Dk, Dv]
+    or the carried buffer it is a layer of (``family.step_state``) -> (o
+    [B, H, Dv], S as it came): :func:`gdn_rule` on ``e^g``.  A row with
     ``beta = g = 0`` leaves its state as it was, bit for bit."""
-    decay = jnp.exp(g)[..., None]                            # [B, H, 1]
-    Sk = jnp.sum(S * k[..., None], axis=2) * decay           # S'^T k
-    Sq = jnp.sum(S * q[..., None], axis=2) * decay
-    u = beta[..., None] * (v - Sk)
-    S = decay[..., None] * S + k[..., None] * u[..., None, :]
-    return Sq + jnp.sum(k * q, -1, keepdims=True) * u, S
+    o, S = step_state(gdn_rule, S, q[..., None], k[..., None],
+                      v[..., None, :], jnp.exp(g)[..., None, None],
+                      beta[..., None, None])
+    return o[..., 0, :], S
 
 
 def gdn_chunk_rule(q, k, v, g, beta, S, block: int):
@@ -327,7 +336,7 @@ def gdn_mix(cfg, x, lp, state, valid):
                       cfg.lin_v_dim)
     Kd, taps = Hk * Dk, cfg.conv_kernel
     f32 = jnp.float32
-    conv, S = state[0], state[1].astype(f32)    # f32 whatever it is kept in
+    conv, S = state
     # the benchmark's vocabulary has attention's words; ours nest in them
     with jax.named_scope("attn_qkv"), jax.named_scope("gdn_proj"):
         a = norm1p(x, lp["attn_norm"], cfg.norm_eps)
@@ -359,7 +368,9 @@ def gdn_mix(cfg, x, lp, state, valid):
             o = o[:, None]
     else:
         with jax.named_scope("kv_attend"), jax.named_scope("gdn_scan"):
-            o, S = gdn_chunk_rule(q, k, v, g, beta, S, cfg.gdn_block)
+            # f32 whatever the state is kept in
+            o, S = gdn_chunk_rule(q, k, v, g, beta, S.astype(f32),
+                                  cfg.gdn_block)
     with jax.named_scope("attn_out"), jax.named_scope("gdn_gate_norm"):
         o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                               + cfg.norm_eps) * lp["gdn_norm"].astype(f32)

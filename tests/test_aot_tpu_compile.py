@@ -497,6 +497,40 @@ def test_latent_cell_programs_fit_and_leave_the_pool_in_place(
 
 
 # ------------------------------------ the recurrent family's cell (PR 35)
+# (layers, slots, heads, R, C) of the carried state and the rule's
+# vectors' last two dimensions, at the two cells' sizes
+STATE_STEPS = {
+    "mamba2": ((36, 96, 64, 64, 128),
+               [(64, 64, 1), (64, 1, 1), (1, 1, 128), (1, 1, 128)]),
+    "delta_rule": ((9, 96, 32, 128, 128),
+                   [(32, 128, 1), (32, 128, 1), (32, 1, 128), (32, 1, 1),
+                    (32, 1, 1)]),
+}
+
+
+@pytest.mark.parametrize("family", STATE_STEPS)
+def test_state_step_kernel(chip, family):
+    """``dstpu_state_step`` alone with each family's own rule, at its
+    cell's sizes: a whole slot's state a tile (2 MiB), the buffer's
+    result aliased to its operand and nothing of its size beside it."""
+    from deepspeed_tpu.models import granite_hybrid, qwen3_next
+
+    rule = {"mamba2": granite_hybrid.ssm_rule,
+            "delta_rule": qwen3_next.gdn_rule}[family]
+    state, vectors = STATE_STEPS[family]
+    assert K._state_tile(state[1], state[2], state[3] * state[4] * 4,
+                         K._STATE_TILE_BYTES) == (1, state[2])
+    f32 = jnp.float32
+    compiled = _compile(
+        lambda state, layer, *v: K.state_step(rule, state, layer, v), chip,
+        (state, f32), ((), jnp.int32),
+        *[((state[1],) + v, f32) for v in vectors])
+    hlo, memory = compiled.as_text(), compiled.memory_analysis()
+    assert re.search(r"%dstpu_state_step[\w.]* = .*tpu_custom_call", hlo)
+    assert "output_to_operand_aliasing={{1}: (" in hlo
+    assert memory.temp_size_in_bytes < 8 << 20
+
+
 # qwen3-next-80b-a3b-ep8-d12.serve.docqa-sat as the benchmark builds it:
 # three periods of three Gated DeltaNet layers and one gated attention
 # layer at the published widths, 64 of 512 experts held, an eighth of the
@@ -542,6 +576,52 @@ def _top_level_results(hlo, dims):
     return found
 
 
+def _state_stepped_in_place(hlo, state_shape, program):
+    """The whole state is only ever the carried buffer, and one layer of
+    it is never a value of its own.  A chunk program updates its slot's
+    rows in place (a dynamic-update-slice, or the fusion that ends in
+    one); a decode program hands the buffer to ``dstpu_state_step``,
+    whose result aliases it, and nothing else of the state's shape is
+    computed: no copy of it, no slice of a layer, no reduction fusion
+    that reads one."""
+    results = _top_level_results(hlo, state_shape)
+    if program == "decode":
+        assert results and all(
+            op == "custom-call" and name.startswith("dstpu_state_step")
+            for name, op, _ in results), [(n, o) for n, o, _ in results]
+        aliased = re.findall(
+            r"%(dstpu_state_step[\w.]*) = .*?custom-call\((.*?)\), "
+            r"custom_call_target=\"tpu_custom_call\".*?"
+            r"output_to_operand_aliasing=\{\{1\}: \((\d+), \{\}\)\}", hlo)
+        assert len(aliased) == len(results)
+        shaped = "f32[" + ",".join(map(str, state_shape)) + "]"
+        for _, operands, at in aliased:
+            # the aliased operand is the carried buffer itself
+            operand = operands.split(", ")[int(at)].split("*/")[-1]
+            assert re.search(
+                re.escape(operand) + r" = " + re.escape(shaped)
+                + r"\S* get-tuple-element\(", hlo), operand
+        # and no fusion takes the buffer (to slice a layer out and reduce
+        # it, as the parent's two a layer did): the entry's own parameter
+        # aside, it is only ever a loop's tuple element
+        assert re.findall(r"%(?!cache)[\w.\-]+ = " + re.escape(shaped)
+                          + r"\S* parameter\(", hlo) == []
+    else:
+        for name, op, body in results:
+            assert op == "dynamic-update-slice" or (
+                op == "fusion" and any(
+                    "ROOT" in l and " dynamic-update-slice(" in l
+                    for l in body)), (name, op)
+    assert _top_level_results(hlo, state_shape[1:]) == []
+    assert _top_level_results(hlo, (1,) + state_shape[1:]) == []
+    # and it is updated once a layer, never rematerialised: with the
+    # three linear layers of a period unrolled in one loop body the
+    # compiler recomputed a layer's in-place update from the buffer it
+    # had already overwritten, under a full chip's memory pressure only,
+    # and the state moved twice a step (v5e, PR 35)
+    assert "remat" not in " ".join(name for name, _, _ in results)
+
+
 @pytest.mark.parametrize("program", QWEN_PROGRAMS)
 def test_recurrent_cell_programs_fit_and_keep_pool_and_state_in_place(
         chip, monkeypatch, program):
@@ -550,10 +630,10 @@ def test_recurrent_cell_programs_fit_and_keep_pool_and_state_in_place(
     weights, a 6.0 GiB pool and 1.73 GiB of per-slot state beside their
     temporaries, inside 15.75 GiB); they hold no copy of the pool, whose
     leading dimension is the three attention layers, nor of the state or
-    of one layer of it: a decode step reads a layer's 96 states in the
-    fusions that reduce them and writes them in the fusion that updates
-    the carried buffer in place; a layer's experts are read in place;
-    the kernels run by name."""
+    of one layer of it: a decode step hands the carried buffer to
+    ``dstpu_state_step``, which reads and writes a layer's 96 states in
+    place, a tile at a time; a layer's experts are read in place; the
+    kernels run by name."""
     from deepspeed_tpu.models import qwen3_next as qn
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -597,20 +677,7 @@ def test_recurrent_cell_programs_fit_and_keep_pool_and_state_in_place(
         < 15.75 * 2 ** 30
     assert 13.1 * 2 ** 30 < memory.argument_size_in_bytes < 13.3 * 2 ** 30
     assert _pool_sized_ops(hlo, shape) == []
-    # the whole state is only ever the carried buffer, updated in place;
-    # one layer of it is never a value of its own
-    for name, op, body in _top_level_results(hlo, state_shape):
-        assert op == "dynamic-update-slice" or (op == "fusion" and any(
-            "ROOT" in l and " dynamic-update-slice(" in l for l in body)), \
-            (name, op)
-    assert _top_level_results(hlo, state_shape[1:]) == []
-    # and it is updated once a layer, never rematerialised: with the
-    # three linear layers of a period unrolled in one loop body the
-    # compiler recomputed a layer's in-place update from the buffer it
-    # had already overwritten, under this cell's memory pressure only,
-    # and the state moved twice a step (v5e, PR 35)
-    assert "remat" not in " ".join(
-        name for name, _, _ in _top_level_results(hlo, state_shape))
+    _state_stepped_in_place(hlo, state_shape, program)
     if program != "decode":
         # a chunk's grouped product reads a layer's 64 experts in the
         # stack; a decode step (96 rows: every held expert on every row)
@@ -642,11 +709,15 @@ def test_recurrent_cell_programs_fit_and_keep_pool_and_state_in_place(
 # of state beside its pages, over 6,145 pages of 16 in a pool of the FOUR
 # attention layers whose rows of 64 numbers take a 128-lane tile.
 _GRANITE_PAGES, _GRANITE_SLOTS, _GRANITE_TABLE = 6145, 96, 2048 // PAGE
-# program -> (rows, tokens, bound on its temporaries in GiB: AOT, PR 42,
-# reads 0.020 and 0.114; 1.24 and 5.98 (which does not fit) while the
+# program -> (rows, tokens, bound on its temporaries in GiB: AOT, PR 43,
+# reads 0.002 and 0.114 (PR 42: 0.020 and 0.114; 0.114 for the decode
+# program while a vector shared by the heads reached the state's kernel
+# as [slots, 1, width]: that layout went back through the convolution to
+# the carried buffer of its rows, re-laid on its way in and out, 2.7 ms
+# a step by the compiler's count); 1.24 and 5.98 (which does not fit) while the
 # Mamba-2 input projection was one stack of 8,512 columns, which the chip
 # keeps rows-minor and each program re-laid whole, 1.17 GB a step)
-GRANITE_PROGRAMS = {"decode": (_GRANITE_SLOTS, 1, 0.04),
+GRANITE_PROGRAMS = {"decode": (_GRANITE_SLOTS, 1, 0.01),
                     "chunk_full_table": (1, 256, 0.16)}
 
 
@@ -660,8 +731,10 @@ def test_state_space_cell_programs_fit_and_keep_pool_and_state_in_place(
     itself); they hold no copy of the pool, whose leading dimension is
     the four attention layers, nor of the state or of one layer of it
     (a layer's 96 states are 192 MiB: a copy would show in the
-    temporaries), nor of a weight stack; the decode kernel runs by name
-    over rows of 128 lanes."""
+    temporaries; the decode step's are 2 MiB since ``dstpu_state_step``
+    steps the carried buffer in place), nor of the convolution's rows or
+    of a weight stack; the decode kernel runs by name over rows of 128
+    lanes."""
     from deepspeed_tpu.models import granite_hybrid as gh
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -707,20 +780,22 @@ def test_state_space_cell_programs_fit_and_keep_pool_and_state_in_place(
         < 15.0 * 2 ** 30
     assert 14.2 * 2 ** 30 < memory.argument_size_in_bytes < 14.4 * 2 ** 30
     assert _pool_sized_ops(hlo, shape) == []
-    # the whole state is only ever the carried buffer, updated in place;
-    # one layer of it is never a value of its own
-    for name, op, body in _top_level_results(hlo, state_shape):
-        assert op == "dynamic-update-slice" or (op == "fusion" and any(
-            "ROOT" in l and " dynamic-update-slice(" in l for l in body)), \
-            (name, op)
-    assert _top_level_results(hlo, state_shape[1:]) == []
-    assert "remat" not in " ".join(
-        name for name, _, _ in _top_level_results(hlo, state_shape))
+    _state_stepped_in_place(hlo, state_shape, program)
     # no stack of the large weights is re-laid or copied, and no layer
     # of one is a value of its own
     for stack in ((36, 2048, 8448), (36, 4096, 2048), (36, 2048, 16384),
                   (36, 8192, 2048), (4, 2048, 16384), (100352, 2048)):
         assert _top_level_results(hlo, stack) == [], stack
+    # nor, in the decode program, are the convolution's rows beside the
+    # state: only ever the carried buffer, updated in place in the
+    # layout it came in (the chunk program re-lays them on their way in
+    # and out, its 0.114 GiB of temporaries, as it did at PR 42)
+    for name, op, body in _top_level_results(
+            hlo, (sr.layers, _GRANITE_SLOTS) + sr.conv):
+        assert program != "decode" or op == "dynamic-update-slice" or (
+            op == "fusion" and any(
+                "ROOT" in l and " dynamic-update-slice(" in l
+                for l in body)), (name, op)
     if program == "decode":
         assert re.search(r"%dstpu_paged_decode[\w.]* = .*tpu_custom_call",
                          hlo)

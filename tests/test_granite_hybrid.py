@@ -327,6 +327,101 @@ def test_the_step_is_the_recurrence_and_holds_still_at_dt_zero():
     np.testing.assert_array_equal(np.asarray(S3), np.asarray(S2))
 
 
+def _state_step_operands(slots=5, H=12, P=16, N=128, layers=3, seed=0):
+    """Every slot's vectors of one decode step as ``ssm_step`` hands them
+    to the rule (slot 1 masked: dt = 0), and a carried state."""
+    x, dt, A, Bm, Cm, _ = _scan_inputs(slots, H=H, P=P, N=N, seed=seed)
+    dt = dt.at[1].set(0.0)
+    vectors = ((dt[..., None] * x)[..., None],
+               jnp.exp(dt * A)[..., None, None], Bm[:, None, None, :],
+               Cm[:, None, None, :])
+    state = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                              (layers, slots, H, P, N))
+    return vectors, state
+
+
+@pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
+@pytest.mark.parametrize("tile_heads", [None, 8],
+                         ids=["whole_slots", "6_of_12_heads"])
+def test_the_state_step_kernel_is_the_rule_in_place(layer, tile_heads):
+    """``dstpu_state_step`` (interpret mode) against the family's jnp
+    rule on the same operands: equal to f32 rounding, every other layer
+    bit for bit as it was, a masked slot's state bit for bit; whole
+    slots a tile, and room for 8 heads, which do not divide the 12: tiles
+    of 6."""
+    vectors, state = _state_step_operands()
+    P, N = state.shape[-2:]
+    assert K._state_tile(5, 12, P * N * 4, 8 * P * N * 4) == (1, 6)
+    assert K._state_tile(5, 12, P * N * 4, K._STATE_TILE_BYTES) == (5, 12)
+    o, new = jax.jit(lambda state, layer, *v: K.state_step(
+        gh.ssm_rule, state, layer, v, interpret=True,
+        tile_bytes=tile_heads and tile_heads * P * N * 4))(
+            state, layer, *vectors)
+    want_o, want_S = gh.ssm_rule(state[layer], *vectors)
+    assert o.shape == want_o.shape == state.shape[1:4] + (1,)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o),
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(new[layer]), np.asarray(want_S),
+                               atol=1e-6, rtol=1e-6)
+    others = [l for l in range(state.shape[0]) if l != layer]
+    np.testing.assert_array_equal(np.asarray(new)[others],
+                                  np.asarray(state)[others])
+    np.testing.assert_array_equal(np.asarray(new[layer, 1]),
+                                  np.asarray(state[layer, 1]))
+
+
+def test_a_decode_step_over_every_slot_steps_the_carried_state(
+        params, monkeypatch):
+    """What the seam hands ``ssm_mix``: the carried buffer and the layer
+    in a decode step over every slot on one device, the rows' state
+    under a mesh (``tp``) and in a chunk's one-slot view; the logits of
+    the two decode steps agree."""
+    from deepspeed_tpu.inference import paged_forward
+    from deepspeed_tpu.models.family import CarriedState
+
+    carried = []
+
+    def mix(cfg, x, lp, state, valid):
+        carried.append(isinstance(state[1], CarriedState))
+        return gh.ssm_mix(cfg, x, lp, state, valid)
+
+    fam = dataclasses.replace(gh.FAMILY, recurrent=dataclasses.replace(
+        gh.FAMILY.recurrent, mix=mix))
+    monkeypatch.setattr(paged_forward, "decoder_family", lambda cfg: fam)
+    tokens = jnp.asarray(np.random.default_rng(3).integers(
+        0, CFG.vocab_size, (2, 16)), jnp.int32)
+    logits = {}
+    for tp in (False, True):
+        cache = _cache(CFG, 2, 2, 32)._replace(
+            real=jnp.ones((2,), jnp.int32))
+        logits[tp], _ = forward_paged(params, tokens[:, :1], CFG, cache,
+                                      tp=tp)
+        assert carried and all(c == (not tp) for c in carried), (tp, carried)
+        del carried[:]
+    np.testing.assert_allclose(np.asarray(logits[False]),
+                               np.asarray(logits[True]), atol=2e-4,
+                               rtol=2e-4)
+    view = _cache(CFG, 2, 1, 32, slot=jnp.zeros((1,), jnp.int32))
+    forward_paged(params, tokens[:1], CFG, view)
+    assert carried and not any(carried)
+
+
+def test_the_policy_names_the_state_stepper(params):
+    """``/statusz`` ``kernels.state_step``: ``pallas`` on one device;
+    under a mesh ``xla``, with a ``fallbacks`` row; a family with no
+    recurrent layer reads ``xla`` and no row."""
+    eng = _engine(params)
+    kernels = eng.statusz()["kernels"]
+    assert kernels["state_step"] == "pallas" and kernels["fallbacks"] == []
+    demoted = K.resolve_serving_kernels(None, tp=True, recurrent=True)
+    assert demoted.state_step == "xla"
+    assert [(f, d) for f, d, _ in demoted.fallbacks] == [
+        ("state_step=pallas", "xla")]
+    assert "tp" in demoted.as_dict()["fallbacks"][0]["reason"]
+    plain = K.resolve_serving_kernels(None, tp=True)
+    assert plain.state_step == "xla" and plain.fallbacks == ()
+
+
 # --------------------------------------------------- (v) what is refused
 @pytest.mark.parametrize("mechanism,kw", [
     ("prefix_cache", dict(prefix_cache=True)),

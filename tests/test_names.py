@@ -363,15 +363,21 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
         "dstpu_fused_sample": lambda: jax.make_jaxpr(
             lambda: sampling_pallas.fused_greedy_rows(
                 jnp.zeros((8, 256), jnp.float32), interpret=True))(),
+        "dstpu_state_step": lambda: jax.make_jaxpr(
+            lambda: K.state_step(
+                lambda S, row: (row, S + row),
+                jnp.zeros((2, 2, 2, 8, 128), jnp.float32), 1,
+                (jnp.zeros((2, 2, 1, 128), jnp.float32),),
+                interpret=True))(),
     }
     for want, make in sites.items():
         names = _pallas_names(make().jaxpr, [])
         assert want in names, (want, names)
-    # the sources give twelve sites twelve names, none shared
+    # the sources give thirteen sites thirteen names, none shared
     named = []
     for mod in (K, adam_pallas, attention_pallas, quant, sampling_pallas):
         with open(mod.__file__) as f:
             text = f.read()
         assert text.count("pl.pallas_call(") == text.count('name="dstpu_')
         named += re.findall(r'name="(dstpu_[a-z0-9_]+)"', text)
-    assert len(named) == len(set(named)) == 12
+    assert len(named) == len(set(named)) == 13

@@ -319,6 +319,50 @@ def test_the_chunked_rule_is_the_recurrence(T, block):
 
 
 # ---------------------------------------------------- (iv) the share
+@pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
+@pytest.mark.parametrize("tile_heads", [None, 8],
+                         ids=["whole_slots", "6_of_12_heads"])
+def test_the_state_step_kernel_is_the_rule_in_place(layer, tile_heads):
+    """``dstpu_state_step`` (interpret mode) against the family's jnp
+    rule on the same operands: equal to f32 rounding, every other layer
+    bit for bit as it was, a masked slot's state (beta = g = 0) bit for
+    bit; whole slots a tile, and room for 8 heads, which do not divide the
+    12: tiles of 6."""
+    slots, H, Dk, Dv = 5, 12, 32, 128
+    q, k, v, g, beta, _ = _rule_inputs(slots, H=H, Dk=Dk, Dv=Dv)
+    g, beta = g.at[1].set(0.0), beta.at[1].set(0.0)
+    vectors = (q[..., None], k[..., None], v[..., None, :],
+               jnp.exp(g)[..., None, None], beta[..., None, None])
+    state = jax.random.normal(jax.random.PRNGKey(1), (3, slots, H, Dk, Dv))
+    o, new = jax.jit(lambda state, layer, *v: K.state_step(
+        qn.gdn_rule, state, layer, v, interpret=True,
+        tile_bytes=tile_heads and tile_heads * Dk * Dv * 4))(
+            state, layer, *vectors)
+    want_o, want_S = qn.gdn_rule(state[layer], *vectors)
+    assert o.shape == want_o.shape == (slots, H, 1, Dv)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o),
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(new[layer]), np.asarray(want_S),
+                               atol=1e-6, rtol=1e-6)
+    others = [l for l in range(state.shape[0]) if l != layer]
+    np.testing.assert_array_equal(np.asarray(new)[others],
+                                  np.asarray(state)[others])
+    np.testing.assert_array_equal(np.asarray(new[layer, 1]),
+                                  np.asarray(state[layer, 1]))
+
+
+def test_the_policy_names_the_state_stepper(params):
+    """``/statusz`` ``kernels.state_step``: ``pallas`` on one device,
+    and the engine's tokens are the reference's through it (the engine
+    tests above); under a mesh ``xla``, with a ``fallbacks`` row."""
+    kernels = _engine(params).statusz()["kernels"]
+    assert kernels["state_step"] == "pallas" and kernels["fallbacks"] == []
+    demoted = K.resolve_serving_kernels(None, tp=True, recurrent=True)
+    assert demoted.state_step == "xla"
+    assert [(f, d) for f, d, _ in demoted.fallbacks] == [
+        ("state_step=pallas", "xla")]
+
+
 def test_eight_ranks_shares_add_up_to_the_uncut_layer(params):
     """The routed parts of all 8 ranks, with what every rank computes
     alike (the gated shared expert) counted once, are the uncut layer:
