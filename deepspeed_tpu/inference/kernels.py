@@ -613,6 +613,16 @@ def _gather_rows(pages, layer, table, scales=None, dtype=None):
     return g if scales is None else dequantize_pages(g, rows(scales), dtype)
 
 
+# A chunk's float32 scores, every head over the whole table, that the
+# gathered reader holds at once; past it the K/V heads go through one at
+# a time.  The widest the benchmark's other chunk programs hold is 1.06
+# GiB (16 heads x 1,024 x 17,408, docqa-sat), and they lower as they
+# did; 48 heads over 18,432 rows would be 3.4 GiB, and are 0.42 a pass
+# (over 8,192 rows 1.5 GiB, which did not fit beside a full chip's
+# arguments: v5e, PR 44).
+_CHUNK_SCORE_BYTES = 5 << 28
+
+
 def paged_chunk_attention_reference(q, k_pages, v_pages, table, start,
                                     scale: Optional[float] = None, *,
                                     layer=None, k_scale=None, v_scale=None):
@@ -623,7 +633,9 @@ def paged_chunk_attention_reference(q, k_pages, v_pages, table, start,
     This is the split-fuse read path: history + chunk in one masked
     gather, so a long prompt can be absorbed ``C`` tokens per iteration
     between decode steps.  ``k_scale``/``v_scale``: the pages are int8
-    codes, dequantized to q's dtype after the gather."""
+    codes, dequantized to q's dtype after the gather.  The scores are
+    held for every head at once up to ``_CHUNK_SCORE_BYTES``, a rule of
+    the shapes; past it a K/V head's query heads at a time."""
     B, C, H, Dh = q.shape
     kg = _gather_rows(k_pages, layer, table, k_scale, q.dtype)
     vg = _gather_rows(v_pages, layer, table, v_scale, q.dtype)
@@ -631,6 +643,22 @@ def paged_chunk_attention_reference(q, k_pages, v_pages, table, start,
     G = H // KV
     scale = scale if scale is not None else Dh ** -0.5
     qg = q.reshape(B, C, KV, G, Dh)
+    if 4 * B * C * H * S > _CHUNK_SCORE_BYTES:
+        kpos = jnp.arange(S)[None, None]
+        qpos = (start[:, None] + jnp.arange(C)[None])[:, :, None]
+
+        def head(qkv):
+            qk, kk, vk = qkv          # [B, C, G, Dh], [B, S, Dh] twice
+            s = jnp.einsum("bcgd,bsd->bcgs", qk.astype(jnp.float32),
+                           kk.astype(jnp.float32)) * scale
+            s = jnp.where((kpos <= qpos)[:, :, None], s, NEG_INF)
+            return jnp.einsum("bcgs,bsd->bcgd", jax.nn.softmax(s, axis=-1),
+                              vk.astype(jnp.float32)).astype(q.dtype)
+
+        out = jax.lax.map(head, (jnp.moveaxis(qg, 2, 0),
+                                 jnp.moveaxis(kg, 1, 0),
+                                 jnp.moveaxis(vg, 1, 0)))
+        return jnp.moveaxis(out, 0, 2).reshape(B, C, H, Dh)
     s = jnp.einsum("bckgd,bksd->bckgs", qg.astype(jnp.float32),
                    kg.astype(jnp.float32)) * scale
     kpos = jnp.arange(S)[None, None]                        # [1, 1, S]

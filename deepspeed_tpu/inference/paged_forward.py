@@ -26,7 +26,8 @@ from deepspeed_tpu.inference.kernels import (latent_attention_step,
                                              state_rows, state_step,
                                              state_stepper, write_state_rows)
 from deepspeed_tpu.inference.quantized import dequantize_params
-from deepspeed_tpu.models.family import CarriedState, decoder_family
+from deepspeed_tpu.models.family import (CarriedRows, CarriedState,
+                                         decoder_family)
 from deepspeed_tpu.parallel.moe import extra_pair_passes
 
 
@@ -73,20 +74,25 @@ def _count_routed(fam, cfg, rows, routed, N: int):
         routed, extra_pair_passes(routed, N, top_k, scored))
 
 
-def _forward_periods(fam, params, x, cfg, cache, block, *, whole: bool,
-                     tp: bool, interpret: bool):
-    """The layers of a family with recurrent layers, whole periods at a
-    time, a period's kinds in the order ``Recurrent.period`` states them
-    (attention may end a period or stand inside it): an attention layer is
-    ``block`` (:func:`_paged_block` over the pool, whose leading dimension
-    counts the attention layers alone); a recurrent layer reads its rows'
-    state beside the pool, mixes, and writes it back, but in a decode step
+def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
+                     whole: bool, tp: bool, interpret: bool):
+    """The layers of a family some of whose layers keep a bounded state
+    a slot (``fam.recurrent``): first the family's leading stack, if it
+    has one (``lead``: its block, over the pool's first layers), then
+    whole periods at a time, a period's kinds in the order
+    ``Recurrent.period`` states them (a pool layer may end a period or
+    stand inside it): a pool layer is ``block`` (:func:`_paged_block`
+    over the pool, whose leading dimension counts the lead's and the
+    periods' pool layers alone); a per-slot layer reads its rows' state
+    beside the pool, mixes, and writes it back, but in a decode step
     over every slot on one device (:func:`~deepspeed_tpu.inference.
     kernels.state_stepper`), where ``mix`` steps its layer of the carried
     state where it lies (``family.CarriedState``) and only the
-    convolution's rows go out and back.  ``cache.real``: how many tokens
-    of each row may move a state; a row that starts at position 0 starts
-    from zero state, whatever its slot held."""
+    convolution's rows go out and back, or not even they
+    (``Recurrent.rows_in_place``, ``family.CarriedRows``).
+    ``cache.real``: how many tokens of each row may move a state; a row
+    that starts at position 0 starts from zero state, whatever its slot
+    held."""
     rec = fam.recurrent
     kinds = rec.period(cfg)
     n_rec = sum(kinds)
@@ -95,16 +101,23 @@ def _forward_periods(fam, params, x, cfg, cache, block, *, whole: bool,
     start, slot = cache.seq_lens, cache.slot
     real = (jnp.full((B,), T, jnp.int32) if cache.real is None
             else cache.real)
-    periods = cache.k.shape[0] // n_att
-    in_place = state_stepper(decode=T == 1 and slot is None,
-                             tp=tp)[0] == "pallas"
+    n_lead = 0
+    if fam.lead is not None:
+        n_lead = jax.tree.leaves(params[fam.lead[0]])[0].shape[0]
+        x, cache = paged_layer_loop(lead, x, params[fam.lead[0]], cache,
+                                    count=n_lead)
+    periods = (cache.k.shape[0] - n_lead) // n_att
+    every_slot = T == 1 and slot is None
+    in_place = cache.state is not None and state_stepper(
+        decode=every_slot, tp=tp)[0] == "pallas"
+    rows_in_place = rec.rows_in_place and every_slot
     step = functools.partial(state_step, interpret=interpret)
 
     def split(stack):
         held = {k: stack[k] for k in fam.whole_stacks} if whole else {}
         return held, {k: v for k, v in stack.items() if k not in held}
 
-    # the recurrent layers' stack stays whole: their loop takes a layer
+    # the per-slot layers' stack stays whole: their loop takes a layer
     # out of it by its index (sliced a period at a time by the outer
     # loop, a period's weights were copied once more: 150 MB of one
     # projection a period, v5e, PR 35)
@@ -119,25 +132,35 @@ def _forward_periods(fam, params, x, cfg, cache, block, *, whole: bool,
               for k, v in rec_stack.items()}
         if rec_whole:
             lp = dict(lp, **rec_whole, layer=layer)
-        carried = (conv,) if in_place else (conv, state)
-        held = state_rows(carried, layer, slot)
+        # what goes out of the carried buffers and back: the rows and
+        # the state, but what a decode step updates where it lies
+        rows_out = () if rows_in_place else (conv,)
+        state_out = () if in_place or state is None else (state,)
+        out = rows_out + state_out
+        held = state_rows(out, layer, slot)
         if T > 1:
             first = start == 0
             held = tuple(jnp.where(
                 first.reshape((B,) + (1,) * (a.ndim - 1)), 0, a)
                 for a in held)
-        if in_place:
-            held += (CarriedState(state, layer, step),)
-        y, held = rec.mix(cfg, x, lp, held, real)
+        held = (CarriedRows(conv, layer) if rows_in_place else held[0],
+                CarriedState(state, layer, step) if in_place
+                else held[-1] if state_out else None)
+        y, held = rec.mix(cfg, x, lp, held, real, start, ctx)
         with jax.named_scope("kv_write"), jax.named_scope(rec.write_scope):
-            carried = write_state_rows(carried, layer, slot, held)
-        conv, state = (carried[0], held[1].buffer) if in_place else carried
+            out = write_state_rows(
+                out, layer, slot,
+                (() if rows_in_place else held[:1])
+                + (held[1:] if state_out else ()))
+        conv = held[0].buffer if rows_in_place else out[0]
+        if state is not None:
+            state = held[1].buffer if in_place else out[-1]
         x = rec.out(cfg, x, y, lp)
         if fam.expert_rows(cfg)[0]:     # else a dense FFN: nothing to count
             x, rows = x[0], _count_routed(fam, cfg, rows, x[1], B * T)
         return (x, rows, conv, state), None
 
-    # consecutive layers of one kind: [(recurrent?, how many), ...]
+    # consecutive layers of one kind: [(per-slot?, how many), ...]
     runs = [(kind, len(list(g))) for kind, g in itertools.groupby(kinds)]
 
     def period(x, att, p, kp, vp, rows, conv, state):
@@ -157,12 +180,13 @@ def _forward_periods(fam, params, x, cfg, cache, block, *, whole: bool,
                 i_rec += n
                 continue
             for _ in range(n):
-                layer = p * n_att + i_att
+                layer = p * n_att + i_att       # in "blocks"; in the pool
                 lp = {k: v[i_att] for k, v in att.items()}
                 if att_whole:
                     lp = dict(lp, **att_whole, layer=layer)
-                x, kp, vp, _, _, rows = block(x, lp, layer, kp, vp, None,
-                                              None, rows)
+                x, kp, vp, _, _, rows = block(
+                    x, lp, n_lead + layer if n_lead else layer, kp, vp,
+                    None, None, rows)
                 i_att += 1
         return x, kp, vp, rows, conv, state
 
@@ -263,6 +287,7 @@ def forward_paged(params, tokens, cfg, cache, *,
     if fam.recurrent is not None:
         x, cache = _forward_periods(
             fam, params, x, cfg, cache, block(fam.out),
+            fam.lead and block(fam.lead[1]), ctx,
             whole=resident and not tp, tp=tp, interpret=interpret)
         return fam.head(params, x, cfg), cache
     n_lead = 0

@@ -1202,7 +1202,7 @@ class ServingEngine:
             sr = self._state_row
             conv = self._put(jnp.zeros(
                 (sr.layers, self.max_batch) + sr.conv, cache_dtype))
-            state = self._put(jnp.zeros(
+            state = None if sr.state is None else self._put(jnp.zeros(
                 (sr.layers, self.max_batch) + sr.state, STATE_DTYPE))
         if self._quant_resident:
             # int8-resident pages: codes replace the dense planes
@@ -1229,6 +1229,18 @@ class ServingEngine:
             table=table, seq_lens=seq_lens,
             page_size=page_size,
             expert_rows=expert_rows, conv=conv, state=state)
+
+    def _state_bytes(self) -> int:
+        """Bytes of the per-slot state beside the pool."""
+        return int(sum(a.nbytes for a in (self.cache.conv, self.cache.state)
+                       if a is not None))
+
+    def _pool_bytes(self) -> int:
+        """Bytes of the page pool (one array, or a layer each where the
+        weights stream)."""
+        c = self.cache
+        return int(sum(a.nbytes for a in jax.tree.leaves(
+            (c.k, c.v, c.k_scale, c.v_scale))))
 
     def _row_view(self, table_row, seq_len: int, b: int) -> PagedKVCache:
         """The private one-row view a prefill or chunk program works
@@ -2990,8 +3002,7 @@ class ServingEngine:
         self._g_kv_util.set(
             (usable - self.allocator.available) / max(usable, 1))
         if self._state_row is not None:
-            self._g_state_bytes.set(self.cache.conv.nbytes
-                                    + self.cache.state.nbytes)
+            self._g_state_bytes.set(self._state_bytes())
             self._g_state_live.set(
                 sum(1 for s in self.slots if s is not None))
         if self._pc_on:
@@ -3279,6 +3290,13 @@ class ServingEngine:
             "queue": {"depth": len(self.queue), "head": queue},
             "finished_pending_drain": len(self.finished),
             "kv": {
+                # the layers that attend over pages (not the model's
+                # depth where some keep a state a slot: "cache.state")
+                "layers": (len(self.cache.k)
+                           if isinstance(self.cache.k, (tuple, list))
+                           else int(self.cache.k.shape[0])),
+                "bytes_per_token": self._pool_bytes() // (
+                    (self.trash_page + 1) * self.page_size),
                 "page_size": self.page_size,
                 "pages_usable": usable,
                 "pages_free": len(al.free),
@@ -3298,11 +3316,9 @@ class ServingEngine:
             # with recurrent layers): what it weighs, and how many
             # slots' states are in use
             "cache.state": {
-                "bytes": int(self.cache.conv.nbytes
-                             + self.cache.state.nbytes),
-                "bytes_per_slot": int(
-                    (self.cache.conv.nbytes + self.cache.state.nbytes)
-                    // self.max_batch),
+                "layers": self._state_row.layers,
+                "bytes": self._state_bytes(),
+                "bytes_per_slot": self._state_bytes() // self.max_batch,
                 "live_slots": sum(1 for s in self.slots if s is not None),
                 "fresh_starts": int(self._c_state_fresh.value),
                 "rows_masked": int(self._c_state_masked.value),
@@ -3808,7 +3824,8 @@ def serving_engine(params, cfg, **kw):
             mesh.size(ax) > 1 for ax in ("model", "expert")),
         interpret=jax.default_backend() != "tpu",
         quantized_resident=kvt.enabled and kvt.quantized_resident,
-        recurrent=fam.recurrent is not None)
+        recurrent=fam.recurrent is not None
+        and fam.recurrent.state_row(cfg).state is not None)
     pk = kw["kernels"].paged_attention
     if fam.latent is not None:
         kw["kernels"] = kw["kernels"]._replace(

@@ -1,8 +1,8 @@
 """What a decoder family is, stated once.
 
 A family's file (``gpt2.py``, ``llama.py``, ``mixtral.py``,
-``pangu_ultra_moe.py``, ``qwen3_next.py``, ``granite_hybrid.py``) ends
-with its
+``pangu_ultra_moe.py``, ``qwen3_next.py``, ``granite_hybrid.py``,
+``laguna.py``) ends with its
 ``FAMILY = DecoderFamily(...)``: the pieces of one transformer layer and
 the facts a serving build needs.  Everything that serves, streams, drafts
 or generates (the ``inference`` package) reads the record through
@@ -49,37 +49,47 @@ class CacheRow(NamedTuple):
 
 
 class StateRow(NamedTuple):
-    """What one SLOT keeps, a recurrent layer, whatever the length of its
-    sequence: ``conv`` (the last inputs of a short causal convolution,
-    rows x channels, in the cache's dtype) and ``state`` (the recurrence's
-    matrix a head, float32), for each of ``layers`` recurrent layers.  It
-    is indexed by slot, not by page: ``PagedKVCache.conv`` is
-    ``[layers, B, *conv]`` and ``PagedKVCache.state`` ``[layers, B,
-    *state]``."""
+    """What one SLOT keeps, a layer that keeps a bounded state, whatever
+    the length of its sequence: ``conv`` (rows x channels in the cache's
+    dtype: the last inputs of a short causal convolution, or a ring of
+    the last ``rows`` tokens' ``[K | V]``) and ``state`` (the
+    recurrence's matrix a head, float32; None where the layer keeps
+    rows alone), for each of ``layers`` such layers.  It is indexed by
+    slot, not by page: ``PagedKVCache.conv`` is ``[layers, B, *conv]``
+    and ``PagedKVCache.state`` ``[layers, B, *state]`` or None."""
 
     layers: int
     conv: Tuple[int, ...]
-    state: Tuple[int, ...]
+    state: Optional[Tuple[int, ...]]
 
 
 @dataclasses.dataclass(frozen=True)
 class Recurrent:
-    """The recurrent layers of a family whose layers come in periods of
-    more than one kind (an author's fields, as ``DecoderFamily``'s).
+    """The layers of a family that keep a bounded state a slot beside
+    the page pool, in periods with layers that attend over pages (an
+    author's fields, as ``DecoderFamily``'s).  Three users: a delta rule
+    (``qwen3_next``) and a state-space mixer (``granite_hybrid``), whose
+    state is a matrix a head that every token moves, and a sliding
+    window's attention (``laguna``), whose state is a ring of the last
+    tokens' K and V.
 
     ``period(cfg)``: one bool a layer of a period, True where the layer
-    mixes tokens through a recurrence over a per-slot state and False
+    mixes tokens over its per-slot state and False
     where it attends over the page pool (``qkv`` / ``out``), in whatever
     order the model has them (an attention layer may end a period or
-    stand inside it); the model is whole periods.  ``key``: the params'
-    stack of the recurrent layers
-    ``[periods * recurrent layers a period, ...]``; ``blocks`` holds the
-    attention layers alone.  ``mix(cfg, x, lp, state, valid) -> (y,
-    state)``: ``x`` [B, T, d] the residual stream, ``state`` the rows'
-    ``(conv [B, *conv], state [B, *state])``, ``valid`` [B] int32 how
-    many of each row's T tokens are real (the rest is padding, or the
-    whole row a slot that is idle or between two chunks of its prompt):
-    the state moves on real tokens only.  ``out(cfg, x, y, lp)``: the
+    stand inside it); the model is whole periods (behind
+    ``DecoderFamily.lead``, where the family has one).  ``key``: the
+    params' stack of these layers
+    ``[periods * such layers a period, ...]``; ``blocks`` holds the
+    pool's layers alone.  ``mix(cfg, x, lp, state, valid, start, ctx) ->
+    (y, state)``: ``x`` [B, T, d] the residual stream, ``state`` the
+    rows' ``(conv [B, *conv], state [B, *state])``, ``valid`` [B] int32
+    how many of each row's T tokens are real (the rest is padding, or
+    the whole row a slot that is idle or between two chunks of its
+    prompt): the state moves on real tokens only; ``start`` [B] where
+    each row's first token stands and ``ctx`` what ``embed`` made of the
+    positions (a mixer without positions ignores both).  ``out(cfg, x,
+    y, lp)``: the
     residual and the FFN half, as ``DecoderFamily.out`` (``(x, rows)``
     where the family counts its experts' rows, ``x`` where it has none).
     ``state_row``: what a slot keeps.  ``write_scope``: the
@@ -89,11 +99,17 @@ class Recurrent:
 
     Where the program is a decode step over every slot on one device,
     ``mix`` is handed as ``state[1]`` not the rows' state but a
-    :class:`CarriedState`: the whole buffer of every recurrent layer and
+    :class:`CarriedState`: the whole buffer of every such layer and
     which layer this is.  A family steps either through
     :func:`step_state` with its one-token rule, and hands back what that
     returned; it never reads a ``CarriedState`` as an array (a prompt
-    chunk's ``mix`` is never handed one)."""
+    chunk's ``mix`` is never handed one).  ``rows_in_place``: in a
+    decode step over every slot ``state[0]`` is a :class:`CarriedRows`
+    too: the mixer writes its layer of the buffer where it lies (one row
+    a live slot), reads it there, and hands the ``CarriedRows`` back;
+    the seam then neither slices the layer's rows out nor puts them
+    back (right for 3 rows of a convolution; three passes over a ring
+    of 512)."""
 
     key: str
     period: Callable[[Any], Tuple[bool, ...]]
@@ -101,6 +117,16 @@ class Recurrent:
     out: Callable[..., Any]
     state_row: Callable[[Any], StateRow]
     write_scope: str
+    rows_in_place: bool = False
+
+
+class CarriedRows(NamedTuple):
+    """A layer's per-slot rows where they live: ``buffer`` [layers,
+    slots, rows, channels] (the serving programs' carry) and ``layer``
+    (a traced index)."""
+
+    buffer: Any
+    layer: Any
 
 
 class CarriedState(NamedTuple):
@@ -198,9 +224,9 @@ class DecoderFamily:
     # index in them (a kernel that takes the stack and an index reads a
     # layer in place; a slice handed to it would be a copy)
     whole_stacks: Tuple[str, ...] = ()
-    # layers in periods of two kinds, some recurrent over a per-slot
+    # layers in periods of two kinds, some over a bounded per-slot
     # state beside the page pool: see ``Recurrent``.  The pool then has
-    # the attention layers only
+    # the other layers only (and the ``lead``'s)
     recurrent: Optional[Recurrent] = None
     # (mechanism, why) the family cannot serve with yet: see ``refuse``
     refuses: Tuple[Tuple[str, str], ...] = ()
@@ -235,7 +261,7 @@ def positions_from(start, T: int):
 
 # the registry: one module name a family
 _FAMILY_MODULES = ("gpt2", "llama", "mixtral", "pangu_ultra_moe",
-                   "qwen3_next", "granite_hybrid")
+                   "qwen3_next", "granite_hybrid", "laguna")
 
 
 def decoder_families() -> Tuple[DecoderFamily, ...]:

@@ -332,12 +332,15 @@ def _gated_norm(o, z, w, eps):
         * w.astype(jnp.float32)
 
 
-def ssm_mix(cfg, x, lp, state, valid):
+def ssm_mix(cfg, x, lp, state, valid, start=None, ctx=()):
     """The Mamba-2 mixer (the family's ``Recurrent.mix``): ``x`` [B, T,
     d] -> (y [B, T, d] before the residual, the rows' new (conv, S)).
     ``valid`` [B]: tokens at or past it move neither S (their dt is 0:
     decay 1, write 0) nor the convolution's rows, which are the
-    ``conv_kernel - 1`` inputs that end at the last real token."""
+    ``conv_kernel - 1`` inputs that end at the last real token.  ``start``
+    and ``ctx`` (the seam hands every mixer where its rows stand and what
+    ``embed`` made of the positions) are not used: the mixer has no
+    positions."""
     B, T, _ = x.shape
     H, Pd, N, taps = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
                       cfg.conv_kernel)

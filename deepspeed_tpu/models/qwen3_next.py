@@ -325,12 +325,15 @@ def gdn_chunk_rule(q, k, v, g, beta, S, block: int):
     return o[:, :T], S
 
 
-def gdn_mix(cfg, x, lp, state, valid):
+def gdn_mix(cfg, x, lp, state, valid, start=None, ctx=()):
     """The Gated DeltaNet mixer (the family's ``Recurrent.mix``): ``x``
     [B, T, d] -> (y [B, T, d] before the residual, the rows' new (conv,
     S)).  ``valid`` [B]: tokens at or past it move neither S (their beta
     and g are 0) nor the convolution's rows, which are the
-    ``conv_kernel - 1`` inputs that end at the last real token."""
+    ``conv_kernel - 1`` inputs that end at the last real token.  ``start``
+    and ``ctx`` (the seam hands every mixer where its rows stand and what
+    ``embed`` made of the positions) are not used: the mixer has no
+    positions."""
     B, T, _ = x.shape
     Hk, Hv, Dk, Dv = (cfg.lin_k_heads, cfg.lin_v_heads, cfg.lin_k_dim,
                       cfg.lin_v_dim)

@@ -123,9 +123,10 @@ def _chunks_then_steps(params, cfg, seq, n_prompt, C=16, dtype=jnp.float32):
 
 
 # -------------------------------- (i) the paged forward vs the reference
-def test_the_family_is_the_sixth_and_its_period_is_as_stated():
-    assert [f.name for f in decoder_families()][-1] == "GraniteHybridConfig"
-    assert len(decoder_families()) == 6
+def test_the_family_is_registered_and_its_period_is_as_stated():
+    assert "GraniteHybridConfig" in [f.name for f in decoder_families()]
+    assert "GraniteHybridConfig" in [
+        f.name for f in decoder_families() if f.recurrent is not None]
     fam = decoder_family(CFG)
     assert fam.recurrent.period(CFG) == (True, True, False, True)
     assert fam.recurrent.write_scope == "ssm_write"
@@ -381,9 +382,9 @@ def test_a_decode_step_over_every_slot_steps_the_carried_state(
 
     carried = []
 
-    def mix(cfg, x, lp, state, valid):
+    def mix(cfg, x, lp, state, valid, *positions):
         carried.append(isinstance(state[1], CarriedState))
-        return gh.ssm_mix(cfg, x, lp, state, valid)
+        return gh.ssm_mix(cfg, x, lp, state, valid, *positions)
 
     fam = dataclasses.replace(gh.FAMILY, recurrent=dataclasses.replace(
         gh.FAMILY.recurrent, mix=mix))

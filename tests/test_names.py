@@ -15,7 +15,7 @@ import deepspeed_tpu
 from benchmark.harness import scopes, trace
 from deepspeed_tpu.inference.kernels import PagedKVCache
 from deepspeed_tpu.inference.serving import serving_engine
-from deepspeed_tpu.models import gpt2, mixtral
+from deepspeed_tpu.models import gpt2, laguna, mixtral
 
 CHILDREN = ("admit", "prefill", "boundary", "grow_pages", "upload",
             "inputs", "dispatch", "token_sync", "append")
@@ -36,7 +36,12 @@ def _mixtral():
     return cfg, mixtral.init_params(jax.random.PRNGKey(0), cfg), mixtral
 
 
-MODELS = {"gpt2": _gpt2, "mixtral": _mixtral}
+def _laguna():
+    cfg = laguna.LagunaConfig.tiny()
+    return cfg, laguna.init_params(jax.random.PRNGKey(0), cfg), laguna
+
+
+MODELS = {"gpt2": _gpt2, "mixtral": _mixtral, "laguna": _laguna}
 
 
 def _serve(telemetry):
@@ -232,9 +237,8 @@ def _paged_programs(name):
                       if hasattr(x, "shape") else x)
     tm = jax.tree_util.tree_map
     params_a, cache_a = tm(absx, eng.params), tm(absx, eng.cache)
-    view_a = tm(absx, eng.cache._replace(
-        table=jnp.zeros((1, eng.max_pages_per_seq), jnp.int32),
-        seq_lens=jnp.zeros((1,), jnp.int32)))
+    # the engine's own one-row view: with the slot, where slots keep rows
+    view_a = tm(absx, eng._row_view(eng._table_host[0:1], 0, 0))
     B = eng.max_batch
     last = jax.ShapeDtypeStruct((1,), jnp.int32)    # the last real position
     lowered = {
@@ -261,6 +265,7 @@ BLOCKS = {"gpt2": {"embed", "attn_qkv", "attn_out", "mlp", "final_norm",
                    "lm_head", "kv_write"},
           "mixtral": {"embed", "attn_qkv", "attn_out", "mlp", "moe_router",
                       "moe_ffn", "final_norm", "lm_head", "kv_write"}}
+BLOCKS["laguna"] = BLOCKS["mixtral"]
 PHASE = {"prefill": {"flash"}, "chunk": {"kv_attend"},
          "decode": {"kv_attend", "sample"}}
 
@@ -276,7 +281,7 @@ def _words(text):
 
 @pytest.mark.parametrize("program", ["prefill", "chunk", "decode",
                                      "boundary"])
-@pytest.mark.parametrize("name", ["gpt2", "mixtral"])
+@pytest.mark.parametrize("name", ["gpt2", "mixtral", "laguna"])
 def test_paged_programs_carry_every_scope_that_applies(name, program):
     text = _paged_programs(name)[program]
     # the boundary sampler runs no model: its one scope is ``sample``
@@ -284,6 +289,28 @@ def test_paged_programs_carry_every_scope_that_applies(name, program):
             else BLOCKS[name] | PHASE[program])
     assert want <= _words(text), want - _words(text)
     assert f"dstpu_{program}" in text          # the program's own name
+
+
+@pytest.mark.parametrize("program", ["prefill", "chunk", "decode"])
+def test_a_window_familys_words_nest_in_the_vocabulary(program):
+    """The laguna family's own words (a sliding layer's scores and sum,
+    its ring's rows, the per-head gate, the routed and the shared part
+    of an expert layer) stand INSIDE a word the harness's vocabulary
+    knows, in every program: an operation under them is labelled by the
+    known word and found by the new one (``benchmark/readers/
+    window.py``)."""
+    paths = re.findall(r'loc\("([^"]*)"', _paged_programs("laguna")[program])
+    for inner, outer in (("win_attend", "kv_attend"),
+                         ("win_write", "kv_write"),
+                         ("attn_gate", "attn_out"),
+                         ("moe_routed", "moe_ffn"),
+                         ("moe_shared", "moe_ffn")):
+        under = [scopes.WORD.findall(p) for p in paths if inner in p]
+        under = [w for w in under if inner in w]
+        assert under, inner
+        for words in under:
+            assert outer in words[:words.index(inner)], (inner, words)
+            assert scopes.scope_of("/".join(words))[0] == outer
 
 
 @pytest.mark.parametrize("name", ["gpt2", "mixtral"])

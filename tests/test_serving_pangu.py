@@ -389,7 +389,7 @@ def test_the_other_families_state_nothing_new():
 
     for fam in decoder_families():
         if fam.config_type is pg.PanguUltraMoEConfig \
-                or fam.recurrent is not None:       # qwen3_next (PR 35)
+                or fam.recurrent is not None:   # qwen3_next, granite, laguna
             continue
         cfg = fam.config_type.tiny() if hasattr(fam.config_type, "tiny") \
             else fam.config_type()
