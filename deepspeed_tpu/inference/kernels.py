@@ -1620,17 +1620,19 @@ class ServingKernelPolicy(NamedTuple):
     env_overrides: Tuple[Tuple[str, str, str], ...] = ()
     # (field, demoted_to, reason) for forced choices the build demoted
     fallbacks: Tuple[Tuple[str, str, str], ...] = ()
-    # (reader, reason): what the decode program's attention runs
-    # (:func:`paged_reader` at this build's layout)
+    # (reader, reason) of the decode program's attention (:func:`paged_reader`)
     decode: Tuple[str, str] = ("xla", "")
     # pallas | xla: how the decode program steps a recurrent layer's
     # per-slot state (:func:`state_stepper`; never configured)
     state_step: str = "xla"
+    # (reader, reason) of a window layer's chunk (ops.attention.window_reader)
+    window: Tuple[str, str] = ("xla", "no window layer")
 
     def as_dict(self) -> dict:
+        pair = lambda p: {"reader": p[0], "reason": p[1]}
         return {
             "paged_attention": self.paged_attention,
-            "decode": {"reader": self.decode[0], "reason": self.decode[1]},
+            "decode": pair(self.decode), "window": pair(self.window),
             "state_step": self.state_step,
             "fused_sampling": self.fused_sampling,
             "env_overrides": [list(o) for o in self.env_overrides],
@@ -1677,26 +1679,24 @@ def resolve_serving_kernels(kernels=None, *, tp: bool = False,
     page table per (batch, kv_head) grid step and KV heads are sharded
     over the mesh, so per-device it would read pages it does not hold;
     the demotion is VISIBLE (``fallbacks`` row + the engine's
-    ``serving_kernel_fallbacks`` counter), fixing the old silent
-    ``tp → False``.
+    ``serving_kernel_fallbacks`` counter).
 
     ``quantized_resident`` (the engine's ``kv_tier.quantized_resident``
-    cache layout) on a real chip (``interpret=False``): the TPU
-    compiler refuses the int8-resident Pallas kernel, so a forced
-    ``pallas_v2`` raises :class:`ServingKernelRefused` here at build,
-    and ``auto`` resolves to ``xla`` with a ``fallbacks`` row — the
-    forward never gets to pick a kernel that cannot compile.
+    cache layout) on a real chip (``interpret=False``): the compiler
+    refuses the int8-resident Pallas kernel, so a forced ``pallas_v2``
+    raises :class:`ServingKernelRefused` here at build, and ``auto``
+    resolves to ``xla`` with a ``fallbacks`` row.
 
     ``recurrent``: the family has recurrent layers, whose per-slot state
     a decode program steps in place through ``dstpu_state_step`` on one
-    device (:func:`state_stepper`).  That is resolved here from those
-    two facts and never configured; where ``tp`` demotes it the
-    ``state_step`` row reads ``xla`` beside a ``fallbacks`` row.
+    device (:func:`state_stepper`): never configured; where ``tp``
+    demotes it ``state_step`` reads ``xla`` beside a ``fallbacks`` row.
+    (``window`` is the family's: ``serving_engine`` asks its
+    ``Recurrent.chunk_reader`` at the engine's chunk width.)
 
-    An already-resolved :class:`ServingKernelPolicy` passes through
-    untouched — ``serving_engine`` resolves once and hands the SAME
-    policy to the engine, so the kernels the closures baked and the
-    policy ``/statusz`` reports can never drift."""
+    An already-resolved :class:`ServingKernelPolicy` passes through:
+    ``serving_engine`` resolves once, so the kernels the closures baked
+    and the policy ``/statusz`` reports are one object."""
     from deepspeed_tpu.config import KernelsConfig
 
     if isinstance(kernels, ServingKernelPolicy):
@@ -1756,10 +1756,10 @@ def resolve_serving_kernels(kernels=None, *, tp: bool = False,
             paged = "xla"
     if fused == "auto":
         # the measured policy (KERNEL_BENCH.json fused_sample_vs_xla):
-        # sampling is one [B, V] argmax — the jitted XLA twin wins at
-        # every serving shape in the committed sweep, so auto resolves
-        # off and the fused kernel stays a forced arm until a chip
-        # re-stamp says otherwise (see ops/sampling_pallas.py)
+        # sampling is one [B, V] argmax and the jitted XLA twin wins at
+        # every serving shape swept, so auto resolves off and the fused
+        # kernel stays a forced arm until a chip re-stamp says otherwise
+        # (ops/sampling_pallas.py)
         from deepspeed_tpu.ops.sampling_pallas import pallas_sample_gate
 
         fused = "on" if pallas_sample_gate(interpret=interpret) else "off"
@@ -2141,3 +2141,4 @@ def paged_period_loop(period, x, stacks, cache: PagedKVCache, periods: int):
         (stacks, jnp.arange(periods, dtype=jnp.int32)))
     return x, cache._replace(k=k, v=v, expert_rows=rows, conv=conv,
                              state=state)
+

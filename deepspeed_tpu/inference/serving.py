@@ -3790,8 +3790,7 @@ def serving_engine(params, cfg, **kw):
         return _encoder_serving_engine(params, cfg, kw)
     # the decoder build stays in THIS function: a helper's frame between
     # here and ServingEngine.__init__ cost 2-3 s of set-up on the chip's
-    # host (where the frames under the warm-up's lowering end on the
-    # interpreter's frame stack decides it: PERF.md 6, PRs 30 and 37)
+    # host (CPython's frame-stack chunks: PERF.md 6, PRs 30 and 37)
     weight_dtype = kw.pop("weight_dtype", "bfloat16")
     quant_group_size = kw.pop("quant_group_size", 128)
     mesh = kw.pop("mesh", None)
@@ -3808,16 +3807,13 @@ def serving_engine(params, cfg, **kw):
                 kw.get("prefix_cache")).enabled,
             speculative=speculating)
     # sharded-ness is baked in at BUILD time: the compiled paths must not
-    # re-read the mutable ambient mesh on a later retrace (a cleared or
-    # replaced global would silently re-enable pallas kernels over the
-    # sharded cache)
+    # re-read the mutable ambient mesh on a later retrace (a cleared one
+    # would silently re-enable pallas kernels over the sharded cache)
     sharded = fam.sharded(mesh)
     # the kernel policy resolves HERE (config + env, once), with the SAME
-    # predicates the engine uses (any model/expert axis > 1 demotes forced
-    # pallas — the kernels read the full page table per device; an
-    # int8-resident cache on a chip refuses it), and the same
-    # ServingKernelPolicy passes through to the engine: the paged_kernel
-    # the closures bake and the policy /statusz reports are one object
+    # predicates the engine uses, and passes through to the engine: what
+    # the closures bake and what /statusz reports are one object.  A
+    # family's own two words (a window layer's chunk) join it below
     kw["kernels"] = resolve_serving_kernels(
         kw.get("kernels"),
         tp=mesh is not None and any(
@@ -3830,6 +3826,11 @@ def serving_engine(params, cfg, **kw):
     if fam.latent is not None:
         kw["kernels"] = kw["kernels"]._replace(
             decode=latent_reader(kw["kernels"].decode))
+    if fam.recurrent is not None and fam.recurrent.chunk_reader is not None:
+        kw["kernels"] = kw["kernels"]._replace(
+            window=fam.recurrent.chunk_reader(
+                cfg, kw.get("prefill_chunk") or 0,
+                jax.default_backend() != "tpu"))
 
     if zi.enabled:
         from deepspeed_tpu.inference.zero_inference import (
@@ -3839,8 +3840,7 @@ def serving_engine(params, cfg, **kw):
             params, cfg, zi, family=fam, weight_dtype=weight_dtype,
             quant_group_size=quant_group_size, mesh=mesh, **kw)
 
-    # int8 leaves are dequantised inside each program: no stack of them
-    # is an array a kernel could read a layer of in place
+    # int8 leaves are dequantised in each program: no stack to read in place
     resident = weight_dtype == "bfloat16"
 
     def step(params, tokens, cache):

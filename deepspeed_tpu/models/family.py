@@ -74,12 +74,11 @@ class Recurrent:
     tokens' K and V.
 
     ``period(cfg)``: one bool a layer of a period, True where the layer
-    mixes tokens over its per-slot state and False
-    where it attends over the page pool (``qkv`` / ``out``), in whatever
-    order the model has them (an attention layer may end a period or
-    stand inside it); the model is whole periods (behind
-    ``DecoderFamily.lead``, where the family has one).  ``key``: the
-    params' stack of these layers
+    mixes tokens over its per-slot state and False where it attends over
+    the page pool (``qkv`` / ``out``), in whatever order the model has
+    them (an attention layer may end a period or stand inside it); the
+    model is whole periods (behind ``DecoderFamily.lead``, where the
+    family has one).  ``key``: the params' stack of these layers
     ``[periods * such layers a period, ...]``; ``blocks`` holds the
     pool's layers alone.  ``mix(cfg, x, lp, state, valid, start, ctx) ->
     (y, state)``: ``x`` [B, T, d] the residual stream, ``state`` the
@@ -89,13 +88,13 @@ class Recurrent:
     prompt): the state moves on real tokens only; ``start`` [B] where
     each row's first token stands and ``ctx`` what ``embed`` made of the
     positions (a mixer without positions ignores both).  ``out(cfg, x,
-    y, lp)``: the
-    residual and the FFN half, as ``DecoderFamily.out`` (``(x, rows)``
-    where the family counts its experts' rows, ``x`` where it has none).
-    ``state_row``: what a slot keeps.  ``write_scope``: the
-    ``jax.named_scope`` word, inside ``kv_write``, of the write-back of
-    a layer's rows into the carried buffers (a capture's readers sum a
-    family's words by their prefix).
+    y, lp)``: the residual and the FFN half, as ``DecoderFamily.out``
+    (``(x, rows)`` where the family counts its experts' rows, ``x``
+    where it has none).  ``state_row``: what a slot keeps.
+    ``write_scope``: the ``jax.named_scope`` word, inside ``kv_write``,
+    of the write-back of a layer's rows into the carried buffers.
+    ``chunk_reader(cfg, tokens, interpret) -> (reader, reason)``: which
+    reader ``mix`` runs over a chunk of ``tokens``, where it has two.
 
     Where the program is a decode step over every slot on one device,
     ``mix`` is handed as ``state[1]`` not the rows' state but a
@@ -118,6 +117,7 @@ class Recurrent:
     state_row: Callable[[Any], StateRow]
     write_scope: str
     rows_in_place: bool = False
+    chunk_reader: Optional[Callable[..., Tuple[str, str]]] = None
 
 
 class CarriedRows(NamedTuple):

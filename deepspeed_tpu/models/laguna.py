@@ -412,46 +412,75 @@ def window_step(cfg, q, row, rings: CarriedRows, pos, live):
     return o.reshape(B, H, Dh), CarriedRows(buffer, layer)
 
 
+def window_reader(cfg, tokens: int, interpret: bool) -> Tuple[str, str]:
+    """Which reader a chunk of ``tokens`` runs over its band, and why
+    (the family's ``Recurrent.chunk_reader``: what ``window_chunk``
+    asks and ``/statusz`` shows)."""
+    from deepspeed_tpu.ops.attention import window_reader as reader
+
+    return reader(tokens=tokens, window=cfg.sliding_window,
+                  head_dim=cfg.head_dim, interpret=interpret)
+
+
+def _band_in_blocks(cfg, q, rows, ring, start):
+    """The band as XLA runs it: queries in blocks of W, each against its
+    own block of keys and the block before it (the first block's is the
+    ring), ``[W, 2 W]`` f32 scores a block a head, not ``[T, T + W]``."""
+    B, T, H, Dh = q.shape
+    W = cfg.sliding_window
+    nb = -(-T // W)
+    pad = nb * W - T
+    j = jnp.arange(W, dtype=jnp.int32)[None]
+    last = start[:, None] - 1
+    hist_pos = last - (last - j) % W                              # [B, W]
+    grow = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),)
+                             * (a.ndim - 2))
+    blocks = lambda a: a.reshape((B, nb, W) + a.shape[2:])
+    # block b's keys: rows b W .. (b + 2) W of [ring | chunk]
+    pairs = lambda a: jnp.concatenate(
+        [blocks(a[:, :nb * W]), blocks(a[:, W:])], axis=2)
+    kvb = pairs(jnp.concatenate([ring.astype(rows.dtype), grow(rows)],
+                                axis=1))                  # [B, nb, 2W, ..]
+    pos = start[:, None] + jnp.arange(nb * W, dtype=jnp.int32)[None]
+    kpos = pairs(jnp.concatenate([hist_pos, pos], axis=1))[:, :, None]
+    qpos = blocks(pos)[..., None]                         # [B, nb, W, 1]
+    seen = (kpos <= qpos) & (kpos > qpos - W) & (kpos >= 0)
+    o = _by_kv_head(cfg, blocks(grow(q)),
+                    lambda lo, n: kvb[..., lo:lo + n], seen)
+    return o.reshape(B, nb * W, H, Dh)[:, :T]
+
+
 def window_chunk(cfg, q, k, v, ring, start, valid):
     """T tokens a row under the band, over what the row's ring held
     before them: q [B, T, H, Dh], k, v [B, T, KV, Dh] at positions
     ``start + 0 .. T - 1``, ring [B, W, 2 KV Dh] -> (o [B, T, H, Dh],
     the ring with the last ``min(valid, W)`` real tokens written).
 
-    The band as a band: queries in blocks of W, each against its own
-    block of keys and the block before it (the first block's is the
-    ring), ``[W, 2 W]`` scores a block a head, not ``[T, T + W]``.  Ring
-    row ``j`` holds the largest position under ``start`` congruent to
-    ``j``, if there is one at or past 0: a first chunk sees nothing of
-    what the slot held."""
+    Ring row ``j`` holds the largest position under ``start`` congruent
+    to ``j``, if there is one at or past 0: a first chunk sees nothing
+    of what the slot held.  On a TPU, at whole 128-row blocks and heads
+    of 128 (:func:`window_reader`), the band's scores stay on the chip
+    (``dstpu_window_flash_fwd``); elsewhere XLA runs it in blocks of W
+    (:func:`_band_in_blocks` over :func:`_by_kv_head`, which
+    ``window_step`` shares: the same arithmetic, the kernel's
+    reference)."""
     B, T, H, Dh = q.shape
     W = cfg.sliding_window
-    nb = -(-T // W)
-    pad = nb * W - T
     with jax.named_scope("kv_attend"), jax.named_scope("win_attend"):
-        j = jnp.arange(W, dtype=jnp.int32)[None]
-        last = start[:, None] - 1
-        hist_pos = last - (last - j) % W                          # [B, W]
-        grow = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),)
-                                 * (a.ndim - 2))
-        blocks = lambda a: a.reshape((B, nb, W) + a.shape[2:])
-        # block b's keys: rows b W .. (b + 2) W of [ring | chunk]
-        pairs = lambda a: jnp.concatenate(
-            [blocks(a[:, :nb * W]), blocks(a[:, W:])], axis=2)
         rows = jnp.concatenate([k.reshape(B, T, -1), v.reshape(B, T, -1)],
                                axis=-1)                   # [B, T, 2 KV Dh]
-        kvb = pairs(jnp.concatenate([ring.astype(rows.dtype), grow(rows)],
-                                    axis=1))              # [B, nb, 2W, ..]
-        pos = start[:, None] + jnp.arange(nb * W, dtype=jnp.int32)[None]
-        kpos = pairs(jnp.concatenate([hist_pos, pos], axis=1))[:, :, None]
-        qpos = blocks(pos)[..., None]                     # [B, nb, W, 1]
-        seen = (kpos <= qpos) & (kpos > qpos - W) & (kpos >= 0)
-        o = _by_kv_head(cfg, blocks(grow(q)),
-                        lambda lo, n: kvb[..., lo:lo + n], seen)
-        o = o.reshape(B, nb * W, H, Dh)[:, :T]
+        if window_reader(cfg, T, jax.default_backend() != "tpu")[0] \
+                == "pallas":
+            from deepspeed_tpu.ops.attention_pallas import (
+                window_flash_attention_tpu)
+
+            o = window_flash_attention_tpu(q, rows, ring, start)
+        else:
+            o = _band_in_blocks(cfg, q, rows, ring, start)
     with jax.named_scope("kv_write"), jax.named_scope("win_write"):
         # ring row j takes the last real token congruent to j, if the
         # chunk has one
+        j = jnp.arange(W, dtype=jnp.int32)[None]
         end = start[:, None] + valid[:, None] - 1                # [B, 1]
         take = end - (end - j) % W - start[:, None]              # [B, W]
         new = jnp.take_along_axis(
@@ -550,7 +579,8 @@ FAMILY = DecoderFamily(
     whole_stacks=("w1", "w3", "w2"),
     recurrent=Recurrent(key="win_blocks", period=_period, mix=win_mix,
                         out=_win_out, state_row=_state_row,
-                        write_scope="win_write", rows_in_place=True),
+                        write_scope="win_write", rows_in_place=True,
+                        chunk_reader=window_reader),
     refuses=(
         ("prefix_cache", _RING + "a shared prefix's pages say nothing of "
          "the rings at its end, and no snapshot of them is kept"),

@@ -132,3 +132,26 @@ def latent_flash_attention(qn, qr, kn, kr, v, start, scale: float,
     from deepspeed_tpu.ops.attention_pallas import latent_flash_attention_tpu
 
     return latent_flash_attention_tpu(qn, qr, kn, kr, v, start, scale)
+
+
+def window_reader(*, tokens: int, window: int, head_dim: int,
+                  interpret: bool):
+    """Which reader a sliding layer's chunk of ``tokens`` runs over its
+    band, and why: ("pallas" | "xla", reason), the one answer the
+    family's chunk and the engine's ``/statusz`` share (as
+    ``kernels.paged_reader`` and ``state_stepper`` are for theirs; here,
+    because ``models/`` imports nothing from ``inference/``), from the
+    backend and the shapes alone.  On a TPU, where the chunk and the
+    window are whole 128-row blocks and a head is one 128-lane tile, the
+    band's scores stay on the chip (``dstpu_window_flash_fwd``:
+    :func:`~deepspeed_tpu.ops.attention_pallas.
+    window_flash_attention_tpu`); elsewhere XLA runs the band in blocks
+    of the window, f32 scores through the memory.  A decode step
+    (``tokens == 1``) reads its ring where it lies, in XLA."""
+    for off, why in ((interpret, "interpret: no TPU backend"),
+                     (tokens <= 0 or tokens % 128 or window % 128,
+                      "chunk or window not whole 128-row blocks"),
+                     (head_dim != 128, "a head is not one 128-lane tile")):
+        if off:
+            return "xla", why
+    return "pallas", "a chunk's band in 128-row blocks on one device"

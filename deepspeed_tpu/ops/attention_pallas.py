@@ -536,3 +536,95 @@ def latent_flash_attention_tpu(qn, qr, kn, kr, v, start, scale: float,
     )(start.astype(jnp.int32), heads_first(qn), heads_first(qr),
       heads_first(kn), kr, heads_first(v))
     return out.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)
+
+
+# ------------------------------------------- sliding window, forward only
+def _band_seen(qpos, kpos, window: int):
+    """Which keys a sliding layer's query sees, from the positions: the
+    ``window`` at and before its own, none before position 0 (a slot's
+    first chunks find ring rows that hold nothing of this request)."""
+    return (kpos <= qpos) & (kpos > qpos - window) & (kpos >= 0)
+
+
+def _window_fwd_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, *, scale,
+                       window, block_q, group):
+    """One K/V head's ``group`` query heads over one block of ``block_q``
+    queries and the ``window + block_q`` keys its band can touch: rows
+    ``i block_q ..`` of ``[ring in order | chunk]``, where row ``c``
+    holds position ``start - window + c``.  The whole band of a block is
+    here at once, so the softmax is the plain one (a row's max, its
+    exponentials, their sum), in f32, and the scores go nowhere."""
+    b, i = pl.program_id(0), pl.program_id(2)
+    Dh = k_ref.shape[-1]
+    span = window + block_q
+    first = pl.multiple_of(i * block_q, block_q)
+    k = k_ref[0, pl.ds(first, span), :]                       # [span, Dh]
+    v = v_ref[0, pl.ds(first, span), :]
+    # the query heads of this K/V head, stacked as rows: one product
+    q = jnp.concatenate([q_ref[0, :, g * Dh:(g + 1) * Dh]
+                         for g in range(group)], axis=0)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale    # [group bq, span]
+    at = start_ref[b] + first
+    seen = _band_seen(
+        at + jax.lax.broadcasted_iota(jnp.int32, (block_q, span), 0),
+        at - window + jax.lax.broadcasted_iota(jnp.int32, (block_q, span), 1),
+        window)
+    p, l = [], []
+    for g in range(group):
+        # a query sees the key at its own position: the max is a score's
+        sg = jnp.where(seen, s[g * block_q:(g + 1) * block_q], NEG_INF)
+        e = jnp.exp(sg - jnp.max(sg, axis=1, keepdims=True))
+        l.append(jnp.sum(e, axis=1, keepdims=True))
+        p.append(e.astype(v.dtype))
+    o = jax.lax.dot_general(
+        jnp.concatenate(p, axis=0), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) / jnp.concatenate(l, axis=0)
+    for g in range(group):
+        o_ref[0, :, g * Dh:(g + 1) * Dh] = o[
+            g * block_q:(g + 1) * block_q].astype(o_ref.dtype)
+
+
+def window_flash_attention_tpu(q, rows, ring, start, interpret: bool = False):
+    """A chunk's sliding-window attention over what the rows' rings held
+    before it, the scores never off the chip: ``q`` [B, T, H, Dh] at
+    positions ``start + 0 .. T - 1``, ``rows`` [B, T, 2 KV Dh] the
+    chunk's ``[K | V]`` of every K/V head, ``ring`` [B, W, 2 KV Dh]
+    (row ``p mod W`` holds position ``p``, the last W under ``start``)
+    -> [B, T, H, Dh].  T and W multiples of 128, Dh 128.
+
+    The ring is put in the order of its positions on the way in (a row
+    gather of W rows), so that the keys a block of queries can see are
+    ONE run of ``W + block`` rows of ``[ring | chunk]`` and the mask
+    needs no operand but ``start``.  A K/V head's rows stay in the fast
+    memory while its query blocks pass."""
+    B, T, H, Dh = q.shape
+    W, KV = ring.shape[1], rows.shape[-1] // (2 * Dh)
+    group, block_q = H // KV, 128
+    order = (start[:, None] + jnp.arange(W, dtype=jnp.int32)[None]) % W
+    kv = jnp.concatenate(
+        [jnp.take_along_axis(ring, order[..., None], axis=1,
+                             mode="promise_in_bounds").astype(rows.dtype),
+         rows], axis=1)                                  # [B, W + T, 2 KV Dh]
+    q_spec = pl.BlockSpec((1, block_q, group * Dh),
+                          lambda b, h, i, st: (b, i, h))
+    out = pl.pallas_call(
+        functools.partial(_window_fwd_kernel, scale=Dh ** -0.5, window=W,
+                          block_q=block_q, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, KV, T // block_q),
+            in_specs=[
+                q_spec,
+                pl.BlockSpec((1, W + T, Dh), lambda b, h, i, st: (b, 0, h)),
+                pl.BlockSpec((1, W + T, Dh),
+                             lambda b, h, i, st: (b, 0, KV + h)),
+            ],
+            out_specs=q_spec,
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, T, H * Dh), q.dtype),
+        interpret=interpret,
+        name="dstpu_window_flash_fwd",
+    )(start.astype(jnp.int32), q.reshape(B, T, H * Dh), kv, kv)
+    return out.reshape(B, T, H, Dh)

@@ -801,6 +801,26 @@ def test_state_space_cell_programs_fit_and_keep_pool_and_state_in_place(
                          hlo)
 
 
+def test_window_flash_kernel(chip):
+    """``dstpu_window_flash_fwd`` alone at the window cell's widths: a
+    chunk of 1,024 queries of 72 heads over 8 K/V heads of 128, the
+    slot's ring of 512 rows before it.  A K/V head's 1,536 rows of K and
+    of V and a block's ``[9 x 128, 640]`` f32 scores fit the fast memory
+    a kernel has without asking, and the scores are no value of the
+    program's."""
+    from deepspeed_tpu.ops.attention_pallas import window_flash_attention_tpu
+
+    bf, T, W, H, KV = jnp.bfloat16, 1024, 512, 72, 8
+    compiled = _compile(
+        window_flash_attention_tpu, chip, ((1, T, H, DH), bf),
+        ((1, T, 2 * KV * DH), bf), ((1, W, 2 * KV * DH), bf),
+        ((1,), jnp.int32))
+    hlo = compiled.as_text()
+    assert re.search(r"%dstpu_window_flash_fwd[\w.]* = .*tpu_custom_call",
+                     hlo)
+    assert not re.search(r"f32\[[0-9,]*,(640|1024)\]", hlo)
+
+
 # v44.laguna-s-2.1-ep16-d13.serve.code-sat as the benchmark builds it:
 # layer 0 and three periods S S S F at the published widths, 16 of 256
 # experts, an eighth of the vocabulary; 96 slots each with 18 MiB of rings
@@ -808,18 +828,20 @@ def test_state_space_cell_programs_fit_and_keep_pool_and_state_in_place(
 # layers over 30,721 pages of 16.
 _LAGUNA_PAGES, _LAGUNA_SLOTS, _LAGUNA_TABLE = 30721, 96, 18432 // PAGE
 # program -> (rows, tokens, table pages, bound on its temporaries in GiB:
-# AOT, PR 44, reads 0.030, 0.571, 0.813 and 0.274).  As first built: 1.07 at
+# AOT, PR 45, reads 0.030, 0.570, 0.813 and 0.274, as PR 44 did: the
+# largest are the full layers' gathered scores, not the band's, which
+# the kernel keeps on the chip since PR 45).  As first built: 1.07 at
 # decode (a transposed copy of W_q's stacks, 0.6 GiB, and a layer's 192
 # MiB of rings sliced out whole); the widest chunk program did not fit
 # (3.4 GiB of float32 scores, 48 heads x 1,024 x 18,432).  The program
 # over 256 pages is the widest that holds every head's scores at once
 # (0.75 GiB, under ``kernels._CHUNK_SCORE_BYTES``, 1.25)
 LAGUNA_PROGRAMS = {"decode": (_LAGUNA_SLOTS, 1, _LAGUNA_TABLE, 0.05),
-                   "chunk_full_table": (1, 1024, _LAGUNA_TABLE, 0.65),
-                   "chunk_256_pages": (1, 1024, 256, 0.9),
+                   "chunk_full_table": (1, 1024, _LAGUNA_TABLE, 0.6),
+                   "chunk_256_pages": (1, 1024, 256, 0.85),
                    # the narrowest that goes a K/V head at a time: whole,
                    # its 1.5 GiB of scores did not fit (v5e, PR 44)
-                   "chunk_512_pages": (1, 1024, 512, 0.35)}
+                   "chunk_512_pages": (1, 1024, 512, 0.3)}
 
 
 @pytest.mark.parametrize("program", LAGUNA_PROGRAMS)
@@ -832,8 +854,11 @@ def test_window_cell_programs_fit_and_keep_pool_and_rings_in_place(
     temporaries); they hold no copy of the pool, whose leading dimension
     is the four full layers; the rings are only ever the carried buffer,
     updated in place, and one layer of them (192 MiB) is never a value
-    of its own; no stack of the large weights is copied; and no float32
-    value is as large as every head's scores over the whole table."""
+    of its own; no stack of the large weights is copied; no float32
+    value is as large as every head's scores over the whole table; and a
+    chunk program's band runs in ``dstpu_window_flash_fwd`` (one call,
+    in the sliding layers' loop), its scores no value of the program's,
+    where the decode program has no such call."""
     from deepspeed_tpu.models import laguna as lg
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -909,6 +934,12 @@ def test_window_cell_programs_fit_and_keep_pool_and_rings_in_place(
     sizes = [math.prod(int(d) for d in dims.split(",") if d)
              for dims in re.findall(r"f32\[([0-9,]+)\]", hlo)]
     assert max(sizes) * 4 <= 0.76 * 2 ** 30
+    # the band: in the kernel, and nowhere an f32 value of its scores (a
+    # K/V head's nine query heads over a block pair, or one head's)
+    band = re.findall(r"%dstpu_window_flash_fwd[\w.]* = .*tpu_custom_call",
+                      hlo)
+    assert len(band) == (0 if program == "decode" else 1)
+    assert not re.search(r"f32\[[0-9,]*(4608|512),1024\]", hlo)
     if program == "decode":
         assert re.search(r"%dstpu_paged_decode[\w.]* = .*tpu_custom_call",
                          hlo)

@@ -347,6 +347,143 @@ def test_the_shares_add_up_to_the_uncut_layer(params):
                                np.asarray(whole), atol=2e-5, rtol=2e-5)
 
 
+# ------------------------------------ (ii b) the band's kernel, alone
+def _band_case(B, T, Wk, starts, dtype, seed=0, KV=2, G=3, Dh=128):
+    """Operands of a chunk's band at toy widths that meet the kernel's
+    shape rule (whole 128-row blocks, heads of 128): ``(cfg, q, rows,
+    ring, start)``.  The ring holds what the slot kept: row ``p mod W``
+    the ``[K | V]`` of position ``p`` for the last W positions under
+    ``start``, and large stale numbers where there was none."""
+    cfg = lg.LagunaConfig.tiny(sliding_window=Wk, n_kv_heads=KV,
+                               head_dim=Dh, n_heads_sliding=KV * G,
+                               n_heads_full=KV)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, T, KV * G, Dh), jnp.float32)
+    rows = jax.random.normal(ks[1], (B, T, 2 * KV * Dh), jnp.float32)
+    start = np.asarray(starts, np.int32)
+    past = np.asarray(jax.random.normal(
+        ks[2], (B, max(int(start.max()), 1), 2 * KV * Dh), jnp.float32))
+    ring = np.full((B, Wk, 2 * KV * Dh), 30.0, np.float32)
+    for b in range(B):
+        for pos in range(max(0, start[b] - Wk), start[b]):
+            ring[b, pos % Wk] = past[b, pos]
+    cast = lambda a: jnp.asarray(a).astype(dtype)
+    return cfg, cast(q), cast(rows), cast(ring), jnp.asarray(start)
+
+
+def _drop_the_lower_edge(qpos, kpos, window):
+    return (kpos <= qpos) & (kpos >= 0)
+
+
+@pytest.mark.parametrize("B,T,Wk,starts,real,dtype,fault", [
+    (1, 128, 128, (0,), 128, jnp.float32, None),
+    (1, 128, 128, (75,), 128, jnp.float32, None),
+    (1, 256, 128, (333,), 70, jnp.float32, None),
+    (2, 128, 128, (300, 5), 128, jnp.float32, None),
+    (1, 256, 256, (1000,), 256, jnp.float32, None),
+    (1, 256, 128, (128,), 256, jnp.float32, None),
+    (1, 256, 128, (201,), 256, jnp.bfloat16, None),
+    (1, 256, 128, (201,), 256, jnp.float32, _drop_the_lower_edge),
+], ids=["first_chunk", "ring_out_of_order", "padded_last_chunk",
+        "two_rows_two_starts", "T_equals_W", "T_twice_W", "bfloat16",
+        "planted_no_lower_edge"])
+def test_the_band_kernel_is_the_band_in_blocks(
+        monkeypatch, B, T, Wk, starts, real, dtype, fault):
+    """``dstpu_window_flash_fwd`` (interpret mode) against the band as
+    XLA runs it (``_band_in_blocks`` over ``_by_kv_head``: the same f32
+    scores and softmax, probabilities rounded for the value product) on
+    a slot's ring as it lies: float32 operands to 1e-5, bfloat16 ones to
+    the outputs' rounding.  With the window's lower edge dropped from
+    the kernel's own mask the comparison must fail: a band that sees
+    too far does not pass."""
+    from deepspeed_tpu.ops import attention_pallas as AP
+
+    cfg, q, rows, ring, start = _band_case(B, T, Wk, starts, dtype)
+    want = jax.jit(lambda *a: lg._band_in_blocks(cfg, *a))(
+        q, rows, ring, start)
+    if fault is not None:
+        monkeypatch.setattr(AP, "_band_seen", fault)
+    got = jax.jit(lambda *a: AP.window_flash_attention_tpu(
+        *a, interpret=True))(q, rows, ring, start)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    tol = dict(atol=1e-5, rtol=1e-5) if dtype == jnp.float32 \
+        else dict(atol=2e-2, rtol=2e-2)
+    close = lambda: np.testing.assert_allclose(
+        np.asarray(got[:, :real], np.float32),
+        np.asarray(want[:, :real], np.float32), **tol)
+    if fault is None:
+        close()
+    else:
+        with pytest.raises(AssertionError):
+            close()
+
+
+@pytest.mark.parametrize("tokens,window,head,interpret,reader", [
+    (1024, 512, 128, False, "pallas"), (128, 128, 128, False, "pallas"),
+    (1024, 512, 128, True, "xla"), (1, 512, 128, False, "xla"),
+    (0, 512, 128, False, "xla"), (1000, 512, 128, False, "xla"),
+    (1024, 8, 128, False, "xla"), (1024, 512, 64, False, "xla")])
+def test_the_band_reader_is_a_rule_of_backend_and_shapes(
+        tokens, window, head, interpret, reader):
+    from deepspeed_tpu.ops.attention import window_reader
+
+    got, why = window_reader(tokens=tokens, window=window, head_dim=head,
+                             interpret=interpret)
+    assert got == reader and why
+    cfg = lg.LagunaConfig.tiny(sliding_window=window, head_dim=head)
+    assert lg.FAMILY.recurrent.chunk_reader(cfg, tokens, interpret) \
+        == (got, why)
+
+
+def test_widths_the_rule_refuses_take_the_band_in_blocks(monkeypatch):
+    """On a TPU backend the tiny preset's chunk (16 tokens, window 8,
+    heads of 16) still runs XLA's band: the kernel is not reached, and
+    the chunk's output and ring are what they are on the CPU."""
+    from deepspeed_tpu.ops import attention_pallas as AP
+
+    def never(*a, **kw):
+        raise AssertionError("the kernel at widths its rule refuses")
+
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    B, T, KV, H, Dh = 1, 16, CFG.n_kv_heads, CFG.n_heads_sliding, \
+        CFG.head_dim
+    q = jax.random.normal(ks[0], (B, T, H, Dh))
+    k, v = (jax.random.normal(kk, (B, T, KV, Dh)) for kk in ks[1:3])
+    ring = jax.random.normal(ks[3], (B, W, 2 * KV * Dh))
+    args = (q, k, v, ring, jnp.asarray([13], jnp.int32),
+            jnp.asarray([11], jnp.int32))
+    want = lg.window_chunk(CFG, *args)
+    monkeypatch.setattr(AP, "window_flash_attention_tpu", never)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    got = lg.window_chunk(CFG, *args)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_a_chunk_at_the_kernels_widths_calls_it_on_a_tpu(monkeypatch):
+    """``window_chunk`` hands the kernel q, the chunk's rows and the
+    ring as they lie, and writes the ring itself whichever reader ran."""
+    from deepspeed_tpu.ops import attention_pallas as AP
+
+    cfg, q, rows, ring, start = _band_case(1, 128, 128, (75,), jnp.float32)
+    KV, Dh = cfg.n_kv_heads, cfg.head_dim
+    k = rows[..., :KV * Dh].reshape(1, 128, KV, Dh)
+    v = rows[..., KV * Dh:].reshape(1, 128, KV, Dh)
+    valid = jnp.asarray([100], jnp.int32)
+    want_o, want_ring = lg.window_chunk(cfg, q, k, v, ring, start, valid)
+    kernel = AP.window_flash_attention_tpu
+    monkeypatch.setattr(AP, "window_flash_attention_tpu",
+                        lambda *a: kernel(*a, interpret=True))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert lg.window_reader(cfg, 128, False)[0] == "pallas"
+    o, new_ring = lg.window_chunk(cfg, q, k, v, ring, start, valid)
+    np.testing.assert_allclose(np.asarray(o[:, :100]),
+                               np.asarray(want_o[:, :100]),
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(new_ring),
+                                  np.asarray(want_ring))
+
+
 # ------------------------------------------ (iii) through serving_engine
 def test_the_engine_serves_the_reference_argmax(params):
     """Scheduler, allocator, rings, boundary sampling: five requests
@@ -360,6 +497,8 @@ def test_the_engine_serves_the_reference_argmax(params):
     assert eng.cache.expert_rows.shape == (8,)
     assert eng.statusz()["kernels"]["state_step"] == "xla"
     assert eng.statusz()["kernels"]["fallbacks"] == []
+    assert eng.statusz()["kernels"]["window"] == {
+        "reader": "xla", "reason": "interpret: no TPU backend"}
     rng = np.random.default_rng(0)
     prompts = {i: rng.integers(0, CFG.vocab_size, n).tolist()
                for i, n in enumerate((37, 21, 5, 9, 33))}

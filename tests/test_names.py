@@ -390,6 +390,11 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
         "dstpu_fused_sample": lambda: jax.make_jaxpr(
             lambda: sampling_pallas.fused_greedy_rows(
                 jnp.zeros((8, 256), jnp.float32), interpret=True))(),
+        "dstpu_window_flash_fwd": lambda: jax.make_jaxpr(
+            lambda: attention_pallas.window_flash_attention_tpu(
+                q, jnp.zeros((1, 128, 256), jnp.float32),
+                jnp.zeros((1, 128, 256), jnp.float32), start[:1],
+                interpret=True))(),
         "dstpu_state_step": lambda: jax.make_jaxpr(
             lambda: K.state_step(
                 lambda S, row: (row, S + row),
@@ -400,11 +405,11 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
     for want, make in sites.items():
         names = _pallas_names(make().jaxpr, [])
         assert want in names, (want, names)
-    # the sources give thirteen sites thirteen names, none shared
+    # the sources give fourteen sites fourteen names, none shared
     named = []
     for mod in (K, adam_pallas, attention_pallas, quant, sampling_pallas):
         with open(mod.__file__) as f:
             text = f.read()
         assert text.count("pl.pallas_call(") == text.count('name="dstpu_')
         named += re.findall(r'name="(dstpu_[a-z0-9_]+)"', text)
-    assert len(named) == len(set(named)) == 13
+    assert len(named) == len(set(named)) == 14
