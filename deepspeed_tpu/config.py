@@ -448,12 +448,15 @@ class KernelsConfig:
     folklore).
 
     ``paged_attention`` picks the paged decode/chunk attention
-    implementation: ``auto`` (``kernels.paged_reader``, from the phase
-    and the layout alone, no size threshold: a decode program on one
-    device over float pages reads live pages only through the Mosaic
-    decode kernel at every batch and table width; chunk programs,
-    tensor parallelism, int8-resident pages and CPU/interpret runs take
-    the XLA gather), ``xla`` (always the gather reference
+    implementation: ``auto`` (``kernels.paged_reader``, from the phase,
+    the layout and the shapes, no size threshold: on one device over
+    float pages a decode program reads live pages only through the
+    Mosaic decode kernel at every batch and table width, and a chunk
+    program of whole 128-row blocks with heads of whole 128-lane tiles
+    runs the blocked Mosaic chunk reader up to each query block's own
+    frontier; tensor parallelism, int8-resident pages, CPU/interpret
+    runs and chunks off those shapes take the XLA gather), ``xla``
+    (always the gather reference
     composition), ``pallas_v1`` (the one-page-per-grid-step kernel,
     kept for A/B), or ``pallas_v2`` (force the DMA kernels, decode and
     chunk).  ``fused_sampling`` picks the boundary/decode sampler:

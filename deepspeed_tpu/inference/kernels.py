@@ -888,145 +888,145 @@ def paged_decode_attention_v2(q, k_pages, v_pages, table, seq_lens,
     return out[:, :, :G].reshape(B, H, Dh)
 
 
-# ----------------------------------- multi-page chunked-prefill kernel (v2)
+# ------------------------------------------ blocked chunk reader (v2)
+# Why the grid is (row, block of queries) with the K/V heads a loop inside:
+# a page of 16 rows is 4 KiB a K/V head, and starting a copy is ~0.07 us of
+# scalar work the products do not hide.  One strided copy a page for all
+# heads, shared by the heads' loop, is an eighth of a copy a page a head,
+# and wider blocks of queries sweep the pages fewer times; the price is
+# every head's running softmax in VMEM scratch (a tile a row a statistic:
+# as much as the f32 sum).
 def _chunk_v2_kernel(table_ref, start_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                     o_ref, *, scale, ps, kv_heads, max_pages, cg8, group,
-                     chunk, ppcb):
-    """The multi-page v2 chunk kernel (decode has its own,
-    :func:`_decode_kernel`): one grid step
-    per (batch, kv_head); K/V pages stream ppcb at a time through a
-    double-buffered VMEM scratch, and the page sweep stops at the last
-    page holding any position ``<= start + C - 1`` (history + chunk),
-    so pages past the frontier are never read.  Rows are the flattened
-    [C*G] chunk queries; row r sits at position start + r // G.  K/V are
-    the whole pool [L, KV, P, ps, Dh] in HBM; ``layer_ref[0]`` names the
-    layer whose pages the DMAs read, so no layer is sliced out first."""
-    bk = pl.program_id(0)
-    b = bk // kv_heads
-    h = bk % kv_heads
-    start = start_ref[b]
+                     o_ref, qs, acc, m_scr, l_scr, kb, vb, sem, *, scale, ps,
+                     group, chunk):
+    """One grid step a (row, block of ``bq`` queries).  The block sweeps
+    the row's pages ``ppb`` at a time up to ITS OWN frontier (``start +
+    (i + 1) bq``): each page's K (and V) for all K/V heads in one strided
+    copy out of the stored pool, as :func:`_decode_kernel`, through a
+    double-buffered scratch.  Nothing behind the frontier is dereferenced
+    (the last block's slots past it take the last live page again: masked
+    keys, finite values, and every block awaits one byte count), no key
+    block wholly above the diagonal is computed, and only the blocks that
+    reach past the block's first query are masked.  A K/V head's
+    ``group`` query heads are stacked as the rows of one product
+    (``qs[h]``: ``group * bq`` rows), operands in the dtype the pool
+    stores, f32 accumulation; the softmax runs across key blocks with
+    its max, sum and rescale in f32 (``m_scr``, ``l_scr``, ``acc``).
+    Every loop is rolled and traced once: the body's size is set-up time
+    in each of a build's chunk programs."""
+    b, i = pl.program_id(0), pl.program_id(1)
     layer = layer_ref[0]
-    live = start + chunk                            # positions 0..live-1
-    pages_live = (live + ps - 1) // ps
-    nch = (pages_live + ppcb - 1) // ppcb
+    kv, rows, dh = acc.shape
+    ppb = kb.shape[2]
+    bq, bk = rows // group, ppb * ps
+    first = start_ref[b] + i * bq           # the block's first position
+    pages_live = jnp.minimum(table_ref.shape[1], (   # a padded chunk may pass
+        start_ref[b] + jnp.minimum((i + 1) * bq, chunk) + ps - 1) // ps)  # it
+    nblk = (pages_live + ppb - 1) // ppb
+    n_open = (first + 1) // bk              # blocks every row sees whole
+    lanes = lambda h: pl.ds(pl.multiple_of(h * (group * dh), group * dh),
+                            group * dh)
 
-    def body(kb, vb, sem):
-        def chunk_dmas(c, slot):
-            dmas = []
-            for j in range(ppcb):                   # static unroll
-                p = c * ppcb + j
-                psafe = jnp.minimum(p, max_pages - 1)
-                pid = jnp.where(p < pages_live, table_ref[b, psafe], 0)
-                dmas.append(pltpu.make_async_copy(
-                    k_hbm.at[layer, h, pid],
-                    kb.at[slot, pl.ds(j * ps, ps), :], sem.at[slot, 0]))
-                dmas.append(pltpu.make_async_copy(
-                    v_hbm.at[layer, h, pid],
-                    vb.at[slot, pl.ds(j * ps, ps), :], sem.at[slot, 1]))
-            return dmas
+    def fetch(c, slot):
+        def page(j, _):
+            pid = table_ref[b, jnp.minimum(c * ppb + j, pages_live - 1)]
+            pltpu.make_async_copy(k_hbm.at[layer, :, pid],
+                                  kb.at[slot, :, j], sem.at[slot, 0]).start()
+            pltpu.make_async_copy(v_hbm.at[layer, :, pid],
+                                  vb.at[slot, :, j], sem.at[slot, 1]).start()
 
-        @pl.when(nch > 0)
+        jax.lax.fori_loop(0, ppb, page, None)
+
+    def stage(h, _):                        # the query heads, head-major
+        q = q_ref[0, :, lanes(h)]
+        qs[h] = jnp.concatenate([q[:, g * dh:(g + 1) * dh]
+                                 for g in range(group)], 0).astype(qs.dtype)
+        m_scr[h] = jnp.full(m_scr.shape[1:], NEG_INF, jnp.float32)
+        l_scr[h] = jnp.zeros(l_scr.shape[1:], jnp.float32)
+        acc[h] = jnp.zeros(acc.shape[1:], jnp.float32)
+
+    def head(masked, c, slot, h, _):
+        k = kb[slot, h].reshape(bk, dh).astype(qs.dtype)
+        v = vb[slot, h].reshape(bk, dh).astype(qs.dtype)
+        s = jax.lax.dot_general(
+            qs[h], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale       # [rows, bk]
+        if masked:
+            n = functools.partial(jax.lax.broadcasted_iota, jnp.int32, s.shape)
+            s = jnp.where(c * bk + n(1) <= first + jax.lax.rem(n(0), bq),
+                          s, NEG_INF)               # key <= the row's position
+        # block 0 holds position 0, which every query sees: m_new is a
+        # score's, and a masked entry's exponential is 0
+        m_new = jnp.maximum(m_scr[h], jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_scr[h] - m_new)
+        pr = jnp.exp(s - m_new)
+        l_scr[h] = l_scr[h] * alpha + jnp.sum(pr, axis=1, keepdims=True)
+        m_scr[h] = m_new
+        acc[h] = acc[h] * alpha + jax.lax.dot_general(
+            pr.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    def block(c, _):
+        slot = jax.lax.rem(c, 2)
+
+        @pl.when(c + 1 < nblk)
         def _():
-            for d in chunk_dmas(0, 0):
-                d.start()
+            fetch(c + 1, 1 - slot)
 
-        q = q_ref[0].astype(jnp.float32)            # [cg8, Dh]
+        for n, buf in enumerate((kb, vb)):  # a semaphore counts bytes
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                  sem.at[slot, n]).wait()
+        heads = lambda masked: lambda: jax.lax.fori_loop(
+            0, kv, functools.partial(head, masked, c, slot), None)
+        jax.lax.cond(c < n_open, heads(False), heads(True))
 
-        def loop(c, carry):
-            m, l, acc = carry
-            slot = jax.lax.rem(c, 2)
+    def finish(h, _):
+        l = jnp.where(l_scr[h] == 0.0, 1.0, l_scr[h])   # no key: zeros
+        o = (acc[h] / l).astype(o_ref.dtype)
+        o_ref[0, :, lanes(h)] = jnp.concatenate(
+            [o[g * bq:(g + 1) * bq] for g in range(group)], 1)
 
-            @pl.when(c + 1 < nch)
-            def _():
-                for d in chunk_dmas(c + 1, jax.lax.rem(c + 1, 2)):
-                    d.start()
+    @pl.when(nblk > 0)
+    def _():
+        fetch(0, 0)
 
-            for d in chunk_dmas(c, slot):
-                d.wait()
-            k = kb[slot].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            kpos = c * (ppcb * ps) + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            qpos = start + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0) // group
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            pr = jnp.exp(s - m_new)
-            # defensive: no current masking pattern can leave a whole
-            # block masked while m == NEG_INF (block 0 always holds
-            # kpos=0 <= qpos, and empty rows skip the loop via nch=0),
-            # but a future mask (e.g. segments) would turn that corner
-            # into pr == 1 row-wide — keep exp's masked entries at 0
-            pr = jnp.where(s > NEG_INF / 2, pr, 0.0)
-            l = l * alpha + jnp.sum(pr, axis=1, keepdims=True)
-            acc = acc * alpha + jax.lax.dot_general(
-                pr, vb[slot].astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return m_new, l, acc
-
-        init = (jnp.full((cg8, 1), NEG_INF, jnp.float32),
-                jnp.zeros((cg8, 1), jnp.float32),
-                jnp.zeros((cg8, q_ref.shape[2]), jnp.float32))
-        m, l, acc = jax.lax.fori_loop(0, nch, loop, init)
-        l = jnp.where(l == 0.0, 1.0, l)             # empty rows → zeros
-        o_ref[0] = (acc / l).astype(o_ref.dtype)
-
-    pl.run_scoped(
-        body,
-        kb=pltpu.VMEM((2, ppcb * ps, q_ref.shape[2]), k_hbm.dtype),
-        vb=pltpu.VMEM((2, ppcb * ps, q_ref.shape[2]), v_hbm.dtype),
-        sem=pltpu.SemaphoreType.DMA((2, 2)),
-    )
+    jax.lax.fori_loop(0, kv, stage, None)
+    jax.lax.fori_loop(0, nblk, block, None)
+    jax.lax.fori_loop(0, kv, finish, None)
 
 
-def paged_chunk_attention_v2(q, k_pages, v_pages, table, start,
-                             scale: Optional[float] = None,
-                             pages_per_block: int = 8,
-                             interpret: bool = False, layer=None):
-    """Multi-page chunked-prefill attention — same contract as
-    :func:`paged_chunk_attention_reference` (HBM-resident pages,
-    explicit double-buffered DMA, live-pages-only sweep)."""
-    B, C, H, Dh = q.shape
-    layer, k_pages, v_pages = _as_pool(layer, k_pages, v_pages)
-    _, KV, P, ps, _ = k_pages.shape
-    G = H // KV
-    mp = table.shape[1]
-    scale = scale if scale is not None else Dh ** -0.5
-    ppcb = max(1, min(pages_per_block, mp))
-    CG = C * G
-    cg8 = -(-CG // 8) * 8
-    qg = q.reshape(B, C, KV, G, Dh).transpose(0, 2, 1, 3, 4) \
-        .reshape(B * KV, CG, Dh)
-    if cg8 != CG:
-        qg = jnp.concatenate(
-            [qg, jnp.zeros((B * KV, cg8 - CG, Dh), q.dtype)], axis=1)
+# A K/V head's block of keys in VMEM (a buffer slot holds every head's),
+# and what a grid step of the chunk reader may hold there in all (a v5e
+# has 128 MiB, of which a kernel gets 16 without asking).
+_CHUNK_KEYS_BYTES, _CHUNK_STEP_BYTES = 256 << 10, 64 << 20
 
-    kernel = functools.partial(
-        _chunk_v2_kernel, scale=scale, ps=ps, kv_heads=KV, max_pages=mp,
-        cg8=cg8, group=G, chunk=C, ppcb=ppcb)
 
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,   # table, start, layer
-            grid=(B * KV,),
-            in_specs=[
-                pl.BlockSpec((1, cg8, Dh), lambda bk, *_: (bk, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, cg8, Dh), lambda bk, *_: (bk, 0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((B * KV, cg8, Dh), q.dtype),
-        interpret=interpret,
-        name="dstpu_paged_chunk_v2",
-    )(table, start, _layer_operand(layer), qg, k_pages, v_pages)
-    out = out[:, :CG].reshape(B, KV, C, G, Dh).transpose(0, 2, 1, 3, 4)
-    return out.reshape(B, C, H, Dh)
+def _chunk_step_bytes(bq: int, keys: int, heads: int, n_kv: int,
+                      head_dim: int, itemsize: int) -> int:
+    """VMEM a grid step holds: a query row's operand, f32 sum and two
+    statistics (a 128-lane tile a number) for every head, the q and o
+    blocks twice and one K/V head's f32 scores three times over; K and V
+    of a block of keys twice."""
+    return (bq * (heads * (head_dim * (5 * itemsize + 4) + 2 * 512)
+                  + 12 * heads // n_kv * keys)
+            + 4 * n_kv * keys * head_dim * itemsize)
+
+
+def chunk_blocks(heads: int, n_kv: int, head_dim: int, page_size: int,
+                 itemsize: int, chunk: int, max_pages: int):
+    """(queries, pages) a block of the chunk reader, from the shapes.
+    Keys: ``_CHUNK_KEYS_BYTES`` of a K/V head's (1,024 at a head of 128:
+    the running softmax's rescale is paid once a block of keys, and at
+    512 it was a third of a step, v5e).  Queries: 256 where they divide
+    the chunk and a step fits ``_CHUNK_STEP_BYTES``, else 128; off the
+    128-row rule the chunk is one block of whole sublanes."""
+    ppb = max(1, min(max_pages,
+                     _CHUNK_KEYS_BYTES // (head_dim * itemsize * page_size)))
+    if chunk % 128:
+        return -(-chunk // 8) * 8, ppb
+    wide = chunk % 256 == 0 and _CHUNK_STEP_BYTES >= _chunk_step_bytes(
+        256, ppb * page_size, heads, n_kv, head_dim, itemsize)
+    return (256 if wide else 128), ppb
 
 
 # ------------------------- int8-dequant-fused multi-page chunked kernel
@@ -1580,34 +1580,37 @@ def latent_attention_step(q, row, w_uk, w_uv, scale, pool, layer, table,
 
 # --------------------------------------------- shared per-layer dispatch
 def paged_reader(policy: Optional[str], *, decode: bool, tp: bool,
-                 interpret: bool, quant: bool) -> Tuple[str, str]:
+                 interpret: bool, quant: bool, tokens: int = 1,
+                 head_dim: int = 0) -> Tuple[str, str]:
     """Which reader a paged program's attention runs, and why: ("xla" |
-    "pallas_v1" | "pallas_v2", reason).  The one answer
-    ``forward_paged``, ``paged_layered_fns`` and the engine's
-    ``/statusz`` share.
+    "pallas_v1" | "pallas_v2", reason), the one answer ``forward_paged``,
+    ``paged_layered_fns`` and the engine's ``/statusz`` share.
 
-    A forced ``policy`` is itself (the engine build has already demoted
-    what cannot run: :func:`resolve_serving_kernels`).  ``auto`` (or
-    None) answers from the phase and the layout alone, no sizes, no env
-    reads: a decode program (``T == 1``) on one device over float pages
-    reads live pages only through the Mosaic kernel
-    (:func:`paged_decode_attention_v2`), whose cost follows the live
-    tokens where the gather's follows slots x ``max_seq``.  The gather
-    stays where the kernel cannot go: under tensor parallelism (the
-    kernel is per-device and the KV heads are sharded), over
-    int8-resident pages (the compiler refuses the quantized kernel) and
-    in ``interpret`` mode (no TPU: interpret-mode kernels are a
-    correctness harness).  Chunk programs (``T > 1`` over history) keep
-    the gather: a chunk reader is its own measurement."""
+    A forced ``policy`` is itself (the build has demoted what cannot
+    run: :func:`resolve_serving_kernels`).  ``auto`` (or None) answers
+    from the phase, the layout and the shapes, no env reads: on one
+    device over float pages a decode program (``T == 1``) reads live
+    pages only (:func:`paged_decode_attention_v2`), and so does a
+    continuation program of whole 128-row blocks of ``tokens`` with
+    heads of whole 128-lane tiles, each block of queries up to its own
+    frontier (:func:`paged_chunk_attention_v2`); the gather's cost
+    follows the table.  It stays under tensor parallelism (the KV heads
+    are sharded), over int8-resident pages (the compiler refuses that
+    kernel), in ``interpret`` mode and off those shapes (a head of 64)."""
     if policy not in (None, "auto"):
         return policy, "forced"
-    for off, why in ((not decode, "chunk program"),
-                     (tp, "tp: KV heads are sharded over the mesh"),
+    whole = lambda n: n > 0 and n % 128 == 0
+    for off, why in ((tp, "tp: KV heads are sharded over the mesh"),
                      (quant, "int8-resident pages"),
-                     (interpret, "interpret: no TPU backend")):
+                     (interpret, "interpret: no TPU backend"),
+                     (not (decode or whole(tokens)),
+                      "chunk program: its rows are not whole 128-row blocks"),
+                     (not (decode or whole(head_dim)),
+                      "chunk program: a head is not whole 128-lane tiles")):
         if off:
             return "xla", why
-    return "pallas_v2", "decode on one device over float pages"
+    return "pallas_v2", ("decode" if decode else "chunk in 128-row blocks") \
+        + " on one device over float pages"
 
 
 class ServingKernelPolicy(NamedTuple):
@@ -1627,12 +1630,14 @@ class ServingKernelPolicy(NamedTuple):
     state_step: str = "xla"
     # (reader, reason) of a window layer's chunk (ops.attention.window_reader)
     window: Tuple[str, str] = ("xla", "no window layer")
+    # (reader, reason) of a chunk program's attention over K/V pages
+    chunk: Tuple[str, str] = ("xla", "no chunk program")
 
     def as_dict(self) -> dict:
-        pair = lambda p: {"reader": p[0], "reason": p[1]}
+        pair = lambda k: dict(zip(("reader", "reason"), getattr(self, k)))
         return {
-            "paged_attention": self.paged_attention,
-            "decode": pair(self.decode), "window": pair(self.window),
+            "paged_attention": self.paged_attention, "decode": pair("decode"),
+            "chunk": pair("chunk"), "window": pair("window"),
             "state_step": self.state_step,
             "fused_sampling": self.fused_sampling,
             "env_overrides": [list(o) for o in self.env_overrides],
@@ -1662,7 +1667,8 @@ _QUANT_RESIDENT_PALLAS_REFUSAL = (
 def resolve_serving_kernels(kernels=None, *, tp: bool = False,
                             interpret: bool = False,
                             quantized_resident: bool = False,
-                            recurrent: bool = False) -> ServingKernelPolicy:
+                            recurrent: bool = False,
+                            chunk=(0, 0)) -> ServingKernelPolicy:
     """Resolve the serving kernel-dispatch policy ONCE, at engine build.
 
     ``kernels``: a ``KernelsConfig`` / dict / None (all-auto).  Env vars
@@ -1675,25 +1681,20 @@ def resolve_serving_kernels(kernels=None, *, tp: bool = False,
     ``DSTPU_FORCE_FUSED_SAMPLING=1`` (→ ``on``) keep working.
 
     A forced Pallas paged kernel under tensor parallelism is demoted to
-    ``xla`` with a recorded reason — the kernel dereferences the full
-    page table per (batch, kv_head) grid step and KV heads are sharded
-    over the mesh, so per-device it would read pages it does not hold;
-    the demotion is VISIBLE (``fallbacks`` row + the engine's
-    ``serving_kernel_fallbacks`` counter).
-
-    ``quantized_resident`` (the engine's ``kv_tier.quantized_resident``
-    cache layout) on a real chip (``interpret=False``): the compiler
-    refuses the int8-resident Pallas kernel, so a forced ``pallas_v2``
-    raises :class:`ServingKernelRefused` here at build, and ``auto``
-    resolves to ``xla`` with a ``fallbacks`` row.
-
+    ``xla`` VISIBLY (a kernel is one device's and reads a row's whole
+    page table, where the KV heads are sharded over the mesh): a
+    ``fallbacks`` row + the ``serving_kernel_fallbacks`` counter.  Over
+    ``quantized_resident`` pages (``kv_tier.quantized_resident``) on a
+    real chip (``interpret=False``) the compiler refuses the int8 kernel:
+    a forced ``pallas_v2`` raises :class:`ServingKernelRefused` here at
+    build, and ``auto`` resolves to ``xla`` with a ``fallbacks`` row.
     ``recurrent``: the family has recurrent layers, whose per-slot state
     a decode program steps in place through ``dstpu_state_step`` on one
     device (:func:`state_stepper`): never configured; where ``tp``
     demotes it ``state_step`` reads ``xla`` beside a ``fallbacks`` row.
-    (``window`` is the family's: ``serving_engine`` asks its
-    ``Recurrent.chunk_reader`` at the engine's chunk width.)
-
+    ``chunk``: the (tokens, head width) of the engine's chunk programs,
+    whose reader the ``chunk`` row names (``window`` is the family's:
+    ``serving_engine`` asks its ``Recurrent.chunk_reader``).
     An already-resolved :class:`ServingKernelPolicy` passes through:
     ``serving_engine`` resolves once, so the kernels the closures baked
     and the policy ``/statusz`` reports are one object."""
@@ -1766,20 +1767,19 @@ def resolve_serving_kernels(kernels=None, *, tp: bool = False,
     stepper, why = state_stepper(decode=recurrent, tp=tp)
     if recurrent and stepper != "pallas":
         fallbacks.append(("state_step=pallas", stepper, why))
+    reader = functools.partial(paged_reader, paged, tp=tp, interpret=interpret,
+                               quant=quantized_resident)
     return ServingKernelPolicy(
         paged_attention=paged, fused_sampling=fused,
         env_overrides=tuple(env_overrides), fallbacks=tuple(fallbacks),
-        decode=paged_reader(paged, decode=True, tp=tp, interpret=interpret,
-                            quant=quantized_resident),
-        state_step=stepper)
+        decode=reader(decode=True), state_step=stepper,
+        chunk=reader(decode=False, tokens=chunk[0], head_dim=chunk[1]))
 
 
 def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
                          continuation: bool, prefill: bool,
-                         paged_kernel: str,
-                         flash_force_reference: bool,
-                         interpret: bool = False,
-                         kps=None, vps=None):
+                         paged_kernel: str, flash_force_reference: bool,
+                         interpret: bool = False, kps=None, vps=None):
     """The per-layer paged-attention step every model family shares:
     page writes + the right attention for the phase, on the WHOLE pool.
 
@@ -1791,17 +1791,17 @@ def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
     a copy of it or of one layer (a per-layer store passes ``kp[None]``
     and layer 0: :func:`~deepspeed_tpu.inference.paged_forward.paged_layered_fns`).
     ``paged_kernel`` is the RESOLVED dispatch ("xla" | "pallas_v1" |
-    "pallas_v2" — :func:`paged_reader` decided before the trace; no
-    env reads here).  A forced Pallas kernel with ``interpret=True`` runs
-    in interpret mode — that is an explicit request and exactly how the
-    CPU identity gates exercise the kernels.  ``kps``/``vps`` non-None
-    selects the int8-resident path: kp/vp hold int8 codes, kps/vps the
-    per-token-row f32 scales, writes quantize on device, and
+    "pallas_v2": :func:`paged_reader`'s answer for this phase and these
+    shapes; no env reads here).  A forced Pallas kernel with
+    ``interpret=True`` runs in interpret mode: an explicit request, and
+    how the CPU identity gates exercise the kernels.  ``kps``/``vps``
+    non-None selects the int8-resident path: kp/vp hold int8 codes,
+    kps/vps the per-token-row f32 scales, writes quantize on device, and
     "pallas_v2" dispatches the dequant-fused kernel ("xla" gathers the
-    codes and dequantizes them with :func:`dequantize_pages`; there is
-    no quantized v1).  Phases: chunked-prefill continuation
-    (split-fuse), whole-prompt prefill (empty cache), or single-token
-    decode.  Returns (attn [B, T, H, Dh], kp, vp, kps, vps)."""
+    codes and dequantizes them, :func:`dequantize_pages`; no quantized
+    v1).  Phases: chunked-prefill continuation (split-fuse), whole-prompt
+    prefill (empty cache), or single-token decode.  Returns (attn [B, T,
+    H, Dh], kp, vp, kps, vps)."""
     from deepspeed_tpu.ops.attention import flash_attention
 
     quant = kps is not None
@@ -2142,3 +2142,58 @@ def paged_period_loop(period, x, stacks, cache: PagedKVCache, periods: int):
     return x, cache._replace(k=k, v=v, expert_rows=rows, conv=conv,
                              state=state)
 
+
+# ---- the blocked chunk reader's call (kernel and block sizes: above)
+def paged_chunk_attention_v2(q, k_pages, v_pages, table, start,
+                             scale: Optional[float] = None,
+                             pages_per_block: Optional[int] = None,
+                             interpret: bool = False, layer=None,
+                             block_q: Optional[int] = None):
+    """Blocked chunked-prefill attention on the chip — same contract as
+    :func:`paged_chunk_attention_reference`: the Mosaic kernel
+    ``dstpu_paged_chunk_v2`` (:func:`_chunk_v2_kernel`).  q [B, C, H, Dh]
+    goes in and the result comes out as the family's hooks hold them
+    (``[B, C, H Dh]``: a reshape, no copy); the pool stays in HBM in its
+    stored layout.  ``block_q`` and ``pages_per_block`` follow from the
+    shapes (:func:`chunk_blocks`); tests pass them to put a block edge
+    where they want one."""
+    B, C, H, Dh = q.shape
+    layer, k_pages, v_pages = _as_pool(layer, k_pages, v_pages)
+    _, KV, _, ps, _ = k_pages.shape
+    G, mp = H // KV, table.shape[1]
+    scale = scale if scale is not None else Dh ** -0.5
+    operand = jnp.promote_types(q.dtype, k_pages.dtype)
+    bq, ppb = chunk_blocks(H, KV, Dh, ps, operand.itemsize, C, mp)
+    bq, ppb = block_q or bq, min(mp, pages_per_block or ppb)
+    Cp = -(-C // bq) * bq           # whole blocks: a few rows pad to 8
+    qf = q.reshape(B, C, H * Dh)
+    if Cp != C:
+        qf = jnp.pad(qf, ((0, 0), (0, Cp - C), (0, 0)))
+    rows = G * bq
+    block = pl.BlockSpec((1, bq, H * Dh), lambda b, i, *_: (b, i, 0))
+    out = pl.pallas_call(
+        functools.partial(_chunk_v2_kernel, scale=scale, ps=ps, group=G,
+                          chunk=C),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,   # table, start, layer
+            grid=(B, Cp // bq),
+            in_specs=[block, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=block,
+            scratch_shapes=[
+                pltpu.VMEM((KV, rows, Dh), operand),
+                pltpu.VMEM((KV, rows, Dh), jnp.float32),
+                pltpu.VMEM((KV, rows, 1), jnp.float32),
+                pltpu.VMEM((KV, rows, 1), jnp.float32),
+                pltpu.VMEM((2, KV, ppb, ps, Dh), k_pages.dtype),
+                pltpu.VMEM((2, KV, ppb, ps, Dh), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, Cp, H * Dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=(8 << 20) + (
+            _chunk_step_bytes(bq, ppb * ps, H, KV, Dh, operand.itemsize))),
+        interpret=interpret,
+        name="dstpu_paged_chunk_v2",
+    )(table, start, _layer_operand(layer), qf, k_pages, v_pages)
+    return out[:, :C].reshape(B, C, H, Dh)

@@ -37,15 +37,15 @@ def _interpret(interpret: Optional[bool]) -> bool:
 
 def _paged_block(fam, out, cfg, x, lp, ctx, layer, kp, vp, kps, vps, rows,
                  table, start, *, continuation: bool, prefill: bool,
-                 paged_kernel: str, tp: bool, interpret: bool):
+                 paged_kernel, tp: bool, interpret: bool):
     """One layer over the pool ``kp``/``vp`` ``[L, KV, P, ps, Dh]`` (a
-    latent family: ``kp`` ``[L, 1, P, ps, C + Dr]`` alone).  ``out`` is
-    the layer kind's second half: the family's, or its leading stack's."""
+    latent family: ``kp`` ``[L, 1, P, ps, C + Dr]`` alone), ``out`` the layer
+    kind's second half, ``paged_kernel(head_dim=)`` ``paged_reader``'s word."""
     B, T = x.shape[:2]
     q, k, v = fam.qkv(cfg, x, lp, *ctx)
-    phase = dict(continuation=continuation, prefill=prefill,
-                 paged_kernel=paged_kernel, flash_force_reference=tp,
-                 interpret=interpret)
+    phase = dict(continuation=continuation, prefill=prefill, paged_kernel=(
+        paged_kernel(head_dim=fam.cache_row(cfg).head_width or q.shape[-1])
+        )[0], flash_force_reference=tp, interpret=interpret)
     if fam.latent is not None:
         attn, kp = latent_attention_step(
             q, k, *fam.latent(cfg, lp), kp, layer, table, start, **phase)
@@ -239,11 +239,11 @@ def forward_paged(params, tokens, cfg, cache, *,
     budget and the host discards it (the engine bounds real positions
     by ``max_seq``, and the family's ``check`` bounds ``max_seq``).
 
-    ``paged_kernel``: the paged-attention policy the serving build
-    resolved (``resolve_serving_kernels``): a forced "xla" | "pallas_v1"
-    | "pallas_v2", or None/"auto", which ``paged_reader`` answers from
-    the phase and the layout (decode on one device over float pages
-    reads live pages through the Mosaic kernel; else the gather).  A
+    ``paged_kernel``: the build's policy (``resolve_serving_kernels``): a
+    forced "xla" | "pallas_v1" | "pallas_v2", or None/"auto", which
+    ``paged_reader`` answers a layer from the phase, the layout and the
+    shapes (on one device over float pages the Mosaic readers: decode's,
+    and a chunk's at whole 128-row blocks and 128-lane heads; else XLA).  A
     cache carrying ``k_scale`` planes is int8-resident
     (``kv_tier.quantized_resident``): writes quantize per token row on
     device and attention dequantizes in VMEM ("pallas_v2") or via
@@ -268,9 +268,9 @@ def forward_paged(params, tokens, cfg, cache, *,
                 jax.errors.ConcretizationTypeError):
             pass  # traced: caller's responsibility
     x, ctx = fam.embed(params, tokens, start, cfg)
-    paged_kernel, _ = paged_reader(
-        paged_kernel, decode=T == 1, tp=tp, interpret=interpret,
-        quant=cache.k_scale is not None)
+    paged_kernel = functools.partial(       # a layer's, by its head's width
+        paged_reader, paged_kernel, decode=T == 1, tp=tp, interpret=interpret,
+        quant=cache.k_scale is not None, tokens=T)
 
     def block(out, whole=None, first=0):
         def run(x, lp, layer, kp, vp, kps, vps, rows):
@@ -337,10 +337,10 @@ def paged_layered_fns(cfg, *, tp: bool = False,
 
     def block_fn(lp, x, ctx, kp, vp, table, start, *,
                  continuation: bool, prefill: bool):
-        lp = dequantize_params(lp)
-        itp = _interpret(interpret)
-        pk, _ = paged_reader(paged_kernel, decode=x.shape[1] == 1, tp=tp,
-                             interpret=itp, quant=False)
+        lp, itp = dequantize_params(lp), _interpret(interpret)
+        pk = functools.partial(
+            paged_reader, paged_kernel, decode=x.shape[1] == 1, tp=tp,
+            interpret=itp, quant=False, tokens=x.shape[1])
         x, kp, vp, _, _, _ = _paged_block(
             fam, fam.out, cfg, x, lp, ctx, 0, kp[None], vp[None], None,
             None, None, table, start, continuation=continuation,

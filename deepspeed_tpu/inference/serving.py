@@ -3810,10 +3810,8 @@ def serving_engine(params, cfg, **kw):
     # re-read the mutable ambient mesh on a later retrace (a cleared one
     # would silently re-enable pallas kernels over the sharded cache)
     sharded = fam.sharded(mesh)
-    # the kernel policy resolves HERE (config + env, once), with the SAME
-    # predicates the engine uses, and passes through to the engine: what
-    # the closures bake and what /statusz reports are one object.  A
-    # family's own two words (a window layer's chunk) join it below
+    # the kernel policy resolves HERE (config + env, once) with the engine's
+    # predicates: what the closures bake and /statusz reports are one object
     kw["kernels"] = resolve_serving_kernels(
         kw.get("kernels"),
         tp=mesh is not None and any(
@@ -3821,7 +3819,9 @@ def serving_engine(params, cfg, **kw):
         interpret=jax.default_backend() != "tpu",
         quantized_resident=kvt.enabled and kvt.quantized_resident,
         recurrent=fam.recurrent is not None
-        and fam.recurrent.state_row(cfg).state is not None)
+        and fam.recurrent.state_row(cfg).state is not None, chunk=(
+            kw.get("prefill_chunk") or 0, fam.cache_row(cfg).head_width
+            or fam.cache_row(cfg).key_width))
     pk = kw["kernels"].paged_attention
     if fam.latent is not None:
         kw["kernels"] = kw["kernels"]._replace(

@@ -26,23 +26,23 @@ def _no_check(cfg, mesh, max_seq) -> None:
 
 class CacheRow(NamedTuple):
     """What one token leaves in the page pool, a layer: ``n_kv`` rows of
-    ``key_width`` numbers that attention reads as keys and, where
-    ``values_in_keys``, whose first ``value_width`` numbers it reads as
-    values too (one pool, no V pool: a latent row ``[c | k_rope]``);
-    otherwise a second pool holds ``value_width`` wide value rows."""
+    ``key_width`` numbers read as keys and, where ``values_in_keys``,
+    their first ``value_width`` as values too (one pool, no V pool: a
+    latent row ``[c | k_rope]``), else a second pool of ``value_width``
+    wide value rows.  ``head_width``: a head's own numbers where the row
+    pads them with zeros to whole tiles (0: all of ``key_width``)."""
 
     n_kv: int
     key_width: int
     value_width: int
     values_in_keys: bool = False
+    head_width: int = 0
 
     @property
     def pool_width(self) -> int:
-        """Numbers a row takes in the pool: a shared row is stored in
-        whole 128-lane tiles, zeros behind (the TPU lays 576 numbers out
-        in 640 lanes whatever is declared, and declared as 576 the
-        compiler re-lays the pool in a copy and Mosaic refuses the page
-        slice: ``inference/kernels.py``, the latent pages)."""
+        """Numbers a row takes in the pool: a shared row lies in whole
+        128-lane tiles, zeros behind (declared 576 wide the compiler re-lays
+        the pool in a copy and Mosaic refuses the page slice: ``kernels``)."""
         if not self.values_in_keys:
             return self.key_width
         return -(-self.key_width // 128) * 128

@@ -463,7 +463,7 @@ FAMILY = DecoderFamily(
     head=_head, param_specs=param_specs, quant_skip_paths=_EXACT,
     check=_check,
     cache_row=lambda cfg: CacheRow(cfg.n_kv_heads, cfg.kv_width,
-                                   cfg.kv_width),
+                                   cfg.kv_width, head_width=cfg.head_dim),
     recurrent=Recurrent(key="ssm_blocks", period=_period, mix=ssm_mix,
                         out=_ssm_out, state_row=_state_row,
                         write_scope="ssm_write"),

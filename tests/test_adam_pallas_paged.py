@@ -502,6 +502,51 @@ class TestPagedChunkV2:
         np.testing.assert_allclose(np.asarray(pert), np.asarray(base),
                                    atol=1e-6)
 
+    def test_a_query_block_stops_at_its_own_frontier(self):
+        """Each block of queries sweeps pages up to ITS frontier, not
+        the chunk's: values that are not numbers in the page behind the
+        first block's frontier (the second block's own rows) leave the
+        first block's rows as they were, where a mask alone would give
+        0 x NaN; the second block's rows, which read the page, show the
+        perturbation was live."""
+        from deepspeed_tpu.inference.kernels import paged_chunk_attention_v2
+
+        rng = np.random.default_rng(6)
+        B, C, H, KV, P, ps, Dh, mp = 1, 8, 4, 2, 8, 4, 8, 4
+        k, v = self._pages(rng, KV, P, ps, Dh)
+        table = jnp.asarray([[0, 1, 2, 3]], jnp.int32)
+        start = jnp.asarray([4], jnp.int32)    # blocks at 4..7 and 8..11
+        q = jnp.asarray(rng.normal(size=(B, C, H, Dh)), jnp.float32)
+        run = lambda v: np.asarray(paged_chunk_attention_v2(
+            q, k, v, table, start, pages_per_block=1, block_q=4,
+            interpret=True))
+        base, pert = run(v), run(v.at[:, 2].set(jnp.nan))  # positions 8..11
+        np.testing.assert_array_equal(pert[:, :4], base[:, :4])
+        assert np.isfinite(base).all() and np.isnan(pert[:, 4:]).all()
+
+    def test_a_padded_chunk_past_the_tables_end_stays_in_the_table(self):
+        """A prompt's last chunk is padded to C rows and may pass the
+        row's table (``_advance_prefill`` clamps the width at the row):
+        the sweep stops at the table's last entry, the rows inside the
+        table read what the gather reads, and the padding's rows (which
+        the host discards) stay finite."""
+        from deepspeed_tpu.inference.kernels import (
+            paged_chunk_attention_reference, paged_chunk_attention_v2)
+
+        rng = np.random.default_rng(8)
+        B, C, H, KV, P, ps, Dh = 1, 8, 4, 2, 8, 4, 8
+        k, v = self._pages(rng, KV, P, ps, Dh)
+        table = jnp.asarray([[5, 2, 7]], jnp.int32)     # 12 keys
+        start = jnp.asarray([8], jnp.int32)             # rows at 8..15
+        q = jnp.asarray(rng.normal(size=(B, C, H, Dh)), jnp.float32)
+        ref = paged_chunk_attention_reference(q, k, v, table, start)
+        out = np.asarray(paged_chunk_attention_v2(
+            q, k, v, table, start, pages_per_block=2, block_q=4,
+            interpret=True))
+        np.testing.assert_allclose(out[:, :4], np.asarray(ref)[:, :4],
+                                   atol=1e-5)
+        assert np.isfinite(out).all()
+
     def test_causal_within_chunk(self):
         """Row i must not see the chunk's rows j > i (per-row frontier,
         not a block frontier)."""

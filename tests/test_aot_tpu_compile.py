@@ -270,6 +270,17 @@ def _shaped_like(hlo, *dims):
                    and d.endswith(f",{dims[-1]}")})
 
 
+def _blocked_chunk_reader(hlo, table_rows=None):
+    """A chunk program's attention over K/V pages runs in the blocked
+    Mosaic reader, by name; and, over a table of ``table_rows`` keys,
+    no f32 value has that count as a dimension: the gathered reader's
+    scores (and its gathered K and V) are gone from the program."""
+    assert re.search(r"%dstpu_paged_chunk_v2[\w.]* = .*tpu_custom_call", hlo)
+    if table_rows:
+        assert not re.search(
+            rf"f32\[(?:[0-9]+,)*{table_rows}(?:,[0-9]+)*\]", hlo)
+
+
 @pytest.mark.parametrize("phase", PHASES)
 @pytest.mark.parametrize("pool", POOLS)
 def test_serving_program_leaves_the_pool_in_place(chip, pool, phase):
@@ -286,9 +297,9 @@ def test_serving_program_leaves_the_pool_in_place(chip, pool, phase):
     holds the Mosaic decode kernel at every engine (28 x 64 table
     entries, 64 x 64, 6 x 520) and nothing shaped like the gathered copy
     of every slot's whole table row ``[B, KV, max_pages * ps, Dh]``.
-    The chunk program keeps the XLA gather; the bound on its temporaries
-    holds that gather to bf16: after a clamped gather the compiler
-    writes the gathered K and V out in f32 (``kernels._gather_rows``)."""
+    The chunk program (128 rows, heads of 128) holds the blocked chunk
+    reader and no f32 value over the table's keys; a whole-prompt
+    prefill reads no page."""
     family, make_cfg, pages, batch, table, decode_temp_gib = POOLS[pool]
     rows, T, continuation = PHASES[phase]
     rows = rows or batch
@@ -334,6 +345,8 @@ def test_serving_program_leaves_the_pool_in_place(chip, pool, phase):
         assert _shaped_like(hlo, rows, cfg.n_kv_heads, table * PAGE,
                             DH) == []
         assert temp <= decode_temp_gib * 2 ** 30
+    elif phase == "chunk":
+        _blocked_chunk_reader(hlo, table * PAGE)
     else:
         assert "dstpu_paged" not in hlo
     pool_bytes = 2 * math.prod(shape) * 2               # K and V, bf16
@@ -342,10 +355,12 @@ def test_serving_program_leaves_the_pool_in_place(chip, pool, phase):
 
 # ------------------------------------- docs-sat's chunk of 1,024 tokens
 # table entries -> bound on the temporaries in GiB.  Over the chunk's own
-# 64 pages the every-expert-every-row program held 0.228 GiB and this one
-# holds 0.126; over the full table both hold the f32 scores of 1,024
-# queries against 8,320 gathered keys, 1.040 and 1.049 GiB (AOT, PR 34).
-@pytest.mark.parametrize("table,temp_gib", [(64, 0.14), (520, 1.06)],
+# 64 pages the every-expert-every-row program held 0.228 GiB and the
+# grouped one 0.126; over the full table both held the f32 scores of
+# 1,024 queries against 8,320 gathered keys, 1.040 and 1.049 GiB (AOT, PR
+# 34).  Since PR 46 the blocked chunk reader keeps the scores on the chip
+# and both tables' programs hold 0.123 GiB, what the FFN leaves (AOT).
+@pytest.mark.parametrize("table,temp_gib", [(64, 0.14), (520, 0.14)],
                          ids=["first_chunk", "full_table"])
 def test_mixtral_chunk_program_groups_the_rows_by_expert(
         chip, monkeypatch, table, temp_gib):
@@ -354,7 +369,9 @@ def test_mixtral_chunk_program_groups_the_rows_by_expert(
     Mosaic grouped products over the 2,048 (row, expert) pairs the
     router chose, read out of the whole stack in place; nothing shaped
     like every expert's answer for every row ``[8, 1024, 14336]`` is
-    left and no layer's 2.8 GB of experts is copied out of the stack."""
+    left and no layer's 2.8 GB of experts is copied out of the stack.
+    Its attention over history is the blocked chunk reader's, and no f32
+    value over the table's 8,320 keys is left."""
     # the grouped product asks the backend which kernel to run; the
     # described chip is not the default backend
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -386,6 +403,7 @@ def test_mixtral_chunk_program_groups_the_rows_by_expert(
     assert _shaped_like(hlo, cfg.num_experts, cfg.dim, cfg.ffn_dim) == []
     assert "dynamic-slice_bitcast_fusion" not in hlo
     assert _pool_sized_ops(hlo, shape) == []
+    _blocked_chunk_reader(hlo, table * PAGE if table * PAGE != T else None)
     assert memory.temp_size_in_bytes <= temp_gib * 2 ** 30
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 15.75 * 2 ** 30
@@ -539,12 +557,14 @@ def test_state_step_kernel(chip, family):
 _QWEN = dict(vocab_size=18992, n_layers=12, experts_held=(0, 64))
 _QWEN_PAGES, _QWEN_SLOTS, _QWEN_TABLE = 65537, 96, 17408 // PAGE
 # program -> (rows, tokens, table entries, bound on its temporaries in
-# GiB: AOT, PR 40, reads 0.070, 1.266 and 0.340 (PR 35: 0.070, 1.247 and
-# 0.337: the attention layers' gathered K/V and scores are the peak, not
-# the pair buffer); 0.26-0.29, 1.26 and 0.55-0.61 while the outer loop
-# sliced a period of the linear layers' weights out of their stack)
+# GiB: AOT, PR 46, reads 0.066, 0.339 and 0.340: with the blocked chunk
+# reader a chunk program holds what the recurrent rule and the FFN leave
+# at every table width (PR 40: 0.070, 1.266 and 0.340: the attention
+# layers' gathered K/V and f32 scores were the widest program's peak);
+# 0.26-0.29, 1.26 and 0.55-0.61 while the outer loop sliced a period of
+# the linear layers' weights out of their stack)
 QWEN_PROGRAMS = {"decode": (_QWEN_SLOTS, 1, _QWEN_TABLE, 0.1),
-                 "chunk_full_table": (1, 1024, _QWEN_TABLE, 1.3),
+                 "chunk_full_table": (1, 1024, _QWEN_TABLE, 0.36),
                  "chunk_first": (1, 1024, 64, 0.36)}
 
 
@@ -633,7 +653,9 @@ def test_recurrent_cell_programs_fit_and_keep_pool_and_state_in_place(
     of one layer of it: a decode step hands the carried buffer to
     ``dstpu_state_step``, which reads and writes a layer's 96 states in
     place, a tile at a time; a layer's experts are read in place; the
-    kernels run by name."""
+    kernels run by name, a chunk's attention over its history (heads of
+    256, groups of 8) in the blocked chunk reader with no f32 value over
+    the table's 17,408 keys."""
     from deepspeed_tpu.models import qwen3_next as qn
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -701,6 +723,8 @@ def test_recurrent_cell_programs_fit_and_keep_pool_and_state_in_place(
                          hlo)
     else:
         assert re.search(r"%gmm[\w.]* = .*tpu_custom_call", hlo)
+        _blocked_chunk_reader(hlo, table * PAGE if table * PAGE != T
+                              else None)
 
 
 # v42.granite-4.0-h-micro.serve.assist-sat as the benchmark builds it:
@@ -799,6 +823,10 @@ def test_state_space_cell_programs_fit_and_keep_pool_and_state_in_place(
     if program == "decode":
         assert re.search(r"%dstpu_paged_decode[\w.]* = .*tpu_custom_call",
                          hlo)
+    else:
+        # a head is 64 numbers in a 128-lane tile (``CacheRow.head_width``):
+        # the shape rule leaves this chunk program the gather, as it was
+        assert "dstpu_paged_chunk_v2" not in hlo
 
 
 def test_window_flash_kernel(chip):
@@ -828,37 +856,38 @@ def test_window_flash_kernel(chip):
 # layers over 30,721 pages of 16.
 _LAGUNA_PAGES, _LAGUNA_SLOTS, _LAGUNA_TABLE = 30721, 96, 18432 // PAGE
 # program -> (rows, tokens, table pages, bound on its temporaries in GiB:
-# AOT, PR 45, reads 0.030, 0.570, 0.813 and 0.274, as PR 44 did: the
-# largest are the full layers' gathered scores, not the band's, which
-# the kernel keeps on the chip since PR 45).  As first built: 1.07 at
-# decode (a transposed copy of W_q's stacks, 0.6 GiB, and a layer's 192
-# MiB of rings sliced out whole); the widest chunk program did not fit
-# (3.4 GiB of float32 scores, 48 heads x 1,024 x 18,432).  The program
-# over 256 pages is the widest that holds every head's scores at once
-# (0.75 GiB, under ``kernels._CHUNK_SCORE_BYTES``, 1.25)
+# AOT, PR 46, reads 0.030 and 0.107 at all three table widths: the full
+# layers' scores stay on the chip in the blocked chunk reader, as the
+# band's do since PR 45, and what is left is the FFN's (PR 45: 0.030,
+# 0.570, 0.813 and 0.274, the gathered reader's f32 scores: every head's
+# over 256 pages, a K/V head's at a time from 512 pages on, under
+# ``kernels._CHUNK_SCORE_BYTES``, which no cell's program reaches now).
+# As first built: 1.07 at decode (a transposed copy of W_q's stacks, 0.6
+# GiB, and a layer's 192 MiB of rings sliced out whole); the widest chunk
+# program did not fit (3.4 GiB of float32 scores, 48 x 1,024 x 18,432)
 LAGUNA_PROGRAMS = {"decode": (_LAGUNA_SLOTS, 1, _LAGUNA_TABLE, 0.05),
-                   "chunk_full_table": (1, 1024, _LAGUNA_TABLE, 0.6),
-                   "chunk_256_pages": (1, 1024, 256, 0.85),
-                   # the narrowest that goes a K/V head at a time: whole,
-                   # its 1.5 GiB of scores did not fit (v5e, PR 44)
-                   "chunk_512_pages": (1, 1024, 512, 0.3)}
+                   "chunk_full_table": (1, 1024, _LAGUNA_TABLE, 0.12),
+                   "chunk_256_pages": (1, 1024, 256, 0.12),
+                   "chunk_512_pages": (1, 1024, 512, 0.12)}
 
 
 @pytest.mark.parametrize("program", LAGUNA_PROGRAMS)
 def test_window_cell_programs_fit_and_keep_pool_and_rings_in_place(
         chip, monkeypatch, program):
-    """The decode program, the widest chunk program and the chunk
-    program with the largest temporaries of the window family's cell, at
-    the cell's sizes: they compile for the described v5e (5.35 GiB of
+    """The decode program and three chunk programs (the widest table
+    and two narrower buckets) of the window family's cell, at the
+    cell's sizes: they compile for the described v5e (5.35 GiB of
     weights, 1.69 GiB of rings and a 7.5 GiB pool beside their
     temporaries); they hold no copy of the pool, whose leading dimension
     is the four full layers; the rings are only ever the carried buffer,
     updated in place, and one layer of them (192 MiB) is never a value
     of its own; no stack of the large weights is copied; no float32
-    value is as large as every head's scores over the whole table; and a
+    value is as large as one K/V head's scores over the whole table; a
     chunk program's band runs in ``dstpu_window_flash_fwd`` (one call,
-    in the sliding layers' loop), its scores no value of the program's,
-    where the decode program has no such call."""
+    in the sliding layers' loop) and its full layers' attention over
+    history in ``dstpu_paged_chunk_v2`` (one call, in theirs), their
+    scores no value of the program's, where the decode program has
+    neither call."""
     from deepspeed_tpu.models import laguna as lg
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -928,12 +957,12 @@ def test_window_cell_programs_fit_and_keep_pool_and_rings_in_place(
         assert [(n, o) for n, o, _ in _top_level_results(hlo, stack)
                 if not o.startswith(("copy-start", "copy-done"))] == [], \
             stack
-    # every head's scores over the whole table would be 3.4 GiB; what is
-    # held is one K/V head's query heads over it (0.42), or every head's
-    # over 256 pages (0.75)
+    # every head's scores over the whole table would be 3.4 GiB and one
+    # K/V head's query heads over it 0.42: neither is held (the largest
+    # f32 value is a chunk's logits, 1,024 x 12,544: 0.048 GiB, AOT, PR 46)
     sizes = [math.prod(int(d) for d in dims.split(",") if d)
              for dims in re.findall(r"f32\[([0-9,]+)\]", hlo)]
-    assert max(sizes) * 4 <= 0.76 * 2 ** 30
+    assert max(sizes) * 4 <= 0.1 * 2 ** 30
     # the band: in the kernel, and nowhere an f32 value of its scores (a
     # K/V head's nine query heads over a block pair, or one head's)
     band = re.findall(r"%dstpu_window_flash_fwd[\w.]* = .*tpu_custom_call",
@@ -943,6 +972,8 @@ def test_window_cell_programs_fit_and_keep_pool_and_rings_in_place(
     if program == "decode":
         assert re.search(r"%dstpu_paged_decode[\w.]* = .*tpu_custom_call",
                          hlo)
+    else:
+        _blocked_chunk_reader(hlo, width * PAGE)
 
 
 # ------------------------------------------- ZeRO-3 over the four chips

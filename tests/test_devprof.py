@@ -134,7 +134,7 @@ def test_the_frames_under_a_builds_lowering_keep_their_words():
         "TrainingEngine.__init__": words(TrainingEngine.__init__),
         "train_batch": words(TrainingEngine.train_batch),
     } == {
-        "serving_engine": 36, "ServingEngine.__init__": 78,
+        "serving_engine": 37, "ServingEngine.__init__": 78,
         "_devprof_warmup": 31, "_SentinelFn.__call__": 12,
         "initialize": 30, "TrainingEngine.__init__": 39,
         "train_batch": 10,

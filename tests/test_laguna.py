@@ -499,6 +499,8 @@ def test_the_engine_serves_the_reference_argmax(params):
     assert eng.statusz()["kernels"]["fallbacks"] == []
     assert eng.statusz()["kernels"]["window"] == {
         "reader": "xla", "reason": "interpret: no TPU backend"}
+    assert eng.statusz()["kernels"]["chunk"] == {
+        "reader": "xla", "reason": "interpret: no TPU backend"}
     rng = np.random.default_rng(0)
     prompts = {i: rng.integers(0, CFG.vocab_size, n).tolist()
                for i, n in enumerate((37, 21, 5, 9, 33))}

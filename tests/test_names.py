@@ -337,14 +337,46 @@ def test_train_step_carries_model_and_step_scopes(name):
         assert ("optimizer", True) not in marks
 
 
-def _pallas_names(jaxpr, out):
+def _pallas_scopes(jaxpr, out):
+    """(kernel name, the scope path it was traced under) of every
+    ``pallas_call`` in ``jaxpr``."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             info = eqn.params.get("name_and_src_info")
-            out.append(getattr(info, "name", None) or eqn.params.get("name"))
+            out.append((getattr(info, "name", None) or eqn.params.get("name"),
+                        str(eqn.source_info.name_stack)))
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            _pallas_names(sub, out)
+            _pallas_scopes(sub, out)
     return out
+
+
+@pytest.mark.parametrize("tokens,kernel", [(128, "dstpu_paged_chunk_v2"),
+                                           (1, "dstpu_paged_decode")])
+def test_the_paged_readers_run_by_name_under_kv_attend(tokens, kernel):
+    """A chunk program traced as the chip would (whole 128-row blocks,
+    heads of 128, ``interpret=False``) holds the blocked reader under its
+    ``dstpu_`` name inside the scope ``kv_attend``, which is how the
+    harness bins it (``benchmark/tests/test_scopes.py`` reads the same
+    name) and how ``breakdown.device_ops`` shows that a build engaged
+    it; a decode program its own."""
+    from deepspeed_tpu.inference.kernels import PagedKVCache
+    from deepspeed_tpu.inference.paged_forward import forward_paged
+    from deepspeed_tpu.models import llama
+
+    cfg = llama.LlamaConfig(vocab_size=64, dim=512, n_layers=2, n_heads=4,
+                            n_kv_heads=2, ffn_dim=64, max_seq_len=512)
+    S = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: llama.init_params(
+        jax.random.PRNGKey(0), cfg))
+    kv = S((2, 2, 33, 16, 128), jnp.bfloat16)
+    cache = PagedKVCache(k=kv, v=kv, table=S((1, 32), jnp.int32),
+                         seq_lens=S((1,), jnp.int32), page_size=16)
+    jaxpr = jax.make_jaxpr(lambda p, t, c: forward_paged(
+        p, t, cfg, c, interpret=False, tp=False,
+        continuation=tokens > 1))(params, S((1, tokens), jnp.int32), cache)
+    found = _pallas_scopes(jaxpr.jaxpr, [])
+    assert [name for name, _ in found] == [kernel]
+    assert "kv_attend" in found[0][1]
 
 
 def test_every_mosaic_kernel_has_a_name_of_its_own():
@@ -403,7 +435,7 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
                 interpret=True))(),
     }
     for want, make in sites.items():
-        names = _pallas_names(make().jaxpr, [])
+        names = [n for n, _ in _pallas_scopes(make().jaxpr, [])]
         assert want in names, (want, names)
     # the sources give fourteen sites fourteen names, none shared
     named = []
