@@ -8,8 +8,7 @@ per second (decode throughput, the FastGen headline unit).
 
 Crash-proof output contract: the run is a LIST of configs, and the
 output JSON is rewritten after EVERY completed config (``partial: true``
-until the last one lands, like tools/kernel_bench.py's per-family
-commits) — a run killed at its time limit still leaves one row per
+until the last one lands) — a run killed at its time limit still leaves one row per
 config that finished.  Any single config's measure loop is capped at
 ~``DSTPU_SERVING_CAP_S`` (default 120 s) of wall clock: the loop stops
 stepping at the cap and the row reports the truncated token count
@@ -49,16 +48,6 @@ honestly (``truncated: true``) rather than burning the window.
         # ON — hit rate, p50 TTFT, demote/promote volume, and a
         # token-identity check between the arms (the bit-exact spill
         # contract); the slow lane stamps this as KV_TIER_BENCH.json
-    python bench_serving.py --kernels
-        # forced-kernel serving A/B: the same traffic with the kernels
-        # block pinned to the XLA twins vs forced Pallas (pallas_v2
-        # paged attention + fused sampling) — tokens/s, TTFT, the
-        # resolved policy each engine baked, and THE greedy identity
-        # gate (kernel_ab.mismatched_requests must be 0: a kernel is
-        # an execution strategy).  On CPU the forced arm runs the
-        # kernels in interpret mode — a correctness stamp, not a perf
-        # claim (rows carry backend).  The slow lane stamps this as
-        # KERNEL_SERVING_BENCH.json
 """
 
 import argparse
@@ -182,7 +171,7 @@ def build_prompts(args, cfg):
 
 def measure_config(name, args, params, mod, cfg, phase, prompts,
                    zero_inference=None, prefix_cache=None,
-                   speculative=None, kv_tier=None, tp=0, kernels=None):
+                   speculative=None, kv_tier=None, tp=0):
     """Build one engine flavor, warm it, drive the request stream under
     the wall-clock cap; returns ``(evidence row, finished outputs)`` —
     the outputs feed the kv-tier A/B's token-identity check."""
@@ -202,8 +191,6 @@ def measure_config(name, args, params, mod, cfg, phase, prompts,
         config["speculative"] = speculative
     if kv_tier is not None:
         config["kv_tier"] = kv_tier
-    if kernels is not None:
-        config["kernels"] = kernels
     # device-truth observability rides every row: the compile sentinel
     # proves the steady-state run never recompiled (bench_gate pins
     # detail.devprof.steady_state_compiles at 0)
@@ -342,8 +329,8 @@ def measure_config(name, args, params, mod, cfg, phase, prompts,
         },
     }
     if hasattr(engine, "_kernels"):
-        # the policy this engine's compiled programs actually baked
-        # (same object /statusz reports — resolved once at build)
+        # the readers this engine's compiled programs baked (what
+        # /statusz reports — resolved once at build)
         row["detail"]["kernels"] = engine._kernels.as_dict()
     # compile ledger + roofline for this row: steady_state_compiles
     # is the zero-recompile contract (gated at exactly 0), MFU/MBU are
@@ -534,16 +521,6 @@ def main():
                          "With --cpu the N virtual host devices are "
                          "forced before the backend comes up; the slow "
                          "lane stamps this as TP_BENCH.json")
-    ap.add_argument("--kernels", action="store_true",
-                    help="A/B the same traffic with the serving kernels "
-                         "pinned to the XLA twins vs forced Pallas "
-                         "(paged_attention=pallas_v2 + "
-                         "fused_sampling=on) — tokens/s, TTFT, the "
-                         "resolved policy per arm, and a greedy token-"
-                         "identity gate (a kernel is an execution "
-                         "strategy, so mismatched_requests must be 0). "
-                         "The slow lane stamps this as "
-                         "KERNEL_SERVING_BENCH.json")
     ap.add_argument("--zero-inference", action="store_true",
                     help="also measure the ZeRO-Inference weight-streamed "
                          "engine (host-tier layer streaming) next to the "
@@ -573,7 +550,7 @@ def main():
     ap.add_argument("--repeats", type=int, default=1,
                     help="measure each config N times and keep the best "
                          "row (tokens/s) — rides out scheduler noise on "
-                         "shared CPU hosts, like kernel_bench's best-of-3")
+                         "shared CPU hosts")
     ap.add_argument("--json-out", default=os.path.join(REPO,
                                                        "SERVING_BENCH.json"))
     args = ap.parse_args()
@@ -591,9 +568,6 @@ def main():
     if args.kv_tier and (args.prefix_cache or args.speculative
                          or args.zero_inference):
         raise SystemExit("--kv-tier is its own A/B")
-    if args.kernels and (args.tp or args.kv_tier or args.prefix_cache
-                         or args.speculative or args.zero_inference):
-        raise SystemExit("--kernels is its own A/B")
     if args.prefix_cache:
         if args.zero_inference:
             raise SystemExit(
@@ -664,20 +638,6 @@ def main():
             # original pages — a pre-existing cross-strategy property
             # of the prefix cache, reported as off_path_divergences.)
             ("kv_tier_ref", None, {"enabled": True}, None, None)]
-    kernels_by_name = {}
-    if args.kernels:
-        # BOTH arms pin their policy explicitly (no auto gate): the A/B
-        # races the forced Pallas hot path against its XLA twins on
-        # identical traffic.  On CPU the forced arm runs the kernels in
-        # interpret mode — the identity gate is the point there.
-        kernels_by_name = {
-            "kernel_xla": {"paged_attention": "xla",
-                           "fused_sampling": "off"},
-            "kernel_forced": {"paged_attention": "pallas_v2",
-                              "fused_sampling": "on"},
-        }
-        configs = [("kernel_xla", None, None, None, None),
-                   ("kernel_forced", None, None, None, None)]
     spec_on = {"enabled": True, "draft_tokens": args.draft_tokens}
     if args.speculative:
         configs = [("spec_off", None, None, None, None),
@@ -711,8 +671,7 @@ def main():
             cand, c_outs = measure_config(
                 name, args, params, mod, cfg, phase, prompts,
                 zero_inference=zi, prefix_cache=pc, speculative=spec,
-                kv_tier=kvt, tp=tp,
-                kernels=kernels_by_name.get(name))
+                kv_tier=kvt, tp=tp)
             if row is None or cand["value"] > row["value"]:
                 row, outs = cand, c_outs
         outputs_by_config[name] = outs
@@ -768,29 +727,6 @@ def main():
                 "mean_accepted_len": zon["detail"]["speculative"][
                     "mean_accepted_len"],
             }
-    if args.kernels and len(out["rows"]) == 2:
-        xla_r, frc_r = out["rows"]
-        o_x = outputs_by_config["kernel_xla"]
-        o_f = outputs_by_config["kernel_forced"]
-        # identity over the requests both arms completed (the wall
-        # cap can truncate different subsets)
-        both = sorted(set(o_x) & set(o_f))
-        mismatched = sum(1 for k in both if o_x[k] != o_f[k])
-        out["kernel_ab"] = {
-            "forced": kernels_by_name["kernel_forced"],
-            "tokens_per_s_xla": xla_r["value"],
-            "tokens_per_s_forced": frc_r["value"],
-            "speedup": (round(frc_r["value"] / xla_r["value"], 3)
-                        if xla_r["value"] else None),
-            "ttft_xla_ms": xla_r["detail"].get("ttft_ms"),
-            "ttft_forced_ms": frc_r["detail"].get("ttft_ms"),
-            "policy_xla": xla_r["detail"].get("kernels"),
-            "policy_forced": frc_r["detail"].get("kernels"),
-            "compared_requests": len(both),
-            # THE gate: a kernel is an execution strategy — greedy
-            # tokens must be identical, any mismatch is a bug
-            "mismatched_requests": mismatched,
-        }
     if args.tp and len(out["rows"]) == 2:
         one, sh = out["rows"]
         o_one = outputs_by_config["tp1"]

@@ -10,7 +10,7 @@ context 1024 — ``GPT2Config.gpt2_1_3b()``, BASELINE.json config #2):
   one warm-up step, then 5 steps timed to ``block_until_ready`` and 5
   timed to a fetched value; the loss must be finite and fall.
 - serve: all 24 layers in bf16 through ``serving_engine`` with the
-  default kernel policy; 8 seeded requests of 256-768 prompt tokens and
+  readers its build chooses; 8 seeded requests of 256-768 prompt tokens and
   64 new tokens, greedy; every served token must be a (near-)argmax of
   the un-paged ``gpt2.forward`` on the same weights, no page may leak
   and nothing may compile after the first token.

@@ -43,7 +43,6 @@ CONFIG_BLOCKS = {
     "ZeroInferenceConfig": "zero_inference",
     "PrefixCacheConfig": "prefix_cache",
     "KVTierConfig": "kv_tier",
-    "KernelsConfig": "kernels",
     "CommConfig": "comm",
     "SpeculativeConfig": "speculative",
     "SLOConfig": "slo",

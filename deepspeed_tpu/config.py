@@ -233,9 +233,9 @@ class KVTierConfig:
     served tokens are identical to tiering off.
     ``quantized_resident`` (requires ``quantize_cold``): keep promoted
     pages int8 IN HBM — the promotion publishes the stored codes +
-    per-token-row scales directly (no dequant, no f32 scatter) and the
-    attention kernel dequantizes in VMEM per block
-    (``paged_chunk_attention_v2_quant``), so the resident KV pool holds
+    per-token-row scales directly (no dequant, no f32 scatter) and
+    attention gathers the codes a table names and dequantizes those
+    (``kernels.dequantize_pages``), so the resident KV pool holds
     ~2x the pages per HBM byte; accuracy stays within the same
     documented ``KV_TIER_QUANT_RTOL`` bound as ``quantize_cold``
     because the codes round-trip losslessly once quantized.
@@ -427,7 +427,7 @@ class CommConfig:
     @classmethod
     def coerce(cls, obj) -> "CommConfig":
         """Accept None (all-default policy), a dict, or a CommConfig —
-        like ``kernels`` there is no enabled switch: the defaults ARE
+        there is no enabled switch: the defaults ARE
         the policy (auto hierarchy, blockwise codec, monolithic
         buckets, bit-exact serving)."""
         if obj is None:
@@ -441,71 +441,12 @@ class CommConfig:
             f"{type(obj).__name__}")
 
 
-@dataclasses.dataclass
-class KernelsConfig:
-    """Serving kernel-dispatch policy (the config-first replacement for
-    the ``DSTPU_FORCE_PAGED_PALLAS`` / ``DSTPU_PAGED_V1`` env-flag
-    folklore).
-
-    ``paged_attention`` picks the paged decode/chunk attention
-    implementation: ``auto`` (``kernels.paged_reader``, from the phase,
-    the layout and the shapes, no size threshold: on one device over
-    float pages a decode program reads live pages only through the
-    Mosaic decode kernel at every batch and table width, and a chunk
-    program of whole 128-row blocks with heads of whole 128-lane tiles
-    runs the blocked Mosaic chunk reader up to each query block's own
-    frontier; tensor parallelism, int8-resident pages, CPU/interpret
-    runs and chunks off those shapes take the XLA gather), ``xla``
-    (always the gather reference
-    composition), ``pallas_v1`` (the one-page-per-grid-step kernel,
-    kept for A/B), or ``pallas_v2`` (force the DMA kernels, decode and
-    chunk).  ``fused_sampling`` picks the boundary/decode sampler:
-    ``auto`` (crossover gate on batch x vocab), ``off`` (the jitted XLA
-    ``_sample_rows``), ``on`` (force the fused Pallas greedy kernel;
-    greedy output is bit-exact either way).
-
-    Resolution happens ONCE at engine build (``resolve_serving_kernels``
-    in :mod:`deepspeed_tpu.inference.kernels`): env vars still win as
-    overrides at that point, the resolved policy is baked into the
-    compiled programs and surfaced in ``/statusz`` under ``kernels``,
-    and a forced Pallas choice that the build must demote (tensor
-    parallelism — the kernel is per-device) falls back VISIBLY with a
-    recorded reason + counter instead of silently.
-    """
-
-    paged_attention: str = "auto"
-    fused_sampling: str = "auto"
-
-    @classmethod
-    def from_dict(cls, d: Dict[str, Any]) -> "KernelsConfig":
-        known = {f.name for f in dataclasses.fields(cls)}
-        k = cls(**{kk: v for kk, v in d.items() if kk in known})
-        k.paged_attention = str(k.paged_attention)
-        k.fused_sampling = str(k.fused_sampling)
-        if k.paged_attention not in ("auto", "xla", "pallas_v1",
-                                     "pallas_v2"):
-            raise ValueError(
-                f"kernels.paged_attention must be one of auto|xla|"
-                f"pallas_v1|pallas_v2, got {k.paged_attention!r}")
-        if k.fused_sampling not in ("auto", "off", "on"):
-            raise ValueError(
-                f"kernels.fused_sampling must be one of auto|off|on, "
-                f"got {k.fused_sampling!r}")
-        return k
-
-    @classmethod
-    def coerce(cls, obj) -> "KernelsConfig":
-        """Accept None (all-auto defaults), a dict, or a KernelsConfig —
-        there is no enabled switch: ``auto`` IS the default policy."""
-        if obj is None:
-            return cls()
-        if isinstance(obj, cls):
-            return obj
-        if isinstance(obj, dict):
-            return cls.from_dict(dict(obj))
-        raise TypeError(
-            f"kernels must be a dict or KernelsConfig, got "
-            f"{type(obj).__name__}")
+# what a config that still carries the block is told: a user who forced
+# a kernel learns that it no longer is
+KERNELS_BLOCK_GONE = (
+    "the `kernels` block is gone: which kernel reads the cache is a rule "
+    "of the build, not an option (MIGRATION.md, \"Serving kernel "
+    "dispatch\"; /statusz `kernels` names the readers). Remove the block")
 
 
 @dataclasses.dataclass
@@ -1797,8 +1738,6 @@ class Config:
         default_factory=PrefixCacheConfig)
     kv_tier: KVTierConfig = dataclasses.field(
         default_factory=KVTierConfig)
-    kernels: KernelsConfig = dataclasses.field(
-        default_factory=KernelsConfig)
     comm: CommConfig = dataclasses.field(
         default_factory=CommConfig)
     speculative: SpeculativeConfig = dataclasses.field(
@@ -1936,12 +1875,10 @@ class Config:
             # "enabled": false still disables
             c.kv_tier = KVTierConfig.coerce(d["kv_tier"])
         if "kernels" in d:
-            # no enabled switch here: "auto" is the default policy and
-            # writing the block just overrides fields of it
-            c.kernels = KernelsConfig.coerce(d["kernels"])
+            raise ValueError(KERNELS_BLOCK_GONE)
         if "comm" in d:
-            # no enabled switch (same contract as kernels): the
-            # defaults are the policy, the block overrides fields
+            # no enabled switch: the defaults are the policy, the block
+            # overrides fields
             c.comm = CommConfig.coerce(d["comm"])
         if "speculative" in d:
             # coerce, not from_dict: writing the block IS the opt-in

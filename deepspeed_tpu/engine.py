@@ -32,7 +32,7 @@ from deepspeed_tpu.config import Config
 from deepspeed_tpu.devprof import BuildCounters, ProgramSpan
 from deepspeed_tpu.ops.optim import Optimizer, from_config as opt_from_config
 from deepspeed_tpu.telemetry import MetricsRegistry
-from deepspeed_tpu.topology import MeshSpec, default_mesh
+from deepspeed_tpu.topology import MeshSpec, default_mesh, set_current_mesh
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -142,7 +142,7 @@ class TrainingEngine:
         # publish for model-side sharded ops (ring/ulysses attention, MoE)
         from deepspeed_tpu import topology as _topo
 
-        _topo.set_current_mesh(self.mesh, zero_stage=config.zero.stage)
+        _topo.set_current_mesh(self.mesh)
         config.resolve_batch_sizes(self.mesh.dp_world)
         self.loss_fn = loss_fn
         self.has_aux = has_aux
@@ -297,7 +297,13 @@ class TrainingEngine:
         # a stable program name: a capture's "XLA Modules" line shows
         # jit_dstpu_train_step
         def dstpu_train_step(state, batch):
-            return self._train_step(state, batch)
+            try:
+                return self._train_step(state, batch)
+            finally:
+                # the ZeRO stage is a fact of this trace: a model's
+                # forward outside an engine must not meet it (the mesh
+                # stays for the ring, Ulysses and MoE readers)
+                set_current_mesh(self.mesh)
 
         self._step_fn = jax.jit(
             dstpu_train_step,
@@ -845,15 +851,18 @@ class TrainingEngine:
 
         _topo.set_current_mesh(self.mesh,
                                zero_stage=self.config.zero.stage)
-        params = state.params
-        if self.grad_comm_mode == "qwz":
-            # flat [world, chunk] master → model leaves (GSPMD inserts the
-            # gather; eval is exact, not int8-quantized)
-            params = self._qwz_unflatten(
-                params.reshape(-1),
-                precision.master_dtype(self.config.precision))
-        loss, aux = self._loss_for(params, batch)
-        return loss if aux is None else (loss, aux)
+        try:
+            params = state.params
+            if self.grad_comm_mode == "qwz":
+                # flat [world, chunk] master → model leaves (GSPMD inserts
+                # the gather; eval is exact, not int8-quantized)
+                params = self._qwz_unflatten(
+                    params.reshape(-1),
+                    precision.master_dtype(self.config.precision))
+            loss, aux = self._loss_for(params, batch)
+            return loss if aux is None else (loss, aux)
+        finally:
+            set_current_mesh(self.mesh)         # as the train step's
 
     # ----------------------------------------------------------- public API
     @property

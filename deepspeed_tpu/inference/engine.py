@@ -206,9 +206,7 @@ def init_serving(params, model_config, *, config: Any = None,
             if getattr(config, block).enabled:
                 kw.setdefault(block, getattr(config, block))
         # ... and these always: telemetry and tracing carry their own
-        # enabled flag into the engine, and for kernels "auto" IS the
-        # default policy (resolved ONCE at engine build, env vars as
-        # overrides of last resort)
-        for block in ("telemetry", "tracing", "kernels"):
+        # enabled flag into the engine
+        for block in ("telemetry", "tracing"):
             kw.setdefault(block, getattr(config, block))
     return serving_engine(params, model_config, mesh=mesh, **kw)

@@ -18,17 +18,17 @@ The jnp reference path (`paged_attention_reference`) materialises the
 gather and is the numerics oracle for tests/CPU.
 
 The Mosaic kernels here, by the names a capture shows: `dstpu_paged_decode`
-(live pages only, one decode step), `dstpu_paged_chunk_v1` / `_v2` /
-`_v2_q8` (a chunk over history), `dstpu_mla_decode` (latent rows) and
+(live pages only, one decode step), `dstpu_paged_chunk_v2` (a chunk over
+history, in blocks), `dstpu_mla_decode` (latent rows) and
 `dstpu_state_step`: one token of a recurrent layer's rule on the per-slot
 state carried beside the pool, a tile at a time, in place
-(:func:`state_step`).
+(:func:`state_step`).  Which of them a program runs is
+:func:`paged_reader`'s answer, a rule of the build.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -693,27 +693,6 @@ def paged_attention_reference(q, k_pages, v_pages, table, seq_lens,
     return out.reshape(B, H, Dh).astype(q.dtype)
 
 
-# ------------------------------------------------------------ pallas kernel
-def paged_decode_attention(q, k_pages, v_pages, table, seq_lens,
-                           scale: Optional[float] = None,
-                           interpret: bool = False, layer=None):
-    """Pallas paged decode attention; same contract as the reference fn.
-
-    q: [B, H, Dh] (one decode step), k/v_pages: the pool and ``layer``,
-    or one layer's [KV, P, ps, Dh].
-
-    Decode IS the C=1 chunked-prefill case — the query sits at position
-    ``seq_lens - 1`` and attends ``kpos <= seq_lens - 1`` — so one kernel
-    (:func:`_chunk_kernel`) serves both paths and any accumulator fix
-    lands exactly once.  Empty rows (seq_lens == 0) resolve to start -1:
-    every position masks out and the finalize's l==0 guard yields zeros,
-    matching the reference's empty-sequence contract.
-    """
-    return paged_chunk_attention(
-        q[:, None], k_pages, v_pages, table, seq_lens - 1, scale=scale,
-        interpret=interpret, layer=layer)[:, 0]
-
-
 # ------------------------------------------- live-pages-only decode kernel
 # One K (or V) block of the decode kernel in VMEM, per buffer slot: big
 # enough that a block's products amortise the loop's fixed cost, small
@@ -1029,283 +1008,59 @@ def chunk_blocks(heads: int, n_kv: int, head_dim: int, page_size: int,
     return (256 if wide else 128), ppb
 
 
-# ------------------------- int8-dequant-fused multi-page chunked kernel
-def _chunk_v2_quant_kernel(table_ref, start_ref, layer_ref, q_ref, kq_hbm,
-                           ks_hbm, vq_hbm, vs_hbm, o_ref, *, scale, ps,
-                           kv_heads, max_pages, cg8, group, chunk, ppcb):
-    """:func:`_chunk_v2_kernel` over int8-resident pages: per page the
-    DMA streams the int8 codes AND the per-token-row f32 scales
-    (``[ps, 1]`` — the same (N, 1) VMEM layout the v1 kernel's m/l
-    scratch uses), and the dequant ``codes * scale`` happens in VMEM
-    right before the dot — the gathered f32 K/V transient never exists
-    in HBM.  Everything else (double buffering, live-page sweep, online
-    softmax, masking) is the v2 kernel unchanged."""
-    bk = pl.program_id(0)
-    b = bk // kv_heads
-    h = bk % kv_heads
-    start = start_ref[b]
-    layer = layer_ref[0]
-    live = start + chunk
-    pages_live = (live + ps - 1) // ps
-    nch = (pages_live + ppcb - 1) // ppcb
-
-    def body(kqb, ksb, vqb, vsb, sem):
-        def chunk_dmas(c, slot):
-            dmas = []
-            for j in range(ppcb):                   # static unroll
-                p = c * ppcb + j
-                psafe = jnp.minimum(p, max_pages - 1)
-                pid = jnp.where(p < pages_live, table_ref[b, psafe], 0)
-                dmas.append(pltpu.make_async_copy(
-                    kq_hbm.at[layer, h, pid],
-                    kqb.at[slot, pl.ds(j * ps, ps), :], sem.at[slot, 0]))
-                dmas.append(pltpu.make_async_copy(
-                    ks_hbm.at[layer, h, pid],
-                    ksb.at[slot, pl.ds(j * ps, ps), :], sem.at[slot, 1]))
-                dmas.append(pltpu.make_async_copy(
-                    vq_hbm.at[layer, h, pid],
-                    vqb.at[slot, pl.ds(j * ps, ps), :], sem.at[slot, 2]))
-                dmas.append(pltpu.make_async_copy(
-                    vs_hbm.at[layer, h, pid],
-                    vsb.at[slot, pl.ds(j * ps, ps), :], sem.at[slot, 3]))
-            return dmas
-
-        @pl.when(nch > 0)
-        def _():
-            for d in chunk_dmas(0, 0):
-                d.start()
-
-        q = q_ref[0].astype(jnp.float32)            # [cg8, Dh]
-
-        def loop(c, carry):
-            m, l, acc = carry
-            slot = jax.lax.rem(c, 2)
-
-            @pl.when(c + 1 < nch)
-            def _():
-                for d in chunk_dmas(c + 1, jax.lax.rem(c + 1, 2)):
-                    d.start()
-
-            for d in chunk_dmas(c, slot):
-                d.wait()
-            # VMEM dequant: per-token-row scales broadcast over Dh
-            k = kqb[slot].astype(jnp.float32) * ksb[slot]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            kpos = c * (ppcb * ps) + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            qpos = start + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0) // group
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            pr = jnp.exp(s - m_new)
-            pr = jnp.where(s > NEG_INF / 2, pr, 0.0)
-            l = l * alpha + jnp.sum(pr, axis=1, keepdims=True)
-            v = vqb[slot].astype(jnp.float32) * vsb[slot]
-            acc = acc * alpha + jax.lax.dot_general(
-                pr, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return m_new, l, acc
-
-        init = (jnp.full((cg8, 1), NEG_INF, jnp.float32),
-                jnp.zeros((cg8, 1), jnp.float32),
-                jnp.zeros((cg8, q_ref.shape[2]), jnp.float32))
-        m, l, acc = jax.lax.fori_loop(0, nch, loop, init)
-        l = jnp.where(l == 0.0, 1.0, l)             # empty rows → zeros
-        o_ref[0] = (acc / l).astype(o_ref.dtype)
-
-    pl.run_scoped(
-        body,
-        kqb=pltpu.VMEM((2, ppcb * ps, q_ref.shape[2]), kq_hbm.dtype),
-        ksb=pltpu.VMEM((2, ppcb * ps, 1), jnp.float32),
-        vqb=pltpu.VMEM((2, ppcb * ps, q_ref.shape[2]), vq_hbm.dtype),
-        vsb=pltpu.VMEM((2, ppcb * ps, 1), jnp.float32),
-        sem=pltpu.SemaphoreType.DMA((2, 4)),
-    )
-
-
-# dstpu: hot-path
-def paged_chunk_attention_v2_quant(q, kq_pages, ks_pages, vq_pages,
-                                   vs_pages, table, start,
-                                   scale: Optional[float] = None,
-                                   pages_per_block: int = 8,
-                                   interpret: bool = False, layer=None):
-    """Int8-dequant-fused chunked-prefill attention: same contract as
-    :func:`paged_chunk_attention_reference` over
-    ``dequantize_pages(kq, ks) / (vq, vs)``, but the dequant happens in
-    VMEM inside the page sweep — the ~2x-smaller int8 pages are what
-    crosses HBM.  ``kq/vq_pages``: int8 ``[KV, P, ps, Dh]``;
-    ``ks/vs_pages``: f32 ``[KV, P, ps, 1]`` per-token-row scales (the
-    ``kv_tier.quantize_page`` codec); or the four pools and ``layer``."""
-    B, C, H, Dh = q.shape
-    layer, kq_pages, ks_pages, vq_pages, vs_pages = _as_pool(
-        layer, kq_pages, ks_pages, vq_pages, vs_pages)
-    _, KV, P, ps, _ = kq_pages.shape
-    G = H // KV
-    mp = table.shape[1]
-    scale = scale if scale is not None else Dh ** -0.5
-    ppcb = max(1, min(pages_per_block, mp))
-    CG = C * G
-    cg8 = -(-CG // 8) * 8
-    qg = q.reshape(B, C, KV, G, Dh).transpose(0, 2, 1, 3, 4) \
-        .reshape(B * KV, CG, Dh)
-    if cg8 != CG:
-        qg = jnp.concatenate(
-            [qg, jnp.zeros((B * KV, cg8 - CG, Dh), q.dtype)], axis=1)
-
-    kernel = functools.partial(
-        _chunk_v2_quant_kernel, scale=scale, ps=ps, kv_heads=KV,
-        max_pages=mp, cg8=cg8, group=G, chunk=C, ppcb=ppcb)
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,   # table, start, layer
-            grid=(B * KV,),
-            in_specs=[
-                pl.BlockSpec((1, cg8, Dh), lambda bk, *_: (bk, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, cg8, Dh), lambda bk, *_: (bk, 0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((B * KV, cg8, Dh), q.dtype),
-        interpret=interpret,
-        name="dstpu_paged_chunk_v2_q8",
-    )(table, start, _layer_operand(layer), qg, kq_pages, ks_pages,
-      vq_pages, vs_pages)
-    out = out[:, :CG].reshape(B, KV, C, G, Dh).transpose(0, 2, 1, 3, 4)
-    return out.reshape(B, C, H, Dh)
-
-
-# dstpu: hot-path
-def paged_decode_attention_v2_quant(q, kq_pages, ks_pages, vq_pages,
-                                    vs_pages, table, seq_lens,
-                                    scale: Optional[float] = None,
-                                    pages_per_block: int = 8,
-                                    interpret: bool = False, layer=None):
-    """Int8-dequant-fused paged decode attention: the C=1 case of the
-    quantized chunk kernel (the position ``seq_lens - 1`` attends
-    ``kpos <= seq_lens - 1``)."""
-    return paged_chunk_attention_v2_quant(
-        q[:, None], kq_pages, ks_pages, vq_pages, vs_pages, table,
-        seq_lens - 1, scale=scale, pages_per_block=pages_per_block,
-        interpret=interpret, layer=layer)[:, 0]
-
-
-# ------------------------------------------- pallas chunked-prefill kernel
-def _chunk_kernel(table_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale, page_size, kv_heads,
-                  max_pages, group, chunk):
-    """Chunk rows are flattened [C*G, Dh]; row r is query position
-    r // G of the chunk.  Causal frontier per row: start + r//G."""
-    bk = pl.program_id(0)
-    p = pl.program_id(1)
-    b = bk // kv_heads
-
-    @pl.when(p == 0)
-    def _():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    start = lens_ref[b]
-    # page live iff it holds any position <= start + C - 1
-    @pl.when(p * page_size < start + chunk)
-    def _():
-        q = q_ref[0]                        # [CG, Dh]
-        k = k_ref[0]                        # [ps, Dh]
-        s = jax.lax.dot_general(
-            q.astype(jnp.float32), k.astype(jnp.float32),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # [CG, ps]
-        kpos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        qpos = start + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0) // group
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        pr = jnp.exp(s - m_new)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(pr, axis=1, keepdims=True)
-        m_scr[:] = m_new
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            pr, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(p == max_pages - 1)
-    def _():
-        l = jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:])
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-
-
-def paged_chunk_attention(q, k_pages, v_pages, table, start,
-                          scale: Optional[float] = None,
-                          interpret: bool = False, layer=None):
-    """Pallas chunked-prefill attention — same contract as
-    :func:`paged_chunk_attention_reference` but streaming pages through
-    the DMA engine instead of materializing the gather.
-
-    q: [B, C, H, Dh] at positions ``start + 0..C-1`` (the chunk's K/V
-    must already be written into the pages).  NOTE: correctness is pinned
-    by interpret-mode tests; the on-chip win over the gather reference is
-    to be confirmed in KERNEL_BENCH before this becomes the small-shape
-    default (the decode kernel's measured policy applies meanwhile).
-    """
+def paged_chunk_attention_v2(q, k_pages, v_pages, table, start,
+                             scale: Optional[float] = None,
+                             pages_per_block: Optional[int] = None,
+                             interpret: bool = False, layer=None,
+                             block_q: Optional[int] = None):
+    """Blocked chunked-prefill attention on the chip — same contract as
+    :func:`paged_chunk_attention_reference`: the Mosaic kernel
+    ``dstpu_paged_chunk_v2`` (:func:`_chunk_v2_kernel`).  q [B, C, H, Dh]
+    goes in and the result comes out as the family's hooks hold them
+    (``[B, C, H Dh]``: a reshape, no copy); the pool stays in HBM in its
+    stored layout.  ``block_q`` and ``pages_per_block`` follow from the
+    shapes (:func:`chunk_blocks`); tests pass them to put a block edge
+    where they want one."""
     B, C, H, Dh = q.shape
     layer, k_pages, v_pages = _as_pool(layer, k_pages, v_pages)
-    L, KV, P, ps, _ = k_pages.shape
-    G = H // KV
-    mp = table.shape[1]
+    _, KV, _, ps, _ = k_pages.shape
+    G, mp = H // KV, table.shape[1]
     scale = scale if scale is not None else Dh ** -0.5
-    CG = C * G
-    pad = (-CG) % 8                      # sublane alignment
-    qg = q.reshape(B, C, KV, G, Dh).transpose(0, 2, 1, 3, 4) \
-        .reshape(B * KV, CG, Dh)
-    if pad:
-        qg = jnp.concatenate(
-            [qg, jnp.zeros((B * KV, pad, Dh), q.dtype)], axis=1)
-
-    kernel = functools.partial(
-        _chunk_kernel, scale=scale, page_size=ps, kv_heads=KV,
-        max_pages=mp, group=G, chunk=C)
-
-    def kv_map(bk, p, tbl, lens, lyr):
-        b = bk // KV
-        pid = jnp.where(p * ps < lens[b] + C, tbl[b, p], 0)
-        return ((lyr[0] * KV + bk % KV) * P + pid, 0, 0)
-
+    operand = jnp.promote_types(q.dtype, k_pages.dtype)
+    bq, ppb = chunk_blocks(H, KV, Dh, ps, operand.itemsize, C, mp)
+    bq, ppb = block_q or bq, min(mp, pages_per_block or ppb)
+    Cp = -(-C // bq) * bq           # whole blocks: a few rows pad to 8
+    qf = q.reshape(B, C, H * Dh)
+    if Cp != C:
+        qf = jnp.pad(qf, ((0, 0), (0, Cp - C), (0, 0)))
+    rows = G * bq
+    block = pl.BlockSpec((1, bq, H * Dh), lambda b, i, *_: (b, i, 0))
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_chunk_v2_kernel, scale=scale, ps=ps, group=G,
+                          chunk=C),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,   # table, start, layer
-            grid=(B * KV, mp),
-            in_specs=[
-                pl.BlockSpec((1, CG + pad, Dh), lambda bk, *_: (bk, 0, 0)),
-                pl.BlockSpec((1, ps, Dh), kv_map),
-                pl.BlockSpec((1, ps, Dh), kv_map),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, CG + pad, Dh), lambda bk, *_: (bk, 0, 0)),
+            grid=(B, Cp // bq),
+            in_specs=[block, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=block,
             scratch_shapes=[
-                pltpu.VMEM((CG + pad, 1), jnp.float32),
-                pltpu.VMEM((CG + pad, 1), jnp.float32),
-                pltpu.VMEM((CG + pad, Dh), jnp.float32),
+                pltpu.VMEM((KV, rows, Dh), operand),
+                pltpu.VMEM((KV, rows, Dh), jnp.float32),
+                pltpu.VMEM((KV, rows, 1), jnp.float32),
+                pltpu.VMEM((KV, rows, 1), jnp.float32),
+                pltpu.VMEM((2, KV, ppb, ps, Dh), k_pages.dtype),
+                pltpu.VMEM((2, KV, ppb, ps, Dh), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B * KV, CG + pad, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Cp, H * Dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=(8 << 20) + (
+            _chunk_step_bytes(bq, ppb * ps, H, KV, Dh, operand.itemsize))),
         interpret=interpret,
-        name="dstpu_paged_chunk_v1",
-    )(table, start, _layer_operand(layer), qg,
-      k_pages.reshape(L * KV * P, ps, Dh),
-      v_pages.reshape(L * KV * P, ps, Dh))
-    out = out[:, :CG].reshape(B, KV, C, G, Dh).transpose(0, 2, 1, 3, 4)
-    return out.reshape(B, C, H, Dh)
+        name="dstpu_paged_chunk_v2",
+    )(table, start, _layer_operand(layer), qf, k_pages, v_pages)
+    return out[:, :C].reshape(B, C, H, Dh)
 
 
 # ------------------------------------------------- latent (one-row) pages
@@ -1500,7 +1255,7 @@ def latent_reader(decode: Tuple[str, str]) -> Tuple[str, str]:
     same answer (:func:`paged_reader`), with the Mosaic reader under its
     own name."""
     reader, why = decode
-    if reader in ("pallas_v1", "pallas_v2"):
+    if reader != "xla":
         return "dstpu_mla_decode", (
             f"{why}; latent rows: absorbed queries against [c | k_rope], "
             "each live page read once as keys and values")
@@ -1509,14 +1264,13 @@ def latent_reader(decode: Tuple[str, str]) -> Tuple[str, str]:
 
 def latent_attention_step(q, row, w_uk, w_uv, scale, pool, layer, table,
                           start, *, continuation: bool, prefill: bool,
-                          paged_kernel: str, flash_force_reference: bool,
-                          interpret: bool = False):
+                          reader: str, flash_force_reference: bool):
     """:func:`paged_attention_step` of a latent family, on its one pool.
 
     q: [B, T, H, Dn + Dr] (rope part rotated); row: [B, T, 1, C + Dr] =
     ``[c | k_rope]``, what is cached; w_uk [C, H, Dn] and w_uv [C, H,
     Dv]: the two halves of W_kvb.  Decode (T == 1) absorbs W_UK into the
-    query and attends over the rows of the live pages (``paged_kernel``
+    query and attends over the rows of the live pages (``reader``
     "xla": the gather), then takes the result through W_UV.  T > 1
     attends in the per-head form, blocked: a whole prompt expands its
     own rows; a chunk writes its rows, gathers the rows its table names
@@ -1538,10 +1292,9 @@ def latent_attention_step(q, row, w_uk, w_uv, scale, pool, layer, table,
             page_id, in_page = _row_targets(pool, table, start)
             pool = _scatter_rows(pool, layer, page_id, in_page, row[:, 0])
         with attend:
-            if paged_kernel in ("pallas_v1", "pallas_v2"):
+            if reader != "xla":
                 o = latent_decode_attention(
-                    q_abs, pool, table, start + 1, scale, C,
-                    interpret=interpret, layer=layer)
+                    q_abs, pool, table, start + 1, scale, C, layer=layer)
             else:
                 o = latent_decode_reference(q_abs, pool, table, start + 1,
                                             scale, C, layer=layer)
@@ -1579,26 +1332,26 @@ def latent_attention_step(q, row, w_uk, w_uv, scale, pool, layer, table,
 
 
 # --------------------------------------------- shared per-layer dispatch
-def paged_reader(policy: Optional[str], *, decode: bool, tp: bool,
-                 interpret: bool, quant: bool, tokens: int = 1,
-                 head_dim: int = 0) -> Tuple[str, str]:
-    """Which reader a paged program's attention runs, and why: ("xla" |
-    "pallas_v1" | "pallas_v2", reason), the one answer ``forward_paged``,
-    ``paged_layered_fns`` and the engine's ``/statusz`` share.
+def paged_reader(*, decode: bool, tp: bool, interpret: bool, quant: bool,
+                 tokens: int = 1, head_dim: int = 0) -> Tuple[str, str]:
+    """Which reader a paged program's attention runs, and why: ("xla" or
+    the Mosaic kernel's own name, reason), the one answer
+    ``forward_paged``, ``paged_layered_fns`` and the engine's
+    ``/statusz`` share.  A rule of the build: nothing configures it and
+    nothing overrides it.
 
-    A forced ``policy`` is itself (the build has demoted what cannot
-    run: :func:`resolve_serving_kernels`).  ``auto`` (or None) answers
-    from the phase, the layout and the shapes, no env reads: on one
-    device over float pages a decode program (``T == 1``) reads live
-    pages only (:func:`paged_decode_attention_v2`), and so does a
-    continuation program of whole 128-row blocks of ``tokens`` with
-    heads of whole 128-lane tiles, each block of queries up to its own
-    frontier (:func:`paged_chunk_attention_v2`); the gather's cost
-    follows the table.  It stays under tensor parallelism (the KV heads
-    are sharded), over int8-resident pages (the compiler refuses that
-    kernel), in ``interpret`` mode and off those shapes (a head of 64)."""
-    if policy not in (None, "auto"):
-        return policy, "forced"
+    It answers from the phase, the layout and the shapes: on one device
+    over float pages a decode program (``T == 1``) reads live pages only
+    (``dstpu_paged_decode``, :func:`paged_decode_attention_v2`), and so
+    does a continuation program of whole 128-row blocks of ``tokens``
+    with heads of whole 128-lane tiles, each block of queries up to its
+    own frontier (``dstpu_paged_chunk_v2``,
+    :func:`paged_chunk_attention_v2`); the gather's cost follows the
+    table.  It stays under tensor parallelism (the KV heads are
+    sharded), over int8-resident pages (gathered and dequantized: the
+    chip's compiler refuses a page copy of the ``[KV, P, ps, 1]`` scale
+    planes, a 1-wide slice of a 128-lane tile), in ``interpret`` mode
+    and off those shapes (a head of 64)."""
     whole = lambda n: n > 0 and n % 128 == 0
     for off, why in ((tp, "tp: KV heads are sharded over the mesh"),
                      (quant, "int8-resident pages"),
@@ -1609,177 +1362,76 @@ def paged_reader(policy: Optional[str], *, decode: bool, tp: bool,
                       "chunk program: a head is not whole 128-lane tiles")):
         if off:
             return "xla", why
-    return "pallas_v2", ("decode" if decode else "chunk in 128-row blocks") \
-        + " on one device over float pages"
+    if decode:
+        return "dstpu_paged_decode", "decode on one device over float pages"
+    return "dstpu_paged_chunk_v2", (
+        "chunk in 128-row blocks on one device over float pages")
 
 
 class ServingKernelPolicy(NamedTuple):
-    """The kernel-dispatch policy an engine build resolved — baked into
-    the compiled programs and surfaced verbatim in ``/statusz``."""
+    """The readers an engine's build resolved: a report, not a request.
+    Each row is a rule's answer from the family, the mesh, the pages and
+    the shapes, baked into the compiled programs and printed by
+    ``/statusz`` under ``kernels``."""
 
-    paged_attention: str            # auto | xla | pallas_v1 | pallas_v2
-    fused_sampling: str             # off | on
-    # (field, value, source) for every env var that overrode the config
-    env_overrides: Tuple[Tuple[str, str, str], ...] = ()
-    # (field, demoted_to, reason) for forced choices the build demoted
-    fallbacks: Tuple[Tuple[str, str, str], ...] = ()
     # (reader, reason) of the decode program's attention (:func:`paged_reader`)
     decode: Tuple[str, str] = ("xla", "")
-    # pallas | xla: how the decode program steps a recurrent layer's
-    # per-slot state (:func:`state_stepper`; never configured)
-    state_step: str = "xla"
-    # (reader, reason) of a window layer's chunk (ops.attention.window_reader)
-    window: Tuple[str, str] = ("xla", "no window layer")
     # (reader, reason) of a chunk program's attention over K/V pages
     chunk: Tuple[str, str] = ("xla", "no chunk program")
+    # (reader, reason) of a window layer's chunk (ops.attention.window_reader)
+    window: Tuple[str, str] = ("xla", "no window layer")
+    # pallas | xla: how the decode program steps a recurrent layer's
+    # per-slot state (:func:`state_stepper`)
+    state_step: str = "xla"
+    # (what, fell back to, reason) where a mesh took a kernel the family
+    # would otherwise run
+    fallbacks: Tuple[Tuple[str, str, str], ...] = ()
 
     def as_dict(self) -> dict:
         pair = lambda k: dict(zip(("reader", "reason"), getattr(self, k)))
         return {
-            "paged_attention": self.paged_attention, "decode": pair("decode"),
-            "chunk": pair("chunk"), "window": pair("window"),
-            "state_step": self.state_step,
-            "fused_sampling": self.fused_sampling,
-            "env_overrides": [list(o) for o in self.env_overrides],
+            "decode": pair("decode"), "chunk": pair("chunk"),
+            "window": pair("window"), "state_step": self.state_step,
             "fallbacks": [{"field": f, "demoted_to": d, "reason": r}
                           for f, d, r in self.fallbacks],
         }
 
 
-class ServingKernelRefused(ValueError):
-    """A forced kernel choice the TPU compiler is known to refuse for
-    this engine's cache layout — raised at engine build, with the
-    compiler's reason, instead of a Mosaic error in the middle of
-    serving."""
-
-
-# Why the int8-resident Pallas kernels cannot run on the chip: compiled
-# for a described v5e, paged_decode_attention_v2_quant is refused with
-# this error (tests/test_aot_tpu_compile.py pins it).  The [KV, P, ps, 1]
-# f32 scale pages put a unit dim on the 128-lane axis, so the per-page
-# scale DMA is a 1-wide slice of a 128-wide tile.
-_QUANT_RESIDENT_PALLAS_REFUSAL = (
-    "MosaicError: Slice shape along dimension 3 must be aligned to tiling "
-    "(128), but is 1 — the int8-resident kernel's per-page DMA of the "
-    "[KV, P, ps, 1] f32 scale planes")
-
-
-def resolve_serving_kernels(kernels=None, *, tp: bool = False,
-                            interpret: bool = False,
+def resolve_serving_kernels(*, tp: bool = False, interpret: bool = False,
                             quantized_resident: bool = False,
                             recurrent: bool = False,
                             chunk=(0, 0)) -> ServingKernelPolicy:
-    """Resolve the serving kernel-dispatch policy ONCE, at engine build.
+    """The readers of an engine's programs, resolved ONCE, at its build
+    (``serving_engine``), from what the build can observe: so what the
+    programs compiled with is what ``/statusz`` reports.  Nothing is
+    read from a config or the environment.
 
-    ``kernels``: a ``KernelsConfig`` / dict / None (all-auto).  Env vars
-    are the overrides of last resort and are read HERE — never again at
-    trace time — so the policy a program compiled with is exactly the
-    policy ``/statusz`` reports: ``DSTPU_PAGED_ATTENTION`` /
-    ``DSTPU_FUSED_SAMPLING`` name a mode directly, and the legacy
-    spellings ``DSTPU_FORCE_PAGED_PALLAS=1`` (→ ``pallas_v2``, or
-    ``pallas_v1`` when ``DSTPU_PAGED_V1=1`` rides along) and
-    ``DSTPU_FORCE_FUSED_SAMPLING=1`` (→ ``on``) keep working.
-
-    A forced Pallas paged kernel under tensor parallelism is demoted to
-    ``xla`` VISIBLY (a kernel is one device's and reads a row's whole
-    page table, where the KV heads are sharded over the mesh): a
-    ``fallbacks`` row + the ``serving_kernel_fallbacks`` counter.  Over
-    ``quantized_resident`` pages (``kv_tier.quantized_resident``) on a
-    real chip (``interpret=False``) the compiler refuses the int8 kernel:
-    a forced ``pallas_v2`` raises :class:`ServingKernelRefused` here at
-    build, and ``auto`` resolves to ``xla`` with a ``fallbacks`` row.
-    ``recurrent``: the family has recurrent layers, whose per-slot state
-    a decode program steps in place through ``dstpu_state_step`` on one
-    device (:func:`state_stepper`): never configured; where ``tp``
-    demotes it ``state_step`` reads ``xla`` beside a ``fallbacks`` row.
-    ``chunk``: the (tokens, head width) of the engine's chunk programs,
-    whose reader the ``chunk`` row names (``window`` is the family's:
-    ``serving_engine`` asks its ``Recurrent.chunk_reader``).
-    An already-resolved :class:`ServingKernelPolicy` passes through:
-    ``serving_engine`` resolves once, so the kernels the closures baked
-    and the policy ``/statusz`` reports are one object."""
-    from deepspeed_tpu.config import KernelsConfig
-
-    if isinstance(kernels, ServingKernelPolicy):
-        return kernels
-    cfg = KernelsConfig.coerce(kernels)
-    paged = cfg.paged_attention
-    fused = cfg.fused_sampling
-    env_overrides = []
-    env_pa = os.environ.get("DSTPU_PAGED_ATTENTION", "")
-    if env_pa:
-        if env_pa not in ("auto", "xla", "pallas_v1", "pallas_v2"):
-            raise ValueError(
-                f"DSTPU_PAGED_ATTENTION must be auto|xla|pallas_v1|"
-                f"pallas_v2, got {env_pa!r}")
-        paged = env_pa
-        env_overrides.append(
-            ("paged_attention", env_pa, "DSTPU_PAGED_ATTENTION"))
-    elif os.environ.get("DSTPU_FORCE_PAGED_PALLAS", "") == "1":
-        paged = ("pallas_v1"
-                 if os.environ.get("DSTPU_PAGED_V1", "") == "1"
-                 else "pallas_v2")
-        env_overrides.append(
-            ("paged_attention", paged, "DSTPU_FORCE_PAGED_PALLAS"))
-    env_fs = os.environ.get("DSTPU_FUSED_SAMPLING", "")
-    if env_fs:
-        if env_fs not in ("auto", "off", "on"):
-            raise ValueError(
-                f"DSTPU_FUSED_SAMPLING must be auto|off|on, got "
-                f"{env_fs!r}")
-        fused = env_fs
-        env_overrides.append(
-            ("fused_sampling", env_fs, "DSTPU_FUSED_SAMPLING"))
-    elif os.environ.get("DSTPU_FORCE_FUSED_SAMPLING", "") == "1":
-        fused = "on"
-        env_overrides.append(
-            ("fused_sampling", "on", "DSTPU_FORCE_FUSED_SAMPLING"))
-
+    ``tp``: a model or expert axis shards the cache; ``interpret``: no
+    TPU backend; ``quantized_resident``: the pages are int8 codes
+    (``kv_tier.quantized_resident``), which every reader gathers and
+    dequantizes.  ``recurrent``: the family has recurrent layers, whose
+    per-slot state a decode program steps in place through
+    ``dstpu_state_step`` on one device (:func:`state_stepper`); where
+    ``tp`` takes that, ``state_step`` reads ``xla`` beside a
+    ``fallbacks`` row.  ``chunk``: the (tokens, head width) of the
+    engine's chunk programs, whose reader the ``chunk`` row names
+    (``window`` is the family's: ``serving_engine`` asks its
+    ``Recurrent.chunk_reader``)."""
     fallbacks = []
-    if tp and paged in ("pallas_v1", "pallas_v2"):
-        fallbacks.append((f"paged_attention={paged}", "xla",
-                          "tp_unsupported: KV heads are sharded over "
-                          "the mesh; the kernel reads the full page "
-                          "table per device"))
-        paged = "xla"
-    if quantized_resident and not interpret:
-        if paged == "pallas_v2":
-            raise ServingKernelRefused(
-                "kernels.paged_attention=pallas_v2 cannot serve "
-                "int8-resident pages (kv_tier.quantized_resident) on "
-                f"TPU: {_QUANT_RESIDENT_PALLAS_REFUSAL}. Use "
-                "paged_attention=xla (dequantize + gather), or drop "
-                "quantized_resident")
-        if paged == "auto":
-            fallbacks.append(("paged_attention=auto", "xla",
-                              "quant_resident_unsupported: "
-                              + _QUANT_RESIDENT_PALLAS_REFUSAL))
-            paged = "xla"
-    if fused == "auto":
-        # the measured policy (KERNEL_BENCH.json fused_sample_vs_xla):
-        # sampling is one [B, V] argmax and the jitted XLA twin wins at
-        # every serving shape swept, so auto resolves off and the fused
-        # kernel stays a forced arm until a chip re-stamp says otherwise
-        # (ops/sampling_pallas.py)
-        from deepspeed_tpu.ops.sampling_pallas import pallas_sample_gate
-
-        fused = "on" if pallas_sample_gate(interpret=interpret) else "off"
     stepper, why = state_stepper(decode=recurrent, tp=tp)
     if recurrent and stepper != "pallas":
         fallbacks.append(("state_step=pallas", stepper, why))
-    reader = functools.partial(paged_reader, paged, tp=tp, interpret=interpret,
+    reader = functools.partial(paged_reader, tp=tp, interpret=interpret,
                                quant=quantized_resident)
     return ServingKernelPolicy(
-        paged_attention=paged, fused_sampling=fused,
-        env_overrides=tuple(env_overrides), fallbacks=tuple(fallbacks),
         decode=reader(decode=True), state_step=stepper,
-        chunk=reader(decode=False, tokens=chunk[0], head_dim=chunk[1]))
+        chunk=reader(decode=False, tokens=chunk[0], head_dim=chunk[1]),
+        fallbacks=tuple(fallbacks))
 
 
 def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
-                         continuation: bool, prefill: bool,
-                         paged_kernel: str, flash_force_reference: bool,
-                         interpret: bool = False, kps=None, vps=None):
+                         continuation: bool, prefill: bool, reader: str,
+                         flash_force_reference: bool, kps=None, vps=None):
     """The per-layer paged-attention step every model family shares:
     page writes + the right attention for the phase, on the WHOLE pool.
 
@@ -1790,57 +1442,39 @@ def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
     its index, so the program updates the pool in place and never holds
     a copy of it or of one layer (a per-layer store passes ``kp[None]``
     and layer 0: :func:`~deepspeed_tpu.inference.paged_forward.paged_layered_fns`).
-    ``paged_kernel`` is the RESOLVED dispatch ("xla" | "pallas_v1" |
-    "pallas_v2": :func:`paged_reader`'s answer for this phase and these
-    shapes; no env reads here).  A forced Pallas kernel with
-    ``interpret=True`` runs in interpret mode: an explicit request, and
-    how the CPU identity gates exercise the kernels.  ``kps``/``vps``
-    non-None selects the int8-resident path: kp/vp hold int8 codes,
-    kps/vps the per-token-row f32 scales, writes quantize on device, and
-    "pallas_v2" dispatches the dequant-fused kernel ("xla" gathers the
-    codes and dequantizes them, :func:`dequantize_pages`; no quantized
-    v1).  Phases: chunked-prefill continuation (split-fuse), whole-prompt
-    prefill (empty cache), or single-token decode.  Returns (attn [B, T,
-    H, Dh], kp, vp, kps, vps)."""
+    ``reader`` is :func:`paged_reader`'s answer for this phase and these
+    shapes: "xla" (the gather) or the Mosaic kernel's name.  ``kps``/
+    ``vps`` non-None: the pages are int8-resident: kp/vp hold int8
+    codes, kps/vps the per-token-row f32 scales, writes quantize on
+    device, and the reader (always "xla") gathers the codes and
+    dequantizes them (:func:`dequantize_pages`).  Phases:
+    chunked-prefill continuation (split-fuse), whole-prompt prefill
+    (empty cache), or single-token decode.  Returns (attn [B, T, H, Dh],
+    kp, vp, kps, vps)."""
     from deepspeed_tpu.ops.attention import flash_attention
 
     quant = kps is not None
-    if quant and paged_kernel == "pallas_v1":
-        raise ValueError("int8-resident pages have no pallas_v1 kernel "
-                         "(use xla or pallas_v2)")
+    if quant and reader != "xla":
+        raise ValueError(f"int8-resident pages are gathered, not {reader}")
     # the one place for the three attention scopes (kv_write, kv_attend,
     # flash): forward_paged and its layered twin pass through here
     write, attend = jax.named_scope("kv_write"), jax.named_scope("kv_attend")
     if continuation and q.shape[1] > 1:
-        if quant:
-            with write:
+        with write:
+            if quant:
                 kp, kps, vp, vps = write_chunk_pages_quant(
                     kp, kps, vp, vps, layer, k, v, table, start)
-            with attend:
-                if paged_kernel == "pallas_v2":
-                    attn = paged_chunk_attention_v2_quant(
-                        q, kp, kps, vp, vps, table, start,
-                        interpret=interpret, layer=layer)
-                else:
-                    attn = paged_chunk_attention_reference(
-                        q, kp, vp, table, start, layer=layer,
-                        k_scale=kps, v_scale=vps)
-        else:
-            with write:
+            else:
                 kp, vp = write_chunk_pages(kp, vp, layer, k, v, table,
                                            start)
-            with attend:
-                if paged_kernel == "pallas_v1":
-                    attn = paged_chunk_attention(q, kp, vp, table, start,
-                                                 interpret=interpret,
-                                                 layer=layer)
-                elif paged_kernel == "pallas_v2":
-                    attn = paged_chunk_attention_v2(
-                        q, kp, vp, table, start, interpret=interpret,
-                        layer=layer)
-                else:
-                    attn = paged_chunk_attention_reference(
-                        q, kp, vp, table, start, layer=layer)
+        with attend:
+            if reader != "xla":
+                attn = paged_chunk_attention_v2(q, kp, vp, table, start,
+                                                layer=layer)
+            else:
+                attn = paged_chunk_attention_reference(
+                    q, kp, vp, table, start, layer=layer, k_scale=kps,
+                    v_scale=vps)
     elif prefill:
         with jax.named_scope("flash"):
             attn = flash_attention(q, k, v, causal=True,
@@ -1852,37 +1486,23 @@ def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
             else:
                 kp, vp = write_prompt_pages(kp, vp, layer, k, v, table)
     else:
-        if quant:
-            with write:
+        with write:
+            if quant:
                 kp, kps, vp, vps = write_token_pages_quant(
                     kp, kps, vp, vps, layer, k[:, 0], v[:, 0], table,
                     start)
-            with attend:
-                if paged_kernel == "pallas_v2":
-                    attn = paged_decode_attention_v2_quant(
-                        q[:, 0], kp, kps, vp, vps, table, start + 1,
-                        interpret=interpret, layer=layer)[:, None]
-                else:
-                    attn = paged_attention_reference(
-                        q[:, 0], kp, vp, table, start + 1, layer=layer,
-                        k_scale=kps, v_scale=vps)[:, None]
-        else:
-            with write:
+            else:
                 kp, vp = write_token_pages(kp, vp, layer, k[:, 0],
                                            v[:, 0], table, start)
-            with attend:
-                if paged_kernel == "pallas_v1":
-                    attn = paged_decode_attention(
-                        q[:, 0], kp, vp, table, start + 1,
-                        interpret=interpret, layer=layer)[:, None]
-                elif paged_kernel == "pallas_v2":
-                    attn = paged_decode_attention_v2(
-                        q[:, 0], kp, vp, table, start + 1,
-                        interpret=interpret, layer=layer)[:, None]
-                else:
-                    attn = paged_attention_reference(
-                        q[:, 0], kp, vp, table, start + 1,
-                        layer=layer)[:, None]
+        with attend:
+            if reader != "xla":
+                attn = paged_decode_attention_v2(
+                    q[:, 0], kp, vp, table, start + 1,
+                    layer=layer)[:, None]
+            else:
+                attn = paged_attention_reference(
+                    q[:, 0], kp, vp, table, start + 1, layer=layer,
+                    k_scale=kps, v_scale=vps)[:, None]
     return attn, kp, vp, kps, vps
 
 
@@ -2142,58 +1762,3 @@ def paged_period_loop(period, x, stacks, cache: PagedKVCache, periods: int):
     return x, cache._replace(k=k, v=v, expert_rows=rows, conv=conv,
                              state=state)
 
-
-# ---- the blocked chunk reader's call (kernel and block sizes: above)
-def paged_chunk_attention_v2(q, k_pages, v_pages, table, start,
-                             scale: Optional[float] = None,
-                             pages_per_block: Optional[int] = None,
-                             interpret: bool = False, layer=None,
-                             block_q: Optional[int] = None):
-    """Blocked chunked-prefill attention on the chip — same contract as
-    :func:`paged_chunk_attention_reference`: the Mosaic kernel
-    ``dstpu_paged_chunk_v2`` (:func:`_chunk_v2_kernel`).  q [B, C, H, Dh]
-    goes in and the result comes out as the family's hooks hold them
-    (``[B, C, H Dh]``: a reshape, no copy); the pool stays in HBM in its
-    stored layout.  ``block_q`` and ``pages_per_block`` follow from the
-    shapes (:func:`chunk_blocks`); tests pass them to put a block edge
-    where they want one."""
-    B, C, H, Dh = q.shape
-    layer, k_pages, v_pages = _as_pool(layer, k_pages, v_pages)
-    _, KV, _, ps, _ = k_pages.shape
-    G, mp = H // KV, table.shape[1]
-    scale = scale if scale is not None else Dh ** -0.5
-    operand = jnp.promote_types(q.dtype, k_pages.dtype)
-    bq, ppb = chunk_blocks(H, KV, Dh, ps, operand.itemsize, C, mp)
-    bq, ppb = block_q or bq, min(mp, pages_per_block or ppb)
-    Cp = -(-C // bq) * bq           # whole blocks: a few rows pad to 8
-    qf = q.reshape(B, C, H * Dh)
-    if Cp != C:
-        qf = jnp.pad(qf, ((0, 0), (0, Cp - C), (0, 0)))
-    rows = G * bq
-    block = pl.BlockSpec((1, bq, H * Dh), lambda b, i, *_: (b, i, 0))
-    out = pl.pallas_call(
-        functools.partial(_chunk_v2_kernel, scale=scale, ps=ps, group=G,
-                          chunk=C),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,   # table, start, layer
-            grid=(B, Cp // bq),
-            in_specs=[block, pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=block,
-            scratch_shapes=[
-                pltpu.VMEM((KV, rows, Dh), operand),
-                pltpu.VMEM((KV, rows, Dh), jnp.float32),
-                pltpu.VMEM((KV, rows, 1), jnp.float32),
-                pltpu.VMEM((KV, rows, 1), jnp.float32),
-                pltpu.VMEM((2, KV, ppb, ps, Dh), k_pages.dtype),
-                pltpu.VMEM((2, KV, ppb, ps, Dh), v_pages.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, Cp, H * Dh), q.dtype),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=(8 << 20) + (
-            _chunk_step_bytes(bq, ppb * ps, H, KV, Dh, operand.itemsize))),
-        interpret=interpret,
-        name="dstpu_paged_chunk_v2",
-    )(table, start, _layer_operand(layer), qf, k_pages, v_pages)
-    return out[:, :C].reshape(B, C, H, Dh)

@@ -31,21 +31,21 @@ from deepspeed_tpu.models.family import (CarriedRows, CarriedState,
 from deepspeed_tpu.parallel.moe import extra_pair_passes
 
 
-def _interpret(interpret: Optional[bool]) -> bool:
+def _interpret(interpret: Optional[bool] = None) -> bool:
     return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
 def _paged_block(fam, out, cfg, x, lp, ctx, layer, kp, vp, kps, vps, rows,
                  table, start, *, continuation: bool, prefill: bool,
-                 paged_kernel, tp: bool, interpret: bool):
+                 reader, tp: bool):
     """One layer over the pool ``kp``/``vp`` ``[L, KV, P, ps, Dh]`` (a
     latent family: ``kp`` ``[L, 1, P, ps, C + Dr]`` alone), ``out`` the layer
-    kind's second half, ``paged_kernel(head_dim=)`` ``paged_reader``'s word."""
+    kind's second half, ``reader(head_dim=)`` ``paged_reader``'s word."""
     B, T = x.shape[:2]
     q, k, v = fam.qkv(cfg, x, lp, *ctx)
-    phase = dict(continuation=continuation, prefill=prefill, paged_kernel=(
-        paged_kernel(head_dim=fam.cache_row(cfg).head_width or q.shape[-1])
-        )[0], flash_force_reference=tp, interpret=interpret)
+    phase = dict(continuation=continuation, prefill=prefill, reader=reader(
+        head_dim=fam.cache_row(cfg).head_width or q.shape[-1])[0],
+        flash_force_reference=tp)
     if fam.latent is not None:
         attn, kp = latent_attention_step(
             q, k, *fam.latent(cfg, lp), kp, layer, table, start, **phase)
@@ -198,7 +198,6 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
 def forward_paged(params, tokens, cfg, cache, *,
                   continuation: bool = False, tp: Optional[bool] = None,
                   interpret: Optional[bool] = None,
-                  paged_kernel: Optional[str] = None,
                   resident: bool = True):
     """Forward over a paged KV cache.  tokens: [B, T] → (logits, cache).
 
@@ -239,15 +238,14 @@ def forward_paged(params, tokens, cfg, cache, *,
     budget and the host discards it (the engine bounds real positions
     by ``max_seq``, and the family's ``check`` bounds ``max_seq``).
 
-    ``paged_kernel``: the build's policy (``resolve_serving_kernels``): a
-    forced "xla" | "pallas_v1" | "pallas_v2", or None/"auto", which
-    ``paged_reader`` answers a layer from the phase, the layout and the
-    shapes (on one device over float pages the Mosaic readers: decode's,
-    and a chunk's at whole 128-row blocks and 128-lane heads; else XLA).  A
-    cache carrying ``k_scale`` planes is int8-resident
-    (``kv_tier.quantized_resident``): writes quantize per token row on
-    device and attention dequantizes in VMEM ("pallas_v2") or via
-    :func:`~deepspeed_tpu.inference.kernels.dequantize_pages` ("xla").
+    Which reader a layer's attention runs is ``paged_reader``'s answer
+    from the phase, the layout and the shapes (on one device over float
+    pages the Mosaic readers: decode's, and a chunk's at whole 128-row
+    blocks and 128-lane heads; else XLA's gather): a rule of the build,
+    not an argument.  A cache carrying ``k_scale`` planes is
+    int8-resident (``kv_tier.quantized_resident``): writes quantize per
+    token row on device and attention gathers the codes and dequantizes
+    them (:func:`~deepspeed_tpu.inference.kernels.dequantize_pages`).
     """
     fam = decoder_family(cfg)
     T = tokens.shape[1]
@@ -268,8 +266,8 @@ def forward_paged(params, tokens, cfg, cache, *,
                 jax.errors.ConcretizationTypeError):
             pass  # traced: caller's responsibility
     x, ctx = fam.embed(params, tokens, start, cfg)
-    paged_kernel = functools.partial(       # a layer's, by its head's width
-        paged_reader, paged_kernel, decode=T == 1, tp=tp, interpret=interpret,
+    reader = functools.partial(             # a layer's, by its head's width
+        paged_reader, decode=T == 1, tp=tp, interpret=interpret,
         quant=cache.k_scale is not None, tokens=T)
 
     def block(out, whole=None, first=0):
@@ -279,8 +277,7 @@ def forward_paged(params, tokens, cfg, cache, *,
             return _paged_block(
                 fam, out, cfg, x, lp, ctx, layer, kp, vp, kps, vps, rows,
                 cache.table, start, continuation=continuation,
-                prefill=prefill, paged_kernel=paged_kernel, tp=tp,
-                interpret=interpret)
+                prefill=prefill, reader=reader, tp=tp)
 
         return run
 
@@ -308,9 +305,7 @@ def forward_paged(params, tokens, cfg, cache, *,
     return fam.head(params, x, cfg), cache._replace(seq_lens=start + T)
 
 
-def paged_layered_fns(cfg, *, tp: bool = False,
-                      interpret: Optional[bool] = None,
-                      paged_kernel: Optional[str] = None):
+def paged_layered_fns(cfg, *, tp: bool = False):
     """Per-layer factoring of :func:`forward_paged` for weight-streamed
     (ZeRO-Inference) serving — the serving twin of a family's
     ``layered_model``: stem (embedding + what the positions give) and
@@ -337,14 +332,13 @@ def paged_layered_fns(cfg, *, tp: bool = False,
 
     def block_fn(lp, x, ctx, kp, vp, table, start, *,
                  continuation: bool, prefill: bool):
-        lp, itp = dequantize_params(lp), _interpret(interpret)
-        pk = functools.partial(
-            paged_reader, paged_kernel, decode=x.shape[1] == 1, tp=tp,
-            interpret=itp, quant=False, tokens=x.shape[1])
+        reader = functools.partial(
+            paged_reader, decode=x.shape[1] == 1, tp=tp,
+            interpret=_interpret(), quant=False, tokens=x.shape[1])
         x, kp, vp, _, _, _ = _paged_block(
-            fam, fam.out, cfg, x, lp, ctx, 0, kp[None], vp[None], None,
-            None, None, table, start, continuation=continuation,
-            prefill=prefill, paged_kernel=pk, tp=tp, interpret=itp)
+            fam, fam.out, cfg, x, dequantize_params(lp), ctx, 0, kp[None],
+            vp[None], None, None, None, table, start,
+            continuation=continuation, prefill=prefill, reader=reader, tp=tp)
         return x, kp[0], vp[0]
 
     def head_fn(hp, x):

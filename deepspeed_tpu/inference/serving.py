@@ -88,9 +88,9 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu import faults as faults_mod
-from deepspeed_tpu.config import (CommConfig, DevprofConfig, FaultsConfig,
-                                  HistoryConfig,
-                                  IncidentsConfig, KernelsConfig,
+from deepspeed_tpu.config import (KERNELS_BLOCK_GONE, CommConfig,
+                                  DevprofConfig, FaultsConfig,
+                                  HistoryConfig, IncidentsConfig,
                                   KVTierConfig, PrefixCacheConfig,
                                   SLOConfig, SpeculativeConfig,
                                   TelemetryConfig, TracingConfig,
@@ -101,7 +101,9 @@ from deepspeed_tpu.faults import ChecksumError, FaultPlan, InjectedFault
 from deepspeed_tpu.history import NULL_HISTORY, MetricHistory
 from deepspeed_tpu.incidents import NULL_INCIDENTS, IncidentManager
 from deepspeed_tpu.inference.kernels import (STATE_DTYPE, PagedKVCache,
-                                             PageAllocator, latent_reader,
+                                             PageAllocator,
+                                             ServingKernelPolicy,
+                                             latent_reader,
                                              resolve_serving_kernels)
 from deepspeed_tpu.inference.paged_forward import forward_paged
 from deepspeed_tpu.inference.prefix_cache import (extend_page_keys,
@@ -177,11 +179,8 @@ def serving_programs(prefill_fn, decode_fn, chunk_prefill_fn, sample,
     names are stable: a capture's "XLA Modules" line says which program
     ran (``jit_dstpu_prefill`` …).
 
-    ``sample`` is the policy-resolved sampler (the fused pallas argmax
-    when ``kernels.fused_sampling`` resolved "on", the jitted XLA twin
-    otherwise) — both emit bit-identical greedy tokens and share the
-    categorical math, so flipping the policy can never change a served
-    greedy stream.  ``expert_rows``: the cache carries a family's count
+    ``sample`` is the engine's sampler (``_sample_rows``).
+    ``expert_rows``: the cache carries a family's count
     of rows routed to its held experts (and of its pair buffer's
     further passes, where it has one), and the decode program returns it
     flat behind its tokens (``[B * K + Eh]`` int32, or one more).
@@ -533,36 +532,17 @@ class ServingEngine:
                     if self._repl is not None else x)
 
         self._put = put_repl
-        # ---- serving-kernel policy: resolved ONCE, here, at build —
-        # config block + env overrides collapse to a concrete choice
-        # per dispatch site BEFORE any program traces (the old
-        # DSTPU_FORCE_PAGED_PALLAS read inside the gate made a cached
-        # trace depend on ambient env state).  Forced Pallas under a
-        # sharded mesh demotes to xla VISIBLY: the reason lands in
-        # policy.fallbacks, the serving_kernel_fallbacks counter, and
-        # /statusz — never a silent False deep in the gate.
-        # kv_tier coerced BEFORE the policy and the cache alloc below:
-        # the quantized_resident mode changes the DEVICE cache's layout
+        # kv_tier coerced BEFORE the cache alloc below: the
+        # quantized_resident mode changes the DEVICE cache's layout
         # (int8 code planes + f32 per-token-row scale planes), not just
-        # the tier pool's host encoding, and with it which paged
-        # kernels the chip can run
+        # the tier pool's host encoding
         kvt = KVTierConfig.coerce(kv_tier)
         self.kv_tier = kvt
         self._kvt_on = kvt.enabled
         self._quant_resident = kvt.enabled and kvt.quantized_resident
-        self._interpret = jax.default_backend() != "tpu"
-        self._kernels = resolve_serving_kernels(
-            kernels, tp=active, interpret=self._interpret,
-            quantized_resident=self._quant_resident)
-        if self._kernels.fused_sampling == "on":
-            from deepspeed_tpu.ops.sampling_pallas import fused_sample_rows
-
-            _itp = self._interpret
-            self._sample_fn = (lambda lg, ky, tm:
-                               fused_sample_rows(lg, ky, tm,
-                                                 interpret=_itp))
-        else:
-            self._sample_fn = _sample_rows
+        # the readers the builder resolved (``serving_engine``: what its
+        # forwards baked): a report for /statusz, nothing here asks it
+        self._kernels = kernels or ServingKernelPolicy()
         # ---- quantized weight placement (the training int8 wire
         # reused for serving, ISSUE 18): when comm.quantized_serving is
         # on, the BUILDER quantizes replica weights host-side so the
@@ -573,12 +553,6 @@ class ServingEngine:
         # the ZeRO-Inference layer stream read it from here.
         self._comm = CommConfig.coerce(comm)
         self.comm_placement: Optional[Dict[str, Any]] = None
-        if self._quant_resident and \
-                self._kernels.paged_attention == "pallas_v1":
-            raise ValueError(
-                "kernels.paged_attention=pallas_v1 cannot serve "
-                "int8-resident pages (kv_tier.quantized_resident) — "
-                "there is no quantized v1 kernel; use xla or pallas_v2")
         with self._sp_build_alloc:
             self.cache = self._alloc_cache(n_layers, n_kv, num_pages,
                                            page_size, head_dim, cache_dtype)
@@ -971,27 +945,20 @@ class ServingEngine:
         self._g_kvt_inflight = r.gauge(
             "kv_tier_promoting_pages",
             "pages with a tier promotion in flight right now")
-        # serving_kernel_dispatch counter family: one counter per
-        # RESOLVED dispatch site (the suffix names the choice
-        # resolve_serving_kernels baked at build), plus the visible
-        # fallback count — together with /statusz "kernels" these make
-        # the policy auditable at runtime, not just at build
-        pk = self._kernels.paged_attention
-        fs = ("fused" if self._kernels.fused_sampling == "on"
-              else "xla")
+        # what the decode sweeps and the samplers dispatched, and what
+        # a mesh took from the build's readers: /statusz "kernels" names
+        # the readers themselves
         self._c_kdisp_paged = r.counter(
-            f"serving_kernel_dispatch_paged_{pk}",
-            "decode sweeps dispatched under the resolved "
-            "paged-attention policy (auto = kernels.paged_reader: the "
-            "reader /statusz kernels.decode names)")
+            "serving_kernel_dispatch_paged",
+            "decode sweeps dispatched (the reader: /statusz "
+            "kernels.decode)")
         self._c_kdisp_sample = r.counter(
-            f"serving_kernel_dispatch_sample_{fs}",
+            "serving_kernel_dispatch_sample",
             "batched sampling dispatches (decode-chunk syncs + "
-            "prefill-boundary flushes) under the resolved sampler")
+            "prefill-boundary flushes)")
         self._c_kernel_fb = r.counter(
             "serving_kernel_fallbacks",
-            "forced kernel choices the build demoted visibly (e.g. "
-            "pallas under a sharded mesh falls back to xla — the "
+            "kernels of the family's that the build's mesh took (the "
             "reason is in /statusz kernels.fallbacks)")
         if self._kernels.fallbacks:
             self._c_kernel_fb.inc(len(self._kernels.fallbacks))
@@ -1274,7 +1241,7 @@ class ServingEngine:
         contracts; the base engine compiles whole-model programs."""
         (dstpu_prefill, dstpu_chunk, dstpu_boundary, dstpu_sweep,
          dstpu_decode) = serving_programs(
-            prefill_fn, decode_fn, chunk_prefill_fn, self._sample_fn,
+            prefill_fn, decode_fn, chunk_prefill_fn, _sample_rows,
             self.decode_chunk, self.max_batch,
             expert_rows=bool(self._n_expert_rows),
             state=self._state_row is not None)
@@ -3681,11 +3648,6 @@ def _live(config_cls):
     return lambda v: config_cls.coerce(v).enabled
 
 
-def _pins_kernels(v) -> bool:
-    k = KernelsConfig.coerce(v)
-    return (k.paged_attention, k.fused_sampling) != ("auto", "auto")
-
-
 # What only the paged-KV decode scheduler has: (keyword, is the value a
 # live request for it, what it asks for).  The encoder engines are
 # fixed-shape batch scorers with no pages, decode loop, sampler or request
@@ -3694,8 +3656,6 @@ def _pins_kernels(v) -> bool:
 _DECODER_ONLY = (
     ("zero_inference", _live(ZeroInferenceConfig),
      "zero_inference streams a paged-KV decoder's layer weights"),
-    ("kernels", _pins_kernels,
-     "the kernels block pins paged-KV decode kernels"),
     ("comm", lambda v: CommConfig.coerce(v).quantized_serving,
      "comm.quantized_serving quantizes TP replica weight placement"),
     ("speculative", _live(SpeculativeConfig),
@@ -3784,6 +3744,8 @@ def serving_engine(params, cfg, **kw):
     tier and stream through a double-buffered HBM working set, so the
     served model's weight image may exceed HBM.
     """
+    if kw.get("kernels") is not None:
+        raise ValueError(KERNELS_BLOCK_GONE)
     try:
         fam = decoder_family(cfg)
     except TypeError:
@@ -3810,19 +3772,18 @@ def serving_engine(params, cfg, **kw):
     # re-read the mutable ambient mesh on a later retrace (a cleared one
     # would silently re-enable pallas kernels over the sharded cache)
     sharded = fam.sharded(mesh)
-    # the kernel policy resolves HERE (config + env, once) with the engine's
-    # predicates: what the closures bake and /statusz reports are one object
+    # the readers resolve HERE, once, from what the build can observe: what
+    # the closures bake and /statusz reports are one object
     kw["kernels"] = resolve_serving_kernels(
-        kw.get("kernels"),
         tp=mesh is not None and any(
             mesh.size(ax) > 1 for ax in ("model", "expert")),
         interpret=jax.default_backend() != "tpu",
         quantized_resident=kvt.enabled and kvt.quantized_resident,
         recurrent=fam.recurrent is not None
         and fam.recurrent.state_row(cfg).state is not None, chunk=(
-            kw.get("prefill_chunk") or 0, fam.cache_row(cfg).head_width
-            or fam.cache_row(cfg).key_width))
-    pk = kw["kernels"].paged_attention
+            # a hit in the prefix cache is absorbed a bucket at a time
+            kw.get("prefill_chunk") or kw.get("prefill_bucket", 32),
+            fam.cache_row(cfg).head_width or fam.cache_row(cfg).key_width))
     if fam.latent is not None:
         kw["kernels"] = kw["kernels"]._replace(
             decode=latent_reader(kw["kernels"].decode))
@@ -3845,11 +3806,11 @@ def serving_engine(params, cfg, **kw):
 
     def step(params, tokens, cache):
         return forward_paged(params, tokens, cfg, cache, tp=sharded,
-                             paged_kernel=pk, resident=resident)
+                             resident=resident)
 
     def chunk_step(params, tokens, cache):
         return forward_paged(params, tokens, cfg, cache, continuation=True,
-                             tp=sharded, paged_kernel=pk, resident=resident)
+                             tp=sharded, resident=resident)
 
     if weight_dtype != "bfloat16":
         from deepspeed_tpu.inference.quantized import quantize_for_inference

@@ -78,7 +78,7 @@ from deepspeed_tpu.inference.kernels import PagedKVCache
 from deepspeed_tpu.inference.paged_forward import paged_layered_fns
 from deepspeed_tpu.inference.serving import (_WIRE_MIN_ELEMS, ServingEngine,
                                              _dispatch_keys, _last_row,
-                                             boundary_program)
+                                             _sample_rows, boundary_program)
 from deepspeed_tpu.models.family import decoder_families
 from deepspeed_tpu.param_stream import TierLayerReader
 from deepspeed_tpu.utils.logging import logger
@@ -442,16 +442,15 @@ class ZeroInferenceServingEngine(ServingEngine):
         # keys from the dispatch ordinal — each the same small function
         # the whole-model programs inline, jitted on its own behind the
         # streamed head
-        sample = self._sample_fn
         K, B = self.decode_chunk, self.max_batch
 
         def dstpu_sample(logits, key, ordinal, j, temps):
             keys = _dispatch_keys(key, ordinal, K, B)
-            return sample(logits[:, -1], keys[j], temps)
+            return _sample_rows(logits[:, -1], keys[j], temps)
 
         self._row_jit = jax.jit(_last_row)
         self._sample_jit = jax.jit(dstpu_sample)
-        self._boundary = jax.jit(boundary_program(sample))
+        self._boundary = jax.jit(boundary_program(_sample_rows))
         self._prefill = self._streamed_prefill
         self._chunk_prefill = self._streamed_chunk_prefill
         self._verify_chunk = self._streamed_verify_chunk
@@ -570,9 +569,6 @@ class ZeroInferenceServingEngine(ServingEngine):
             x = self._run_blocks("decode", x, ctx, k_list, v_list,
                                  cache.table, start)
             logits = self._head_jit(self._head_dev, x)
-            # the policy-resolved sampler (base ctor): the fused pallas
-            # argmax when kernels.fused_sampling resolved "on", the
-            # jitted XLA twin otherwise — bit-identical greedy tokens
             nxt = self._sample_jit(logits, key, ordinal, j, temps)
             cols.append(nxt)
             tok = nxt[:, None]
@@ -778,9 +774,9 @@ def zero_inference_serving_engine(params, cfg, zi, *, family, kernels,
     :func:`~deepspeed_tpu.inference.serving.serving_engine` routes a live
     ``zero_inference`` block here with ``family``, the config's
     :class:`~deepspeed_tpu.models.family.DecoderFamily`, and ``kernels``,
-    the policy it resolved: the per-layer block programs bake its
-    ``paged_attention`` and the engine reports the same policy in
-    /statusz.  ``zi.dtype`` overrides ``weight_dtype``; int8 quantizes on
+    the readers it resolved, which the engine reports in /statusz (the
+    per-layer block programs ask the same rule).  ``zi.dtype`` overrides
+    ``weight_dtype``; int8 quantizes on
     the family's one per-leaf grid (``quant_skip_paths``), so streamed
     int8 serving is token-identical to resident int8 serving."""
     if family.streamed_split is None:
@@ -790,8 +786,7 @@ def zero_inference_serving_engine(params, cfg, zi, *, family, kernels,
             + ", ".join(f.name for f in decoder_families()
                         if f.streamed_split is not None))
     sharded = family.sharded(mesh)
-    fns = paged_layered_fns(cfg, tp=sharded,
-                            paged_kernel=kernels.paged_attention)
+    fns = paged_layered_fns(cfg, tp=sharded)
 
     stem_keys, head_keys = family.streamed_split(cfg)
     stem = {k: params[k] for k in stem_keys}

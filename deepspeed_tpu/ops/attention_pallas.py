@@ -197,7 +197,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, num_q_blocks,
 
 
 def _pick_blocks(T: int, S: int):
-    """Tile sizes measured on v5e (KERNEL_BENCH.json flash_block_sweep,
+    """Tile sizes measured on v5e (a sweep older than the ledger, at
     B=4 T=S=2048 H=16 D=128): (512,512) fwd 5.0ms / fwd+bwd 11.0ms vs
     (256,256) 6.0/15.2 and (128,128) 8.8/25.4 — larger tiles amortize
     the softmax rescale and keep the MXU fed; VMEM still fits at 512

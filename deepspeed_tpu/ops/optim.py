@@ -5,8 +5,7 @@ deepspeed/runtime/fp16/fused_optimizer.py).
 The reference ships CUDA "fused" optimizers that loop over flat param
 buffers in one kernel.  On TPU the idiomatic equivalent is a functional
 ``(init, update)`` pair over the param pytree: XLA fuses the elementwise
-update chain into a single HBM pass per leaf, and a Pallas fused path
-(:mod:`deepspeed_tpu.ops.adam_pallas`) covers the multi-tensor case.
+update chain into a single HBM pass per leaf.
 
 The API is optax-compatible (init(params) -> state; update(grads, state,
 params) -> (updates, state)) so user optax transforms drop in, but the

@@ -1,5 +1,5 @@
 """Atomic JSON evidence writes, shared by every bench/evidence producer
-(bench_serving.py, tools/kernel_bench.py, examples/*_offload.py).
+(bench_serving.py, examples/*_offload.py).
 
 The whole point of incremental evidence flushing is surviving a run
 killed at its time limit — so the flush itself must never be the thing

@@ -1,78 +1,16 @@
-"""Tests: pallas fused Adam, stochastic rounding, paged decode attention."""
+"""Tests: stochastic rounding, paged attention (the references, the
+page store, the rule that picks a reader, the two Mosaic readers in
+interpret mode)."""
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from deepspeed_tpu.ops import optim
-from deepspeed_tpu.ops.adam_pallas import adam_update_flat, fused_adam
 from deepspeed_tpu.ops.rounding import (stochastic_round_bf16,
                                         stochastic_round_tree)
 from deepspeed_tpu.inference.kernels import (PageAllocator, PagedKVCache,
-                                             paged_attention_reference,
-                                             paged_decode_attention)
-
-
-class TestFusedAdamPallas:
-    def test_matches_reference_adam(self):
-        ref = optim.adam(lr=0.01, weight_decay=0.1)
-        fus = fused_adam(lr=0.01, weight_decay=0.1, interpret=True)
-        params = {"w": jax.random.normal(jax.random.PRNGKey(0), (33, 7)),
-                  "b": jax.random.normal(jax.random.PRNGKey(1), (129,))}
-        rs, fs = ref.init(params), fus.init(params)
-        g = jax.tree.map(
-            lambda p: jax.random.normal(jax.random.PRNGKey(2), p.shape), params)
-        for _ in range(3):
-            ru, rs = ref.update(g, rs, params)
-            fu, fs = fus.update(g, fs, params)
-            params_r = jax.tree.map(lambda p, u: p + u, params, ru)
-            params = jax.tree.map(lambda p, u: p + u, params, fu)
-            jax.tree.map(lambda a, b: np.testing.assert_allclose(
-                a, b, atol=1e-6), params, params_r)
-        jax.tree.map(lambda a, b: np.testing.assert_allclose(
-            a, b, atol=1e-6), fs.mu, rs.mu)
-
-    def test_bf16_grads_and_params(self):
-        g = jax.random.normal(jax.random.PRNGKey(0), (300,)).astype(jnp.bfloat16)
-        p = jnp.ones((300,), jnp.bfloat16)
-        m = jnp.zeros((300,), jnp.float32)
-        v = jnp.zeros((300,), jnp.float32)
-        u, m1, v1 = adam_update_flat(g, m, v, p, jnp.int32(0), 0.1,
-                                     interpret=True)
-        assert u.dtype == jnp.float32 and u.shape == (300,)
-        assert jnp.isfinite(u).all()
-
-    def test_schedule_parity_with_reference(self):
-        # warmup schedule: step-1 off-by-one would use lr=0 on step one
-        sched = lambda s: 0.05 * jnp.minimum(s.astype(jnp.float32) / 3.0, 1.0)
-        ref, fus = optim.adam(lr=sched), fused_adam(lr=sched, interpret=True)
-        params = {"w": jnp.ones((32,))}
-        rs, fs = ref.init(params), fus.init(params)
-        g = {"w": jnp.full((32,), 0.5)}
-        for _ in range(4):
-            ru, rs = ref.update(g, rs, params)
-            fu, fs = fus.update(g, fs, params)
-            np.testing.assert_allclose(fu["w"], ru["w"], atol=1e-7)
-
-    def test_tuple_params_tree(self):
-        fus = fused_adam(lr=0.01, interpret=True)
-        params = (jnp.ones((16,)), {"b": jnp.ones((8,))})
-        st = fus.init(params)
-        g = jax.tree.map(jnp.ones_like, params)
-        u, st = fus.update(g, st, params)
-        assert isinstance(u, tuple) and u[0].shape == (16,)
-        assert u[1]["b"].shape == (8,)
-
-    def test_schedule_lr(self):
-        sched = lambda s: 0.1 / (1.0 + s.astype(jnp.float32))
-        fus = fused_adam(lr=sched, interpret=True)
-        params = {"w": jnp.ones((16,))}
-        st = fus.init(params)
-        g = {"w": jnp.ones((16,))}
-        u0, st = fus.update(g, st, params)
-        u1, st = fus.update(g, st, params)
-        assert abs(float(u1["w"][0])) < abs(float(u0["w"][0]))
+                                             paged_attention_reference)
 
 
 class TestStochasticRounding:
@@ -135,29 +73,6 @@ class TestPagedAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
 
-    def test_pallas_matches_reference(self):
-        B, H, KV, P, ps, Dh = 2, 8, 2, 12, 8, 16
-        kp, vp = _mk_pages(KV, P, ps, Dh)
-        # non-trivial page table: scrambled pages
-        table = jnp.asarray([[3, 7, 1, 0], [5, 2, 9, 11]], jnp.int32)
-        lens = jnp.asarray([29, 17], jnp.int32)
-        q = jax.random.normal(jax.random.PRNGKey(4), (B, H, Dh))
-        ref = paged_attention_reference(q, kp, vp, table, lens)
-        out = paged_decode_attention(q, kp, vp, table, lens, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=1e-4)
-
-    def test_pallas_mha_no_gqa(self):
-        B, H, KV, P, ps, Dh = 1, 4, 4, 8, 8, 16
-        kp, vp = _mk_pages(KV, P, ps, Dh, seed=9)
-        table = jnp.asarray([[0, 1, 2, 3]], jnp.int32)
-        lens = jnp.asarray([26], jnp.int32)
-        q = jax.random.normal(jax.random.PRNGKey(5), (B, H, Dh))
-        ref = paged_attention_reference(q, kp, vp, table, lens)
-        out = paged_decode_attention(q, kp, vp, table, lens, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=1e-4)
-
     def test_cache_write_and_attend(self):
         cache = PagedKVCache.alloc(n_layers=1, n_kv=2, num_pages=8,
                                    page_size=4, head_dim=16, batch=2,
@@ -183,28 +98,6 @@ class TestPagedAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
 
-    def test_empty_sequence_zero_output(self):
-        kp, vp = _mk_pages(2, 8, 8, 16)
-        table = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
-        lens = jnp.asarray([10, 0], jnp.int32)
-        q = jax.random.normal(jax.random.PRNGKey(6), (2, 4, 16))
-        ref = paged_attention_reference(q, kp, vp, table, lens)
-        out = paged_decode_attention(q, kp, vp, table, lens, interpret=True)
-        np.testing.assert_allclose(np.asarray(out[1]), 0.0)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=1e-4)
-
-    def test_stale_table_ids_masked(self):
-        # dead slots hold garbage ids; clamped to page 0 and masked
-        kp, vp = _mk_pages(2, 8, 8, 16)
-        table = jnp.asarray([[0, 1, 7, 7]], jnp.int32)
-        stale = jnp.asarray([[0, 1, 6, 5]], jnp.int32)  # dead slots differ
-        lens = jnp.asarray([12], jnp.int32)              # only 2 live pages
-        q = jax.random.normal(jax.random.PRNGKey(7), (1, 4, 16))
-        a = paged_decode_attention(q, kp, vp, table, lens, interpret=True)
-        b = paged_decode_attention(q, kp, vp, stale, lens, interpret=True)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
-
     def test_cache_overflow_raises(self):
         cache = PagedKVCache.alloc(n_layers=1, n_kv=1, num_pages=2,
                                    page_size=2, head_dim=8, batch=1,
@@ -227,63 +120,6 @@ class TestPagedAttention:
         assert set(c) == set(a)
 
 
-class TestPagedChunkAttention:
-    """Chunked-prefill kernel vs the masked-gather reference."""
-
-    def test_pallas_matches_reference_gqa(self):
-        from deepspeed_tpu.inference.kernels import (
-            paged_chunk_attention, paged_chunk_attention_reference)
-
-        B, C, H, KV, P, ps, Dh = 2, 6, 8, 2, 12, 8, 16
-        kp, vp = _mk_pages(KV, P, ps, Dh, seed=11)
-        table = jnp.asarray([[3, 7, 1, 0], [5, 2, 9, 11]], jnp.int32)
-        start = jnp.asarray([9, 0], jnp.int32)  # mid-sequence and fresh
-        q = jax.random.normal(jax.random.PRNGKey(6), (B, C, H, Dh))
-        ref = paged_chunk_attention_reference(q, kp, vp, table, start)
-        out = paged_chunk_attention(q, kp, vp, table, start,
-                                    interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=1e-4)
-
-    def test_pallas_mha_single_row(self):
-        from deepspeed_tpu.inference.kernels import (
-            paged_chunk_attention, paged_chunk_attention_reference)
-
-        B, C, H, KV, P, ps, Dh = 1, 4, 4, 4, 6, 8, 16
-        kp, vp = _mk_pages(KV, P, ps, Dh, seed=12)
-        table = jnp.asarray([[0, 1, 2, 3]], jnp.int32)
-        start = jnp.asarray([13], jnp.int32)
-        q = jax.random.normal(jax.random.PRNGKey(7), (B, C, H, Dh))
-        ref = paged_chunk_attention_reference(q, kp, vp, table, start)
-        out = paged_chunk_attention(q, kp, vp, table, start,
-                                    interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=1e-4)
-
-    def test_causal_within_chunk(self):
-        """Earlier chunk rows must not see later rows' K/V: perturbing a
-        later position's page contents leaves earlier outputs unchanged."""
-        from deepspeed_tpu.inference.kernels import paged_chunk_attention
-
-        B, C, H, KV, P, ps, Dh = 1, 4, 2, 2, 4, 4, 8
-        kp, vp = _mk_pages(KV, P, ps, Dh, seed=13)
-        table = jnp.asarray([[0, 1, 2, 3]], jnp.int32)
-        start = jnp.asarray([5], jnp.int32)
-        q = jax.random.normal(jax.random.PRNGKey(8), (B, C, H, Dh))
-        base = paged_chunk_attention(q, kp, vp, table, start,
-                                     interpret=True)
-        # position start+C-1 = 8 lives in page slot 2, in-page 0
-        kp2 = kp.at[:, 2, 0].add(100.0)
-        vp2 = vp.at[:, 2, 0].add(100.0)
-        pert = paged_chunk_attention(q, kp2, vp2, table, start,
-                                     interpret=True)
-        # rows 0..2 (positions 5..7) unchanged; row 3 (position 8) differs
-        np.testing.assert_allclose(np.asarray(pert[:, :3]),
-                                   np.asarray(base[:, :3]), atol=1e-6)
-        assert not np.allclose(np.asarray(pert[:, 3]),
-                               np.asarray(base[:, 3]))
-
-
 class TestPagedGatePolicy:
     """The rule that replaced the byte threshold: which reader a paged
     program runs follows from the phase, the device layout and the page
@@ -299,9 +135,9 @@ class TestPagedGatePolicy:
     def test_decode_takes_the_kernel_at_every_shape(self, family, rows,
                                                     table, monkeypatch):
         """``forward_paged`` traced as the chip would (``interpret=False``)
-        with the default policy: the decode program holds a Pallas call,
-        the chunk program none.  An env switch set after the build
-        changes nothing: the trace reads no environment."""
+        : the decode program holds a Pallas call, the chunk program (four
+        rows) none.  The old env switches change nothing: the trace
+        reads no environment."""
         from deepspeed_tpu.inference.kernels import PagedKVCache
         from deepspeed_tpu.inference.paged_forward import forward_paged
         from deepspeed_tpu.models import gpt2, llama
@@ -336,51 +172,38 @@ class TestPagedGatePolicy:
         assert "pallas_call" not in jaxpr(4, True)
 
     @pytest.mark.parametrize("layout,reader,why", [
-        (dict(), "pallas_v2", "decode on one device"),
+        (dict(), "dstpu_paged_decode", "decode on one device"),
         (dict(tp=True), "xla", "tp"),
         (dict(quant=True), "xla", "int8-resident"),
         (dict(interpret=True), "xla", "interpret"),
         (dict(decode=False), "xla", "chunk"),
     ])
-    def test_auto_answers_from_phase_and_layout(self, layout, reader, why):
+    def test_the_rule_answers_from_phase_and_layout(self, layout, reader,
+                                                    why):
         from deepspeed_tpu.inference.kernels import paged_reader
 
         kw = dict(decode=True, tp=False, interpret=False, quant=False)
         kw.update(layout)
-        for policy in (None, "auto"):
-            got, reason = paged_reader(policy, **kw)
-            assert got == reader and why in reason
-        # a forced policy is itself, whatever the layout
-        for forced in ("xla", "pallas_v1", "pallas_v2"):
-            assert paged_reader(forced, **kw) == (forced, "forced")
+        got, reason = paged_reader(**kw)
+        assert got == reader and why in reason
 
-    @pytest.mark.parametrize("build,reader,row", [
-        (dict(), "pallas_v2", None),
-        (dict(tp=True), "xla", None),
-        (dict(tp=True, kernels={"paged_attention": "pallas_v2"}), "xla",
-         "tp_unsupported"),
-        (dict(quantized_resident=True), "xla", "quant_resident_unsupported"),
-        (dict(interpret=True), "xla", None),
-        (dict(interpret=True, kernels={"paged_attention": "pallas_v2"}),
-         "pallas_v2", None),
+    @pytest.mark.parametrize("build,reader,why", [
+        (dict(), "dstpu_paged_decode", "decode on one device"),
+        (dict(tp=True), "xla", "tp"),
+        (dict(quantized_resident=True), "xla", "int8-resident"),
+        (dict(interpret=True), "xla", "interpret"),
     ])
     def test_the_build_says_which_reader_decode_baked(self, build, reader,
-                                                      row):
+                                                      why):
         """``/statusz`` names the decode program's reader with its
-        reason; what the build had to demote keeps its ``fallbacks``
-        row (a forced kernel under TP, ``auto`` over int8-resident
-        pages on a chip)."""
+        reason; a family with no kernel of its own for a mesh to take
+        has no ``fallbacks`` row."""
         from deepspeed_tpu.inference.kernels import resolve_serving_kernels
 
-        kw = dict(tp=False, interpret=False, quantized_resident=False)
-        kw.update(build)
-        d = resolve_serving_kernels(kw.pop("kernels", None), **kw).as_dict()
-        assert d["decode"]["reader"] == reader and d["decode"]["reason"]
-        if row is None:
-            assert d["fallbacks"] == []
-        else:
-            assert [f["demoted_to"] for f in d["fallbacks"]] == ["xla"]
-            assert row in d["fallbacks"][0]["reason"]
+        d = resolve_serving_kernels(**build).as_dict()
+        assert d["decode"]["reader"] == reader
+        assert why in d["decode"]["reason"]
+        assert d["fallbacks"] == []
 
     def test_no_byte_threshold_is_left(self):
         from deepspeed_tpu.inference import kernels
@@ -393,8 +216,46 @@ class TestPagedDecodeV2:
     """Multi-page-per-step decode kernel (paged_decode_attention_v2):
     interpret-mode numerics vs the gather oracle.  The kernel streams
     ppcb pages per inner iteration by explicit double-buffered DMA and
-    reads only live pages — the fix for the v1 shape measured 25x
-    slower than the gather (KERNEL_BENCH r5)."""
+    reads only live pages."""
+
+    # (H, KV, pages, table, lens): a scrambled table under GQA; MHA,
+    # one row; an empty sequence beside a live one
+    LAYOUTS = {
+        "gqa_scrambled_table": (8, 2, 12, [[3, 7, 1, 0], [5, 2, 9, 11]],
+                                [29, 17]),
+        "mha_one_row": (4, 4, 8, [[0, 1, 2, 3]], [26]),
+        "empty_sequence": (4, 2, 8, [[0, 1], [2, 3]], [10, 0]),
+    }
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_matches_the_gather(self, layout):
+        from deepspeed_tpu.inference.kernels import paged_decode_attention_v2
+
+        H, KV, P, table, lens = self.LAYOUTS[layout]
+        kp, vp = _mk_pages(KV, P, 8, 16, seed=len(layout))
+        table = jnp.asarray(table, jnp.int32)
+        lens = jnp.asarray(lens, jnp.int32)
+        q = jax.random.normal(jax.random.PRNGKey(4), (len(lens), H, 16))
+        ref = paged_attention_reference(q, kp, vp, table, lens)
+        out = paged_decode_attention_v2(q, kp, vp, table, lens,
+                                        interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=1e-4)
+        # empty sequences (continuous batching admits them): zeros
+        assert not np.asarray(out)[np.asarray(lens) == 0].any()
+
+    def test_stale_table_ids_change_nothing(self):
+        # dead slots hold whatever ids their last owner left
+        from deepspeed_tpu.inference.kernels import paged_decode_attention_v2
+
+        kp, vp = _mk_pages(2, 8, 8, 16)
+        table = jnp.asarray([[0, 1, 7, 7]], jnp.int32)
+        stale = jnp.asarray([[0, 1, 6, 5]], jnp.int32)  # dead slots differ
+        lens = jnp.asarray([12], jnp.int32)              # only 2 live pages
+        q = jax.random.normal(jax.random.PRNGKey(7), (1, 4, 16))
+        a = paged_decode_attention_v2(q, kp, vp, table, lens, interpret=True)
+        b = paged_decode_attention_v2(q, kp, vp, stale, lens, interpret=True)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def _pages(self, rng, KV, P, ps, Dh):
         k = jnp.asarray(rng.normal(size=(KV, P, ps, Dh)), jnp.float32)
@@ -460,6 +321,53 @@ class TestPagedChunkV2:
     """Multi-page chunked-prefill kernel (paged_chunk_attention_v2) vs
     the gather oracle in interpret mode — the split-fuse twin of
     TestPagedDecodeV2."""
+
+    # (C, H, KV, pages, Dh, table, start): GQA, a row mid-sequence and a
+    # fresh one over scrambled pages; MHA, one row
+    LAYOUTS = {
+        "gqa_mid_sequence_and_fresh": (
+            6, 8, 2, 12, 16, [[3, 7, 1, 0], [5, 2, 9, 11]], [9, 0]),
+        "mha_one_row": (4, 4, 4, 6, 16, [[0, 1, 2, 3]], [13]),
+    }
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_matches_the_gather(self, layout):
+        from deepspeed_tpu.inference.kernels import (
+            paged_chunk_attention_reference, paged_chunk_attention_v2)
+
+        C, H, KV, P, Dh, table, start = self.LAYOUTS[layout]
+        kp, vp = _mk_pages(KV, P, 8, Dh, seed=11)
+        table = jnp.asarray(table, jnp.int32)
+        start = jnp.asarray(start, jnp.int32)
+        q = jax.random.normal(jax.random.PRNGKey(6),
+                              (len(start), C, H, Dh))
+        ref = paged_chunk_attention_reference(q, kp, vp, table, start)
+        out = paged_chunk_attention_v2(q, kp, vp, table, start,
+                                       interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=1e-4)
+
+    def test_a_later_rows_keys_move_no_earlier_row(self):
+        """Earlier chunk rows must not see later rows' K/V: perturbing a
+        later position's page contents leaves earlier outputs unchanged."""
+        from deepspeed_tpu.inference.kernels import paged_chunk_attention_v2
+
+        B, C, H, KV, P, ps, Dh = 1, 4, 2, 2, 4, 4, 8
+        kp, vp = _mk_pages(KV, P, ps, Dh, seed=13)
+        table = jnp.asarray([[0, 1, 2, 3]], jnp.int32)
+        start = jnp.asarray([5], jnp.int32)
+        q = jax.random.normal(jax.random.PRNGKey(8), (B, C, H, Dh))
+        base = paged_chunk_attention_v2(q, kp, vp, table, start,
+                                        interpret=True)
+        # position start+C-1 = 8 lives in page slot 2, in-page 0
+        pert = paged_chunk_attention_v2(
+            q, kp.at[:, 2, 0].add(100.0), vp.at[:, 2, 0].add(100.0), table,
+            start, interpret=True)
+        # rows 0..2 (positions 5..7) unchanged; row 3 (position 8) differs
+        np.testing.assert_allclose(np.asarray(pert[:, :3]),
+                                   np.asarray(base[:, :3]), atol=1e-6)
+        assert not np.allclose(np.asarray(pert[:, 3]),
+                               np.asarray(base[:, 3]))
 
     def _pages(self, rng, KV, P, ps, Dh):
         k = jnp.asarray(rng.normal(size=(KV, P, ps, Dh)), jnp.float32)

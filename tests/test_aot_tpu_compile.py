@@ -26,7 +26,6 @@ from deepspeed_tpu.inference.paged_forward import forward_paged
 from deepspeed_tpu.inference.serving import _sample_rows, serving_programs
 from deepspeed_tpu.models import gpt2, mixtral
 from deepspeed_tpu.ops.attention_pallas import flash_attention_tpu
-from deepspeed_tpu.ops.sampling_pallas import fused_greedy_rows
 
 # (name, heads, kv_heads, batch, seq) — head_dim is 128 in both
 GPT2_1_3B = ("gpt2_1_3b", 16, 16, 4, 1024)
@@ -128,49 +127,40 @@ def test_paged_chunk_v2(chip, layout):
              chip, ((B, 128, H, DH), jnp.bfloat16), kv, kv, table, lens)
 
 
-@pytest.mark.parametrize("vocab", [50257, 128256])
-def test_fused_greedy_rows(chip, vocab):
-    _compile(fused_greedy_rows, chip, ((8, vocab), jnp.float32))
-
-
-def test_quant_resident_kernel_is_refused_by_the_compiler(chip):
-    """The int8-resident decode kernel does not compile for the chip.
-    When this stops raising, the kernel was repaired: lift the refusal
-    in ``resolve_serving_kernels`` in the same change."""
+def test_int8_resident_pages_are_gathered_on_the_chip(chip):
+    """Over int8-resident pages (``kv_tier.quantized_resident``) the
+    rule answers the gather in both phases on a chip, and a decode step
+    over codes and scale planes compiles for it with no Mosaic call: a
+    page copy of the ``[KV, P, ps, 1]`` scale planes is a 1-wide slice
+    of a 128-lane tile, which the compiler refuses ("Slice shape along
+    dimension 3 must be aligned to tiling (128), but is 1", AOT, PR 28),
+    so no kernel reads them."""
+    readers = {decode: K.paged_reader(
+        decode=decode, tp=False, interpret=False, quant=True, tokens=1024,
+        head_dim=DH) for decode in (True, False)}
+    assert set(readers.values()) == {("xla", "int8-resident pages")}
     B, H, kv, table, lens = _pages(GQA_32_8, jnp.int8)
-    scale = (kv[0][:3] + (1,), jnp.float32)
-    with pytest.raises(Exception, match="aligned to tiling"):
-        _compile(lambda q, kq, ks, vq, vs, t, n:
-                 K.paged_decode_attention_v2_quant(q, kq, ks, vq, vs, t, n),
-                 chip, ((B, H, DH), jnp.bfloat16), kv, scale, kv, scale,
-                 table, lens)
+    pool = ((1,) + kv[0], jnp.int8)
+    scale = (pool[0][:4] + (1,), jnp.float32)
+    KV = kv[0][0]
 
+    def step(q, k, v, kq, ks, vq, vs, t, n):
+        return K.paged_attention_step(
+            q, k, v, kq, vq, 0, t, n, continuation=False, prefill=False,
+            reader=readers[True][0], flash_force_reference=False, kps=ks,
+            vps=vs)
 
-@pytest.mark.parametrize("paged,want", [
-    ("pallas_v2", K.ServingKernelRefused), ("auto", "xla"), ("xla", "xla")])
-def test_quant_resident_policy_on_chip(paged, want):
-    """What the engine build does about it (no compile involved): a
-    forced Pallas kernel over an int8-resident cache on a chip is a
-    typed error at build, auto resolves to xla with a visible row."""
-    resolve = lambda: K.resolve_serving_kernels(
-        {"paged_attention": paged}, interpret=False,
-        quantized_resident=True)
-    if want is K.ServingKernelRefused:
-        with pytest.raises(K.ServingKernelRefused, match="aligned to tiling"):
-            resolve()
-        return
-    policy = resolve()
-    assert policy.paged_attention == want
-    assert bool(policy.fallbacks) == (paged == "auto")
-    # interpret mode (the CPU tests) keeps the forced kernel
-    assert K.resolve_serving_kernels(
-        {"paged_attention": "pallas_v2"}, interpret=True,
-        quantized_resident=True).paged_attention == "pallas_v2"
+    new = ((B, 1, KV, DH), jnp.bfloat16)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+        ((B, 1, H, DH), jnp.bfloat16), new, new, pool, scale, pool, scale,
+        table, lens)]
+    hlo = jax.jit(step).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in hlo
 
 
 # ------------------------------------------- the K/V pool stays in place
 # The serving programs at the benchmark's widths, four layers deep, under
-# the default policy (``auto``): (family, config, pool pages, decode rows,
+# the build's rule (``kernels.paged_reader``): (family, config, pool pages, decode rows,
 # table entries, bound on the decode program's temporaries in GiB:
 # PERF.md 4, AOT, PR 25; the chats' 0.017 is of the engine's whole decode
 # program, 64 rows of the sampler's f32 logits included: 15.8 MiB, AOT,
@@ -293,7 +283,7 @@ def test_serving_program_leaves_the_pool_in_place(chip, pool, phase):
     index.  None returns ``[1, T, V]`` logits: a prefill's result is the
     one row its first token is sampled from, a decode's its tokens.
 
-    A decode program reads live pages only: under the default policy it
+    A decode program reads live pages only: under the rule it
     holds the Mosaic decode kernel at every engine (28 x 64 table
     entries, 64 x 64, 6 x 520) and nothing shaped like the gathered copy
     of every slot's whole table row ``[B, KV, max_pages * ps, Dh]``.

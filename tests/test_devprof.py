@@ -115,7 +115,13 @@ def test_the_frames_under_a_builds_lowering_keep_their_words():
     lowering (PERF.md 6, PR 37: +1 to +1.5 s of ``setup_s``).  A PR
     that changes one of these numbers changes set-up: measure it on
     the chip (``note_build``'s ``lower_faults``), then write the new
-    number here."""
+    number here.  PR 47 took ``serving_engine`` 37 -> 35 and
+    ``ServingEngine.__init__`` 78 -> 74 (a local and the sampler's fork
+    went): on the chip the programs' lowering fell (``lower_s`` 5.39 ->
+    4.15 s in the tail cell, 8.80-9.13 -> 6.55-6.62 in chat-sat), their
+    tracing rose (2.27 -> 2.86, 3.44-3.51 -> 3.99-4.00) and warm
+    ``setup_s`` read 39.24 -> 40.27 and 41.37-42.51 -> 38.12-38.41
+    (my chip runs, PR 47; PERF.md 6)."""
     from deepspeed_tpu import initialize
     from deepspeed_tpu.engine import TrainingEngine
     from deepspeed_tpu.inference import serving
@@ -134,7 +140,7 @@ def test_the_frames_under_a_builds_lowering_keep_their_words():
         "TrainingEngine.__init__": words(TrainingEngine.__init__),
         "train_batch": words(TrainingEngine.train_batch),
     } == {
-        "serving_engine": 37, "ServingEngine.__init__": 78,
+        "serving_engine": 35, "ServingEngine.__init__": 74,
         "_devprof_warmup": 31, "_SentinelFn.__call__": 12,
         "initialize": 30, "TrainingEngine.__init__": 39,
         "train_batch": 10,

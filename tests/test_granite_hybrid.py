@@ -414,12 +414,12 @@ def test_the_policy_names_the_state_stepper(params):
     eng = _engine(params)
     kernels = eng.statusz()["kernels"]
     assert kernels["state_step"] == "pallas" and kernels["fallbacks"] == []
-    demoted = K.resolve_serving_kernels(None, tp=True, recurrent=True)
+    demoted = K.resolve_serving_kernels(tp=True, recurrent=True)
     assert demoted.state_step == "xla"
     assert [(f, d) for f, d, _ in demoted.fallbacks] == [
         ("state_step=pallas", "xla")]
     assert "tp" in demoted.as_dict()["fallbacks"][0]["reason"]
-    plain = K.resolve_serving_kernels(None, tp=True)
+    plain = K.resolve_serving_kernels(tp=True)
     assert plain.state_step == "xla" and plain.fallbacks == ()
 
 

@@ -188,7 +188,7 @@ def test_forward_paged_honours_whole_stacks_without_a_lead(
     tokens = jnp.asarray(np.random.default_rng(7).integers(
         0, CFG.vocab_size, (2, 16)), jnp.int32)
     logits, cache = forward_paged(params, tokens, CFG, _cache(),
-                                  interpret=True, paged_kernel="xla", **kw)
+                                  interpret=True, **kw)
     assert seen == [(4, True) if whole else (3, False)]
     np.testing.assert_allclose(
         logits, mixtral.forward_eval(params, tokens, CFG), atol=2e-4,

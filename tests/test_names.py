@@ -381,8 +381,7 @@ def test_the_paged_readers_run_by_name_under_kv_attend(tokens, kernel):
 
 def test_every_mosaic_kernel_has_a_name_of_its_own():
     from deepspeed_tpu.inference import kernels as K
-    from deepspeed_tpu.ops import (adam_pallas, attention_pallas, quant,
-                                   sampling_pallas)
+    from deepspeed_tpu.ops import attention_pallas, quant
 
     q = jnp.zeros((1, 128, 2, 128), jnp.float32)
     flash = lambda q: attention_pallas.flash_attention_tpu(
@@ -391,8 +390,6 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
     table = jnp.zeros((2, 4), jnp.int32)
     start = jnp.zeros((2,), jnp.int32)
     qc = jnp.zeros((2, 8, 2, 128), jnp.float32)
-    codes = jnp.zeros((2, 9, 8, 128), jnp.int8)
-    scale = jnp.ones((2, 9, 8, 1), jnp.float32)
     latent = jnp.zeros((1, 1, 9, 8, 128), jnp.float32)
     sites = {
         "dstpu_mla_decode": lambda: jax.make_jaxpr(
@@ -406,22 +403,12 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
         "dstpu_flash_fwd": lambda: jax.make_jaxpr(flash)(q),
         "dstpu_flash_bwd_dq": lambda: jax.make_jaxpr(jax.grad(flash))(q),
         "dstpu_flash_bwd_dkv": lambda: jax.make_jaxpr(jax.grad(flash))(q),
-        "dstpu_paged_chunk_v1": lambda: jax.make_jaxpr(
-            lambda: K.paged_chunk_attention(qc, pages, pages, table, start,
-                                            interpret=True))(),
         "dstpu_paged_chunk_v2": lambda: jax.make_jaxpr(
             lambda: K.paged_chunk_attention_v2(
                 qc, pages, pages, table, start, interpret=True))(),
         "dstpu_paged_decode": lambda: jax.make_jaxpr(
             lambda: K.paged_decode_attention_v2(
                 qc[:, 0], pages, pages, table, start, interpret=True))(),
-        "dstpu_paged_chunk_v2_q8": lambda: jax.make_jaxpr(
-            lambda: K.paged_chunk_attention_v2_quant(
-                qc, codes, scale, codes, scale, table, start,
-                interpret=True))(),
-        "dstpu_fused_sample": lambda: jax.make_jaxpr(
-            lambda: sampling_pallas.fused_greedy_rows(
-                jnp.zeros((8, 256), jnp.float32), interpret=True))(),
         "dstpu_window_flash_fwd": lambda: jax.make_jaxpr(
             lambda: attention_pallas.window_flash_attention_tpu(
                 q, jnp.zeros((1, 128, 256), jnp.float32),
@@ -437,11 +424,11 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
     for want, make in sites.items():
         names = [n for n, _ in _pallas_scopes(make().jaxpr, [])]
         assert want in names, (want, names)
-    # the sources give fourteen sites fourteen names, none shared
+    # the sources give ten sites ten names, none shared
     named = []
-    for mod in (K, adam_pallas, attention_pallas, quant, sampling_pallas):
+    for mod in (K, attention_pallas, quant):
         with open(mod.__file__) as f:
             text = f.read()
         assert text.count("pl.pallas_call(") == text.count('name="dstpu_')
         named += re.findall(r'name="(dstpu_[a-z0-9_]+)"', text)
-    assert len(named) == len(set(named)) == 14
+    assert len(named) == len(set(named)) == 10

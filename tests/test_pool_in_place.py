@@ -32,7 +32,7 @@ FAMILIES = {
     "llama": (llama, llama.LlamaConfig.tiny),
     "mixtral": (mixtral, mixtral.MixtralConfig.tiny),
 }
-STEP = dict(paged_kernel="xla", flash_force_reference=False, interpret=True)
+STEP = dict(reader="xla", flash_force_reference=False)
 
 
 def _layered(family, cfg, quant):
@@ -41,8 +41,7 @@ def _layered(family, cfg, quant):
     -> (x, pages), head(params, x) -> logits)`` over ONE layer's pages
     ``(kp, vp[, kps, vps])``."""
     if family is not gpt2 and not quant:
-        stem_fn, block_fn, head_fn = paged_layered_fns(
-            cfg, interpret=True, paged_kernel="xla")
+        stem_fn, block_fn, head_fn = paged_layered_fns(cfg)
 
         def block(lp, x, aux, pages, table, start, **phase):
             x, kp, vp = block_fn(lp, x, aux, *pages, table, start, **phase)
@@ -113,7 +112,7 @@ def test_carried_pool_equals_per_layer_pools(name, quant):
     fwd = jax.jit(
         lambda params, tokens, cache, continuation: forward_paged(
             params, tokens, cfg, cache, interpret=True, tp=False,
-            continuation=continuation, paged_kernel="xla"),
+            continuation=continuation),
         static_argnums=3)
 
     @functools.partial(jax.jit, static_argnums=5)

@@ -357,7 +357,7 @@ def test_the_policy_names_the_state_stepper(params):
     tests above); under a mesh ``xla``, with a ``fallbacks`` row."""
     kernels = _engine(params).statusz()["kernels"]
     assert kernels["state_step"] == "pallas" and kernels["fallbacks"] == []
-    demoted = K.resolve_serving_kernels(None, tp=True, recurrent=True)
+    demoted = K.resolve_serving_kernels(tp=True, recurrent=True)
     assert demoted.state_step == "xla"
     assert [(f, d) for f, d, _ in demoted.fallbacks] == [
         ("state_step=pallas", "xla")]

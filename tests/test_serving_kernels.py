@@ -1,18 +1,21 @@
-"""Serving kernel-dispatch policy + the int8-dequant-fused / fused-
-sampling Pallas hot path (ref: DeepSpeed-FastGen's kernel injection —
-the serving engine picks kernels ONCE at build, never at trace time).
+"""Which kernel reads the cache: the rule of the build (``kernels.
+paged_reader``), the readers it chooses between and the sampler every
+engine runs (ref: DeepSpeed-FastGen's kernel injection — the serving
+engine picks kernels ONCE at build, never at trace time).
 
 Oracles:
-  * the XLA gather/sampler twins — forced Pallas kernels must serve
-    token-identical greedy output across every decode mode
-    (interpret-mode on CPU is the correctness harness);
-  * ``dequantize_pages`` — the dequant-fused attention kernel must match
-    the reference computed over host-dequantized pages, and sit within
-    ``KV_TIER_QUANT_RTOL`` of the exact-path reference;
-  * ``resolve_serving_kernels`` — env/config resolution happens once,
-    TP demotions are VISIBLE (fallback rows + counter), and the policy
-    ``/statusz`` reports is the one the compiled programs baked.
+  * the XLA gather — the Mosaic readers, in interpret mode on the CPU,
+    match it at the shapes the cells send;
+  * ``dequantize_pages`` — the int8-resident read is the gather over
+    the dequantized pool, bit for bit;
+  * ``np.argmax`` and ``jax.random.categorical`` — ``_sample_rows``;
+  * ``resolve_serving_kernels`` — resolved once, from what the build can
+    observe; no config block and no environment variable reaches it,
+    and what ``/statusz`` reports is what the compiled programs baked.
 """
+
+import importlib.util
+import pathlib
 
 import numpy as np
 import pytest
@@ -20,30 +23,19 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.config import Config, KernelsConfig, KVTierConfig
+import deepspeed_tpu
+from deepspeed_tpu.config import Config, KVTierConfig
+from deepspeed_tpu.inference import init_inference, init_serving
 from deepspeed_tpu.inference.kernels import (
-    dequantize_pages, paged_attention_reference,
+    ServingKernelPolicy, dequantize_pages, latent_reader,
+    paged_attention_reference, paged_attention_step,
     paged_chunk_attention_reference, paged_chunk_attention_v2,
-    paged_chunk_attention_v2_quant, paged_decode_attention_v2,
-    paged_decode_attention_v2_quant, paged_reader, quantize_kv_rows,
+    paged_decode_attention_v2, paged_reader, quantize_kv_rows,
     resolve_serving_kernels)
 from deepspeed_tpu.inference.kv_tier import KV_TIER_QUANT_RTOL, quantize_page
 from deepspeed_tpu.inference.serving import _sample_rows, serving_engine
 from deepspeed_tpu.models import gpt2, llama
-from deepspeed_tpu.ops.sampling_pallas import (
-    _FUSED_SAMPLE_MIN_ROWS_X_VOCAB, fused_greedy_rows, fused_sample_rows,
-    pallas_sample_gate)
-from deepspeed_tpu.topology import MeshSpec, set_current_mesh
-
-ENV_VARS = ("DSTPU_PAGED_ATTENTION", "DSTPU_FORCE_PAGED_PALLAS",
-            "DSTPU_PAGED_V1", "DSTPU_FUSED_SAMPLING",
-            "DSTPU_FORCE_FUSED_SAMPLING")
-
-
-@pytest.fixture(autouse=True)
-def clean_kernel_env(monkeypatch):
-    for v in ENV_VARS:
-        monkeypatch.delenv(v, raising=False)
+from deepspeed_tpu.models.family import CacheRow
 
 
 @pytest.fixture(scope="module")
@@ -63,31 +55,7 @@ def llama_model():
 
 
 # ---------------------------------------------------------------- config
-class TestKernelsConfig:
-    def test_coerce_forms(self):
-        assert KernelsConfig.coerce(None).paged_attention == "auto"
-        k = KernelsConfig.coerce({"paged_attention": "pallas_v2",
-                                  "fused_sampling": "on"})
-        assert (k.paged_attention, k.fused_sampling) == ("pallas_v2", "on")
-        assert KernelsConfig.coerce(k) is k
-        with pytest.raises(TypeError):
-            KernelsConfig.coerce(3)
-
-    def test_invalid_values_raise(self):
-        with pytest.raises(ValueError):
-            KernelsConfig.coerce({"paged_attention": "pallas_v3"})
-        with pytest.raises(ValueError):
-            KernelsConfig.coerce({"fused_sampling": "maybe"})
-
-    def test_top_level_config_block(self):
-        cfg = Config.from_dict(
-            {"kernels": {"paged_attention": "xla"}})
-        assert cfg.kernels.paged_attention == "xla"
-        assert cfg.kernels.fused_sampling == "auto"
-        # no block → all-auto defaults (auto IS the policy; no enabled
-        # switch exists)
-        assert Config.from_dict({}).kernels.paged_attention == "auto"
-
+class TestQuantizedResidentConfig:
     def test_quantized_resident_requires_quantize_cold(self):
         with pytest.raises(ValueError, match="quantize_cold"):
             KVTierConfig.coerce({"quantized_resident": True,
@@ -100,70 +68,28 @@ class TestKernelsConfig:
 # ----------------------------------------------------------- resolution
 class TestResolveServingKernels:
     def test_defaults(self):
-        p = resolve_serving_kernels()
-        assert p.paged_attention == "auto"
-        # fused auto resolves off at every measured shape (the
-        # committed fused_sample_vs_xla sweep)
-        assert p.fused_sampling == "off"
-        assert p.env_overrides == () and p.fallbacks == ()
-
-    def test_resolved_policy_passes_through(self):
-        p = resolve_serving_kernels(
-            {"paged_attention": "pallas_v2", "fused_sampling": "on"})
-        # builders resolve once and hand the SAME object to the engine
-        assert resolve_serving_kernels(p, tp=True) is p
-
-    def test_env_names_mode_directly(self, monkeypatch):
-        monkeypatch.setenv("DSTPU_PAGED_ATTENTION", "xla")
-        monkeypatch.setenv("DSTPU_FUSED_SAMPLING", "on")
-        p = resolve_serving_kernels(
-            {"paged_attention": "pallas_v2", "fused_sampling": "off"})
-        assert (p.paged_attention, p.fused_sampling) == ("xla", "on")
-        assert ("paged_attention", "xla",
-                "DSTPU_PAGED_ATTENTION") in p.env_overrides
-        assert ("fused_sampling", "on",
-                "DSTPU_FUSED_SAMPLING") in p.env_overrides
-
-    def test_legacy_force_flags(self, monkeypatch):
-        monkeypatch.setenv("DSTPU_FORCE_PAGED_PALLAS", "1")
-        assert resolve_serving_kernels().paged_attention == "pallas_v2"
-        monkeypatch.setenv("DSTPU_PAGED_V1", "1")
-        assert resolve_serving_kernels().paged_attention == "pallas_v1"
-        monkeypatch.setenv("DSTPU_FORCE_FUSED_SAMPLING", "1")
-        assert resolve_serving_kernels().fused_sampling == "on"
-
-    def test_named_env_wins_over_legacy(self, monkeypatch):
-        monkeypatch.setenv("DSTPU_FORCE_PAGED_PALLAS", "1")
-        monkeypatch.setenv("DSTPU_PAGED_ATTENTION", "xla")
-        p = resolve_serving_kernels()
-        assert p.paged_attention == "xla"
-        assert len(p.env_overrides) == 1
-
-    def test_invalid_env_raises(self, monkeypatch):
-        monkeypatch.setenv("DSTPU_PAGED_ATTENTION", "gather")
-        with pytest.raises(ValueError, match="DSTPU_PAGED_ATTENTION"):
-            resolve_serving_kernels()
-
-    def test_tp_demotes_forced_pallas_visibly(self):
-        # satellite: the old gate silently returned False under TP;
-        # the resolver must demote WITH a recorded reason instead
-        for forced in ("pallas_v1", "pallas_v2"):
-            p = resolve_serving_kernels({"paged_attention": forced},
-                                        tp=True)
-            assert p.paged_attention == "xla"
-            assert len(p.fallbacks) == 1
-            field, demoted_to, reason = p.fallbacks[0]
-            assert forced in field and demoted_to == "xla"
-            assert "tp_unsupported" in reason
-        # auto under TP carries no fallback row — nothing was forced
-        assert resolve_serving_kernels(tp=True).fallbacks == ()
+        """Off the chip (``interpret``) every reader is the gather; the
+        policy is a report with five rows and nothing to request."""
+        p = resolve_serving_kernels(interpret=True)
+        assert p._fields == ("decode", "chunk", "window", "state_step",
+                             "fallbacks")
+        assert p.decode == p.chunk == ("xla", "interpret: no TPU backend")
+        assert (p.state_step, p.fallbacks) == ("xla", ())
+        # what a directly constructed engine reports: all gathers
+        assert ServingKernelPolicy().decode[0] == "xla"
 
     def test_as_dict_shape(self):
-        d = resolve_serving_kernels(
-            {"paged_attention": "pallas_v2"}, tp=True).as_dict()
-        assert d["paged_attention"] == "xla"
-        assert d["fallbacks"][0]["demoted_to"] == "xla"
-        assert "tp_unsupported" in d["fallbacks"][0]["reason"]
+        d = resolve_serving_kernels(tp=True, recurrent=True).as_dict()
+        assert sorted(d) == ["chunk", "decode", "fallbacks", "state_step",
+                             "window"]
+        assert d["decode"] == {
+            "reader": "xla",
+            "reason": "tp: KV heads are sharded over the mesh"}
+        assert d["fallbacks"] == [{
+            "field": "state_step=pallas", "demoted_to": "xla",
+            "reason": "tp: the kernel is one device's"}]
+        # a mesh takes nothing from a family that steps no state
+        assert resolve_serving_kernels(tp=True).fallbacks == ()
 
 
 # ------------------------------------------- the live-pages decode kernel
@@ -300,12 +226,12 @@ class TestChunkKernelIdentity:
 
     # (tokens, head width) of the three cells' chunk programs
     @pytest.mark.parametrize("shape", [(1024, 128), (1024, 256), (256, 128)])
-    def test_auto_runs_it_where_the_shapes_are_whole_blocks(self, shape):
+    def test_the_rule_runs_it_where_the_shapes_are_whole_blocks(self, shape):
         kw = dict(decode=False, tp=False, interpret=False, quant=False,
                   tokens=shape[0], head_dim=shape[1])
-        for policy in (None, "auto"):
-            reader, why = paged_reader(policy, **kw)
-            assert reader == "pallas_v2" and "chunk in 128-row blocks" in why
+        reader, why = paged_reader(**kw)
+        assert reader == "dstpu_paged_chunk_v2"
+        assert "chunk in 128-row blocks" in why
 
     @pytest.mark.parametrize("off,why", [
         (dict(tp=True), "tp"), (dict(quant=True), "int8-resident"),
@@ -313,16 +239,12 @@ class TestChunkKernelIdentity:
         (dict(tokens=5), "not whole 128-row blocks"),
         (dict(head_dim=64), "not whole 128-lane tiles"),
     ], ids=["tp", "quant", "interpret", "five_rows", "head_of_64"])
-    def test_auto_keeps_the_gather_and_says_why(self, off, why):
+    def test_the_rule_keeps_the_gather_and_says_why(self, off, why):
         kw = dict(decode=False, tp=False, interpret=False, quant=False,
                   tokens=1024, head_dim=128)
         kw.update(off)
-        for policy in (None, "auto"):
-            reader, reason = paged_reader(policy, **kw)
-            assert reader == "xla" and why in reason
-        # a forced policy keeps meaning what it meant
-        for forced in ("xla", "pallas_v1", "pallas_v2"):
-            assert paged_reader(forced, **kw) == (forced, "forced")
+        reader, reason = paged_reader(**kw)
+        assert reader == "xla" and why in reason
 
     def test_a_padded_head_counts_as_its_own_numbers(self):
         """A family that stores a head of 64 in a 128-lane tile says so
@@ -335,7 +257,7 @@ class TestChunkKernelIdentity:
         cfg = gh.GraniteHybridConfig(head_dim=64)
         row = decoder_family(cfg).cache_row(cfg)
         assert (row.key_width, row.head_width) == (128, 64)
-        chunk = resolve_serving_kernels(None, interpret=False, chunk=(
+        chunk = resolve_serving_kernels(interpret=False, chunk=(
             256, row.head_width or row.key_width)).chunk
         assert chunk == ("xla",
                          "chunk program: a head is not whole 128-lane tiles")
@@ -345,29 +267,18 @@ class TestChunkKernelIdentity:
             assert decoder_family(fam_cfg).cache_row(fam_cfg).head_width == 0
 
     @pytest.mark.parametrize("chunk,reader", [
-        ((1024, 128), "pallas_v2"), ((256, 64), "xla"), ((0, 128), "xla")])
+        ((1024, 128), "dstpu_paged_chunk_v2"), ((256, 64), "xla"),
+        ((0, 128), "xla")])
     def test_statusz_names_the_chunk_reader(self, chunk, reader):
         """``/statusz``'s ``kernels`` block shows the ``chunk`` row, the
         reader of the build's chunk programs with its reason, beside
         ``decode`` and ``window``."""
-        d = resolve_serving_kernels(None, interpret=False,
-                                    chunk=chunk).as_dict()
+        d = resolve_serving_kernels(interpret=False, chunk=chunk).as_dict()
         assert d["chunk"]["reader"] == reader and d["chunk"]["reason"]
         assert set(d) >= {"decode", "chunk", "window", "state_step"}
         # in interpret mode (the CPU's engines) every build gathers
-        assert resolve_serving_kernels(None, interpret=True, chunk=chunk) \
+        assert resolve_serving_kernels(interpret=True, chunk=chunk) \
             .as_dict()["chunk"]["reader"] == "xla"
-
-
-# ----------------------------------------------------------- shape gates
-class TestSampleGatePolicy:
-    def test_gate_policy(self):
-        assert not pallas_sample_gate(interpret=True)
-        # unknown shapes (engine build time) resolve conservatively off
-        assert not pallas_sample_gate()
-        big = _FUSED_SAMPLE_MIN_ROWS_X_VOCAB
-        assert pallas_sample_gate(batch=big // 32000 + 1, vocab=32000)
-        assert not pallas_sample_gate(batch=8, vocab=32000)
 
 
 # ---------------------------------------------------------- int8 codec
@@ -397,54 +308,69 @@ class TestQuantCodecParity:
         assert np.all(np.abs(back - x) <= bound)
 
 
-# ------------------------------------------------------- fused sampling
-class TestFusedSampling:
-    """Greedy rows are bit-exact vs jnp.argmax (first-occurrence
-    contract); temperature rows run the identical categorical math on
-    the same key streams, so the fused and XLA samplers agree on every
-    row."""
+# ---------------------------------------------------------- the sampler
+class TestSampleRows:
+    """``_sample_rows``, the sampler every engine's programs run: greedy
+    rows are ``np.argmax`` (first occurrence on ties), temperature rows
+    are ``jax.random.categorical`` at the row's own key and
+    temperature."""
 
     @pytest.mark.parametrize("B,V", [(1, 7), (3, 37), (8, 128),
                                      (9, 257), (16, 500)])
-    def test_greedy_bit_exact(self, B, V):
+    def test_greedy_is_argmax(self, B, V):
         logits = jax.random.normal(jax.random.PRNGKey(B * V), (B, V))
-        got = fused_greedy_rows(logits, interpret=True)
-        np.testing.assert_array_equal(np.asarray(got),
-                                      np.asarray(jnp.argmax(logits, -1)))
+        keys = jax.random.split(jax.random.PRNGKey(1), B)
+        got = _sample_rows(logits, keys, jnp.zeros((B,)))
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(
+            np.asarray(got), np.argmax(np.asarray(logits), -1))
 
-    def test_greedy_first_occurrence_ties(self):
-        # duplicate maxima: the kernel must report the FIRST index,
-        # matching jnp.argmax — the serving identity gates depend on it
+    def test_greedy_first_occurrence_on_ties(self):
+        # duplicate maxima: the FIRST index, as np.argmax; the serving
+        # identity checks against a plain reference depend on it
         logits = jnp.zeros((4, 200)).at[:, 150].set(5.0).at[:, 30].set(5.0)
-        got = np.asarray(fused_greedy_rows(logits, interpret=True))
+        keys = jax.random.split(jax.random.PRNGKey(2), 4)
+        got = np.asarray(_sample_rows(logits, keys, jnp.zeros((4,))))
         np.testing.assert_array_equal(got, np.full(4, 30))
 
-    def test_sampler_twin_agrees_rowwise(self):
-        B, V = 6, 97
-        logits = jax.random.normal(jax.random.PRNGKey(3), (B, V))
-        keys = jax.random.split(jax.random.PRNGKey(7), B)
-        temps = jnp.asarray([0.0, 1.0, 0.0, 0.7, 2.0, 0.0])
-        got = fused_sample_rows(logits, keys, temps, interpret=True)
-        want = _sample_rows(logits, keys, temps)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-    def test_temperature_distribution_sanity(self):
+    def test_temperature_distribution(self):
         # sharply-biased logits at temp 1.0: the favored token must
         # dominate; a flat draw (or an argmax leak into temp rows)
         # cannot pass this
         B, V = 256, 16
         logits = jnp.zeros((B, V)).at[:, 5].set(3.0)
         keys = jax.random.split(jax.random.PRNGKey(11), B)
-        toks = np.asarray(fused_sample_rows(
-            logits, keys, jnp.ones((B,)), interpret=True))
+        toks = np.asarray(_sample_rows(logits, keys, jnp.ones((B,))))
         frac = np.mean(toks == 5)
         # softmax prob of token 5 ≈ 0.57 at these logits
         assert 0.4 < frac < 0.75
         assert len(np.unique(toks)) > 1     # it actually sampled
 
+    def test_each_row_draws_with_its_own_key_and_temperature(self):
+        """Greedy and sampled rows in one batch: a row at temperature 0
+        is its argmax whatever its key; another is the categorical draw
+        of ITS key at ITS temperature, so two rows with the same logits
+        and key agree and the batch's order changes no row."""
+        B, V = 6, 97
+        logits = jax.random.normal(jax.random.PRNGKey(3), (B, V))
+        logits = logits.at[4].set(logits[1])
+        keys = jax.random.split(jax.random.PRNGKey(7), B)
+        keys = keys.at[4].set(keys[1])
+        temps = jnp.asarray([0.0, 1.0, 0.0, 0.7, 1.0, 2.0])
+        got = np.asarray(_sample_rows(logits, keys, temps))
+        for b in range(B):
+            want = (np.argmax(np.asarray(logits[b])) if temps[b] == 0
+                    else jax.random.categorical(
+                        keys[b], logits[b].astype(jnp.float32) / temps[b]))
+            assert got[b] == int(want), b
+        assert got[4] == got[1]
+        back = np.asarray(_sample_rows(logits[::-1], keys[::-1],
+                                       temps[::-1]))
+        np.testing.assert_array_equal(back[::-1], got)
 
-# --------------------------------------- dequant-fused attention kernel
-def _quant_paged_setup(seed, B, H, KV, Dh, P, ps, mp, lens):
+
+# ------------------------------------------------ the int8-resident read
+def _quant_paged_setup(seed, B, KV, Dh, P, ps, mp):
     rng = np.random.default_rng(seed)
     k = jnp.asarray(rng.normal(size=(KV, P, ps, Dh)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(KV, P, ps, Dh)), jnp.float32)
@@ -452,76 +378,214 @@ def _quant_paged_setup(seed, B, H, KV, Dh, P, ps, mp, lens):
     vq, vs = quantize_kv_rows(v)
     table = jnp.asarray(
         rng.permutation(P)[:B * mp].reshape(B, mp), jnp.int32)
-    lens = jnp.asarray(lens, jnp.int32)
-    return k, v, kq, ks, vq, vs, table, lens
+    return k, v, kq, ks, vq, vs, table
 
 
-class TestQuantKernelIdentity:
-    """The int8-dequant-fused kernel vs two oracles: (tight) the gather
-    reference over host-dequantized pages — same values, so float-level
-    agreement; (bounded) the exact-path reference — within the codec's
-    documented KV_TIER_QUANT_RTOL regime."""
+class TestQuantResidentRead:
+    """Over int8-resident pages every program gathers the codes its
+    table names and dequantizes those: the references with ``k_scale`` /
+    ``v_scale`` are the references over the dequantized pool, bit for
+    bit (the dequant is elementwise), and within the codec's bound of
+    the float pages."""
 
-    def test_decode_matches_dequantized_reference(self):
-        B, H, KV, Dh, ps, mp = 3, 4, 2, 16, 8, 4
-        k, v, kq, ks, vq, vs, table, lens = _quant_paged_setup(
-            0, B, H, KV, Dh, 16, ps, mp, [5, 17, 32])
+    HEADS = {"gqa_4_2": (4, 2), "mha_2_2": (2, 2)}
+
+    @pytest.mark.parametrize("heads", HEADS)
+    def test_decode_is_the_gather_over_the_dequantized_pool(self, heads):
+        (H, KV), B, Dh, ps, mp = self.HEADS[heads], 3, 16, 8, 4
+        k, v, kq, ks, vq, vs, table = _quant_paged_setup(
+            0, B, KV, Dh, 16, ps, mp)
+        lens = jnp.asarray([5, 17, 32], jnp.int32)      # ragged; one full
         q = jax.random.normal(jax.random.PRNGKey(1), (B, H, Dh))
-        got = paged_decode_attention_v2_quant(
-            q, kq, ks, vq, vs, table, lens, interpret=True)
+        got = paged_attention_reference(q, kq, vq, table, lens,
+                                        k_scale=ks, v_scale=vs)
         want = paged_attention_reference(
             q, dequantize_pages(kq, ks, jnp.float32),
             dequantize_pages(vq, vs, jnp.float32), table, lens)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=2e-5, rtol=1e-5)
-
-    @pytest.mark.slow
-    def test_decode_within_quant_bound_of_exact(self):
-        B, H, KV, Dh, ps, mp = 2, 4, 2, 16, 8, 3
-        k, v, kq, ks, vq, vs, table, lens = _quant_paged_setup(
-            2, B, H, KV, Dh, 8, ps, mp, [9, 22])
-        q = jax.random.normal(jax.random.PRNGKey(3), (B, H, Dh))
-        got = paged_decode_attention_v2_quant(
-            q, kq, ks, vq, vs, table, lens, interpret=True)
-        exact = paged_attention_reference(q, k, v, table, lens)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         # attention output error under per-row int8 KV stays within a
         # few quantization steps of the unit-scale values
+        exact = paged_attention_reference(q, k, v, table, lens)
         np.testing.assert_allclose(np.asarray(got), np.asarray(exact),
                                    atol=12 * KV_TIER_QUANT_RTOL)
 
-    @pytest.mark.slow
-    def test_chunk_matches_dequantized_reference(self):
-        B, C, H, KV, Dh, ps, mp = 2, 5, 4, 2, 16, 8, 4
-        k, v, kq, ks, vq, vs, table, _ = _quant_paged_setup(
-            4, B, H, KV, Dh, 16, ps, mp, [0, 0])
-        start = jnp.asarray([3, 11], jnp.int32)
+    @pytest.mark.parametrize("heads", HEADS)
+    def test_chunk_is_the_gather_over_the_dequantized_pool(self, heads):
+        (H, KV), B, C, Dh, ps, mp = self.HEADS[heads], 2, 5, 16, 8, 4
+        k, v, kq, ks, vq, vs, table = _quant_paged_setup(
+            4, B, KV, Dh, 16, ps, mp)
+        start = jnp.asarray([3, 11], jnp.int32)         # ragged histories
         q = jax.random.normal(jax.random.PRNGKey(5), (B, C, H, Dh))
-        got = paged_chunk_attention_v2_quant(
-            q, kq, ks, vq, vs, table, start, interpret=True)
+        got = paged_chunk_attention_reference(q, kq, vq, table, start,
+                                              k_scale=ks, v_scale=vs)
         want = paged_chunk_attention_reference(
             q, dequantize_pages(kq, ks, jnp.float32),
             dequantize_pages(vq, vs, jnp.float32), table, start)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=2e-5, rtol=1e-5)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        exact = paged_chunk_attention_reference(q, k, v, table, start)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(exact),
+                                   atol=12 * KV_TIER_QUANT_RTOL)
 
-    @pytest.mark.slow
-    def test_chunk_ppcb_sweep_and_mha(self):
-        # ppcb > live pages, ppcb = 1, and the MHA (G=1) layout
-        B, C, H, KV, Dh, ps, mp = 1, 3, 2, 2, 16, 4, 6
-        k, v, kq, ks, vq, vs, table, _ = _quant_paged_setup(
-            6, B, H, KV, Dh, 8, ps, mp, [0])
-        start = jnp.asarray([13], jnp.int32)
-        q = jax.random.normal(jax.random.PRNGKey(7), (B, C, H, Dh))
-        want = paged_chunk_attention_reference(
-            q, dequantize_pages(kq, ks, jnp.float32),
-            dequantize_pages(vq, vs, jnp.float32), table, start)
-        for ppcb in (1, 2, 16):
-            got = paged_chunk_attention_v2_quant(
-                q, kq, ks, vq, vs, table, start,
-                pages_per_block=ppcb, interpret=True)
-            np.testing.assert_allclose(np.asarray(got),
-                                       np.asarray(want),
-                                       atol=2e-5, rtol=1e-5)
+    @pytest.mark.parametrize("decode", [True, False],
+                             ids=["decode", "chunk"])
+    def test_the_rule_gathers_and_says_why(self, decode):
+        kw = dict(decode=decode, tp=False, interpret=False, tokens=1024,
+                  head_dim=128)
+        assert paged_reader(quant=False, **kw)[0].startswith("dstpu_paged_")
+        assert paged_reader(quant=True, **kw) == ("xla",
+                                                  "int8-resident pages")
+
+    def test_a_step_over_codes_refuses_a_mosaic_reader(self):
+        """``paged_attention_step`` with scale planes and a reader that
+        is not the gather would hand int8 codes to a kernel that reads
+        them as values: it raises instead."""
+        pool = jnp.zeros((1, 2, 4, 8, 16), jnp.int8)
+        scale = jnp.ones((1, 2, 4, 8, 1), jnp.float32)
+        x = jnp.zeros((1, 1, 2, 16))
+        with pytest.raises(ValueError, match="int8-resident"):
+            paged_attention_step(
+                x, x, x, pool, pool, 0, jnp.zeros((1, 4), jnp.int32),
+                jnp.zeros((1,), jnp.int32), continuation=False,
+                prefill=False, reader="dstpu_paged_decode",
+                flash_force_reference=False, kps=scale, vps=scale)
+
+
+# ------------------------------------- the rule at the traffic the cells send
+# What each serving cell of the benchmark hands the rule, written down
+# from benchmark/configs and benchmark/workloads (not imported): the
+# family's cache row (K/V heads, stored width, values, and the head's own
+# width where it is padded to a tile), the engine's chunk and bucket,
+# page size, and whether the rows are latent / the family steps a state.
+CELLS = {
+    "gpt2-1.3b.serve.chat-0.8knee": (
+        CacheRow(16, 128, 128), dict(prefill_bucket=128, page_size=16), ""),
+    "mixtral-8x7b-d4.serve.chat-sat": (
+        CacheRow(8, 128, 128), dict(prefill_bucket=128, page_size=16), ""),
+    "mixtral-8x7b-d4.serve.docs-sat": (
+        CacheRow(8, 128, 128),
+        dict(prefill_chunk=1024, prefill_bucket=0, page_size=16), ""),
+    "openpangu-ultra-moe-718b-ep16-d5.serve.think-sat": (
+        CacheRow(1, 576, 512, values_in_keys=True),
+        dict(prefill_chunk=1024, prefill_bucket=0, page_size=16), "latent"),
+    "qwen3-next-80b-a3b-ep8-d12.serve.docqa-sat": (
+        CacheRow(2, 256, 256),
+        dict(prefill_chunk=1024, prefill_bucket=0, page_size=16), "state"),
+    "v42.granite-4.0-h-micro.serve.assist-sat": (
+        CacheRow(8, 128, 128, head_width=64),
+        dict(prefill_chunk=256, prefill_bucket=0, page_size=16), "state"),
+    "v44.laguna-s-2.1-ep16-d13.serve.code-sat": (
+        CacheRow(8, 128, 128),
+        dict(prefill_chunk=1024, prefill_bucket=0, page_size=16), ""),
+}
+# cell -> (decode, chunk) readers of its build on one device
+ON_ONE_DEVICE = {
+    "gpt2-1.3b.serve.chat-0.8knee":
+        ("dstpu_paged_decode", "dstpu_paged_chunk_v2"),
+    "mixtral-8x7b-d4.serve.chat-sat":
+        ("dstpu_paged_decode", "dstpu_paged_chunk_v2"),
+    "mixtral-8x7b-d4.serve.docs-sat":
+        ("dstpu_paged_decode", "dstpu_paged_chunk_v2"),
+    # a latent chunk expands its rows and runs the flash kernel
+    "openpangu-ultra-moe-718b-ep16-d5.serve.think-sat":
+        ("dstpu_mla_decode", "xla"),
+    "qwen3-next-80b-a3b-ep8-d12.serve.docqa-sat":
+        ("dstpu_paged_decode", "dstpu_paged_chunk_v2"),
+    # a head of 64 in a 128-lane tile
+    "v42.granite-4.0-h-micro.serve.assist-sat":
+        ("dstpu_paged_decode", "xla"),
+    "v44.laguna-s-2.1-ep16-d13.serve.code-sat":
+        ("dstpu_paged_decode", "dstpu_paged_chunk_v2"),
+}
+
+
+class TestTheRuleAtTheCells:
+    """The table a reviewer needs to see that no cell's reader changed,
+    and the one the next kernel PR edits."""
+
+    @pytest.mark.parametrize("where", ["one_device", "tp", "interpret"])
+    @pytest.mark.parametrize("cell", CELLS)
+    def test_the_readers_a_cells_build_gets(self, cell, where):
+        row, engine, kind = CELLS[cell]
+        policy = resolve_serving_kernels(
+            tp=where == "tp", interpret=where == "interpret",
+            recurrent=kind == "state", chunk=(
+                engine.get("prefill_chunk") or engine["prefill_bucket"],
+                row.head_width or row.key_width))
+        if kind == "latent":
+            policy = policy._replace(decode=latent_reader(policy.decode))
+        readers = (policy.decode[0], policy.chunk[0])
+        if where == "one_device":
+            assert readers == ON_ONE_DEVICE[cell]
+            assert policy.state_step == ("pallas" if kind == "state"
+                                         else "xla")
+            assert policy.fallbacks == ()
+            return
+        assert readers == ("xla", "xla")
+        assert policy.decode[1].startswith(
+            "tp:" if where == "tp" else "interpret:")
+        # a mesh takes the state's kernel, visibly; off the chip it runs
+        # in interpret mode
+        assert policy.state_step == (
+            "pallas" if kind == "state" and where == "interpret" else "xla")
+        assert len(policy.fallbacks) == (kind == "state" and where == "tp")
+
+
+# --------------------------------------- nothing reads the old switches
+OLD_SWITCHES = {
+    "DSTPU_PAGED_ATTENTION": "xla", "DSTPU_FORCE_PAGED_PALLAS": "1",
+    "DSTPU_PAGED_V1": "1", "DSTPU_FUSED_SAMPLING": "on",
+    "DSTPU_FORCE_FUSED_SAMPLING": "1", "DSTPU_FORCE_ADAM_PALLAS": "1",
+}
+KERNELS_BLOCK = {"paged_attention": "pallas_v2", "fused_sampling": "on"}
+
+
+class TestNothingReadsTheOldSwitches:
+    @pytest.mark.parametrize("name", OLD_SWITCHES)
+    def test_an_old_env_switch_moves_nothing(self, name, monkeypatch):
+        kw = dict(interpret=False, recurrent=True, chunk=(1024, 128))
+        default = resolve_serving_kernels(**kw)
+        monkeypatch.setenv(name, OLD_SWITCHES[name])
+        assert resolve_serving_kernels(**kw) == default
+        assert default.decode[0] == "dstpu_paged_decode"
+
+    @pytest.mark.parametrize("door", ["training_config", "serving_config",
+                                      "init_serving", "init_inference",
+                                      "encoder"])
+    def test_a_kernels_block_is_refused_by_name(self, door, gpt2_model):
+        """Not dropped silently: a user who forced a kernel learns that
+        it no longer is, and where to read on."""
+        cfg, params = gpt2_model
+        with pytest.raises(ValueError, match="MIGRATION.md") as e:
+            if door == "training_config":
+                Config.from_dict({"train_batch_size": 1,
+                                  "kernels": dict(KERNELS_BLOCK)})
+            elif door == "serving_config":
+                serving_engine(params, cfg, kernels=dict(KERNELS_BLOCK))
+            elif door == "init_serving":
+                init_serving(params, cfg,
+                             config={"kernels": dict(KERNELS_BLOCK)})
+            elif door == "init_inference":
+                init_inference(apply_fn=lambda p, x: x, params=params,
+                               config={"kernels": dict(KERNELS_BLOCK)})
+            else:
+                from deepspeed_tpu.models import bert
+
+                serving_engine(None, bert.BertConfig.tiny(),
+                               kernels={"paged_attention": "auto"})
+        assert "`kernels` block is gone" in str(e.value)
+
+    def test_no_environment_read_under_inference_or_ops(self):
+        root = pathlib.Path(deepspeed_tpu.__file__).parent
+        reads = [str(f.relative_to(root))
+                 for d in ("inference", "ops")
+                 for f in sorted((root / d).rglob("*.py"))
+                 if "os.environ" in f.read_text()
+                 or "getenv" in f.read_text()]
+        assert reads == []
+        # and the two modules that read a switch of their own are gone
+        for gone in ("adam_pallas", "sampling_pallas"):
+            assert importlib.util.find_spec(
+                f"deepspeed_tpu.ops.{gone}") is None
 
 
 # --------------------------------------------------- engine-level policy
@@ -542,152 +606,31 @@ def serve_all(eng):
 
 
 class TestEnginePolicy:
-    @pytest.mark.slow
-    def test_statusz_counters_and_identity_fused_sampling(
+    def test_statusz_reports_the_readers_and_nothing_to_request(
             self, gpt2_model, devices):
         cfg, params = gpt2_model
-        base = serving_engine(params, cfg, **KW)
-        want = serve_all(base)
-
-        eng = serving_engine(params, cfg,
-                             kernels={"fused_sampling": "on"}, **KW)
-        assert serve_all(eng) == want      # greedy identity, fused on
+        eng = serving_engine(params, cfg, telemetry=True, **KW)
+        out = serve_all(eng)
+        assert all(len(out[rid]) == len(p) + n
+                   for rid, (p, n) in PROMPTS.items())
         kz = eng.statusz()["kernels"]
-        assert kz["paged_attention"] == "auto"
-        assert kz["fused_sampling"] == "on"
-        assert kz["fallbacks"] == []
+        assert sorted(kz) == ["chunk", "decode", "fallbacks", "state_step",
+                              "window"]
+        for row in ("decode", "chunk", "window"):
+            reader = kz[row]["reader"]
+            assert reader == "xla" or reader.startswith("dstpu_"), kz
+            assert kz[row]["reason"]
+        assert (kz["state_step"], kz["fallbacks"]) == ("xla", [])
+        # one fixed name a counter: the reader is /statusz's to name
         cnt = eng.registry.snapshot()["counters"]
-        assert cnt["serving_kernel_dispatch_paged_auto"] > 0
-        assert cnt["serving_kernel_dispatch_sample_fused"] > 0
-        assert cnt.get("serving_kernel_fallbacks", 0) == 0
-        # the baseline engine dispatched the XLA sampler, visibly
-        bcnt = base.registry.snapshot()["counters"]
-        assert bcnt["serving_kernel_dispatch_sample_xla"] > 0
-
-    def test_env_override_reaches_statusz(self, gpt2_model, devices,
-                                          monkeypatch):
-        monkeypatch.setenv("DSTPU_FUSED_SAMPLING", "on")
-        cfg, params = gpt2_model
-        eng = serving_engine(params, cfg, **KW)
-        kz = eng.statusz()["kernels"]
-        assert kz["fused_sampling"] == "on"
-        assert ["fused_sampling", "on",
-                "DSTPU_FUSED_SAMPLING"] in kz["env_overrides"]
+        names = sorted(n for n in cnt if n.startswith("serving_kernel_"))
+        assert names == ["serving_kernel_dispatch_paged",
+                         "serving_kernel_dispatch_sample",
+                         "serving_kernel_fallbacks"]
+        assert cnt["serving_kernel_dispatch_paged"] > 0
+        assert cnt["serving_kernel_dispatch_sample"] > 0
+        assert cnt["serving_kernel_fallbacks"] == 0
         eng.shutdown()
-
-    def test_pallas_v1_rejects_quantized_resident(self, gpt2_model,
-                                                  devices):
-        cfg, params = gpt2_model
-        with pytest.raises(ValueError, match="pallas_v1"):
-            serving_engine(
-                params, cfg, prefix_cache=True,
-                kernels={"paged_attention": "pallas_v1"},
-                kv_tier={"enabled": True, "quantize_cold": True,
-                         "quantized_resident": True}, **KW)
-
-    def test_encoder_rejects_pinned_kernels(self, devices):
-        from deepspeed_tpu.models import bert
-
-        cfg = bert.BertConfig.tiny()
-        params = bert.init_params(jax.random.PRNGKey(0), cfg)
-        with pytest.raises(NotImplementedError, match="paged-KV"):
-            serving_engine(params, cfg,
-                           kernels={"paged_attention": "pallas_v2"})
-        # an all-auto block is inert and must not trip the guard
-        serving_engine(params, cfg, kernels={"paged_attention": "auto"})
-
-    @pytest.mark.slow
-    def test_tp_visible_fallback_both_arms(self, llama_model, devices):
-        """Satellite regression: forced pallas under TP serves (demoted
-        to xla) and the demotion is VISIBLE — statusz reason + counter —
-        for both forced arms, token-identical to the unforced TP run."""
-        cfg, params = llama_model
-        mesh = MeshSpec.build({"model": 2}, devices=jax.devices()[:2])
-        try:
-            base = serving_engine(params, cfg, mesh=mesh, **KW)
-            want = serve_all(base)
-            for forced in ("pallas_v1", "pallas_v2"):
-                eng = serving_engine(
-                    params, cfg, mesh=mesh,
-                    kernels={"paged_attention": forced}, **KW)
-                assert serve_all(eng) == want
-                kz = eng.statusz()["kernels"]
-                assert kz["paged_attention"] == "xla"
-                assert len(kz["fallbacks"]) == 1
-                fb = kz["fallbacks"][0]
-                assert forced in fb["field"]
-                assert "tp_unsupported" in fb["reason"]
-                cnt = eng.registry.snapshot()["counters"]
-                assert cnt["serving_kernel_fallbacks"] == 1
-                eng.shutdown()
-        finally:
-            set_current_mesh(None)
-
-
-# ------------------------------------------- forced-kernel identity gates
-def churn_prompts(vocab, groups=3, per=2, prefix_len=24, tail_len=4,
-                  seed=0):
-    rng = np.random.default_rng(seed)
-    prefs = [rng.integers(1, vocab, prefix_len).tolist()
-             for _ in range(groups)]
-    out = []
-    for _ in range(2):
-        for p in prefs:
-            for _ in range(per):
-                out.append(p + rng.integers(1, vocab, tail_len).tolist())
-    return out
-
-
-FORCED = {"paged_attention": "pallas_v2", "fused_sampling": "on"}
-
-MODES = {
-    "plain": {},
-    "chunked_decode": {"decode_chunk": 4},
-    "split_fuse": {"prefill_chunk": 8},
-    "speculative": {"speculative": {"enabled": True, "draft_tokens": 3}},
-    "prefix_cache": {"prefix_cache": True},
-}
-
-
-class TestForcedKernelIdentity:
-    """Acceptance gate: with BOTH new kernels forced on (interpret mode
-    on CPU), greedy serving is token-identical to the XLA baseline
-    across every decode mode — mismatched_requests would be 0 on the
-    serving A/B."""
-
-    @pytest.mark.slow
-    @pytest.mark.parametrize("mode", sorted(MODES))
-    def test_token_identity(self, mode, gpt2_model, devices):
-        cfg, params = gpt2_model
-        kw = dict(KW, **MODES[mode])
-        prompts = churn_prompts(cfg.vocab_size, seed=13)[:6]
-        base = serving_engine(params, cfg, **kw)
-        for i, p in enumerate(prompts):
-            base.submit(i, p, max_new_tokens=5)
-        want = base.run()
-        eng = serving_engine(params, cfg, kernels=dict(FORCED), **kw)
-        for i, p in enumerate(prompts):
-            eng.submit(i, p, max_new_tokens=5)
-        assert eng.run() == want
-        cnt = eng.registry.snapshot()["counters"]
-        assert cnt["serving_kernel_dispatch_paged_pallas_v2"] > 0
-        assert cnt["serving_kernel_dispatch_sample_fused"] > 0
-
-    @pytest.mark.slow
-    def test_zero_inference_fused_sampling(self, llama_model, devices):
-        cfg, params = llama_model
-        prompts = churn_prompts(cfg.vocab_size, groups=2, per=1,
-                                seed=17)[:4]
-        kw = dict(KW, zero_inference={"enabled": True, "tier": "host"})
-        base = serving_engine(params, cfg, **kw)
-        for i, p in enumerate(prompts):
-            base.submit(i, p, max_new_tokens=5)
-        want = base.run()
-        eng = serving_engine(
-            params, cfg, kernels={"fused_sampling": "on"}, **kw)
-        for i, p in enumerate(prompts):
-            eng.submit(i, p, max_new_tokens=5)
-        assert eng.run() == want
 
     def test_zero_inference_rejects_quantized_resident(
             self, llama_model, devices):
@@ -699,6 +642,19 @@ class TestForcedKernelIdentity:
                 kv_tier={"enabled": True, "quantize_cold": True,
                          "quantized_resident": True},
                 zero_inference={"enabled": True, "tier": "host"}, **KW)
+
+
+def churn_prompts(vocab, groups=3, per=2, prefix_len=24, tail_len=4,
+                  seed=0):
+    rng = np.random.default_rng(seed)
+    prefs = [rng.integers(1, vocab, prefix_len).tolist()
+             for _ in range(groups)]
+    out = []
+    for _ in range(2):
+        for p in prefs:
+            for _ in range(per):
+                out.append(p + rng.integers(1, vocab, tail_len).tolist())
+    return out
 
 
 # ------------------------------------------------ prequantized tier pool
@@ -826,21 +782,3 @@ class TestQuantizedResident:
         # the device cache really is int8 + f32 scales
         assert eng.cache.k.dtype == jnp.int8
         assert eng.cache.k_scale.dtype == jnp.float32
-
-    @pytest.mark.slow
-    def test_qres_with_forced_pallas_v2(self, gpt2_model, devices):
-        # the dequant-fused kernel serves the int8-resident cache
-        # end-to-end (interpret mode on CPU)
-        cfg, params = gpt2_model
-        prompts = churn_prompts(cfg.vocab_size, groups=2, per=1,
-                                seed=23)[:4]
-        eng = serving_engine(params, cfg, prefix_cache=True,
-                             kv_tier=dict(self.QRES),
-                             kernels={"paged_attention": "pallas_v2"},
-                             max_batch=2, page_size=8, num_pages=16,
-                             max_seq=64, prefill_bucket=8)
-        for i, p in enumerate(prompts):
-            eng.submit(i, p, max_new_tokens=5)
-        outs = eng.run()
-        assert len(outs) == len(prompts)
-        assert eng.check_leaks() == []

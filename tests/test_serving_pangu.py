@@ -66,8 +66,7 @@ def _cache(cfg, rows, max_seq, dtype=jnp.float32):
 
 
 # ------------------------------------ (i) the paged path vs the reference
-@pytest.mark.parametrize("kernel", ["xla", "pallas_v2"])
-def test_chunked_prefill_then_decode_matches_the_reference(params, kernel):
+def test_chunked_prefill_then_decode_matches_the_reference(params):
     """Ragged rows: prompts of 5, 21 and 37 tokens (the last crosses
     pages and two chunk boundaries) go through chunks of 16 into the
     latent pages, then six decode steps run on all three rows at once;
@@ -88,7 +87,7 @@ def test_chunked_prefill_then_decode_matches_the_reference(params, kernel):
                 seq_lens=jnp.full((1,), done, jnp.int32))
             logits, view = forward_paged(
                 params, jnp.asarray(toks), CFG, view, continuation=True,
-                tp=False, interpret=True, paged_kernel=kernel)
+                tp=False, interpret=True)
             got[b][done:done + take] = np.asarray(logits[0, :take])
             cache = cache._replace(k=view.k, expert_rows=view.expert_rows)
     cache = cache._replace(seq_lens=jnp.asarray(lens, jnp.int32))
@@ -96,7 +95,7 @@ def test_chunked_prefill_then_decode_matches_the_reference(params, kernel):
         toks = jnp.asarray([[s[n + j]] for n, s in zip(lens, seqs)],
                            jnp.int32)
         logits, cache = forward_paged(params, toks, CFG, cache, tp=False,
-                                      interpret=True, paged_kernel=kernel)
+                                      interpret=True)
         for b, n in enumerate(lens):
             got[b][n + j] = np.asarray(logits[b, 0])
     for g, w in zip(got, want):
@@ -174,7 +173,8 @@ def test_a_chunk_routed_past_the_pair_buffer_makes_further_passes(
 
 
 def test_statusz_names_the_latent_reader():
-    assert K.latent_reader(("pallas_v2", "decode on one device"))[0] \
+    assert K.latent_reader(K.paged_reader(
+        decode=True, tp=False, interpret=False, quant=False))[0] \
         == "dstpu_mla_decode"
     reader, why = K.latent_reader(("xla", "interpret: no TPU backend"))
     assert reader == "xla" and "absorbed" in why
@@ -205,7 +205,7 @@ def test_absorbed_decode_is_the_per_head_attention():
     scale = (Dn + Dr) ** -0.5
     attn, pool2 = K.latent_attention_step(
         q, row, w_uk, w_uv, scale, pool, 1, table, lens,
-        continuation=False, prefill=False, paged_kernel="xla",
+        continuation=False, prefill=False, reader="xla",
         flash_force_reference=True)
     rows = np.asarray(K._gather_rows(pool2, 1, table)[:, 0, :, :C + Dr])
     for b, n in enumerate(np.asarray(lens) + 1):
@@ -405,11 +405,17 @@ def test_the_other_families_state_nothing_new():
 
 
 # ------------------------------------------------ names in the programs
-def test_programs_carry_the_new_scopes_and_kernel_names(params):
+def test_programs_carry_the_new_scopes_and_kernel_names(params,
+                                                        monkeypatch):
+    # the build asks the backend which readers to bake; lowered for the
+    # chip from here, the decode program holds the Mosaic reader
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     eng = serving_engine(params, CFG, max_batch=2, page_size=PAGE,
                          num_pages=24, max_seq=64, prefill_bucket=8,
-                         prefill_chunk=8, cache_dtype=jnp.float32,
-                         kernels={"paged_attention": "pallas_v2"})
+                         prefill_chunk=8, cache_dtype=jnp.float32)
+    assert eng.statusz()["kernels"]["decode"]["reader"] == "dstpu_mla_decode"
+    for_chip = lambda program, *args: program.trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
     absx = lambda x: (jax.ShapeDtypeStruct(x.shape, x.dtype)
                       if hasattr(x, "shape") else x)
     tm = jax.tree_util.tree_map
@@ -418,13 +424,14 @@ def test_programs_carry_the_new_scopes_and_kernel_names(params):
         seq_lens=jnp.zeros((1,), jnp.int32)))
     last = jax.ShapeDtypeStruct((1,), jnp.int32)
     toks = jax.ShapeDtypeStruct((1, 8), jnp.int32)
-    chunk = eng._chunk_prefill.lower(tm(absx, eng.params), toks, view,
-                                     last).as_text(debug_info=True)
-    decode = eng._decode_chunk_fn.lower(
+    chunk = for_chip(eng._chunk_prefill, tm(absx, eng.params), toks, view,
+                     last)
+    decode = for_chip(
+        eng._decode_chunk_fn,
         tm(absx, eng.params), jax.ShapeDtypeStruct((2, 1), jnp.int32),
         tm(absx, eng.cache), absx(eng._key),
         jax.ShapeDtypeStruct((), jnp.int32),
-        jax.ShapeDtypeStruct((2,), jnp.float32)).as_text(debug_info=True)
+        jax.ShapeDtypeStruct((2,), jnp.float32))
     words = lambda text: set(re.findall(
         r"\b(mla_q|mla_kv|mla_expand|moe_routed|moe_shared|moe_router|"
         r"moe_ffn|kv_write|kv_attend|attn_qkv|attn_out|mlp)\b", text))

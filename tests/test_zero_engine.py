@@ -374,3 +374,30 @@ def test_gathered_layer_keeps_its_model_axis(devices, monkeypatch):
     base, batch0, _, _ = _model_engine("gpt2", 0, {"data": 1})
     want = [float(base.train_batch(batch0)) for _ in range(4)]
     np.testing.assert_allclose(losses, want, rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama", "mixtral"])
+def test_a_bare_forward_after_a_stage3_engine_meets_no_stage(devices,
+                                                             family):
+    """The ZeRO stage is a fact of one engine's own trace (ROADMAP D10
+    (a)): once a stage-3 engine over the 8-way mesh has stepped and
+    evaluated, a model's forward outside any engine, at a batch the
+    data axis does not divide, runs as if none had been built (it was a
+    ``ValueError`` in ``pin_to_batch`` while the stage outlived the
+    trace); the mesh stays published for the readers that had it."""
+    from deepspeed_tpu import topology
+    from deepspeed_tpu.models import gpt2, llama, mixtral
+
+    engine, batch, params, cfg = _model_engine(family, 3, {"data": 8})
+    assert topology.current_zero_stage() == 0       # built, not traced
+    engine.train_batch(batch)
+    assert topology.current_zero_stage() == 0
+    engine.eval_batch(batch)
+    assert topology.current_zero_stage() == 0
+    assert topology.current_mesh() is engine.mesh
+    forward = {"gpt2": gpt2.forward, "llama": llama.forward,
+               "mixtral": mixtral.forward}[family]
+    tokens = jnp.zeros((1, T_ACT), jnp.int32)       # 1 row over data = 8
+    out = forward(params, tokens, cfg)
+    logits = out[0] if isinstance(out, tuple) else out
+    assert logits.shape == (1, T_ACT, cfg.vocab_size)

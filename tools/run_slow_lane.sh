@@ -166,26 +166,6 @@ timeout -k 10 600 env JAX_PLATFORMS=cpu python bench_serving.py --cpu \
   --tp 2 --requests 16 --new-tokens 32 --cpu-dim 256 --cpu-layers 2 \
   --json-out "$REPO/TP_BENCH.json" >/dev/null 2>&1 || true
 
-# serving-kernel sweeps: the paged decode kernels against the gather,
-# and the family behind pallas_sample_gate.  On a chip they time the
-# forced Pallas arms vs XLA at shapes bracketing the crossovers; on
-# this CPU lane they stamp interpret-mode IDENTITY rows (explicit
-# backend/note labels) and MERGE into KERNEL_BENCH.json without
-# clobbering the committed TPU families.
-timeout -k 10 600 env JAX_PLATFORMS=cpu python tools/kernel_bench.py \
-  --quick --families paged_v2_vs_xla,fused_sample_vs_xla \
-  --json-out "$REPO/KERNEL_BENCH.json" >/dev/null 2>&1 || true
-
-# forced-kernel serving A/B: the same traffic with every serving
-# kernel forced off (paged=xla, fused_sampling=off) vs forced on
-# (paged=pallas_v2 interpret, fused_sampling=on) — tokens/s, TTFT,
-# and the token-identity gate (kernel_ab.mismatched_requests must
-# stay 0: a kernel is an execution strategy).  Stamps
-# KERNEL_SERVING_BENCH.json, gated by bench_gate below.
-timeout -k 10 600 env JAX_PLATFORMS=cpu python bench_serving.py --cpu \
-  --kernels --requests 16 --new-tokens 32 --cpu-dim 256 --cpu-layers 2 \
-  --json-out "$REPO/KERNEL_SERVING_BENCH.json" >/dev/null 2>&1 || true
-
 # hierarchical + quantized collectives A/B: the same ZeRO-2 training
 # run under three gradient-wire schemes (flat f32 / flat int8 /
 # two-level hierarchical int8) on the 8-device mesh — per-arm step
