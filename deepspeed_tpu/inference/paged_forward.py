@@ -27,7 +27,7 @@ from deepspeed_tpu.inference.kernels import (latent_attention_step,
                                              state_stepper, write_state_rows)
 from deepspeed_tpu.inference.quantized import dequantize_params
 from deepspeed_tpu.models.family import (CarriedRows, CarriedState,
-                                         decoder_family)
+                                         decoder_family, sections_of)
 from deepspeed_tpu.parallel.moe import extra_pair_passes
 
 
@@ -53,7 +53,7 @@ def _paged_block(fam, out, cfg, x, lp, ctx, layer, kp, vp, kps, vps, rows,
         attn, kp, vp, kps, vps = paged_attention_step(
             q, k, v, kp, vp, layer, table, start, kps=kps, vps=vps, **phase)
     x = out(cfg, x, attn.reshape(B, T, -1), lp)
-    if out is fam.out and fam.expert_rows(cfg)[0]:
+    if isinstance(x, tuple):            # the layer's FFN counts its rows
         x, routed = x
         rows = _count_routed(fam, cfg, rows, routed, B * T)
     return x, kp, vp, kps, vps, rows
@@ -79,24 +79,23 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
     """The layers of a family some of whose layers keep a bounded state
     a slot (``fam.recurrent``): first the family's leading stack, if it
     has one (``lead``: its block, over the pool's first layers), then
-    whole periods at a time, a period's kinds in the order
-    ``Recurrent.period`` states them (a pool layer may end a period or
-    stand inside it): a pool layer is ``block`` (:func:`_paged_block`
-    over the pool, whose leading dimension counts the lead's and the
-    periods' pool layers alone); a per-slot layer reads its rows' state
-    beside the pool, mixes, and writes it back, but in a decode step
-    over every slot on one device (:func:`~deepspeed_tpu.inference.
-    kernels.state_stepper`), where ``mix`` steps its layer of the carried
-    state where it lies (``family.CarriedState``) and only the
-    convolution's rows go out and back, or not even they
-    (``Recurrent.rows_in_place``, ``family.CarriedRows``).
+    its sections in order (``family.sections_of``: a period and a
+    count each, the three kinds' layer indices running on from one to
+    the next over the same pool and the same state buffers), a
+    section's periods in a loop, a period's kinds in the order stated:
+    a pool layer is ``block`` (:func:`_paged_block` over the pool, whose
+    leading dimension counts the lead's and the sections' pool layers
+    alone); an FFN alone (``Recurrent.ffn``) touches neither cache; a
+    per-slot layer reads its rows' state beside the pool, mixes, and
+    writes it back, but in a decode step over every slot on one device
+    (:func:`~deepspeed_tpu.inference.kernels.state_stepper`), where
+    ``mix`` steps its layer of the carried state where it lies
+    (``family.CarriedState``) and only the convolution's rows go out and
+    back, or not even they (``Recurrent.rows_in_place``).
     ``cache.real``: how many tokens of each row may move a state; a row
     that starts at position 0 starts from zero state, whatever its slot
     held."""
     rec = fam.recurrent
-    kinds = rec.period(cfg)
-    n_rec = sum(kinds)
-    n_att = len(kinds) - n_rec
     B, T = x.shape[:2]
     start, slot = cache.seq_lens, cache.slot
     real = (jnp.full((B,), T, jnp.int32) if cache.real is None
@@ -106,7 +105,7 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
         n_lead = jax.tree.leaves(params[fam.lead[0]])[0].shape[0]
         x, cache = paged_layer_loop(lead, x, params[fam.lead[0]], cache,
                                     count=n_lead)
-    periods = (cache.k.shape[0] - n_lead) // n_att
+    n_pool = cache.k.shape[0] - n_lead
     every_slot = T == 1 and slot is None
     in_place = cache.state is not None and state_stepper(
         decode=every_slot, tp=tp)[0] == "pallas"
@@ -114,24 +113,34 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
     step = functools.partial(state_step, interpret=interpret)
 
     def split(stack):
-        held = {k: stack[k] for k in fam.whole_stacks} if whole else {}
+        held = {k: stack[k] for k in fam.whole_stacks
+                if k in stack} if whole else {}
         return held, {k: v for k, v in stack.items() if k not in held}
 
-    # the per-slot layers' stack stays whole: their loop takes a layer
-    # out of it by its index (sliced a period at a time by the outer
-    # loop, a period's weights were copied once more: 150 MB of one
-    # projection a period, v5e, PR 35)
-    rec_whole, rec_stack = split(params[rec.key])
-    att_whole, att_stack = split(params["blocks"])
-    att_stack = {k: v.reshape((periods, n_att) + v.shape[1:])
-                 for k, v in att_stack.items()}
+    # a kind's stack stays whole: its layers are taken out of it by
+    # their index (sliced a period at a time by the outer loop, a
+    # period's weights were copied once more: 150 MB of one projection a
+    # period, v5e, PR 35), but the pool layers' where one section takes
+    # them all: the loop over its periods then scans them
+    stacks = {True: split(params[rec.key]), False: split(params["blocks"]),
+              None: split(params[rec.ffn[0]]) if rec.ffn else None}
+
+    def layer_of(kind, layer):
+        held, stack = stacks[kind]
+        lp = {k: jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
+              for k, v in stack.items()}
+        return dict(lp, **held, layer=layer) if held else lp
+
+    def counted(x, rows):
+        """``(x, rows)`` of a layer whose FFN counts its experts' rows
+        (a dense one, or none: ``x`` alone, nothing to count)."""
+        if isinstance(x, tuple):
+            return x[0], _count_routed(fam, cfg, rows, x[1], B * T)
+        return x, rows
 
     def recurrent_layer(carry, layer):
         x, rows, conv, state = carry
-        lp = {k: jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
-              for k, v in rec_stack.items()}
-        if rec_whole:
-            lp = dict(lp, **rec_whole, layer=layer)
+        lp = layer_of(True, layer)
         # what goes out of the carried buffers and back: the rows and
         # the state, but what a decode step updates where it lies
         rows_out = () if rows_in_place else (conv,)
@@ -155,18 +164,20 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
         conv = held[0].buffer if rows_in_place else out[0]
         if state is not None:
             state = held[1].buffer if in_place else out[-1]
-        x = rec.out(cfg, x, y, lp)
-        if fam.expert_rows(cfg)[0]:     # else a dense FFN: nothing to count
-            x, rows = x[0], _count_routed(fam, cfg, rows, x[1], B * T)
+        x, rows = counted(rec.out(cfg, x, y, lp), rows)
         return (x, rows, conv, state), None
 
-    # consecutive layers of one kind: [(per-slot?, how many), ...]
-    runs = [(kind, len(list(g))) for kind, g in itertools.groupby(kinds)]
-
-    def period(x, att, p, kp, vp, rows, conv, state):
-        i_rec = i_att = 0
-        for recurrent, n in runs:
-            if recurrent:
+    def period(kinds, done, scanned, x, att, p, kp, vp, rows, conv, state):
+        """Period ``p`` of a section whose period is ``kinds``, ``done``
+        layers of each kind before it; ``att``: the period's pool
+        layers' params where the section ``scanned`` them."""
+        n = {kind: kinds.count(kind) for kind in done}
+        i = dict.fromkeys(done, 0)
+        # consecutive layers of one kind: (kind, how many)
+        for kind, run in ((k, len(list(g)))
+                          for k, g in itertools.groupby(kinds)):
+            first, i[kind] = i[kind], i[kind] + run     # in the period
+            if kind:
                 # a loop of their own: each iteration reads its layer's
                 # state and updates the carried buffer once.  Unrolled,
                 # layer i + 1 read the buffer layer i had just updated
@@ -176,21 +187,37 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
                 # overwritten: the state moved twice a step (v5e, PR 35)
                 (x, rows, conv, state), _ = jax.lax.scan(
                     recurrent_layer, (x, rows, conv, state),
-                    p * n_rec + i_rec + jnp.arange(n, dtype=jnp.int32))
-                i_rec += n
+                    p * n[kind] + (done[kind] + first)
+                    + jnp.arange(run, dtype=jnp.int32))
                 continue
-            for _ in range(n):
-                layer = p * n_att + i_att       # in "blocks"; in the pool
-                lp = {k: v[i_att] for k, v in att.items()}
-                if att_whole:
-                    lp = dict(lp, **att_whole, layer=layer)
+            for j in range(first, first + run):
+                layer = p * n[kind] + (done[kind] + j)  # in the kind's stack
+                if kind is None:
+                    x, rows = counted(
+                        rec.ffn[1](cfg, x, layer_of(None, layer)), rows)
+                    continue
+                if scanned:
+                    lp = {k: v[j] for k, v in att.items()}
+                    if stacks[False][0]:
+                        lp = dict(lp, **stacks[False][0], layer=layer)
+                else:
+                    lp = layer_of(False, layer)
                 x, kp, vp, _, _, rows = block(
                     x, lp, n_lead + layer if n_lead else layer, kp, vp,
                     None, None, rows)
-                i_att += 1
         return x, kp, vp, rows, conv, state
 
-    x, cache = paged_period_loop(period, x, att_stack, cache, periods)
+    done = {True: 0, False: 0, None: 0}
+    for kinds, count in sections_of(rec, cfg, n_pool):
+        n_att = kinds.count(False)
+        scanned = bool(n_att) and n_att * count == n_pool
+        att = {k: v.reshape((count, n_att) + v.shape[1:])
+               for k, v in stacks[False][1].items()} if scanned else {}
+        x, cache = paged_period_loop(
+            functools.partial(period, kinds, dict(done), scanned), x, att,
+            cache, count)
+        done = {kind: n + count * kinds.count(kind)
+                for kind, n in done.items()}
     return x, cache._replace(
         seq_lens=start + jnp.where(real > 0, T, 0), real=None)
 

@@ -3279,11 +3279,11 @@ class ServingEngine:
                     1.0 - valid_tokens / mapped_capacity, 4)
                 if mapped_capacity else 0.0,
             },
-            # the per-slot recurrent state beside the pages (a family
-            # with recurrent layers): what it weighs, and how many
-            # slots' states are in use
+            # the per-slot state beside the pages; layers that keep nothing
             "cache.state": {
                 "layers": self._state_row.layers,
+                "ffn_alone": {"bytes": 0, "layers": getattr(
+                    self, "ffn_alone_layers", 0)},
                 "bytes": self._state_bytes(),
                 "bytes_per_slot": self._state_bytes() // self.max_batch,
                 "live_slots": sum(1 for s in self.slots if s is not None),
@@ -3838,7 +3838,7 @@ def serving_engine(params, cfg, **kw):
         # the pool has the layers that attend over pages; the others
         # keep a state a slot beside it
         kw["state_row"] = fam.recurrent.state_row(cfg)
-        n_layers -= kw["state_row"].layers
+        n_layers = fam.pool_layers(cfg)
     held, per_row = fam.expert_rows(cfg)
     # the counts ride in the decode program's fetch; a speculating
     # engine's steady program is the verify sweep, which has none
@@ -3853,4 +3853,8 @@ def serving_engine(params, cfg, **kw):
         **kw)
     if comm_stats is not None:
         _record_comm_placement(eng, comm_stats)
+    if fam.recurrent is not None:
+        # layers that are an FFN alone: neither pages nor a state
+        eng.ffn_alone_layers = cfg.n_layers - n_layers \
+            - kw["state_row"].layers
     return eng

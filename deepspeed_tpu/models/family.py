@@ -2,7 +2,7 @@
 
 A family's file (``gpt2.py``, ``llama.py``, ``mixtral.py``,
 ``pangu_ultra_moe.py``, ``qwen3_next.py``, ``granite_hybrid.py``,
-``laguna.py``) ends with its
+``laguna.py``, ``nemotron_h.py``) ends with its
 ``FAMILY = DecoderFamily(...)``: the pieces of one transformer layer and
 the facts a serving build needs.  Everything that serves, streams, drafts
 or generates (the ``inference`` package) reads the record through
@@ -56,68 +56,68 @@ class StateRow(NamedTuple):
     recurrence's matrix a head, float32; None where the layer keeps
     rows alone), for each of ``layers`` such layers.  It is indexed by
     slot, not by page: ``PagedKVCache.conv`` is ``[layers, B, *conv]``
-    and ``PagedKVCache.state`` ``[layers, B, *state]`` or None."""
+    and ``PagedKVCache.state`` ``[layers, B, *state]`` or None.  (A layer
+    that is an FFN alone, ``Recurrent.ffn``, keeps nothing.)"""
 
     layers: int
     conv: Tuple[int, ...]
     state: Optional[Tuple[int, ...]]
 
 
+
 @dataclasses.dataclass(frozen=True)
 class Recurrent:
     """The layers of a family that keep a bounded state a slot beside
     the page pool, in periods with layers that attend over pages (an
-    author's fields, as ``DecoderFamily``'s).  Three users: a delta rule
-    (``qwen3_next``) and a state-space mixer (``granite_hybrid``), whose
-    state is a matrix a head that every token moves, and a sliding
-    window's attention (``laguna``), whose state is a ring of the last
-    tokens' K and V.
+    author's fields, as ``DecoderFamily``'s).  Four users: a delta rule
+    (``qwen3_next``), a state-space mixer (``granite_hybrid``,
+    ``nemotron_h``): a matrix a head that every token moves, and a
+    sliding window (``laguna``): a ring of the last tokens' K and V.
 
-    ``period(cfg)``: one bool a layer of a period, True where the layer
-    mixes tokens over its per-slot state and False where it attends over
-    the page pool (``qkv`` / ``out``), in whatever order the model has
-    them (an attention layer may end a period or stand inside it); the
-    model is whole periods (behind ``DecoderFamily.lead``, where the
-    family has one).  ``key``: the params' stack of these layers
-    ``[periods * such layers a period, ...]``; ``blocks`` holds the
-    pool's layers alone.  ``mix(cfg, x, lp, state, valid, start, ctx) ->
-    (y, state)``: ``x`` [B, T, d] the residual stream, ``state`` the
-    rows' ``(conv [B, *conv], state [B, *state])``, ``valid`` [B] int32
-    how many of each row's T tokens are real (the rest is padding, or
-    the whole row a slot that is idle or between two chunks of its
+    ``period(cfg)``: one entry a layer of a period: True where the layer
+    mixes tokens over its per-slot state, False where it attends over
+    the page pool (``qkv`` / ``out``), None where it is an FFN alone
+    (``ffn``), in the model's order; the model is whole periods (behind
+    ``DecoderFamily.lead``, if any).  ``sections(cfg)``, for a model that
+    is not: ``((period, count), ...)``, run in order over one pool and
+    one state buffer, each kind's layer indices running on (None: one
+    section, :func:`sections_of`).  ``key``: the params' stack of the
+    per-slot layers; ``blocks`` holds the pool's layers alone.  ``ffn``:
+    ``(key, hook)`` of the layers that are an FFN alone, their stack and
+    ``hook(cfg, x, lp) -> x`` or ``(x, rows)``, residual included: such
+    a layer touches neither the pool nor the per-slot state.  ``mix(cfg,
+    x, lp, state, valid, start, ctx) -> (y, state)``: ``x`` [B, T, d],
+    ``state`` the rows' ``(conv [B, *conv], state [B, *state])``,
+    ``valid`` [B] how many of each row's T tokens are real (the rest
+    padding, or the whole row a slot idle or between two chunks of its
     prompt): the state moves on real tokens only; ``start`` [B] where
     each row's first token stands and ``ctx`` what ``embed`` made of the
     positions (a mixer without positions ignores both).  ``out(cfg, x,
-    y, lp)``: the residual and the FFN half, as ``DecoderFamily.out``
-    (``(x, rows)`` where the family counts its experts' rows, ``x``
-    where it has none).  ``state_row``: what a slot keeps.
-    ``write_scope``: the ``jax.named_scope`` word, inside ``kv_write``,
-    of the write-back of a layer's rows into the carried buffers.
-    ``chunk_reader(cfg, tokens, interpret) -> (reader, reason)``: which
-    reader ``mix`` runs over a chunk of ``tokens``, where it has two.
+    y, lp)``: the residual and, where the layer has one, the FFN half
+    (``(x, rows)`` where that counts its experts' rows, else ``x``).
+    ``state_row``: what a slot keeps.  ``write_scope``: the scope word,
+    inside ``kv_write``, of the write-back of a layer's rows.
+    ``chunk_reader(cfg, tokens, interpret) -> (reader, reason)``.
 
-    Where the program is a decode step over every slot on one device,
-    ``mix`` is handed as ``state[1]`` not the rows' state but a
-    :class:`CarriedState`: the whole buffer of every such layer and
-    which layer this is.  A family steps either through
-    :func:`step_state` with its one-token rule, and hands back what that
-    returned; it never reads a ``CarriedState`` as an array (a prompt
-    chunk's ``mix`` is never handed one).  ``rows_in_place``: in a
-    decode step over every slot ``state[0]`` is a :class:`CarriedRows`
-    too: the mixer writes its layer of the buffer where it lies (one row
-    a live slot), reads it there, and hands the ``CarriedRows`` back;
-    the seam then neither slices the layer's rows out nor puts them
-    back (right for 3 rows of a convolution; three passes over a ring
-    of 512)."""
+    In a decode step over every slot on one device ``mix`` is handed as
+    ``state[1]`` not the rows' state but a :class:`CarriedState`: the
+    whole buffer of every such layer and which layer this is.  A family
+    steps either through :func:`step_state` with its one-token rule and
+    hands back what that returned; it never reads one as an array.
+    ``rows_in_place``: there ``state[0]`` is a :class:`CarriedRows` too:
+    the mixer writes its layer of the buffer where it lies and hands it
+    back (right for 3 rows of a convolution; three passes over 512)."""
 
     key: str
-    period: Callable[[Any], Tuple[bool, ...]]
+    period: Callable[[Any], tuple]
     mix: Callable[..., Tuple[Any, Any]]
     out: Callable[..., Any]
     state_row: Callable[[Any], StateRow]
     write_scope: str
     rows_in_place: bool = False
     chunk_reader: Optional[Callable[..., Tuple[str, str]]] = None
+    sections: Optional[Callable[[Any], tuple]] = None
+    ffn: Optional[Tuple[str, Callable[..., Any]]] = None
 
 
 class CarriedRows(NamedTuple):
@@ -159,6 +159,17 @@ def step_state(rule, S, *vectors):
 
 def _per_head_rows(cfg) -> CacheRow:
     return CacheRow(cfg.n_kv_heads, cfg.head_dim, cfg.head_dim)
+
+
+def sections_of(rec: Recurrent, cfg, pool_layers: Optional[int] = None):
+    """``((period, count), ...)`` of a family's per-slot seam: what its
+    ``sections`` states, else its one ``period`` as often as
+    ``pool_layers`` (the pool's layers behind the lead) hold its pool
+    layers."""
+    if rec.sections is not None:
+        return tuple(rec.sections(cfg))
+    kinds = tuple(rec.period(cfg))
+    return ((kinds, pool_layers // kinds.count(False)),)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -235,6 +246,18 @@ class DecoderFamily:
     def name(self) -> str:
         return self.config_type.__name__
 
+    def pool_layers(self, cfg) -> int:
+        """How many of ``cfg``'s layers attend over pages: the pool's
+        leading dimension.  Every layer; with a per-slot seam, those
+        that neither keep a state a slot nor are an FFN alone."""
+        rec = self.recurrent
+        if rec is None:
+            return cfg.n_layers
+        if rec.sections is None:
+            return cfg.n_layers - rec.state_row(cfg).layers
+        return sum(kinds.count(False) * count
+                   for kinds, count in rec.sections(cfg))
+
     def sharded(self, mesh) -> bool:
         return mesh is not None and any(
             mesh.size(ax) > 1 for ax in self.shard_axes)
@@ -261,7 +284,7 @@ def positions_from(start, T: int):
 
 # the registry: one module name a family
 _FAMILY_MODULES = ("gpt2", "llama", "mixtral", "pangu_ultra_moe",
-                   "qwen3_next", "granite_hybrid", "laguna")
+                   "qwen3_next", "granite_hybrid", "laguna", "nemotron_h")
 
 
 def decoder_families() -> Tuple[DecoderFamily, ...]:

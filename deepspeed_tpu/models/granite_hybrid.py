@@ -17,17 +17,19 @@ Attention on ``a = N(x)``: ``[q | k | v] = a W_qkv``, no bias, no
 rotation, causal softmax of ``attention_multiplier * q k^T`` (a stated
 scale, not ``head_dim^-1/2``), ``W_o``.
 
-Mamba-2 on ``a`` (``H`` heads of ``P`` channels, one group, state ``N``):
-``[z | xBC | dt] = a [W_in | W_dt]``; ``xBC`` passes a depthwise causal
-convolution of ``conv_kernel`` taps with bias, then SiLU, and splits into
-``x`` [H, P], ``B`` [N], ``C`` [N] (shared by the heads); ``D_t =
-softplus(dt + dt_bias)`` and ``A = -exp(A_log)``, one a head, f32.  A
-head keeps ``S`` [P, N] in float32, from zero::
+Mamba-2 on ``a`` (``H`` heads of ``P`` channels in ``G`` groups, state
+``N``; this model has one group, ``nemotron_h``, which runs the same
+functions, eight): ``[z | xBC | dt] = a [W_in | W_dt]``; ``xBC`` passes a
+depthwise causal convolution of ``conv_kernel`` taps with bias, then
+SiLU, and splits into ``x`` [H, P], ``B`` [G, N], ``C`` [G, N] (head
+``h`` reads group ``h // (H / G)``'s); ``D_t = softplus(dt + dt_bias)``
+and ``A = -exp(A_log)``, one a head, f32.  A head keeps ``S`` [P, N] in
+float32, from zero::
 
     S_t = exp(D_t A) S_(t-1) + D_t x_t B_t^T;   o_t = S_t C_t + D x_t
 
-then ``y = N_inner(o_t * SiLU(z_t))`` (the gate before the norm, which
-runs over all ``H P`` channels) and ``W_out``.  A decode step is that
+then ``y = N_group(o_t * SiLU(z_t))`` (the gate before the norm, which
+runs over each group's ``H P / G`` channels) and ``W_out``.  A decode step is that
 recurrence (:func:`ssm_step`); a prompt chunk computes the same in
 blocks of ``ssm_block`` tokens (:func:`ssm_chunk_scan`), the state
 carried from block to block and from chunk to chunk.  What a slot keeps
@@ -69,6 +71,7 @@ class GraniteHybridConfig:
     ssm_heads: int = 64
     ssm_head_dim: int = 64
     ssm_state: int = 128
+    ssm_groups: int = 1
     conv_kernel: int = 4
     embedding_multiplier: float = 12.0
     residual_multiplier: float = 0.22
@@ -88,6 +91,7 @@ class GraniteHybridConfig:
         assert self.n_layers % len(self.period) == 0, \
             "the model is whole periods"
         assert self.n_heads % self.n_kv_heads == 0
+        assert self.ssm_heads % self.ssm_groups == 0
 
     @classmethod
     def from_layer_types(cls, layer_types, **kw):
@@ -121,7 +125,7 @@ class GraniteHybridConfig:
 
     @property
     def conv_channels(self) -> int:
-        return self.ssm_inner + 2 * self.ssm_state
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @classmethod
     def tiny(cls, **kw):
@@ -274,22 +278,26 @@ def ssm_rule(S, dtx, decay, Bm, Cm):
 
 def ssm_step(x, dt, A, Bm, Cm, S):
     """One token of the recurrence, every row and head at once: x [B, H,
-    P], dt [B, H], A [H], Bm, Cm [B, N], all f32, S [B, H, P, N] or the
-    carried buffer it is a layer of (``family.step_state``) -> (o [B, H,
-    P] without the ``D x`` skip, S as it came): :func:`ssm_rule` on
-    ``dt x`` and ``e^(dt A)``.  A row with ``dt = 0`` leaves its state as
-    it was, bit for bit."""
+    P], dt [B, H], A [H], Bm, Cm [B, N] (one group: shared by the heads)
+    or [B, G, N] (a group's, handed to each of its heads), all f32, S
+    [B, H, P, N] or the carried buffer it is a layer of
+    (``family.step_state``) -> (o [B, H, P] without the ``D x`` skip, S
+    as it came): :func:`ssm_rule` on ``dt x`` and ``e^(dt A)``.  A row
+    with ``dt = 0`` leaves its state as it was, bit for bit."""
     decay = jnp.exp(dt * A)[..., None, None]                 # [B, H, 1, 1]
     dtx = (dt[..., None] * x)[..., None]                     # [B, H, P, 1]
-    o, S = step_state(ssm_rule, S, dtx, decay, Bm[:, None, None, :],
-                      Cm[:, None, None, :])
+    a_head = lambda v: v[:, None, None, :] if v.ndim == 2 else jnp.repeat(
+        v, x.shape[1] // v.shape[1], axis=1)[:, :, None, :]
+    o, S = step_state(ssm_rule, S, dtx, decay, a_head(Bm), a_head(Cm))
     return o[..., 0], S
 
 
 def ssm_chunk_scan(x, dt, A, Bm, Cm, S, block: int):
     """The recurrence of :func:`ssm_step` over T tokens in blocks: x [B,
-    T, H, P], dt [B, T, H], A [H], Bm, Cm [B, T, N], S [B, H, P, N], f32
-    -> (o [B, T, H, P], S).  Inside a block, with ``L`` the running sum
+    T, H, P], dt [B, T, H], A [H], Bm, Cm [B, T, N] (one group) or [B, T,
+    G, N], S [B, H, P, N], f32 -> (o [B, T, H, P], S).  With groups the
+    ``C . B`` products are a group's, made once and handed to each of
+    its heads.  Inside a block, with ``L`` the running sum
     of ``dt A``: ``o_i = e^L_i S C_i + sum_(j <= i) e^(L_i - L_j) (C_i .
     B_j) dt_j x_j``, and the block leaves ``e^L_last S + sum_j e^(L_last
     - L_j) dt_j x_j B_j^T``.  No factor is above 1.  T is padded to
@@ -308,7 +316,13 @@ def ssm_chunk_scan(x, dt, A, Bm, Cm, S, block: int):
     i, j = np.arange(C)[:, None], np.arange(C)[None]
     decay = jnp.exp(jnp.where(i >= j, L[..., :, None] - L[..., None, :],
                               -jnp.inf))                     # j <= i, else 0
-    M = _mm("nbis,nbjs->nbij", Cm, Bm)[:, :, None] * decay   # [N, B, H, C, C]
+    if Bm.ndim == 5:                    # [N, B, C, G, N]: a group's
+        each, g = H // Bm.shape[3], "h"
+        M = jnp.repeat(_mm("nbigs,nbjgs->nbgij", Cm, Bm), each, axis=2)
+        Bm, Cm = jnp.repeat(Bm, each, axis=3), jnp.repeat(Cm, each, axis=3)
+    else:
+        M, g = _mm("nbis,nbjs->nbij", Cm, Bm)[:, :, None], ""
+    M = M * decay                                            # [N, B, H, C, C]
     intra = _mm("nbhij,nbjhp->nbihp", M, dtx)
     q_in = jnp.exp(L)                                        # [N, B, H, C]
     k_out = jnp.exp(L[..., -1:] - L)
@@ -316,8 +330,8 @@ def ssm_chunk_scan(x, dt, A, Bm, Cm, S, block: int):
 
     def one(S, b):
         intra, Cm, Bm, dtx, q_in, k_out, last = b
-        o = intra + _mm("bis,bhps,bhi->bihp", Cm, S, q_in)
-        return last * S + _mm("bjhp,bjs,bhj->bhps", dtx, Bm, k_out), o
+        o = intra + _mm(f"bi{g}s,bhps,bhi->bihp", Cm, S, q_in)
+        return last * S + _mm(f"bjhp,bj{g}s,bhj->bhps", dtx, Bm, k_out), o
 
     S, o = jax.lax.scan(one, S, (intra, Cm, Bm, dtx, q_in, k_out, last),
                         unroll=True)
@@ -325,8 +339,9 @@ def ssm_chunk_scan(x, dt, A, Bm, Cm, S, block: int):
 
 
 def _gated_norm(o, z, w, eps):
-    """``N(o * SiLU(z))`` over all the inner channels (one group): the
-    gate is applied BEFORE the norm."""
+    """``N(o * SiLU(z))`` over the last axis (all the inner channels, or
+    one group's where the caller has set the groups apart): the gate is
+    applied BEFORE the norm."""
     g = o * jax.nn.silu(z.astype(jnp.float32))
     return g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + eps) \
         * w.astype(jnp.float32)
@@ -344,6 +359,7 @@ def ssm_mix(cfg, x, lp, state, valid, start=None, ctx=()):
     B, T, _ = x.shape
     H, Pd, N, taps = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
                       cfg.conv_kernel)
+    G = cfg.ssm_groups
     inner, f32 = cfg.ssm_inner, jnp.float32
     conv, S = state
     # the benchmark's vocabulary has attention's words; ours nest in them
@@ -365,7 +381,9 @@ def ssm_mix(cfg, x, lp, state, valid, start=None, ctx=()):
         conv = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
             rows, n, taps - 1))(seen, valid).astype(conv.dtype)
         xs = y[..., :inner].reshape(B, T, H, Pd)
-        Bm, Cm = y[..., inner:inner + N], y[..., inner + N:]
+        Bm, Cm = y[..., inner:inner + G * N], y[..., inner + G * N:]
+        if G > 1:                       # a group's, [B, T, G, N]
+            Bm, Cm = Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N)
     if T == 1:
         with jax.named_scope("kv_attend"), jax.named_scope("ssm_step"):
             o, S = ssm_step(xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], S)
@@ -376,9 +394,11 @@ def ssm_mix(cfg, x, lp, state, valid, start=None, ctx=()):
             o, S = ssm_chunk_scan(xs, dt, A, Bm, Cm, S.astype(f32),
                                   cfg.ssm_block)
     with jax.named_scope("attn_out"), jax.named_scope("ssm_gate_norm"):
-        o = o + lp["D"].astype(f32)[:, None] * xs
-        o = _gated_norm(o.reshape(B, T, inner), z, lp["ssm_norm"],
-                        cfg.norm_eps)
+        o = (o + lp["D"].astype(f32)[:, None] * xs).reshape(B, T, inner)
+        # the norm runs over each group's channels
+        apart = lambda a: a.reshape(a.shape[:-1] + (G, -1)) if G > 1 else a
+        o = _gated_norm(apart(o), apart(z), apart(lp["ssm_norm"]),
+                        cfg.norm_eps).reshape(B, T, inner)
         return o.astype(x.dtype) @ lp["w_out"], (conv, S)
 
 

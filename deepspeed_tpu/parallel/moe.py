@@ -172,17 +172,20 @@ def expert_param_specs(specs: Any) -> Any:
 
 # ------------------------------------------- a share of the experts, served
 def sigmoid_topk_route(h, gate, top_k: int, scale: float = 1.0,
-                       normalize: bool = True):
+                       normalize: bool = True, bias=None):
     """Router of the sigmoid-scored families: ``h`` [N, d] against
     ``gate`` [d, E] over ALL E experts, in f32 whatever the inputs are
     (a bf16 score flips near-tied choices) -> (weights [N, k] f32,
-    experts [N, k] int32).  The k largest scores, divided by their sum
-    (``normalize``) and multiplied by ``scale``."""
+    experts [N, k] int32).  The k largest (by ``s + bias`` [E]: the choice
+    moves, not the weight), over their sum (``normalize``), x ``scale``."""
     with jax.named_scope("moe_router"):
         s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32),
                                    gate.astype(jnp.float32),
                                    precision=jax.lax.Precision.HIGHEST))
-        top, idx = jax.lax.top_k(s, top_k)
+        top, idx = jax.lax.top_k(
+            s if bias is None else s + bias.astype(jnp.float32), top_k)
+        if bias is not None:
+            top = jnp.take_along_axis(s, idx, axis=-1)
         if normalize:
             top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
         return top * scale, idx.astype(jnp.int32)
@@ -190,9 +193,8 @@ def sigmoid_topk_route(h, gate, top_k: int, scale: float = 1.0,
 
 def softmax_topk_route(h, gate, top_k: int, normalize: bool = True):
     """Router of the softmax-scored families: ``p = softmax(h W)`` over
-    ALL E experts in f32 (as :func:`sigmoid_topk_route`, and for its
-    reason) -> (weights [N, k] f32, experts [N, k] int32): the k largest
-    probabilities, divided by their sum where ``normalize``."""
+    ALL E experts in f32 (as :func:`sigmoid_topk_route`) -> (weights,
+    experts): the k largest, divided by their sum where ``normalize``."""
     with jax.named_scope("moe_router"):
         p = jax.nn.softmax(jnp.dot(h.astype(jnp.float32),
                                    gate.astype(jnp.float32),
@@ -222,21 +224,19 @@ def _every_row_pays(N: int, k: int, Eh: int) -> bool:
     the ``Eh`` held experts on every row than sorted into groups: a rule
     of the shapes alone.  Every expert on every row is ``N * Eh`` row
     products; the grouped product is at most ``N * k`` (every pair held)
-    plus up to a row tile of padding an expert.  Both pay the same
-    6 d f a row product, so the widths cancel, and under a row tile of
-    rows both are one pass over the held weights, which the plain
-    products make without a sort and two gathers of wide rows.
+    plus up to a row tile of padding an expert.  Both pay the same a row
+    product (6 d f gated, 4 d f not), so the widths cancel, and under a
+    row tile of rows both are one pass over the held weights, which the
+    plain products make without a sort and two gathers of wide rows.
 
-    On a v5e (ms for the three products; PERF.md 6, PR 34), 8 of 8
-    experts of 4096 x 14336, k = 2: 256 rows 4.3 plain against 4.9
-    grouped, 384 rows 6.0 against 5.5, 1,024 rows 16.0 against 8.3 (the
-    rule crosses at 341); 16 of 256 experts of 7680 x 2048, k = 8: 256
-    rows 2.4 against 2.6, 512 rows 4.5 against 2.9 (it crosses at 512,
-    late for a share: few of its N * k pairs are held.  The grouped
-    branch's pair buffer is sized from how many experts there are in
-    all, :func:`_pair_buffer_rows`; this rule still is not, and no
-    cell's programs have a row count between the two crossings).
-    """
+    On a v5e (ms for the three products; PERF.md 6, PR 34), 8 of 8 of
+    4096 x 14336, k = 2: 256 rows 4.3 plain against 4.9 grouped, 384
+    rows 6.0 against 5.5, 1,024 rows 16.0 against 8.3 (the rule crosses
+    at 341); 16 of 256 of 7680 x 2048, k = 8: 256 rows 2.4 against 2.6,
+    512 rows 4.5 against 2.9 (it crosses at 512, late for a share: few
+    of its N * k pairs are held; :func:`_pair_buffer_rows` knows how
+    many experts there are in all, this rule does not, and no cell's
+    programs have a row count between the two crossings)."""
     return N * (Eh - k) < Eh * _GMM_TILING[0]
 
 
@@ -300,52 +300,52 @@ def extra_pair_passes(sizes, N: int, k: int, E: int):
 
 def held_experts_ffn(h, weights, experts, w1, w3, w2, first: int = 0,
                      layer=None, grouped: bool = True,
-                     n_experts: Optional[int] = None):
+                     n_experts: Optional[int] = None, act=None):
     """The part of a routed FFN that the experts held here contribute:
-    drop-free at any imbalance, static shapes.
-
-    ``h`` [N, d]; ``weights``/``experts`` [N, k] from the router over all
-    the ``n_experts`` there are (the width of its gate; not said: no
-    more than are held); ``w1``/``w3`` [Eh, d, f] and ``w2`` [Eh, f, d]
-    the SwiGLU experts ``first .. first + Eh`` -> (y [N, d] in ``h``'s
-    dtype, rows [Eh] int32 routed to each held expert).  With ``layer``
-    (a layer loop's traced index) the weights are the whole stacks
-    [L, Eh, ...] and that layer's experts are meant.
+    drop-free at any imbalance, static shapes.  ``h`` [N, d];
+    ``weights``/``experts`` [N, k] from the router over all the
+    ``n_experts`` there are (its gate's width; not said: no more than
+    are held); the experts ``first .. first + Eh`` in the body the
+    caller states: gated, ``(SiLU(h w1) * (h w3)) w2`` with ``w1``/``w3``
+    [Eh, d, f] and ``w2`` [Eh, f, d], or (``w3`` None) ``act(h w1) w2``
+    -> (y [N, d] in ``h``'s dtype, rows [Eh] int32 routed to each held
+    expert).  With ``layer`` (a layer loop's traced index) the weights
+    are the whole stacks [L, Eh, ...] and that layer's experts are meant.
 
     Many rows: every (token, expert) pair is a row; pairs whose expert is
     held sort first, by expert, and the rest (what other ranks compute)
     fall past the last group, where the grouped product visits no tile.
     The row buffer holds a bound on the pairs held HERE
-    (:func:`_pair_buffer_rows`: twice the even share of a rank that
-    holds ``Eh`` of ``n_experts``), not every pair the router made; when
-    more are held than it takes, further passes over the sorted order
-    take the rest (:func:`extra_pair_passes` counts them), each adding
-    into the same f32 sum.  Few rows (a decode step;
-    :func:`_every_row_pays`): each held expert evaluates every row and
-    the router's weight, zero where it did not choose the expert,
-    combines them; that reads each expert once, as the grouped product
-    would, without its row tiles.
+    (:func:`_pair_buffer_rows`), not every pair the router made; when
+    more are held, further passes over the sorted order take the rest
+    (:func:`extra_pair_passes` counts them), each adding into the same
+    f32 sum.  Few rows (a decode step; :func:`_every_row_pays`): each
+    held expert evaluates every row and the router's weight, zero where
+    it did not choose the expert, combines them: one read an expert.
 
-    ``grouped=False`` is the caller's word that the weights are not
-    plain arrays held whole on one device (sharded over a mesh, which a
-    Mosaic call cannot be partitioned over; dequantised on the way in;
-    a layer's slice of a stack, which a Mosaic call would copy): every
-    held expert then evaluates every row at any row count.
-    """
+    ``grouped=False``: the caller's word that the weights are not plain
+    arrays held whole on one device (sharded over a mesh; dequantised on
+    the way in; a layer's slice of a stack, which a Mosaic call would
+    copy): every held expert then evaluates every row at any row count."""
     N, k = experts.shape
     Eh = w1.shape[-3]
+    if w3 is not None:
+        mid = lambda a, b: jax.nn.silu(a) * b
+        one = lambda a, b, c: (jax.nn.silu(h @ a) * (h @ b)) @ c
+    else:
+        mid = lambda a, b: act(a)
+        one = lambda a, c: act(h @ a) @ c
     local = experts.reshape(-1) - first
     held = (local >= 0) & (local < Eh)
     group = jnp.where(held, local, Eh)               # not held: sorts last
     sizes = jnp.zeros((Eh + 1,), jnp.int32).at[group].add(1)[:Eh]
     with jax.named_scope("moe_routed"):
         if not grouped or _every_row_pays(N, k, Eh):
-            if layer is not None:
-                w1, w3, w2 = w1[layer], w3[layer], w2[layer]
+            ws = tuple(w if layer is None else w[layer]
+                       for w in (w1, w3, w2) if w is not None)
             gain = jnp.zeros((N, Eh + 1), jnp.float32).at[
                 jnp.arange(N * k) // k, group].add(weights.reshape(-1))
-            ys = jax.vmap(lambda a, b, c: (jax.nn.silu(h @ a) * (h @ b)) @ c)(
-                w1, w3, w2)                                     # [Eh, N, d]
+            ys = jax.vmap(one)(*ws)                             # [Eh, N, d]
             out = jnp.einsum("ne,end->nd", gain[:, :Eh],
                              ys.astype(jnp.float32))
             return out.astype(h.dtype), sizes
@@ -353,8 +353,8 @@ def held_experts_ffn(h, weights, experts, w1, w3, w2, first: int = 0,
 
         def products(x, sizes):
             a = _grouped_product(x, w1, sizes, layer)
-            b = _grouped_product(x, w3, sizes, layer)
-            return _grouped_product(jax.nn.silu(a) * b, w2, sizes, layer)
+            b = w3 is not None and _grouped_product(x, w3, sizes, layer)
+            return _grouped_product(mid(a, b), w2, sizes, layer)
 
         def stands():
             """Where each pair stands in the sorted order (``order`` is
