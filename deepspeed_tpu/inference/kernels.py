@@ -709,105 +709,104 @@ def decode_pages_per_block(n_kv: int, page_size: int, head_dim: int,
     return max(1, min(max_pages, _DECODE_BLOCK_BYTES // page_bytes))
 
 
-def _decode_kernel(table_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                   o_ref, kb, vb, sem, *, scale, ps, max_pages, ppb):
-    """One grid step a batch row.  The row's live pages stream ``ppb`` at
-    a time through a double-buffered VMEM scratch, each page's K (and V)
-    for ALL kv heads in one strided copy out of the stored pool
-    [L, KV, P, ps, Dh] (``k_hbm.at[layer, :, pid]``); the products are
-    batched over the kv heads.  Nothing past the row's ``seq_len`` is
-    dereferenced: a dead page of the last block is zeroed in VMEM, not
-    copied, and a row with ``seq_len == 0`` starts no copy and returns
-    zeros.  Scores, the online softmax and the accumulator are f32."""
-    b = pl.program_id(0)
-    n = lens_ref[b]
-    layer = layer_ref[0]
-    pages_live = (n + ps - 1) // ps
-    nblk = (pages_live + ppb - 1) // ppb
+def _stream_live_blocks(table_ref, lens_ref, layer, streams, sem, turn,
+                        body, init, *, ps):
+    """Row ``pl.program_id(0)``'s ``body(c, slot, carry)`` over its blocks
+    of ``ppb`` pages, block ``c`` in ``slot`` of every buffer when its
+    ``body`` runs (design note: above :func:`_start_pages`).  ``streams``:
+    (pool [L, KV, P, ps, W] in HBM, buffer [2, KV, ppb, ps, W] in VMEM)
+    pairs; ``sem``: DMA semaphores [2, len(streams)]; ``turn``: SMEM
+    scratch, the slot of this row's first block, which the row before it
+    started.  No table entry past the row's live pages is read; a dead
+    slot of a row's last block holds a live page's rows or the zeros the
+    first row puts there: finite, under keys the ``body`` masks.  Every
+    copy started is awaited before the grid ends (rows run in order)."""
+    b, rows, ppb = pl.program_id(0), lens_ref.shape[0], streams[0][1].shape[2]
+    pages = lambda r: jnp.minimum(table_ref.shape[1],
+                                  (lens_ref[r] + ps - 1) // ps)
+    live = pages(b)
+    nblk = (live + ppb - 1) // ppb
+    after = jnp.minimum(b + 1, rows - 1)
+    live_after = jnp.where(b + 1 < rows, pages(after), 0)
 
-    def page(c, slot, j):
-        """(live, the two copies) of page ``j`` of block ``c``."""
-        p = c * ppb + j
-        pid = table_ref[b, jnp.minimum(p, max_pages - 1)]
-        return p < pages_live, (
-            pltpu.make_async_copy(k_hbm.at[layer, :, pid],
-                                  kb.at[slot, :, j], sem.at[slot, 0]),
-            pltpu.make_async_copy(v_hbm.at[layer, :, pid],
-                                  vb.at[slot, :, j], sem.at[slot, 1]))
+    def fetch(row, c, slot, live):
+        _start_pages(table_ref, row, c * ppb, jnp.minimum(
+            ppb, live - c * ppb), layer, streams, sem, slot)
 
-    def start(c, slot):
-        def body(j, _):
-            live, copies = page(c, slot, j)
-
-            @pl.when(live)
-            def _():
-                for d in copies:
-                    d.start()
-
-            # stale VMEM under a masked position: its score is masked
-            # whatever K holds, but 0 * V must stay 0
-            @pl.when(jnp.logical_not(live))
-            def _():
-                vb[slot, :, j] = jnp.zeros(
-                    (vb.shape[1], ps, vb.shape[4]), vb.dtype)
-
-        jax.lax.fori_loop(0, ppb, body, None)
-
-    def wait(c, slot):
-        def body(j, _):
-            live, copies = page(c, slot, j)
-
-            @pl.when(live)
-            def _():
-                for d in copies:
-                    d.wait()
-
-        jax.lax.fori_loop(0, ppb, body, None)
-
-    @pl.when(nblk > 0)
+    @pl.when(b == 0)
     def _():
-        start(0, 0)
+        turn[0] = 0
+        for _, buf in streams:              # 0 * what VMEM held may be NaN
+            buf[...] = jnp.zeros(buf.shape, buf.dtype)
 
+    first = turn[0]
+    own = jnp.logical_and(b == 0, nblk > 0)     # no row before the first
+
+    @pl.when(jnp.logical_or(own, jnp.logical_and(nblk == 0, live_after > 0)))
+    def _():                                # an empty row hands over too
+        fetch(jnp.where(own, b, after), 0, first,
+              jnp.where(own, live, live_after))
+
+    def block(c, carry):
+        slot = jax.lax.rem(first + c, 2)
+        last = c + 1 == nblk
+
+        @pl.when(jnp.logical_or(jnp.logical_not(last), live_after > 0))
+        def _():
+            fetch(jnp.where(last, after, b), jnp.where(last, 0, c + 1),
+                  1 - slot, jnp.where(last, live_after, live))
+
+        _await_pages(jnp.minimum(ppb, live - c * ppb), streams, sem, slot)
+        return body(c, slot, carry)
+
+    carry = jax.lax.fori_loop(0, nblk, block, init)
+    turn[0] = jax.lax.rem(first + nblk, 2)
+    return carry
+
+
+def _decode_kernel(table_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                   o_ref, kb, vb, sem, turn, *, scale, ps):
+    """One grid step a batch row.  The row's live pages stream ``ppb`` at
+    a time through a double-buffered VMEM scratch (:func:`_stream_live_
+    blocks`), each page's K (and V) for ALL kv heads in one strided copy
+    out of the stored pool [L, KV, P, ps, Dh]; the products are batched
+    over the kv heads.  Nothing past the row's ``seq_len`` is dereferenced
+    and a row with ``seq_len == 0`` starts no copy and returns zeros.
+    Scores, the online softmax and the accumulator are f32."""
+    n = lens_ref[pl.program_id(0)]
     q = q_ref[0].astype(jnp.float32)                # [KV, g8, Dh]
     kv, g8, dh = q.shape
+    keys = kb.shape[2] * ps
 
     def block(buf, slot):
         """One slot's pages as [KV, ppb * ps, Dh] in f32: a page is a
         whole number of tiles, so the merge moves nothing."""
-        return buf[slot].reshape(kv, ppb * ps, dh).astype(jnp.float32)
+        return buf[slot].reshape(kv, keys, dh).astype(jnp.float32)
 
-    def loop(c, carry):
+    def attend(c, slot, carry):
         m, l, acc = carry
-        slot = jax.lax.rem(c, 2)
-
-        @pl.when(c + 1 < nblk)
-        def _():
-            start(c + 1, 1 - slot)
-
-        wait(c, slot)
         s = jax.lax.dot_general(
-            q, block(kb, slot),
-            (((2,), (2,)), ((0,), (0,))),
+            q, block(kb, slot), (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale     # [KV, g8, S]
-        kpos = c * (ppb * ps) + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2)
+        kpos = c * keys + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(kpos < n, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
         alpha = jnp.exp(m - m_new)
         # block 0 always holds position 0 < n, so m_new is finite; the
-        # select keeps a masked entry at 0 whatever a later mask does
+        # select keeps a masked entry at exactly 0, over finite values
         pr = jnp.where(kpos < n, jnp.exp(s - m_new), 0.0)
         l = l * alpha + jnp.sum(pr, axis=2, keepdims=True)
         acc = acc * alpha + jax.lax.dot_general(
-            pr, block(vb, slot),
-            (((2,), (1,)), ((0,), (0,))),
+            pr, block(vb, slot), (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)             # [KV, g8, Dh]
         return m_new, l, acc
 
     init = (jnp.full((kv, g8, 1), NEG_INF, jnp.float32),
             jnp.zeros((kv, g8, 1), jnp.float32),
             jnp.zeros((kv, g8, dh), jnp.float32))
-    m, l, acc = jax.lax.fori_loop(0, nblk, loop, init)
+    m, l, acc = _stream_live_blocks(
+        table_ref, lens_ref, layer_ref[0], ((k_hbm, kb), (v_hbm, vb)), sem,
+        turn, attend, init, ps=ps)
     l = jnp.where(l == 0.0, 1.0, l)                 # empty rows → zeros
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
@@ -840,11 +839,9 @@ def paged_decode_attention_v2(q, k_pages, v_pages, table, seq_lens,
     if g8 != G:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g8 - G), (0, 0)))
 
-    kernel = functools.partial(_decode_kernel, scale=scale, ps=ps,
-                               max_pages=mp, ppb=ppb)
     row = lambda b, *_: (b, 0, 0, 0)
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_decode_kernel, scale=scale, ps=ps),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,   # table, seq_lens, layer
             grid=(B,),
@@ -858,9 +855,12 @@ def paged_decode_attention_v2(q, k_pages, v_pages, table, seq_lens,
                 pltpu.VMEM((2, KV, ppb, ps, Dh), k_pages.dtype),
                 pltpu.VMEM((2, KV, ppb, ps, Dh), v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, g8, Dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(   # the rows in order
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="dstpu_paged_decode",
     )(table, seq_lens, _layer_operand(layer), qg, k_pages, v_pages)
@@ -1098,99 +1098,95 @@ def latent_decode_reference(q_abs, pages, table, seq_lens, scale: float,
     return out.astype(q_abs.dtype)
 
 
+# How a decode reader (K/V pages or latent rows) brings a row's live pages
+# into VMEM (:func:`_stream_live_blocks`; these two stand below the chunk
+# reader so that none of its lines moved: a Mosaic payload carries them,
+# and with them every chunk program's compile-cache key).  A page of 16
+# rows costs the kernel its scalar work, the same whatever the page holds
+# (v5e, kernel alone, PR 49: 78 ns at 16 KiB and at 32 KiB a page with a
+# guarded start and a guarded wait a page; 50 and 57 as it stands): the
+# table read, the copies' starts and their waits, which the products do
+# not hide.  So a block's live pages start with no guard, eight a loop
+# trip (the scheduler overlaps their table reads and addresses); a block
+# is awaited by its byte count, one wait a buffer when it is full (a DMA
+# semaphore counts bytes); nothing is started for a dead slot of a row's
+# last block; and the grid's rows are one stream: a row's last block
+# computes over the next row's first block's copies (a sixth of the call
+# at 28 rows of three blocks of 64 KiB pages; where the starts bind, as at
+# 2 K/V heads, it costs 3%).  PERF.md 6, PR 49 has the table by step.
+_DECODE_ISSUE_TRIP = 8
+
+
+def _start_pages(table_ref, row, at, n, layer, streams, sem, slot):
+    """Starts the copies of the ``n`` pages that entries ``at``, ``at +
+    1``, ... of ``row``'s table name, into places 0, 1, ... of ``slot``:
+    a page one strided copy a (pool, buffer) pair for all its KV heads.
+    Reads no other entry of the table."""
+    ppb = streams[0][1].shape[2]
+    trip = max(u for u in (_DECODE_ISSUE_TRIP, 4, 2, 1) if ppb % u == 0)
+
+    def page(j, _):
+        pid = table_ref[row, at + j]
+        for i, (pool, buf) in enumerate(streams):
+            pltpu.make_async_copy(pool.at[layer, :, pid], buf.at[slot, :, j],
+                                  sem.at[slot, i]).start()
+
+    def some(t, _):
+        for j in range(trip):
+            page(t * trip + j, None)
+
+    whole = n // trip
+    jax.lax.fori_loop(0, whole, some, None)
+    jax.lax.fori_loop(whole * trip, n, page, None)
+
+
+def _await_pages(n, streams, sem, slot):
+    """Awaits ``n`` pages' bytes on each buffer's semaphore of ``slot``,
+    a wait a set bit of ``n``: a full block is one wait a buffer."""
+    ppb = streams[0][1].shape[2]
+    for bit in range(ppb.bit_length()):
+        @pl.when((n >> bit) & 1 == 1)
+        def _():
+            for i, (_, buf) in enumerate(streams):
+                part = buf.at[slot, :, pl.ds(0, 1 << bit)]
+                pltpu.make_async_copy(part, part, sem.at[slot, i]).wait()
+
+
 # tokens a block of the latent decode kernel holds in VMEM, a buffer slot
 _MLA_BLOCK_TOKENS = 1024
 
 
 def _mla_decode_kernel(table_ref, lens_ref, layer_ref, qc_ref, qr_ref,
-                       pool_hbm, o_ref, buf, sem, *, scale, ps, max_pages,
-                       ppb, width_c, width):
+                       pool_hbm, o_ref, buf, sem, turn, *, scale, ps,
+                       width_c, width):
     """One grid step a batch row, as :func:`_decode_kernel`: the row's
     live pages stream ``ppb`` at a time through a double-buffered VMEM
-    scratch, each page ONE copy of [ps, W] that serves as keys and as
-    values.  The row's H heads are the M dimension of both products
-    (q~ @ rows^T, p @ c), so the MXU does the work; operands stay in the
-    pool's dtype, scores, softmax and the accumulator are f32."""
-    b = pl.program_id(0)
-    n = lens_ref[b]
-    layer = layer_ref[0]
-    pages_live = (n + ps - 1) // ps
-    nblk = (pages_live + ppb - 1) // ppb
-
-    def page(c, slot, j):
-        p = c * ppb + j
-        pid = table_ref[b, jnp.minimum(p, max_pages - 1)]
-        return p < pages_live, pltpu.make_async_copy(
-            pool_hbm.at[layer, 0, pid], buf.at[slot, j], sem.at[slot])
-
-    def each_page(c, slot, full, live_do, dead_do=None):
-        """A block's ``ppb`` pages: all of them straight-line where the
-        block is ``full`` of live pages (every block but a row's last:
-        starting and awaiting a copy is scalar work, and 64 guarded loop
-        trips a block cost more than the block's products), guarded
-        page by page where it is not."""
-        @pl.when(full)
-        def _():
-            for j in range(ppb):
-                live_do(page(c, slot, j)[1])
-
-        @pl.when(jnp.logical_not(full))
-        def _():
-            def body(j, _):
-                live, copy = page(c, slot, j)
-
-                @pl.when(live)
-                def _():
-                    live_do(copy)
-
-                if dead_do is not None:
-                    @pl.when(jnp.logical_not(live))
-                    def _():
-                        dead_do(j)
-
-            jax.lax.fori_loop(0, ppb, body, None)
-
-    def start(c, slot):
-        def zero(j):
-            # the rows are values too: 0 * stale VMEM must stay 0
-            buf[slot, j] = jnp.zeros(buf.shape[2:], buf.dtype)
-
-        each_page(c, slot, (c + 1) * ppb <= pages_live,
-                  lambda copy: copy.start(), zero)
-
-    def wait(c, slot):
-        each_page(c, slot, (c + 1) * ppb <= pages_live,
-                  lambda copy: copy.wait())
-
-    @pl.when(nblk > 0)
-    def _():
-        start(0, 0)
-
+    scratch (:func:`_stream_live_blocks`), each page ONE copy of [ps, W]
+    that serves as keys and as values.  The row's H heads are the M
+    dimension of both products (q~ @ rows^T, p @ c), so the MXU does the
+    work; operands stay in the pool's dtype, scores, softmax and the
+    accumulator are f32."""
+    n = lens_ref[pl.program_id(0)]
     qc, qr = qc_ref[0], qr_ref[0]                   # [H, C], [H, Dr]
     heads = qc.shape[0]
+    keys = buf.shape[2] * ps
     dims = (((1,), (1,)), ((), ()))
 
-    def loop(c, carry):
+    def attend(c, slot, carry):
         m, l, acc = carry
-        slot = jax.lax.rem(c, 2)
-
-        @pl.when(c + 1 < nblk)
-        def _():
-            start(c + 1, 1 - slot)
-
-        wait(c, slot)
-        rows = buf[slot].reshape(ppb * ps, buf.shape[3])
+        rows = buf[slot, 0].reshape(keys, buf.shape[4])
         lat, rope = rows[:, :width_c], rows[:, width_c:width]
         s = (jax.lax.dot_general(qc, lat, dims,
                                  preferred_element_type=jnp.float32)
              + jax.lax.dot_general(qr, rope, dims,
                                    preferred_element_type=jnp.float32)
              ) * scale                                      # [H, S]
-        kpos = c * (ppb * ps) + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+        kpos = c * keys + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kpos < n, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
+        # the rows are values too: a masked entry is exactly 0 over a live
+        # page's rows
         pr = jnp.where(kpos < n, jnp.exp(s - m_new), 0.0)
         l = l * alpha + jnp.sum(pr, axis=1, keepdims=True)
         acc = acc * alpha + jax.lax.dot_general(
@@ -1201,7 +1197,9 @@ def _mla_decode_kernel(table_ref, lens_ref, layer_ref, qc_ref, qr_ref,
     init = (jnp.full((heads, 1), NEG_INF, jnp.float32),
             jnp.zeros((heads, 1), jnp.float32),
             jnp.zeros((heads, width_c), jnp.float32))
-    m, l, acc = jax.lax.fori_loop(0, nblk, loop, init)
+    m, l, acc = _stream_live_blocks(
+        table_ref, lens_ref, layer_ref[0], ((pool_hbm, buf),), sem, turn,
+        attend, init, ps=ps)
     l = jnp.where(l == 0.0, 1.0, l)                 # empty rows -> zeros
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
@@ -1226,8 +1224,7 @@ def latent_decode_attention(q_abs, pages, table, seq_lens, scale: float,
     row = lambda b, *_: (b, 0, 0)
     out = pl.pallas_call(
         functools.partial(_mla_decode_kernel, scale=scale, ps=ps,
-                          max_pages=mp, ppb=ppb, width_c=value_width,
-                          width=W),
+                          width_c=value_width, width=W),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,   # table, seq_lens, layer
             grid=(B,),
@@ -1238,11 +1235,14 @@ def latent_decode_attention(q_abs, pages, table, seq_lens, scale: float,
             ],
             out_specs=pl.BlockSpec((1, h8, value_width), row),
             scratch_shapes=[
-                pltpu.VMEM((2, ppb, ps, Wp), pages.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((2, 1, ppb, ps, Wp), pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 1)),
+                pltpu.SMEM((1,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, h8, value_width), q_abs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="dstpu_mla_decode",
     )(table, seq_lens, _layer_operand(layer), q_abs[..., :value_width],

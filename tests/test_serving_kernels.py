@@ -105,6 +105,13 @@ class TestDecodeKernelIdentity:
         # on a page edge, on a block edge, a full table, one past an edge
         "edges": [8, 16, 48, 17, 32],
         "all_empty": [0, 0, 0, 0, 0],
+        # a last block with a dead slot (1, 3 and 5 live pages of 2 a block)
+        "dead_slots": [17, 40, 3, 33, 24],
+        # the hand-over of a row's first block to the row before it: an
+        # empty first and last row, one and two empty rows between live ones
+        "handover": [0, 20, 0, 0, 33, 16, 0],
+        # exactly a block's pages, a page more, two whole blocks, a full table
+        "whole_blocks": [16, 17, 32, 9, 48],
     }
 
     @pytest.mark.parametrize("lens", LENS)
@@ -115,20 +122,28 @@ class TestDecodeKernelIdentity:
     def test_matches_the_gather(self, heads, pool, lens):
         H, KV = heads
         lens = np.asarray(self.LENS[lens], np.int32)
-        B, P = len(lens), len(lens) * self.MP + 1
+        B, P = len(lens), len(lens) * self.MP + 2
         rng = np.random.default_rng(7)
         shape = (self.LAYERS, KV, P, self.PS, self.DH)
-        k = jnp.asarray(rng.normal(size=shape), jnp.float32)
-        v = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        # pages 0 and P - 1 are poison that no table names: where an id
+        # outside the pool lands once the interpreter has clamped it
+        poison = np.isin(np.arange(P), [0, P - 1])[None, None, :, None, None]
+        k = jnp.asarray(np.where(poison, np.nan, rng.normal(size=shape)),
+                        jnp.float32)
+        v = jnp.asarray(np.where(poison, np.nan, rng.normal(size=shape)),
+                        jnp.float32)
         q = jnp.asarray(rng.normal(size=(B, H, self.DH)), jnp.float32)
-        table = (rng.permutation(P - 1)[:B * self.MP] + 1).reshape(
+        table = (rng.permutation(P - 2)[:B * self.MP] + 1).reshape(
             B, self.MP).astype(np.int32)
         # past a row's live pages the table is stale: ids that name no
-        # page of the pool, which the kernel must never dereference (the
-        # oracle gets them clamped: it masks what it gathers)
+        # page of the pool, which the kernel must never dereference, not
+        # for a dead slot of a row's last block either (the oracle gets
+        # real pages there: it masks what it gathers)
         stale = np.arange(self.MP)[None] >= -(-lens[:, None] // self.PS)
         oracle_table = jnp.asarray(table)
-        table = jnp.asarray(np.where(stale, P + 1000, table))
+        table = jnp.asarray(np.where(
+            stale, np.where(np.arange(self.MP)[None] % 2, P + 1000, -7),
+            table))
         lens = jnp.asarray(lens)
 
         if pool == "one_layer":

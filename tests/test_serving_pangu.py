@@ -220,20 +220,47 @@ def test_absorbed_decode_is_the_per_head_attention():
         np.testing.assert_allclose(attn[b, 0], want, atol=2e-5, rtol=2e-5)
 
 
+# rows of ``PAGE`` = 8 tokens over a table of 6 pages, 2 pages a block
+# where the case says so
+LATENT_ROWS = {
+    # empty, mid-page and multi-block rows; a block edge inside the
+    # row's live pages and past them
+    "ragged": [0, 13, 48],
+    # a last block with a dead slot (3, 5 and 1 live pages)
+    "dead_slots": [17, 40, 3],
+    # a row's first block is started by the row before it: an empty
+    # first and last row, one and two empty rows between live ones
+    "handover": [0, 20, 0, 0, 33, 16, 0],
+    # exactly a block's pages, one page more, two whole blocks
+    "whole_blocks": [16, 17, 32],
+}
+
+
 @pytest.mark.parametrize("pages_per_block", [None, 2, 4])
-def test_mla_decode_kernel_is_the_xla_formulation(pages_per_block):
-    """Interpret mode: empty, mid-page and multi-block rows; a block
-    edge inside the row's live pages and past them."""
+@pytest.mark.parametrize("rows", LATENT_ROWS)
+def test_mla_decode_kernel_is_the_xla_formulation(rows, pages_per_block):
+    """Interpret mode, against the gather.  Past a row's live pages the
+    kernel's table holds ids that name no page of the pool (the oracle's
+    holds real pages, which it masks), and the two pages such an id lands
+    on once the interpreter has clamped it are poison: nothing there may
+    be read, not for a dead slot of a row's last block either."""
     rng = np.random.default_rng(3)
-    pool, table, _, _, f = _latent_case(rng)
-    lens = jnp.asarray([0, 13, 48], jnp.int32)
-    q = f(3, 4, 40)
-    want = K.latent_decode_reference(q, pool, table, lens, 0.2, 32, layer=1)
-    got = K.latent_decode_attention(q, pool, table, lens, 0.2, 32, layer=1,
-                                    interpret=True,
-                                    pages_per_block=pages_per_block)
+    lens = np.asarray(LATENT_ROWS[rows], np.int32)
+    pool, table, _, _, f = _latent_case(rng, B=len(lens))
+    poison = jnp.full_like(pool[:, :, :1], np.nan)
+    pool = jnp.concatenate([poison, pool, poison], 2)
+    pages, table = pool.shape[2], np.asarray(table) + 1
+    stale = np.arange(table.shape[1])[None] >= -(-lens[:, None] // PAGE)
+    stale_ids = np.where(np.arange(table.shape[1])[None] % 2, pages + 1000, -7)
+    q = f(len(lens), 4, 40)
+    want = K.latent_decode_reference(q, pool, jnp.asarray(table),
+                                     jnp.asarray(lens), 0.2, 32, layer=1)
+    got = K.latent_decode_attention(
+        q, pool, jnp.asarray(np.where(stale, stale_ids, table)),
+        jnp.asarray(lens), 0.2, 32, layer=1, interpret=True,
+        pages_per_block=pages_per_block)
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
-    assert not np.asarray(got[0]).any()            # an empty row: zeros
+    assert not np.asarray(got)[lens == 0].any()     # an empty row: zeros
 
 
 @pytest.mark.parametrize("start", [(0, 0), (0, 200)],
