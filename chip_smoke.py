@@ -176,6 +176,56 @@ def kernel_check(size, seed):
          rel_err_of_max=worst, tolerance=tol)
 
 
+def state_chunk_check(seed, on_tpu):
+    """A prompt chunk's delta rule in ``dstpu_state_chunk`` against
+    ``gdn_chunk_rule`` at ``HIGHEST`` on the same operands, at the
+    recurrent cell's shape (32 value heads over 16 key heads of 128, a
+    chunk of 1,024, a random state, the last eighth of the rows masked;
+    a toy shape in interpret mode off the chip): o and S within 1e-4 of
+    the rule's by norm, and a chunk with no real row leaves S bit for
+    bit."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.inference.kernels import state_chunk
+    from deepspeed_tpu.models import qwen3_next as qn
+    from deepspeed_tpu.models.family import SlotState
+
+    T, Hk, Hv, D, block = (1024, 16, 32, 128, 64) if on_tpu \
+        else (32, 2, 4, 16, 8)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    l2 = lambda t: t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True) + 1e-6)
+    q = l2(jax.random.normal(ks[0], (1, T, Hk, D))) * D ** -0.5
+    k = l2(jax.random.normal(ks[1], (1, T, Hk, D)))
+    v = jax.random.normal(ks[2], (1, T, Hv, D))
+    real = (jnp.arange(T) < T - T // 8)[None, :, None]
+    g = jnp.where(real, -jnp.exp(jax.random.normal(ks[3], (1, T, Hv)) - 2.0),
+                  0.0)
+    beta = jnp.where(real, jax.nn.sigmoid(
+        jax.random.normal(ks[4], (1, T, Hv))), 0.0)
+    S = jax.random.normal(ks[5], (1, Hv, D, D))
+    wide = lambda t: jnp.repeat(t, Hv // Hk, axis=2)
+    rule = jax.jit(lambda g, beta: qn.gdn_chunk_rule(
+        wide(q), wide(k), v, g, beta, S, block))
+    chunk = functools.partial(state_chunk, interpret=not on_tpu)
+    kernel = jax.jit(lambda g, beta: qn.gdn_chunk_kernel(
+        q, k, v, g, beta, SlotState(S, chunk), block))
+    rel = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+    err = dict(zip(("o", "S"), map(rel, kernel(g, beta), rule(g, beta))))
+    tol = 1e-4
+    for name, e in err.items():
+        if not e <= tol:
+            raise AssertionError(f"state chunk {name}: {e:.3g} > {tol}")
+    _, kept = kernel(jnp.zeros_like(g), jnp.zeros_like(beta))
+    if not np.array_equal(np.asarray(kept), np.asarray(S)):
+        raise AssertionError("a chunk with no real row moved the state")
+    emit(phase="state_chunk_check", shape=[T, Hk, Hv, D], block=block,
+         rel_err_by_norm=err, tolerance=tol, masked_chunk="bit for bit")
+
+
 # ------------------------------------------------------------------ train
 def build_trainer(size, seed, stage, chips):
     """(engine, batch, cfg): the train phase's model through
@@ -516,6 +566,7 @@ def main():
         on_tpu = device["platform"] == "tpu"
         if on_tpu:
             kernel_check(size, args.seed)
+        state_chunk_check(args.seed, on_tpu)
         train_phase(size, args.seed, cache)
         gc.collect()                  # the trainer's HBM, before the server
         serve_phase(size, args.seed, cache, on_tpu)

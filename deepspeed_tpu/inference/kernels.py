@@ -19,11 +19,11 @@ gather and is the numerics oracle for tests/CPU.
 
 The Mosaic kernels here, by the names a capture shows: `dstpu_paged_decode`
 (live pages only, one decode step), `dstpu_paged_chunk_v2` (a chunk over
-history, in blocks), `dstpu_mla_decode` (latent rows) and
-`dstpu_state_step`: one token of a recurrent layer's rule on the per-slot
-state carried beside the pool, a tile at a time, in place
-(:func:`state_step`).  Which of them a program runs is
-:func:`paged_reader`'s answer, a rule of the build.
+history, in blocks), `dstpu_mla_decode` (latent rows), `dstpu_state_step`
+(one token of a recurrent layer's rule on the per-slot state carried
+beside the pool, a tile at a time, in place: :func:`state_step`) and
+`dstpu_state_chunk` (a prompt chunk of it: :func:`state_chunk`).  Which a
+program runs is a rule of the build (:func:`paged_reader` and its kin).
 """
 
 from __future__ import annotations
@@ -1371,8 +1371,7 @@ def paged_reader(*, decode: bool, tp: bool, interpret: bool, quant: bool,
 class ServingKernelPolicy(NamedTuple):
     """The readers an engine's build resolved: a report, not a request.
     Each row is a rule's answer from the family, the mesh, the pages and
-    the shapes, baked into the compiled programs and printed by
-    ``/statusz`` under ``kernels``."""
+    the shapes, baked into the programs and printed by ``/statusz``."""
 
     # (reader, reason) of the decode program's attention (:func:`paged_reader`)
     decode: Tuple[str, str] = ("xla", "")
@@ -1380,18 +1379,19 @@ class ServingKernelPolicy(NamedTuple):
     chunk: Tuple[str, str] = ("xla", "no chunk program")
     # (reader, reason) of a window layer's chunk (ops.attention.window_reader)
     window: Tuple[str, str] = ("xla", "no window layer")
-    # pallas | xla: how the decode program steps a recurrent layer's
-    # per-slot state (:func:`state_stepper`)
+    # pallas | xla: a decode step of the per-slot state (:func:`state_stepper`)
     state_step: str = "xla"
-    # (what, fell back to, reason) where a mesh took a kernel the family
-    # would otherwise run
+    # (what, fell back to, reason) where a mesh took a kernel of the family's
     fallbacks: Tuple[Tuple[str, str, str], ...] = ()
+    # (pallas | xla, reason): a prompt chunk's state (:func:`state_chunker`)
+    state_chunk: Tuple[str, str] = ("xla", "")
 
     def as_dict(self) -> dict:
         pair = lambda k: dict(zip(("reader", "reason"), getattr(self, k)))
         return {
             "decode": pair("decode"), "chunk": pair("chunk"),
             "window": pair("window"), "state_step": self.state_step,
+            "state_chunk": pair("state_chunk"),
             "fallbacks": [{"field": f, "demoted_to": d, "reason": r}
                           for f, d, r in self.fallbacks],
         }
@@ -1399,32 +1399,32 @@ class ServingKernelPolicy(NamedTuple):
 
 def resolve_serving_kernels(*, tp: bool = False, interpret: bool = False,
                             quantized_resident: bool = False,
-                            recurrent: bool = False,
-                            chunk=(0, 0)) -> ServingKernelPolicy:
+                            recurrent: bool = False, chunk=(0, 0),
+                            state_block=None):
     """The readers of an engine's programs, resolved ONCE, at its build
     (``serving_engine``), from what the build can observe: so what the
-    programs compiled with is what ``/statusz`` reports.  Nothing is
-    read from a config or the environment.
-
-    ``tp``: a model or expert axis shards the cache; ``interpret``: no
-    TPU backend; ``quantized_resident``: the pages are int8 codes
-    (``kv_tier.quantized_resident``), which every reader gathers and
-    dequantizes.  ``recurrent``: the family has recurrent layers, whose
-    per-slot state a decode program steps in place through
-    ``dstpu_state_step`` on one device (:func:`state_stepper`); where
-    ``tp`` takes that, ``state_step`` reads ``xla`` beside a
-    ``fallbacks`` row.  ``chunk``: the (tokens, head width) of the
-    engine's chunk programs, whose reader the ``chunk`` row names
-    (``window`` is the family's: ``serving_engine`` asks its
-    ``Recurrent.chunk_reader``)."""
+    programs compiled with is what ``/statusz`` reports; nothing is read
+    from a config or the environment.  ``tp``: a model or expert axis
+    shards the cache; ``interpret``: no TPU backend; ``quantized_resident``:
+    the pages are int8 codes, gathered.  ``recurrent``: it has recurrent
+    layers, whose state a decode program steps in place through
+    ``dstpu_state_step`` on one device (:func:`state_stepper`);
+    ``state_block``: the family's ``(Recurrent, cfg)``, whose block and
+    state's shape :func:`state_chunker` asks for a prompt chunk's
+    ``dstpu_state_chunk``; where ``tp`` takes either kernel, a
+    ``fallbacks`` row says so.  ``chunk``: the (tokens, head width) of
+    the chunk programs' reader (``window`` is the family's)."""
     fallbacks = []
     stepper, why = state_stepper(decode=recurrent, tp=tp)
     if recurrent and stepper != "pallas":
         fallbacks.append(("state_step=pallas", stepper, why))
+    chunker = state_chunker(state_block, tp=tp, interpret=interpret)
+    if tp and chunker[1].startswith("tp"):
+        fallbacks.append(("state_chunk=pallas",) + chunker)
     reader = functools.partial(paged_reader, tp=tp, interpret=interpret,
                                quant=quantized_resident)
     return ServingKernelPolicy(
-        decode=reader(decode=True), state_step=stepper,
+        decode=reader(decode=True), state_step=stepper, state_chunk=chunker,
         chunk=reader(decode=False, tokens=chunk[0], head_dim=chunk[1]),
         fallbacks=tuple(fallbacks))
 
@@ -1566,9 +1566,9 @@ def state_stepper(*, decode: bool, tp: bool) -> Tuple[str, str]:
     ``/statusz`` share.  A decode step over every slot (``decode``: one
     token a row, the rows the slots) on one device steps the carried
     state where it lies (:func:`state_step`, in interpret mode off the
-    TPU).  A prompt chunk's one-slot view runs the family's chunked rule
-    on the slot's rows; under a mesh (``tp``) the kernel, which is one
-    device's, cannot be partitioned."""
+    TPU).  A prompt chunk's one-slot view is :func:`state_chunker`'s to
+    answer for; under a mesh (``tp``) the kernel, which is one device's,
+    cannot be partitioned."""
     for off, why in ((not decode, "no decode step over every slot's state"),
                      (tp, "tp: the kernel is one device's")):
         if off:
@@ -1762,3 +1762,162 @@ def paged_period_loop(period, x, stacks, cache: PagedKVCache, periods: int):
     return x, cache._replace(k=k, v=v, expert_rows=rows, conv=conv,
                              state=state)
 
+
+
+# ------------------------------------------- the per-slot state's chunk
+# What a grid step of the chunk kernel may hold of its operands' tiles
+# (the pipeline keeps two of each), and how many heads it carries side by
+# side: their chains of products are independent, and a product costs the
+# matrix unit its latency, so the more heads stand in one block's body the
+# less the unit waits (blocks of 64, ms a layer of the recurrent cell's
+# shape: 0.68 at 2 heads, 0.53 at 4, 0.47 at 8, 0.48 at 16; v5e, PR 50).
+_CHUNK_SPAN_BYTES = 10 << 20
+_CHUNK_HEADS = 8
+
+
+def state_chunker(stated, *, tp: bool, interpret: bool) -> Tuple[str, str]:
+    """How a prompt chunk carries a recurrent layer's state through its
+    tokens, and why: ("pallas" | "xla", reason), the one answer
+    ``forward_paged`` and the engine's ``/statusz`` share; a rule of the
+    build, as :func:`state_stepper` is for a decode step.  ``stated``:
+    the family's ``(Recurrent, cfg)`` or None.  Where the family states
+    one block of its chunked rule on one head (``Recurrent.block``), on
+    one device on the TPU, a head's state of whole 128-lane tiles stays
+    in VMEM from block to block (:func:`state_chunk`); elsewhere ``mix``
+    runs the family's chunked rule in XLA on the slot's rows."""
+    shape = stated and stated[0].block and stated[0].state_row(stated[1]).state
+    for off, why in ((not shape, "the family states no block of its rule"),
+                     (tp, "tp: the kernel is one device's"),
+                     (interpret, "interpret: no TPU backend"),
+                     (any(w % 128 for w in (shape or ())[-2:]),
+                      "a head's state is not whole 128-lane tiles")):
+        if off:
+            return "xla", why
+    return "pallas", "a chunk's blocks on one device, the state in VMEM"
+
+
+def _state_chunk_kernel(s_ref, *refs, rule, takes, block: int, heads: int):
+    """``heads`` heads' states [heads, R, C] through the ``span`` tokens
+    of this grid step, a block at a time, all the heads at once: ``acc``
+    holds them in f32 from a head group's first step (read from
+    ``s_ref``) to its last (written to ``out_ref``).  The rule gets
+    operand m's heads of the step's tile stacked (``takes[m]``: a head's
+    width, how many of them), the heads' ``col`` [heads, block, n] and
+    ``lane`` [heads, n, block], and gives their ``o`` [heads, block, C]."""
+    *m_refs, col_ref, lane_ref, o_ref, out_ref, acc = refs
+    step, span, width = pl.program_id(2), o_ref.shape[0], acc.shape[-1]
+    nc, nl = col_ref.shape[-1] // heads, lane_ref.shape[-2] // heads
+
+    @pl.when(step == 0)
+    def _():
+        acc[...] = s_ref[...].astype(jnp.float32)
+
+    def one(j, S):
+        at = pl.multiple_of(j * block, block)
+        rows = pl.ds(at, block)
+        o, S = rule(
+            S, *(jnp.stack([ref[rows, h * w:(h + 1) * w] for h in range(n)])
+                 for ref, (w, n) in zip(m_refs, takes)),
+            jnp.stack([col_ref[rows, h * nc:(h + 1) * nc]
+                       for h in range(heads)]),
+            jnp.stack([lane_ref[j, h * nl:(h + 1) * nl, :]
+                       for h in range(heads)]))
+        for h in range(heads):
+            o_ref[rows, h * width:(h + 1) * width] = o[h]
+        return S
+
+    S = acc[...]
+    acc[...] = one(0, S) if span == block else jax.lax.fori_loop(
+        0, span // block, one, S)
+
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _():
+        out_ref[...] = acc[...].astype(out_ref.dtype)
+
+
+def _chunk_heads(H: int, reps) -> int:
+    """How many of the state's heads a grid step carries: the most
+    within ``_CHUNK_HEADS`` that divide H and hold, or divide, what one
+    head of each operand serves."""
+    return max(h for h in range(1, min(H, _CHUNK_HEADS) + 1)
+               if H % h == 0 and not any(h % r and r % h for r in reps))
+
+
+def state_chunk(rule, S, mats, cols, lanes, *, block: int,
+                interpret: bool = False, heads: Optional[int] = None,
+                span: Optional[int] = None):
+    """A prompt chunk of a recurrence on the rows' state ``S`` [B, H, R,
+    C] (``STATE_DTYPE``; f32 inside), in place: the Mosaic kernel
+    ``dstpu_state_chunk`` holds a few heads' states in VMEM while the
+    chunk's blocks of ``block`` tokens pass, so the state leaves the
+    memory once and comes back once, and nothing a block makes on its way
+    (its ``[block, block]`` matrices) is ever in the memory at all.
+
+    ``rule(S [h, R, C], *tiles, col, lane) -> (o [h, block, C], S)`` is
+    the family's own statement of one block on the h heads of a grid
+    step, side by side, in the arithmetic a kernel body may use; it
+    chooses its products' precision.  ``mats``: its operands [B, T, heads,
+    w] f32 as the program has them, row-major (a tile [block, w] a head
+    is picked where it lies and the step's stacked: nothing is
+    transposed, and an operand of fewer heads than H is not repeated: the
+    rule gets the ``h // (H // heads)`` of them that serve the step's).
+    ``cols``, ``lanes`` [B, T, H, n] f32: what the rule needs a token and
+    head, down a block (``col`` [h, block, n]) and across it (``lane``
+    [h, n, block]): kilobytes, turned out here so that the kernel turns
+    nothing.  T is whole blocks (a caller pads with tokens that move
+    nothing).  Returns (o [B, T, H, C] f32, S).  The heads a grid step
+    carries and the tokens it spans are read from the shapes; ``heads``
+    and ``span`` are a measurement's and a test's."""
+    B, H, R, C = S.shape
+    T, f32 = mats[0].shape[1], jnp.float32
+    if T % block:
+        raise ValueError(f"{T} tokens are not whole blocks of {block}")
+    reps = tuple(H // m.shape[2] for m in mats)
+    hb = heads or _chunk_heads(H, reps)
+    if any(hb % rep and rep % hb for rep in reps) or H % hb:
+        raise ValueError(f"{hb} heads a step over operands serving {reps}")
+    # an operand's head's width and how many of its heads a step holds
+    takes = tuple((m.shape[3], max(1, hb // rep)) for m, rep in zip(mats, reps))
+    held = tuple(w * n for w, n in takes)                    # a step's lanes
+    nc, nl = cols.shape[-1], lanes.shape[-1]
+    tiles = lambda n, tile: -(-n // tile) * tile
+    # f32, two buffers each: the operands' and o's lanes, a token's cols in
+    # whole 128-lane tiles, a block's lanes in whole (8, 128) tiles
+    per_token = 4 * 2 * (sum(held) + hb * C + tiles(hb * nc, 128)
+                         + tiles(hb * nl, 8) * tiles(block, 128) // block)
+    if span is None:
+        span = block
+        while T % (2 * span) == 0 and 2 * span * per_token <= _CHUNK_SPAN_BYTES:
+            span *= 2
+    N, G = T // block, H // hb
+    flat = [m.reshape(B, T, -1).astype(f32) for m in mats]
+    # down a block: [B, G, T, hb n]; across it: [B, G, N, hb n, block]
+    cols = cols.astype(f32).reshape(B, T, G, hb * nc).transpose(0, 2, 1, 3)
+    lanes = lanes.astype(f32).reshape(B, N, block, G, hb * nl).transpose(
+        0, 3, 1, 4, 2)
+    state = pl.BlockSpec((None, hb, R, C), lambda b, g, n: (b, g, 0, 0))
+    o, S = pl.pallas_call(
+        functools.partial(_state_chunk_kernel, rule=rule, takes=takes,
+                          block=block, heads=hb),
+        grid=(B, G, T // span),
+        in_specs=[state] + [
+            pl.BlockSpec((None, span, w), functools.partial(
+                lambda b, g, n, per: (b, n, g * hb // per), per=max(hb, rep)))
+            for w, rep in zip(held, reps)] + [
+            pl.BlockSpec((None, None, span, hb * nc),
+                         lambda b, g, n: (b, g, n, 0)),
+            pl.BlockSpec((None, None, span // block, hb * nl, block),
+                         lambda b, g, n: (b, g, n, 0, 0))],
+        out_specs=[pl.BlockSpec((None, span, hb * C),
+                                lambda b, g, n: (b, n, g)), state],
+        out_shape=[jax.ShapeDtypeStruct((B, T, H * C), f32),
+                   jax.ShapeDtypeStruct(S.shape, S.dtype)],
+        scratch_shapes=[pltpu.VMEM((hb, R, C), f32)],
+        input_output_aliases={0: 1},        # the rows come back in place
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=max(32 << 20, span * per_token + (24 << 20))),
+        interpret=interpret,
+        name="dstpu_state_chunk",
+    )(S, *flat, cols, lanes)
+    return o.reshape(B, T, H, C), S

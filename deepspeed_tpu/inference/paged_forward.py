@@ -19,15 +19,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.kernels import (latent_attention_step,
-                                             paged_attention_step,
-                                             paged_layer_loop,
-                                             paged_period_loop, paged_reader,
-                                             state_rows, state_step,
-                                             state_stepper, write_state_rows)
+from deepspeed_tpu.inference.kernels import (
+    latent_attention_step, paged_attention_step, paged_layer_loop,
+    paged_period_loop, paged_reader, state_chunk, state_chunker, state_rows,
+    state_step, state_stepper, write_state_rows)
 from deepspeed_tpu.inference.quantized import dequantize_params
 from deepspeed_tpu.models.family import (CarriedRows, CarriedState,
-                                         decoder_family, sections_of)
+                                         SlotState, decoder_family,
+                                         sections_of)
+# (the per-slot seam's three hand-overs: rows, a layer's state, a chunk's)
 from deepspeed_tpu.parallel.moe import extra_pair_passes
 
 
@@ -77,24 +77,24 @@ def _count_routed(fam, cfg, rows, routed, N: int):
 def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
                      whole: bool, tp: bool, interpret: bool):
     """The layers of a family some of whose layers keep a bounded state
-    a slot (``fam.recurrent``): first the family's leading stack, if it
-    has one (``lead``: its block, over the pool's first layers), then
-    its sections in order (``family.sections_of``: a period and a
-    count each, the three kinds' layer indices running on from one to
-    the next over the same pool and the same state buffers), a
-    section's periods in a loop, a period's kinds in the order stated:
-    a pool layer is ``block`` (:func:`_paged_block` over the pool, whose
-    leading dimension counts the lead's and the sections' pool layers
-    alone); an FFN alone (``Recurrent.ffn``) touches neither cache; a
-    per-slot layer reads its rows' state beside the pool, mixes, and
-    writes it back, but in a decode step over every slot on one device
-    (:func:`~deepspeed_tpu.inference.kernels.state_stepper`), where
-    ``mix`` steps its layer of the carried state where it lies
-    (``family.CarriedState``) and only the convolution's rows go out and
-    back, or not even they (``Recurrent.rows_in_place``).
+    a slot (``fam.recurrent``): first its leading stack, if it has one
+    (``lead``: its block, over the pool's first layers), then its
+    sections in order (``family.sections_of``: a period and a count
+    each, the three kinds' layer indices running on over the same pool
+    and the same state buffers), a section's periods in a loop, a
+    period's kinds in the order stated: a pool layer is ``block``
+    (:func:`_paged_block`); an FFN alone (``Recurrent.ffn``) touches
+    neither cache; a per-slot layer reads its rows' state beside the
+    pool, mixes, and writes it back, but in a decode step over every
+    slot on one device (``kernels.state_stepper``), where ``mix`` steps
+    its layer of the carried state where it lies (``CarriedState``) and
+    only the convolution's rows go out and back, or not even they
+    (``Recurrent.rows_in_place``); a prompt chunk's rows it is handed
+    with the chunk's kernel where the build runs one (``SlotState``).
     ``cache.real``: how many tokens of each row may move a state; a row
     that starts at position 0 starts from zero state, whatever its slot
-    held."""
+    held (a first chunk's rows are zeroed before ``mix`` or the kernel
+    sees them)."""
     rec = fam.recurrent
     B, T = x.shape[:2]
     start, slot = cache.seq_lens, cache.slot
@@ -111,17 +111,18 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
         decode=every_slot, tp=tp)[0] == "pallas"
     rows_in_place = rec.rows_in_place and every_slot
     step = functools.partial(state_step, interpret=interpret)
+    chunk = functools.partial(state_chunk, interpret=interpret)
+    # a prompt chunk's state on the chip, where the build runs it there
+    on_chip = T > 1 and cache.state is not None and state_chunker(
+        (rec, cfg), tp=tp, interpret=interpret)[0] == "pallas"
 
     def split(stack):
         held = {k: stack[k] for k in fam.whole_stacks
                 if k in stack} if whole else {}
         return held, {k: v for k, v in stack.items() if k not in held}
 
-    # a kind's stack stays whole: its layers are taken out of it by
-    # their index (sliced a period at a time by the outer loop, a
-    # period's weights were copied once more: 150 MB of one projection a
-    # period, v5e, PR 35), but the pool layers' where one section takes
-    # them all: the loop over its periods then scans them
+    # a kind's stack stays whole, its layers taken out by index (a period's
+    # slice copied 150 MB a projection, v5e, PR 35), but one section's pool
     stacks = {True: split(params[rec.key]), False: split(params["blocks"]),
               None: split(params[rec.ffn[0]]) if rec.ffn else None}
 
@@ -132,8 +133,7 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
         return dict(lp, **held, layer=layer) if held else lp
 
     def counted(x, rows):
-        """``(x, rows)`` of a layer whose FFN counts its experts' rows
-        (a dense one, or none: ``x`` alone, nothing to count)."""
+        """``(x, rows)``, the layer's FFN counting its experts' rows or not."""
         if isinstance(x, tuple):
             return x[0], _count_routed(fam, cfg, rows, x[1], B * T)
         return x, rows
@@ -141,8 +141,7 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
     def recurrent_layer(carry, layer):
         x, rows, conv, state = carry
         lp = layer_of(True, layer)
-        # what goes out of the carried buffers and back: the rows and
-        # the state, but what a decode step updates where it lies
+        # out of the carried buffers and back: all a decode step leaves
         rows_out = () if rows_in_place else (conv,)
         state_out = () if in_place or state is None else (state,)
         out = rows_out + state_out
@@ -154,6 +153,7 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
                 for a in held)
         held = (CarriedRows(conv, layer) if rows_in_place else held[0],
                 CarriedState(state, layer, step) if in_place
+                else SlotState(held[-1], chunk) if on_chip
                 else held[-1] if state_out else None)
         y, held = rec.mix(cfg, x, lp, held, real, start, ctx)
         with jax.named_scope("kv_write"), jax.named_scope(rec.write_scope):

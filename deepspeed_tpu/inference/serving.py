@@ -3781,9 +3781,9 @@ def serving_engine(params, cfg, **kw):
         quantized_resident=kvt.enabled and kvt.quantized_resident,
         recurrent=fam.recurrent is not None
         and fam.recurrent.state_row(cfg).state is not None, chunk=(
-            # a hit in the prefix cache is absorbed a bucket at a time
             kw.get("prefill_chunk") or kw.get("prefill_bucket", 32),
-            fam.cache_row(cfg).head_width or fam.cache_row(cfg).key_width))
+            fam.cache_row(cfg).head_width or fam.cache_row(cfg).key_width),
+        state_block=fam.recurrent and (fam.recurrent, cfg))
     if fam.latent is not None:
         kw["kernels"] = kw["kernels"]._replace(
             decode=latent_reader(kw["kernels"].decode))

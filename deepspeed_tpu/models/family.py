@@ -98,7 +98,6 @@ class Recurrent:
     ``state_row``: what a slot keeps.  ``write_scope``: the scope word,
     inside ``kv_write``, of the write-back of a layer's rows.
     ``chunk_reader(cfg, tokens, interpret) -> (reader, reason)``.
-
     In a decode step over every slot on one device ``mix`` is handed as
     ``state[1]`` not the rows' state but a :class:`CarriedState`: the
     whole buffer of every such layer and which layer this is.  A family
@@ -106,7 +105,8 @@ class Recurrent:
     hands back what that returned; it never reads one as an array.
     ``rows_in_place``: there ``state[0]`` is a :class:`CarriedRows` too:
     the mixer writes its layer of the buffer where it lies and hands it
-    back (right for 3 rows of a convolution; three passes over 512)."""
+    back (right for 3 rows of a convolution).  ``block``: the family's
+    rule of a chunk's block of tokens, a few heads (:func:`chunk_state`)."""
 
     key: str
     period: Callable[[Any], tuple]
@@ -118,7 +118,7 @@ class Recurrent:
     chunk_reader: Optional[Callable[..., Tuple[str, str]]] = None
     sections: Optional[Callable[[Any], tuple]] = None
     ffn: Optional[Tuple[str, Callable[..., Any]]] = None
-
+    block: Optional[Callable[..., Tuple[Any, Any]]] = None
 
 class CarriedRows(NamedTuple):
     """A layer's per-slot rows where they live: ``buffer`` [layers,
@@ -155,6 +155,30 @@ def step_state(rule, S, *vectors):
         o, buffer = S.step(rule, S.buffer, S.layer, vectors)
         return o, S._replace(buffer=buffer)
     return rule(S.astype(jnp.float32), *vectors)
+
+
+class SlotState(NamedTuple):
+    """The rows' state of a recurrent layer at a prompt chunk, where the
+    build runs the chunk on the chip: ``rows`` [B, H, R, C] and
+    ``chunk(rule, rows, mats, cols, lanes, block=) -> (o, rows)``, which
+    carries every head's state through the chunk's blocks under the
+    family's block rule (:func:`deepspeed_tpu.inference.kernels.
+    state_chunk`)."""
+
+    rows: Any
+    chunk: Callable[..., Tuple[Any, Any]]
+
+
+def chunk_state(rule, S, mats, cols, lanes, block: int):
+    """A prompt chunk of a recurrence on a :class:`SlotState`: ``rule(S
+    [h, R, C], *tiles, col, lane) -> (o [h, block, C], S)`` is the
+    family's statement of one block of tokens on h heads side by side,
+    ``mats`` its operands [B, T, heads, width] (a head of fewer serves as
+    many of ``S``'s as it takes), ``cols`` and ``lanes`` [B, T, H, n] what
+    it needs a token and head, handed to it down a block (``col`` [h,
+    block, n]) and across (``lane`` [h, n, block]).  Returns (o [B, T, H,
+    C], the rows' state as an array)."""
+    return S.chunk(rule, S.rows, mats, cols, lanes, block=block)
 
 
 def _per_head_rows(cfg) -> CacheRow:

@@ -19,23 +19,22 @@ Gated DeltaNet on ``a``: ``[q, k, v, z] = a W_qkvz``, ``[b, a'] = a
 W_ba``; ``[q, k, v]`` pass a depthwise causal convolution of
 ``conv_kernel`` taps and SiLU; ``beta = sigmoid(b)``, ``g = -exp(A_log)
 softplus(a' + dt_bias)``; q and k are L2-normalised a head, q scaled by
-``Dk^-1/2``, and each q/k head serves ``Hv / Hk`` value heads.  A value
-head keeps ``S`` [Dk, Dv] in float32, from zero::
+``Dk^-1/2``; a q/k head serves ``Hv / Hk`` value heads, each keeping ``S``
+[Dk, Dv] in float32, from zero::
 
     S' = e^g S;  u = beta (v - S'^T k);  S = S' + k u^T;  o = S^T q
 
 then ``y = (RMS_head(o) w) * SiLU(z)`` and ``W_out``.  A decode step is
 that recurrence (:func:`gdn_step`); a prompt chunk computes the same in
-blocks of ``gdn_block`` tokens (:func:`gdn_chunk_rule`, the WY/UT
-form), the state carried from block to block and from chunk to chunk.
-What a slot keeps a layer is the last ``conv_kernel - 1`` inputs of the
-convolution and ``S``: :class:`~deepspeed_tpu.models.family.StateRow`.
+blocks of ``gdn_block`` tokens (the WY/UT form: :func:`gdn_chunk_rule` in
+XLA, :func:`gdn_block_rule` a block on the chip), the state carried on.
+A slot keeps a layer's last ``conv_kernel - 1`` inputs of the convolution and
+``S``: :class:`~deepspeed_tpu.models.family.StateRow`.
 
 MoE: ``p = softmax(m W_r)`` over all the experts in f32, the ``top_k``
 largest divided by their sum, ``y = sum p_e E_e(m) + sigmoid(m w_s)
 E_shared(m)``; a rank holds ``experts_held`` of them, as
 :mod:`~deepspeed_tpu.models.pangu_ultra_moe` does.
-
 Serving only.  The next-token-prediction module is not instantiated.
 """
 
@@ -52,7 +51,8 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models import llama as _llama
-from deepspeed_tpu.models.family import (DecoderFamily, Recurrent, StateRow,
+from deepspeed_tpu.models.family import (DecoderFamily, Recurrent, SlotState,
+                                         StateRow, chunk_state,
                                          positions_from, step_state)
 from deepspeed_tpu.parallel.moe import held_experts_ffn, softmax_topk_route
 
@@ -331,9 +331,8 @@ def gdn_mix(cfg, x, lp, state, valid, start=None, ctx=()):
     S)).  ``valid`` [B]: tokens at or past it move neither S (their beta
     and g are 0) nor the convolution's rows, which are the
     ``conv_kernel - 1`` inputs that end at the last real token.  ``start``
-    and ``ctx`` (the seam hands every mixer where its rows stand and what
-    ``embed`` made of the positions) are not used: the mixer has no
-    positions."""
+    and ``ctx`` (where the rows stand, what ``embed`` made of the positions)
+    are not used: the mixer has none.  A chunk's ``SlotState`` runs on chip."""
     B, T, _ = x.shape
     Hk, Hv, Dk, Dv = (cfg.lin_k_heads, cfg.lin_v_heads, cfg.lin_k_dim,
                       cfg.lin_v_dim)
@@ -363,7 +362,8 @@ def gdn_mix(cfg, x, lp, state, valid, start=None, ctx=()):
         q = _l2norm(y[..., :Kd].reshape(B, T, Hk, Dk)) * Dk ** -0.5
         k = _l2norm(y[..., Kd:2 * Kd].reshape(B, T, Hk, Dk))
         v = y[..., 2 * Kd:].reshape(B, T, Hv, Dv)
-        q, k = (jnp.repeat(t, Hv // Hk, axis=2) for t in (q, k))
+        if not isinstance(S, SlotState):    # the kernel reads a key head
+            q, k = (jnp.repeat(t, Hv // Hk, axis=2) for t in (q, k))
     if T == 1:
         with jax.named_scope("kv_attend"), jax.named_scope("gdn_step"):
             o, S = gdn_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
@@ -371,14 +371,121 @@ def gdn_mix(cfg, x, lp, state, valid, start=None, ctx=()):
             o = o[:, None]
     else:
         with jax.named_scope("kv_attend"), jax.named_scope("gdn_scan"):
-            # f32 whatever the state is kept in
-            o, S = gdn_chunk_rule(q, k, v, g, beta, S.astype(f32),
-                                  cfg.gdn_block)
+            if isinstance(S, SlotState):
+                o, S = gdn_chunk_kernel(q, k, v, g, beta, S, cfg.gdn_block)
+            else:       # f32 whatever the state is kept in
+                o, S = gdn_chunk_rule(q, k, v, g, beta, S.astype(f32),
+                                      cfg.gdn_block)
     with jax.named_scope("attn_out"), jax.named_scope("gdn_gate_norm"):
         o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                               + cfg.norm_eps) * lp["gdn_norm"].astype(f32)
         o = o.reshape(B, T, -1) * jax.nn.silu(z.astype(f32))
         return o.astype(x.dtype) @ lp["w_out"], (conv, S)
+
+
+def _pair(a):
+    """``a`` f32 as two bf16 numbers an entry, ``hi + lo``: 16 bits of
+    its mantissa."""
+    hi = a.astype(jnp.bfloat16)
+    return hi, (a - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+# [h, m, k] x [h, k, n], x [h, n, k] and [h, k, m] x [h, k, n], a head each
+_NN, _NT, _TN = ((((2,), (1,)), ((0,), (0,))), (((2,), (2,)), ((0,), (0,))),
+                 (((1,), (1,)), ((0,), (0,))))
+
+
+def _mm3(a, b, dims=_NN):
+    """The product of two :func:`_pair` operands, a head each, in three
+    bf16 passes (``hi hi + hi lo + lo hi``) accumulated in f32: an error
+    of ~2^-16 a product, where a bf16 operand's is 2^-8 (PERF.md 6, PR
+    50)."""
+    dot = lambda x, y: jax.lax.dot_general(
+        x, y, dims, preferred_element_type=jnp.float32)
+    return dot(a[0], b[0]) + (dot(a[0], b[1]) + dot(a[1], b[0]))
+
+
+def _unit_lower_inverse(M, i, j, leaf: int = 16):
+    """``(I + M)^-1`` of strictly lower-triangular M [h, C, C] (``i``,
+    ``j`` [1, C, C] the row and column numbers), by blocks and exact: the
+    ``leaf``-wide diagonal blocks D first, all at once (``D^leaf = 0``:
+    the product of ``I + (-D)^(2^n)``), then ``[[A, 0], [B, D]]^-1 =
+    [[A^-1, 0], [-D^-1 B A^-1, D^-1]]`` a doubling of the block, each
+    level two products of masked [C, C] matrices: a product costs the
+    chip's matrix unit its latency, whatever its operands' size, and
+    nothing is sliced or put together."""
+    C = M.shape[-1]
+    leaf = min(leaf, C)
+    if C % leaf or (C // leaf) & (C // leaf - 1) or leaf & (leaf - 1):
+        raise ValueError(f"a block of {C} tokens is not a power of two")
+    X = jnp.where(i // leaf == j // leaf, -M, 0.0)
+    inv = jnp.where(i == j, 1.0, 0.0) + X
+    for _ in range(leaf.bit_length() - 2):
+        Xp = _pair(X)
+        X = _mm3(Xp, Xp)
+        inv = inv + _mm3(_pair(inv), _pair(X))
+    while leaf < C:
+        below = (i // (2 * leaf) == j // (2 * leaf)) & (i // leaf != j // leaf)
+        ip = _pair(inv)
+        inv = inv - _mm3(_pair(_mm3(ip, _pair(jnp.where(below, M, 0.0)))), ip)
+        leaf *= 2
+    return inv
+
+
+def gdn_block_rule(S, q, k, v, col, lane):
+    """One block of C tokens of :func:`gdn_chunk_rule` on h value heads
+    at once (the family's ``Recurrent.block``, what ``dstpu_state_chunk``
+    runs a block: :func:`~deepspeed_tpu.inference.kernels.state_chunk`):
+    S [h, Dk, Dv], q, k [h or fewer, C, Dk] (a key head serves as many
+    value heads as it takes), v [h, C, Dv], ``col`` [h, C, 2] the running
+    sum ``c`` of g inside the block and beta down the block, ``lane`` [h,
+    1, C] ``c`` across it -> (o [h, C, Dv], S).  The same algebra with the
+    solve last: ``u = (I + M)^-1 beta (v - e^c k S)``, ``o = e^c q S + (q
+    k^T decay) u``, ``S <- e^c_C S + k^T (e^(c_C - c) u)``; every product
+    three bf16 passes in f32 (:func:`_mm3`), the heads' side by side (their
+    chains are independent: the chip runs one while another waits), no
+    factor above 1, and a block whose beta and g are 0 leaves S bit for
+    bit."""
+    h, C = S.shape[0], q.shape[1]
+    c, beta = col[..., 0:1], col[..., 1:2]
+    i = jax.lax.broadcasted_iota(jnp.int32, (1, C, C), 1)
+    j = jax.lax.broadcasted_iota(jnp.int32, (1, C, C), 2)
+    decay = jnp.where(i >= j, jnp.exp(jnp.minimum(c - lane, 0.0)), 0.0)
+    # a key head's products once, then a copy a value head it serves
+    wide = lambda t: t if t.shape[0] == h else jnp.repeat(
+        t, h // t.shape[0], axis=0)
+    kp, qp = _pair(k), _pair(q)
+    kk, qk = wide(_mm3(kp, kp, _NT)), wide(_mm3(qp, kp, _NT))
+    kp, qp = tuple(map(wide, kp)), tuple(map(wide, qp))
+    inv = _unit_lower_inverse(jnp.where(i > j, beta * decay * kk, 0.0), i, j)
+    ec, Sp = jnp.exp(c), _pair(S)
+    u = _mm3(_pair(inv), _pair(beta * (v - ec * _mm3(kp, Sp))))
+    o = ec * _mm3(qp, Sp) + _mm3(_pair(qk * decay), _pair(u))
+    # c_C as a row: c over the lanes, its last row summed out with zeros
+    # (the chip spreads one number one way at a time)
+    over = jnp.broadcast_to(c, u.shape)
+    last = jnp.sum(jnp.where(jax.lax.broadcasted_iota(
+        jnp.int32, u.shape, 1) == C - 1, over, 0.0), axis=1, keepdims=True)
+    return o, jnp.exp(last) * S + _mm3(kp, _pair(jnp.exp(last - over) * u),
+                                       _TN)
+
+
+def gdn_chunk_kernel(q, k, v, g, beta, S: SlotState, block: int):
+    """:func:`gdn_chunk_rule` where the build runs a chunk's state on the
+    chip: q, k [B, T, Hk, Dk] (a key head is not repeated: it serves ``Hv
+    // Hk`` value heads where it lies), v [B, T, Hv, Dv], g, beta [B, T,
+    Hv], f32 -> (o [B, T, Hv, Dv], S [B, Hv, Dk, Dv]): the running sums
+    made here, the blocks :func:`gdn_block_rule`'s."""
+    B, T, H = g.shape
+    pad = -T % block
+    if pad:
+        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),)
+                                    * (a.ndim - 2))
+                            for a in (q, k, v, g, beta))
+    c = jnp.cumsum(g.reshape(B, -1, block, H), axis=2).reshape(g.shape)
+    o, S = chunk_state(gdn_block_rule, S, (q, k, v),
+                       jnp.stack([c, beta], axis=-1), c[..., None], block)
+    return o[:, :T], S
 
 
 def expert_layer(cfg, h, lp):
@@ -489,7 +596,7 @@ FAMILY = DecoderFamily(
     whole_stacks=("w1", "w3", "w2"),
     recurrent=Recurrent(key="gdn_blocks", period=_period, mix=gdn_mix,
                         out=_gdn_out, state_row=_state_row,
-                        write_scope="gdn_write"),
+                        write_scope="gdn_write", block=gdn_block_rule),
     refuses=(
         ("prefix_cache", _STATE + "a shared prefix's pages say nothing of "
          "the state at its end, and no snapshot of it is kept"),

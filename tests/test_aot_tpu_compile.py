@@ -539,6 +539,31 @@ def test_state_step_kernel(chip, family):
     assert memory.temp_size_in_bytes < 8 << 20
 
 
+@pytest.mark.parametrize("block", [64, 128])
+def test_state_chunk_kernel(chip, block):
+    """``dstpu_state_chunk`` alone under the delta rule's block, at the
+    recurrent cell's sizes (a slot's 32 states of 128 x 128, 16 key
+    heads, a chunk of 1,024 tokens) and both blocks the family may run:
+    Mosaic takes the rule's arithmetic (products of bf16 pairs with f32
+    accumulation, the inverse by blocks, a product over the rows of both
+    operands), the rows' result is aliased to its operand, and nothing
+    the rule makes on its way is a value of the program's."""
+    from deepspeed_tpu.models import qwen3_next
+    from deepspeed_tpu.models.family import SlotState
+
+    f32, T, Hk, Hv, D = jnp.float32, 1024, 16, 32, 128
+    compiled = _compile(
+        lambda q, k, v, g, beta, S: qwen3_next.gdn_chunk_kernel(
+            q, k, v, g, beta, SlotState(S, K.state_chunk), block), chip,
+        ((1, T, Hk, D), f32), ((1, T, Hk, D), f32), ((1, T, Hv, D), f32),
+        ((1, T, Hv), f32), ((1, T, Hv), f32), ((1, Hv, D, D), f32))
+    hlo, memory = compiled.as_text(), compiled.memory_analysis()
+    assert re.search(r"%dstpu_state_chunk[\w.]* = .*tpu_custom_call", hlo)
+    assert "output_to_operand_aliasing={{1}: (0, {})}" in hlo
+    assert f"f32[{T // block},1,{Hv},{block}" not in hlo
+    assert memory.temp_size_in_bytes < 8 << 20
+
+
 # qwen3-next-80b-a3b-ep8-d12.serve.docqa-sat as the benchmark builds it:
 # three periods of three Gated DeltaNet layers and one gated attention
 # layer at the published widths, 64 of 512 experts held, an eighth of the
@@ -552,10 +577,12 @@ _QWEN_PAGES, _QWEN_SLOTS, _QWEN_TABLE = 65537, 96, 17408 // PAGE
 # at every table width (PR 40: 0.070, 1.266 and 0.340: the attention
 # layers' gathered K/V and f32 scores were the widest program's peak);
 # 0.26-0.29, 1.26 and 0.55-0.61 while the outer loop sliced a period of
-# the linear layers' weights out of their stack)
+# the linear layers' weights out of their stack; AOT, PR 50, reads 0.066,
+# 0.246 and 0.246: the chunked rule's [16, 1, 32, 64, 64] matrices and
+# its re-blocked q, k and v are gone with it, 0.09 GiB of the 0.34)
 QWEN_PROGRAMS = {"decode": (_QWEN_SLOTS, 1, _QWEN_TABLE, 0.1),
-                 "chunk_full_table": (1, 1024, _QWEN_TABLE, 0.36),
-                 "chunk_first": (1, 1024, 64, 0.36)}
+                 "chunk_full_table": (1, 1024, _QWEN_TABLE, 0.26),
+                 "chunk_first": (1, 1024, 64, 0.26)}
 
 
 def _top_level_results(hlo, dims):
@@ -645,7 +672,7 @@ def test_recurrent_cell_programs_fit_and_keep_pool_and_state_in_place(
     place, a tile at a time; a layer's experts are read in place; the
     kernels run by name, a chunk's attention over its history (heads of
     256, groups of 8) in the blocked chunk reader with no f32 value over
-    the table's 17,408 keys."""
+    the table's 17,408 keys, its delta rule in ``dstpu_state_chunk``."""
     from deepspeed_tpu.models import qwen3_next as qn
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -715,6 +742,21 @@ def test_recurrent_cell_programs_fit_and_keep_pool_and_state_in_place(
         assert re.search(r"%gmm[\w.]* = .*tpu_custom_call", hlo)
         _blocked_chunk_reader(hlo, table * PAGE if table * PAGE != T
                               else None)
+        # a chunk's delta rule is one Mosaic call a layer (PR 50), handed
+        # the slot's rows (aliased to its result), q and k at the 16 key
+        # heads' width, not repeated to the 32 value heads, and v; no
+        # value is left that holds a block's matrices or its re-blocked
+        # operands for every block and head at once
+        call = re.search(
+            r"%dstpu_state_chunk[\w.]* = .*tpu_custom_call.*?"
+            r"operand_layout_constraints=\{([^}]*\}[^}]*)*?\}, "
+            r"output_to_operand_aliasing=\{\{1\}: \(0, \{\}\)\}", hlo)
+        assert call, "dstpu_state_chunk"
+        assert ("f32[1,32,128,128]{3,2,1,0}, f32[1,1024,2048]{2,1,0}, "
+                "f32[1,1024,2048]{2,1,0}, f32[1,1024,4096]{2,1,0}"
+                ) in call.group(0)
+        for gone in ("f32[16,1,32,64", "f32[8,1,32,128"):
+            assert gone not in hlo, gone
 
 
 # v42.granite-4.0-h-micro.serve.assist-sat as the benchmark builds it:

@@ -408,9 +408,15 @@ class TestSentinel:
         dps = [_devprof(), _devprof()]
         assert devprof_mod.install_compile_listener()
         assert len(monitoring.get_event_duration_listeners()) == n
-        # engine 0 compiles a heavy program, engine 1 a light one
-        bodies = [lambda x: jnp.linalg.inv(x @ x.T + jnp.eye(24)).sum(),
-                  lambda x: x + 1]
+        # engine 0 compiles a heavy program, engine 1 a light one (eight
+        # inverses: 0.35 s against 0.04; one read 0.109 against 0.116 on
+        # a loaded machine, PR 50)
+        def heavy(x):
+            for _ in range(8):
+                x = jnp.linalg.inv(x @ x.T + jnp.eye(24))
+            return x.sum()
+
+        bodies = [heavy, lambda x: x + 1]
         fns = [dp.wrap(f"site{i}", jax.jit(_named("dstpu_t_two", b)))
                for i, (dp, b) in enumerate(zip(dps, bodies))]
         gate = threading.Barrier(2)

@@ -420,15 +420,23 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
                 jnp.zeros((2, 2, 2, 8, 128), jnp.float32), 1,
                 (jnp.zeros((2, 2, 1, 128), jnp.float32),),
                 interpret=True))(),
+        "dstpu_state_chunk": lambda: jax.make_jaxpr(
+            lambda: K.state_chunk(
+                lambda S, x, col, lane: (x + col, S),
+                jnp.zeros((1, 2, 8, 128), jnp.float32),
+                (jnp.zeros((1, 16, 2, 128), jnp.float32),),
+                jnp.zeros((1, 16, 2, 1), jnp.float32),
+                jnp.zeros((1, 16, 2, 1), jnp.float32), block=8,
+                interpret=True))(),
     }
     for want, make in sites.items():
         names = [n for n, _ in _pallas_scopes(make().jaxpr, [])]
         assert want in names, (want, names)
-    # the sources give ten sites ten names, none shared
+    # the sources give eleven sites eleven names, none shared
     named = []
     for mod in (K, attention_pallas, quant):
         with open(mod.__file__) as f:
             text = f.read()
         assert text.count("pl.pallas_call(") == text.count('name="dstpu_')
         named += re.findall(r'name="(dstpu_[a-z0-9_]+)"', text)
-    assert len(named) == len(set(named)) == 10
+    assert len(named) == len(set(named)) == 11

@@ -5,6 +5,7 @@ per-slot state beside the page pool, one gated attention layer a
 period, the softmax-routed share of the experts, the seam's refusals.
 Toy widths, seeded weights, CPU."""
 
+import functools
 import os
 import sys
 
@@ -318,6 +319,107 @@ def test_the_chunked_rule_is_the_recurrence(T, block):
                                atol=2e-5, rtol=2e-5)
 
 
+# case -> (T, block, key heads, value heads, real rows, S from zero,
+# heads a grid step, tokens a grid step)
+CHUNK_CASES = {
+    "whole_blocks": (64, 16, 4, 4, 64, False, None, None),
+    "sixteen_real_rows": (64, 16, 4, 4, 16, False, None, None),
+    "no_real_row": (64, 16, 4, 4, 0, False, None, None),
+    "from_zero_state": (64, 16, 4, 4, 64, True, None, None),
+    "two_value_heads_a_key_head": (64, 8, 2, 4, 64, False, None, None),
+    "one_head_a_step": (64, 8, 2, 4, 40, False, 1, None),
+    "two_heads_a_step_a_block_a_step": (64, 16, 2, 4, 64, False, 2, 16),
+    "eight_heads_a_step": (32, 8, 4, 8, 30, False, None, None),
+    "heads_of_128": (128, 64, 1, 2, 100, False, None, None),
+}
+
+
+@pytest.mark.parametrize("case", CHUNK_CASES)
+def test_the_chunk_kernel_is_the_recurrence_and_the_chunked_rule(case):
+    """``dstpu_state_chunk`` (interpret mode) under the family's block
+    rule, against the reference's token-by-token recurrence and against
+    ``gdn_chunk_rule`` on the same operands: whole blocks; a last chunk
+    with 16 real rows (beta = g = 0 behind them); a chunk with no real
+    row, which leaves S bit for bit; S from zero and not; a key head
+    serving two value heads through the index map, not repeated; one
+    head, two and eight a grid step, the whole chunk and one block a step."""
+    from deepspeed_tpu.models.family import SlotState
+
+    T, block, Hk, Hv, real, from_zero, heads, span = CHUNK_CASES[case]
+    wide = case == "heads_of_128"
+    q, k, v, g, beta, S = _rule_inputs(T, H=Hv, Dk=128 if wide else 8,
+                                       Dv=128 if wide else 6)
+    q, k = q[:, :Hk], k[:, :Hk]
+    live = (jnp.arange(T) < real)[:, None]
+    g, beta = jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0)
+    S = jnp.zeros_like(S) if from_zero else S
+    wide_q, wide_k = (jnp.repeat(t, Hv // Hk, axis=1) for t in (q, k))
+    want_o, want_S, _ = reference.recurrence(wide_q, wide_k, v, g, beta,
+                                             S, T)
+    rule_o, rule_S = qn.gdn_chunk_rule(
+        wide_q[None], wide_k[None], v[None], g[None], beta[None], S[None],
+        block)
+    chunk = functools.partial(K.state_chunk, interpret=True, heads=heads,
+                              span=span)
+    o, new = jax.jit(lambda q, k, v, g, beta, S: qn.gdn_chunk_kernel(
+        q, k, v, g, beta, SlotState(S, chunk), block))(
+            q[None], k[None], v[None], g[None], beta[None], S[None])
+    assert o.shape == (1, T, Hv, v.shape[-1]) and new.shape == S[None].shape
+    for got, want in ((o[0, :real], want_o[:real]), (new[0], want_S),
+                      (o[:, :real], rule_o[:, :real]), (new, rule_S)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=3e-5, rtol=3e-5)
+    if not real:
+        np.testing.assert_array_equal(np.asarray(new[0]), np.asarray(S))
+
+
+def test_the_chunk_kernel_refuses_what_it_cannot_tile():
+    """Tokens that are not whole blocks (a caller pads), and heads a
+    step that neither hold nor divide a key head's value heads."""
+    q, k, v, g, beta, S = _rule_inputs(24, H=6)
+    cols = jnp.stack([g, beta], -1)[None]
+    run = lambda block, heads, q=q: K.state_chunk(
+        qn.gdn_block_rule, S[None], (q[None], k[None], v[None]), cols,
+        g[None, ..., None], block=block, interpret=True, heads=heads)
+    with pytest.raises(ValueError, match="whole blocks"):
+        run(16, None)
+    with pytest.raises(ValueError, match="heads a step"):
+        run(8, 4)
+    with pytest.raises(ValueError, match="heads a step"):
+        run(8, 2, q=q[:, :2])           # a key head serves three
+
+
+def test_the_engine_serves_the_reference_argmax_through_the_chunk_kernel(
+        params, monkeypatch):
+    """The rule of the build says ``xla`` off the TPU; forced, every
+    prompt chunk of the rehearsal-size engine carries its state through
+    ``dstpu_state_chunk`` in interpret mode (a first chunk from zeroed
+    rows, later ones from what the slot holds, a last one padded), and
+    the greedy tokens are still the reference's argmax."""
+    from deepspeed_tpu.inference import paged_forward
+
+    calls = []
+    monkeypatch.setattr(paged_forward, "state_chunker",
+                        lambda *a, **kw: ("pallas", "forced by the test"))
+
+    def counted(*a, **kw):
+        assert kw["interpret"] and kw["block"] == CFG.gdn_block
+        calls.append(a[1].shape)
+        return K.state_chunk(*a, **kw)
+
+    monkeypatch.setattr(paged_forward, "state_chunk", counted)
+    eng = _engine(params)
+    rng = np.random.default_rng(1)
+    prompts = {i: rng.integers(0, CFG.vocab_size, n).tolist()
+               for i, n in enumerate((37, 21, 5, 9))}
+    for i, p in prompts.items():
+        eng.submit(i, p, max_new_tokens=7)
+    _argmax_served(params, eng.run(), prompts)
+    sr = decoder_family(CFG).recurrent.state_row(CFG)
+    assert calls and set(calls) == {(1,) + sr.state}
+    assert eng.check_leaks() == []
+
+
 # ---------------------------------------------------- (iv) the share
 @pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
 @pytest.mark.parametrize("tile_heads", [None, 8],
@@ -357,10 +459,20 @@ def test_the_policy_names_the_state_stepper(params):
     tests above); under a mesh ``xla``, with a ``fallbacks`` row."""
     kernels = _engine(params).statusz()["kernels"]
     assert kernels["state_step"] == "pallas" and kernels["fallbacks"] == []
-    demoted = K.resolve_serving_kernels(tp=True, recurrent=True)
-    assert demoted.state_step == "xla"
+    # a prompt chunk's answer beside it (``kernels.state_chunk``): the
+    # rehearsal runs off the TPU, at heads of 16
+    assert kernels["state_chunk"] == {
+        "reader": "xla", "reason": "interpret: no TPU backend"}
+    stated = (decoder_family(CFG).recurrent, qn.Qwen3NextConfig())
+    on_chip = K.resolve_serving_kernels(recurrent=True, state_block=stated)
+    assert on_chip.state_chunk[0] == "pallas" and on_chip.fallbacks == ()
+    assert K.state_chunker((stated[0], CFG), tp=False, interpret=False) == (
+        "xla", "a head's state is not whole 128-lane tiles")
+    demoted = K.resolve_serving_kernels(tp=True, recurrent=True,
+                                        state_block=stated)
+    assert demoted.state_step == demoted.state_chunk[0] == "xla"
     assert [(f, d) for f, d, _ in demoted.fallbacks] == [
-        ("state_step=pallas", "xla")]
+        ("state_step=pallas", "xla"), ("state_chunk=pallas", "xla")]
 
 
 def test_eight_ranks_shares_add_up_to_the_uncut_layer(params):

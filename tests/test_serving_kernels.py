@@ -69,19 +69,22 @@ class TestQuantizedResidentConfig:
 class TestResolveServingKernels:
     def test_defaults(self):
         """Off the chip (``interpret``) every reader is the gather; the
-        policy is a report with five rows and nothing to request."""
+        policy is a report with six rows and nothing to request."""
         p = resolve_serving_kernels(interpret=True)
         assert p._fields == ("decode", "chunk", "window", "state_step",
-                             "fallbacks")
+                             "fallbacks", "state_chunk")
         assert p.decode == p.chunk == ("xla", "interpret: no TPU backend")
         assert (p.state_step, p.fallbacks) == ("xla", ())
+        assert p.state_chunk == (
+            "xla", "the family states no block of its rule")
         # what a directly constructed engine reports: all gathers
         assert ServingKernelPolicy().decode[0] == "xla"
 
     def test_as_dict_shape(self):
         d = resolve_serving_kernels(tp=True, recurrent=True).as_dict()
-        assert sorted(d) == ["chunk", "decode", "fallbacks", "state_step",
-                             "window"]
+        assert sorted(d) == ["chunk", "decode", "fallbacks", "state_chunk",
+                             "state_step", "window"]
+        assert d["state_chunk"]["reader"] == "xla"
         assert d["decode"] == {
             "reader": "xla",
             "reason": "tp: KV heads are sharded over the mesh"}
@@ -513,6 +516,17 @@ ON_ONE_DEVICE = {
 }
 
 
+def _state_block(cell):
+    """The ``(Recurrent, cfg)`` a recurrent cell's build hands the rule,
+    at the published widths (the default configs)."""
+    from deepspeed_tpu.models import granite_hybrid, qwen3_next
+
+    if cell.startswith("qwen3-next"):
+        return qwen3_next.FAMILY.recurrent, qwen3_next.Qwen3NextConfig()
+    return (granite_hybrid.FAMILY.recurrent,
+            granite_hybrid.GraniteHybridConfig())
+
+
 class TestTheRuleAtTheCells:
     """The table a reviewer needs to see that no cell's reader changed,
     and the one the next kernel PR edits."""
@@ -525,16 +539,24 @@ class TestTheRuleAtTheCells:
             tp=where == "tp", interpret=where == "interpret",
             recurrent=kind == "state", chunk=(
                 engine.get("prefill_chunk") or engine["prefill_bucket"],
-                row.head_width or row.key_width))
+                row.head_width or row.key_width),
+            state_block=_state_block(cell) if kind == "state" else None)
         if kind == "latent":
             policy = policy._replace(decode=latent_reader(policy.decode))
         readers = (policy.decode[0], policy.chunk[0])
+        # the one family that states a block of its chunked rule (PR 50)
+        blocked = cell.startswith("qwen3-next")
         if where == "one_device":
             assert readers == ON_ONE_DEVICE[cell]
             assert policy.state_step == ("pallas" if kind == "state"
                                          else "xla")
+            assert policy.state_chunk[0] == ("pallas" if blocked else "xla")
             assert policy.fallbacks == ()
             return
+        assert policy.state_chunk[0] == "xla"
+        assert policy.state_chunk[1].startswith(
+            "the family states no block" if not blocked
+            else "tp:" if where == "tp" else "interpret:")
         assert readers == ("xla", "xla")
         assert policy.decode[1].startswith(
             "tp:" if where == "tp" else "interpret:")
@@ -542,7 +564,8 @@ class TestTheRuleAtTheCells:
         # in interpret mode
         assert policy.state_step == (
             "pallas" if kind == "state" and where == "interpret" else "xla")
-        assert len(policy.fallbacks) == (kind == "state" and where == "tp")
+        assert len(policy.fallbacks) == (where == "tp") * (
+            (kind == "state") + blocked)
 
 
 # --------------------------------------- nothing reads the old switches
@@ -629,9 +652,9 @@ class TestEnginePolicy:
         assert all(len(out[rid]) == len(p) + n
                    for rid, (p, n) in PROMPTS.items())
         kz = eng.statusz()["kernels"]
-        assert sorted(kz) == ["chunk", "decode", "fallbacks", "state_step",
-                              "window"]
-        for row in ("decode", "chunk", "window"):
+        assert sorted(kz) == ["chunk", "decode", "fallbacks", "state_chunk",
+                              "state_step", "window"]
+        for row in ("decode", "chunk", "window", "state_chunk"):
             reader = kz[row]["reader"]
             assert reader == "xla" or reader.startswith("dstpu_"), kz
             assert kz[row]["reason"]
