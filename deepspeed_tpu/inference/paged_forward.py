@@ -78,10 +78,12 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
                      whole: bool, tp: bool, interpret: bool):
     """The layers of a family some of whose layers keep a bounded state
     a slot (``fam.recurrent``): first its leading stack, if it has one
-    (``lead``: its block, over the pool's first layers), then its
-    sections in order (``family.sections_of``: a period and a count
-    each, the three kinds' layer indices running on over the same pool
-    and the same state buffers), a section's periods in a loop, a
+    (``lead``: its block, over the pool's first layers; or
+    ``Recurrent.lead``: per-slot layers with a second half of their own,
+    over the state buffers' first layers), then its sections in order
+    (``family.sections_of``: a period and a count each, the three
+    kinds' layer indices running on over the same pool and the same
+    state buffers), a section's periods in a loop, a
     period's kinds in the order stated: a pool layer is ``block``
     (:func:`_paged_block`); an FFN alone (``Recurrent.ffn``) touches
     neither cache; a per-slot layer reads its rows' state beside the
@@ -125,6 +127,10 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
     # slice copied 150 MB a projection, v5e, PR 35), but one section's pool
     stacks = {True: split(params[rec.key]), False: split(params["blocks"]),
               None: split(params[rec.ffn[0]]) if rec.ffn else None}
+    s_lead = 0                  # per-slot layers of a leading stack, sliced
+    if rec.lead is not None:    # a layer (a leading FFN holds no experts)
+        stacks["lead"] = {}, params[rec.lead[0]]
+        s_lead = jax.tree.leaves(stacks["lead"][1])[0].shape[0]
 
     def layer_of(kind, layer):
         held, stack = stacks[kind]
@@ -138,18 +144,21 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
             return x[0], _count_routed(fam, cfg, rows, x[1], B * T)
         return x, rows
 
-    def recurrent_layer(carry, layer):
+    def recurrent_layer(kind, out_half, first, carry, layer):
+        """Per-slot layer ``layer`` of the state buffers, a layer of the
+        stack ``kind`` ("lead", or True: the family's own) whose layer 0
+        keeps the buffers' layer ``first``."""
         x, rows, conv, state = carry
-        lp = layer_of(True, layer)
+        lp = layer_of(kind, layer - first if first else layer)
         # out of the carried buffers and back: all a decode step leaves
         rows_out = () if rows_in_place else (conv,)
         state_out = () if in_place or state is None else (state,)
         out = rows_out + state_out
         held = state_rows(out, layer, slot)
         if T > 1:
-            first = start == 0
+            fresh = start == 0
             held = tuple(jnp.where(
-                first.reshape((B,) + (1,) * (a.ndim - 1)), 0, a)
+                fresh.reshape((B,) + (1,) * (a.ndim - 1)), 0, a)
                 for a in held)
         held = (CarriedRows(conv, layer) if rows_in_place else held[0],
                 CarriedState(state, layer, step) if in_place
@@ -164,8 +173,10 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
         conv = held[0].buffer if rows_in_place else out[0]
         if state is not None:
             state = held[1].buffer if in_place else out[-1]
-        x, rows = counted(rec.out(cfg, x, y, lp), rows)
+        x, rows = counted(out_half(cfg, x, y, lp), rows)
         return (x, rows, conv, state), None
+
+    own_layer = functools.partial(recurrent_layer, True, rec.out, s_lead)
 
     def period(kinds, done, scanned, x, att, p, kp, vp, rows, conv, state):
         """Period ``p`` of a section whose period is ``kinds``, ``done``
@@ -186,8 +197,8 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
                 # in-place update from the buffer it had already
                 # overwritten: the state moved twice a step (v5e, PR 35)
                 (x, rows, conv, state), _ = jax.lax.scan(
-                    recurrent_layer, (x, rows, conv, state),
-                    p * n[kind] + (done[kind] + first)
+                    own_layer, (x, rows, conv, state),
+                    p * n[kind] + (done[kind] + first + s_lead)
                     + jnp.arange(run, dtype=jnp.int32))
                 continue
             for j in range(first, first + run):
@@ -207,6 +218,12 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
                     None, None, rows)
         return x, kp, vp, rows, conv, state
 
+    if s_lead:
+        (x, rows, conv, state), _ = jax.lax.scan(
+            functools.partial(recurrent_layer, "lead", rec.lead[1], 0),
+            (x, cache.expert_rows, cache.conv, cache.state),
+            jnp.arange(s_lead, dtype=jnp.int32))
+        cache = cache._replace(expert_rows=rows, conv=conv, state=state)
     done = {True: 0, False: 0, None: 0}
     for kinds, count in sections_of(rec, cfg, n_pool):
         n_att = kinds.count(False)

@@ -2,7 +2,7 @@
 
 A family's file (``gpt2.py``, ``llama.py``, ``mixtral.py``,
 ``pangu_ultra_moe.py``, ``qwen3_next.py``, ``granite_hybrid.py``,
-``laguna.py``, ``nemotron_h.py``) ends with its
+``laguna.py``, ``nemotron_h.py``, ``ling_flash.py``) ends with its
 ``FAMILY = DecoderFamily(...)``: the pieces of one transformer layer and
 the facts a serving build needs.  Everything that serves, streams, drafts
 or generates (the ``inference`` package) reads the record through
@@ -69,8 +69,9 @@ class StateRow(NamedTuple):
 class Recurrent:
     """The layers of a family that keep a bounded state a slot beside
     the page pool, in periods with layers that attend over pages (an
-    author's fields, as ``DecoderFamily``'s).  Four users: a delta rule
-    (``qwen3_next``), a state-space mixer (``granite_hybrid``,
+    author's fields, as ``DecoderFamily``'s).  Five users: a delta rule
+    gated a head (``qwen3_next``) or a key channel (``ling_flash``, whose
+    pool layers are latent), a state-space mixer (``granite_hybrid``,
     ``nemotron_h``): a matrix a head that every token moves, and a
     sliding window (``laguna``): a ring of the last tokens' K and V.
 
@@ -78,7 +79,8 @@ class Recurrent:
     mixes tokens over its per-slot state, False where it attends over
     the page pool (``qkv`` / ``out``), None where it is an FFN alone
     (``ffn``), in the model's order; the model is whole periods (behind
-    ``DecoderFamily.lead``, if any).  ``sections(cfg)``, for a model that
+    ``DecoderFamily.lead``, if any, whose layers attend over the pool,
+    or ``lead``).  ``sections(cfg)``, for a model that
     is not: ``((period, count), ...)``, run in order over one pool and
     one state buffer, each kind's layer indices running on (None: one
     section, :func:`sections_of`).  ``key``: the params' stack of the
@@ -106,7 +108,13 @@ class Recurrent:
     ``rows_in_place``: there ``state[0]`` is a :class:`CarriedRows` too:
     the mixer writes its layer of the buffer where it lies and hands it
     back (right for 3 rows of a convolution).  ``block``: the family's
-    rule of a chunk's block of tokens, a few heads (:func:`chunk_state`)."""
+    rule of a chunk's block of tokens, a few heads side by side, over
+    the operands it names itself (:func:`chunk_state`).  ``lead``:
+    ``(key, out)`` of a leading stack of per-slot layers with another
+    second half (leading dense layers that fall on the period's per-slot
+    kind): ``mix`` runs on that stack's own mixer weights over the state
+    buffers' first layers, ``out(cfg, x, y, lp)`` in ``out``'s place, and
+    ``key``'s own layers' state stands behind theirs."""
 
     key: str
     period: Callable[[Any], tuple]
@@ -119,6 +127,8 @@ class Recurrent:
     sections: Optional[Callable[[Any], tuple]] = None
     ffn: Optional[Tuple[str, Callable[..., Any]]] = None
     block: Optional[Callable[..., Tuple[Any, Any]]] = None
+    lead: Optional[Tuple[str, Callable[..., Any]]] = None
+
 
 class CarriedRows(NamedTuple):
     """A layer's per-slot rows where they live: ``buffer`` [layers,
@@ -308,7 +318,8 @@ def positions_from(start, T: int):
 
 # the registry: one module name a family
 _FAMILY_MODULES = ("gpt2", "llama", "mixtral", "pangu_ultra_moe",
-                   "qwen3_next", "granite_hybrid", "laguna", "nemotron_h")
+                   "qwen3_next", "granite_hybrid", "laguna", "nemotron_h",
+                   "ling_flash")
 
 
 def decoder_families() -> Tuple[DecoderFamily, ...]:

@@ -199,11 +199,28 @@ def _embed(params, tokens, start, cfg):
         return x, _rope_tables(cfg, positions_from(start, tokens.shape[1]))
 
 
+def mla_queries(cfg, q, cos, sin):
+    """q [B, T, H * (Dn + Dr)] as heads [B, T, H, Dn + Dr], each head's
+    rope part rotated."""
+    Dn = cfg.qk_nope_dim
+    q = q.reshape(q.shape[:2] + (cfg.n_heads, -1))
+    return jnp.concatenate(
+        [q[..., :Dn], _llama.apply_rope(q[..., Dn:], cos, sin)], -1)
+
+
+def mla_row(cfg, a, lp, cos, sin):
+    """What a token leaves in the cache, of the layer's normed input
+    ``a`` [B, T, d]: the row [B, T, 1, C + Dr] = ``[RMS(c) | RoPE(k_r)]``."""
+    C = cfg.kv_lora_rank
+    kv = a @ lp["wkv_a"]
+    c = _llama.rms_norm(kv[..., :C], lp["kv_norm"], cfg.norm_eps)
+    k_rope = _llama.apply_rope(kv[:, :, None, C:], cos, sin)
+    return jnp.concatenate([c[:, :, None], k_rope], -1)
+
+
 def _qkv(cfg, x, lp, cos, sin):
     """-> (q [B, T, H, Dn + Dr] with its rope part rotated, the cache
     row [B, T, 1, C + Dr] = ``[c | k_rope]``, None)."""
-    B, T, _ = x.shape
-    C, Dn = cfg.kv_lora_rank, cfg.qk_nope_dim
     eps = cfg.norm_eps
     # the benchmark's vocabulary knows attn_qkv; the new words nest in it
     qkv = jax.named_scope("attn_qkv")
@@ -211,14 +228,9 @@ def _qkv(cfg, x, lp, cos, sin):
         a = _llama.rms_norm(x, lp["attn_norm"], eps)
     with qkv, jax.named_scope("mla_q"):
         c_q = _llama.rms_norm(a @ lp["wq_a"], lp["q_norm"], eps)
-        q = (c_q @ lp["wq_b"]).reshape(B, T, cfg.n_heads, -1)
-        q = jnp.concatenate(
-            [q[..., :Dn], _llama.apply_rope(q[..., Dn:], cos, sin)], -1)
+        q = mla_queries(cfg, c_q @ lp["wq_b"], cos, sin)
     with qkv, jax.named_scope("mla_kv"):
-        kv = a @ lp["wkv_a"]
-        c = _llama.rms_norm(kv[..., :C], lp["kv_norm"], eps)
-        k_rope = _llama.apply_rope(kv[:, :, None, C:], cos, sin)
-        row = jnp.concatenate([c[:, :, None], k_rope], -1)
+        row = mla_row(cfg, a, lp, cos, sin)
     return q, row, None
 
 
@@ -245,16 +257,18 @@ def _out_dense(cfg, x, attn, lp):
         return x + _llama.rms_norm(y, lp["post_mlp_norm"], cfg.norm_eps)
 
 
-def expert_layer(cfg, h, lp):
+def expert_layer(cfg, h, lp, **choice):
     """h [B, T, d] (normed) -> (this rank's part of the routed sum plus
-    the shared expert, rows [Eh] int32 routed to each held expert)."""
+    the shared expert, rows [Eh] int32 routed to each held expert).
+    ``choice``: what else the family's router chooses by (``bias``,
+    ``groups``: :func:`~deepspeed_tpu.parallel.moe.sigmoid_topk_route`)."""
     from deepspeed_tpu.ops.fused_ops import swiglu
 
     B, T, d = h.shape
     hf = h.reshape(-1, d)
     w, experts = sigmoid_topk_route(
         hf, lp["gate"], cfg.top_k, cfg.routed_scaling_factor,
-        cfg.norm_topk_prob)
+        cfg.norm_topk_prob, **choice)
     with jax.named_scope("moe_ffn"):
         y, rows = held_experts_ffn(hf, w, experts, lp["w1"], lp["w3"],
                                    lp["w2"], first=cfg.experts_held[0],

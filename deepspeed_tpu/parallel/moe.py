@@ -172,19 +172,32 @@ def expert_param_specs(specs: Any) -> Any:
 
 # ------------------------------------------- a share of the experts, served
 def sigmoid_topk_route(h, gate, top_k: int, scale: float = 1.0,
-                       normalize: bool = True, bias=None):
+                       normalize: bool = True, bias=None, groups=None):
     """Router of the sigmoid-scored families: ``h`` [N, d] against
     ``gate`` [d, E] over ALL E experts, in f32 whatever the inputs are
     (a bf16 score flips near-tied choices) -> (weights [N, k] f32,
     experts [N, k] int32).  The k largest (by ``s + bias`` [E]: the choice
-    moves, not the weight), over their sum (``normalize``), x ``scale``."""
+    moves, not the weight), over their sum (``normalize``), x ``scale``.
+    ``groups`` ``(n, keep)``: the choice is group-limited: the experts lie
+    in ``n`` groups of consecutive E / n, a group's score is the sum of
+    its two largest ``s + bias``, and only experts of the ``keep`` best
+    groups may be chosen (a rank that holds whole groups is sent rows by
+    the tokens that kept one of them, and by no other)."""
     with jax.named_scope("moe_router"):
         s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32),
                                    gate.astype(jnp.float32),
                                    precision=jax.lax.Precision.HIGHEST))
-        top, idx = jax.lax.top_k(
-            s if bias is None else s + bias.astype(jnp.float32), top_k)
-        if bias is not None:
+        choice = s if bias is None else s + bias.astype(jnp.float32)
+        if groups is not None:
+            n, keep = groups
+            by_group = choice.reshape(choice.shape[0], n, -1)
+            best = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+            _, kept = jax.lax.top_k(best, keep)              # [N, keep]
+            open_ = jnp.any(kept[..., None] == jnp.arange(n), axis=1)
+            choice = jnp.where(open_[..., None], by_group,
+                               -jnp.inf).reshape(choice.shape)
+        top, idx = jax.lax.top_k(choice, top_k)
+        if bias is not None or groups is not None:
             top = jnp.take_along_axis(s, idx, axis=-1)
         if normalize:
             top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
