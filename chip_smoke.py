@@ -226,6 +226,101 @@ def state_chunk_check(seed, on_tpu):
          rel_err_by_norm=err, tolerance=tol, masked_chunk="bit for bit")
 
 
+# chunk shapes of the cells whose expert layers run the grouped branch:
+# (rows, k, held, experts, d, f, gated)
+HELD_FFN_SHAPES = {
+    "qwen3-next docqa-sat": (1024, 10, 64, 512, 2048, 512, True),
+    "ling docqa-sat": (1024, 8, 64, 512, 2560, 768, True),
+    "laguna code-sat": (1024, 10, 16, 256, 3072, 1024, True),
+    "nemotron code-sat": (1024, 6, 16, 128, 2688, 1920, False),
+    "mixtral docs-sat": (1024, 2, 8, 8, 4096, 14336, True),
+}
+
+
+def held_ffn_check(seed, on_tpu):
+    """A pass of the held experts in ``dstpu_held_ffn`` against XLA's
+    ``ragged_dot`` and the slot-by-slot combine on the same operands
+    (``held_experts_ffn`` with and without the kernel), a layer of a
+    two-layer stack, at the five cells' chunk shapes under a router
+    drawn from the seed (a toy shape in interpret mode off the chip):
+    within bf16's rounding of the largest value, and the ms a call
+    beside ``benchmark/roofline/moe.py``'s floor for the rows drawn."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.roofline import moe as gated_floor, moe_ungated
+    from deepspeed_tpu.parallel import moe
+
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "peaks.json")) as fh:
+        peaks = json.load(fh)["devices"]["TPU v5 lite"]
+    shapes = HELD_FFN_SHAPES if on_tpu else {
+        "toy": (48, 2, 4, 8, 128, 256, True),
+        "toy, two matrices": (48, 2, 4, 8, 128, 256, False)}
+    dtype, L, tol = (jnp.bfloat16 if on_tpu else jnp.float32), 2, 3e-2
+    laps = 8 if on_tpu else 1
+    relu2 = lambda a: jnp.square(jax.nn.relu(a))
+    was = moe._on_chip, moe._every_row_pays
+    for name, (N, k, Eh, E, d, f, gated) in shapes.items():
+        ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+        first = Eh if E > Eh else 0
+        h = jax.random.normal(ks[0], (N, d), dtype)
+        wts, experts = jax.lax.top_k(jax.nn.softmax(
+            jax.random.normal(ks[1], (N, E))), k)
+        g = lambda key, *s: (jax.random.normal(key, s) * s[-2] ** -0.5
+                             ).astype(dtype)
+        w1, w2 = g(ks[2], L, Eh, d, f), g(ks[4], L, Eh, f, d)
+        w3 = g(ks[3], L, Eh, d, f) if gated else None
+
+        def layers(h, w1, w3, w2):
+            def one(l, acc):
+                y, _ = moe.held_experts_ffn(
+                    h, wts, experts.astype(jnp.int32), w1, w3, w2,
+                    first=first, layer=l, n_experts=E,
+                    act=None if gated else relu2)
+                return acc + y.astype(jnp.float32)
+            # each layer several times a dispatch: the host's part of a
+            # call is then a small share of what is timed
+            return jax.lax.fori_loop(0, L * laps, lambda i, acc: one(
+                i % L, acc), jnp.zeros((N, d)))
+
+        got = {}
+        try:
+            moe._every_row_pays = lambda *a: False
+            for which in (True, False):
+                moe._on_chip = lambda which=which: which
+                fn = jax.jit(lambda *a: layers(*a))    # a trace a side
+                y = jax.block_until_ready(fn(h, w1, w3, w2))
+                t0 = time.perf_counter()
+                for _ in range(5):
+                    y = fn(h, w1, w3, w2)
+                jax.block_until_ready(y)
+                got[which] = (np.asarray(y, np.float32),
+                              (time.perf_counter() - t0) / 5 / (L * laps)
+                              * 1e3)
+        finally:
+            moe._on_chip, moe._every_row_pays = was
+        (y, ms), (ref, ref_ms) = got[True], got[False]
+        if not np.isfinite(y).all():
+            raise AssertionError(f"held ffn {name}: not finite")
+        err = float(np.abs(y - ref).max() / np.abs(ref).max())
+        if not err <= tol:
+            raise AssertionError(f"held ffn {name}: {err:.4f} of max > {tol}")
+        local = np.asarray(experts) - first
+        rows = np.bincount(local[(local >= 0) & (local < Eh)], minlength=Eh)
+        floor_ms = 1e3 * (gated_floor if gated else moe_ungated).floor_seconds(
+            d, f, N * k, [r / (N * k) for r in rows], peaks)
+        emit(phase="held_ffn_check", cell=name, shape=[N, k, Eh, E, d, f],
+             gated=gated, rows_held=int(rows.sum()),
+             tiles=list(moe._held_ffn_tiles(N, k, E, d, f, w1.dtype.itemsize,
+                                            2 + gated)),
+             err_of_max=err, tolerance=tol,
+             **({"ms_a_call": ms, "ragged_dot_ms_a_call": ref_ms,
+                 "floor_ms": floor_ms} if on_tpu else {}))
+        del w1, w2, w3
+
+
 # ------------------------------------------------------------------ train
 def build_trainer(size, seed, stage, chips):
     """(engine, batch, cfg): the train phase's model through
@@ -567,6 +662,7 @@ def main():
         if on_tpu:
             kernel_check(size, args.seed)
         state_chunk_check(args.seed, on_tpu)
+        held_ffn_check(args.seed, on_tpu)
         train_phase(size, args.seed, cache)
         gc.collect()                  # the trainer's HBM, before the server
         serve_phase(size, args.seed, cache, on_tpu)

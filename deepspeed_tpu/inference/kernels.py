@@ -1385,6 +1385,9 @@ class ServingKernelPolicy(NamedTuple):
     fallbacks: Tuple[Tuple[str, str, str], ...] = ()
     # (pallas | xla, reason): a prompt chunk's state (:func:`state_chunker`)
     state_chunk: Tuple[str, str] = ("xla", "")
+    # (product, reason) of a chunk program's held experts
+    # (:func:`~deepspeed_tpu.parallel.moe.held_product`)
+    experts: Tuple[str, str] = ("none", "no expert layer")
 
     def as_dict(self) -> dict:
         pair = lambda k: dict(zip(("reader", "reason"), getattr(self, k)))
@@ -1392,9 +1395,31 @@ class ServingKernelPolicy(NamedTuple):
             "decode": pair("decode"), "chunk": pair("chunk"),
             "window": pair("window"), "state_step": self.state_step,
             "state_chunk": pair("state_chunk"),
+            "experts": dict(zip(("product", "reason"), self.experts)),
             "fallbacks": [{"field": f, "demoted_to": d, "reason": r}
                           for f, d, r in self.fallbacks],
         }
+
+
+def held_experts_product(params, fam, cfg, rows: int, whole: bool):
+    """``ServingKernelPolicy.experts`` of a build: (product, reason) of
+    the held experts in a chunk program of ``rows`` rows, by the rule the
+    program itself follows (:func:`~deepspeed_tpu.parallel.moe.
+    held_product`) on the family's router and its stacks' shapes.
+    ``whole``: the paged forward hands the family's ``whole_stacks``
+    over unsliced (plain arrays held on one device)."""
+    from deepspeed_tpu.parallel.moe import held_product
+
+    if not fam.whole_stacks:
+        return ServingKernelPolicy().experts
+    if not rows:
+        return "every_row", "no chunk program"
+    name = fam.whole_stacks[0]
+    w = next(v[name] for v in params.values()
+             if isinstance(v, dict) and name in v)
+    (E, k), Eh = fam.router(cfg), fam.expert_rows(cfg)[0]
+    return held_product(rows, k, Eh, E, *w.shape[-2:], w.dtype.itemsize,
+                        len(fam.whole_stacks), whole)[:2]
 
 
 def resolve_serving_kernels(*, tp: bool = False, interpret: bool = False,

@@ -103,6 +103,7 @@ from deepspeed_tpu.incidents import NULL_INCIDENTS, IncidentManager
 from deepspeed_tpu.inference.kernels import (STATE_DTYPE, PagedKVCache,
                                              PageAllocator,
                                              ServingKernelPolicy,
+                                             held_experts_product,
                                              latent_reader,
                                              resolve_serving_kernels)
 from deepspeed_tpu.inference.paged_forward import forward_paged
@@ -3792,6 +3793,10 @@ def serving_engine(params, cfg, **kw):
             window=fam.recurrent.chunk_reader(
                 cfg, kw.get("prefill_chunk") or 0,
                 jax.default_backend() != "tpu"))
+
+    kw["kernels"] = kw["kernels"]._replace(experts=held_experts_product(
+        params, fam, cfg, kw.get("prefill_chunk") or 0,
+        weight_dtype == "bfloat16" and not sharded and not zi.enabled))
 
     if zi.enabled:
         from deepspeed_tpu.inference.zero_inference import (

@@ -127,6 +127,41 @@ def test_paged_chunk_v2(chip, layout):
              chip, ((B, 128, H, DH), jnp.bfloat16), kv, kv, table, lens)
 
 
+# (N rows, k, held, experts, d, f): docqa-sat's chunk (gated, 64 of 512
+# experts of 2048 x 512: one block of f, a tile a step) and Nemotron's
+# (two matrices of 2688 x 1,920 stored columns, 16 of 128); Mixtral's
+# chunk (all 8 experts, f in blocks, four products a step) rides in
+# ``test_mixtral_chunk_program_groups_the_rows_by_expert``
+@pytest.mark.parametrize("shape,gated", [
+    ((1024, 10, 64, 512, 2048, 512), True),
+    ((1024, 6, 16, 128, 2688, 1920), False)], ids=["gated", "two_matrices"])
+def test_held_ffn_kernel(chip, monkeypatch, shape, gated):
+    """``dstpu_held_ffn`` through ``held_experts_ffn`` at a share's chunk
+    shape, a layer of whole stacks: the pass loop is one Mosaic call, no
+    layer is sliced out of a stack and no [C, d] buffer of rows is left."""
+    from deepspeed_tpu.parallel import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    N, k, Eh, E, d, f = shape
+    bf, L = jnp.bfloat16, 3
+    relu2 = lambda a: jnp.square(jax.nn.relu(a))
+
+    def fn(h, w, e, w1, w3, w2, layer):
+        return moe.held_experts_ffn(
+            h, w, e, w1, w3 if gated else None, w2, first=Eh, layer=layer,
+            n_experts=E, act=None if gated else relu2)
+
+    hlo = _compile(
+        fn, chip, ((N, d), bf), ((N, k), jnp.float32), ((N, k), jnp.int32),
+        ((L, Eh, d, f), bf), ((L, Eh, d, f), bf), ((L, Eh, f, d), bf),
+        ((), jnp.int32)).as_text()
+    assert len(re.findall(r"%dstpu_held_ffn[\w.]* = .*tpu_custom_call",
+                          hlo)) == 1
+    C = moe._pair_buffer_rows(N, k, Eh, E)
+    assert f"bf16[{C},{d}]" not in hlo and f"bf16[{C},{f}]" not in hlo
+    assert f"bf16[{Eh},{d},{f}]" not in hlo         # no layer's slice
+
+
 def test_int8_resident_pages_are_gathered_on_the_chip(chip):
     """Over int8-resident pages (``kv_tier.quantized_resident``) the
     rule answers the gather in both phases on a chip, and a decode step
@@ -355,11 +390,11 @@ def test_serving_program_leaves_the_pool_in_place(chip, pool, phase):
 def test_mixtral_chunk_program_groups_the_rows_by_expert(
         chip, monkeypatch, table, temp_gib):
     """``mixtral-8x7b-d4.serve.docs-sat``'s chunk program as the engine
-    builds it (the experts' rows counted): each layer's FFN is three
-    Mosaic grouped products over the 2,048 (row, expert) pairs the
-    router chose, read out of the whole stack in place; nothing shaped
-    like every expert's answer for every row ``[8, 1024, 14336]`` is
-    left and no layer's 2.8 GB of experts is copied out of the stack.
+    builds it (the experts' rows counted): each layer's FFN is one
+    Mosaic call (``dstpu_held_ffn``, PR 52) over the 2,048 (row, expert)
+    pairs the router chose, read out of the whole stack in place; nothing
+    shaped like every expert's answer for every row ``[8, 1024, 14336]``
+    is left and no layer's 2.8 GB of experts is copied out of the stack.
     Its attention over history is the blocked chunk reader's, and no f32
     value over the table's 8,320 keys is left."""
     # the grouped product asks the backend which kernel to run; the
@@ -388,7 +423,8 @@ def test_mixtral_chunk_program_groups_the_rows_by_expert(
         params, jax.ShapeDtypeStruct((1, T), jnp.int32), cache,
         jax.ShapeDtypeStruct((1,), jnp.int32)))).compile()
     hlo, memory = compiled.as_text(), compiled.memory_analysis()
-    assert len(re.findall(r"%gmm[\w.]* = .*tpu_custom_call", hlo)) == 3
+    assert len(re.findall(r"%dstpu_held_ffn[\w.]* = .*tpu_custom_call",
+                          hlo)) == 1
     assert _shaped_like(hlo, cfg.num_experts, T, cfg.ffn_dim) == []
     assert _shaped_like(hlo, cfg.num_experts, cfg.dim, cfg.ffn_dim) == []
     assert "dynamic-slice_bitcast_fusion" not in hlo
@@ -497,7 +533,7 @@ def test_latent_cell_programs_fit_and_leave_the_pool_in_place(
         else "dstpu_latent_flash_fwd"
     assert re.search(rf"%{kernel}[\w.]* = .*tpu_custom_call", hlo)
     if program != "decode":
-        assert re.search(r"%gmm[\w.]* = .*tpu_custom_call", hlo)
+        assert re.search(r"%dstpu_held_ffn[\w.]* = .*tpu_custom_call", hlo)
         assert "bf16[8192,7680]" not in hlo and "f32[1024,8,7680]" not in hlo
         # the pass loop hands the Mosaic call the stack it was handed
         for stack in ((64, 7680, 2048), (64, 2048, 7680)):
@@ -733,18 +769,19 @@ def test_recurrent_cell_programs_fit_and_keep_pool_and_state_in_place(
         for stack in ((192, 2048, 512), (192, 512, 2048),
                       (576, 2048, 512), (576, 512, 2048)):
             assert _top_level_results(hlo, stack) == []
-        # the pair buffer is 4,096 rows, a bound on the ~1,280 of the
-        # chunk's 10,240 pairs that are held here; no pair that another
-        # rank computes is gathered, re-laid out or summed
-        assert "bf16[4096,2048]" in hlo
-        for gone in ("bf16[16384,2048]", "bf16[10240,2048]",
-                     "f32[1024,10,2048]", "f32[10240,2048]"):
+        # a pass takes 4,096 sorted pairs, a bound on the ~1,280 of the
+        # chunk's 10,240 pairs that are held here, and since PR 52 holds
+        # no buffer of their rows' products ([C, f]); no pair that
+        # another rank computes is gathered, re-laid out or summed
+        for gone in ("bf16[4096,512]", "bf16[16384,2048]",
+                     "bf16[10240,2048]", "f32[1024,10,2048]",
+                     "f32[10240,2048]"):
             assert gone not in hlo, gone
     if program == "decode":
         assert re.search(r"%dstpu_paged_decode[\w.]* = .*tpu_custom_call",
                          hlo)
     else:
-        assert re.search(r"%gmm[\w.]* = .*tpu_custom_call", hlo)
+        assert re.search(r"%dstpu_held_ffn[\w.]* = .*tpu_custom_call", hlo)
         _blocked_chunk_reader(hlo, table * PAGE if table * PAGE != T
                               else None)
         # a chunk's delta rule is one Mosaic call a layer (PR 50), handed
@@ -973,7 +1010,7 @@ def test_sectioned_cell_programs_fit_and_keep_pool_state_and_experts_in_place(
                     "ROOT" in l and " dynamic-update-slice(" in l
                     for l in body)), (name, op)
         assert _top_level_results(hlo, state_shape[1:]) == []
-        assert re.search(r"%gmm[\w.]* = .*tpu_custom_call", hlo)
+        assert re.search(r"%dstpu_held_ffn[\w.]* = .*tpu_custom_call", hlo)
         _blocked_chunk_reader(hlo, table * PAGE if table * PAGE != T
                               else None)
     L = sr.layers
@@ -1323,12 +1360,13 @@ def test_channel_gated_cell_programs_fit_and_keep_pool_and_state_in_place(
         assert re.search(r"%dstpu_mla_decode[\w.]* = .*tpu_custom_call",
                          hlo)
     else:
-        assert re.search(r"%gmm[\w.]* = .*tpu_custom_call", hlo)
+        assert re.search(r"%dstpu_held_ffn[\w.]* = .*tpu_custom_call", hlo)
         assert re.search(
             r"%dstpu_latent_flash_fwd[\w.]* = .*tpu_custom_call", hlo)
         for experts in ((64, 2560, 768), (64, 768, 2560)):
             assert _top_level_results(hlo, experts) == []
-        assert "bf16[2048,2560]" in hlo and "bf16[8192,2560]" not in hlo
+        # no buffer of the pairs' rows, bounded (2,048) or not (PR 52)
+        assert "bf16[2048,2560]" not in hlo and "bf16[8192,2560]" not in hlo
         call = re.search(
             r"%dstpu_state_chunk[\w.]* = .*tpu_custom_call.*?"
             r"output_to_operand_aliasing=\{\{1\}: \(0, \{\}\)\}", hlo)

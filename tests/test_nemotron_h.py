@@ -296,10 +296,10 @@ def test_the_stored_width_gives_the_published_widths_numbers(
         params, monkeypatch, every_row):
     """40 columns stored in 128 with zeros behind, and ``W_down``'s rows
     likewise: ``relu(0)^2 = 0`` meets zero rows.  On the every-row
-    branch and on the grouped one (its passes too: a row tile of 8)."""
+    branch and on the grouped one (its passes too: a granule of 8 rows)."""
     assert CFG.moe_ffn_stored == 128 and CFG.moe_ffn_dim == 40
     monkeypatch.setattr(moe, "_every_row_pays", lambda *a: every_row)
-    monkeypatch.setattr(moe, "_GMM_TILING", (8, 128, 128))
+    monkeypatch.setattr(moe, "_ROW_GRANULE", 8)
     lp = jax.tree.map(lambda a: a[1], params["moe_blocks"])
     assert not np.asarray(lp["w_up"][..., 40:]).any()
     assert not np.asarray(lp["w_down"][:, 40:]).any()

@@ -229,7 +229,7 @@ def test_a_chunk_routed_past_the_pair_buffer_makes_further_passes(
     cfg = dataclasses.replace(CFG, n_routed_experts=64)
     assert bench_family._ref_kw(cfg) == bench_family._ref_kw(CFG)
     monkeypatch.setattr(moe, "_every_row_pays", lambda N, k, Eh: N < 16)
-    monkeypatch.setattr(moe, "_GMM_TILING", (4, 128, 128))
+    monkeypatch.setattr(moe, "_ROW_GRANULE", 4)
     params = qn.init_params(jax.random.PRNGKey(1), cfg)
     for stack in ("blocks", "gdn_blocks"):
         params[stack]["gate"] = params[stack]["gate"].at[

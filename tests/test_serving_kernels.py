@@ -72,7 +72,8 @@ class TestResolveServingKernels:
         policy is a report with six rows and nothing to request."""
         p = resolve_serving_kernels(interpret=True)
         assert p._fields == ("decode", "chunk", "window", "state_step",
-                             "fallbacks", "state_chunk")
+                             "fallbacks", "state_chunk", "experts")
+        assert p.experts == ("none", "no expert layer")
         assert p.decode == p.chunk == ("xla", "interpret: no TPU backend")
         assert (p.state_step, p.fallbacks) == ("xla", ())
         assert p.state_chunk == (
@@ -82,8 +83,10 @@ class TestResolveServingKernels:
 
     def test_as_dict_shape(self):
         d = resolve_serving_kernels(tp=True, recurrent=True).as_dict()
-        assert sorted(d) == ["chunk", "decode", "fallbacks", "state_chunk",
-                             "state_step", "window"]
+        assert sorted(d) == ["chunk", "decode", "experts", "fallbacks",
+                             "state_chunk", "state_step", "window"]
+        assert d["experts"] == {"product": "none",
+                                "reason": "no expert layer"}
         assert d["state_chunk"]["reader"] == "xla"
         assert d["decode"] == {
             "reader": "xla",
@@ -652,8 +655,8 @@ class TestEnginePolicy:
         assert all(len(out[rid]) == len(p) + n
                    for rid, (p, n) in PROMPTS.items())
         kz = eng.statusz()["kernels"]
-        assert sorted(kz) == ["chunk", "decode", "fallbacks", "state_chunk",
-                              "state_step", "window"]
+        assert sorted(kz) == ["chunk", "decode", "experts", "fallbacks",
+                              "state_chunk", "state_step", "window"]
         for row in ("decode", "chunk", "window", "state_chunk"):
             reader = kz[row]["reader"]
             assert reader == "xla" or reader.startswith("dstpu_"), kz

@@ -148,7 +148,7 @@ def test_a_chunk_routed_past_the_pair_buffer_makes_further_passes(
 
     cfg = dataclasses.replace(CFG, n_routed_experts=64)
     monkeypatch.setattr(moe, "_every_row_pays", lambda N, k, Eh: N < 16)
-    monkeypatch.setattr(moe, "_GMM_TILING", (4, 128, 128))
+    monkeypatch.setattr(moe, "_ROW_GRANULE", 4)
     params = pg.init_params(jax.random.PRNGKey(1), cfg)
     params["blocks"]["gate"] = params["blocks"]["gate"].at[
         ..., cfg.experts_held[1]:].set(0)
