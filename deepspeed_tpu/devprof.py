@@ -33,6 +33,17 @@ build to its contract:
   the incident probe trips a ``steady_state_recompile`` bundle and the
   bench gate pins ``steady_state_recompiles == 0``.
 
+- **Step ledger**: the steady state's record, beside the build's.
+  :data:`STEP_LEDGER` keeps one row a ``ServingEngine.step()`` for the
+  whole run (a capture holds seconds of it): the step's ordinal and its
+  two instants on ``time.perf_counter``, the seconds of each phase its
+  spans timed, what it dispatched by site (programs, rows, real
+  tokens), and the *exposed* seconds: the stretches inside the step in
+  which the device provably had nothing queued, from the return of a
+  device-to-host fetch to the next dispatch call.  An engine with
+  telemetry on writes through its :class:`StepRow`; with telemetry off
+  nothing is written and no clock is read.
+
 On-demand device traces: ``/profilez?capture_s=`` runs a bounded
 ``jax.profiler`` capture under ``tracing.dump_dir``; the capture
 reference and the engine's compiles ride incident bundles.
@@ -41,6 +52,7 @@ reference and the engine's compiles ride incident bundles.
 from __future__ import annotations
 
 import collections
+import itertools
 import os
 import threading
 import time
@@ -354,6 +366,218 @@ class BuildCounters:
         self._g_build.set(self._seconds)
         if _tl.mirror is self:
             _tl.mirror = None
+
+
+# ---------------------------------------------------------- step ledger
+# a step's phases, in the order its spans are entered (serving_<phase>)
+STEP_PHASES = ("admit", "prefill", "boundary", "grow_pages", "upload",
+               "inputs", "dispatch", "token_sync", "append")
+# where a step hands the device a program: a whole-prompt prefill, a
+# prompt chunk, the decode chunk, the speculative verify sweep
+STEP_SITES = ("prefill", "chunk", "decode", "sweep")
+# the longest window at the shortest step: ~110 steps/s for 74 s
+_STEP_ROWS = 1 << 13
+
+
+class StepLedger:
+    """Bounded record of every serving step the process made with
+    telemetry on: a preallocated ring of rows (the oldest dropped), each
+    stored once as its step ends, raw, from values the step already
+    had: the hot path pays a tuple and a list store, and everything a
+    reader wants (phases by name, idle stretches by phase, the running
+    sums) is worked out by :meth:`snapshot`.  Process-wide, as the build
+    ledger, and on the same clock (``time.perf_counter``), so a reader
+    cuts a window at two instants exactly and reads it after the engine
+    is gone.  :meth:`snapshot` is what ``/statusz`` carries under
+    ``steps``."""
+
+    def __init__(self, capacity: int = _STEP_ROWS):
+        self._lock = threading.Lock()           # snapshot's alone
+        self._ring: List[Optional[Tuple]] = [None] * int(capacity)
+        self._ordinals = itertools.count()
+        self._folded = -1       # the newest ordinal in the sums
+        self._unseen = 0        # rows the ring dropped before a fold
+        self.steps = 0
+        # seconds: inside steps, exposed, between steps, in the tick
+        self.sums = [0.0, 0.0, 0.0, 0.0]
+        self.programs = {site: [0, 0, 0] for site in STEP_SITES}
+
+    @staticmethod
+    def _as_read(row: Tuple) -> Dict[str, Any]:
+        """A row as a reader takes it: the phases a step entered by
+        name, in seconds, and its idle stretches cut to the step and
+        given to the phases they fell in."""
+        (n, t0, t1, last_end, tick0, tick1, k, queue, admitted,
+         preempted, boundary, since, dispatched, idle, spans) = row
+        # a span the step did not enter still holds an earlier step's
+        entered = [(name, p0, p1) for name, (p0, p1)
+                   in zip(STEP_PHASES, spans) if p0 >= t0 and p1 >= p0]
+        if since is not None:   # it ended with nothing queued
+            idle = idle + [(since, t1)]
+        # a step that began drained is idle from its first instant
+        idle = [(max(a, t0), min(b, t1)) for a, b in idle]
+        idle = [(a, b) for a, b in idle if b > a]
+        exposed = {}
+        for name, p0, p1 in entered:
+            inside = sum(max(min(b, p1) - max(a, p0), 0.0)
+                         for a, b in idle)
+            if inside > 0:
+                exposed[name] = inside
+        programs = {site: [0, 0, 0] for site in STEP_SITES}
+        for site, rows, tokens in dispatched:
+            p = programs[site]
+            p[0] += 1
+            p[1] += rows
+            p[2] += tokens
+        return {
+            "n": n, "t0": t0, "t1": t1,
+            "between_s": 0.0 if last_end is None else t0 - last_end,
+            "tick_s": tick1 - tick0, "k": k, "queue": queue,
+            "admitted": admitted, "preempted": preempted,
+            "boundary_tokens": boundary, "drained": since is not None,
+            "programs": programs,
+            "phases": {name: p1 - p0 for name, p0, p1 in entered},
+            "exposed_s": sum(b - a for a, b in idle),
+            "exposed": exposed,
+            "idle": [list(i) for i in idle],
+        }
+
+    def _fold(self, fresh: List[Dict[str, Any]], oldest: int) -> None:
+        """The rows no snapshot has seen go into the running sums (the
+        hot path keeps none): whoever reads folds.  A reader that comes
+        less often than the ring turns misses the ordinals between the
+        newest it folded and the ``oldest`` kept: ``unseen``."""
+        if not fresh:
+            return
+        self._unseen += max(oldest - 1 - self._folded, 0)
+        self._folded = fresh[-1]["n"]
+        self.steps += len(fresh)
+        sums = self.sums
+        for r in fresh:
+            sums[0] += r["t1"] - r["t0"]
+            sums[1] += r["exposed_s"]
+            sums[2] += r["between_s"]
+            sums[3] += r["tick_s"]
+            for site, p in r["programs"].items():
+                total = self.programs[site]
+                for i in range(3):
+                    total[i] += p[i]
+
+    def snapshot(self, last: Optional[int] = None) -> Dict[str, Any]:
+        """JSON-safe: the running sums and the rows kept, oldest first
+        (``last``: no more than that many, the newest).  A row: ``n``,
+        ``t0``, ``t1``; ``between_s`` (from the end of this engine's
+        last ``step()`` call to ``t0``: the caller's time) and
+        ``tick_s`` (the control plane after ``t1``); ``k``, ``queue``
+        (as the step began), ``admitted``, ``preempted``,
+        ``boundary_tokens``; ``programs`` by site ``[programs, rows,
+        real tokens]`` (decode: ``[1, max_batch, live slots]``);
+        ``phases`` in seconds; ``exposed_s`` and ``exposed`` by phase
+        (``idle``: the stretches themselves); ``drained``: the step
+        ended with nothing queued, so the time to the next row's ``t0``
+        is the device's idle time too.  The sums (``steps``,
+        ``step_s``, ``exposed_s``, ``between_s``, ``tick_s``,
+        ``programs``) hold every row a snapshot has seen."""
+        with self._lock:
+            raw = sorted((r for r in self._ring if r is not None),
+                         key=lambda r: r[0])
+            # /statusz asks for one row a second: it works out that
+            # one and the rows since its last call, not the ring's 8,192
+            first = raw[-last][0] if last and len(raw) > last else 0
+            read = [self._as_read(r) for r in raw
+                    if r[0] >= first or r[0] > self._folded]
+            self._fold([r for r in read if r["n"] > self._folded],
+                       raw[0][0] if raw else 0)
+            rows = [r for r in read if r["n"] >= first]
+            sums = self.sums
+            return {
+                "steps": self.steps, "unseen": self._unseen,
+                "step_s": round(sums[0], 6),
+                "exposed_s": round(sums[1], 6),
+                "between_s": round(sums[2], 6),
+                "tick_s": round(sums[3], 6),
+                "programs": {k: list(v)
+                             for k, v in self.programs.items()},
+                "rows": rows,
+            }
+
+
+STEP_LEDGER = StepLedger()
+
+
+class StepRow:
+    """The row one engine has open, and its pen in the ledger.  The
+    step's own code adds to the counts as it goes; the hooks are
+    :meth:`dispatch`, called before a program is handed to the device
+    (an idle stretch ends there: the one clock read), :meth:`edge` as
+    that call returns (the program is queued: a capture shows where),
+    and ``drained``, which the engine sets to the instant a
+    device-to-host fetch returned: everything dispatched before it has
+    run, so until the next dispatch the device has nothing queued.
+    ``drained`` outlives the step: a step that ends on a fetch leaves
+    the next one idle from its first instant.  Nothing here is worked
+    out: a step's host time is the tail cell's latency, and a row is
+    stored raw."""
+
+    __slots__ = ("_ring", "_next", "_mark", "_step", "_tick", "_phases",
+                 "n", "queue", "admitted", "preempted", "boundary",
+                 "dispatched", "idle", "drained", "_end")
+
+    def __init__(self, namespace: str, step, tick, phases,
+                 ledger: StepLedger = STEP_LEDGER):
+        """``step``, ``tick`` and ``phases`` (in :data:`STEP_PHASES`'
+        order) are the engine's spans: they hold the clock readings a
+        row is made of."""
+        self._ring = ledger._ring
+        self._next = ledger._ordinals.__next__
+        self._mark = f"{namespace}/dispatch"
+        self._step, self._tick, self._phases = step, tick, tuple(phases)
+        self.drained: Optional[float] = None    # unknown until a fetch
+        self._end: Optional[float] = None
+        self.n = -1             # no step yet
+        self.queue = self.admitted = self.preempted = self.boundary = 0
+        self.dispatched: List[Tuple[str, int, int]] = []
+        self.idle: List[Tuple[float, float]] = []
+
+    # dstpu: hot-path
+    def begin(self, queue: int) -> int:
+        """A step begins: its process-wide ordinal (what
+        ``dstpu/serving_step`` is annotated with)."""
+        self.n = n = self._next()
+        self.queue = queue
+        return n
+
+    # dstpu: hot-path
+    def dispatch(self, site: str, rows: int, tokens: int) -> None:
+        """Before the call that hands the device a program of ``rows``
+        rows, ``tokens`` of them real."""
+        since = self.drained
+        if since is not None:
+            self.idle.append((since, time.perf_counter()))
+            self.drained = None
+        self.dispatched.append((site, rows, tokens))
+
+    # dstpu: hot-path
+    def edge(self) -> None:
+        """As that call returns: the device has the program, the host
+        is about to wait for it or to prepare the next one."""
+        site, rows, tokens = self.dispatched[-1]
+        telemetry_mark(self._mark, site=site, rows=rows, tokens=tokens)
+
+    # dstpu: hot-path
+    def end(self, k: int) -> None:
+        """The step and its tick ended: the row is stored, and what is
+        counted from here on (a test's or a router's ``_admit_one``
+        between steps) falls to the next."""
+        n, step, tick = self.n, self._step, self._tick
+        self._ring[n % len(self._ring)] = (
+            n, step.t0, step.t1, self._end, tick.t0, tick.t1, k,
+            self.queue, self.admitted, self.preempted, self.boundary,
+            self.drained, self.dispatched, self.idle,
+            [(sp.t0, sp.t1) for sp in self._phases])
+        self._end = tick.t1
+        self.admitted = self.preempted = self.boundary = 0
+        self.dispatched, self.idle = [], []
 
 
 class ProgramSpan:

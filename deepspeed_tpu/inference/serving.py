@@ -95,8 +95,9 @@ from deepspeed_tpu.config import (KERNELS_BLOCK_GONE, CommConfig,
                                   SLOConfig, SpeculativeConfig,
                                   TelemetryConfig, TracingConfig,
                                   ZeroInferenceConfig)
-from deepspeed_tpu.devprof import (BUILD_LEDGER, NULL_DEVPROF, BuildCounters,
-                                   DevProf, ProgramSpan)
+from deepspeed_tpu.devprof import (BUILD_LEDGER, NULL_DEVPROF, STEP_LEDGER,
+                                   BuildCounters, DevProf, ProgramSpan,
+                                   StepRow)
 from deepspeed_tpu.faults import ChecksumError, FaultPlan, InjectedFault
 from deepspeed_tpu.history import NULL_HISTORY, MetricHistory
 from deepspeed_tpu.incidents import NULL_INCIDENTS, IncidentManager
@@ -753,6 +754,10 @@ class ServingEngine:
         self._sp_tick = r.span(
             "serving_tick", "exporter / SLO / history / incident pass "
             "after the iteration")
+        # the step ledger's open row (deepspeed_tpu.devprof): what a
+        # step counts and where the device had nothing queued, written
+        # once as the step ends; with telemetry off there is none
+        self._row = self._step_row() if self._tel_on else None
         self._h_queue_wait = r.histogram(
             "serving_queue_wait_seconds",
             "arrival -> admitted to a slot: the time work waited",
@@ -946,17 +951,9 @@ class ServingEngine:
         self._g_kvt_inflight = r.gauge(
             "kv_tier_promoting_pages",
             "pages with a tier promotion in flight right now")
-        # what the decode sweeps and the samplers dispatched, and what
-        # a mesh took from the build's readers: /statusz "kernels" names
-        # the readers themselves
-        self._c_kdisp_paged = r.counter(
-            "serving_kernel_dispatch_paged",
-            "decode sweeps dispatched (the reader: /statusz "
-            "kernels.decode)")
-        self._c_kdisp_sample = r.counter(
-            "serving_kernel_dispatch_sample",
-            "batched sampling dispatches (decode-chunk syncs + "
-            "prefill-boundary flushes)")
+        # what a mesh took from the build's readers: /statusz
+        # "kernels" names the readers themselves, and what a step
+        # dispatched is in the step ledger's rows (/statusz "steps")
         self._c_kernel_fb = r.counter(
             "serving_kernel_fallbacks",
             "kernels of the family's that the build's mesh took (the "
@@ -2011,9 +2008,13 @@ class ServingEngine:
             toks[0, :T] = req.tokens
             view = self._row_view(self._table_host[b:b + 1], 0, b)
             self._c_state_fresh.inc(self._state_row is not None)
+            if self._tel_on:
+                self._row.dispatch("prefill", end, T)
             row, view = self._prefill(
                 self.params, self._put(toks), view,
                 self._put(np.full((1,), T - 1, np.int32)))
+            if self._tel_on:
+                self._row.edge()
             self._rows_pending += end
             self.cache = self._adopt(view)
 
@@ -2069,7 +2070,10 @@ class ServingEngine:
         that already produced a token is not work newly waiting) and
         mark the edge in a capture under the request's id."""
         self._c_admitted.inc()
-        if self._tel_on and req.t_submit is not None:
+        if not self._tel_on:
+            return
+        self._row.admitted += 1
+        if req.t_submit is not None:
             self._h_queue_wait.observe(
                 time.perf_counter() - req.t_arrival)
             telemetry_mark(self._mark_admitted,
@@ -2587,9 +2591,13 @@ class ServingEngine:
         # a chunk that starts at position 0 starts the slot's recurrent
         # state from zero, whatever the slot held
         self._c_state_fresh.inc(self._state_row is not None and done == 0)
+        if self._tel_on:
+            self._row.dispatch("chunk", C, take)
         row, view = self._chunk_prefill(
             self.params, self._put(toks), view,
             self._put(np.full((1,), take - 1, np.int32)))
+        if self._tel_on:
+            self._row.edge()
         self._rows_pending += C
         self.cache = self._adopt(view)
         s.prefill_done = done + take
@@ -2651,6 +2659,8 @@ class ServingEngine:
             traced=req.traced, first_token_seen=req.first_token_seen,
             t_arrival=req.t_arrival, tier=req.tier))
         self._c_preempted.inc()
+        if self._tel_on:
+            self._row.preempted += 1
         if req.traced:
             self.tracer.event("requeue", req.req_id)
 
@@ -2672,7 +2682,6 @@ class ServingEngine:
                               np.int32)),
             self._put(np.full((1,), slot.req.temperature, np.float32)))
         self._pending_boundary.append((b, tok))
-        self._c_kdisp_sample.inc()
 
     # dstpu: hot-path
     def _flush_boundary(self) -> None:
@@ -2684,6 +2693,11 @@ class ServingEngine:
         # the device when its prefill was dispatched; nothing is
         # dispatched here)
         toks = jax.device_get([tok for _, tok in pend])
+        if self._tel_on:
+            # every program dispatched so far has run: the device has
+            # nothing queued until the next dispatch call
+            self._row.drained = time.perf_counter()
+            self._row.boundary += len(pend)
         self._c_boundary_syncs.inc()
         self._c_boundary_tokens.inc(len(pend))
         for (b, _), tok in zip(pend, toks):
@@ -2782,6 +2796,19 @@ class ServingEngine:
                 self._table_dirty = True
 
     # ------------------------------------------------------------------ step
+    def _step_row(self) -> StepRow:
+        """This engine's pen in the step ledger: a row's phases are
+        these spans' own clock readings, in ``devprof.STEP_PHASES``'
+        order.  (Not inline in the constructor: its frame's words are
+        pinned, see ``tests/test_devprof.py``.)"""
+        return StepRow(self.registry.namespace, self._sp_step,
+                       self._sp_tick, (
+                           self._sp_admit, self._sp_prefill,
+                           self._sp_boundary, self._sp_grow,
+                           self._sp_upload, self._sp_inputs,
+                           self._sp_dispatch, self._sp_token_sync,
+                           self._sp_append))
+
     def step(self) -> List[Any]:
         """One scheduling iteration: admit → batched decode.  Returns
         request ids that finished during this step."""
@@ -2790,11 +2817,13 @@ class ServingEngine:
         if self._tel_on:
             # span: wall time into serving_step_seconds + a
             # TraceAnnotation so captured device timelines show the
-            # scheduler iteration
-            with self._sp_step:
+            # scheduler iteration, under the ordinal its row in the
+            # step ledger has
+            with self._sp_step(n=self._row.begin(len(self.queue))):
                 self._step_inner()
             with self._sp_tick:
                 self._tick()
+            self._row.end(self.decode_chunk)
         else:
             self._step_inner()
             if self._tick_inline:
@@ -2900,6 +2929,9 @@ class ServingEngine:
                     self._put(toks), self._next_dispatch(),
                     self._put(temps))
             with self._sp_dispatch:
+                if self._tel_on:
+                    self._row.dispatch("decode", self.max_batch,
+                                       len(active))
                 out, self.cache = self._decode_chunk_fn(
                     self.params, toks_d, self.cache, self._key,
                     ordinal_d, temps_d)
@@ -2910,17 +2942,23 @@ class ServingEngine:
                     s.seq_len += K
                 self._c_decode_steps.inc(K)
                 self._c_decode_syncs.inc()
-                self._c_kdisp_paged.inc()
-                self._c_kdisp_sample.inc(K)
                 if self._state_row is not None:
                     self._c_state_masked.inc(
                         K * (self.max_batch - len(active)))
+                if self._tel_on:
+                    # the device has its program: what the host does
+                    # from here to the fetch is in its shadow
+                    self._row.edge()
             with self._sp_token_sync:
                 # dstpu: host-sync-ok: the ONE device→host transfer per
                 # decode chunk (K tokens per sync — the module contract)
                 host_toks = np.asarray(out)
                 if self._n_expert_rows:
                     host_toks = self._take_expert_rows(host_toks, K)
+            if self._tel_on:
+                # the fetch returned as the span ended: nothing is
+                # queued from its own clock reading on
+                self._row.drained = self._sp_token_sync.t1
             with self._sp_append:
                 if self._trace_on and any(
                         s.req.traced for _, s in active):
@@ -3052,12 +3090,17 @@ class ServingEngine:
         with self._sp_upload:
             self._upload_dirty()
         with self._sp_dispatch:
+            if self._tel_on:
+                self._row.dispatch("sweep", Bm * (K + 1),
+                                   len(active) + drafted)
             logits, self.cache = self._verify_chunk(
                 self.params, self._put(toks), self.cache)
             n_acc_d, stop_d = verify_accept(
                 logits, self._put(drafts), self._put(dlens),
                 self._key, self._next_dispatch(),
                 self._put(temps))
+            if self._tel_on:
+                self._row.edge()
         with self._sp_token_sync:
             if traced_any:
                 self.tracer.event("spec_verify", attrs={
@@ -3066,6 +3109,8 @@ class ServingEngine:
             # verify sweep (accepted lengths + stop tokens for the
             # whole batch)
             n_acc, stop = jax.device_get((n_acc_d, stop_d))
+        if self._tel_on:
+            self._row.drained = self._sp_token_sync.t1
         with self._sp_append:
             self._spec_accept(active, n_acc, stop, drafts, dlens,
                               traced_any)
@@ -3078,7 +3123,6 @@ class ServingEngine:
         K = self.speculative.draft_tokens
         Bm = self.max_batch
         self._c_decode_syncs.inc()
-        self._c_kdisp_paged.inc()   # the verify sweep IS a paged dispatch
         self._c_decode_steps.inc(K + 1)
         self._c_spec_sweeps.inc()
         if self._tel_on:
@@ -3334,6 +3378,9 @@ class ServingEngine:
             # what making the process's programs ready cost (the
             # process-wide build ledger, its newest entries)
             "build": BUILD_LEDGER.snapshot(last=64),
+            # what the steps of the process's engines did (the step
+            # ledger's running totals and its newest row)
+            "steps": STEP_LEDGER.snapshot(last=1),
             # the BOUND port (meaningful when http_port=0 asked for an
             # ephemeral bind): how a parent process that spawned this
             # replica learns where to scrape it

@@ -27,7 +27,13 @@ now flow through:
   ``serving_queue_wait_seconds`` (arrival to admitted) is a plain
   histogram.  :func:`mark` is the zero-length annotation for an edge
   that exists once (``dstpu/request_admitted``,
-  ``dstpu/request_first_token``, ``dstpu/xla_compile``).
+  ``dstpu/request_first_token``, ``dstpu/xla_compile``, and
+  ``dstpu/dispatch`` with ``site=``, ``rows=``, ``tokens=`` where a
+  step hands the device a program).  A span keeps its last two clock
+  readings, and ``serving_step`` is entered with ``n=<ordinal>``: the
+  step ledger (``deepspeed_tpu.devprof.STEP_LEDGER``) keeps every
+  step's phases from them for the whole run, where a capture holds
+  seconds.
 - Three sinks: a periodic bridge into the existing
   :class:`~deepspeed_tpu.monitor.MonitorMaster`
   (tensorboard/wandb/csv/comet), a Prometheus text-exposition writer
@@ -233,15 +239,19 @@ class Span:
     through :meth:`MetricsRegistry.span` and ``with`` it every step, so
     a step pays no registry lock and no string formatting.  Called
     with keywords (``with span(site="prefill"):``) it annotates them.
+    It keeps the two ``perf_counter`` readings of its last use
+    (``t0``, ``t1``): the step ledger collects a step's phases from
+    its spans and reads no clock of its own for them.
     """
 
-    __slots__ = ("_hist", "_label", "_ann", "_t0", "_kw")
+    __slots__ = ("_hist", "_label", "_ann", "_kw", "t0", "t1")
 
     def __init__(self, hist: Histogram, label: str):
         self._hist = hist
         self._label = label
         self._ann = None
         self._kw = {}
+        self.t0 = self.t1 = 0.0
 
     def __call__(self, **kw):
         self._kw = kw
@@ -252,11 +262,12 @@ class Span:
 
         self._ann = jax.profiler.TraceAnnotation(self._label, **self._kw)
         self._ann.__enter__()
-        self._t0 = time.perf_counter()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._hist.observe(time.perf_counter() - self._t0)
+        self.t1 = time.perf_counter()
+        self._hist.observe(self.t1 - self.t0)
         self._ann.__exit__(*exc)
         return False
 

@@ -13,12 +13,16 @@ import jax.numpy as jnp
 
 import deepspeed_tpu
 from benchmark.harness import scopes, trace
+from deepspeed_tpu.devprof import STEP_LEDGER, STEP_SITES
 from deepspeed_tpu.inference.kernels import PagedKVCache
 from deepspeed_tpu.inference.serving import serving_engine
 from deepspeed_tpu.models import gpt2, laguna, mixtral
 
 CHILDREN = ("admit", "prefill", "boundary", "grow_pages", "upload",
             "inputs", "dispatch", "token_sync", "append")
+# zero-length marks inside a step: edges, not phases
+EDGES = ("dstpu/request_admitted", "dstpu/request_first_token",
+         "dstpu/dispatch")
 ENGINE_KW = dict(max_batch=2, page_size=8, num_pages=32, max_seq=64,
                  prefill_bucket=8)
 PROMPTS = {"a": ([5, 9, 2], 6), "b": ([17, 3, 3, 8, 1], 5),
@@ -121,7 +125,7 @@ def test_serving_spans_nest_in_the_step_and_tile_it(capture):
         scopes.Scoped({}, {}, mine), "dstpu/serving_step")
     assert len(per_step) == steps
     for parent, kids in per_step:
-        names = [k.name for k in kids if "request_" not in k.name]
+        names = [k.name for k in kids if k.name not in EDGES]
         # one span a phase a step: none repeated per slot or per token
         assert len(names) == len(set(names))
         assert set(names) <= {f"dstpu/serving_{c}" for c in CHILDREN}
@@ -144,6 +148,47 @@ def test_step_phases_add_up_to_the_step(capture):
                                    beside=["dstpu/serving_tick"])
     assert all(a >= b for a, b in zip(
         with_tick, scopes.step_phases(mine, ["dstpu/serving_append"])))
+
+
+def test_a_captured_step_is_its_row_in_the_ledger(capture):
+    """``dstpu/serving_step`` carries the ordinal of its row in the
+    step ledger, and every dispatch of the step is an edge inside it
+    under its site, with the rows and the tokens the row counts: a
+    capture and the ledger are read side by side, by name."""
+    scoped, steps, _ = capture
+    mine = scopes.Scoped({}, {}, _inside(scoped, "bench/on"))
+    per_step = scopes.children(mine, "dstpu/serving_step")
+    ordinals = [int(parent.stats["n"]) for parent, _ in per_step]
+    assert len(ordinals) == steps
+    assert ordinals == list(range(ordinals[0], ordinals[0] + steps))
+    rows = {r["n"]: r for r in STEP_LEDGER.snapshot()["rows"]}
+    offsets = []
+    for (parent, kids), n in zip(per_step, ordinals):
+        row = rows[n]
+        seen = {}
+        for k in kids:
+            if k.name == "dstpu/dispatch":
+                p = seen.setdefault(k.stats["site"], [0, 0, 0])
+                p[0] += 1
+                p[1] += int(k.stats["rows"])
+                p[2] += int(k.stats["tokens"])
+        assert seen == {s: p for s, p in row["programs"].items() if p[0]}
+        assert set(seen) <= set(STEP_SITES)
+        # the decode's edge lies in its phase, a prefill's in admit
+        for k in kids:
+            if k.name == "dstpu/dispatch":
+                phase = {"decode": "dispatch", "prefill": "admit",
+                         "chunk": "prefill"}[k.stats["site"]]
+                (box,) = [c for c in kids
+                          if c.name == f"dstpu/serving_{phase}"]
+                assert box.start <= k.start <= box.start + box.dur
+        # one clock beside the other: the span and the row are the
+        # same interval, a constant apart
+        assert parent.dur == pytest.approx(row["t1"] - row["t0"], abs=2e-4)
+        offsets.append(parent.start - row["t0"])
+    assert "decode" in {k.stats.get("site") for _, kids in per_step
+                        for k in kids}
+    assert max(offsets) - min(offsets) < 1e-3
 
 
 def test_a_request_is_marked_once_at_each_edge_under_its_id(capture):

@@ -662,15 +662,19 @@ class TestEnginePolicy:
             assert reader == "xla" or reader.startswith("dstpu_"), kz
             assert kz[row]["reason"]
         assert (kz["state_step"], kz["fallbacks"]) == ("xla", [])
-        # one fixed name a counter: the reader is /statusz's to name
+        # the reader is /statusz's to name; what the steps dispatched
+        # is the step ledger's to count (/statusz "steps"), beside the
+        # fetches the registry counts
         cnt = eng.registry.snapshot()["counters"]
         names = sorted(n for n in cnt if n.startswith("serving_kernel_"))
-        assert names == ["serving_kernel_dispatch_paged",
-                         "serving_kernel_dispatch_sample",
-                         "serving_kernel_fallbacks"]
-        assert cnt["serving_kernel_dispatch_paged"] > 0
-        assert cnt["serving_kernel_dispatch_sample"] > 0
+        assert names == ["serving_kernel_fallbacks"]
         assert cnt["serving_kernel_fallbacks"] == 0
+        steps = eng.statusz()["steps"]
+        assert steps["programs"]["decode"][0] \
+            >= cnt["serving_decode_syncs"] > 0
+        assert steps["programs"]["prefill"][0] >= len(PROMPTS)
+        (last,) = steps["rows"]
+        assert last["programs"]["decode"] == [1, KW["max_batch"], 1]
         eng.shutdown()
 
     def test_zero_inference_rejects_quantized_resident(

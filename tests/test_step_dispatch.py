@@ -6,6 +6,8 @@ fetch the host runs no eager ``jnp`` / ``jax.random`` / device-indexing
 operation."""
 
 import contextlib
+import gc
+import time
 
 import numpy as np
 import pytest
@@ -13,6 +15,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.devprof import (STEP_LEDGER, STEP_PHASES, StepLedger,
+                                   StepRow)
 from deepspeed_tpu.inference.generation import paged_generator
 from deepspeed_tpu.inference.serving import (RequestFailed, _last_row,
                                              _sample_rows,
@@ -164,6 +168,256 @@ def test_a_step_dispatches_its_programs_and_nothing_else(model, drive, kw):
         want = drive(eng, log)
         got = list(log)
     assert {n: got.count(n) for n in set(got)} == want, got
+
+
+# ---------------------------------------- (a') the step ledger's rows
+def ledger_rows(ordinals):
+    """The ledger's rows of the steps whose ordinals are given (the
+    ledger is the process's: other tests' engines wrote to it too)."""
+    rows = {r["n"]: r for r in STEP_LEDGER.snapshot()["rows"]}
+    return [rows[n] for n in ordinals]
+
+
+def stepped(eng, steps=None, sleep=0.0):
+    """Step ``eng`` (until it has no work), and the ordinals its steps
+    were given."""
+    ordinals = []
+    while eng.has_work if steps is None else len(ordinals) < steps:
+        if sleep and ordinals:
+            time.sleep(sleep)
+        eng.step()
+        ordinals.append(eng._row.n)
+    return ordinals
+
+
+SITE_OF = {"dstpu_prefill": "prefill", "dstpu_chunk": "chunk",
+           "dstpu_decode": "decode", "dstpu_verify": "sweep"}
+
+
+def _rows_admit_two(eng):
+    return {"prefill": [2, 16, 8], "decode": [1, 4, 2]}
+
+
+def _rows_chunk_end(eng):
+    return {"chunk": [1, 8, 5], "decode": [1, 4, 1]}    # 21 = 8 + 8 + 5
+
+
+def _rows_plain(eng):
+    # a sweep scores draft_tokens + 1 positions a slot: the token fed
+    # back and what the drafter proposed
+    return ({"sweep": [1, 4 * 4, 1 + eng._c_spec_drafted.value
+                       - eng._drafted_before]} if eng._spec_on
+            else {"decode": [1, 4, 1]})
+
+
+@pytest.mark.parametrize("drive,kw,want", [
+    (_admit_two, {}, _rows_admit_two),
+    (_chunk_end, {"prefill_chunk": 8}, _rows_chunk_end),
+    (_plain, {}, _rows_plain),
+    (_plain, {"speculative": {"draft_tokens": 3}}, _rows_plain)],
+    ids=["admits_two", "finishes_a_chunked_prefill", "plain_decode",
+         "speculative_sweep"])
+def test_a_row_counts_what_the_step_dispatched(model, drive, kw, want):
+    """The row of the step that is read says what the dispatch log saw:
+    programs by site, and with them the rows each ran and the real
+    tokens among them."""
+    cfg, params = model
+    with dispatch_log() as log:
+        eng = serving_engine(params, cfg, telemetry=True, **KW, **kw)
+        for p in PROMPTS:
+            eng.submit(("warm", len(p)), p, max_new_tokens=3)
+        eng.run()
+        step = eng.step
+
+        def noting_the_drafts():
+            eng._drafted_before = eng._c_spec_drafted.value
+            return step()
+
+        eng.step = noting_the_drafts
+        drive(eng, log)
+        got = list(log)
+    (row,) = ledger_rows([eng._row.n])
+    seen = {}
+    for name in got:
+        if name in SITE_OF:
+            seen[SITE_OF[name]] = seen.get(SITE_OF[name], 0) + 1
+    programs = {site: p for site, p in row["programs"].items() if p[0]}
+    assert {site: p[0] for site, p in programs.items()} == seen
+    assert programs == want(eng)
+    # a prefill that completed fetched its boundary token in this step
+    assert row["boundary_tokens"] == got.count("dstpu_boundary")
+    assert row["admitted"] == got.count("dstpu_prefill")
+    assert row["k"] == eng.decode_chunk and row["preempted"] == 0
+
+
+def test_a_step_writes_one_row_and_none_with_telemetry_off(model):
+    cfg, params = model
+    eng = serving_engine(params, cfg, telemetry=True, prefill_chunk=8,
+                         **KW)
+    for i, p in enumerate(PROMPTS):
+        eng.submit(i, p, max_new_tokens=4)
+    before = STEP_LEDGER.snapshot(last=1)["steps"]
+    ordinals = stepped(eng)
+    assert STEP_LEDGER.snapshot(last=1)["steps"] - before == len(ordinals)
+    assert ordinals == sorted(set(ordinals))
+    rows = ledger_rows(ordinals)
+    assert [r["queue"] for r in rows][:2] == [len(PROMPTS), 0]
+    assert sum(r["admitted"] for r in rows) == len(PROMPTS)
+    assert sum(r["boundary_tokens"] for r in rows) == len(PROMPTS)
+    c = counters(eng)
+    # what the two counters that went said, the rows say
+    assert sum(r["programs"]["decode"][0] for r in rows) \
+        == c["serving_decode_syncs"]
+    assert sum(r["programs"]["chunk"][0] for r in rows) \
+        == c["serving_prefill_chunks"]
+    assert sum(r["boundary_tokens"] > 0 for r in rows) \
+        == c["serving_boundary_syncs"]
+    # the phases tile the step: theirs are the spans' own readings
+    for r in rows:
+        assert r["t0"] < r["t1"]
+        assert set(r["phases"]) <= set(STEP_PHASES)
+        assert sum(r["phases"].values()) <= r["t1"] - r["t0"]
+    whole = sum(r["t1"] - r["t0"] for r in rows)
+    assert sum(sum(r["phases"].values()) for r in rows) >= 0.99 * whole
+    hist = eng.registry.snapshot()["histograms"]
+    assert hist["serving_step_seconds"]["sum"] == pytest.approx(whole)
+    # /statusz: the running totals and the newest row
+    block = eng.statusz()["steps"]
+    assert [r["n"] for r in block["rows"]] == ordinals[-1:]
+    assert block["steps"] == STEP_LEDGER.steps and block["unseen"] == 0
+    assert block["programs"]["chunk"][2] >= sum(len(p) for p in PROMPTS)
+    # with telemetry off: no row, no pen, nothing written
+    off = serving_engine(params, cfg, telemetry=False, prefill_chunk=8,
+                         **KW)
+    for i, p in enumerate(PROMPTS):
+        off.submit(i, p, max_new_tokens=4)
+    off.run()
+    assert off._row is None
+    assert STEP_LEDGER.snapshot(last=1)["steps"] == block["steps"]
+    assert "serving_step_seconds" not in \
+        off.registry.snapshot()["histograms"]
+
+
+def test_exposed_seconds_follow_a_fetch_and_end_at_a_dispatch(gpt2_model):
+    """The device provably has nothing queued from the return of a
+    fetch to the next dispatch call, and only then."""
+    cfg, params = gpt2_model
+    eng = serving_engine(params, cfg, telemetry=True, prefill_chunk=8,
+                         **KW)
+    eng.submit("long", PROMPTS[3], max_new_tokens=6)    # three chunks
+    first, second, third, fourth = ledger_rows(stepped(eng, 4))
+    # nothing was fetched before the first chunk went out, nor before
+    # the second: no instant of these steps is known to be idle
+    for row in (first, second):
+        assert row["programs"]["chunk"][0] == 1
+        assert row["programs"]["decode"][0] == 0
+        assert (row["exposed_s"], row["exposed"], row["drained"]) \
+            == (0.0, {}, False)
+    # the third ends the prompt: its boundary token is fetched, and
+    # from there to the decode dispatch the device waits for the host
+    assert third["boundary_tokens"] == 1 and third["drained"]
+    assert set(third["exposed"]) == {"boundary", "grow_pages", "upload",
+                                     "inputs", "dispatch", "append"}
+    assert "admit" not in third["exposed"]
+    assert "token_sync" not in third["exposed"]
+    # the fourth begins drained: idle from its first instant to its
+    # decode dispatch, then again from the token fetch to its end
+    assert set(fourth["exposed"]) >= {"admit", "prefill", "boundary",
+                                      "grow_pages", "inputs", "append"}
+    assert "token_sync" not in fourth["exposed"]
+    for row in (third, fourth):
+        assert row["exposed_s"] == pytest.approx(
+            sum(b - a for a, b in row["idle"]))
+        assert 0 < sum(row["exposed"].values()) <= row["exposed_s"] \
+            < row["t1"] - row["t0"]
+        for name, seconds in row["exposed"].items():
+            assert seconds <= row["phases"][name] + 1e-9
+    # a chunk that goes out while a slot decodes ends the idle stretch
+    # in the prefill phase: nothing after it is exposed until the fetch
+    eng.submit("next", PROMPTS[3], max_new_tokens=3)
+    (row,) = ledger_rows(stepped(eng, 1))
+    assert row["programs"]["chunk"][0] == row["programs"]["decode"][0] == 1
+    assert set(row["exposed"]) == {"admit", "prefill", "append"}
+
+
+def test_what_is_dispatched_between_steps_falls_to_the_next_row(gpt2_model):
+    """A router or a test may admit between steps: the last row is
+    written and keeps what it had; the next one counts the program,
+    whose work its decode waits behind."""
+    cfg, params = gpt2_model
+    eng = serving_engine(params, cfg, telemetry=True, **KW)
+    eng.submit("a", PROMPTS[0], max_new_tokens=6)
+    (n,) = stepped(eng, 1)
+    (before,) = ledger_rows([n])
+    assert before["drained"] and before["admitted"] == 1
+    eng.submit("b", PROMPTS[1], max_new_tokens=6)
+    assert eng._admit_one()                 # a prefill goes out
+    eng._flush_boundary()
+    (after,) = ledger_rows([n])
+    assert after == before
+    (row,) = ledger_rows(stepped(eng, 1))
+    assert row["admitted"] == 1 and row["boundary_tokens"] == 1
+    assert row["programs"]["prefill"] == [1, 8, 5]
+    assert row["programs"]["decode"] == [1, 4, 2]
+    # the stretch that prefill ended began in the row before: this one
+    # is idle from the boundary fetch (before it began) to its decode
+    assert "admit" in row["exposed"] and row["idle"][0][0] == row["t0"]
+
+
+def test_between_is_the_callers_time(gpt2_model):
+    cfg, params = gpt2_model
+    eng = serving_engine(params, cfg, telemetry=True, **KW)
+    eng.submit("a", PROMPTS[0], max_new_tokens=6)
+    rows = ledger_rows(stepped(eng, 3, sleep=0.05))
+    assert rows[0]["between_s"] == 0.0          # nothing came before it
+    for prev, row in zip(rows, rows[1:]):
+        assert 0.05 <= row["between_s"] < 0.5
+        # from the end of the last call's tick to this step's start
+        assert row["between_s"] == pytest.approx(
+            row["t0"] - prev["t1"] - prev["tick_s"], abs=1e-3)
+        assert row["between_s"] < row["t0"] - prev["t1"]
+
+
+def test_the_ring_drops_the_oldest_and_outlives_the_engine(gpt2_model):
+    cfg, params = gpt2_model
+    small = StepLedger(capacity=4)
+    eng = serving_engine(params, cfg, telemetry=True, **KW)
+    polled = StepLedger(capacity=4)     # read every step: /statusz's way
+    other = serving_engine(params, cfg, telemetry=True, **KW)
+    row = other._row
+    other._row = StepRow("dstpu", row._step, row._tick, row._phases,
+                         ledger=polled)
+    other.submit("a", PROMPTS[0], max_new_tokens=12)
+    while other.has_work:
+        other.step()
+        polled.snapshot(last=1)
+    seen = polled.snapshot(last=1)
+    assert (seen["steps"], seen["unseen"]) == (11, 0)
+    assert seen["programs"]["decode"] == [11, 44, 11]
+    assert seen["programs"]["prefill"] == [1, 8, 3]
+    row = eng._row
+    eng._row = StepRow("dstpu", row._step, row._tick, row._phases,
+                       ledger=small)
+    eng.submit("a", PROMPTS[0], max_new_tokens=12)
+    ordinals = stepped(eng)
+    # the first step fetches the boundary token and a decoded one
+    assert len(ordinals) == 11 and ordinals[0] == 0
+    eng.shutdown()
+    del eng
+    gc.collect()
+    snap = small.snapshot()
+    assert [r["n"] for r in snap["rows"]] == ordinals[-4:]
+    # a reader folds what it sees into the sums (the hot path keeps
+    # none): this one came after the ring had turned
+    assert (snap["steps"], snap["unseen"]) == (4, 7)
+    assert snap["programs"]["decode"] == [4, 16, 4]
+    assert snap["step_s"] > snap["exposed_s"] > 0
+    again = small.snapshot(last=2)
+    assert [r["n"] for r in again["rows"]] == ordinals[-2:]
+    assert (again["steps"], again["step_s"]) == (4, snap["step_s"])
+    # the process's own ledger is sized for a whole window at the
+    # shortest step: ~110 steps a second for over a minute
+    assert len(STEP_LEDGER._ring) >= 110 * (8 + 51)
 
 
 # ------------------------------------------ (b) the same greedy streams
