@@ -226,6 +226,73 @@ def state_chunk_check(seed, on_tpu):
          rel_err_by_norm=err, tolerance=tol, masked_chunk="bit for bit")
 
 
+# decode shapes of the cells whose programs run ``dstpu_paged_decode``:
+# (slots, query heads, K/V heads, table entries)
+DECODE_ROW_SHAPES = {
+    "gpt2 chat-0.8knee": (28, 16, 16, 64),
+    "mixtral chat-sat": (64, 32, 8, 64),
+    "laguna code-sat": (8, 48, 8, 128),
+}
+
+
+def decode_row_check(seed, on_tpu):
+    """A decode step's new K/V row through ``dstpu_paged_decode`` (it
+    attends to the row from VMEM and copies it to its page by the tile of
+    8 packed rows, which interpret mode cannot judge) against the row
+    scatter and the gather, bfloat16 pages of 16 x 128 at three cells'
+    shapes (toys in interpret mode off the chip): rows that open a page,
+    fill one, stand at capacity, idle slots on the trash page.  Both
+    pools bit for bit (the trash page's row 0 is any idle slot's), the
+    attention of the live rows within bfloat16's 3e-2."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.inference import kernels as K
+
+    shapes = DECODE_ROW_SHAPES if on_tpu else {"toy": (6, 6, 2, 4)}
+    fused = functools.partial(K.paged_decode_attention_v2,
+                              interpret=not on_tpu)
+    worst, tol = {}, 3e-2
+    for name, (B, H, KV, mp) in shapes.items():
+        rng = np.random.default_rng(seed)
+        P, cap = B * mp + 1, mp * 16
+        keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+        bf = lambda key, *shape: jax.random.normal(key, shape, jnp.bfloat16)
+        k, v = (bf(key, 3, KV, P, 16, 128) for key in keys[:2])
+        q, nk, nv = bf(keys[2], B, H, 128), bf(keys[3], B, KV, 128), \
+            bf(keys[4], B, KV, 128)
+        lens = rng.integers(1, cap, B).astype(np.int32)
+        lens[:6] = [0, cap, 16, cap - 1, 0, 15]
+        table = rng.permutation(P - 1)[:B * mp].reshape(B, mp)
+        table[lens == 0] = P - 1                    # idle: the trash page
+        table, n = jnp.asarray(table, jnp.int32), jnp.asarray(lens)
+        rk, rv = jax.jit(lambda k, v: K.write_token_pages(
+            k, v, 1, nk, nv, table, n))(k, v)
+        ref = jax.jit(lambda: K.paged_attention_reference(
+            q, rk, rv, table, jnp.minimum(n + 1, cap), layer=1))()
+        out, ok, ov = jax.jit(lambda k, v, layer: fused(
+            q, k, v, table, n, layer=layer, new_k=nk, new_v=nv))(
+                k, v, jnp.int32(1))
+        live = lens > 0
+        err = float(np.abs(np.asarray(out, np.float32)
+                           - np.asarray(ref, np.float32))[live].max())
+        worst[name] = err
+        for got, want, was in ((ok, rk, k), (ov, rv, v)):    # on the device
+            if not (jnp.array_equal(got[:, :, :P - 1], want[:, :, :P - 1])
+                    and jnp.array_equal(got[:, :, P - 1, 1:],
+                                        was[:, :, P - 1, 1:])):
+                raise AssertionError(f"decode row {name}: the pool differs "
+                                     "from the row scatter's")
+        if not err <= tol:
+            raise AssertionError(f"decode row {name}: {err:.3g} > {tol}")
+    emit(phase="decode_row_check", shapes={k: list(v) for k, v in
+                                           shapes.items()},
+         max_abs_err=worst, tolerance=tol, pools="bit for bit")
+
+
 # chunk shapes of the cells whose expert layers run the grouped branch:
 # (rows, k, held, experts, d, f, gated)
 HELD_FFN_SHAPES = {
@@ -663,6 +730,7 @@ def main():
             kernel_check(size, args.seed)
         state_chunk_check(args.seed, on_tpu)
         held_ffn_check(args.seed, on_tpu)
+        decode_row_check(args.seed, on_tpu)
         train_phase(size, args.seed, cache)
         gc.collect()                  # the trainer's HBM, before the server
         serve_phase(size, args.seed, cache, on_tpu)

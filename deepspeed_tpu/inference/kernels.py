@@ -18,7 +18,8 @@ The jnp reference path (`paged_attention_reference`) materialises the
 gather and is the numerics oracle for tests/CPU.
 
 The Mosaic kernels here, by the names a capture shows: `dstpu_paged_decode`
-(live pages only, one decode step), `dstpu_paged_chunk_v2` (a chunk over
+(live pages only, one decode step, whose new K/V row it writes to its
+page), `dstpu_paged_chunk_v2` (a chunk over
 history, in blocks), `dstpu_mla_decode` (latent rows), `dstpu_state_step`
 (one token of a recurrent layer's rule on the per-slot state carried
 beside the pool, a tile at a time, in place: :func:`state_step`) and
@@ -29,6 +30,7 @@ program runs is a rule of the build (:func:`paged_reader` and its kin).
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -764,15 +766,120 @@ def _stream_live_blocks(table_ref, lens_ref, layer, streams, sem, turn,
     return carry
 
 
-def _decode_kernel(table_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                   o_ref, kb, vb, sem, turn, *, scale, ps):
+# The step's new row reaches its page by the tile: a copy into a packed
+# page addresses whole tiles of 8 rows (the chip's compiler refuses a
+# slice of one or two, "must be aligned to tiling (8)", AOT, PR 54), so a
+# row's tile is read, the row set in VMEM and the tile written back.  Both
+# copies have a latency no product hides in a row with few pages, so they
+# run ahead and behind the rows: a tile is read ``_APPEND_AHEAD`` rows
+# before its row's turn, in one of ``_APPEND_TILES`` buffers, and its
+# write-back awaited when the buffer comes round again.  GPT-2 1.3B's 24
+# layers of 28 rows, the kernel in a loop of its own (v5e, PR 54): 0.95 ms
+# with 3 rows live and 3.64 with all, where the row scatter and the reader
+# took 3.16 and 5.60; the same at 2, 4 and 8 rows ahead, and 1.12 and 3.77
+# by the page of 16 rows.
+_APPEND_TILE_ROWS = 8
+_APPEND_TILES, _APPEND_AHEAD = 4, 2
+
+
+def _row_appender(table_ref, lens_ref, layer, streams, r_sem, w_sem, *, ps):
+    """The grid's rows' new K/V rows into their pages, row
+    ``pl.program_id(0)``'s in this step.  ``streams``: (pool in HBM, the
+    same buffer as the call's result, tiles [held, KV, g, Dh] in VMEM)
+    triples; ``lens_ref``: the lengths before the write, so row r's goes
+    to position ``lens_ref[r]``, row ``pos % ps`` of page ``table[r, pos
+    // ps]``, for every kv head: the contract of :func:`_row_targets`, a
+    row at capacity (or whose entry names no page of the pool) writes
+    nothing.  Returns (ahead, write): ``ahead()`` before the row's pages
+    are swept starts the reads; ``write(news)`` after it sets ``news``
+    ([KV, 1, Dh] f32 a stream) in the row's tiles and starts them back.
+    No byte of the pool but the rows' own changes, and every copy started
+    is awaited before the grid ends (rows run in order)."""
+    b, rows, mp = pl.program_id(0), lens_ref.shape[0], table_ref.shape[1]
+    held, g = streams[0][2].shape[0], streams[0][2].shape[2]
+
+    def target(r):
+        """(row r writes its row, the page, the tile of the page's rows)."""
+        pos = lens_ref[r]
+        pid = table_ref[r, jnp.minimum(pos // ps, mp - 1)]
+        writes = (pos < mp * ps) & (pid >= 0) & (pid < streams[0][0].shape[2])
+        return writes, pid, pl.ds(pl.multiple_of(pos % ps // g * g, g), g)
+
+    def copies(r, out: bool):
+        """Row r's tile a stream, into VMEM or ``out`` of it."""
+        _, pid, at = target(r)
+        w = jax.lax.rem(r, held)
+        return [pltpu.make_async_copy(
+            *((buf.at[w], res.at[layer, :, pid, at]) if out
+              else (pool.at[layer, :, pid, at], buf.at[w])),
+            (w_sem if out else r_sem).at[w, i])
+            for i, (pool, res, buf) in enumerate(streams)]
+
+    def when_written(r, do, in_grid=None):
+        """``do()`` where row r (of the grid: ``in_grid``) writes its row."""
+        def written():
+            @pl.when(target(r)[0])
+            def _():
+                do()
+
+        written() if in_grid is None else pl.when(in_grid)(written)
+
+    start = lambda r, out: lambda: [c.start() for c in copies(r, out)]
+    wait = lambda r, out: lambda: [c.wait() for c in copies(r, out)]
+
+    def ahead():
+        def row(r, _):                      # its buffer: row r - held's
+            when_written(r - held, wait(r - held, True), r >= held)
+            when_written(r, start(r, False))
+
+        nxt = b + _APPEND_AHEAD             # the first row: those before too
+        jax.lax.fori_loop(jnp.where(b == 0, 0, nxt),
+                          jnp.minimum(nxt + 1, rows), row, None)
+
+    def write(news):
+        def patch():
+            wait(b, False)()
+            w, pos = jax.lax.rem(b, held), lens_ref[b]
+            for (_, _, buf), new in zip(streams, news):
+                row = jax.lax.broadcasted_iota(jnp.int32, buf.shape[1:], 1)
+                buf[w] = jnp.where(row == pos % g, new,
+                                   buf[w].astype(jnp.float32)
+                                   ).astype(buf.dtype)
+            start(b, True)()
+
+        when_written(b, patch)
+
+        @pl.when(b == rows - 1)             # the writes nobody awaited yet
+        def _():
+            first = max(0, rows - held)
+            jax.lax.fori_loop(
+                first, rows,
+                lambda r, _: when_written(r, wait(r, True)), None)
+
+    return ahead, write
+
+
+def _decode_kernel(table_ref, lens_ref, layer_ref, q_ref, *refs, scale, ps,
+                   append: bool):
     """One grid step a batch row.  The row's live pages stream ``ppb`` at
     a time through a double-buffered VMEM scratch (:func:`_stream_live_
     blocks`), each page's K (and V) for ALL kv heads in one strided copy
     out of the stored pool [L, KV, P, ps, Dh]; the products are batched
     over the kv heads.  Nothing past the row's ``seq_len`` is dereferenced
     and a row with ``seq_len == 0`` starts no copy and returns zeros.
-    Scores, the online softmax and the accumulator are f32."""
+    Scores, the online softmax and the accumulator are f32.
+
+    ``append``: the step's new row a slot (``nk_ref``, ``nv_ref``, in the
+    pool's dtype) is the softmax's first term, from VMEM, and goes to its
+    page beside the sweep (:func:`_row_appender`): the sweep, over the
+    ``seq_len`` keys from before the write, never expects it in HBM (a
+    row's first block is fetched by the row before it).  A row at
+    capacity attends to its pages only."""
+    if append:
+        (nk_ref, nv_ref, k_hbm, v_hbm, o_ref, ko_hbm, vo_hbm, kb, vb, sem,
+         turn, kw, vw, r_sem, w_sem) = refs
+    else:
+        k_hbm, v_hbm, o_ref, kb, vb, sem, turn = refs
     n = lens_ref[pl.program_id(0)]
     q = q_ref[0].astype(jnp.float32)                # [KV, g8, Dh]
     kv, g8, dh = q.shape
@@ -804,9 +911,23 @@ def _decode_kernel(table_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
     init = (jnp.full((kv, g8, 1), NEG_INF, jnp.float32),
             jnp.zeros((kv, g8, 1), jnp.float32),
             jnp.zeros((kv, g8, dh), jnp.float32))
+    if append:
+        ahead, write = _row_appender(
+            table_ref, lens_ref, layer_ref[0],
+            ((k_hbm, ko_hbm, kw), (v_hbm, vo_hbm, vw)), r_sem, w_sem, ps=ps)
+        ahead()
+        news = [ref[0].astype(jnp.float32) for ref in (nk_ref, nv_ref)]
+        room = n < table_ref.shape[1] * ps
+        init = (jnp.where(room, jnp.sum(q * news[0], axis=2, keepdims=True)
+                          * scale, NEG_INF),        # the row's own key: p = 1
+                jnp.where(room, 1.0, init[1]),
+                jnp.where(room, jnp.broadcast_to(news[1], init[2].shape),
+                          0.0))
     m, l, acc = _stream_live_blocks(
         table_ref, lens_ref, layer_ref[0], ((k_hbm, kb), (v_hbm, vb)), sem,
         turn, attend, init, ps=ps)
+    if append:
+        write(news)
     l = jnp.where(l == 0.0, 1.0, l)                 # empty rows → zeros
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
@@ -814,7 +935,8 @@ def _decode_kernel(table_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
 def paged_decode_attention_v2(q, k_pages, v_pages, table, seq_lens,
                               scale: Optional[float] = None,
                               pages_per_block: Optional[int] = None,
-                              interpret: bool = False, layer=None):
+                              interpret: bool = False, layer=None,
+                              new_k=None, new_v=None):
     """Paged decode attention that reads live pages only (same contract
     as :func:`paged_attention_reference`).
 
@@ -825,8 +947,17 @@ def paged_decode_attention_v2(q, k_pages, v_pages, table, seq_lens,
     slots x ``max_seq``: table entries past ``seq_len`` (stale, or not a
     page of the pool) are never dereferenced.  ``pages_per_block`` is
     derived (:func:`decode_pages_per_block`); tests pass it to put a
-    block edge where they want one."""
+    block edge where they want one.
+
+    ``new_k``/``new_v`` [B, KV, Dh]: the step's new row a slot, and
+    ``seq_lens`` the lengths BEFORE it.  The kernel writes the rows where
+    :func:`write_token_pages` would and attends to each with its row's
+    pages, as :func:`paged_attention_reference` over the written pool at
+    ``seq_lens + 1`` (a row at capacity: its pages alone, nothing
+    written).  Returns (attn, k_pages, v_pages), the pools in the
+    buffers they came in."""
     B, H, Dh = q.shape
+    one_layer = k_pages.ndim == 4
     layer, k_pages, v_pages = _as_pool(layer, k_pages, v_pages)
     _, KV, _, ps, _ = k_pages.shape
     G = H // KV
@@ -840,31 +971,48 @@ def paged_decode_attention_v2(q, k_pages, v_pages, table, seq_lens,
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g8 - G), (0, 0)))
 
     row = lambda b, *_: (b, 0, 0, 0)
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    attn = jax.ShapeDtypeStruct((B, KV, g8, Dh), q.dtype)
+    in_specs = [pl.BlockSpec((1, KV, g8, Dh), row), any_space, any_space]
+    out_specs, out_shape = pl.BlockSpec((1, KV, g8, Dh), row), attn
+    scratch = [pltpu.VMEM((2, KV, ppb, ps, Dh), k_pages.dtype),
+               pltpu.VMEM((2, KV, ppb, ps, Dh), v_pages.dtype),
+               pltpu.SemaphoreType.DMA((2, 2)),
+               pltpu.SMEM((1,), jnp.int32)]
+    append = new_k is not None
+    news, aliases = (), {}
+    if append:
+        news = tuple(n.astype(p.dtype)[:, :, None]          # [B, KV, 1, Dh]
+                     for n, p in ((new_k, k_pages), (new_v, v_pages)))
+        in_specs[1:1] = [pl.BlockSpec((1, KV, 1, Dh), row)] * 2
+        out_specs, out_shape = [out_specs, any_space, any_space], [
+            attn, *(jax.ShapeDtypeStruct(p.shape, p.dtype)
+                    for p in (k_pages, v_pages))]
+        g = math.gcd(ps, _APPEND_TILE_ROWS)
+        scratch += [pltpu.VMEM((_APPEND_TILES, KV, g, Dh), k_pages.dtype),
+                    pltpu.VMEM((_APPEND_TILES, KV, g, Dh), v_pages.dtype),
+                    pltpu.SemaphoreType.DMA((_APPEND_TILES, 2)),
+                    pltpu.SemaphoreType.DMA((_APPEND_TILES, 2))]
+        # operands 6 and 7 (behind table, seq_lens, layer, q and the rows):
+        # the pools come back in their buffers
+        aliases = {6: 1, 7: 2}
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, ps=ps),
+        functools.partial(_decode_kernel, scale=scale, ps=ps, append=append),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,   # table, seq_lens, layer
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, KV, g8, Dh), row),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, KV, g8, Dh), row),
-            scratch_shapes=[
-                pltpu.VMEM((2, KV, ppb, ps, Dh), k_pages.dtype),
-                pltpu.VMEM((2, KV, ppb, ps, Dh), v_pages.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.SMEM((1,), jnp.int32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, KV, g8, Dh), q.dtype),
+            grid=(B,), in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch),
+        out_shape=out_shape, input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(   # the rows in order
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="dstpu_paged_decode",
-    )(table, seq_lens, _layer_operand(layer), qg, k_pages, v_pages)
-    return out[:, :, :G].reshape(B, H, Dh)
+    )(table, seq_lens, _layer_operand(layer), qg, *news, k_pages, v_pages)
+    if not append:
+        return out[:, :, :G].reshape(B, H, Dh)
+    out, *pools = out
+    return (out[:, :, :G].reshape(B, H, Dh),
+            *(p[0] if one_layer else p for p in pools))
 
 
 # ------------------------------------------ blocked chunk reader (v2)
@@ -1342,10 +1490,11 @@ def paged_reader(*, decode: bool, tp: bool, interpret: bool, quant: bool,
 
     It answers from the phase, the layout and the shapes: on one device
     over float pages a decode program (``T == 1``) reads live pages only
-    (``dstpu_paged_decode``, :func:`paged_decode_attention_v2`), and so
-    does a continuation program of whole 128-row blocks of ``tokens``
-    with heads of whole 128-lane tiles, each block of queries up to its
-    own frontier (``dstpu_paged_chunk_v2``,
+    and writes the step's row itself (``dstpu_paged_decode``,
+    :func:`paged_decode_attention_v2`: no row scatter runs before it),
+    and a continuation program of whole 128-row blocks of ``tokens``
+    with heads of whole 128-lane tiles reads so too, each block of
+    queries up to its own frontier (``dstpu_paged_chunk_v2``,
     :func:`paged_chunk_attention_v2`); the gather's cost follows the
     table.  It stays under tensor parallelism (the KV heads are
     sharded), over int8-resident pages (gathered and dequantized: the
@@ -1391,8 +1540,14 @@ class ServingKernelPolicy(NamedTuple):
 
     def as_dict(self) -> dict:
         pair = lambda k: dict(zip(("reader", "reason"), getattr(self, k)))
+        # where a decode step's new K/V row is written: by the reader that
+        # takes it (:func:`paged_decode_attention_v2`), else by the row
+        # scatter before the reader, for the reader's own reason
+        write = ("kernel" if self.decode[0] == "dstpu_paged_decode"
+                 else "scatter")
         return {
-            "decode": pair("decode"), "chunk": pair("chunk"),
+            "decode": {**pair("decode"), "write": write},
+            "chunk": pair("chunk"),
             "window": pair("window"), "state_step": self.state_step,
             "state_chunk": pair("state_chunk"),
             "experts": dict(zip(("product", "reason"), self.experts)),
@@ -1468,7 +1623,8 @@ def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
     a copy of it or of one layer (a per-layer store passes ``kp[None]``
     and layer 0: :func:`~deepspeed_tpu.inference.paged_forward.paged_layered_fns`).
     ``reader`` is :func:`paged_reader`'s answer for this phase and these
-    shapes: "xla" (the gather) or the Mosaic kernel's name.  ``kps``/
+    shapes: "xla" (the gather) or the Mosaic kernel's name; a decode
+    step's Mosaic reader is its writer too.  ``kps``/
     ``vps`` non-None: the pages are int8-resident: kp/vp hold int8
     codes, kps/vps the per-token-row f32 scales, writes quantize on
     device, and the reader (always "xla") gathers the codes and
@@ -1510,6 +1666,12 @@ def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
                     kp, kps, vp, vps, layer, k, v, table)
             else:
                 kp, vp = write_prompt_pages(kp, vp, layer, k, v, table)
+    elif reader != "xla":
+        with attend:                    # the reader writes the row it takes
+            attn, kp, vp = paged_decode_attention_v2(
+                q[:, 0], kp, vp, table, start, layer=layer, new_k=k[:, 0],
+                new_v=v[:, 0])
+        attn = attn[:, None]
     else:
         with write:
             if quant:
@@ -1520,14 +1682,9 @@ def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
                 kp, vp = write_token_pages(kp, vp, layer, k[:, 0],
                                            v[:, 0], table, start)
         with attend:
-            if reader != "xla":
-                attn = paged_decode_attention_v2(
-                    q[:, 0], kp, vp, table, start + 1,
-                    layer=layer)[:, None]
-            else:
-                attn = paged_attention_reference(
-                    q[:, 0], kp, vp, table, start + 1, layer=layer,
-                    k_scale=kps, v_scale=vps)[:, None]
+            attn = paged_attention_reference(
+                q[:, 0], kp, vp, table, start + 1, layer=layer,
+                k_scale=kps, v_scale=vps)[:, None]
     return attn, kp, vp, kps, vps
 
 

@@ -115,9 +115,23 @@ def test_flash_attention_packed(chip, layout):
 
 @pytest.mark.parametrize("layout", LAYOUTS, ids=lambda l: l[0])
 def test_paged_decode_v2(chip, layout):
+    """The decode reader as a step calls it: it takes the step's new K/V
+    row a slot, attends to it from VMEM and copies it to its page, by the
+    tile of 8 rows (a copy of one or two rows of a packed page is refused:
+    "Slice shape along dimension 3 must be aligned to tiling (8)"), and
+    gives the pool back in the buffers it came in."""
     B, H, kv, table, lens = _pages(layout)
-    _compile(lambda q, k, v, t, n: K.paged_decode_attention_v2(q, k, v, t, n),
-             chip, ((B, H, DH), jnp.bfloat16), kv, kv, table, lens)
+    pool = ((2,) + kv[0], kv[1])
+    new = ((B, kv[0][0], DH), jnp.bfloat16)
+    step = lambda q, nk, nv, k, v, t, n: K.paged_decode_attention_v2(
+        q, k, v, t, n, layer=1, new_k=nk, new_v=nv)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+        ((B, H, DH), jnp.bfloat16), new, new, pool, pool, table, lens)]
+    compiled = jax.jit(step, donate_argnums=(3, 4)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _pool_sized_ops(compiled.as_text(), pool[0]) == []
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 2 * 2 * math.prod(pool[0])
 
 
 @pytest.mark.parametrize("layout", LAYOUTS, ids=lambda l: l[0])
@@ -282,6 +296,15 @@ ENTRY %main (k: bf16[2,4,9,8,16]) -> bf16[2,4,9,8,16] {
     assert _pool_sized_ops(hlo, (2, 4, 9, 8, 16)) == [
         "copy_bitcast_fusion.4 = bf16[4,9,8,16] fusion",
         "copy.54 = bf16[2,4,9,8,16] copy"]
+    assert _pool_scatters(hlo, (2, 4, 9, 8, 16)) == ["scatter.1"]
+
+
+def _pool_scatters(hlo, pool_shape):
+    """Scatters anywhere in the HLO, fusion bodies included, whose result
+    has the pool's shape: the row writers (``kernels._scatter_rows``),
+    which :func:`_pool_sized_ops` lets pass."""
+    dims = ",".join(map(str, pool_shape))
+    return re.findall(rf"%([\w.\-]+) = \w+\[{dims}\]\S* scatter\(", hlo)
 
 
 def _shaped_like(hlo, *dims):
@@ -370,6 +393,8 @@ def test_serving_program_leaves_the_pool_in_place(chip, pool, phase):
         assert _shaped_like(hlo, rows, cfg.n_kv_heads, table * PAGE,
                             DH) == []
         assert temp <= decode_temp_gib * 2 ** 30
+        # the reader writes the step's rows: no row scatter walks the pool
+        assert _pool_scatters(hlo, shape) == []
     elif phase == "chunk":
         _blocked_chunk_reader(hlo, table * PAGE)
     else:
