@@ -60,7 +60,9 @@ class PagedKVCache(NamedTuple):
     pages, which is not the model's depth where a family has recurrent
     layers (``DecoderFamily.recurrent``): those keep, a SLOT and not a
     page, ``conv`` [L_rec, B, rows, channels] and ``state`` [L_rec, B,
-    heads, Dk, Dv] float32 beside the pool, B the engine's slots.
+    heads, Dk, Dv] float32 beside the pool, B the engine's slots; a
+    family of two per-slot kinds (``Recurrent.also``) keeps the second's
+    rows in ``ring`` [L_ring, B, rows, channels].
 
     table: [B, max_pages] int32 page ids; seq_lens: [B] int32 valid
     token counts.  ``expert_rows`` ([Eh] int32, or None): rows routed to
@@ -95,6 +97,7 @@ class PagedKVCache(NamedTuple):
     state: Optional[jnp.ndarray] = None
     real: Optional[jnp.ndarray] = None
     slot: Optional[jnp.ndarray] = None
+    ring: Optional[jnp.ndarray] = None
 
     @classmethod
     def alloc(cls, n_layers: int, n_kv: int, num_pages: int, page_size: int,
@@ -1500,7 +1503,10 @@ def paged_reader(*, decode: bool, tp: bool, interpret: bool, quant: bool,
     sharded), over int8-resident pages (gathered and dequantized: the
     chip's compiler refuses a page copy of the ``[KV, P, ps, 1]`` scale
     planes, a 1-wide slice of a 128-lane tile), in ``interpret`` mode
-    and off those shapes (a head of 64)."""
+    and off those shapes (a head of 64).  A reader need not be a writer:
+    layers that read pages another layer wrote (``family.PoolReader``)
+    run the decode reader without a row to append, over lengths that
+    count what this program's writer wrote before them."""
     whole = lambda n: n > 0 and n % 128 == 0
     for off, why in ((tp, "tp: KV heads are sharded over the mesh"),
                      (quant, "int8-resident pages"),
@@ -1630,8 +1636,10 @@ def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
     device, and the reader (always "xla") gathers the codes and
     dequantizes them (:func:`dequantize_pages`).  Phases:
     chunked-prefill continuation (split-fuse), whole-prompt prefill
-    (empty cache), or single-token decode.  Returns (attn [B, T, H, Dh],
-    kp, vp, kps, vps)."""
+    (empty cache), or single-token decode.  ``k`` None: a layer that
+    reads ``layer``'s pages and writes none (one token a row, ``start``
+    the lengths to read: the decode reader with nothing to append).
+    Returns (attn [B, T, H, Dh], kp, vp, kps, vps)."""
     from deepspeed_tpu.ops.attention import flash_attention
 
     quant = kps is not None
@@ -1640,7 +1648,17 @@ def paged_attention_step(q, k, v, kp, vp, layer, table, start, *,
     # the one place for the three attention scopes (kv_write, kv_attend,
     # flash): forward_paged and its layered twin pass through here
     write, attend = jax.named_scope("kv_write"), jax.named_scope("kv_attend")
-    if continuation and q.shape[1] > 1:
+    if k is None:
+        with attend:
+            if reader != "xla":
+                attn = paged_decode_attention_v2(q[:, 0], kp, vp, table,
+                                                 start, layer=layer)
+            else:
+                attn = paged_attention_reference(
+                    q[:, 0], kp, vp, table, start, layer=layer, k_scale=kps,
+                    v_scale=vps)
+        attn = attn[:, None]
+    elif continuation and q.shape[1] > 1:
         with write:
             if quant:
                 kp, kps, vp, vps = write_chunk_pages_quant(
@@ -1786,8 +1804,9 @@ def _state_step_kernel(layer_ref, *refs, rule, kinds, o_kind, tile, ahead):
     C] at a time through ``buf``: tile t is stepped in its buffer while
     tiles up to t + ``ahead`` are read and the ones before it written
     back.  ``rule`` runs on one head's [R, C] at a time, its vectors
-    beside it as [1, C] (``row``), [R, 1] (``col``) or a scalar (``one``,
-    in the scalar memory).  A vector that runs along R arrives as a row
+    beside it as [1, C] (``row``), [R, 1] (``col``), a scalar (``one``,
+    in the scalar memory) or the head's [R, C] of a tile the slots share
+    (``tile``).  A vector that runs along R arrives as a row
     of its array, R on the lanes, and is turned on the spot: the
     diagonal of its [R, R] broadcast, summed over the lanes (one number
     and zeros: exact); a result along R goes back the same way, summed
@@ -1816,6 +1835,8 @@ def _state_step_kernel(layer_ref, *refs, rule, kinds, o_kind, tile, ahead):
     def vector(ref, kind, b, h):
         if kind == "one":
             return ref[b * H + h]
+        if kind == "tile":
+            return ref[h]
         v = ref[pl.ds(b, 1), :] if ref.ndim == 2 else ref[b, pl.ds(h, 1), :]
         return (jnp.sum(jnp.where(eye, v, 0.0), axis=1, keepdims=True)
                 if kind == "col" else v)
@@ -1869,14 +1890,16 @@ def state_step(rule, state, layer, vectors, *, interpret: bool = False,
     multiplies or adds to S by broadcasting; the kernel hands it the
     last as a scalar), ``o`` [..., 1, C] or [..., R, 1].  ``vectors``:
     every slot's, [slots, H or 1, R or 1, C or 1] float32 (1 heads:
-    shared by the heads; one number a head is always [slots, H, 1, 1]);
+    shared by the heads; one number a head is always [slots, H, 1, 1]),
+    or a layer's tile [1, H, R, C] that every slot shares (a decay a
+    (row, column) pair: handed over once, not a copy a slot);
     they and ``o`` are whole in the kernel's memory.  Returns (o [slots,
     H, 1, C] or [slots, H, R, 1], the buffer).  The tile is read from
     the shapes (:func:`_state_tile`); ``tile_bytes`` is a measurement's
     and a test's."""
     L, B, H, R, C = state.shape
-    kind_of = lambda shape: {(1, C): "row", (R, 1): "col",
-                             (1, 1): "one"}[tuple(shape)]
+    kind_of = lambda shape: {(1, C): "row", (R, 1): "col", (1, 1): "one",
+                             (R, C): "tile"}[tuple(shape)]
     kinds = tuple(kind_of(v.shape[2:]) for v in vectors)
     o_shape = jax.eval_shape(
         rule, jax.ShapeDtypeStruct((R, C), jnp.float32),
@@ -1891,7 +1914,8 @@ def state_step(rule, state, layer, vectors, *, interpret: bool = False,
     # went back through the convolution to the carried buffer of its
     # rows, which the program then re-laid on its way in and out, v5e)
     ones = [v.reshape(-1) for v, k in zip(vectors, kinds) if k == "one"]
-    rows = [v.reshape((B,) + ((H,) if v.shape[1] > 1 else ()) + (-1,))
+    rows = [v.reshape((H, R, C) if k == "tile" else
+                      (B,) + ((H,) if v.shape[1] > 1 else ()) + (-1,))
             for v, k in zip(vectors, kinds) if k != "one"]
     o = jax.ShapeDtypeStruct((B, H, R if o_kind == "col" else C),
                              jnp.float32)
@@ -1925,8 +1949,9 @@ def state_step(rule, state, layer, vectors, *, interpret: bool = False,
 
 def paged_period_loop(period, x, stacks, cache: PagedKVCache, periods: int):
     """:func:`paged_layer_loop` for a family whose layers come in
-    periods of two kinds: ``period(x, lps, p, kp, vp, rows, conv, state)
-    -> (x, kp, vp, rows, conv, state)`` runs period ``p``'s layers,
+    periods of two kinds: ``period(x, lps, p, kp, vp, rows, conv, state,
+    ring) -> (x, kp, vp, rows, conv, state, ring)`` runs period ``p``'s
+    layers (``x`` whatever the caller carries with the activations),
     ``stacks`` the stacked params the loop slices a period out of
     (``[periods, ...]`` leaves; a kind whose layers run in a loop of
     their own inside ``period`` takes its layers out of its own stack).
@@ -1937,12 +1962,12 @@ def paged_period_loop(period, x, stacks, cache: PagedKVCache, periods: int):
         x, *held = period(x, *xs, *held)
         return (x, tuple(held)), None
 
-    (x, (k, v, rows, conv, state)), _ = jax.lax.scan(
+    (x, (k, v, rows, conv, state, ring)), _ = jax.lax.scan(
         body, (x, (cache.k, cache.v, cache.expert_rows, cache.conv,
-                   cache.state)),
+                   cache.state, cache.ring)),
         (stacks, jnp.arange(periods, dtype=jnp.int32)))
     return x, cache._replace(k=k, v=v, expert_rows=rows, conv=conv,
-                             state=state)
+                             state=state, ring=ring)
 
 
 

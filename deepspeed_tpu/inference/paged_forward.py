@@ -74,7 +74,20 @@ def _count_routed(fam, cfg, rows, routed, N: int):
         routed, extra_pair_passes(routed, N, top_k, scored))
 
 
-def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
+def _paged_read_block(rd, cfg, x, lp, ctx, kp, vp, table, lens, *, reader):
+    """One layer of a ``family.PoolReader``: one token a row, a query of
+    its own over pool layer ``rd.reads`` as far as ``lens``; it writes
+    nothing."""
+    q = rd.q(cfg, x, lp, *ctx)
+    with jax.named_scope("kv_attend"), jax.named_scope(rd.scope):
+        attn = paged_attention_step(
+            q, None, None, kp, vp, rd.reads, table, lens, continuation=False,
+            prefill=False, reader=reader(head_dim=q.shape[-1])[0],
+            flash_force_reference=False)[0]
+    return rd.out(cfg, x, attn.reshape(x.shape[0], 1, -1), lp)
+
+
+def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, read, *,
                      whole: bool, tp: bool, interpret: bool):
     """The layers of a family some of whose layers keep a bounded state
     a slot (``fam.recurrent``): first its leading stack, if it has one
@@ -96,7 +109,13 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
     ``cache.real``: how many tokens of each row may move a state; a row
     that starts at position 0 starts from zero state, whatever its slot
     held (a first chunk's rows are zeroed before ``mix`` or the kernel
-    sees them)."""
+    sees them).  A kind a period names by a string is a further per-slot
+    kind (``Recurrent.also``: the same layer over ``cache.ring``) or a
+    pool layer that writes nothing (``Recurrent.readers``: ``read``);
+    where a kind hands a value on (``Recurrent.hands_on``) it rides with
+    the activations, ``(x, memory)``; before the last ``Recurrent.tail``
+    sections a program of T > 1 tokens cuts both to each row's last real
+    token."""
     rec = fam.recurrent
     B, T = x.shape[:2]
     start, slot = cache.seq_lens, cache.slot
@@ -109,14 +128,14 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
                                     count=n_lead)
     n_pool = cache.k.shape[0] - n_lead
     every_slot = T == 1 and slot is None
-    in_place = cache.state is not None and state_stepper(
-        decode=every_slot, tp=tp)[0] == "pallas"
-    rows_in_place = rec.rows_in_place and every_slot
+    stepped = state_stepper(decode=every_slot, tp=tp)[0] == "pallas"
     step = functools.partial(state_step, interpret=interpret)
     chunk = functools.partial(state_chunk, interpret=interpret)
-    # a prompt chunk's state on the chip, where the build runs it there
-    on_chip = T > 1 and cache.state is not None and state_chunker(
-        (rec, cfg), tp=tp, interpret=interpret)[0] == "pallas"
+    # the per-slot kinds by their name in a period, and the pool's readers
+    also = {r.key: r for r in rec.also}
+    per_slot = {True: rec, "lead": rec, **also}
+    readers = {r.key: r for r in rec.readers}
+    memory = rec.hands_on is not None
 
     def split(stack):
         held = {k: stack[k] for k in fam.whole_stacks
@@ -126,7 +145,8 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
     # a kind's stack stays whole, its layers taken out by index (a period's
     # slice copied 150 MB a projection, v5e, PR 35), but one section's pool
     stacks = {True: split(params[rec.key]), False: split(params["blocks"]),
-              None: split(params[rec.ffn[0]]) if rec.ffn else None}
+              None: split(params[rec.ffn[0]]) if rec.ffn else None,
+              **{key: split(params[key]) for key in (*also, *readers)}}
     s_lead = 0                  # per-slot layers of a leading stack, sliced
     if rec.lead is not None:    # a layer (a leading FFN holds no experts)
         stacks["lead"] = {}, params[rec.lead[0]]
@@ -141,14 +161,23 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
     def counted(x, rows):
         """``(x, rows)``, the layer's FFN counting its experts' rows or not."""
         if isinstance(x, tuple):
-            return x[0], _count_routed(fam, cfg, rows, x[1], B * T)
+            return x[0], _count_routed(fam, cfg, rows, x[1],
+                                       x[0].shape[0] * x[0].shape[1])
         return x, rows
 
     def recurrent_layer(kind, out_half, first, carry, layer):
         """Per-slot layer ``layer`` of the state buffers, a layer of the
         stack ``kind`` ("lead", or True: the family's own) whose layer 0
-        keeps the buffers' layer ``first``."""
+        keeps the buffers' layer ``first``; ``conv`` and ``state`` the
+        buffers of the layer's kind (a ring kind's: the rings and None)."""
         x, rows, conv, state = carry
+        x, mem = x if memory else (x, None)
+        rk = per_slot[kind]
+        in_place = state is not None and stepped
+        rows_in_place = rk.rows_in_place and every_slot
+        # a prompt chunk's state on the chip, where the build runs it there
+        on_chip = T > 1 and state is not None and state_chunker(
+            (rk, cfg), tp=tp, interpret=interpret)[0] == "pallas"
         lp = layer_of(kind, layer - first if first else layer)
         # out of the carried buffers and back: all a decode step leaves
         rows_out = () if rows_in_place else (conv,)
@@ -164,8 +193,10 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
                 CarriedState(state, layer, step) if in_place
                 else SlotState(held[-1], chunk) if on_chip
                 else held[-1] if state_out else None)
-        y, held = rec.mix(cfg, x, lp, held, real, start, ctx)
-        with jax.named_scope("kv_write"), jax.named_scope(rec.write_scope):
+        y, held = rk.mix(cfg, x, lp, held, real, start, ctx)
+        if rk.hands_on is not None:
+            y, mem = y
+        with jax.named_scope("kv_write"), jax.named_scope(rk.write_scope):
             out = write_state_rows(
                 out, layer, slot,
                 (() if rows_in_place else held[:1])
@@ -174,11 +205,16 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
         if state is not None:
             state = held[1].buffer if in_place else out[-1]
         x, rows = counted(out_half(cfg, x, y, lp), rows)
-        return (x, rows, conv, state), None
+        return ((x, mem) if memory else x, rows, conv, state), None
 
-    own_layer = functools.partial(recurrent_layer, True, rec.out, s_lead)
+    # one function a kind: a scan's body is traced once a function, and the
+    # masks it closes over stay one constant of the program
+    own_layer = {kind: functools.partial(
+        recurrent_layer, kind, rk.out, s_lead if kind is True else 0)
+        for kind, rk in per_slot.items() if kind != "lead"}
 
-    def period(kinds, done, scanned, x, att, p, kp, vp, rows, conv, state):
+    def period(kinds, done, scanned, x, att, p, kp, vp, rows, conv, state,
+               ring):
         """Period ``p`` of a section whose period is ``kinds``, ``done``
         layers of each kind before it; ``att``: the period's pool
         layers' params where the section ``scanned`` them."""
@@ -188,7 +224,15 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
         for kind, run in ((k, len(list(g)))
                           for k, g in itertools.groupby(kinds)):
             first, i[kind] = i[kind], i[kind] + run     # in the period
-            if kind:
+            if kind in also:
+                # the family's second per-slot kind: the same layer over
+                # the rings, a loop of its own as the first kind's
+                (x, rows, ring, _), _ = jax.lax.scan(
+                    own_layer[kind], (x, rows, ring, None),
+                    p * n[kind] + (done[kind] + first)
+                    + jnp.arange(run, dtype=jnp.int32))
+                continue
+            if kind is True:
                 # a loop of their own: each iteration reads its layer's
                 # state and updates the carried buffer once.  Unrolled,
                 # layer i + 1 read the buffer layer i had just updated
@@ -197,15 +241,21 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
                 # in-place update from the buffer it had already
                 # overwritten: the state moved twice a step (v5e, PR 35)
                 (x, rows, conv, state), _ = jax.lax.scan(
-                    own_layer, (x, rows, conv, state),
+                    own_layer[True], (x, rows, conv, state),
                     p * n[kind] + (done[kind] + first + s_lead)
                     + jnp.arange(run, dtype=jnp.int32))
                 continue
+            x, mem = x if memory else (x, None)
             for j in range(first, first + run):
                 layer = p * n[kind] + (done[kind] + j)  # in the kind's stack
                 if kind is None:
-                    x, rows = counted(
-                        rec.ffn[1](cfg, x, layer_of(None, layer)), rows)
+                    lp = layer_of(None, layer)
+                    x, rows = counted(rec.ffn[1](
+                        cfg, x, dict(lp, memory=mem) if memory else lp), rows)
+                    continue
+                if kind in readers:
+                    x = read(readers[kind], x, layer_of(kind, layer), kp, vp,
+                             start + real)
                     continue
                 if scanned:
                     lp = {k: v[j] for k, v in att.items()}
@@ -216,7 +266,8 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
                 x, kp, vp, _, _, rows = block(
                     x, lp, n_lead + layer if n_lead else layer, kp, vp,
                     None, None, rows)
-        return x, kp, vp, rows, conv, state
+            x = (x, mem) if memory else x
+        return x, kp, vp, rows, conv, state, ring
 
     if s_lead:
         (x, rows, conv, state), _ = jax.lax.scan(
@@ -224,8 +275,16 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
             (x, cache.expert_rows, cache.conv, cache.state),
             jnp.arange(s_lead, dtype=jnp.int32))
         cache = cache._replace(expert_rows=rows, conv=conv, state=state)
-    done = {True: 0, False: 0, None: 0}
-    for kinds, count in sections_of(rec, cfg, n_pool):
+    if memory:
+        x = x, jnp.zeros((B, T, rec.hands_on(cfg)), x.dtype)
+    done = dict.fromkeys(stacks, 0)
+    sections = sections_of(rec, cfg, n_pool)
+    for at, (kinds, count) in enumerate(sections):
+        if rec.tail and T > 1 and at == len(sections) - rec.tail:
+            # what is behind here a row's last real token alone pays
+            x = jax.tree.map(lambda a: jax.vmap(
+                lambda row, i: jax.lax.dynamic_slice_in_dim(row, i, 1))(
+                    a, jnp.maximum(real - 1, 0)), x)
         n_att = kinds.count(False)
         scanned = bool(n_att) and n_att * count == n_pool
         att = {k: v.reshape((count, n_att) + v.shape[1:])
@@ -235,7 +294,7 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, *,
             cache, count)
         done = {kind: n + count * kinds.count(kind)
                 for kind, n in done.items()}
-    return x, cache._replace(
+    return x[0] if memory else x, cache._replace(
         seq_lens=start + jnp.where(real > 0, T, 0), real=None)
 
 
@@ -251,7 +310,9 @@ def forward_paged(params, tokens, cfg, cache, *,
     per-slot state the cache carries beside the pool with ``real``, the
     rows' real token counts (:class:`~deepspeed_tpu.inference.kernels.
     PagedKVCache`).  Such a cache comes back with ``real`` consumed and
-    the lengths of rows that had no real token left as they were.
+    the lengths of rows that had no real token left as they were.  A
+    family that states a ``Recurrent.tail`` gives, from a program of T > 1
+    tokens, the logits of each row's last real token alone, ``[B, 1, V]``.
 
     ``tp``: True = params/cache are sharded over the mesh, so every
     pallas path (paged kernels AND the prefill flash kernel) must yield
@@ -326,9 +387,14 @@ def forward_paged(params, tokens, cfg, cache, *,
         return run
 
     if fam.recurrent is not None:
+        def read(rd, x, lp, kp, vp, lens):
+            return _paged_read_block(
+                rd, cfg, x, lp, ctx, kp, vp, cache.table, lens,
+                reader=functools.partial(reader, decode=True))
+
         x, cache = _forward_periods(
             fam, params, x, cfg, cache, block(fam.out),
-            fam.lead and block(fam.lead[1]), ctx,
+            fam.lead and block(fam.lead[1]), ctx, read,
             whole=resident and not tp, tp=tp, interpret=interpret)
         return fam.head(params, x, cfg), cache
     n_lead = 0

@@ -154,7 +154,11 @@ def _last_row(logits: jnp.ndarray, last: jnp.ndarray) -> jnp.ndarray:
     """What a prefill's first token is sampled from and no more:
     ``[1, T, V]`` logits -> the ``[1, V]`` row at ``last`` (int32 [1]),
     the last REAL position of the call.  The padded tail's logits are
-    never read, and no ``[1, T, V]`` leaves a program."""
+    never read, and no ``[1, T, V]`` leaves a program.  A family that
+    cuts its rows before its last layers (``Recurrent.tail``) hands over
+    that row alone, ``[1, 1, V]``."""
+    if logits.shape[1] == 1:
+        return logits[0, 0]
     return jax.lax.dynamic_index_in_dim(logits[0], last[0], axis=0)
 
 
@@ -374,6 +378,10 @@ class ServingEngine:
     contract ``(params, tokens, cache) -> (logits, cache)``; built for a
     decoder family's config by :func:`serving_engine`.
     """
+
+    # the chunk programs hand over their row's last token alone (a family
+    # that states ``Recurrent.tail``; set by :func:`serving_engine`)
+    tail_cut = False
 
     def __init__(self, params, prefill_fn, decode_fn, *,
                  n_layers: int, n_kv: int, head_dim: int,
@@ -599,6 +607,14 @@ class ServingEngine:
             "serving_decode_syncs", "device->host token syncs")
         self._c_prefill_chunks = r.counter(
             "serving_prefill_chunks", "split-fuse prompt chunks absorbed")
+        self._c_chunk_rows = r.counter(
+            "serving_chunk_rows_total",
+            "real prompt rows that chunk programs took through the layers")
+        self._c_tail_rows = r.counter(
+            "serving_tail_rows_total",
+            "rows of chunk programs that paid the last layers and the "
+            "head: every real row, or one a program where the family cuts "
+            "its rows before them (Recurrent.tail)")
         # rows the programs routed to each held expert (the registry has
         # no labels: the expert's index is the name's suffix), and every
         # (row, expert) pair they routed anywhere, padding rows included
@@ -1160,7 +1176,7 @@ class ServingEngine:
         expert_rows = (self._put(np.zeros(
             (self._n_expert_rows + self._pair_passes,), np.int32))
             if self._n_expert_rows else None)
-        conv = state = None
+        conv = state = ring = None
         if self._state_row is not None:
             # indexed by slot, not by page; made on the device, as the
             # pool is: 6.8 GiB of host zeros took 29 s to upload (PR 42)
@@ -1169,6 +1185,10 @@ class ServingEngine:
                 (sr.layers, self.max_batch) + sr.conv, cache_dtype))
             state = None if sr.state is None else self._put(jnp.zeros(
                 (sr.layers, self.max_batch) + sr.state, STATE_DTYPE))
+            if sr.ring is not None:     # the family's second per-slot kind
+                ring = self._put(jnp.zeros(
+                    (sr.ring.layers, self.max_batch) + sr.ring.conv,
+                    cache_dtype))
         if self._quant_resident:
             # int8-resident pages: codes replace the dense planes
             # (~2x the pages per HBM byte at bf16, 4x at f32) and a
@@ -1193,11 +1213,12 @@ class ServingEngine:
                 cache_dtype)),
             table=table, seq_lens=seq_lens,
             page_size=page_size,
-            expert_rows=expert_rows, conv=conv, state=state)
+            expert_rows=expert_rows, conv=conv, state=state, ring=ring)
 
     def _state_bytes(self) -> int:
         """Bytes of the per-slot state beside the pool."""
-        return int(sum(a.nbytes for a in (self.cache.conv, self.cache.state)
+        return int(sum(a.nbytes for a in (self.cache.conv, self.cache.state,
+                                          self.cache.ring)
                        if a is not None))
 
     def _pool_bytes(self) -> int:
@@ -1218,6 +1239,7 @@ class ServingEngine:
             k=self.cache.k, v=self.cache.v,
             expert_rows=self.cache.expert_rows,
             conv=self.cache.conv, state=self.cache.state,
+            ring=self.cache.ring,
             slot=(None if self._state_row is None
                   else self._put(np.full((1,), b, np.int32))),
             table=self._put(table_row),
@@ -1230,7 +1252,8 @@ class ServingEngine:
         call's own)."""
         return self.cache._replace(k=view.k, v=view.v,
                                    expert_rows=view.expert_rows,
-                                   conv=view.conv, state=view.state)
+                                   conv=view.conv, state=view.state,
+                                   ring=view.ring)
 
     def _build_programs(self, prefill_fn, decode_fn,
                         chunk_prefill_fn) -> None:
@@ -2603,6 +2626,8 @@ class ServingEngine:
         s.prefill_done = done + take
         s.seq_len = s.prefill_done
         self._c_prefill_chunks.inc()
+        self._c_chunk_rows.inc(take)
+        self._c_tail_rows.inc(1 if self.tail_cut else take)
         if s.req.traced:
             self.tracer.event("prefill_chunk", s.req.req_id, b, attrs={
                 "done": s.prefill_done, "of": T, "take": take})
@@ -3327,6 +3352,9 @@ class ServingEngine:
             # the per-slot state beside the pages; layers that keep nothing
             "cache.state": {
                 "layers": self._state_row.layers,
+                # a family's second per-slot kind (Recurrent.also)
+                "ring_layers": (self._state_row.ring.layers
+                                if self._state_row.ring else 0),
                 "ffn_alone": {"bytes": 0, "layers": getattr(
                     self, "ffn_alone_layers", 0)},
                 "bytes": self._state_bytes(),
@@ -3907,6 +3935,7 @@ def serving_engine(params, cfg, **kw):
         _record_comm_placement(eng, comm_stats)
     if fam.recurrent is not None:
         # layers that are an FFN alone: neither pages nor a state
-        eng.ffn_alone_layers = cfg.n_layers - n_layers \
-            - kw["state_row"].layers
+        eng.ffn_alone_layers = fam.ffn_alone_layers(cfg)
+        # chunk programs give their row's last token alone (Recurrent.tail)
+        eng.tail_cut = bool(fam.recurrent.tail)
     return eng

@@ -2,7 +2,8 @@
 
 A family's file (``gpt2.py``, ``llama.py``, ``mixtral.py``,
 ``pangu_ultra_moe.py``, ``qwen3_next.py``, ``granite_hybrid.py``,
-``laguna.py``, ``nemotron_h.py``, ``ling_flash.py``) ends with its
+``laguna.py``, ``nemotron_h.py``, ``ling_flash.py``, ``phi4_flash.py``) ends
+with its
 ``FAMILY = DecoderFamily(...)``: the pieces of one transformer layer and
 the facts a serving build needs.  Everything that serves, streams, drafts
 or generates (the ``inference`` package) reads the record through
@@ -62,18 +63,54 @@ class StateRow(NamedTuple):
     layers: int
     conv: Tuple[int, ...]
     state: Optional[Tuple[int, ...]]
+    # what a slot keeps a layer of a family's SECOND per-slot kind
+    # (``Recurrent.also``): see :class:`SlotRows`
+    ring = None
 
+
+class SlotRows(StateRow):
+    """A :class:`StateRow` (it compares as one) of a family whose slots
+    keep two kinds of rows: the first kind's, and as ``ring`` the
+    ``StateRow`` of the second (``Recurrent.also``), whose rows live in
+    ``PagedKVCache.ring`` ``[layers, B, *conv]``."""
+
+    def __new__(cls, first: StateRow, ring: StateRow):
+        self = super().__new__(cls, *first)
+        self.ring = ring
+        return self
+
+
+class PoolReader(NamedTuple):
+    """Layers that attend over pages ANOTHER layer wrote and write none:
+    ``key`` their stack (and their kind's name in a period), ``q(cfg, x,
+    lp, *ctx) -> q [B, 1, H, Dh]``, ``out`` their second half (as
+    ``DecoderFamily.out``), ``reads`` the pool layer they read, ``scope``
+    their word inside ``kv_attend``.  Such a layer runs on one token a
+    row (a decode step, or behind ``Recurrent.tail``'s cut) over the
+    row's length WITH the rows this program wrote."""
+
+    key: str
+    q: Callable[..., Any]
+    out: Callable[..., Any]
+    reads: int
+    scope: str
 
 
 @dataclasses.dataclass(frozen=True)
 class Recurrent:
     """The layers of a family that keep a bounded state a slot beside
     the page pool, in periods with layers that attend over pages (an
-    author's fields, as ``DecoderFamily``'s).  Five users: a delta rule
+    author's fields, as ``DecoderFamily``'s).  Six users: a delta rule
     gated a head (``qwen3_next``) or a key channel (``ling_flash``, whose
     pool layers are latent), a state-space mixer (``granite_hybrid``,
-    ``nemotron_h``): a matrix a head that every token moves, and a
-    sliding window (``laguna``): a ring of the last tokens' K and V.
+    ``nemotron_h``): a matrix a head that every token moves, a sliding
+    window (``laguna``): a ring of the last tokens' K and V, and both at
+    once (``phi4_flash``).  What the sixth changed: a period's kinds are
+    no longer three (a string names a further kind by its stack's key: a
+    second per-slot kind, ``also``, or pool layers that write nothing,
+    ``readers``), a per-slot layer may hand a value on to the layers
+    behind it (``hands_on``), and the last sections may run on a row's
+    last real token alone (``tail``).
 
     ``period(cfg)``: one entry a layer of a period: True where the layer
     mixes tokens over its per-slot state, False where it attends over
@@ -114,7 +151,22 @@ class Recurrent:
     second half (leading dense layers that fall on the period's per-slot
     kind): ``mix`` runs on that stack's own mixer weights over the state
     buffers' first layers, ``out(cfg, x, y, lp)`` in ``out``'s place, and
-    ``key``'s own layers' state stands behind theirs."""
+    ``key``'s own layers' state stands behind theirs.
+
+    ``also``: further per-slot kinds, a ``Recurrent`` each (its ``key``,
+    ``mix``, ``out``, ``state_row``, ``write_scope``, ``rows_in_place``;
+    its ``period`` is not read), named in a period by its ``key``; one is
+    built: its rows live in ``PagedKVCache.ring`` and ``state_row``
+    states them as :class:`SlotRows`.  ``readers``: :class:`PoolReader`s,
+    named in a period by their ``key``.  ``hands_on(cfg) -> width``: this
+    kind's ``mix`` returns ``((y, m), state)``, ``m`` [B, T, width] the
+    value its layer hands on; the newest is ``lp["memory"]`` of every
+    ``ffn`` hook behind it.  ``tail``: how many of the last sections a
+    row's last real token alone pays: a program of T > 1 tokens cuts ``x``
+    and the memory to ``[B, 1]`` at ``cache.real - 1`` before them, and
+    its logits are that row's, ``[B, 1, V]`` (exact where the sections
+    behind the cut keep nothing a token: their outputs at other positions
+    feed nothing)."""
 
     key: str
     period: Callable[[Any], tuple]
@@ -128,6 +180,10 @@ class Recurrent:
     ffn: Optional[Tuple[str, Callable[..., Any]]] = None
     block: Optional[Callable[..., Tuple[Any, Any]]] = None
     lead: Optional[Tuple[str, Callable[..., Any]]] = None
+    also: Tuple["Recurrent", ...] = ()
+    readers: Tuple[PoolReader, ...] = ()
+    hands_on: Optional[Callable[[Any], int]] = None
+    tail: int = 0
 
 
 class CarriedRows(NamedTuple):
@@ -156,11 +212,12 @@ def step_state(rule, S, *vectors):
     """One token of a recurrence: ``rule(S, *vectors) -> (o, S)``, the
     family's statement of it over the last two dimensions of ``S`` [...,
     R, C], each vector [..., 1, C], [..., R, 1] or [..., 1, 1] (a scalar
-    where the rule is applied to one head's [R, C]) and ``o`` [..., 1,
-    C] or [..., R, 1].  ``S`` is the rows' state [B, H, R, C], and the
-    rule is applied to it as it stands, or a :class:`CarriedState`,
-    whose layer is stepped where it lies; the second result is of the
-    kind ``S`` was."""
+    where the rule is applied to one head's [R, C]) or a tile [1, H, R,
+    C] that the slots share (a layer's own: a decay a (row, column) pair)
+    and ``o`` [..., 1, C] or [..., R, 1].  ``S`` is the rows' state [B,
+    H, R, C], and the rule is applied to it as it stands, or a
+    :class:`CarriedState`, whose layer is stepped where it lies; the
+    second result is of the kind ``S`` was."""
     if isinstance(S, CarriedState):
         o, buffer = S.step(rule, S.buffer, S.layer, vectors)
         return o, S._replace(buffer=buffer)
@@ -292,6 +349,16 @@ class DecoderFamily:
         return sum(kinds.count(False) * count
                    for kinds, count in rec.sections(cfg))
 
+    def ffn_alone_layers(self, cfg) -> int:
+        """How many of ``cfg``'s layers are an FFN alone
+        (``Recurrent.ffn``): neither pages nor a state."""
+        rec = self.recurrent
+        if rec.sections is None:
+            return cfg.n_layers - self.pool_layers(cfg) \
+                - rec.state_row(cfg).layers
+        return sum(kinds.count(None) * count
+                   for kinds, count in rec.sections(cfg))
+
     def sharded(self, mesh) -> bool:
         return mesh is not None and any(
             mesh.size(ax) > 1 for ax in self.shard_axes)
@@ -319,7 +386,7 @@ def positions_from(start, T: int):
 # the registry: one module name a family
 _FAMILY_MODULES = ("gpt2", "llama", "mixtral", "pangu_ultra_moe",
                    "qwen3_next", "granite_hybrid", "laguna", "nemotron_h",
-                   "ling_flash")
+                   "ling_flash", "phi4_flash")
 
 
 def decoder_families() -> Tuple[DecoderFamily, ...]:
