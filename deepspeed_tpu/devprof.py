@@ -373,8 +373,10 @@ class BuildCounters:
 STEP_PHASES = ("admit", "prefill", "boundary", "grow_pages", "upload",
                "inputs", "dispatch", "token_sync", "append")
 # where a step hands the device a program: a whole-prompt prefill, a
-# prompt chunk, the decode chunk, the speculative verify sweep
-STEP_SITES = ("prefill", "chunk", "decode", "sweep")
+# prompt chunk, the decode chunk with its tokens up from the host, the
+# speculative verify sweep, and the decode chunk dispatched AHEAD: before
+# the tokens of the one before it were read, which it takes on the device
+STEP_SITES = ("prefill", "chunk", "decode", "sweep", "decode_ahead")
 # the longest window at the shortest step: ~110 steps/s for 74 s
 _STEP_ROWS = 1 << 13
 

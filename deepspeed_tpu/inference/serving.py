@@ -81,7 +81,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import time
-from typing import Any, Dict, List, Optional, Tuple, Union
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple, Union
 
 import numpy as np
 import jax
@@ -246,6 +246,32 @@ def serving_programs(prefill_fn, decode_fn, chunk_prefill_fn, sample,
         dstpu_sweep, dstpu_decode
 
 
+def decode_from_output(decode, decode_chunk: int, max_batch: int):
+    """``dstpu_decode`` over the LAST decode program's output in the
+    form it left the device (``[B, K]``, or flat behind the experts'
+    rows, ``[B * K + Eh]``): each row's newest token is cut out inside
+    the program, so a step can be dispatched before the host has read
+    the step before it.  With ``decode_chunk`` 1 and no expert rows the
+    output IS the operand and no engine wraps its program in this; a
+    caller that has the tokens themselves (``[B, 1]``) still hands
+    them over as they are."""
+    def dstpu_decode(params, prev, cache, key, ordinal, temps):
+        if prev.shape != (max_batch, 1):
+            prev = prev.reshape(-1)[:max_batch * decode_chunk].reshape(
+                max_batch, decode_chunk)[:, -1:]
+        return decode(params, prev, cache, key, ordinal, temps)
+
+    return dstpu_decode
+
+
+# why a decode step's tokens went up from the host (/statusz "decode"):
+# a free slot stood beside a waiting queue; a boundary token waited; a
+# row ended by count with the step before; a prompt's last chunk came;
+# anything else (the first step, a page the pool could only give by
+# preempting, a row that ended on its own, a promotion in flight)
+DECODE_BEHIND = ("admission", "boundary", "finish", "prefill", "other")
+
+
 def _req_key(req_id: Any) -> str:
     """Canonical string form of a request id — the /requestz?id= query
     arrives as text, so matching happens in string space."""
@@ -353,6 +379,14 @@ class _Promotion:
 _KV_PROMO_DEFER_CAP = 16
 
 
+class _Flying(NamedTuple):
+    """A decode step the device has and the host has not read."""
+    out: Any                            # its output, on the device
+    rows: List[Tuple[int, "_Slot"]]     # the rows it was dispatched for
+    ordinal: int                        # what its draws were folded from
+    temps: Any                          # the temperatures it ran under
+
+
 @dataclasses.dataclass
 class _Slot:
     req: Request
@@ -382,6 +416,12 @@ class ServingEngine:
     # the chunk programs hand over their row's last token alone (a family
     # that states ``Recurrent.tail``; set by :func:`serving_engine`)
     tail_cut = False
+    # the one jitted decode program and the form its output has (None: an
+    # engine whose decode the host drives layer by layer, which takes
+    # the tokens ``[B, 1]`` and never has a step in flight)
+    _decode_jit = _out_shape = None
+    # the decode step the device has and the host has not read
+    _flying: Optional[_Flying] = None
 
     def __init__(self, params, prefill_fn, decode_fn, *,
                  n_layers: int, n_kv: int, head_dim: int,
@@ -588,6 +628,11 @@ class ServingEngine:
         # for a decode or verify dispatch (2 * its ordinal)
         self._key = self._put(jax.random.PRNGKey(seed))
         self._n_dispatch = 0
+        # decode dispatches whose tokens came up from the host, by what
+        # made the step before them land first (DECODE_BEHIND), and the
+        # reason the next such dispatch will be counted under
+        self._behind = dict.fromkeys(DECODE_BEHIND, 0)
+        self._behind_why = "other"
         self.finished: Dict[Any, List[int]] = {}
         self._newly_finished: List[Any] = []
 
@@ -605,6 +650,10 @@ class ServingEngine:
             "serving_decode_steps", "batched decode steps (tokens/slot)")
         self._c_decode_syncs = r.counter(
             "serving_decode_syncs", "device->host token syncs")
+        self._c_decode_ahead = r.counter(
+            "serving_decode_ahead",
+            "decode programs dispatched before the tokens of the one "
+            "before them were read: their token operand was its output")
         self._c_prefill_chunks = r.counter(
             "serving_prefill_chunks", "split-fuse prompt chunks absorbed")
         self._c_chunk_rows = r.counter(
@@ -1275,8 +1324,18 @@ class ServingEngine:
         # forward's logits, so the one engine that builds this program
         self._verify_chunk = (jax.jit(dstpu_sweep, donate_argnums=(2,))
                               if self._spec_on else None)
-        self._decode_chunk_fn = jax.jit(dstpu_decode,
-                                        donate_argnums=(2,))
+        # ONE decode program, whose token operand has the form of its
+        # own output: the tokens' [B, 1] itself where that is the form,
+        # else cut out of it inside the program (decode_from_output)
+        self._out_shape = (self.max_batch, self.decode_chunk)
+        if self._n_expert_rows:
+            self._out_shape = (self.max_batch * self.decode_chunk
+                               + self.cache.expert_rows.shape[0],)
+        if self._out_shape != (self.max_batch, 1):
+            dstpu_decode = decode_from_output(
+                dstpu_decode, self.decode_chunk, self.max_batch)
+        self._decode_chunk_fn = self._decode_jit = jax.jit(
+            dstpu_decode, donate_argnums=(2,))
 
     def _devprof_warmup(self) -> None:
         """Devprof build-time precompile: dispatch every sweep program
@@ -1364,7 +1423,7 @@ class ServingEngine:
         with self._sp_build_program("decode_chunk", b=self.max_batch):
             _, self.cache = self._decode_chunk_fn(
                 self.params,
-                self._put(zi((self.max_batch, 1), np.int32)),
+                self._put(zi(self._out_shape, np.int32)),
                 self.cache, self._key, ordinal,
                 self._put(zi((self.max_batch,), np.float32)))
         logger.info("devprof warmup: %s", BUILD_LEDGER.since(n0))
@@ -1431,7 +1490,10 @@ class ServingEngine:
 
     @property
     def has_work(self) -> bool:
-        return bool(self.queue) or any(s is not None for s in self.slots)
+        # a step in flight is work: its tokens (all void, where no row
+        # of it lives) are read by the next ``step()``
+        return bool(self.queue) or self._flying is not None \
+            or any(s is not None for s in self.slots)
 
     # ------------------------------------- robustness: shed / fail / leaks
     def _shed(self, req_id, tier: Optional[str],
@@ -1532,7 +1594,11 @@ class ServingEngine:
         every scenario: each page must sit in exactly one of
         {free list, warm pool, live-owned, parked}, refcounts must
         match ownership multiplicity, and an idle engine must own
-        nothing."""
+        nothing.  A decode step in flight owns no page of its own: it
+        writes its rows' pages, which are the slots' until they end (a
+        row that ended under it wrote a released page before any
+        program that is handed the page runs), so the books are these
+        with or without one."""
         al = self.allocator
         probs: List[str] = []
         usable = self.trash_page
@@ -1605,6 +1671,9 @@ class ServingEngine:
         failover half of the fleet handoff; leaves ``check_leaks``
         clean on this engine."""
         out: List[Tuple[Request, int]] = []
+        # a step in flight is dropped unread (no device work here): its
+        # tokens were these rows', and are nobody's now
+        self._flying = None
         for b, s in enumerate(self.slots):
             if s is None:
                 continue
@@ -2783,13 +2852,16 @@ class ServingEngine:
             self.slots[b] = None
 
     # dstpu: hot-path
-    def _grow_pages(self, ahead: int = 1) -> None:
+    def _grow_pages(self, ahead: int = 1,
+                    preempt: bool = True) -> bool:
         """Before decode writes: map every page the next ``ahead`` token
         positions will touch (chunked decode provisions its whole window
         up front); preempt when the pool is dry.  Positions past the
         request's lifetime are NOT provisioned — their garbage writes
         clamp into the sequence's own final page, which is released when
-        it finishes."""
+        it finishes.  ``preempt=False`` is how a step in flight asks (no
+        row may leave under it, and ``seq_len`` counts it already): a dry
+        pool ends the walk with False and what it mapped stays mapped."""
         ps = self.page_size
         for b, s in enumerate(self.slots):
             if s is None or s.prefilling:
@@ -2806,6 +2878,8 @@ class ServingEngine:
                 # available counts the warm pool: allocate reclaims
                 # cached pages before any preemption is considered
                 while not self.allocator.available:
+                    if not preempt:
+                        return False
                     self._preempt_youngest()
                     if self.slots[b] is None:   # we preempted ourselves
                         break
@@ -2819,6 +2893,7 @@ class ServingEngine:
                 pg = self.allocator.allocate(s.seq_id, 1)[0]
                 self._table_host[b, slot_idx] = pg
                 self._table_dirty = True
+        return True
 
     # ------------------------------------------------------------------ step
     def _step_row(self) -> StepRow:
@@ -2881,6 +2956,12 @@ class ServingEngine:
 
     # dstpu: hot-path
     def _step_inner(self) -> None:
+        # What the host could not know when it let a step fly (an
+        # arrival between two calls, a row that ended on its own) finds
+        # that step in flight: it lands first, and the call goes on as
+        # it always has, over the state it has always seen.
+        landed = self._flying is not None and self._land_first(
+            self._why_sync(self._flying.rows))
         with self._sp_admit:
             if self._shed_deadline and self.queue:
                 # BEFORE admission: a request whose deadline already
@@ -2931,11 +3012,24 @@ class ServingEngine:
                  else K)
         ready = lambda: [(b, s) for b, s in enumerate(self.slots)
                          if s is not None and not s.prefilling]
+        active, flying = ready(), self._flying
+        if flying is not None and (len(active) != len(flying.rows) or any(
+                s is not t for (_, s), (_, t) in zip(active, flying.rows))):
+            # not the rows it flew with (a router or a test admitted
+            # between two calls): it lands, as at the top
+            landed, flying = self._land_first("other", newest=False), None
+        # ``why``: what keeps the step after the one this call lands on
+        # the ground (None: its rows are known to be these rows)
+        why = "other"
         with self._sp_grow:
-            active = ready()
-            if active:
+            if active and flying is None:
                 self._grow_pages(ahead=ahead)
                 active = ready()
+            if active and not landed and not self._spec_on:
+                why = self._why_behind(active, K)
+                if why is None and not self._grow_pages(
+                        ahead=K if flying else 2 * K, preempt=False):
+                    why = "other"
             if self._tel_on:
                 self._set_step_gauges(len(active))
         if active and self._spec_on:
@@ -2943,60 +3037,159 @@ class ServingEngine:
         elif active:
             with self._sp_upload:
                 self._upload_dirty()
-            with self._sp_inputs:
-                toks = np.zeros((self.max_batch, 1), np.int32)
-                temps = np.zeros((self.max_batch,), np.float32)
-                for b, s in active:
-                    toks[b, 0] = s.generated[-1] if s.generated \
-                        else s.req.tokens[-1]
-                    temps[b] = s.req.temperature
-                toks_d, ordinal_d, temps_d = (
-                    self._put(toks), self._next_dispatch(),
-                    self._put(temps))
+            fresh = flying is None
+            if fresh:
+                with self._sp_inputs:
+                    # the tokens in the form the program's output has:
+                    # a row's newest is the last of its K
+                    kf = K if self._out_shape else 1
+                    toks = np.zeros(self._out_shape or (self.max_batch, 1),
+                                    np.int32)
+                    temps = np.zeros((self.max_batch,), np.float32)
+                    newest = toks.reshape(-1)
+                    for b, s in active:
+                        newest[b * kf + kf - 1] = s.generated[-1] \
+                            if s.generated else s.req.tokens[-1]
+                        temps[b] = s.req.temperature
+                    toks_d, temps_d = self._put(toks), self._put(temps)
             with self._sp_dispatch:
-                if self._tel_on:
-                    self._row.dispatch("decode", self.max_batch,
-                                       len(active))
-                out, self.cache = self._decode_chunk_fn(
-                    self.params, toks_d, self.cache, self._key,
-                    ordinal_d, temps_d)
-                # trust the decode's structural seq_lens+K between
-                # composition changes (inactive rows drift, rebuilt on
-                # the next dirty upload)
-                for b, s in active:
-                    s.seq_len += K
-                self._c_decode_steps.inc(K)
-                self._c_decode_syncs.inc()
-                if self._state_row is not None:
-                    self._c_state_masked.inc(
-                        K * (self.max_batch - len(active)))
-                if self._tel_on:
-                    # the device has its program: what the host does
-                    # from here to the fetch is in its shadow
-                    self._row.edge()
-            with self._sp_token_sync:
-                # dstpu: host-sync-ok: the ONE device→host transfer per
-                # decode chunk (K tokens per sync — the module contract)
-                host_toks = np.asarray(out)
-                if self._n_expert_rows:
-                    host_toks = self._take_expert_rows(host_toks, K)
-            if self._tel_on:
-                # the fetch returned as the span ended: nothing is
-                # queued from its own clock reading on
-                self._row.drained = self._sp_token_sync.t1
-            with self._sp_append:
-                if self._trace_on and any(
-                        s.req.traced for _, s in active):
-                    # one event per BATCH sync (not per token): the
-                    # decode timeline at chunk granularity, nothing
-                    # hotter
-                    self.tracer.event("decode_batch", attrs={
-                        "active": len(active), "chunk": K})
-                for b, s in active:
-                    for j in range(K):
-                        self._append_token(b, int(host_toks[b, j]))
-                        if self.slots[b] is None:   # finished mid-chunk:
-                            break                   # rest is discard
+                if fresh:
+                    self._behind[self._behind_why] += 1
+                    flying = self._dispatch_decode(
+                        "decode", active, toks_d, temps_d)
+                nxt = None
+                if landed and self._fault_plan is None:
+                    # one call, one decode's tokens: this one's are the
+                    # next call's, and the caller's turn is in its shadow
+                    flying, nxt = None, flying
+                elif why is None:
+                    self._c_decode_ahead.inc()
+                    nxt = self._dispatch_decode(
+                        "decode_ahead", active, flying.out, flying.temps)
+                else:
+                    self._behind_why = why
+            self._flying = nxt
+            if flying is not None:
+                self._land(flying, newest=nxt is None and (
+                    fresh or not (self._tel_on and self._row.dispatched)))
+
+    # dstpu: hot-path
+    def _dispatch_decode(self, site: str, rows, prev, temps_d):
+        """Hand the device the ONE decode program over ``rows``, its
+        token operand ``prev`` in the form the program's output has (up
+        from the host, or the output of the step before it, still on the
+        device); what the host counts advances here, at dispatch.
+        Returns the step as ``_flying`` holds it."""
+        K = self.decode_chunk
+        if self._tel_on:
+            self._row.dispatch(site, self.max_batch, len(rows))
+        n = self._n_dispatch
+        out, self.cache = self._decode_chunk_fn(
+            self.params, prev, self.cache, self._key,
+            self._next_dispatch(), temps_d)
+        # trust the decode's structural seq_lens+K between composition
+        # changes (inactive rows drift, rebuilt on the next dirty upload)
+        for _, s in rows:
+            s.seq_len += K
+        self._c_decode_steps.inc(K)
+        self._c_decode_syncs.inc()
+        if self._state_row is not None:
+            self._c_state_masked.inc(K * (self.max_batch - len(rows)))
+        if self._tel_on:
+            # the device has its program: what the host does from here
+            # to the fetch is in its shadow
+            self._row.edge()
+        return _Flying(out, rows, n, temps_d)
+
+    def _rows_change(self) -> Optional[str]:
+        """Whether the next call's own work will change the decode rows,
+        from what the host holds: the reason (``DECODE_BEHIND``), or
+        None.  A prefilling slot with more than one chunk left changes
+        none: its row is the trash page at length 0 whichever of the two
+        programs runs first."""
+        if self._fault_plan is not None or self._decode_jit is None:
+            return "other"
+        if self._pending_boundary:
+            return "boundary"
+        free = False
+        for s in self.slots:
+            if s is None:
+                free = True
+            elif s.prefilling:
+                if s.promo is not None:
+                    return "other"
+                if len(s.req.tokens) - s.prefill_done <= (
+                        self.prefill_chunk or self.prefill_bucket):
+                    return "prefill"
+        return "admission" if free and self.queue else None
+
+    def _why_sync(self, rows) -> Optional[str]:
+        """A call begins with a step in flight over ``rows``: why it has
+        to land before anything else, or None."""
+        if any(self.slots[b] is not s for b, s in rows):
+            return "other"
+        return self._rows_change()
+
+    def _why_behind(self, rows, K: int) -> Optional[str]:
+        """THE RULE, read from the engine's state and set by no one.
+        With a decode step over ``rows`` about to be read: why the next
+        step has to wait for its tokens, or None where the next call's
+        decode rows are already known to be these rows, whatever the
+        tokens are: no row ends by count with this step's tokens (one
+        that ends on ``eos`` the host cannot know: its token in the next
+        step is void), and the next call admits nothing, finishes no
+        prompt and fetches no boundary token."""
+        for _, s in rows:
+            if len(s.generated) + K >= s.req.max_new_tokens:
+                return "finish"
+        return self._rows_change()
+
+    def _land_first(self, why: Optional[str], newest: bool = True) -> bool:
+        """Land the step in flight before going on, if there is a reason
+        to (the next host-fed dispatch is counted under it)."""
+        if why is None:
+            return False
+        flying, self._flying = self._flying, None
+        self._behind_why = why
+        self._land(flying, newest)
+        return True
+
+    # dstpu: hot-path
+    def _land(self, flying, newest: bool) -> None:
+        """Read a decode step's tokens and append them, each to the row
+        it was dispatched for if that row still lives: a row that ended
+        while the step flew (on ``eos``, failed, abandoned) left a void
+        token there, which is dropped and counted nowhere.  ``newest``:
+        nothing was dispatched behind it, so the device has nothing
+        queued once the fetch returns."""
+        out, rows, ordinal, _ = flying
+        K = self.decode_chunk
+        with self._sp_token_sync:
+            # dstpu: host-sync-ok: the ONE device→host transfer per
+            # decode chunk (K tokens per sync — the module contract)
+            host_toks = np.asarray(out)
+            if self._n_expert_rows:
+                host_toks = self._take_expert_rows(host_toks, K)
+        if self._tel_on and newest:
+            # the fetch returned as the span ended: nothing is queued
+            # from its own clock reading on
+            self._row.drained = self._sp_token_sync.t1
+        with self._sp_append:
+            live = [(b, s) for b, s in rows if self.slots[b] is s]
+            if self._trace_on and any(s.req.traced for _, s in live):
+                # one event per BATCH sync (not per token): the decode
+                # timeline at chunk granularity, nothing hotter
+                self.tracer.event("decode_batch", attrs={
+                    "active": len(live), "chunk": K})
+            for b, s in live:
+                for j in range(K):
+                    self._append_token(b, int(host_toks[b, j]))
+                    if self.slots[b] is None:   # finished mid-chunk:
+                        break                   # rest is discard
+        if not live and self._flying is None:
+            # a step no row lived to see drew nothing: the next one
+            # draws what it would have drawn
+            self._n_dispatch = ordinal
 
     def _take_expert_rows(self, flat: np.ndarray, K: int) -> np.ndarray:
         """Split what a decode program of a family that counts its
@@ -3148,6 +3341,7 @@ class ServingEngine:
         K = self.speculative.draft_tokens
         Bm = self.max_batch
         self._c_decode_syncs.inc()
+        self._behind["other"] += 1      # a sweep's tokens are read here
         self._c_decode_steps.inc(K + 1)
         self._c_spec_sweeps.inc()
         if self._tel_on:
@@ -3409,6 +3603,16 @@ class ServingEngine:
             # what the steps of the process's engines did (the step
             # ledger's running totals and its newest row)
             "steps": STEP_LEDGER.snapshot(last=1),
+            # the decode dispatches: those that went ahead of the host
+            # (their tokens came from the step before, on the device)
+            # and those that stayed behind it, by reason; the two sum
+            # to ``dispatches`` (= serving_decode_syncs)
+            "decode": {
+                "dispatches": int(self._c_decode_syncs.value),
+                "ahead": int(self._c_decode_ahead.value),
+                "behind": dict(self._behind),
+                "in_flight": self._flying is not None,
+            },
             # the BOUND port (meaningful when http_port=0 asked for an
             # ephemeral bind): how a parent process that spawned this
             # replica learns where to scrape it
@@ -3590,6 +3794,7 @@ class ServingEngine:
         if self._closed:
             return
         self._closed = True
+        self._flying = None     # a step in flight is never read
         if self._owns_fault_plan:
             faults_mod.clear_fault_plan(self._fault_plan)
         ex = self._tel_exporter

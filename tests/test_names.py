@@ -177,7 +177,8 @@ def test_a_captured_step_is_its_row_in_the_ledger(capture):
         # the decode's edge lies in its phase, a prefill's in admit
         for k in kids:
             if k.name == "dstpu/dispatch":
-                phase = {"decode": "dispatch", "prefill": "admit",
+                phase = {"decode": "dispatch", "decode_ahead": "dispatch",
+                         "prefill": "admit",
                          "chunk": "prefill"}[k.stats["site"]]
                 (box,) = [c for c in kids
                           if c.name == f"dstpu/serving_{phase}"]

@@ -856,10 +856,13 @@ class TestEnginePolicy:
         assert cnt["serving_kernel_fallbacks"] == 0
         steps = eng.statusz()["steps"]
         assert steps["programs"]["decode"][0] \
+            + steps["programs"]["decode_ahead"][0] \
             >= cnt["serving_decode_syncs"] > 0
         assert steps["programs"]["prefill"][0] >= len(PROMPTS)
         (last,) = steps["rows"]
-        assert last["programs"]["decode"] == [1, KW["max_batch"], 1]
+        # the last step read the decode that flew in behind the one before
+        assert last["programs"]["decode"][0] \
+            + last["programs"]["decode_ahead"][0] <= 1
         eng.shutdown()
 
     def test_zero_inference_rejects_quantized_resident(

@@ -127,7 +127,9 @@ def _admit_two(eng, log):
     del log[:]
     eng.step()
     assert counters(eng)["serving_boundary_tokens"] == len(PROMPTS) + 2
-    return {"dstpu_prefill": 2, "dstpu_boundary": 2, "dstpu_decode": 1}
+    # the step's own decode and, its rows being known to stay, the next
+    # one, dispatched ahead of the fetch
+    return {"dstpu_prefill": 2, "dstpu_boundary": 2, "dstpu_decode": 2}
 
 
 def _chunk_end(eng, log):
@@ -139,7 +141,7 @@ def _chunk_end(eng, log):
     del log[:]
     eng.step()
     assert len(slot.generated) == 2         # the boundary token, one decode
-    return {"dstpu_chunk": 1, "dstpu_boundary": 1, "dstpu_decode": 1}
+    return {"dstpu_chunk": 1, "dstpu_boundary": 1, "dstpu_decode": 2}
 
 
 def _plain(eng, log):
@@ -194,12 +196,22 @@ SITE_OF = {"dstpu_prefill": "prefill", "dstpu_chunk": "chunk",
            "dstpu_decode": "decode", "dstpu_verify": "sweep"}
 
 
+def decodes(programs):
+    """A row's decode programs, whichever way their tokens came: up
+    from the host (``decode``) or from the step before them, on the
+    device (``decode_ahead``)."""
+    return [a + b for a, b in zip(programs["decode"],
+                                  programs["decode_ahead"])]
+
+
 def _rows_admit_two(eng):
-    return {"prefill": [2, 16, 8], "decode": [1, 4, 2]}
+    return {"prefill": [2, 16, 8], "decode": [1, 4, 2],
+            "decode_ahead": [1, 4, 2]}
 
 
 def _rows_chunk_end(eng):
-    return {"chunk": [1, 8, 5], "decode": [1, 4, 1]}    # 21 = 8 + 8 + 5
+    return {"chunk": [1, 8, 5], "decode": [1, 4, 1],    # 21 = 8 + 8 + 5
+            "decode_ahead": [1, 4, 1]}
 
 
 def _rows_plain(eng):
@@ -207,7 +219,7 @@ def _rows_plain(eng):
     # back and what the drafter proposed
     return ({"sweep": [1, 4 * 4, 1 + eng._c_spec_drafted.value
                        - eng._drafted_before]} if eng._spec_on
-            else {"decode": [1, 4, 1]})
+            else {"decode_ahead": [1, 4, 1]})    # the step before flew
 
 
 @pytest.mark.parametrize("drive,kw,want", [
@@ -242,7 +254,11 @@ def test_a_row_counts_what_the_step_dispatched(model, drive, kw, want):
         if name in SITE_OF:
             seen[SITE_OF[name]] = seen.get(SITE_OF[name], 0) + 1
     programs = {site: p for site, p in row["programs"].items() if p[0]}
-    assert {site: p[0] for site, p in programs.items()} == seen
+    by_site = {}
+    for site, p in programs.items():
+        site = site.replace("_ahead", "")   # one program, either way
+        by_site[site] = by_site.get(site, 0) + p[0]
+    assert by_site == seen
     assert programs == want(eng)
     # a prefill that completed fetched its boundary token in this step
     assert row["boundary_tokens"] == got.count("dstpu_boundary")
@@ -266,7 +282,7 @@ def test_a_step_writes_one_row_and_none_with_telemetry_off(model):
     assert sum(r["boundary_tokens"] for r in rows) == len(PROMPTS)
     c = counters(eng)
     # what the two counters that went said, the rows say
-    assert sum(r["programs"]["decode"][0] for r in rows) \
+    assert sum(decodes(r["programs"])[0] for r in rows) \
         == c["serving_decode_syncs"]
     assert sum(r["programs"]["chunk"][0] for r in rows) \
         == c["serving_prefill_chunks"]
@@ -314,30 +330,37 @@ def test_exposed_seconds_follow_a_fetch_and_end_at_a_dispatch(gpt2_model):
         assert (row["exposed_s"], row["exposed"], row["drained"]) \
             == (0.0, {}, False)
     # the third ends the prompt: its boundary token is fetched, and
-    # from there to the decode dispatch the device waits for the host
-    assert third["boundary_tokens"] == 1 and third["drained"]
+    # from there to the decode dispatch the device waits for the host;
+    # the next decode goes out behind that one, ahead of its fetch, so
+    # the step ends with a program queued: nothing after is exposed
+    assert third["boundary_tokens"] == 1 and not third["drained"]
+    assert third["programs"]["decode"][0] == 1
+    assert third["programs"]["decode_ahead"][0] == 1
     assert set(third["exposed"]) == {"boundary", "grow_pages", "upload",
-                                     "inputs", "dispatch", "append"}
-    assert "admit" not in third["exposed"]
-    assert "token_sync" not in third["exposed"]
-    # the fourth begins drained: idle from its first instant to its
-    # decode dispatch, then again from the token fetch to its end
-    assert set(fourth["exposed"]) >= {"admit", "prefill", "boundary",
-                                      "grow_pages", "inputs", "append"}
-    assert "token_sync" not in fourth["exposed"]
-    for row in (third, fourth):
-        assert row["exposed_s"] == pytest.approx(
-            sum(b - a for a, b in row["idle"]))
-        assert 0 < sum(row["exposed"].values()) <= row["exposed_s"] \
-            < row["t1"] - row["t0"]
-        for name, seconds in row["exposed"].items():
-            assert seconds <= row["phases"][name] + 1e-9
-    # a chunk that goes out while a slot decodes ends the idle stretch
-    # in the prefill phase: nothing after it is exposed until the fetch
+                                     "inputs", "dispatch"}
+    assert third["exposed_s"] == pytest.approx(
+        sum(b - a for a, b in third["idle"]))
+    assert 0 < sum(third["exposed"].values()) <= third["exposed_s"] \
+        < third["t1"] - third["t0"]
+    for name, seconds in third["exposed"].items():
+        assert seconds <= third["phases"][name] + 1e-9
+    # the fourth begins with that step in flight and puts the next one
+    # behind it before it reads it: no instant of it is known to be idle
+    assert fourth["programs"]["decode_ahead"][0] == 1
+    assert fourth["programs"]["decode"][0] == 0
+    assert (fourth["exposed_s"], fourth["exposed"], fourth["drained"]) \
+        == (0.0, {}, False)
+    assert {"token_sync", "append"} <= set(fourth["phases"])
+    # an arrival beside a free slot finds a step in flight: that step
+    # lands first (its fetch returns with nothing queued), and the chunk
+    # that goes out in the prefill phase ends the idle stretch; the
+    # decode behind it is this call's last program and is left to fly
     eng.submit("next", PROMPTS[3], max_new_tokens=3)
     (row,) = ledger_rows(stepped(eng, 1))
     assert row["programs"]["chunk"][0] == row["programs"]["decode"][0] == 1
+    assert row["programs"]["decode_ahead"][0] == 0 and not row["drained"]
     assert set(row["exposed"]) == {"admit", "prefill", "append"}
+    assert eng.statusz()["decode"]["behind"]["admission"] == 1
 
 
 def test_what_is_dispatched_between_steps_falls_to_the_next_row(gpt2_model):
@@ -349,7 +372,9 @@ def test_what_is_dispatched_between_steps_falls_to_the_next_row(gpt2_model):
     eng.submit("a", PROMPTS[0], max_new_tokens=6)
     (n,) = stepped(eng, 1)
     (before,) = ledger_rows([n])
-    assert before["drained"] and before["admitted"] == 1
+    # the next decode is in flight behind the one this step read
+    assert not before["drained"] and before["admitted"] == 1
+    assert eng._flying is not None
     eng.submit("b", PROMPTS[1], max_new_tokens=6)
     assert eng._admit_one()                 # a prefill goes out
     eng._flush_boundary()
@@ -358,6 +383,8 @@ def test_what_is_dispatched_between_steps_falls_to_the_next_row(gpt2_model):
     (row,) = ledger_rows(stepped(eng, 1))
     assert row["admitted"] == 1 and row["boundary_tokens"] == 1
     assert row["programs"]["prefill"] == [1, 8, 5]
+    # the rows are not the rows the step in flight left with: it lands,
+    # and this step's decode takes its tokens from the host
     assert row["programs"]["decode"] == [1, 4, 2]
     # the stretch that prefill ended began in the row before: this one
     # is idle from the boundary fetch (before it began) to its decode
@@ -393,7 +420,7 @@ def test_the_ring_drops_the_oldest_and_outlives_the_engine(gpt2_model):
         polled.snapshot(last=1)
     seen = polled.snapshot(last=1)
     assert (seen["steps"], seen["unseen"]) == (11, 0)
-    assert seen["programs"]["decode"] == [11, 44, 11]
+    assert decodes(seen["programs"]) == [11, 44, 11]
     assert seen["programs"]["prefill"] == [1, 8, 3]
     row = eng._row
     eng._row = StepRow("dstpu", row._step, row._tick, row._phases,
@@ -410,7 +437,10 @@ def test_the_ring_drops_the_oldest_and_outlives_the_engine(gpt2_model):
     # a reader folds what it sees into the sums (the hot path keeps
     # none): this one came after the ring had turned
     assert (snap["steps"], snap["unseen"]) == (4, 7)
-    assert snap["programs"]["decode"] == [4, 16, 4]
+    # the first step dispatched two decodes (its own and the next,
+    # ahead); the last read its tokens and, the row ending by count,
+    # dispatched none
+    assert decodes(snap["programs"]) == [3, 12, 3]
     assert snap["step_s"] > snap["exposed_s"] > 0
     again = small.snapshot(last=2)
     assert [r["n"] for r in again["rows"]] == ordinals[-2:]
