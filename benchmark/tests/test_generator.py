@@ -4,6 +4,7 @@ import collections
 
 import pytest
 
+from benchmark import manifest
 from benchmark.harness import cell, generator
 
 SEEDS = [0, 1, 2, 3, 7, 11, 2 ** 31 + 5, 2 ** 31 + 6, 123456789, 3000000000]
@@ -38,6 +39,42 @@ def test_open_loop_offers_the_same_work_whatever_the_seed(mix_name, seconds):
         assert all(0 <= r.due < seconds for r in reqs)
         assert [r.due for r in reqs] == sorted(r.due for r in reqs)
         assert all(r.prompt_len + r.new_tokens <= 1024 for r in reqs)
+
+
+# cell -> its mix, for the cells whose arrivals keep a schedule
+OPEN_CELLS = {
+    w["name"]: cell.load_json("traffic", w["traffic"])
+    for w in manifest.build()["workloads"]
+    if cell.load_json("traffic", w["traffic"])["kind"] == "serve_open"}
+
+
+def test_the_open_cells_are_found():
+    assert "gpt2-1.3b.serve.chat-0.8knee" in OPEN_CELLS
+
+
+@pytest.mark.parametrize("cell_name", sorted(OPEN_CELLS))
+def test_an_open_cell_offers_four_fifths_of_its_knee(cell_name):
+    """A mix that says 0.8 x the knee offers it: a benchmark PR that
+    finds the knee again writes both numbers, to two decimals."""
+    mix = OPEN_CELLS[cell_name]
+    assert mix["rate_rps"] == round(0.8 * mix["knee_rps"], 2)
+
+
+@pytest.mark.parametrize("cell_name", sorted(OPEN_CELLS))
+def test_a_warm_start_holds_no_more_requests_than_the_cell_has_slots(
+        cell_name):
+    """The warm start submits round(rate x lifetime) requests at their
+    residual life: more than the slots and the window opens on a queue
+    no steady state below the knee holds."""
+    mix = OPEN_CELLS[cell_name]
+    slots = cell.load_json("workloads", cell_name)["engine"]["max_batch"]
+    n_held = round(mix["rate_rps"] * mix["mean_lifetime_s"])
+    assert 0 < n_held <= slots
+    # the held requests come first, all due as the warm-up starts
+    reqs = generator.open_loop(mix, SEEDS[0], 51)
+    warm = -mix["warm_seconds"]
+    assert [r.due for r in reqs[:n_held]] == [warm] * n_held
+    assert all(r.due > warm for r in reqs[n_held:])
 
 
 @pytest.mark.parametrize("mix_name", BACKLOG)

@@ -110,13 +110,13 @@ def test_the_manifest_keeps_what_it_had_and_appends():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         assert json.load(f) == built
     names = [m["name"] for m in built["per_layer"]]
-    first = min(i for i, n in enumerate(names) if n.startswith(NEW))
-    assert all(n.startswith(NEW) for n in names[first:])
-    assert built["configs"][-1]["name"] == CONFIG
-    assert built["workloads"][-1]["name"] == CELL
-    for m in built["end_to_end"] + built["per_layer"]:
-        if CELL in m.get("workloads", ()):
-            assert m["workloads"][-1] == CELL
+    # later PRs appended theirs behind: this PR's stay in one run, behind
+    # every name without a version's prefix
+    mine = [i for i, n in enumerate(names) if n.startswith(NEW)]
+    assert mine == list(range(mine[0], mine[-1] + 1))
+    assert all(n[0] == "v" and n[1:3].isdigit() for n in names[mine[0]:])
+    assert CONFIG in [c["name"] for c in built["configs"]]
+    assert CELL in [w["name"] for w in built["workloads"]]
 
 
 @pytest.mark.parametrize("rid,plen,total,want", [
