@@ -1798,7 +1798,8 @@ def _state_tile(slots: int, heads: int, head_bytes: int,
     return 1, most(heads, fit)
 
 
-def _state_step_kernel(layer_ref, *refs, rule, kinds, o_kind, tile, ahead):
+def _state_step_kernel(layer_ref, *refs, rule, kinds, o_kind, tile, ahead,
+                       in_place=False):
     """Layer ``layer_ref[0]`` of the state, which stays where it is
     (``s_hbm`` and ``out_hbm`` are one buffer), a tile [slots, heads, R,
     C] at a time through ``buf``: tile t is stepped in its buffer while
@@ -1810,7 +1811,11 @@ def _state_step_kernel(layer_ref, *refs, rule, kinds, o_kind, tile, ahead):
     of its array, R on the lanes, and is turned on the spot: the
     diagonal of its [R, R] broadcast, summed over the lanes (one number
     and zeros: exact); a result along R goes back the same way, summed
-    over the sublanes."""
+    over the sublanes.  A vector of another shape (``rows``: [n, w], what
+    the rule expands itself) is handed over as it is, and so is an ``o``
+    of n rows.  ``in_place``: the rule takes the head's state as the
+    buffer's [R, C] view and reads and writes it a piece at a time itself
+    (a head of megabytes is no value)."""
     n = kinds.count("one")
     s_hbm, *tiles, o_ref, out_hbm, buf, r_sem, w_sem = refs[n:]
     ones, tiles = iter(refs[:n]), iter(tiles)
@@ -1818,8 +1823,11 @@ def _state_step_kernel(layer_ref, *refs, rule, kinds, o_kind, tile, ahead):
     _, B, H, R, C = s_hbm.shape
     (bb, hb), layer, held = tile, layer_ref[0], buf.shape[0]
     n_tiles = (B // bb) * (H // hb)
-    eye = (jax.lax.broadcasted_iota(jnp.int32, (R, R), 0)
-           == jax.lax.broadcasted_iota(jnp.int32, (R, R), 1))
+    # (a rule in place turns what it needs turned itself: its R is not
+    # its vectors' width)
+    eye = None if in_place else (
+        jax.lax.broadcasted_iota(jnp.int32, (R, R), 0)
+        == jax.lax.broadcasted_iota(jnp.int32, (R, R), 1))
 
     def first(t):
         """Tile t's first slot and head."""
@@ -1837,6 +1845,10 @@ def _state_step_kernel(layer_ref, *refs, rule, kinds, o_kind, tile, ahead):
             return ref[b * H + h]
         if kind == "tile":
             return ref[h]
+        if isinstance(kind, int):       # rows: a head's ``kind`` of them
+            at = h * kind
+            return ref[b, pl.ds(pl.multiple_of(at, 8) if kind % 8 == 0
+                                else at, kind), :]
         v = ref[pl.ds(b, 1), :] if ref.ndim == 2 else ref[b, pl.ds(h, 1), :]
         return (jnp.sum(jnp.where(eye, v, 0.0), axis=1, keepdims=True)
                 if kind == "col" else v)
@@ -1856,14 +1868,22 @@ def _state_step_kernel(layer_ref, *refs, rule, kinds, o_kind, tile, ahead):
 
         def head(i, _):
             b, h = i // hb, i % hb
-            # float32 whatever the state is kept in; rounded on its way out
-            o, S = rule(cur[b, h].astype(jnp.float32),
-                        *(vector(ref, kind, b0 + b, h0 + h)
-                          for ref, kind in zip(v_refs, kinds)))
+            vectors = (vector(ref, kind, b0 + b, h0 + h)
+                       for ref, kind in zip(v_refs, kinds))
+            if in_place:
+                o, S = rule(cur.at[b, h], *vectors), None
+            else:
+                # float32 whatever the state is kept in; rounded on its
+                # way out
+                o, S = rule(cur[b, h].astype(jnp.float32), *vectors)
             if o_kind == "col":
                 o = jnp.sum(jnp.where(eye, o, 0.0), axis=0, keepdims=True)
-            o_ref[b0 + b, pl.ds(h0 + h, 1), :] = o
-            cur[b, h] = S.astype(cur.dtype)
+            n = o.shape[0]
+            o_ref[b0 + b, pl.ds(h0 + h, 1) if n == 1 else pl.ds(
+                pl.multiple_of((h0 + h) * n, 8) if n % 8 == 0
+                else (h0 + h) * n, n), :] = o
+            if S is not None:
+                cur[b, h] = S.astype(cur.dtype)
 
         jax.lax.fori_loop(0, bb * hb, head, None)
         copy(t, True).start()
@@ -1876,7 +1896,7 @@ def _state_step_kernel(layer_ref, *refs, rule, kinds, o_kind, tile, ahead):
 
 
 def state_step(rule, state, layer, vectors, *, interpret: bool = False,
-               tile_bytes: Optional[int] = None):
+               tile_bytes: Optional[int] = None, in_place=None):
     """One token of a recurrence on layer ``layer`` of the carried state
     ``state`` [layers, slots, H, R, C] (``STATE_DTYPE``), in place: the
     Mosaic kernel ``dstpu_state_step`` reads a tile of it, applies
@@ -1896,12 +1916,26 @@ def state_step(rule, state, layer, vectors, *, interpret: bool = False,
     they and ``o`` are whole in the kernel's memory.  Returns (o [slots,
     H, 1, C] or [slots, H, R, 1], the buffer).  The tile is read from
     the shapes (:func:`_state_tile`); ``tile_bytes`` is a measurement's
-    and a test's."""
+    and a test's.
+
+    A vector of any other shape [slots, H, n, w] (n rows a state head of
+    what the rule expands in the fast memory itself: a key of w numbers
+    that stands for a row of C, the n query heads that read one state) is
+    handed to the rule as its [n, w], and ``o`` may be such rows too: [slots,
+    H, n, w]; whole (8, 128) tiles a head where n is a multiple of 8.
+    ``in_place(S_ref, *vectors) -> o``: the same rule on a head's state
+    where it lies in the fast memory, a reference [R, C] it reads and
+    writes a piece at a time: what the kernel runs where a head's state
+    is megabytes (one head a tile, whatever ``_STATE_TILE_BYTES``);
+    ``rule`` is then not read, and ``o`` is rows as the first of the
+    vectors that is rows: the rows that read the state."""
     L, B, H, R, C = state.shape
     kind_of = lambda shape: {(1, C): "row", (R, 1): "col", (1, 1): "one",
-                             (R, C): "tile"}[tuple(shape)]
+                             (R, C): "tile"}.get(tuple(shape), shape[0])
     kinds = tuple(kind_of(v.shape[2:]) for v in vectors)
-    o_shape = jax.eval_shape(
+    o_shape = next(
+        v.shape[2:] for v, k in zip(vectors, kinds) if isinstance(k, int)
+    ) if in_place else jax.eval_shape(
         rule, jax.ShapeDtypeStruct((R, C), jnp.float32),
         *(jax.ShapeDtypeStruct(() if k == "one" else v.shape[2:], v.dtype)
           for v, k in zip(vectors, kinds)))[0].shape
@@ -1915,18 +1949,21 @@ def state_step(rule, state, layer, vectors, *, interpret: bool = False,
     # rows, which the program then re-laid on its way in and out, v5e)
     ones = [v.reshape(-1) for v, k in zip(vectors, kinds) if k == "one"]
     rows = [v.reshape((H, R, C) if k == "tile" else
+                      (B, -1, v.shape[-1]) if isinstance(k, int) else
                       (B,) + ((H,) if v.shape[1] > 1 else ()) + (-1,))
             for v, k in zip(vectors, kinds) if k != "one"]
-    o = jax.ShapeDtypeStruct((B, H, R if o_kind == "col" else C),
-                             jnp.float32)
+    o = jax.ShapeDtypeStruct(
+        (B, H * o_kind, o_shape[1]) if isinstance(o_kind, int)
+        else (B, H, R if o_kind == "col" else C), jnp.float32)
     held = min(_STATE_TILES, (B // tile[0]) * (H // tile[1]))
     buf = jax.ShapeDtypeStruct((held,) + tile + (R, C), state.dtype)
     in_vmem = sum(a.size * a.dtype.itemsize for a in rows + [o, buf])
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     o, state = pl.pallas_call(
-        functools.partial(_state_step_kernel, rule=rule, kinds=kinds,
-                          o_kind=o_kind, tile=tile,
-                          ahead=max(1, min(_STATE_TILES_AHEAD, held - 1))),
+        functools.partial(_state_step_kernel, rule=in_place or rule,
+                          kinds=kinds, o_kind=o_kind, tile=tile,
+                          ahead=max(1, min(_STATE_TILES_AHEAD, held - 1)),
+                          **({"in_place": True} if in_place else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1 + len(ones),   # layer, the scalars
             grid=(1,),
@@ -1980,6 +2017,8 @@ def paged_period_loop(period, x, stacks, cache: PagedKVCache, periods: int):
 # shape: 0.68 at 2 heads, 0.53 at 4, 0.47 at 8, 0.48 at 16; v5e, PR 50).
 _CHUNK_SPAN_BYTES = 10 << 20
 _CHUNK_HEADS = 8
+# ... of states that fit in this together (a head of megabytes goes alone)
+_CHUNK_STATE_BYTES = 8 << 20
 
 
 def state_chunker(stated, *, tp: bool, interpret: bool) -> Tuple[str, str]:
@@ -1989,30 +2028,34 @@ def state_chunker(stated, *, tp: bool, interpret: bool) -> Tuple[str, str]:
     build, as :func:`state_stepper` is for a decode step.  ``stated``:
     the family's ``(Recurrent, cfg)`` or None.  Where the family states
     one block of its chunked rule on one head (``Recurrent.block``), on
-    one device on the TPU, a head's state of whole 128-lane tiles stays
+    one device on the TPU, a head's state of whole (8, 128) tiles stays
     in VMEM from block to block (:func:`state_chunk`); elsewhere ``mix``
     runs the family's chunked rule in XLA on the slot's rows."""
     shape = stated and stated[0].block and stated[0].state_row(stated[1]).state
     for off, why in ((not shape, "the family states no block of its rule"),
                      (tp, "tp: the kernel is one device's"),
                      (interpret, "interpret: no TPU backend"),
-                     (any(w % 128 for w in (shape or ())[-2:]),
+                     (bool(shape) and (shape[-1] % 128 or shape[-2] % 8),
                       "a head's state is not whole 128-lane tiles")):
         if off:
             return "xla", why
     return "pallas", "a chunk's blocks on one device, the state in VMEM"
 
 
-def _state_chunk_kernel(s_ref, *refs, rule, takes, block: int, heads: int):
+def _state_chunk_kernel(s_ref, *refs, rule, takes, block: int, heads: int,
+                        in_place: bool = False):
     """``heads`` heads' states [heads, R, C] through the ``span`` tokens
     of this grid step, a block at a time, all the heads at once: ``acc``
     holds them in f32 from a head group's first step (read from
     ``s_ref``) to its last (written to ``out_ref``).  The rule gets
     operand m's heads of the step's tile stacked (``takes[m]``: a head's
     width, how many of them), the heads' ``col`` [heads, block, n] and
-    ``lane`` [heads, n, block], and gives their ``o`` [heads, block, C]."""
+    ``lane`` [heads, n, block], and gives their ``o`` [heads, block, C].
+    ``in_place``: the rule takes ``acc`` itself in S's place, moves it a
+    piece at a time and gives ``o`` alone."""
     *m_refs, col_ref, lane_ref, o_ref, out_ref, acc = refs
-    step, span, width = pl.program_id(2), o_ref.shape[0], acc.shape[-1]
+    step, span = pl.program_id(2), o_ref.shape[0]
+    width = o_ref.shape[-1] // heads
     nc, nl = col_ref.shape[-1] // heads, lane_ref.shape[-2] // heads
 
     @pl.when(step == 0)
@@ -2022,37 +2065,44 @@ def _state_chunk_kernel(s_ref, *refs, rule, takes, block: int, heads: int):
     def one(j, S):
         at = pl.multiple_of(j * block, block)
         rows = pl.ds(at, block)
-        o, S = rule(
+        out = rule(
             S, *(jnp.stack([ref[rows, h * w:(h + 1) * w] for h in range(n)])
                  for ref, (w, n) in zip(m_refs, takes)),
             jnp.stack([col_ref[rows, h * nc:(h + 1) * nc]
                        for h in range(heads)]),
             jnp.stack([lane_ref[j, h * nl:(h + 1) * nl, :]
                        for h in range(heads)]))
+        o, S = (out, None) if in_place else out
         for h in range(heads):
             o_ref[rows, h * width:(h + 1) * width] = o[h]
         return S
 
-    S = acc[...]
-    acc[...] = one(0, S) if span == block else jax.lax.fori_loop(
-        0, span // block, one, S)
+    if in_place:
+        jax.lax.fori_loop(0, span // block, lambda j, _: one(j, acc), None)
+    else:
+        S = acc[...]
+        acc[...] = one(0, S) if span == block else jax.lax.fori_loop(
+            0, span // block, one, S)
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _():
         out_ref[...] = acc[...].astype(out_ref.dtype)
 
 
-def _chunk_heads(H: int, reps) -> int:
+def _chunk_heads(H: int, reps, head_bytes: int = 0) -> int:
     """How many of the state's heads a grid step carries: the most
-    within ``_CHUNK_HEADS`` that divide H and hold, or divide, what one
-    head of each operand serves."""
-    return max(h for h in range(1, min(H, _CHUNK_HEADS) + 1)
+    within ``_CHUNK_HEADS``, and within ``_CHUNK_STATE_BYTES`` of state,
+    that divide H and hold, or divide, what one head of each operand
+    serves."""
+    most = min(H, _CHUNK_HEADS, max(1, _CHUNK_STATE_BYTES
+                                    // max(1, head_bytes)))
+    return max(h for h in range(1, most + 1)
                if H % h == 0 and not any(h % r and r % h for r in reps))
 
 
 def state_chunk(rule, S, mats, cols, lanes, *, block: int,
                 interpret: bool = False, heads: Optional[int] = None,
-                span: Optional[int] = None):
+                span: Optional[int] = None, in_place: bool = False):
     """A prompt chunk of a recurrence on the rows' state ``S`` [B, H, R,
     C] (``STATE_DTYPE``; f32 inside), in place: the Mosaic kernel
     ``dstpu_state_chunk`` holds a few heads' states in VMEM while the
@@ -2074,13 +2124,21 @@ def state_chunk(rule, S, mats, cols, lanes, *, block: int,
     nothing.  T is whole blocks (a caller pads with tokens that move
     nothing).  Returns (o [B, T, H, C] f32, S).  The heads a grid step
     carries and the tokens it spans are read from the shapes; ``heads``
-    and ``span`` are a measurement's and a test's."""
+    and ``span`` are a measurement's and a test's.
+
+    ``in_place``: ``rule(S_ref [h, R, C], *tiles, col, lane) -> o``, the
+    step's states a reference the rule reads and writes a piece at a
+    time (a head of megabytes is no value of a kernel's; such a head goes
+    a grid step alone), and ``o`` is as wide a state head as the widest
+    head of ``mats`` ([B, T, H, width]): an operand whose head holds the
+    queries of several heads that read one state comes back as wide."""
     B, H, R, C = S.shape
     T, f32 = mats[0].shape[1], jnp.float32
     if T % block:
         raise ValueError(f"{T} tokens are not whole blocks of {block}")
     reps = tuple(H // m.shape[2] for m in mats)
-    hb = heads or _chunk_heads(H, reps)
+    width = max(m.shape[3] for m in mats) if in_place else C
+    hb = heads or _chunk_heads(H, reps, R * C * 4)
     if any(hb % rep and rep % hb for rep in reps) or H % hb:
         raise ValueError(f"{hb} heads a step over operands serving {reps}")
     # an operand's head's width and how many of its heads a step holds
@@ -2090,7 +2148,7 @@ def state_chunk(rule, S, mats, cols, lanes, *, block: int,
     tiles = lambda n, tile: -(-n // tile) * tile
     # f32, two buffers each: the operands' and o's lanes, a token's cols in
     # whole 128-lane tiles, a block's lanes in whole (8, 128) tiles
-    per_token = 4 * 2 * (sum(held) + hb * C + tiles(hb * nc, 128)
+    per_token = 4 * 2 * (sum(held) + hb * width + tiles(hb * nc, 128)
                          + tiles(hb * nl, 8) * tiles(block, 128) // block)
     if span is None:
         span = block
@@ -2105,7 +2163,8 @@ def state_chunk(rule, S, mats, cols, lanes, *, block: int,
     state = pl.BlockSpec((None, hb, R, C), lambda b, g, n: (b, g, 0, 0))
     o, S = pl.pallas_call(
         functools.partial(_state_chunk_kernel, rule=rule, takes=takes,
-                          block=block, heads=hb),
+                          block=block, heads=hb, **(
+                              {"in_place": True} if in_place else {})),
         grid=(B, G, T // span),
         in_specs=[state] + [
             pl.BlockSpec((None, span, w), functools.partial(
@@ -2115,16 +2174,18 @@ def state_chunk(rule, S, mats, cols, lanes, *, block: int,
                          lambda b, g, n: (b, g, n, 0)),
             pl.BlockSpec((None, None, span // block, hb * nl, block),
                          lambda b, g, n: (b, g, n, 0, 0))],
-        out_specs=[pl.BlockSpec((None, span, hb * C),
+        out_specs=[pl.BlockSpec((None, span, hb * width),
                                 lambda b, g, n: (b, n, g)), state],
-        out_shape=[jax.ShapeDtypeStruct((B, T, H * C), f32),
+        out_shape=[jax.ShapeDtypeStruct((B, T, H * width), f32),
                    jax.ShapeDtypeStruct(S.shape, S.dtype)],
         scratch_shapes=[pltpu.VMEM((hb, R, C), f32)],
         input_output_aliases={0: 1},        # the rows come back in place
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=max(32 << 20, span * per_token + (24 << 20))),
+            # (the step's states: in and out twice each, and ``acc``)
+            vmem_limit_bytes=max(32 << 20, span * per_token + (24 << 20)
+                                 + max(0, 5 * hb * R * C * 4 - (16 << 20)))),
         interpret=interpret,
         name="dstpu_state_chunk",
     )(S, *flat, cols, lanes)
-    return o.reshape(B, T, H, C), S
+    return o.reshape(B, T, H, width), S

@@ -126,7 +126,8 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, read, *,
         n_lead = jax.tree.leaves(params[fam.lead[0]])[0].shape[0]
         x, cache = paged_layer_loop(lead, x, params[fam.lead[0]], cache,
                                     count=n_lead)
-    n_pool = cache.k.shape[0] - n_lead
+    # (a family with no pool layer at all: the cache holds no pool)
+    n_pool = 0 if cache.k is None else cache.k.shape[0] - n_lead
     every_slot = T == 1 and slot is None
     stepped = state_stepper(decode=every_slot, tp=tp)[0] == "pallas"
     step = functools.partial(state_step, interpret=interpret)
@@ -144,7 +145,8 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, read, *,
 
     # a kind's stack stays whole, its layers taken out by index (a period's
     # slice copied 150 MB a projection, v5e, PR 35), but one section's pool
-    stacks = {True: split(params[rec.key]), False: split(params["blocks"]),
+    stacks = {True: split(params[rec.key]),
+              False: split(params["blocks"] if n_pool else {}),
               None: split(params[rec.ffn[0]]) if rec.ffn else None,
               **{key: split(params[key]) for key in (*also, *readers)}}
     s_lead = 0                  # per-slot layers of a leading stack, sliced
@@ -180,6 +182,8 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, read, *,
             (rk, cfg), tp=tp, interpret=interpret)[0] == "pallas"
         lp = layer_of(kind, layer - first if first else layer)
         # out of the carried buffers and back: all a decode step leaves
+        # (a kind that keeps a state alone has no rows: ``conv`` is None)
+        rows_in_place = rows_in_place or conv is None
         rows_out = () if rows_in_place else (conv,)
         state_out = () if in_place or state is None else (state,)
         out = rows_out + state_out
@@ -189,7 +193,8 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, read, *,
             held = tuple(jnp.where(
                 fresh.reshape((B,) + (1,) * (a.ndim - 1)), 0, a)
                 for a in held)
-        held = (CarriedRows(conv, layer) if rows_in_place else held[0],
+        held = (None if conv is None else
+                CarriedRows(conv, layer) if rows_in_place else held[0],
                 CarriedState(state, layer, step) if in_place
                 else SlotState(held[-1], chunk) if on_chip
                 else held[-1] if state_out else None)
@@ -201,7 +206,8 @@ def _forward_periods(fam, params, x, cfg, cache, block, lead, ctx, read, *,
                 out, layer, slot,
                 (() if rows_in_place else held[:1])
                 + (held[1:] if state_out else ()))
-        conv = held[0].buffer if rows_in_place else out[0]
+        if conv is not None:
+            conv = held[0].buffer if rows_in_place else out[0]
         if state is not None:
             state = held[1].buffer if in_place else out[-1]
         x, rows = counted(out_half(cfg, x, y, lp), rows)

@@ -476,6 +476,12 @@ class ServingEngine:
         # what a slot keeps beside its pages, a recurrent layer (a
         # family's ``Recurrent.state_row``; None: nothing)
         self._state_row = state_row
+        # a family with no pool layer (every layer keeps its state a
+        # slot): the cache holds no pool, no request needs a page, and a
+        # request is admitted when a slot is free.  0 pages of 0
+        self._pooled = n_layers > 0
+        if not self._pooled:
+            num_pages = 1           # the reserved one alone: none usable
         # Sharded serving (ref: deepspeed/module_inject/replace_module.py
         # TP injection + deepspeed/moe/sharded_moe.py expert-parallel
         # inference): with a mesh, params arrive pre-sharded from the
@@ -1230,7 +1236,7 @@ class ServingEngine:
             # indexed by slot, not by page; made on the device, as the
             # pool is: 6.8 GiB of host zeros took 29 s to upload (PR 42)
             sr = self._state_row
-            conv = self._put(jnp.zeros(
+            conv = None if sr.conv is None else self._put(jnp.zeros(
                 (sr.layers, self.max_batch) + sr.conv, cache_dtype))
             state = None if sr.state is None else self._put(jnp.zeros(
                 (sr.layers, self.max_batch) + sr.state, STATE_DTYPE))
@@ -1256,8 +1262,9 @@ class ServingEngine:
         return PagedKVCache(
             k=put_kv(jnp.zeros(
                 (n_layers, n_kv, num_pages, page_size, head_dim),
-                cache_dtype)),
-            v=None if self._values_in_keys else put_kv(jnp.zeros(
+                cache_dtype)) if self._pooled else None,
+            v=None if self._values_in_keys or not self._pooled
+            else put_kv(jnp.zeros(
                 (n_layers, n_kv, num_pages, page_size, head_dim),
                 cache_dtype)),
             table=table, seq_lens=seq_lens,
@@ -1387,7 +1394,7 @@ class ServingEngine:
             widths, w = [], 1
             while w < -(-C // self.page_size):
                 w *= 2
-            while w < self.max_pages_per_seq:
+            while w < self.max_pages_per_seq and self._pooled:
                 widths.append(w)
                 w *= 2
             widths.append(self.max_pages_per_seq)
@@ -1932,7 +1939,7 @@ class ServingEngine:
         return None
 
     def _pages_needed(self, tokens: int) -> int:
-        return -(-tokens // self.page_size)
+        return -(-tokens // self.page_size) if self._pooled else 0
 
     def _admit_one(self) -> bool:
         """Admit one queued request into a free slot; returns True if
@@ -2678,7 +2685,9 @@ class ServingEngine:
         np_bkt = 1
         while np_bkt < np_live:
             np_bkt *= 2
-        np_bkt = min(np_bkt, self.max_pages_per_seq)
+        # (no pool: no page is read, and the one program takes the row)
+        np_bkt = (min(np_bkt, self.max_pages_per_seq) if self._pooled
+                  else self.max_pages_per_seq)
         view = self._row_view(self._table_host[b:b + 1, :np_bkt], done, b)
         # a chunk that starts at position 0 starts the slot's recurrent
         # state from zero, whatever the slot held
@@ -2863,7 +2872,7 @@ class ServingEngine:
         row may leave under it, and ``seq_len`` counts it already): a dry
         pool ends the walk with False and what it mapped stays mapped."""
         ps = self.page_size
-        for b, s in enumerate(self.slots):
+        for b, s in enumerate(self.slots if self._pooled else ()):
             if s is None or s.prefilling:
                 # chunk writes land in the pages reserved at admission
                 continue
@@ -3525,7 +3534,8 @@ class ServingEngine:
                 # depth where some keep a state a slot: "cache.state")
                 "layers": (len(self.cache.k)
                            if isinstance(self.cache.k, (tuple, list))
-                           else int(self.cache.k.shape[0])),
+                           else int(self.cache.k.shape[0])
+                           if self._pooled else 0),
                 "bytes_per_token": self._pool_bytes() // (
                     (self.trash_page + 1) * self.page_size),
                 "page_size": self.page_size,
@@ -4048,7 +4058,7 @@ def serving_engine(params, cfg, **kw):
             quantized_resident=kvt.quantized_resident,
             prefix_cache=PrefixCacheConfig.coerce(
                 kw.get("prefix_cache")).enabled,
-            speculative=speculating)
+            speculative=speculating, tensor_parallel=fam.sharded(mesh))
     # sharded-ness is baked in at BUILD time: the compiled paths must not
     # re-read the mutable ambient mesh on a later retrace (a cleared one
     # would silently re-enable pallas kernels over the sharded cache)

@@ -2,7 +2,8 @@
 
 A family's file (``gpt2.py``, ``llama.py``, ``mixtral.py``,
 ``pangu_ultra_moe.py``, ``qwen3_next.py``, ``granite_hybrid.py``,
-``laguna.py``, ``nemotron_h.py``, ``ling_flash.py``, ``phi4_flash.py``) ends
+``laguna.py``, ``nemotron_h.py``, ``ling_flash.py``, ``phi4_flash.py``,
+``brumby.py``) ends
 with its
 ``FAMILY = DecoderFamily(...)``: the pieces of one transformer layer and
 the facts a serving build needs.  Everything that serves, streams, drafts
@@ -53,7 +54,9 @@ class StateRow(NamedTuple):
     """What one SLOT keeps, a layer that keeps a bounded state, whatever
     the length of its sequence: ``conv`` (rows x channels in the cache's
     dtype: the last inputs of a short causal convolution, or a ring of
-    the last ``rows`` tokens' ``[K | V]``) and ``state`` (the
+    the last ``rows`` tokens' ``[K | V]``; None where the layer keeps a
+    state alone: ``PagedKVCache.conv`` is then None, and ``mix`` is handed
+    None for its rows and hands None back) and ``state`` (the
     recurrence's matrix a head, float32; None where the layer keeps
     rows alone), for each of ``layers`` such layers.  It is indexed by
     slot, not by page: ``PagedKVCache.conv`` is ``[layers, B, *conv]``
@@ -110,7 +113,23 @@ class Recurrent:
     second per-slot kind, ``also``, or pool layers that write nothing,
     ``readers``), a per-slot layer may hand a value on to the layers
     behind it (``hands_on``), and the last sections may run on a row's
-    last real token alone (``tail``).
+    last real token alone (``tail``).  What a seventh changed
+    (``brumby``: power retention, a state of 4.3 MiB a head with its
+    normaliser in it, read by five query heads, in EVERY layer): a period
+    may have no pool layer at all (the cache then holds no pool, ``blocks``
+    is not a key of the params, ``DecoderFamily.qkv`` / ``out`` name
+    nothing, and a section may be empty: a tail that is the head alone); a
+    kind may keep a state and no rows (``StateRow.conv`` None); a step's
+    vectors and its ``o`` may be n rows a state head of what the rule
+    expands itself (:func:`step_state`: a key of 128 numbers for a row of
+    8,320, a whole tile of 8 query rows); and a rule may be stated on the
+    state where it lies (``in_place``, of :func:`step_state` and
+    :func:`chunk_state`): a reference it reads and writes a piece at a
+    time, because a head of megabytes is no value of a kernel's, with an
+    ``o`` read from the operands (a step's: rows as the rows that read
+    the state; a chunk's: as wide as the operand whose head holds the
+    heads that read one state).  The six families' statements keep their
+    meaning.
 
     ``period(cfg)``: one entry a layer of a period: True where the layer
     mixes tokens over its per-slot state, False where it attends over
@@ -208,7 +227,7 @@ class CarriedState(NamedTuple):
     step: Callable[..., Tuple[Any, Any]]
 
 
-def step_state(rule, S, *vectors):
+def step_state(rule, S, *vectors, in_place=None):
     """One token of a recurrence: ``rule(S, *vectors) -> (o, S)``, the
     family's statement of it over the last two dimensions of ``S`` [...,
     R, C], each vector [..., 1, C], [..., R, 1] or [..., 1, 1] (a scalar
@@ -217,9 +236,16 @@ def step_state(rule, S, *vectors):
     and ``o`` [..., 1, C] or [..., R, 1].  ``S`` is the rows' state [B,
     H, R, C], and the rule is applied to it as it stands, or a
     :class:`CarriedState`, whose layer is stepped where it lies; the
-    second result is of the kind ``S`` was."""
+    second result is of the kind ``S`` was.  A vector of any other shape
+    [..., n, w] is n rows of what the rule expands itself, and ``o`` may
+    be such rows.  ``in_place(S_ref, *vectors) -> o``: the rule on a
+    head's [R, C] as a reference in the kernel's memory, read and written
+    a piece at a time, for a head of megabytes (``kernels.state_step``:
+    ``o`` is then rows as the first vector that is rows)."""
     if isinstance(S, CarriedState):
-        o, buffer = S.step(rule, S.buffer, S.layer, vectors)
+        o, buffer = S.step(rule, S.buffer, S.layer, vectors,
+                           **({} if in_place is None
+                              else {"in_place": in_place}))
         return o, S._replace(buffer=buffer)
     return rule(S.astype(jnp.float32), *vectors)
 
@@ -236,7 +262,7 @@ class SlotState(NamedTuple):
     chunk: Callable[..., Tuple[Any, Any]]
 
 
-def chunk_state(rule, S, mats, cols, lanes, block: int):
+def chunk_state(rule, S, mats, cols, lanes, block: int, **how):
     """A prompt chunk of a recurrence on a :class:`SlotState`: ``rule(S
     [h, R, C], *tiles, col, lane) -> (o [h, block, C], S)`` is the
     family's statement of one block of tokens on h heads side by side,
@@ -244,8 +270,12 @@ def chunk_state(rule, S, mats, cols, lanes, block: int):
     many of ``S``'s as it takes), ``cols`` and ``lanes`` [B, T, H, n] what
     it needs a token and head, handed to it down a block (``col`` [h,
     block, n]) and across (``lane`` [h, n, block]).  Returns (o [B, T, H,
-    C], the rows' state as an array)."""
-    return S.chunk(rule, S.rows, mats, cols, lanes, block=block)
+    C], the rows' state as an array).  ``how``: ``in_place`` (the rule
+    takes the step's states as a reference [h, R, C], reads and writes
+    them a piece at a time and returns ``o`` alone: a head of megabytes;
+    ``o`` is then as wide a state head as the widest head of ``mats``:
+    the queries of several heads that read one state, side by side)."""
+    return S.chunk(rule, S.rows, mats, cols, lanes, block=block, **how)
 
 
 def _per_head_rows(cfg) -> CacheRow:
@@ -386,7 +416,7 @@ def positions_from(start, T: int):
 # the registry: one module name a family
 _FAMILY_MODULES = ("gpt2", "llama", "mixtral", "pangu_ultra_moe",
                    "qwen3_next", "granite_hybrid", "laguna", "nemotron_h",
-                   "ling_flash", "phi4_flash")
+                   "ling_flash", "phi4_flash", "brumby")
 
 
 def decoder_families() -> Tuple[DecoderFamily, ...]:
