@@ -52,14 +52,23 @@ Prefill-boundary tokens follow the same discipline: a prefill program
 returns the logits ROW of its last real position (sliced inside the
 program), one small compiled program — the resolved sampler, the same
 function the decode program calls — turns the row into a ``[1]`` token
-array on the device, every prompt that finishes prefilling within a
-step queues that array, and ONE ``device_get`` of the queue appends
-them all.  Sampling keys are derived on the device from integers the
-host already has (the admission ordinal, the dispatch ordinal) folded
-into a base key uploaded once at build.  So between the start of
-``step()`` and the token fetch the host dispatches compiled programs
-and uploads NumPy arrays, and runs no eager ``jnp`` / ``jax.random``
-operation.
+array on the device, and that array STAYS on the device until the
+decode that consumes it has been dispatched: ``dstpu_join`` writes it
+at its row's newest position in the decode's token operand (the
+output of the step in flight, or the tokens the host sent up), so an
+admission or a prompt's last chunk goes out behind the decode in
+flight and lands nothing first.  The token is read with the decode it
+joined, in that decode's one fetch, and appended before that decode's
+token.  Where the rule says no (``_joins``: speculation, a fault plan,
+a tiered cache, a request that wants one token, a pool that could only
+give the pages by preempting) every prompt that finished prefilling
+within the step is fetched in ONE ``device_get`` before the decode is
+built, as it always was.  Sampling keys are derived on the device from
+integers the host already has (the admission ordinal, the dispatch
+ordinal) folded into a base key uploaded once at build.  So between
+the start of ``step()`` and the token fetch the host dispatches
+compiled programs and uploads NumPy arrays, and runs no eager ``jnp``
+/ ``jax.random`` operation.
 
 Speculative decoding (``speculative=`` / the config block): each decode
 iteration drafts up to K cheap tokens per slot (prompt-lookup n-gram by
@@ -176,6 +185,23 @@ def boundary_program(sample):
     return dstpu_boundary
 
 
+def join_program():
+    """A boundary token joins the decode that consumes it, on the
+    device: ``prev``, a decode program's token operand in the form its
+    output has (``[B, K]``, or flat behind the experts' rows), with the
+    ``[1]`` token ``tok`` written at flat position ``at``, its row's
+    newest (``b * K + K - 1``).  ONE program whatever the row, and the
+    decode program's text, cache entry and compile-cache key are what
+    they were.  (A function an engine, as the others: a jit's cache is
+    its function's.)"""
+    def dstpu_join(prev, tok, at):
+        with jax.named_scope("sample"):
+            return jax.lax.dynamic_update_slice(
+                prev.reshape(-1), tok, (at,)).reshape(prev.shape)
+
+    return dstpu_join
+
+
 def serving_programs(prefill_fn, decode_fn, chunk_prefill_fn, sample,
                      decode_chunk: int, max_batch: int,
                      expert_rows: bool = False, state: bool = False):
@@ -270,6 +296,9 @@ def decode_from_output(decode, decode_chunk: int, max_batch: int):
 # anything else (the first step, a page the pool could only give by
 # preempting, a row that ended on its own, a promotion in flight)
 DECODE_BEHIND = ("admission", "boundary", "finish", "prefill", "other")
+# those of them a boundary token that joins its decode on the device
+# takes away (``ServingEngine._may_join``)
+_JOINED = ("admission", "boundary", "prefill")
 
 
 def _req_key(req_id: Any) -> str:
@@ -398,6 +427,9 @@ class _Slot:
     prefill_done: int = -1             # chunked prefill progress; -1 = done
     last_tok_t: float = 0.0            # inter-token latency clock
     promo: Optional[_Promotion] = None  # in-flight tier-page promotion
+    # the first token, on the device: it joined a decode there and is
+    # read, and appended before that decode's token, when it lands
+    boundary: Any = None
 
     @property
     def prefilling(self) -> bool:
@@ -419,7 +451,7 @@ class ServingEngine:
     # the one jitted decode program and the form its output has (None: an
     # engine whose decode the host drives layer by layer, which takes
     # the tokens ``[B, 1]`` and never has a step in flight)
-    _decode_jit = _out_shape = None
+    _decode_jit = _out_shape = _join = None
     # the decode step the device has and the host has not read
     _flying: Optional[_Flying] = None
 
@@ -751,6 +783,12 @@ class ServingEngine:
             "fetch; "
             "÷ serving_admitted_requests: 1.0 less what failed or was "
             "preempted before its flush)")
+        self._c_boundary_joined = r.counter(
+            "serving_boundary_joined",
+            "boundary tokens that reached the decode that consumes them "
+            "on the device (dstpu_join): read with that decode's tokens, "
+            "no fetch of their own (+ serving_boundary_tokens: the "
+            "admissions that produced a token)")
         # speculative-decoding metric family (all zero when off)
         self._c_spec_drafted = r.counter(
             "spec_drafted_tokens",
@@ -923,6 +961,7 @@ class ServingEngine:
                     "spec_verify", self._verify_chunk)
                 self._boundary = self.devprof.wrap("boundary",
                                                    self._boundary)
+                self._join = self.devprof.wrap("join", self._join)
             with self._sp_build_warmup:
                 self._devprof_warmup()
 
@@ -1326,6 +1365,7 @@ class ServingEngine:
         self._chunk_prefill = (jax.jit(dstpu_chunk, donate_argnums=(2,))
                                if chunk_prefill_fn is not None else None)
         self._boundary = jax.jit(dstpu_boundary)
+        self._join = jax.jit(join_program(), out_shardings=self._repl)
         # the speculative verify sweep scores K+1 positions and needs
         # them all: the one caller that reads the continuation
         # forward's logits, so the one engine that builds this program
@@ -1427,6 +1467,10 @@ class ServingEngine:
             with self._sp_build_program("boundary"):
                 self._boundary(logits_row, self._key, ordinal,
                                self._put(zi((1,), np.float32)))
+            # and what writes its token into a decode's operand
+            with self._sp_build_program("join"):
+                self._join(self._put(zi(self._out_shape, np.int32)),
+                           last, ordinal)
         with self._sp_build_program("decode_chunk", b=self.max_batch):
             _, self.cache = self._decode_chunk_fn(
                 self.params,
@@ -2965,10 +3009,14 @@ class ServingEngine:
 
     # dstpu: hot-path
     def _step_inner(self) -> None:
-        # What the host could not know when it let a step fly (an
-        # arrival between two calls, a row that ended on its own) finds
-        # that step in flight: it lands first, and the call goes on as
-        # it always has, over the state it has always seen.
+        # What the host could not know when it let a step fly (a row
+        # that ended on its own, rows a router changed between two
+        # calls) finds that step in flight: it lands first, and the
+        # call goes on as it always has, over the state it has always
+        # seen.  An arrival beside a free slot and a prompt's last chunk
+        # land nothing where the boundary token can join the next decode
+        # on the device (_may_join): their programs take the cache the
+        # step in flight returns, so the device runs them behind it.
         landed = self._flying is not None and self._land_first(
             self._why_sync(self._flying.rows))
         with self._sp_admit:
@@ -3009,12 +3057,25 @@ class ServingEngine:
                             faults_mod.inject("slot", key=s.req.req_id)
                         except InjectedFault as e:
                             self._fail_slot(b, e)
-        # every prompt that finished prefilling this step samples its
-        # boundary token in ONE batched fetch, before the decode phase
-        # reads generated[-1]
-        with self._sp_boundary:
-            self._flush_boundary()
         K = self.decode_chunk
+        # every prompt that finished prefilling this step has its
+        # boundary token on the device.  Where the rule lets them and
+        # the pool maps the step's pages without preempting, they join
+        # this call's decode there (``joins``); else the parent's
+        # sequence: a step still in flight lands, and ONE batched fetch
+        # appends them before the decode phase reads generated[-1]
+        with self._sp_boundary:
+            joins = self._pending_boundary
+            if joins and self._joins() and self._grow_pages(
+                    ahead=K, preempt=False):
+                self._pending_boundary = []
+                for b, tok in joins:
+                    self.slots[b].boundary = tok
+            elif joins:
+                if self._flying is not None:
+                    landed = self._land_first("boundary", newest=False)
+                self._flush_boundary()
+                joins = []
         # the speculative sweep writes K_draft+1 positions per slot —
         # provision its whole window, like chunked decode does
         ahead = (self.speculative.draft_tokens + 1 if self._spec_on
@@ -3022,8 +3083,13 @@ class ServingEngine:
         ready = lambda: [(b, s) for b, s in enumerate(self.slots)
                          if s is not None and not s.prefilling]
         active, flying = ready(), self._flying
-        if flying is not None and (len(active) != len(flying.rows) or any(
-                s is not t for (_, s), (_, t) in zip(active, flying.rows))):
+        # the rows the step in flight left with: these, less the rows
+        # that join the step behind it
+        joining = dict(joins)
+        stay = [r for r in active if r[0] not in joining] \
+            if joins else active
+        if flying is not None and (len(stay) != len(flying.rows) or any(
+                s is not t for (_, s), (_, t) in zip(stay, flying.rows))):
             # not the rows it flew with (a router or a test admitted
             # between two calls): it lands, as at the top
             landed, flying = self._land_first("other", newest=False), None
@@ -3034,7 +3100,11 @@ class ServingEngine:
             if active and flying is None:
                 self._grow_pages(ahead=ahead)
                 active = ready()
-            if active and not landed and not self._spec_on:
+            if joins and flying is not None:
+                # the step that joins IS the step behind the one in
+                # flight (its pages were mapped above)
+                why = None
+            elif active and not landed and not self._spec_on:
                 why = self._why_behind(active, K)
                 if why is None and not self._grow_pages(
                         ahead=K if flying else 2 * K, preempt=False):
@@ -3047,10 +3117,13 @@ class ServingEngine:
             with self._sp_upload:
                 self._upload_dirty()
             fresh = flying is None
-            if fresh:
+            if not fresh:
+                toks_d, temps_d = flying.out, flying.temps
+            if fresh or joins:
                 with self._sp_inputs:
                     # the tokens in the form the program's output has:
-                    # a row's newest is the last of its K
+                    # a row's newest is the last of its K (a row that
+                    # joins has its own written there on the device)
                     kf = K if self._out_shape else 1
                     toks = np.zeros(self._out_shape or (self.max_batch, 1),
                                     np.int32)
@@ -3060,12 +3133,22 @@ class ServingEngine:
                         newest[b * kf + kf - 1] = s.generated[-1] \
                             if s.generated else s.req.tokens[-1]
                         temps[b] = s.req.temperature
-                    toks_d, temps_d = self._put(toks), self._put(temps)
+                    if fresh:
+                        toks_d = self._put(toks)
+                    temps_d = self._put(temps)
+                    for b, tok in joins:
+                        toks_d = self._join(toks_d, tok, self._put(
+                            np.full((), b * kf + kf - 1, np.int32)))
+                    if joins:
+                        self._c_boundary_joined.inc(len(joins))
+                        if self._tel_on:
+                            self._row.boundary += len(joins)
             with self._sp_dispatch:
                 if fresh:
                     self._behind[self._behind_why] += 1
                     flying = self._dispatch_decode(
                         "decode", active, toks_d, temps_d)
+                    toks_d = flying.out
                 nxt = None
                 if landed and self._fault_plan is None:
                     # one call, one decode's tokens: this one's are the
@@ -3074,7 +3157,7 @@ class ServingEngine:
                 elif why is None:
                     self._c_decode_ahead.inc()
                     nxt = self._dispatch_decode(
-                        "decode_ahead", active, flying.out, flying.temps)
+                        "decode_ahead", active, toks_d, temps_d)
                 else:
                     self._behind_why = why
             self._flying = nxt
@@ -3137,7 +3220,11 @@ class ServingEngine:
         to land before anything else, or None."""
         if any(self.slots[b] is not s for b, s in rows):
             return "other"
-        return self._rows_change()
+        why = self._rows_change()
+        if why in _JOINED and self._ends_by_count(rows, self.decode_chunk):
+            # the slot it frees is landed for, as it always was
+            return "finish"
+        return None if self._may_join(why, rows) else why
 
     def _why_behind(self, rows, K: int) -> Optional[str]:
         """THE RULE, read from the engine's state and set by no one.
@@ -3147,11 +3234,56 @@ class ServingEngine:
         tokens are: no row ends by count with this step's tokens (one
         that ends on ``eos`` the host cannot know: its token in the next
         step is void), and the next call admits nothing, finishes no
-        prompt and fetches no boundary token."""
-        for _, s in rows:
-            if len(s.generated) + K >= s.req.max_new_tokens:
-                return "finish"
-        return self._rows_change()
+        prompt and fetches no boundary token, or what it admits and
+        finishes joins the step behind this one on the device."""
+        if self._ends_by_count(rows, K):
+            return "finish"
+        why = self._rows_change()
+        return None if self._may_join(why, rows) else why
+
+    @staticmethod
+    def _ends_by_count(rows, K: int) -> bool:
+        """Some row of ``rows`` ends by count with the next ``K`` tokens
+        it is given (a first token still on the device is one it has)."""
+        return any(len(s.generated) + (s.boundary is not None) + K
+                   >= s.req.max_new_tokens for _, s in rows)
+
+    def _joins(self) -> bool:
+        """THE RULE's other half, read from the engine's state alone:
+        whether the boundary tokens that wait may stay on the device and
+        join this call's decode there.  No: under speculation (the sweep
+        reads them), a fault plan or a tiered cache (each keeps the
+        synchronous sequence), an engine whose decode the host drives,
+        and where the host knows ahead that a boundary token is its
+        request's last."""
+        return not (self._spec_on or self._kvt_on
+                    or self._fault_plan is not None
+                    or self._decode_jit is None) and all(
+            self.slots[b].req.max_new_tokens > 1
+            for b, _ in self._pending_boundary)
+
+    def _may_join(self, why: Optional[str], rows) -> bool:
+        """With a decode step over ``rows`` in flight or about to be:
+        whether the work that ``why`` names (``_JOINED``: an admission,
+        a prompt's last chunk, a boundary token that waits) goes out
+        behind it and joins the step after it on the device (the
+        callers have seen that no row ends by count with that step's
+        tokens).  Only where no request that could finish its prompt
+        wants one token alone, and where the pages are free NOW: the
+        head of the queue's and a step's growth of every row, with no
+        eviction and no preemption."""
+        if why not in _JOINED or not self._joins():
+            return False
+        ends = [s.req for s in self.slots if s is not None and s.prefilling]
+        need = (1 + self.decode_chunk // self.page_size) * (len(rows) + 1)
+        if why == "admission":
+            head = self.queue[0]
+            ends.append(head)
+            bkt = self.prefill_chunk or self.prefill_bucket
+            T = len(head.tokens)
+            need += self._pages_needed(max(-(-T // bkt) * bkt, T + 1))
+        return all(req.max_new_tokens > 1 for req in ends) and (
+            not self._pooled or len(self.allocator.free) >= need)
 
     def _land_first(self, why: Optional[str], newest: bool = True) -> bool:
         """Land the step in flight before going on, if there is a reason
@@ -3173,10 +3305,14 @@ class ServingEngine:
         queued once the fetch returns."""
         out, rows, ordinal, _ = flying
         K = self.decode_chunk
+        # the rows whose first token joined this step on the device
+        first = [(b, s) for b, s in rows if s.boundary is not None]
         with self._sp_token_sync:
             # dstpu: host-sync-ok: the ONE device→host transfer per
-            # decode chunk (K tokens per sync — the module contract)
-            host_toks = np.asarray(out)
+            # decode chunk (K tokens per sync — the module contract);
+            # the boundary tokens that joined the step come with it
+            host_toks, *heads = jax.device_get(
+                [out, *(s.boundary for _, s in first)])
             if self._n_expert_rows:
                 host_toks = self._take_expert_rows(host_toks, K)
         if self._tel_on and newest:
@@ -3184,6 +3320,11 @@ class ServingEngine:
             # from its own clock reading on
             self._row.drained = self._sp_token_sync.t1
         with self._sp_append:
+            for (b, s), tok in zip(first, heads):
+                # before the step's own: the order the tokens have
+                s.boundary = None
+                if self.slots[b] is s:
+                    self._append_token(b, int(tok[0]))
             live = [(b, s) for b, s in rows if self.slots[b] is s]
             if self._trace_on and any(s.req.traced for _, s in live):
                 # one event per BATCH sync (not per token): the decode
@@ -3621,6 +3762,8 @@ class ServingEngine:
                 "dispatches": int(self._c_decode_syncs.value),
                 "ahead": int(self._c_decode_ahead.value),
                 "behind": dict(self._behind),
+                # boundary tokens that joined their decode on the device
+                "joined": int(self._c_boundary_joined.value),
                 "in_flight": self._flying is not None,
             },
             # the BOUND port (meaningful when http_port=0 asked for an

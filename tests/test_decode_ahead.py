@@ -7,7 +7,16 @@ The oracle is the engine itself with the rule switched off (every step
 lands before the next is built: the sequence the engine had before):
 every request's tokens must be equal, whatever happened while a step
 was in flight.
+
+An admission no longer stops the chip (ISSUE 60): a boundary token stays
+on the device until the decode that consumes it has been dispatched
+(``dstpu_join``), so an arrival beside a free slot and a prompt's last
+chunk go out behind the step in flight, and the token is read with the
+decode it joined.  The ``join_*`` scenarios hold that to the same
+oracle.
 """
+
+import time
 
 import numpy as np
 import pytest
@@ -55,6 +64,8 @@ def _engine(family, synchronous=False, **kw):
         # the replay: nothing is known ahead, so every step lands in
         # the call that dispatched it
         eng._rows_change = lambda: "other"
+        # and every boundary token is fetched before its decode is built
+        eng._joins = lambda: False
     return eng
 
 
@@ -93,13 +104,41 @@ SCENARIOS = {
     # four tokens a dispatch: rows end inside a chunk
     "chunk4": dict(lens=(5, 8, 6, 9, 4), news=(13, 6, 10, 3, 9),
                    at=(0, 0, 0, 2, 5), engine=dict(decode_chunk=4)),
+    # ---- ISSUE 60: the boundary token joins its decode on the device
+    # an arrival beside a free slot finds a step in flight, twice
+    "join_arrival": dict(lens=(6, 9, 7), news=(16, 9, 7), at=(0, 3, 7)),
+    # two arrivals in one call, beside two free slots
+    "join_two": dict(lens=(5, 11, 8), news=(15, 8, 11), at=(0, 4, 4)),
+    # a prompt's last chunk goes through under a step in flight
+    "join_chunk": dict(lens=(5, 27, 19), news=(22, 7, 6), at=(0, 2, 6),
+                       engine=dict(prefill_chunk=8, prefill_bucket=0)),
+    # a request that wants ONE token: its boundary token is its last,
+    # the host knows, and the synchronous sequence runs
+    "join_one": dict(lens=(6, 8, 5), news=(14, 1, 8), at=(0, 4, 4)),
+    # the boundary token IS eos (learned by serving once without one:
+    # the late arrival's first token)
+    "join_eos": dict(lens=(6, 7, 9), news=(15, 9, 8), at=(0, 4, 6),
+                     eos="first"),
+    # a row fails between the join and the landing of its decode
+    "join_fail": dict(lens=(6, 8, 7), news=(15, 9, 8), at=(0, 4, 6),
+                      fail=(1, 5)),
+    # the arrival's pages are only in the warm pool (a finished
+    # request's, published): an eviction, so the step lands first
+    "join_evict": dict(lens=(30, 28, 26, 33), news=(4, 14, 3, 6),
+                       at=(0, 0, 0, 9),
+                       engine=dict(num_pages=15, prefix_cache=True)),
+    # the pool is dry when the arrival's decode has to grow: a
+    # preemption, so the boundary token is fetched
+    "join_preempt": dict(lens=(14, 15, 13), news=(30, 28, 20),
+                         at=(0, 0, 8), engine=dict(num_pages=9)),
 }
 
 
-def _serve(eng, mix):
+def _serve(eng, mix, fail=None):
     """Submit as the schedule says and step until nothing is left.
     Returns (outputs, whether a row ever ended with a step in flight
-    behind it)."""
+    behind it).  ``fail``: (request, the step before which its slot
+    fails)."""
     ended_under_a_step = False
     step = 0
     pending = sorted(mix, key=lambda r: r[0])
@@ -107,6 +146,10 @@ def _serve(eng, mix):
         while pending and pending[0][0] <= step:
             _, rid, prompt, n_new, temp = pending.pop(0)
             eng.submit(rid, prompt, max_new_tokens=n_new, temperature=temp)
+        if fail is not None and fail[1] == step:
+            (b,) = [b for b, s in enumerate(eng.slots)
+                    if s is not None and s.req.req_id == fail[0]]
+            eng._fail_slot(b, RuntimeError("the test's"))
         done = eng.step()
         step += 1
         if done and eng._flying is not None:
@@ -117,35 +160,60 @@ def _serve(eng, mix):
 
 def _serve_pair(family, scenario):
     spec = dict(SCENARIOS[scenario])
-    kw = spec.pop("engine", {})
+    kw = dict(spec.pop("engine", {}), **spec.pop("more", {}))
     want_eos = spec.pop("eos", False)
+    fail = spec.pop("fail", None)
     cfg = _model(family)[0]
     mix = _mix(cfg, **spec)
     if want_eos:
-        # the token that some request says LATEST for the first time
-        # (a tiny model repeats itself), learned by serving once without
-        # an eos: that row then ends in the middle of the others' decode
         probe = _engine(family, synchronous=True, **kw)
         said, _ = _serve(probe, mix)
         probe.shutdown()
-        firsts = {}
-        for _, rid, prompt, _, _ in mix:
-            new = said[rid][len(prompt):]
-            for tok in set(new):
-                firsts[tok] = min(firsts.get(tok, len(new)), new.index(tok))
-        eos = max(firsts, key=firsts.get)
-        assert firsts[eos] >= 2
+        new = {rid: said[rid][len(prompt):] for _, rid, prompt, _, _ in mix}
+        if want_eos == "first":
+            # the last arrival's first token, which nobody said earlier
+            # than it does
+            eos = new[mix[-1][1]][0]
+            assert all(eos not in new[rid][:2] for rid in new
+                       if rid != mix[-1][1])
+        else:
+            # the token that some request says LATEST for the first
+            # time (a tiny model repeats itself): that row then ends in
+            # the middle of the others' decode
+            firsts = {}
+            for toks in new.values():
+                for tok in set(toks):
+                    firsts[tok] = min(firsts.get(tok, len(toks)),
+                                      toks.index(tok))
+            eos = max(firsts, key=firsts.get)
+            assert firsts[eos] >= 2
         kw = dict(kw, eos_token_id=eos)
     eng = _engine(family, **kw)
-    out, ended = _serve(eng, mix)
+    out, ended = _serve(eng, mix, fail)
     replay = _engine(family, synchronous=True, **kw)
-    want, _ = _serve(replay, mix)
+    want, _ = _serve(replay, mix, fail)
     return eng, replay, out, want, ended
 
 
+# the same with four tokens a dispatch (and answers three times as
+# long): the joined token is the last of its row's four in the operand
+for _name in ("join_arrival", "join_two", "join_chunk", "join_one",
+              "join_eos"):
+    SCENARIOS[_name + ".k4"] = dict(
+        SCENARIOS[_name], more=dict(decode_chunk=4),
+        news=tuple(n if n == 1 else 3 * n
+                   for n in SCENARIOS[_name]["news"]))
+
 CASES = [("plain", s) for s in SCENARIOS] + [
     (f, s) for f in ("expert_rows", "state")
-    for s in ("eos", "chunked", "chunk4")]
+    for s in ("eos", "chunked", "chunk4")] + [
+    # flat behind the experts' rows: position b * K + K - 1
+    ("expert_rows", "join_arrival"), ("expert_rows", "join_chunk"),
+    ("expert_rows", "join_eos"), ("expert_rows", "join_two.k4"),
+    # a per-slot state (every admission is chunked: the slot is idle,
+    # length 0, until the lengths go up with the joined step)
+    ("state", "join_arrival"), ("state", "join_eos"),
+    ("state", "join_fail"), ("state", "join_two.k4")]
 
 
 @pytest.mark.parametrize("family,scenario", CASES)
@@ -153,8 +221,14 @@ def test_served_ahead_is_served_in_turn(family, scenario, devices):
     """Every request's tokens are what the synchronous engine gives,
     and no page is leaked, whatever found a step in flight."""
     eng, replay, out, want, ended = _serve_pair(family, scenario)
+    spec = SCENARIOS[scenario]
+    scenario = scenario.split(".")[0]
+    failed = spec.get("fail", (None,))[0]
     assert set(out) == set(want) and all(
-        isinstance(v, list) for v in out.values())
+        isinstance(v, list) for rid, v in out.items() if rid != failed)
+    if failed is not None:
+        # it had read less of what it had been given when it failed
+        assert out.pop(failed).generated <= want.pop(failed).generated
     assert out == want
     assert eng.check_leaks() == [] and replay.check_leaks() == []
     assert eng._flying is None and not eng.has_work
@@ -171,32 +245,83 @@ def test_served_ahead_is_served_in_turn(family, scenario, devices):
         # the void step was dispatched all the same
         assert c["serving_decode_syncs"] >= r["serving_decode_syncs"]
     else:
-        # by count the host knows: no step is dispatched for nothing
-        assert c["serving_decode_syncs"] == r["serving_decode_syncs"]
+        # by count the host knows: no step is dispatched for nothing.
+        # (A row that joins behind a step in flight decodes one step
+        # later than where that step lands first: one more at most.)
+        assert 0 <= c["serving_decode_syncs"] - r["serving_decode_syncs"] \
+            <= c["serving_boundary_joined"]
     if scenario == "preempt":
         assert c["serving_preempted_requests"] > 0
+    d = eng.statusz()["decode"]
+    assert d["joined"] == c["serving_boundary_joined"]
+    assert r["serving_boundary_joined"] == 0
+    if failed is None and not c["serving_preempted_requests"]:
+        # an admission's first token joined its decode or was fetched
+        assert d["joined"] + c["serving_boundary_tokens"] \
+            == c["serving_admitted_requests"] == len(want)
     if scenario == "chunked":
         assert c["serving_prefill_chunks"] >= 8
-        # chunks went through while a step was in flight
-        assert eng.statusz()["decode"]["behind"]["prefill"] > 0
+        # chunks went through while a step was in flight, the last of
+        # a prompt too: its token joined the step behind that one
+        assert d["behind"]["prefill"] == 0 < d["joined"]
+    prompts = {rid: p for _, rid, p, _, _ in
+               _mix(_model(family)[0], **{k: spec[k] for k in
+                                          ("lens", "news", "at")})}
+    if scenario in ("join_arrival", "join_two", "join_chunk"):
+        # no step landed for an admission, a boundary token or a chunk
+        assert d["joined"] >= 2
+        assert not any(d["behind"][why]
+                       for why in ("admission", "boundary", "prefill"))
+        assert c["serving_boundary_syncs"] <= 1      # the first call's
+    if scenario == "join_one":
+        assert len(out[1]) == len(prompts[1]) + 1
+        assert c["serving_boundary_tokens"] >= 1 <= d["joined"]
+        assert d["behind"]["admission"] + d["behind"]["boundary"] >= 1
+    if scenario == "join_eos":
+        # its decode flew with a void token for it, dropped unread
+        assert out[2] == prompts[2] + [eng.eos] and d["joined"] >= 1
+        assert c["serving_decode_syncs"] >= r["serving_decode_syncs"]
+    if scenario == "join_fail":
+        # its first token had joined a decode and was never read
+        assert d["joined"] + c["serving_boundary_tokens"] \
+            == c["serving_admitted_requests"]
+        assert eng.finished[failed].generated == 0
+    if scenario == "join_evict":
+        assert c["prefix_cache_evicted_pages"] > 0
+        assert d["behind"]["admission"] >= 1
+    if scenario == "join_preempt":
+        assert c["serving_preempted_requests"] > 0
+        assert c["serving_boundary_tokens"] >= 1
     eng.shutdown()
     replay.shutdown()
 
 
-def test_sampled_rows_draw_what_they_drew(devices):
+@pytest.mark.parametrize("at", [(0, 0, 0), (0, 3, 3, 8)],
+                         ids=["together", "interleaved"])
+def test_sampled_rows_draw_what_they_drew(at, devices):
     """The dispatch ordinal advances once a dispatch, in dispatch
-    order: where the rows are the same rows (nothing arrives, rows end
-    by count), sampled tokens are the same draws."""
+    order, and an admission's once an admission: where the rows are the
+    same rows (nothing arrives, rows end by count), sampled tokens are
+    the synchronous engine's draws.  With admissions interleaved a row
+    that arrives under a step in flight decodes from the step behind
+    it, in the parent's sequence (that step lands, then the admission)
+    as where its token joins on the device: the oracle there is the
+    engine with the join alone switched off, and every draw is equal."""
     cfg = _model("plain")[0]
     rng = np.random.default_rng(7)
-    mix = [(0, i, _prompt(rng, cfg, 5 + i), 6 + 3 * i, 0.9)
-           for i in range(3)]
-    eng = _engine("plain")
-    replay = _engine("plain", synchronous=True)
+    mix = [(a, i, _prompt(rng, cfg, 5 + i), 6 + 3 * i, 0.9)
+           for i, a in enumerate(at)]
+    eng = _engine("plain", max_batch=4)
+    replay = _engine("plain", synchronous=not any(at), max_batch=4)
+    replay._joins = lambda: False
     out, _ = _serve(eng, mix)
     want, _ = _serve(replay, mix)
     assert out == want
-    assert eng.registry.snapshot()["counters"]["serving_decode_ahead"] > 0
+    d, was = eng.statusz()["decode"], replay.statusz()["decode"]
+    assert d["ahead"] > 0 and d["dispatches"] == was["dispatches"]
+    assert d["joined"] == len(at) and was["joined"] == 0
+    if any(at):
+        assert was["behind"]["admission"] > 0 == d["behind"]["admission"]
     eng.shutdown()
     replay.shutdown()
 
@@ -209,36 +334,47 @@ def test_a_build_makes_the_programs_it_made(family, kw, devices):
     the same programs, one ``dstpu_decode`` among them, and the jitted
     decode has ONE cache entry after steps fed from the host and steps
     fed from the device have both run."""
+    t_build = time.perf_counter()
     eng = _engine(family, devprof={"enabled": True}, **kw)
     cfg = _model(family)[0]
-    # the ledger is the process's and keeps its newest entries: a
-    # build's warm-up ends on its decode program, so this engine's are
-    # the ones behind the decode of whichever engine was built before
+    # the ledger is the process's: this build's entries are the ones
+    # that ended after it began, and its warm-up ends on its decode
     after_build = BUILD_LEDGER.snapshot()
-    names = [e["program"] for e in after_build["entries"]]
+    names = [e["program"] for e in after_build["entries"]
+             if e["t_end"] > t_build]
     assert names[-1] == "dstpu_decode"
-    names = names[len(names) - 1 - names[-2::-1].index("dstpu_decode"):] \
-        if "dstpu_decode" in names[:-1] else names
     made = len(names)
     assert names.count("dstpu_decode") == 1
-    assert set(names) == {"dstpu_prefill", "dstpu_chunk",
-                          "dstpu_boundary", "dstpu_decode"}
+    assert names.count("dstpu_join") == 1
+    assert set(names) == {"dstpu_prefill", "dstpu_chunk", "dstpu_boundary",
+                          "dstpu_join", "dstpu_decode"}
     # what PR 55's build made for these shapes: a prefill a bucket
     # multiple up to the row, a chunk a table width, one boundary
-    # sampler, one decode
+    # sampler, one decode; and since ISSUE 60 the one program that
+    # writes a boundary token into a decode's operand
     row = eng.max_pages_per_seq * eng.page_size
     widths, w = 0, 1
     while w < eng.max_pages_per_seq:
         widths, w = widths + 1, w * 2
-    assert made == -(-row // eng.prefill_bucket) + widths + 1 + 2
+    assert made == -(-row // eng.prefill_bucket) + widths + 1 + 3
     assert eng._decode_jit._cache_size() == 1
     rng = np.random.default_rng(3)
     for i in range(4):
         eng.submit(i, _prompt(rng, cfg, 4 + i), max_new_tokens=5 + 2 * i)
+    for _ in range(3):
+        eng.step()
+    # an arrival beside a free slot under a step in flight: its token
+    # joins the operand the decode takes from the device
+    eng.submit(4, _prompt(rng, cfg, 6), max_new_tokens=7)
     eng.run()
     d = eng.statusz()["decode"]
     assert d["ahead"] > 0 and sum(d["behind"].values()) > 0
+    # tokens up from the host with a token joined, the output of the
+    # step before with one joined, and either alone: ONE entry, and ONE
+    # of the join's whatever it wrote into
+    assert d["joined"] == 5 and d["behind"]["admission"] == 0
     assert eng._decode_jit._cache_size() == 1
+    assert eng._join.jfn._cache_size() == 1
     # nothing was made ready after the build, named or not
     now = BUILD_LEDGER.snapshot()
     assert now["programs"] == after_build["programs"]
@@ -265,7 +401,9 @@ def test_the_dispatches_are_counted_where_they_went(devices):
     assert d["ahead"] == c["serving_decode_ahead"] > 0
     assert d["dispatches"] == c["serving_decode_syncs"]
     assert d["ahead"] + sum(d["behind"].values()) == d["dispatches"]
-    assert d["behind"]["finish"] > 0 and d["behind"]["admission"] > 0
+    # an admission no longer stays behind: its token joins on the device
+    assert d["behind"]["finish"] > 0 and d["behind"]["admission"] == 0
+    assert d["joined"] == c["serving_boundary_joined"] > 0
     assert not d["in_flight"]
     rows = [r for r in STEP_LEDGER.snapshot()["rows"] if r["n"] > n_before]
     by_site = {site: sum(r["programs"][site][0] for r in rows)
@@ -302,3 +440,41 @@ def test_a_step_in_flight_is_work_and_is_dropped_unread(devices):
     assert eng._flying is not None
     eng.shutdown()
     assert eng._flying is None
+
+
+@pytest.mark.parametrize("family", ["plain", "expert_rows", "state"])
+def test_a_joined_token_unread_is_work_and_is_dropped(family, devices):
+    """A boundary token that joined a decode on the device is read when
+    that decode lands: until then its request has nothing, the engine
+    has work, and ``abandon_inflight`` drops token and step unread."""
+    eng = _engine(family)
+    cfg = _model(family)[0]
+    rng = np.random.default_rng(13)
+    eng.submit(0, _prompt(rng, cfg, 6), max_new_tokens=30)
+    while not (eng._flying is not None and eng.slots[0].generated):
+        eng.step()
+    eng.submit(1, _prompt(rng, cfg, 7), max_new_tokens=9)
+    while eng.slots[1] is None or eng.slots[1].prefilling:
+        eng.step()                  # the call that finishes its prompt
+    new = eng.slots[1]
+    c = eng.registry.snapshot()["counters"]
+    assert new.boundary is not None and new.generated == []
+    assert c["serving_boundary_joined"] == 2
+    assert c["serving_boundary_syncs"] == c["serving_boundary_tokens"] == 0
+    assert (1, new) in eng._flying.rows and eng.has_work
+    d = eng.statusz()["decode"]
+    assert d["joined"] == 2 and d["in_flight"]
+    got = eng.abandon_inflight()
+    # nothing of it was read: it may be served again elsewhere
+    assert sorted((r.req_id, n > 0) for r, n in got) \
+        == [(0, True), (1, False)]
+    assert eng._flying is None and not eng.has_work
+    assert eng.check_leaks() == []
+    # and the engine goes on: the next request's tokens are its own
+    prompt = _prompt(rng, cfg, 5)
+    eng.submit(2, prompt, max_new_tokens=6)
+    replay = _engine(family, synchronous=True)
+    replay.submit(2, prompt, max_new_tokens=6)
+    assert eng.run() == replay.run()
+    eng.shutdown()
+    replay.shutdown()

@@ -619,18 +619,19 @@ class TestServingBuild:
         snap = eng.statusz()["build"]
         mine = [e for e in snap["entries"] if t0 < e["t_end"] < t1]
         # prefill at 8 multiples of the bucket, the chunk program at
-        # tables of 1, 2, 4 and 8 pages, the boundary sampler, the decode
+        # tables of 1, 2, 4 and 8 pages, the boundary sampler, what
+        # writes its token into the decode's operand, the decode
         assert [e["span"] for e in mine] == (
             [f"prefill end={8 * i}" for i in range(1, 9)]
             + [f"chunk_prefill w={w}" for w in (1, 2, 4, 8)]
-            + ["boundary", "decode_chunk b=2"])
+            + ["boundary", "join", "decode_chunk b=2"])
         assert [e["program"] for e in mine] == (
             ["dstpu_prefill"] * 8 + ["dstpu_chunk"] * 4
-            + ["dstpu_boundary", "dstpu_decode"])
+            + ["dstpu_boundary", "dstpu_join", "dstpu_decode"])
         assert [e["site"] for e in mine] == (
             ["prefill"] * 8 + ["chunk_prefill"] * 4
-            + ["boundary", "decode_chunk"])
-        assert len(mine) == eng.devprof.compiles_warmup == 14
+            + ["boundary", "join", "decode_chunk"])
+        assert len(mine) == eng.devprof.compiles_warmup == 15
         for e in mine:
             assert e["trace_s"] > 0 and e["lower_s"] > 0
             assert e["compile_s"] > 0 and not e["cache_hit"]
@@ -647,7 +648,7 @@ class TestServingBuild:
         # eager fills of the same stretch, nothing of an earlier engine
         after = BUILD_LEDGER.snapshot()
         assert cnt["build_programs"] == after["programs"] - before["programs"]
-        assert cnt["build_programs"] >= 14 + 1
+        assert cnt["build_programs"] >= 15 + 1
         assert cnt["build_cache_misses"] == cnt["build_programs"]
         named = sum(e[k] for e in after["entries"] for k in PARTS
                     if t0 < e["t_end"] < t1)
@@ -658,7 +659,7 @@ class TestServingBuild:
 
     @pytest.mark.parametrize("name,count", [
         ("build_alloc", 1), ("build_programs", 2), ("build_warmup", 1),
-        ("build_program", 14)])
+        ("build_program", 15)])
     def test_build_spans_are_entered_once_each(self, built, name, count):
         eng, _, _ = built
         h = eng.registry.snapshot()["histograms"][f"{name}_seconds"]
@@ -692,7 +693,7 @@ class TestServingBuild:
             got = {e["program"] for e in BUILD_LEDGER.snapshot()["entries"]
                    if e["t_end"] > t0}
             assert got == {"dstpu_prefill", "dstpu_boundary",
-                           "dstpu_decode"}
+                           "dstpu_join", "dstpu_decode"}
         finally:
             eng.shutdown()
 

@@ -219,7 +219,7 @@ def test_train_spans(capture):
 
 
 BUILD_SPANS = {"dstpu/build_alloc": 1, "dstpu/build_programs": 2,
-               "dstpu/build_warmup": 1, "dstpu/build_program": 14,
+               "dstpu/build_warmup": 1, "dstpu/build_program": 15,
                "dstpu/build_state": 1, "dstpu/build_step": 1}
 BUILD_COUNTERS = ("build_programs", "build_cache_misses",
                   "build_trace_seconds", "build_lower_seconds",
@@ -248,7 +248,8 @@ def test_build_spans(capture, name):
                              for i in range(1, 9)]
         assert words[8:12] == [("chunk_prefill", {"w": w})
                                for w in (1, 2, 4, 8)]
-        assert words[12:] == [("boundary", {}), ("decode_chunk", {"b": 2})]
+        assert words[12:] == [("boundary", {}), ("join", {}),
+                              ("decode_chunk", {"b": 2})]
     if name == "dstpu/build_step":
         assert mine[0].stats["site"] == "train_step"
 
@@ -298,6 +299,11 @@ def _paged_programs(name):
             jax.ShapeDtypeStruct((1, cfg.vocab_size), jnp.float32),
             absx(eng._key), jax.ShapeDtypeStruct((), jnp.int32),
             jax.ShapeDtypeStruct((1,), jnp.float32)),
+        # a boundary token written into a decode's token operand
+        "join": eng._join.lower(
+            jax.ShapeDtypeStruct(eng._out_shape, jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32)),
         "decode": eng._decode_chunk_fn.lower(
             params_a, jax.ShapeDtypeStruct((B, 1), jnp.int32), cache_a,
             absx(eng._key), jax.ShapeDtypeStruct((), jnp.int32),
@@ -326,12 +332,13 @@ def _words(text):
 
 
 @pytest.mark.parametrize("program", ["prefill", "chunk", "decode",
-                                     "boundary"])
+                                     "boundary", "join"])
 @pytest.mark.parametrize("name", ["gpt2", "mixtral", "laguna"])
 def test_paged_programs_carry_every_scope_that_applies(name, program):
     text = _paged_programs(name)[program]
-    # the boundary sampler runs no model: its one scope is ``sample``
-    want = ({"sample"} if program == "boundary"
+    # the boundary sampler runs no model, nor does what writes its
+    # token into a decode's operand: their one scope is ``sample``
+    want = ({"sample"} if program in ("boundary", "join")
             else BLOCKS[name] | PHASE[program])
     assert want <= _words(text), want - _words(text)
     assert f"dstpu_{program}" in text          # the program's own name

@@ -538,7 +538,9 @@ class TestTelemetryAndTrace:
     def test_boundary_sampling_batched(self, gpt2_model, devices):
         """Satellite: prefill-boundary tokens sample in ONE batched
         fetch per step — concurrent admissions share a sync instead of
-        paying one device round-trip each."""
+        paying one device round-trip each.  Since ISSUE 60 the sync
+        they share is the decode's own where they join it on the
+        device; a speculative engine still fetches them, once."""
         cfg, params = gpt2_model
         eng = serving_engine(params, cfg, telemetry=True,
                              max_batch=4, page_size=8,
@@ -546,7 +548,21 @@ class TestTelemetryAndTrace:
                              prefill_bucket=8)
         for i in range(4):
             eng.submit(i, [5 + i, 9, 2], max_new_tokens=4)
-        eng.step()                         # 4 admissions, one flush
+        eng.step()                         # 4 admissions, no flush
         c = eng.registry.snapshot()["counters"]
-        assert c["serving_boundary_syncs"] == 1
+        assert c["serving_boundary_syncs"] == 0
+        assert c["serving_boundary_joined"] == 4
         eng.run()
+        spec = serving_engine(params, cfg, telemetry=True,
+                              max_batch=4, page_size=8,
+                              num_pages=32, max_seq=64,
+                              prefill_bucket=8,
+                              speculative={"draft_tokens": 3})
+        for i in range(4):
+            spec.submit(i, [5 + i, 9, 2], max_new_tokens=4)
+        spec.step()                        # 4 admissions, one flush
+        c = spec.registry.snapshot()["counters"]
+        assert c["serving_boundary_syncs"] == 1
+        assert c["serving_boundary_tokens"] == 4
+        assert c["serving_boundary_joined"] == 0
+        assert spec.run() == eng.finished

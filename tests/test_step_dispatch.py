@@ -126,10 +126,15 @@ def _admit_two(eng, log):
     eng.submit("b", PROMPTS[1], max_new_tokens=4)
     del log[:]
     eng.step()
-    assert counters(eng)["serving_boundary_tokens"] == len(PROMPTS) + 2
+    # every boundary token joined its decode on the device (ISSUE 60):
+    # none was fetched before that decode was built
+    c = counters(eng)
+    assert c["serving_boundary_joined"] == len(PROMPTS) + 2
+    assert c["serving_boundary_tokens"] == c["serving_boundary_syncs"] == 0
     # the step's own decode and, its rows being known to stay, the next
     # one, dispatched ahead of the fetch
-    return {"dstpu_prefill": 2, "dstpu_boundary": 2, "dstpu_decode": 2}
+    return {"dstpu_prefill": 2, "dstpu_boundary": 2, "dstpu_join": 2,
+            "dstpu_decode": 2}
 
 
 def _chunk_end(eng, log):
@@ -141,7 +146,8 @@ def _chunk_end(eng, log):
     del log[:]
     eng.step()
     assert len(slot.generated) == 2         # the boundary token, one decode
-    return {"dstpu_chunk": 1, "dstpu_boundary": 1, "dstpu_decode": 2}
+    return {"dstpu_chunk": 1, "dstpu_boundary": 1, "dstpu_join": 1,
+            "dstpu_decode": 2}
 
 
 def _plain(eng, log):
@@ -260,8 +266,10 @@ def test_a_row_counts_what_the_step_dispatched(model, drive, kw, want):
         by_site[site] = by_site.get(site, 0) + p[0]
     assert by_site == seen
     assert programs == want(eng)
-    # a prefill that completed fetched its boundary token in this step
-    assert row["boundary_tokens"] == got.count("dstpu_boundary")
+    # a prefill that completed left its boundary token to this step's
+    # decode, one ``dstpu_join`` a token
+    assert row["boundary_tokens"] == got.count("dstpu_boundary") \
+        == got.count("dstpu_join")
     assert row["admitted"] == got.count("dstpu_prefill")
     assert row["k"] == eng.decode_chunk and row["preempted"] == 0
 
@@ -286,8 +294,9 @@ def test_a_step_writes_one_row_and_none_with_telemetry_off(model):
         == c["serving_decode_syncs"]
     assert sum(r["programs"]["chunk"][0] for r in rows) \
         == c["serving_prefill_chunks"]
-    assert sum(r["boundary_tokens"] > 0 for r in rows) \
-        == c["serving_boundary_syncs"]
+    # each joined its decode on the device: no fetch of their own
+    assert c["serving_boundary_joined"] == len(PROMPTS)
+    assert c["serving_boundary_syncs"] == c["serving_boundary_tokens"] == 0
     # the phases tile the step: theirs are the spans' own readings
     for r in rows:
         assert r["t0"] < r["t1"]
@@ -314,12 +323,58 @@ def test_a_step_writes_one_row_and_none_with_telemetry_off(model):
         off.registry.snapshot()["histograms"]
 
 
-def test_exposed_seconds_follow_a_fetch_and_end_at_a_dispatch(gpt2_model):
-    """The device provably has nothing queued from the return of a
-    fetch to the next dispatch call, and only then."""
+def test_no_fetch_stands_between_a_prompt_and_its_decode(gpt2_model):
+    """Where the boundary token joins its decode on the device (ISSUE
+    60) no call of a request's life fetches before it dispatches: no
+    instant is known to be idle, and an arrival under a step in flight
+    lands nothing first."""
     cfg, params = gpt2_model
     eng = serving_engine(params, cfg, telemetry=True, prefill_chunk=8,
                          **KW)
+    eng.submit("long", PROMPTS[3], max_new_tokens=12)   # three chunks
+    rows = ledger_rows(stepped(eng, 4))
+    third, fourth = rows[2:]
+    for row in rows:
+        assert (row["exposed_s"], row["exposed"], row["drained"]) \
+            == (0.0, {}, False)
+    # the third ends the prompt: its token joins the decode, which is
+    # read (the token with it) behind the next one
+    assert third["boundary_tokens"] == 1
+    assert third["programs"]["chunk"][0] == 1
+    assert third["programs"]["decode"][0] == 1
+    assert third["programs"]["decode_ahead"][0] == 1
+    assert {"token_sync", "append"} <= set(third["phases"])
+    assert fourth["programs"]["decode_ahead"][0] == 1
+    assert fourth["programs"]["decode"][0] == 0
+    # an arrival beside a free slot finds a step in flight, which stays
+    # there: the first chunk goes out behind it, the next decode behind
+    # that, and then the step is read
+    eng.submit("next", PROMPTS[3], max_new_tokens=3)
+    (row,) = ledger_rows(stepped(eng, 1))
+    assert row["programs"]["chunk"][0] == 1
+    assert row["programs"]["decode_ahead"][0] == 1
+    assert row["programs"]["decode"][0] == 0 and not row["drained"]
+    assert row["exposed"] == {}
+    # its last chunk too: the token joins the step behind the one in
+    # flight, which goes out from the device
+    rows = ledger_rows(stepped(eng, 2))
+    assert rows[1]["boundary_tokens"] == 1
+    assert rows[1]["programs"]["decode_ahead"][0] == 1
+    assert rows[1]["programs"]["decode"][0] == 0
+    assert rows[1]["exposed"] == {}
+    d = eng.statusz()["decode"]
+    assert d["joined"] == 2 and not any(
+        d["behind"][why] for why in ("admission", "boundary", "prefill"))
+
+
+def test_exposed_seconds_follow_a_fetch_and_end_at_a_dispatch(gpt2_model):
+    """The device provably has nothing queued from the return of a
+    fetch to the next dispatch call, and only then.  (The sequence the
+    rule falls back to: here every boundary token is fetched.)"""
+    cfg, params = gpt2_model
+    eng = serving_engine(params, cfg, telemetry=True, prefill_chunk=8,
+                         **KW)
+    eng._joins = lambda: False
     eng.submit("long", PROMPTS[3], max_new_tokens=6)    # three chunks
     first, second, third, fourth = ledger_rows(stepped(eng, 4))
     # nothing was fetched before the first chunk went out, nor before
@@ -470,8 +525,14 @@ def _serve_against_the_reference(cfg, params, kw):
     for i, (p, n) in reqs.items():
         assert out[i] == offline(cfg, params, p, n), i
     c = counters(eng)
-    assert c["serving_boundary_tokens"] == c["serving_admitted_requests"]
+    # joined to its decode on the device, or fetched before it
+    assert c["serving_boundary_tokens"] + c["serving_boundary_joined"] \
+        == c["serving_admitted_requests"]
     assert c["serving_boundary_syncs"] <= c["serving_boundary_tokens"]
+    if "speculative" in kw or "zero_inference" in kw:
+        assert c["serving_boundary_joined"] == 0
+    else:
+        assert c["serving_boundary_tokens"] == 0
     return c
 
 
@@ -501,15 +562,23 @@ def test_streamed_engine_serves_the_reference(family, kw):
     _serve_against_the_reference(cfg, params, {"zero_inference": {}, **kw})
 
 
-def test_admissions_of_one_step_share_a_fetch(gpt2_model):
+@pytest.mark.parametrize("joins", [True, False],
+                         ids=["the_decodes", "their_own"])
+def test_admissions_of_one_step_share_a_fetch(gpt2_model, joins):
+    """The decode's, where their tokens join it on the device; one of
+    their own where the rule says they are fetched first."""
     cfg, params = gpt2_model
     eng = serving_engine(params, cfg, telemetry=True, **KW)
+    if not joins:
+        eng._joins = lambda: False
     for i, p in enumerate(PROMPTS):
         eng.submit(i, p, max_new_tokens=4)
     eng.step()
     c = counters(eng)
-    assert c["serving_boundary_tokens"] == 4
-    assert c["serving_boundary_syncs"] == 1
+    assert c["serving_boundary_joined"] == (4 if joins else 0)
+    assert c["serving_boundary_tokens"] == (0 if joins else 4)
+    assert c["serving_boundary_syncs"] == (0 if joins else 1)
+    assert [len(s.generated) for s in eng.slots] == [2] * 4
 
 
 # -------------------------------------------------- (c) sampled streams
