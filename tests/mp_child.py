@@ -36,6 +36,9 @@ jax.config.update("jax_platforms", "cpu")
 # params than the parent's single-process oracle and fail loss parity
 # by bf16-visible margins
 jax.config.update("jax_threefry_partitionable", True)
+# uncached: conftest.py keeps a compile cache a PROCESS, and the ranks
+# would share whatever directory the environment names
+jax.config.update("jax_compilation_cache_dir", None)
 
 import numpy as np  # noqa: E402
 

@@ -13,7 +13,7 @@ on the device until the decode that consumes it has been dispatched
 (``dstpu_join``), so an arrival beside a free slot and a prompt's last
 chunk go out behind the step in flight, and the token is read with the
 decode it joined.  The ``join_*`` scenarios hold that to the same
-oracle.
+oracle (``test_decode_ahead_join.py`` runs them).
 """
 
 import time
@@ -216,8 +216,7 @@ CASES = [("plain", s) for s in SCENARIOS] + [
     ("state", "join_fail"), ("state", "join_two.k4")]
 
 
-@pytest.mark.parametrize("family,scenario", CASES)
-def test_served_ahead_is_served_in_turn(family, scenario, devices):
+def _served_in_turn(family, scenario):
     """Every request's tokens are what the synchronous engine gives,
     and no page is leaked, whatever found a step in flight."""
     eng, replay, out, want, ended = _serve_pair(family, scenario)
@@ -294,6 +293,13 @@ def test_served_ahead_is_served_in_turn(family, scenario, devices):
         assert c["serving_boundary_tokens"] >= 1
     eng.shutdown()
     replay.shutdown()
+
+
+# the ``join_*`` scenarios are ``test_decode_ahead_join.py``'s cases
+@pytest.mark.parametrize("family,scenario", [
+    c for c in CASES if not c[1].startswith("join_")])
+def test_served_ahead_is_served_in_turn(family, scenario, devices):
+    _served_in_turn(family, scenario)
 
 
 @pytest.mark.parametrize("at", [(0, 0, 0), (0, 3, 3, 8)],
@@ -440,41 +446,3 @@ def test_a_step_in_flight_is_work_and_is_dropped_unread(devices):
     assert eng._flying is not None
     eng.shutdown()
     assert eng._flying is None
-
-
-@pytest.mark.parametrize("family", ["plain", "expert_rows", "state"])
-def test_a_joined_token_unread_is_work_and_is_dropped(family, devices):
-    """A boundary token that joined a decode on the device is read when
-    that decode lands: until then its request has nothing, the engine
-    has work, and ``abandon_inflight`` drops token and step unread."""
-    eng = _engine(family)
-    cfg = _model(family)[0]
-    rng = np.random.default_rng(13)
-    eng.submit(0, _prompt(rng, cfg, 6), max_new_tokens=30)
-    while not (eng._flying is not None and eng.slots[0].generated):
-        eng.step()
-    eng.submit(1, _prompt(rng, cfg, 7), max_new_tokens=9)
-    while eng.slots[1] is None or eng.slots[1].prefilling:
-        eng.step()                  # the call that finishes its prompt
-    new = eng.slots[1]
-    c = eng.registry.snapshot()["counters"]
-    assert new.boundary is not None and new.generated == []
-    assert c["serving_boundary_joined"] == 2
-    assert c["serving_boundary_syncs"] == c["serving_boundary_tokens"] == 0
-    assert (1, new) in eng._flying.rows and eng.has_work
-    d = eng.statusz()["decode"]
-    assert d["joined"] == 2 and d["in_flight"]
-    got = eng.abandon_inflight()
-    # nothing of it was read: it may be served again elsewhere
-    assert sorted((r.req_id, n > 0) for r, n in got) \
-        == [(0, True), (1, False)]
-    assert eng._flying is None and not eng.has_work
-    assert eng.check_leaks() == []
-    # and the engine goes on: the next request's tokens are its own
-    prompt = _prompt(rng, cfg, 5)
-    eng.submit(2, prompt, max_new_tokens=6)
-    replay = _engine(family, synchronous=True)
-    replay.submit(2, prompt, max_new_tokens=6)
-    assert eng.run() == replay.run()
-    eng.shutdown()
-    replay.shutdown()

@@ -320,8 +320,8 @@ print(json.dumps(BUILD_LEDGER.snapshot()))
 @pytest.fixture(scope="module")
 def cache_probe():
     """A miss and a hit against a temporary ``jax_compilation_cache_dir``,
-    in a process of their own: the suite keeps the persistent cache off
-    on the CPU (conftest.py says why)."""
+    in a process of their own: the ledger there holds these two programs
+    and nothing the suite's own cache (conftest.py) has read back."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,

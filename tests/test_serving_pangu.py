@@ -77,6 +77,11 @@ def test_chunked_prefill_then_decode_matches_the_reference(params):
     want = [_reference_logits(params, s) for s in seqs]
     cache = _cache(CFG, len(lens), 64)
     got = [np.zeros_like(w) for w in want]
+    # jitted: compiled once a shape (called eagerly the forward's loops
+    # compile anew at every call)
+    forward = jax.jit(lambda toks, c, continuation=False: forward_paged(
+        params, toks, CFG, c, continuation=continuation, tp=False,
+        interpret=True), static_argnames="continuation")
     for b, (n, seq) in enumerate(zip(lens, seqs)):
         for done in range(0, n, C):
             take = min(C, n - done)
@@ -85,17 +90,15 @@ def test_chunked_prefill_then_decode_matches_the_reference(params):
             view = cache._replace(
                 table=cache.table[b:b + 1],
                 seq_lens=jnp.full((1,), done, jnp.int32))
-            logits, view = forward_paged(
-                params, jnp.asarray(toks), CFG, view, continuation=True,
-                tp=False, interpret=True)
+            logits, view = forward(jnp.asarray(toks), view,
+                                   continuation=True)
             got[b][done:done + take] = np.asarray(logits[0, :take])
             cache = cache._replace(k=view.k, expert_rows=view.expert_rows)
     cache = cache._replace(seq_lens=jnp.asarray(lens, jnp.int32))
     for j in range(new):
         toks = jnp.asarray([[s[n + j]] for n, s in zip(lens, seqs)],
                            jnp.int32)
-        logits, cache = forward_paged(params, toks, CFG, cache, tp=False,
-                                      interpret=True)
+        logits, cache = forward(toks, cache)
         for b, n in enumerate(lens):
             got[b][n + j] = np.asarray(logits[b, 0])
     for g, w in zip(got, want):
