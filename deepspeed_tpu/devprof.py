@@ -104,9 +104,9 @@ class _Thread(threading.local):
         self.mirror: Optional["BuildCounters"] = None
         # named entries made ready here: a sentinel's or a span's to claim
         self.made: "collections.deque" = collections.deque(maxlen=32)
-        self.named = 0
-        # programs, cache misses, trace, lower, cache-load, compile
-        self.sums = [0, 0, 0.0, 0.0, 0.0, 0.0]
+        # and what the program being traced said of itself (_on_build_word)
+        self.named, self.notes = 0, {}
+        self.sums = [0, 0, 0.0, 0.0, 0.0, 0.0]  # as BuildLedger.sums
 
 
 _tl = _Thread()
@@ -602,7 +602,7 @@ class ProgramSpan:
 
     def __enter__(self):
         tl = _tl
-        self._named, self._timed = tl.named, sum(tl.sums[2:])
+        tl.notes, self._named, self._timed = {}, tl.named, sum(tl.sums[2:])
         self._span(site=self._site, **self._word).__enter__()
         self._t0 = time.perf_counter()
         return self
@@ -613,8 +613,8 @@ class ProgramSpan:
         tl = _tl
         if tl.named > self._named:
             e = tl.made[-1]
-            e["span"] = " ".join(
-                [self._site] + [f"{k}={v}" for k, v in self._word.items()])
+            e["span"] = " ".join([self._site] + [
+                f"{k}={v}" for k, v in {**self._word, **tl.notes}.items()])
             e["run_s"] = round(
                 max(wall - (sum(tl.sums[2:]) - self._timed), 0.0), 6)
         return False
@@ -878,3 +878,28 @@ NULL_DEVPROF = _NullDevProf()
 
 # whatever the process jits from here on is in the ledger
 install_compile_listener()
+
+
+# what a program says of itself while it is traced: the event a rule
+# of the shapes records deep inside a model (``ops/attention_pallas.py``:
+# which flash backward a step runs, and why), on JAX's own bus, so the
+# kernels import nothing of the tracing
+BUILD_WORD_EVENT = "/dstpu/build_word"
+
+
+def _on_build_word(event: str, **word) -> None:
+    """Words for the build span the trace runs under: :class:`ProgramSpan`
+    writes them after its own into the ``span`` of the entry its dispatch
+    made (no entry where the trace was cached: nothing was built).  A
+    word said twice with two answers keeps both (``fused+split``);
+    outside a span the words go when the next one starts.  (At the
+    file's end: a Mosaic kernel's payload carries the lines of the
+    frames above its call, ``_SentinelFn.__call__`` among them.)"""
+    if event == BUILD_WORD_EVENT:
+        notes = _tl.notes
+        for k, v in word.items():
+            had = str(notes.get(k, v)).split("+")
+            notes[k] = "+".join(had if str(v) in had else had + [str(v)])
+
+
+jax.monitoring.register_event_listener(_on_build_word)

@@ -8,9 +8,9 @@ block sweep, output is rescaled once at the last block.  Causal blocks
 above the diagonal are skipped via masking (the index map keeps the sweep
 dense; skipped blocks cost one compare).
 
-Backward: custom VJP — one pallas kernel computes dQ (sweep over K
-blocks), a second computes dK/dV (sweep over Q blocks), both recomputing
-p = exp(qk - lse) from the saved logsumexp, FlashAttention-2 style.
+Backward, where a head does not lie whole in VMEM (``attention_pallas_bwd``
+has the rule and the fused kernel): one pallas kernel computes dQ (sweep
+over K blocks), a second dK/dV (sweep over Q blocks), from p = exp(qk - lse).
 
 GQA: logical-head BlockSpec index maps — query head h reads kv head
 h // (H // KV) directly (``_kv_row``), so K/V are never repeated in HBM
@@ -25,10 +25,13 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from deepspeed_tpu.ops.attention_pallas_bwd import (FLASH_NAMES, NEG_INF,
+                                                    _flash_bwd_fused,
+                                                    flash_backward)
 
 
 def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
@@ -374,39 +377,36 @@ def _flash_bwd_impl(q, k, v, seg, out, lse, do, *, causal, block_q,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _flash_bhtd(q, k, v, seg, causal: bool, interpret: bool, heads: int,
                 kv_heads: int):
+    """The forward kernel ONCE a call, as an ordinary call on operands
+    cut from the gradient and OUTSIDE any custom VJP, its two results
+    named: a remat policy sees no product in a ``pallas_call``, and what
+    a VJP's forward rule makes is not saveable under any policy, so the
+    old rule's residuals ``out`` and ``lse`` cost a second run of the
+    whole kernel in every ``jax.checkpoint``-ed backward (PERF.md 6, PR
+    62).  ``remat.policy`` joins the names to every policy that keeps
+    anything; the gradient comes after, from a VJP whose forward rule
+    runs no kernel (:func:`attach`, at the file's end), and a caller
+    that never differentiates compiles the kernel call alone.
+
+    The log-sum leaves as ``[B H, 1, T]``: a row, lane-dense in memory
+    between the passes, where the kernel's ``[B H, T, 1]`` column pads
+    each number to 128 lanes.
+
+    (``attach`` stands below the file's last kernel, and this function
+    with the imports keeps the old VJP's count of lines: the latent and
+    the window kernels below are lowered from the lines they stand on,
+    and a moved line is a new compile-cache key for every serving
+    program that holds one, ROADMAP S2 (c).)"""
     block_q, block_k = _pick_blocks(q.shape[1], k.shape[1])
-    out, _ = _flash_fwd_impl(q, k, v, seg, causal=causal, block_q=block_q,
-                             block_k=block_k, heads=heads,
-                             kv_heads=kv_heads, interpret=interpret)
-    return out
-
-
-def _flash_bhtd_fwd(q, k, v, seg, causal, interpret, heads, kv_heads):
-    block_q, block_k = _pick_blocks(q.shape[1], k.shape[1])
-    out, lse = _flash_fwd_impl(q, k, v, seg, causal=causal, block_q=block_q,
-                               block_k=block_k, heads=heads,
-                               kv_heads=kv_heads, interpret=interpret)
-    return out, (q, k, v, seg, out, lse)
-
-
-def _flash_bhtd_bwd(causal, interpret, heads, kv_heads, res, do):
-    q, k, v, seg, out, lse = res
-    block_q, block_k = _pick_blocks(q.shape[1], k.shape[1])
-    dq, dk, dv = _flash_bwd_impl(q, k, v, seg, out, lse, do, causal=causal,
-                                 block_q=block_q, block_k=block_k,
-                                 heads=heads, kv_heads=kv_heads,
-                                 interpret=interpret)
-    # segment ids are integral: their cotangent is float0 (None when the
-    # operand was None — the pytree structures must match)
-    dseg = (None if seg is None
-            else np.zeros(seg.shape, jax.dtypes.float0))
-    return dq, dk, dv, dseg
-
-
-_flash_bhtd.defvjp(_flash_bhtd_fwd, _flash_bhtd_bwd)
+    out, lse = _flash_fwd_impl(
+        *map(jax.lax.stop_gradient, (q, k, v)), seg, causal=causal,
+        block_q=block_q, block_k=block_k, heads=heads, kv_heads=kv_heads,
+        interpret=interpret)
+    out = checkpoint_name(out, FLASH_NAMES[0])
+    lse = checkpoint_name(lse.reshape(lse.shape[0], 1, -1), FLASH_NAMES[1])
+    return attach(q, k, v, seg, out, lse, causal, interpret, heads, kv_heads)
 
 
 def flash_attention_tpu(q, k, v, causal: bool = True, segment_ids=None,
@@ -628,3 +628,49 @@ def window_flash_attention_tpu(q, rows, ring, start, interpret: bool = False):
         name="dstpu_window_flash_fwd",
     )(start.astype(jnp.int32), q.reshape(B, T, H * Dh), kv, kv)
     return out.reshape(B, T, H, Dh)
+
+
+# ------------------------------------------- the flash kernel's gradient
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def attach(q, k, v, seg, out, lse, causal: bool, interpret: bool,
+           heads: int, kv_heads: int):
+    """``out``, with the flash gradient to ``q``, ``k`` and ``v``: the
+    context and the log-sum (``[B H, 1, T]``) are the forward kernel's,
+    made outside on operands cut from the gradient (``_flash_bhtd``)."""
+    return out
+
+
+def _attach_fwd(q, k, v, seg, out, lse, causal, interpret, heads, kv_heads):
+    return out, (q, k, v, seg, out, lse)
+
+
+def _attach_bwd(causal, interpret, heads, kv_heads, res, do):
+    q, k, v, seg, out, lse = res
+    (BH, T, D), S = q.shape, k.shape[1]
+    path, why = flash_backward(T, S, D, heads, kv_heads, seg is not None,
+                               q.dtype.itemsize)
+    # for the build span this trace runs under (devprof listens): which
+    # backward the step it makes ready runs, and why
+    jax.monitoring.record_event(
+        "/dstpu/build_word", flash_bwd=path,
+        flash_bwd_why=why.replace(" ", "_"))
+    if path == "fused":
+        delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
+                        axis=-1)[:, None, :]                # [BH, 1, T]
+        dq, dk, dv = _flash_bwd_fused(q, k, v, lse, delta, do, causal=causal,
+                                      interpret=interpret)
+    else:
+        block_q, block_k = _pick_blocks(T, S)
+        dq, dk, dv = _flash_bwd_impl(
+            q, k, v, seg, out, lse.reshape(BH, T, 1), do, causal=causal,
+            block_q=block_q, block_k=block_k, heads=heads,
+            kv_heads=kv_heads, interpret=interpret)
+    # segment ids are integral: their cotangent is float0 (None when the
+    # operand was None — the pytree structures must match); the context
+    # and the log-sum were cut from the gradient where they were made:
+    # None is a zero cotangent
+    dseg = None if seg is None else np.zeros(seg.shape, jax.dtypes.float0)
+    return dq, dk, dv, dseg, None, None
+
+
+attach.defvjp(_attach_fwd, _attach_bwd)

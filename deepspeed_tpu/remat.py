@@ -19,13 +19,46 @@ Models tag their two big per-block intermediates with
 context, quadratic to recompute) and ``mlp_out`` (the FFN inner
 activation) — the names ``save_attn`` keeps on-device and
 ``offload_attn`` spills to host.
+
+The flash kernel's two results (``FLASH_NAMES``: a head's context and
+its log-sum, which the backward reads) are kept by EVERY policy that
+keeps a product, offloaded by the two that offload: the context is a
+matmul's output hidden in a ``pallas_call``, where ``checkpoint_dots``
+sees no ``dot_general``, and unkept it costs a second run of the whole
+forward kernel in the backward (PERF.md 6, PR 62).  ``full`` still
+recomputes it.
 """
 
 from __future__ import annotations
 
 import jax
 
+from deepspeed_tpu.ops.attention_pallas_bwd import FLASH_NAMES
+
 _NAMES = ("attn_out", "mlp_out")
+
+
+def _and_flash(base, named=None):
+    """``named``'s word on the flash kernel's two results (by default:
+    saved), ``base``'s on everything else.  (``save_from_both_policies``
+    joins bools only; an offload policy answers with a place.)"""
+    flash = jax.checkpoint_policies.save_only_these_names(*FLASH_NAMES)
+    named = named or flash
+
+    def joined(prim, *args, **params):
+        which = named if flash(prim, *args, **params) else base
+        return which(prim, *args, **params)
+
+    return joined
+
+
+def _to_host():
+    """The tagged intermediates and the flash kernel's two results, to
+    host RAM."""
+    return jax.checkpoint_policies.save_and_offload_only_these_names(
+        names_which_can_be_saved=[],
+        names_which_can_be_offloaded=list(_NAMES + FLASH_NAMES),
+        offload_src="device", offload_dst="pinned_host")
 
 
 def policy(name: str):
@@ -36,22 +69,22 @@ def policy(name: str):
         return jax.checkpoint_policies.nothing_saveable
     if name == "save_dots":
         # keep matmul outputs, recompute elementwise — the usual sweet spot
-        return jax.checkpoint_policies.checkpoint_dots
+        return _and_flash(jax.checkpoint_policies.checkpoint_dots)
     if name == "save_dots_no_batch":
-        return jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
+        return _and_flash(
+            jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
     if name == "save_attn":
-        return jax.checkpoint_policies.save_only_these_names(*_NAMES)
+        return jax.checkpoint_policies.save_only_these_names(
+            *_NAMES, *FLASH_NAMES)
     if name == "offload_attn":
         # ref cpu_checkpointing: the tagged intermediates live in host
         # RAM between forward and backward instead of HBM
-        return jax.checkpoint_policies.save_and_offload_only_these_names(
-            names_which_can_be_saved=[],
-            names_which_can_be_offloaded=list(_NAMES),
-            offload_src="device", offload_dst="pinned_host")
+        return _to_host()
     if name == "offload_dots_no_batch":
         # heavier offload: every no-batch-dim matmul output goes to host
-        return jax.checkpoint_policies.offload_dot_with_no_batch_dims(
-            "device", "pinned_host")
+        return _and_flash(
+            jax.checkpoint_policies.offload_dot_with_no_batch_dims(
+                "device", "pinned_host"), _to_host())
     raise ValueError(f"unknown remat policy {name!r}")
 
 

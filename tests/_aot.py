@@ -18,6 +18,14 @@ def _compile(fn, chip, *shapes):
     return compiled
 
 
+def _flash_kernels(hlo):
+    """The flash kernels' calls in the optimized HLO, one name a call
+    (a gradient's program spells them ``jvp_dstpu_flash_fwd_.1`` and
+    ``transpose_jvp_dstpu_flash_bwd__.1``)."""
+    return sorted(re.findall(
+        r"%\w*?(dstpu_flash_(?:fwd|bwd_dq|bwd_dkv|bwd))[_.\d]* = ", hlo))
+
+
 _NOT_OPS = {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
             "call", "conditional"}
 

@@ -23,8 +23,10 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.inference import kernels as K
 from deepspeed_tpu.ops.attention_pallas import flash_attention_tpu
+from deepspeed_tpu.ops.attention_pallas_bwd import flash_backward
 
-from _aot import DH, PAGE, TABLE_TOKENS, _compile, _pool_sized_ops
+from _aot import (DH, PAGE, TABLE_TOKENS, _compile, _flash_kernels,
+                  _pool_sized_ops)
 
 # (name, heads, kv_heads, batch, seq) — head_dim is 128 in both
 GPT2_1_3B = ("gpt2_1_3b", 16, 16, 4, 1024)
@@ -56,7 +58,42 @@ def test_flash_attention(chip, layout, grad):
         return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    _compile(fwd_bwd if grad else fwd, chip, *_qkv(layout))
+    hlo = _compile(fwd_bwd if grad else fwd, chip, *_qkv(layout)).as_text()
+    kernels = set(_flash_kernels(hlo))
+    # which backward a layout compiled is the rule's answer for its
+    # shapes: GPT-2's head (1024 x 128) lies whole in the vector memory
+    # (Mosaic's own check of it ran just now); the GQA layout's dK and
+    # dV sum over four query heads, which the split sweep does
+    _, H, KV, _, T = layout
+    path, why = flash_backward(T, T, DH, H, KV)
+    assert (path, why[:3]) == {"gpt2_1_3b": ("fused", "a h"),
+                               "gqa_32_8": ("split", "GQA")}[layout[0]]
+    backward = {"fused": {"dstpu_flash_bwd"},
+                "split": {"dstpu_flash_bwd_dq", "dstpu_flash_bwd_dkv"}}[path]
+    assert kernels == {"dstpu_flash_fwd"} | (backward if grad else set())
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("T,D,dtype", [
+    (2048, 128, jnp.bfloat16), (1024, 128, jnp.float32),
+    (2048, 64, jnp.bfloat16)], ids=["2048x128_bf16", "1024x128_f32",
+                                    "2048x64_bf16"])
+def test_fused_flash_backward_at_the_rules_edge(chip, T, D, dtype, causal):
+    """The largest heads ``flash_backward`` admits, a dtype and a head
+    width: Mosaic's own count of the vector memory runs on each, with the
+    mask and without (without, every key block meets every query row and
+    the body's temporaries are at their most: 14.3 MiB of the 32 asked
+    for at 2048 x 128 in bf16).  One row more and the rule answers
+    ``split``."""
+    size = jnp.dtype(dtype).itemsize
+    assert flash_backward(T, T, D, 2, 2, False, size)[0] == "fused"
+    assert flash_backward(2 * T, 2 * T, D, 2, 2, False, size)[0] == "split"
+    hlo = _compile(
+        lambda *a: jax.grad(lambda *a: flash_attention_tpu(
+            *a, causal=causal).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(*a),
+        chip, *[((1, T, 2, D), dtype)] * 3).as_text()
+    assert set(_flash_kernels(hlo)) == {"dstpu_flash_fwd", "dstpu_flash_bwd"}
 
 
 @pytest.mark.parametrize("layout", LAYOUTS, ids=lambda l: l[0])

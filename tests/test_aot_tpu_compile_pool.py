@@ -18,8 +18,8 @@ from deepspeed_tpu.inference.paged_forward import forward_paged
 from deepspeed_tpu.inference.serving import _sample_rows, serving_programs
 from deepspeed_tpu.models import gpt2, mixtral
 
-from _aot import (DH, PAGE, _blocked_chunk_reader, _pool_scatters,
-                  _pool_sized_ops, _shaped_like)
+from _aot import (DH, PAGE, _blocked_chunk_reader, _flash_kernels,
+                  _pool_scatters, _pool_sized_ops, _shaped_like)
 
 
 # ------------------------------------------- the K/V pool stays in place
@@ -210,7 +210,8 @@ def test_mixtral_chunk_program_groups_the_rows_by_expert(
 def test_zero3_step_gathers_a_layer_and_scatters_its_gradient(
         topo, monkeypatch):
     """``gpt2-1.3b.train.zero3-x4``'s loss and gradient at the published
-    widths (two layers), compiled for the 2x2: a layer's weights arrive
+    widths (two layers), compiled for the 2x2: the flash forward kernel
+    runs once a layer and one backward kernel a head; a layer's weights arrive
     by bf16 all-gathers and nothing activation-shaped moves inside the
     layer loop; the four matrices' gradients leave as reduce-scatter
     fusions (the TPU compiler's spelling); the one all-to-all left is the
@@ -239,11 +240,18 @@ def test_zero3_step_gathers_a_layer_and_scatters_its_gradient(
         g = jax.grad(lambda p: loss(cast(p), {"tokens": tokens}))(params)
         return zero.grad_constraint(g, ms, 3)
 
-    hlo = jax.jit(grads, out_shardings=layout).lower(
+    compiled = jax.jit(grads, out_shardings=layout).lower(
         jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=sh), shapes, layout),
         jax.ShapeDtypeStruct((B, T + 1), jnp.int32, sharding=ms.sharding(
-            ms.batch_spec()))).compile().as_text()
+            ms.batch_spec()))).compile()
+    hlo = compiled.as_text()
+    # the flash forward kernel once, in the forward's layer body: its
+    # context and log-sum survive ``save_dots`` (before PR 62 the
+    # backward's body ran it again), and one backward kernel a head
+    assert _flash_kernels(hlo) == ["dstpu_flash_bwd", "dstpu_flash_fwd"]
+    # two layers' step: 1.70 GiB of temporaries (PERF.md 6, PR 62)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.9 * 2 ** 30
 
     lines = [l for l in hlo.splitlines() if re.search(
         r" (all-gather|all-to-all|collective-permute)(-start)?\(", l)]

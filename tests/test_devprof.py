@@ -298,6 +298,31 @@ class TestLedger:
             fn(jnp.ones((8, 8)))
         assert len(_entries("dstpu_t_span")) == 1
 
+    def test_a_program_adds_its_own_words_to_the_span_it_is_built_under(self):
+        """An event ``BUILD_WORD_EVENT`` on JAX's bus at trace time (the
+        flash backward's rule of the shapes, PR 62): after the span's
+        own words, both answers where a word was said with two, and gone
+        with the next span."""
+        import jax
+        import jax.numpy as jnp
+
+        def body(x):
+            for path in ("fused", "split", "fused"):
+                jax.monitoring.record_event(
+                    devprof_mod.BUILD_WORD_EVENT, flash_bwd=path, why="fits")
+            return x @ x
+
+        span = ProgramSpan(MetricsRegistry().span("build_program"))
+        with span("train_step"):
+            jax.jit(_named("dstpu_t_noted", body))(jnp.ones((8, 8)))
+        with span("decode_chunk", b=2):
+            jax.jit(_named("dstpu_t_silent", lambda x: x + x))(
+                jnp.ones((8, 8)))
+        (noted,), (silent,) = (_entries("dstpu_t_noted"),
+                               _entries("dstpu_t_silent"))
+        assert noted["span"] == "train_step flash_bwd=fused+split why=fits"
+        assert silent["span"] == "decode_chunk b=2"
+
 
 _CACHE_PROBE = """
 import json, sys, tempfile

@@ -434,7 +434,7 @@ def test_the_paged_readers_run_by_name_under_kv_attend(tokens, kernel):
 
 def test_every_mosaic_kernel_has_a_name_of_its_own():
     from deepspeed_tpu.inference import kernels as K
-    from deepspeed_tpu.ops import attention_pallas, quant
+    from deepspeed_tpu.ops import attention_pallas, attention_pallas_bwd, quant
 
     q = jnp.zeros((1, 128, 2, 128), jnp.float32)
     flash = lambda q: attention_pallas.flash_attention_tpu(
@@ -456,6 +456,9 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
         "dstpu_flash_fwd": lambda: jax.make_jaxpr(flash)(q),
         "dstpu_flash_bwd_dq": lambda: jax.make_jaxpr(jax.grad(flash))(q),
         "dstpu_flash_bwd_dkv": lambda: jax.make_jaxpr(jax.grad(flash))(q),
+        # two blocks of 256 key rows: the rule of the shapes answers fused
+        "dstpu_flash_bwd": lambda: jax.make_jaxpr(jax.grad(flash))(
+            jnp.zeros((1, 512, 2, 128), jnp.float32)),
         "dstpu_paged_chunk_v2": lambda: jax.make_jaxpr(
             lambda: K.paged_chunk_attention_v2(
                 qc, pages, pages, table, start, interpret=True))(),
@@ -485,11 +488,11 @@ def test_every_mosaic_kernel_has_a_name_of_its_own():
     for want, make in sites.items():
         names = [n for n, _ in _pallas_scopes(make().jaxpr, [])]
         assert want in names, (want, names)
-    # the sources give eleven sites eleven names, none shared
+    # the sources give twelve sites twelve names, none shared
     named = []
-    for mod in (K, attention_pallas, quant):
+    for mod in (K, attention_pallas, attention_pallas_bwd, quant):
         with open(mod.__file__) as f:
             text = f.read()
         assert text.count("pl.pallas_call(") == text.count('name="dstpu_')
         named += re.findall(r'name="(dstpu_[a-z0-9_]+)"', text)
-    assert len(named) == len(set(named)) == 11
+    assert len(named) == len(set(named)) == 12
